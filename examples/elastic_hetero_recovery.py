@@ -32,7 +32,6 @@ os.environ["XLA_FLAGS"] = \
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
-jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 
@@ -87,4 +86,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.engine.precompile import (
+        enable_persistent_compilation_cache)
+    enable_persistent_compilation_cache()
     main()

@@ -14,8 +14,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def worker():
+    os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU multi-process demo
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
     from hetu_tpu import optim
     from hetu_tpu.engine import build_train_step, init_state, make_plan

@@ -16,10 +16,6 @@ Run (CPU simulation):
 import os
 import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import dataclasses
 
 import jax
@@ -95,4 +91,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.engine.precompile import (
+        enable_persistent_compilation_cache)
+    enable_persistent_compilation_cache()
     main()

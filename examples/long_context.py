@@ -12,10 +12,6 @@ On a TPU slice, raise --seq (32k+) and drop the platform overrides.
 import os
 import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import argparse
 import time
 
@@ -65,4 +61,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from hetu_tpu.engine.precompile import (
+        enable_persistent_compilation_cache)
+    enable_persistent_compilation_cache()
     main()

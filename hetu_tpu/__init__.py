@@ -26,10 +26,6 @@ Layer map (mirrors SURVEY.md §1, re-architected for XLA):
 
 from hetu_tpu.version import __version__
 
-from hetu_tpu.core import compat as _compat
-
-_compat.install()   # jax API shims (shard_map on 0.4.x) before submodules
-
 from hetu_tpu.core.dtypes import Policy, autocast, current_policy
 from hetu_tpu.core.mesh import make_mesh, local_devices
 from hetu_tpu import telemetry

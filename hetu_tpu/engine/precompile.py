@@ -250,27 +250,33 @@ def precompile_top_k(model, opt, dims, topo, *, k: int = 3,
                                  batch_shape=batch_shape, **kw)
 
 
+#: The one fixed cache directory inside the checkout (git-ignored). The
+#: path is part of the cache key, so it never moves with a pid or a time.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def enable_persistent_compilation_cache(
         path: Optional[str] = None, *,
-        min_compile_seconds: float = 1.0) -> Optional[str]:
-    """Point jax's persistent (on-disk) compilation cache at ``path`` so
-    process restarts start warm: the cache is keyed on the XLA program,
-    so an identical strategy re-compiled after a restart is a disk read
+        min_compile_seconds: float = 1.0) -> str:
+    """Turn on jax's persistent (on-disk) compilation cache so process
+    restarts start warm: the cache is keyed on the XLA program, so an
+    identical strategy re-compiled after a restart is a disk read
     instead of a full XLA compile.
 
-    ``path`` defaults to ``$HETU_COMPILE_CACHE_DIR`` (unset + no arg =
-    no-op, returns None — the cache stays opt-in because XLA:CPU
-    executable *deserialization* is known-broken under jaxlib 0.4.37
-    when many processes share one cache; see docs/PERFORMANCE.md).
-    Returns the activated path."""
-    path = path or os.environ.get("HETU_COMPILE_CACHE_DIR")
-    if not path:
-        return None
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, jax's own handling of
+    it is the whole mechanism and nothing is set here. Otherwise the
+    cache goes to ``path``, by default :data:`COMPILE_CACHE_DIR`. The
+    entry points (``chip_smoke.py``, ``bench.py``, ``examples/``) call
+    this with no argument; ``import hetu_tpu`` never does. Returns the
+    directory in force."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = path or COMPILE_CACHE_DIR
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_seconds))
-    except Exception:     # knob renamed across jax versions: best-effort
-        pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_seconds))
     return path

@@ -82,16 +82,9 @@ class TrainerConfig:
                                  # persistent XLA compilation cache dir
                                  # (engine.precompile.enable_persistent_
                                  # compilation_cache): restarts re-trace
-                                 # but skip the XLA compile. Also honors
-                                 # $HETU_COMPILE_CACHE_DIR when unset.
-    comm_overlap: str = "auto"   # "auto": wire XLA's async-collective +
-                                 # latency-hiding-scheduler flags on TPU
-                                 # (parallel.overlap.enable_xla_overlap)
-                                 # — the automatic comm/compute overlap
-                                 # fallback when the manual ring
-                                 # (Strategy.tp_overlap="ring") is off;
-                                 # "off": leave XLA_FLAGS alone. Only
-                                 # effective before backend init.
+                                 # but skip the XLA compile. Where
+                                 # $JAX_COMPILATION_CACHE_DIR is set,
+                                 # jax's handling of it wins.
     aggregate_every: int = 0     # cadence (steps) for publishing this
                                  # rank's metric snapshot through
                                  # telemetry.cluster_aggregate during
@@ -150,20 +143,13 @@ class Trainer:
         # .rank / .num_processes) — enables the cross-rank telemetry
         # aggregation cadence (config.aggregate_every) on multi-host runs
         self._dist = dist
-        if self.config.comm_overlap != "off":
-            # XLA-side comm/compute overlap: best-effort (only lands
-            # before backend init, TPU-only flags), the data-plane
-            # fallback when the manual ring is not in force
-            from hetu_tpu.parallel.overlap import enable_xla_overlap
-            enable_xla_overlap()
         self.state: Optional[TrainState] = None
         self.plan = None
         self._step_fn = None
         self._eval_fn = None
         self._live_prefetcher = None   # re-pointed on mid-run hot switch
         self._ckpt_writer: Optional[CheckpointWriter] = None
-        if self.config.compile_cache_dir \
-                or "HETU_COMPILE_CACHE_DIR" in os.environ:
+        if self.config.compile_cache_dir:
             from hetu_tpu.engine.precompile import (
                 enable_persistent_compilation_cache)
             enable_persistent_compilation_cache(

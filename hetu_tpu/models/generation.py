@@ -10,6 +10,7 @@ top-k / nucleus (top-p) sampling.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -70,6 +71,25 @@ def init_kv_caches(model, batch: int, max_len: int, dtype=jnp.float32):
     return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
+def init_paged_caches(model, n_blocks: int, block_size: int,
+                      dtype=jnp.float32, sharding=None):
+    """The block-paged arena: :func:`init_kv_caches` leaves with
+    (batch, max_len) := (n_blocks, block_size) and the trailing
+    ``(hkv, d)`` — ``(hkv, 1)`` for int8 scales — merged into ONE minor
+    dim, ``(L, n_blocks, block_size, hkv*d)``. The TPU tiles an array's
+    last two dims (8, 128): a (12, 64) pair would either pad 2.7x or
+    make XLA pick a blocks-minor layout the paged kernel cannot take a
+    page from. Allocated in the stored shape and, where ``sharding``
+    is given, in place on it — a reshape or a ``device_put`` of finished
+    zeros would hold the arena twice on the device."""
+    leaves = jax.eval_shape(
+        lambda: init_kv_caches(model, n_blocks, block_size, dtype))
+    return tuple(
+        jnp.zeros(s.shape[:3] + (math.prod(s.shape[3:]),), s.dtype,
+                  device=sharding)
+        for s in leaves)
+
+
 def decode(model, params, input_ids, positions, caches, *,
            slot_mask=None, block_tables=None, row_mask=None,
            attn_kernel: str = "reference", w8a8_mask=None,
@@ -82,7 +102,7 @@ def decode(model, params, input_ids, positions, caches, *,
     ``positions[r, 0]`` — the serving engine's slot-pooled path — and
     masked-off rows leave their KV rows untouched. ``block_tables``
     (b, W) switches the caches to the block-paged arena layout
-    (``(L, n_blocks, block_size, hkv, d)`` leaves; see
+    (``(L, n_blocks, block_size, hkv*d)`` leaves; see
     ``ParallelAttention._decode``). ``row_mask`` (b, s) bool gates KV
     writes per CELL within a row (paged mode only) — the speculative
     verify lane's guard against draft rows beyond a slot's allocated

@@ -535,7 +535,7 @@ class ParallelAttention(Module):
         the caller).
 
         ``block_tables`` (b, W) switches the cache to the PAGED layout:
-        leaves are ``(n_blocks, block_size, hkv, d)`` arenas shared by
+        leaves are ``(n_blocks, block_size, hkv*d)`` arenas shared by
         every row, and row ``r``'s position ``p`` lives at arena row
         ``block_tables[r, p // bs] * bs + p % bs``. Writes become flat
         scatters (rows with ``slot_mask=False`` scatter out of bounds
@@ -557,7 +557,7 @@ class ParallelAttention(Module):
         selects HOW the attention reads the arena: "reference" is the
         XLA-gather path (materializes each row's full table view —
         :func:`~hetu_tpu.ops.attention.gather_block_rows`, the
-        CPU/0.4.37 fallback), "paged" streams KV tiles through the
+        CPU path), "paged" streams KV tiles through the
         block tables inside the Pallas kernel
         (:func:`~hetu_tpu.ops.paged_pallas.paged_attention_pallas` —
         no materialized gather, cost ∝ live context). Resolve requests
@@ -621,9 +621,11 @@ class ParallelAttention(Module):
 
         def upd(buf, new):
             if paged:
+                # the arena merges (hkv, d) into one minor dim
+                # (serving/kv_pool.py): rows take the buffer's shape
                 flat = buf.reshape((n_blk * blk,) + buf.shape[2:])
                 flat = flat.at[rows].set(
-                    new.reshape((-1,) + new.shape[2:]).astype(buf.dtype),
+                    new.reshape((-1,) + buf.shape[2:]).astype(buf.dtype),
                     mode="drop")
                 return flat.reshape(buf.shape)
             if per_row:
@@ -760,8 +762,9 @@ class ParallelAttention(Module):
 
         def upd(buf, new):
             flat = buf.reshape((n_blk * blk,) + buf.shape[2:])
-            flat = flat.at[rows].set(new[0].astype(buf.dtype),
-                                     mode="drop")
+            flat = flat.at[rows].set(
+                new[0].reshape((-1,) + buf.shape[2:]).astype(buf.dtype),
+                mode="drop")
             return flat.reshape(buf.shape)
 
         if quant:

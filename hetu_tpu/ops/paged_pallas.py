@@ -36,8 +36,8 @@ block size on the real chip into ``workloads/out/paged_blocks.json``
 (``core.measured.read_measured``, the same persistence the flash block
 sweep uses).
 
-The XLA-gather path (``paged_attention_reference``) remains the
-CPU/0.4.37 fallback and the parity oracle; dispatch lives in
+The XLA-gather path (``paged_attention_reference``) remains the CPU
+path and the parity oracle; dispatch lives in
 ``ParallelAttention._decode`` behind ``attn_kernel="paged"|"reference"``.
 """
 
@@ -84,12 +84,14 @@ def default_pages_per_step(block_size: int) -> int:
 
 
 def _paged_kernel(tbl_ref, off_ref, q_ref, *refs, rows, g, bs, L,
-                  n_steps, quant):
-    """One grid step: slot ``s``, kv head ``h``, table-lane chunk ``w``
-    (L pages). Online softmax across chunks (grid axis 2 is
+                  hkv, n_steps, quant):
+    """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
+    ``(bs, hkv*d)``, every kv head — a TPU block's last two dims must be
+    (8, 128)-tiled or span the array's, so heads are lane slices taken
+    inside the kernel). Online softmax across chunks (grid axis 1 is
     "arbitrary")."""
     s_i = pl.program_id(0)
-    w = pl.program_id(2)
+    w = pl.program_id(1)
 
     # static ref layout: L k pages, L v pages, [L k scales, L v scales],
     # then outputs (o, lse) and scratch (m, l, acc)
@@ -109,46 +111,51 @@ def _paged_kernel(tbl_ref, off_ref, q_ref, *refs, rows, g, bs, L,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    d = q_ref.shape[-1]
     off = off_ref[s_i]
     # q row r of the (rows = R*g) tile belongs to verify row r // g and
     # attends absolute positions <= off + r // g
     qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // g
     last_q = off + (rows // g - 1)
-    q = q_ref[0, 0]                              # (rows, d), scale folded
 
     for j in range(L):
         page_start = (w * L + j) * bs
 
         def compute(j=j, page_start=page_start):
-            if quant:
-                k = k_pages[j][0, :, 0].astype(jnp.float32) \
-                    * ks_pages[j][0, :, 0]       # (bs, d) dequant in VMEM
-                v = v_pages[j][0, :, 0].astype(jnp.float32) \
-                    * vs_pages[j][0, :, 0]
-            else:
-                k = k_pages[j][0, :, 0]          # (bs, d)
-                v = v_pages[j][0, :, 0]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
             kpos = page_start + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bs), 1)
             mask = kpos <= qpos
-            s = jnp.where(mask, s, NEG_INF)
-            m_prev = m_scr[:, :1]
-            l_prev = l_scr[:, :1]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_next = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(s - m_next)
-            p = jnp.where(mask, p, 0.0)
-            l_cur = jnp.sum(p, axis=1, keepdims=True)
-            alpha = jnp.exp(m_prev - m_next)
-            m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
-            l_scr[...] = jnp.broadcast_to(alpha * l_prev + l_cur,
-                                          l_scr.shape)
-            pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_scr[...] = acc_scr[...] * alpha + pv
+            for h in range(hkv):
+                q = q_ref[0, h]                  # (rows, d), scale folded
+                head = slice(h * d, (h + 1) * d)
+                if quant:
+                    # (bs, d) dequant in VMEM
+                    k = k_pages[j][0, :, head].astype(jnp.float32) \
+                        * ks_pages[j][0, :, h:h + 1]
+                    v = v_pages[j][0, :, head].astype(jnp.float32) \
+                        * vs_pages[j][0, :, h:h + 1]
+                else:
+                    k = k_pages[j][0, :, head]   # (bs, d)
+                    v = v_pages[j][0, :, head]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                s = jnp.where(mask, s, NEG_INF)
+                m_prev = m_scr[h, :, :1]
+                l_prev = l_scr[h, :, :1]
+                m_cur = jnp.max(s, axis=1, keepdims=True)
+                m_next = jnp.maximum(m_prev, m_cur)
+                p = jnp.exp(s - m_next)
+                p = jnp.where(mask, p, 0.0)
+                l_cur = jnp.sum(p, axis=1, keepdims=True)
+                alpha = jnp.exp(m_prev - m_next)
+                m_scr[h] = jnp.broadcast_to(m_next, m_scr.shape[1:])
+                l_scr[h] = jnp.broadcast_to(alpha * l_prev + l_cur,
+                                            l_scr.shape[1:])
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                acc_scr[h] = acc_scr[h] * alpha + pv
 
         # dead-lane skip: pages wholly beyond the slot's last live
         # position never touch the MXU (cost ∝ context, not table
@@ -157,11 +164,12 @@ def _paged_kernel(tbl_ref, off_ref, q_ref, *refs, rows, g, bs, L,
 
     @pl.when(w == n_steps - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[:, :, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(l == 0.0, NEG_INF, m_scr[:, :1] + jnp.log(l_safe))
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        lse = jnp.where(l == 0.0, NEG_INF,
+                        m_scr[:, :, :1] + jnp.log(l_safe))
+        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
 def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
@@ -176,9 +184,12 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
       k+1 for the speculative verify lane, C×1 for the packed-prefill
       per-token rows); row ``i`` of slot ``s`` attends absolute
       positions ``<= q_offset[s] + i``.
-    - ``k``/``v``: the paged arena ``(n_blocks, block_size, hkv, d)``;
-      int8 when ``k_scale``/``v_scale`` (``(n_blocks, block_size, hkv,
-      1)`` fp32) are given — pages dequantize per tile in VMEM.
+    - ``k``/``v``: the paged arena as stored, ``(n_blocks, block_size,
+      hkv*d)`` (``models.generation.init_paged_caches`` — the ONE
+      layout: a ``(hkv, d)`` minor pair would make the TPU re-tile the
+      whole arena around the kernel); int8 when ``k_scale``/``v_scale``
+      (``(n_blocks, block_size, hkv)`` fp32) are given — pages
+      dequantize per tile in VMEM.
     - ``block_tables``: ``(S, W)`` int32 — logical lane ``w`` of slot
       ``s`` holds positions ``[w*block_size, (w+1)*block_size)`` at
       physical page ``block_tables[s, w]``.
@@ -187,11 +198,14 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     Returns ``(S, R, hq, d)`` in q's dtype (plus the fp32
     ``(S, R*… )``-shaped LSE ``(S, hq, R)`` when ``return_lse`` — the
     packed-prefill lane's LSE-combine consumes it). Matches
-    ``attention_reference(causal=True, q_offset=array,
-    block_tables=...)`` semantics up to fp associativity.
+    :func:`paged_attention_reference` up to fp associativity.
     """
     S, R, hq, d = q.shape
-    n_blocks, bs, hkv, _ = k.shape
+    if k.ndim != 3:
+        raise ValueError(
+            f"the paged arena is (n_blocks, block_size, hkv*d); got "
+            f"{k.shape}")
+    n_blocks, bs, hkv = k.shape[0], k.shape[1], k.shape[2] // d
     g = hq // hkv
     rows = R * g
     quant = k_scale is not None
@@ -216,14 +230,16 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     qh = qf.reshape(S, R, hkv, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(S, hkv, rows, d)
 
-    q_spec = pl.BlockSpec((1, 1, rows, d),
-                          lambda s, h, w, tbl, off: (s, h, 0, 0))
+    q_spec = pl.BlockSpec((1, hkv, rows, d),
+                          lambda s, w, tbl, off: (s, 0, 0, 0))
 
     def page_spec(j, scalar=False):
-        width = 1 if scalar else d
+        # one whole page, all kv heads: the block's last two dims span
+        # the arena's (block_size, hkv*d) — a one-head block would
+        # slice the minor dims below the TPU's (8, 128) tile
         return pl.BlockSpec(
-            (1, bs, 1, width),
-            lambda s, h, w, tbl, off, j=j: (tbl[s, w * L + j], 0, h, 0))
+            (1, bs, hkv if scalar else hkv * d),
+            lambda s, w, tbl, off, j=j: (tbl[s, w * L + j], 0, 0))
 
     in_specs = [q_spec]
     args = [qh]
@@ -238,10 +254,10 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         args += [v_scale] * L
 
     out_specs = [
-        pl.BlockSpec((1, 1, rows, d),
-                     lambda s, h, w, tbl, off: (s, h, 0, 0)),
-        pl.BlockSpec((1, 1, rows, NUM_LANES),
-                     lambda s, h, w, tbl, off: (s, h, 0, 0)),
+        pl.BlockSpec((1, hkv, rows, d),
+                     lambda s, w, tbl, off: (s, 0, 0, 0)),
+        pl.BlockSpec((1, hkv, rows, NUM_LANES),
+                     lambda s, w, tbl, off: (s, 0, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((S, hkv, rows, d), q.dtype),
@@ -249,22 +265,22 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, hkv, n_steps),
+        grid=(S, n_steps),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((rows, NUM_LANES), jnp.float32),
-            pltpu.VMEM((rows, NUM_LANES), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((hkv, rows, NUM_LANES), jnp.float32),
+            pltpu.VMEM((hkv, rows, NUM_LANES), jnp.float32),
+            pltpu.VMEM((hkv, rows, d), jnp.float32),
         ],
     )
     out, lse_l = pl.pallas_call(
         functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
-                          n_steps=n_steps, quant=quant),
+                          hkv=hkv, n_steps=n_steps, quant=quant),
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, q_offset, *args)
 
@@ -318,7 +334,7 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
     nh = _axis_size(mesh, head_ax)
     if nh <= 1:
         return plain()
-    hq, hkv = q.shape[2], k.shape[2]
+    hq, hkv = q.shape[2], k.shape[2] // q.shape[3]
     if hq % nh or hkv % nh:
         # resolve_decode_kernel degrades ragged head counts before the
         # trace ever reaches here; keep the plain call as the safe twin
@@ -327,11 +343,12 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    head_spec = P(None, None, head_ax, None)   # q/out/arena: heads dim 2
-    in_specs = (head_spec,) * 3 + (P(None, None), P(None))
+    head_spec = P(None, None, head_ax, None)   # q/out: heads dim 2
+    page_spec = P(None, None, head_ax)         # arena: head-major dim 2
+    in_specs = (head_spec, page_spec, page_spec, P(None, None), P(None))
     args = (q, k, v, block_tables, jnp.asarray(q_offset, jnp.int32))
     if k_scale is not None:
-        in_specs += (head_spec, head_spec)
+        in_specs += (page_spec, page_spec)
         args += (k_scale, v_scale)
     out_specs = (head_spec, P(None, head_ax, None)) if return_lse \
         else head_spec
@@ -357,28 +374,30 @@ def paged_attention_reference(q, k, v, block_tables, q_offset, *,
     """The XLA-gather twin (and parity oracle): materialize each slot's
     table view with :func:`~hetu_tpu.ops.attention.gather_block_rows`
     and run the dense reference — exactly what ``ParallelAttention.
-    _decode`` did before the kernel existed, kept as the CPU/0.4.37
-    fallback. Int8 arenas gather quantized rows + scales (1/4 the
+    _decode`` did before the kernel existed, kept as the CPU
+    path. Int8 arenas gather quantized rows + scales (1/4 the
     bytes) and dequantize after, matching the kernel's lanes."""
     from hetu_tpu.ops.attention import (
         attention_reference, gather_block_rows,
     )
     from hetu_tpu.ops.quantization import dequantize_int8
+
+    def rows(buf, w):
+        # gather in the stored layout, split heads on the gathered rows
+        # only: (S, W*block_size, hkv*w) → (..., hkv, w) — never a
+        # reshape of the whole arena
+        x = gather_block_rows(buf, block_tables)
+        return x.reshape(x.shape[:2] + (-1, w))
+
+    d = q.shape[-1]
     if k_scale is not None:
-        k_buf = dequantize_int8(gather_block_rows(k, block_tables),
-                                gather_block_rows(k_scale, block_tables),
-                                q.dtype)
-        v_buf = dequantize_int8(gather_block_rows(v, block_tables),
-                                gather_block_rows(v_scale, block_tables),
-                                q.dtype)
-        return attention_reference(q, k_buf, v_buf, causal=causal,
-                                   q_offset=q_offset, kv_offset=0,
-                                   scale=scale, return_lse=return_lse)
-    return attention_reference(q, k, v, causal=causal,
-                               q_offset=q_offset,
-                               kv_offset=0, scale=scale,
-                               block_tables=block_tables,
-                               return_lse=return_lse)
+        k_buf = dequantize_int8(rows(k, d), rows(k_scale, 1), q.dtype)
+        v_buf = dequantize_int8(rows(v, d), rows(v_scale, 1), q.dtype)
+    else:
+        k_buf, v_buf = rows(k, d), rows(v, d)
+    return attention_reference(q, k_buf, v_buf, causal=causal,
+                               q_offset=q_offset, kv_offset=0,
+                               scale=scale, return_lse=return_lse)
 
 
 def combine_attention_lse(o1, lse1, o2, lse2):

@@ -28,16 +28,12 @@ bytes per traced step program (`comm_bytes_total{kind=...}`), DP gradient
 sync counts from the delayed-sync wrappers in
 ``engine.train_step.build_grad_accum_steps``, and the derived
 ``comm_overlap_ratio`` that ``bench.py`` and ``tools/trace_summary.py``
-report. When the manual ring is off, :func:`enable_xla_overlap` wires
-XLA's async-collective + latency-hiding-scheduler flags as the automatic
-fallback (``TrainerConfig.comm_overlap``).
+report.
 """
 
 from __future__ import annotations
 
-import os
 import threading
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -513,63 +509,3 @@ def record_fsdp_gather_bytes(params, specs, ndp: int, *,
         nbytes += size * leaf.dtype.itemsize * (ndp - 1) // ndp
     record_comm_bytes("fsdp_gather", int(nbytes * n_layers),
                       overlapped=overlapped)
-
-
-# -- XLA scheduler fallback --------------------------------------------------
-
-#: Async-collective + latency-hiding-scheduler flags: XLA's own
-#: comm/compute overlap, used when the manual ring is off (or for the
-#: collectives the ring does not cover — ZeRO gathers, pipeline
-#: ppermutes). Known-good set from public TPU training recipes.
-XLA_OVERLAP_FLAGS = (
-    "--xla_tpu_enable_async_collective_fusion=true",
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-    "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
-    "--xla_tpu_overlap_compute_collective_tc=true",
-    "--xla_enable_async_all_gather=true",
-    "--xla_enable_async_collective_permute=true",
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-)
-
-
-def xla_overlap_flags() -> tuple:
-    return XLA_OVERLAP_FLAGS
-
-
-def enable_xla_overlap(*, force: bool = False) -> bool:
-    """Append the async-collective/latency-hiding flags to ``XLA_FLAGS``.
-
-    Only effective BEFORE backend initialization, and only applied when
-    the process is headed for a TPU backend (the flags are TPU-spelled;
-    an unknown flag is a hard abort on other backends) — ``force=True``
-    overrides the platform guess. Returns True when the environment was
-    modified. Idempotent."""
-    try:
-        from jax._src import xla_bridge
-        if xla_bridge.backends_are_initialized():
-            return False
-    except Exception:
-        if getattr(jax, "_src", None) is None:  # pragma: no cover
-            return False
-    if not force and not _tpu_expected():
-        return False
-    cur = os.environ.get("XLA_FLAGS", "")
-    # exact flag-name match: several names here are prefixes of others
-    # (e.g. ...async_collective_fusion vs ..._fuse_all_gather), so a
-    # substring test would let a preset longer flag suppress the base
-    present = {tok.split("=")[0] for tok in cur.split()}
-    missing = [f for f in XLA_OVERLAP_FLAGS
-               if f.split("=")[0] not in present]
-    if not missing:
-        return False
-    os.environ["XLA_FLAGS"] = (cur + " " + " ".join(missing)).strip()
-    return True
-
-
-def _tpu_expected() -> bool:
-    plats = os.environ.get("JAX_PLATFORMS", "") \
-        or os.environ.get("JAX_PLATFORM_NAME", "")
-    if plats:
-        return "tpu" in plats
-    import importlib.util
-    return importlib.util.find_spec("libtpu") is not None

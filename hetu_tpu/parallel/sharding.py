@@ -67,6 +67,31 @@ def _axis_size(mesh: Mesh, axis) -> int:
     return mesh.shape.get(axis, 1)
 
 
+def home_sharding(params, mesh: Optional[Mesh] = None):
+    """Where state lives that a compiled step hands back and takes
+    again beside ``params`` (a KV arena, advanced control vectors):
+    replicated on ``mesh`` — by default the mesh the params are typed
+    with (their first ``NamedSharding`` leaf's) — else committed to the
+    params' one device.
+
+    jit keys an executable on each argument's sharding and on whether
+    it is committed, types an array by its sharding's mesh, and returns
+    outputs committed and typed like the inputs. State born anywhere
+    else than where the step returns it is a second program on the
+    second call (same trace or not): allocate it here and pin the
+    step's ``out_shardings`` to the same."""
+    leaves = jax.tree.leaves(params)
+    if mesh is None:
+        mesh = next((x.sharding.mesh for x in leaves
+                     if isinstance(getattr(x, "sharding", None),
+                                   NamedSharding)), None)
+    if mesh is not None:
+        return NamedSharding(mesh, P())
+    on = [x for x in leaves if isinstance(x, jax.Array)]
+    dev = next(iter(on[0].devices())) if on else jax.devices()[0]
+    return jax.sharding.SingleDeviceSharding(dev)
+
+
 def manual_unbound_axes(b: int, heads) -> Optional[tuple]:
     """(abstract_mesh, axis_names, batch_ax, head_ax) when the trace is
     inside a partial-manual region (the pipeline executor) that left

@@ -61,9 +61,7 @@ class Strategy:
                                  # chunk matmuls so each comm hop hides
                                  # behind partial compute
                                  # (parallel.overlap, ASPLOS'23-style);
-                                 # "off": GSPMD collectives (pair with
-                                 # TrainerConfig.comm_overlap="auto" for
-                                 # XLA's async-collective scheduler)
+                                 # "off": GSPMD collectives
     pp_overlap: bool = False     # double-buffer the pipeline ring: the
                                  # ppermute of tick t's activations is
                                  # issued alongside tick t+1's stage

@@ -113,9 +113,7 @@ def bootstrap_distributed(*, coord_port: Optional[int] = None,
                 time.sleep(0.05)
                 addr = client.get(key)
         import jax
-        from hetu_tpu.core.compat import enable_cpu_collectives
         from hetu_tpu.telemetry.flight import flight_record
-        enable_cpu_collectives()   # old-jax CPU default is "none"
         # collective bootstraps are the classic distributed-hang site:
         # bracket the blocking initialize in the black box so a wedged
         # rendezvous is attributable post-mortem

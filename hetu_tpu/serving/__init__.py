@@ -2,7 +2,7 @@
 cache with radix-tree prefix sharing.
 
 - :mod:`~hetu_tpu.serving.kv_pool` — the paged KV arena
-  (``(layers, n_blocks, block_size, hkv, d)``), the refcounting
+  (``(layers, n_blocks, block_size, hkv*d)``), the refcounting
   :class:`BlockManager`, and sizing from the memory-plane ledger;
 - :mod:`~hetu_tpu.serving.prefix_cache` — the radix-tree prompt-prefix
   cache (whole-block sharing, CoW partial tails, LRU leaf eviction);

@@ -11,7 +11,7 @@ turn a decode loop into a serving engine, mapped onto TPU idioms:
   fixed token budget of prefill — so new requests join and finished
   ones leave between iterations, never mid-batch;
 - **block-paged KV** (vLLM's PagedAttention, SOSP'23): requests live in
-  a ``(layers, n_blocks, block_size, hkv, d)`` arena indexed through
+  a ``(layers, n_blocks, block_size, hkv*d)`` arena indexed through
   per-slot BLOCK TABLES (:class:`~hetu_tpu.serving.kv_pool.KVPool`),
   so bytes are allocated per block, not per worst-case slot;
 - **radix-tree prefix caching** (SGLang's RadixAttention): admission
@@ -75,7 +75,11 @@ asserted in ``tests/test_serving.py`` / ``tests/test_paged_serving.py``).
 
 TP-sharded serving rides the existing ``Strategy``/``make_plan`` path:
 pass ``plan=`` and the step traces under ``plan.act`` against sharded
-params, exactly like ``generate`` under a tp mesh.
+params, exactly like ``generate`` under a tp mesh. The arena and the
+control state the step returns live beside the params from
+construction (``__init__``: ``_rep`` / ``_arena_sh``), so committed or
+mesh-typed params — a plan's, a checkpoint's, a ``Trainer``'s handed
+over as they are — cost no second compile.
 """
 
 from __future__ import annotations
@@ -202,6 +206,26 @@ class ServingEngine:
             sizes.append(long_max_len)
             self._cp_buckets = SeqLenBuckets(sizes=sizes,
                                              multiple_of=mult)
+        # One home for what the compiled steps hand back and take again
+        # (the arena; pos/last_tok/key): beside the params, on their
+        # mesh (a plan=, or the Trainer's params on a train→serve
+        # handoff) or their one device — parallel.sharding.home_sharding
+        # says why anything else costs extra compiles of the "one"
+        # step. The arena is born there, control uploads go there, and
+        # the steps pin what they return to the same. Under a tp plan
+        # the arena's minor dim splits over the tp axis where kv heads
+        # divide it (what engine/memory prices per device and the tp
+        # paged kernel's shard_map reads).
+        from hetu_tpu.parallel.sharding import home_sharding
+        self._rep = self._arena_sh = home_sharding(
+            params, plan.mesh if plan is not None else None)
+        if plan is not None and isinstance(plan.act.tp, str):
+            n_tp = plan.mesh.shape.get(plan.act.tp, 1)
+            if n_tp > 1 and \
+                    model.blocks.block.attn.num_kv_heads % n_tp == 0:
+                from jax.sharding import NamedSharding, PartitionSpec
+                self._arena_sh = NamedSharding(plan.mesh, PartitionSpec(
+                    None, None, None, plan.act.tp))
         if slots is None:
             if hbm_budget_bytes is None:
                 raise ValueError("pass slots= or hbm_budget_bytes=")
@@ -232,7 +256,8 @@ class ServingEngine:
             self.pool = KVPool.sized_for(
                 model, hbm_budget_bytes=hbm_budget_bytes,
                 max_len=max_len, cache_dtype=cache_dtype, tp=tp,
-                block_size=block_size, table_len=long_max_len)
+                block_size=block_size, table_len=long_max_len,
+                sharding=self._arena_sh)
             if long_max_len is not None:
                 # admission-gate honesty: the lane's one-pass prefill
                 # carries real activation bytes the slot arithmetic
@@ -262,7 +287,8 @@ class ServingEngine:
             # admission's free-block gate keeps it sound.
             self.pool = KVPool(model, slots, max_len, cache_dtype,
                                block_size=block_size, n_blocks=kv_blocks,
-                               table_len=long_max_len)
+                               table_len=long_max_len,
+                               sharding=self._arena_sh)
         self.model = model
         self.params = params
         #: weight generation currently loaded — bumped by
@@ -485,6 +511,16 @@ class ServingEngine:
             if self._cp_buckets is not None else None
         self._spill_fn, self._resume_fn = self._build_spill_resume()
 
+    def step_executables(self) -> int:
+        """How many executables the ONE fused step holds (the jit's
+        cache entries). ``record_trace("serving_step")`` counts traces;
+        an operand that changes its sharding or its committedness
+        between calls compiles again under the SAME trace, and only
+        this count sees it (committed params beside an uncommitted
+        arena cost three compiles at one trace before the arena got its
+        home — ``__init__``)."""
+        return self._fn._cache_size()
+
     # -- KV spill / resume (resumable preemption) ---------------------------
     def _build_spill_resume(self):
         """Two tiny jits over the arena, both operating on a fixed
@@ -509,7 +545,9 @@ class ServingEngine:
                 lambda c, d: c.at[:, blk_ids].set(
                     d.astype(c.dtype), mode="drop"), caches, data)
 
-        return (jax.jit(spill), jax.jit(resume, donate_argnums=(0,)))
+        return (jax.jit(spill),
+                jax.jit(resume, donate_argnums=(0,),
+                        out_shardings=self._arena_sh))
 
     def _prequantize_decode_weights(self):
         """Build the decode lane's pre-quantized W8A8 weight tree from
@@ -725,7 +763,11 @@ class ServingEngine:
             return (caches, committed, ncommit, first_toks,
                     new_pos, new_last, new_key)
 
-        return jax.jit(step, donate_argnums=(1,))
+        # what the step returns AND takes again keeps its home
+        # (__init__): the arena, and the advanced pos/last_tok/key
+        rep = self._rep
+        return jax.jit(step, donate_argnums=(1,), out_shardings=(
+            self._arena_sh, None, None, None, rep, rep, rep))
 
     # -- the CP-prefill lane ------------------------------------------------
     def _build_cp_prefill(self):
@@ -779,8 +821,9 @@ class ServingEngine:
             def scat(buf, new):
                 flat = buf.reshape((buf.shape[0], n_blk * blk)
                                    + buf.shape[3:])
-                flat = flat.at[:, rows].set(new.astype(buf.dtype),
-                                            mode="drop")
+                flat = flat.at[:, rows].set(
+                    new.reshape(new.shape[:2] + buf.shape[3:])
+                    .astype(buf.dtype), mode="drop")
                 return flat.reshape(buf.shape)
 
             k_new, v_new = ks[:, 0], vs[:, 0]    # (layers, L, hkv, d)
@@ -811,7 +854,8 @@ class ServingEngine:
             return caches, tok.astype(jnp.int32), \
                 jax.random.key_data(k)
 
-        return jax.jit(cp_prefill, donate_argnums=(1,))
+        return jax.jit(cp_prefill, donate_argnums=(1,),
+                       out_shardings=(self._arena_sh, None, None))
 
     def _prep_cp_prefill_locked(self) -> Optional[dict]:
         """Pop ONE pending CP-lane request and build its host operands
@@ -2152,16 +2196,16 @@ class ServingEngine:
                 np.clip(d_tok, 0, v - 1, out=d_tok)
         with self._lock:
             if self._ctl_dirty:
-                self._ctl_dev = {"pos": jnp.asarray(self._pos),
-                                 "last_tok": jnp.asarray(self._last_tok),
-                                 "active": jnp.asarray(self._active),
-                                 "temp": jnp.asarray(self._temp),
-                                 "topk": jnp.asarray(self._topk),
-                                 "topp": jnp.asarray(self._topp),
-                                 "key": jnp.asarray(self._key_state),
-                                 "adapter": jnp.asarray(
-                                     self._adapter_page)}
-                self._bt_dev = jnp.asarray(self._bt)
+                # uploaded to the step's home: pos/last_tok/key come
+                # back from the step on it, and a differently-typed
+                # upload would be a different program
+                self._ctl_dev, self._bt_dev = jax.device_put(
+                    ({"pos": self._pos, "last_tok": self._last_tok,
+                      "active": self._active, "temp": self._temp,
+                      "topk": self._topk, "topp": self._topp,
+                      "key": self._key_state,
+                      "adapter": self._adapter_page}, self._bt),
+                    self._rep)
                 self._ctl_dirty = False
             ctl = self._ctl_dev
             # pack the prefill budget FCFS over in-flight prefills: the

@@ -421,15 +421,22 @@ class RemoteEngineProxy:
         from hetu_tpu.serving.streaming import (
             count_fallback, count_subscribe,
         )
+        # claim the stream BEFORE the frame goes out: the server's
+        # answer (a ``drop`` from an engine without streaming, a ``done``
+        # for a request that already finished) can reach the reader
+        # thread first, and its ``_stream_ok = False`` must not be
+        # overwritten afterwards — the poll lane would never take the
+        # request back
+        rr._stream_ok = True
         try:
             ch = self._stream_channel()
             ch.subscribe(rr.id, offset=len(rr.tokens),
                          sink=lambda ev, _rr=rr:
                          self._on_stream_event(_rr, ev))
         except Exception:                             # noqa: BLE001
+            rr._stream_ok = False
             count_fallback("subscribe_failed")
             return False
-        rr._stream_ok = True
         count_subscribe("resume" if resume else "new")
         return True
 

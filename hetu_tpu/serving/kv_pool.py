@@ -8,7 +8,8 @@ shared system prompt is stored once per slot. This module is the
 PagedAttention answer (vLLM, SOSP'23) mapped onto the jit-once TPU
 discipline:
 
-- the arena is ``(layers, n_blocks, block_size, kv_heads, head_dim)``,
+- the arena is ``(layers, n_blocks, block_size, kv_heads*head_dim)``
+  (heads merged into the minor dim — the TPU-tileable page layout),
   allocated once; a request maps onto a per-slot BLOCK TABLE (fixed
   ``max_len/block_size`` width, padded with the null block 0), and the
   compiled step indexes KV through a gather on the table
@@ -18,8 +19,8 @@ discipline:
   cache (``serving/prefix_cache.py``) maps one physical block into many
   slots' tables, so a fleet-wide system prompt is prefilled once and
   costs one set of pages total;
-- the fp32/bf16/int8 layouts are exactly ``generation.init_kv_caches``
-  with (batch, max_len) := (n_blocks, block_size) — the int8 pool
+- the fp32/bf16/int8 leaves come from
+  ``generation.init_paged_caches`` — the int8 pool
   quarters decode's HBM bandwidth with per-(position, head) scales, and
   quantized blocks are shared bit-for-bit like fp blocks.
 
@@ -38,7 +39,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
-from hetu_tpu.models.generation import init_kv_caches
+from hetu_tpu.models.generation import init_paged_caches
 
 #: block table entries point here when a position is unallocated; the
 #: null block is never handed out and never written, so its rows stay
@@ -124,7 +125,7 @@ class KVPool:
     def __init__(self, model, slots: int, max_len: int,
                  cache_dtype=jnp.float32, block_size: Optional[int] = None,
                  n_blocks: Optional[int] = None,
-                 table_len: Optional[int] = None):
+                 table_len: Optional[int] = None, sharding=None):
         max_positions = getattr(getattr(model, "cfg", None),
                                 "max_positions", None)
         if max_positions is not None and max_len > max_positions:
@@ -175,17 +176,19 @@ class KVPool:
         #: belongs to the new generation — the tag is how audits (and
         #: the version-tagged prefix trie) tell the two apart.
         self.weight_version = 0
-        # the paged arena reuses the generation layouts with
-        # (batch, max_len) := (n_blocks, block_size)
-        self.caches = init_kv_caches(model, self.n_blocks,
-                                     self.block_size, cache_dtype)
+        # ``sharding``: where the engine's compiled steps keep the
+        # arena (ServingEngine places it with its params)
+        self.caches = init_paged_caches(model, self.n_blocks,
+                                        self.block_size, cache_dtype,
+                                        sharding=sharding)
 
     @classmethod
     def sized_for(cls, model, *, hbm_budget_bytes: float, max_len: int,
                   cache_dtype=jnp.float32, tp: int = 1,
                   max_slots: Optional[int] = None,
                   block_size: Optional[int] = None,
-                  table_len: Optional[int] = None) -> "KVPool":
+                  table_len: Optional[int] = None,
+                  sharding=None) -> "KVPool":
         """Build the largest pool the HBM budget allows (ledger-sized:
         whole worst-case slots, so admission can never strand a request
         that passed the budget gate)."""
@@ -205,7 +208,7 @@ class KVPool:
             if table_len else None
         return cls(model, slots, max_len, cache_dtype,
                    block_size=block_size, table_len=table_len,
-                   n_blocks=n_blocks)
+                   n_blocks=n_blocks, sharding=sharding)
 
     @property
     def quantized(self) -> bool:

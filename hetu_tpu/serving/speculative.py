@@ -372,7 +372,7 @@ class ModelDraftsman:
         import jax
         import jax.numpy as jnp
 
-        from hetu_tpu.models.generation import init_kv_caches
+        from hetu_tpu.models.generation import init_paged_caches
 
         check_draft_model(model)
         self.model = model
@@ -396,9 +396,14 @@ class ModelDraftsman:
                 f"the target's max_len {max_len} + spec_depth "
                 f"{self.K} rows — use a draft model with a longer "
                 f"context or lower spec_depth")
-        self.caches = init_kv_caches(
+        # the draft arena lives beside the draft params, like the
+        # engine's beside its params (parallel.sharding.home_sharding)
+        from hetu_tpu.parallel.sharding import home_sharding
+        self._rep = home_sharding(params)
+        self.caches = init_paged_caches(
             model, self.slots + 1, self.row_len,
-            cache_dtype if cache_dtype is not None else jnp.float32)
+            cache_dtype if cache_dtype is not None else jnp.float32,
+            sharding=self._rep)
         # identity tables: slot r owns arena block r+1 (0 = null)
         self._tables = jnp.asarray(
             np.arange(1, self.slots + 1, dtype=np.int32)[:, None])
@@ -482,7 +487,8 @@ class ModelDraftsman:
                 q = q1[:, None]
             return caches, drafts, q           # (S, K), (S, K, Vq)
 
-        return jax.jit(draft_step, donate_argnums=(1,))
+        return jax.jit(draft_step, donate_argnums=(1,),
+                       out_shardings=(self._rep, None, None))
 
     def reset(self, slot: int, tokens: Sequence[int]) -> None:
         """A new (or resumed) request owns ``slot``: its draft KV is

@@ -39,9 +39,39 @@ MEM_CALIBRATION_PATH = os.path.join(
     os.path.dirname(CALIBRATION_PATH), "mem_calibration.json")
 
 
+#: Per-chip facts keyed by ``device_kind``: bf16 peak FLOP/s and HBM
+#: bytes. The ONE table, and it holds only kinds this tree has run on —
+#: ``"TPU v5 lite"`` is what the v5e reports (chip run, PR 21); 197e12
+#: and 16 GB are its published figures (Google Cloud TPU documentation,
+#: "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM2e per chip). A device that is
+#: not here is an error, never a default: add its row, with the source
+#: of its bf16 figure, when the program first runs on it.
+DEVICE_SPECS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "hbm_bytes": 16e9},
+}
+
+
+def device_spec(device) -> dict:
+    """``DEVICE_SPECS`` row of a live device; where the runtime reports
+    its memory limit (``memory_stats()["bytes_limit"]``) that replaces
+    the published size. An unknown ``device_kind`` raises."""
+    kind = device.device_kind
+    if kind not in DEVICE_SPECS:
+        raise KeyError(f"no peak/memory on record for device kind "
+                       f"{kind!r} (tools/galvatron/cost_model.py "
+                       f"DEVICE_SPECS)")
+    spec = dict(DEVICE_SPECS[kind])
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        spec["hbm_bytes"] = float(limit)
+    return spec
+
+
 @dataclasses.dataclass(frozen=True)
 class TPUTopology:
-    """One slice. Defaults ≈ TPU v5p."""
+    """One slice. The field defaults ≈ TPU v5p are for planning and
+    simulation off the chip; on a live TPU :meth:`calibrated` takes peak
+    and memory from the device (:func:`device_spec`)."""
 
     num_devices: int
     peak_flops: float = 459e12        # bf16 per chip
@@ -78,31 +108,36 @@ class TPUTopology:
                    ) -> "TPUTopology":
         """Topology seeded from the MEASURED calibration when one exists
         (profile-first, like the reference's ``profile_hardware`` flow —
-        ``tools/Galvatron/galvatron/profile_hardware/``); spec-sheet
-        defaults otherwise. Explicit ``overrides`` always win."""
-        fields = {}
-        try:
-            with open(path or CALIBRATION_PATH) as f:
-                cal = json.load(f)
+        ``tools/Galvatron/galvatron/profile_hardware/``). On a live TPU
+        peak and memory come from the device (:func:`device_spec`; an
+        unknown kind raises) and a calibration recorded on another
+        ``device_kind`` is not applied; off the chip the spec defaults
+        stand in. A missing file is no calibration; an unreadable one
+        raises. Explicit ``overrides`` always win."""
+        import jax
+        dev = jax.devices()[0]
+        on_tpu = dev.platform == "tpu"
+        fields = device_spec(dev) if on_tpu else {}
+
+        def load(p):
+            if not os.path.exists(p):
+                return None
+            with open(p) as f:
+                return json.load(f)
+
+        cal = load(path or CALIBRATION_PATH)
+        if cal is not None and (not on_tpu or cal.get(
+                "device_kind", dev.device_kind) == dev.device_kind):
             for k in ("peak_flops", "ici_bw", "dcn_bw", "hbm_bytes",
                       "mxu_efficiency", "dp_overlap"):
-                if k in cal:
-                    fields[k] = float(cal[k])
-        except (OSError, ValueError, TypeError, KeyError):
-            fields = {}     # torn/hand-edited file → spec defaults whole
-        try:
-            with open(MEM_CALIBRATION_PATH) as f:
-                mc = json.load(f)
-            # parse fully before assigning: a torn file must not apply
-            # half (global scale without its per-remat refinements)
-            mem_scale = float(mc["mem_scale"])
-            mem_scale_remat = tuple(
+                if k in cal:    # the live device's own facts stay
+                    fields.setdefault(k, float(cal[k]))
+        mc = load(MEM_CALIBRATION_PATH)
+        if mc is not None:
+            fields["mem_scale"] = float(mc["mem_scale"])
+            fields["mem_scale_remat"] = tuple(
                 (str(r), float(s))
                 for r, s in mc.get("remat_scales", {}).items())
-            fields["mem_scale"] = mem_scale
-            fields["mem_scale_remat"] = mem_scale_remat
-        except (OSError, ValueError, TypeError, KeyError):
-            pass
         fields.update(overrides)
         return cls(num_devices=num_devices, **fields)
 

@@ -14,7 +14,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_bench_emits_json_contract():
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"   # force the fallback path
+    env["JAX_PLATFORMS"] = "cpu"   # the CPU smoke, asked for
     r = subprocess.run([sys.executable, os.path.join(_ROOT, "bench.py")],
                        capture_output=True, text=True, timeout=300,
                        env=env, cwd=_ROOT)
@@ -34,7 +34,7 @@ def test_bench_serving_emits_json_contract(tmp_path):
     iteration-normalized TPOT improving monotonically with acceptance,
     and a preempt→spill→resume probe that lost nothing."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--serving"],
         capture_output=True, text=True, timeout=500, env=env, cwd=_ROOT)
@@ -95,7 +95,7 @@ def test_bench_router_emits_json_contract():
     write BENCH_router.json with the zero-downtime weight-push
     evidence (the fleet-plane round artifact)."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--router"],
         capture_output=True, text=True, timeout=500, env=env, cwd=_ROOT)
@@ -127,7 +127,7 @@ def test_bench_ragged_emits_json_contract():
     long-prompt probe served through the CP lane (the shape-plane round
     evidence)."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--ragged"],
         capture_output=True, text=True, timeout=500, env=env, cwd=_ROOT)
@@ -170,7 +170,7 @@ def test_bench_chaos_emits_json_contract():
     final loss (recovery is lossless), and async+delta checkpointing
     blocking the loop measurably less than sync full saves."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--chaos"],
         capture_output=True, text=True, timeout=580, env=env, cwd=_ROOT)
@@ -219,7 +219,7 @@ def test_bench_moe_emits_json_contract():
     BENCH_moe.json with the serialized-vs-chunked and eager-vs-delayed
     evidence (the expert-plane round artifact)."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--moe"],
         capture_output=True, text=True, timeout=500, env=env, cwd=_ROOT)
@@ -251,7 +251,7 @@ def test_bench_kernels_emits_json_contract():
     comparison — the CPU smoke runs the Pallas kernels in interpret
     mode (schema in place for the real-TPU measurement-debt run)."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--kernels"],
         capture_output=True, text=True, timeout=560, env=env, cwd=_ROOT)
@@ -290,7 +290,7 @@ def test_bench_fleet_emits_json_contract():
     the shared-prefix lanes (directory pull on/off) and the SIGKILL
     recovery lanes (buddy replication on/off)."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--fleet"],
         capture_output=True, text=True, timeout=840, env=env, cwd=_ROOT)
@@ -350,7 +350,7 @@ def test_bench_tenants_emits_json_contract():
     rejected, and the noisy-neighbor isolation lane where the bulk
     tenant's slot cap actually throttles."""
     env = dict(os.environ)
-    env["HETU_TPU_BENCH_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, os.path.join(_ROOT, "bench.py"), "--tenants"],
         capture_output=True, text=True, timeout=600, env=env, cwd=_ROOT)
@@ -455,33 +455,30 @@ def test_calibration_anchor_follows_recorded_config(tmp_path):
     assert ms3 == 77.0 and cfg3 == _ANCHOR_CFG_FALLBACK
 
 
-def test_combo_probe_parses_mfu_sweep_result_line(tmp_path,
-                                                  monkeypatch):
-    """The combo probe parses mfu_sweep's RESULT line by index — pin the
-    format end to end with the REAL measure_one print shape (index 6 is
-    ms: token 0 is the RESULT tag; a drift here once pointed at the attn
-    string and float('auto') would have crashed the secured bench)."""
+def test_bench_refuses_without_tpu_or_explicit_cpu(monkeypatch):
+    """bench.py takes the device JAX gives its one process: with no TPU
+    and no JAX_PLATFORMS=cpu from the caller it exits non-zero — it
+    never falls back to a CPU smoke by itself. In-process on a fake
+    device: a child without the variable would take a real chip where
+    there is one and run the benchmark."""
     sys.path.insert(0, _ROOT)
-    import subprocess as sp
+    import types
 
     import bench
 
-    line = "RESULT 0.4100 48 selective 1 auto 310.5 158000 TPU v5 lite"
-    # the exact shape measure_one prints (workloads/mfu_sweep.py)
-    assert line.split()[6] == "310.5"
+    def gives(platform):
+        monkeypatch.setattr(bench.jax, "devices", lambda: [
+            types.SimpleNamespace(platform=platform)])
 
-    def fake_run(cmd, timeout, capture_output, text):
-        class R:
-            returncode = 0
-            stdout = "warmup noise\n" + line + "\n"
-            stderr = ""
-        return R()
-
-    monkeypatch.setattr(sp, "run", fake_run)
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    # secured: b32 at 367.86ms -> 89077 tok/s; fake combo: 158k tok/s
-    out = bench._combo_probe(0.36786, 32, 1024)
-    assert isinstance(out, tuple), out
-    dt_c, b, note = out
-    assert b == 48 and abs(dt_c - 0.3105) < 1e-9
-    assert "adopted" in note
+    gives("cpu")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit) as e:
+        bench.bench_device()
+    assert e.value.code not in (0, None)
+    with pytest.raises(SystemExit) as e:
+        bench.cpu_sim_device("--fleet")
+    assert e.value.code not in (0, None)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert bench.bench_device()[1] is False
+    gives("tpu")
+    assert bench.bench_device()[1] is True

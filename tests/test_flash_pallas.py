@@ -126,29 +126,6 @@ def test_tuned_entries_absent_on_cpu():
     assert fp._tuned_entries() == ()
 
 
-def test_mosaic_kernels_aot_compile_for_v5e():
-    """The REAL Mosaic lowerings of the flash-attention and fused-CE
-    kernels (not interpret mode) must compile for a v5e target — libtpu
-    is local, so a lowering regression is caught here instead of
-    mid-TPU-window (workloads/aot_check.py is the full matrix)."""
-    import pytest
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
-    except Exception as e:
-        pytest.skip(f"TPU AOT topology unavailable: {e}")
-
-    from workloads.aot_check import check_flash, check_fused_ce
-    devs = list(topo.devices)
-    assert "compile_s" in check_flash(devs, shape=(2, 512, 8, 64))
-    assert "compile_s" in check_flash(devs, shape=(2, 512, 8, 64),
-                                      kv_heads=2, seg=True)
-    # in-kernel dropout: SMEM seed + uint32 counter RNG must pass Mosaic
-    assert "compile_s" in check_flash(devs, shape=(2, 512, 8, 64),
-                                      dropout_rate=0.1)
-    assert "compile_s" in check_fused_ce(devs, n=1024, e=256, v=2048)
-
-
 def _drop_oracle_mask(key, b, h, sq, sk, rate):
     """Whole-matrix draw of the kernel's position-addressable counter
     RNG: one (sq, sk) 'block' at iq=ik=0 — equality with the kernel's
@@ -265,22 +242,3 @@ def test_flash_dropout_lse_and_determinism(rng):
     m = _dropout_keep(seed[0], 0, 0, 0, 0, rate=rate, block_q=256,
                       block_k=256, q_offset=0, kv_offset=0)
     assert abs(float(m.mean()) - (1 - rate)) < 0.02
-
-
-def test_mosaic_cp_dropout_train_step_compiles_for_v5e():
-    """A full train step with ring CP AND attention dropout must pass
-    the real Mosaic+GSPMD pipeline (the SMEM seed operand now rides
-    inside the ring's shard_map region — the exact class of surface
-    interpret-mode CPU tests can never validate)."""
-    try:
-        from jax.experimental import topologies
-        topo = topologies.get_topology_desc("v5e:2x4", "tpu")
-    except Exception as e:
-        pytest.skip(f"TPU AOT topology unavailable: {e}")
-
-    from workloads.aot_check import check_step
-    from hetu_tpu.parallel.strategy import Strategy
-    devs = list(topo.devices)
-    r = check_step(devs, Strategy(dp=4, cp=2), batch=8, seq=1024,
-                   cfgkw={"attn_pdrop": 0.1})
-    assert "compile_s" in r and "error" not in r, r

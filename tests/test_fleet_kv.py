@@ -398,6 +398,13 @@ def test_result_poll_backoff_widens_and_snaps_back():
     proxy = RemoteEngineProxy(port, poll_s=0.01, poll_max_s=0.05)
     try:
         r = proxy.submit([1, 2, 3], SamplingParams(max_tokens=2))
+        # the stub cannot stream: its ``drop`` answer arrives on the
+        # channel's reader thread and puts the request on the poll lane
+        # — only from then on does a RESULT poll answer PEND
+        deadline = time.monotonic() + 5
+        while not getattr(r, "_stream_denied", False) \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
         assert proxy._result_delay == pytest.approx(0.01)
         delays = []
         for _ in range(4):

@@ -373,11 +373,25 @@ def test_fleetmetrics_and_fleet_healthz_end_to_end(telem):
         router.register("s1", RemoteEngineProxy(p1, poll_s=0.02))
         telem.get_registry().counter("fedtest_total", "probe").inc(5)
         cli = CoordinatorClient(fport, timeout=5.0)
-        text = cli.fleet_metrics_text()
+        # the monitor may have scraped between the two register()
+        # calls: a round cached then names s0 alone until it is older
+        # than scrape_every_s (and a slow older round can land after a
+        # newer one) — wait for a round that saw both
+        def settled(fetch, seen):
+            deadline = time.monotonic() + 5.0
+            got = fetch()
+            while not seen(got) and time.monotonic() < deadline:
+                time.sleep(0.06)             # past scrape_every_s
+                got = fetch()
+            return got
+
+        text = settled(cli.fleet_metrics_text,
+                       lambda t: 'replica="s1"' in t)
         assert 'replica="s0"' in text and 'replica="s1"' in text
         assert f'replica="{FLEET_REPLICA}"' in text
         assert 'replica="_local"' in text
-        hz = cli.healthz()
+        hz = settled(cli.healthz,
+                     lambda h: "s1" in h["fleet"]["replicas"])
         fleet = hz["fleet"]
         assert set(fleet["replicas"]) == {"s0", "s1"}
         assert fleet["replicas_total"] == 2

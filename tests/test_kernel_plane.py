@@ -38,11 +38,16 @@ def gpt():
     return cfg, model, params
 
 
+def _pages(x):
+    """(n_blocks, bs, hkv, w) → the arena's stored (n_blocks, bs, hkv*w)."""
+    return x.reshape(x.shape[:2] + (-1,))
+
+
 def _arena(rng, *, S=3, R=1, hq=4, hkv=2, d=16, n_blocks=9, bs=4, W=8,
            dtype=jnp.float32):
     q = jnp.asarray(rng.normal(size=(S, R, hq, d)), dtype)
-    k = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv, d)), dtype)
-    v = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv, d)), dtype)
+    k = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv * d)), dtype)
+    v = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv * d)), dtype)
     tbl = np.zeros((S, W), np.int32)
     for s in range(S):
         tbl[s] = np.concatenate(
@@ -81,8 +86,9 @@ def test_paged_kernel_int8_arena_lane():
     rng = np.random.default_rng(1)
     q, k, v, tbl = _arena(rng, R=2)
     off = jnp.asarray([3, 0, 9], jnp.int32)
-    kq, ks = quantize_int8(k, axis=-1)
-    vq, vs = quantize_int8(v, axis=-1)
+    # per-(position, head) scales: quantize the heads view, store merged
+    kq, ks = map(_pages, quantize_int8(k.reshape(9, 4, 2, 16), axis=-1))
+    vq, vs = map(_pages, quantize_int8(v.reshape(9, 4, 2, 16), axis=-1))
     out = paged_attention_pallas(q, kq, vq, tbl, off,
                                  k_scale=ks, v_scale=vs)
     ref = paged_attention_reference(q, kq, vq, tbl, off,
@@ -161,7 +167,8 @@ def test_packed_flash_formulation_matches_per_token_gather():
         row = tbl[seg[t], pos[t] // bs] * bs + pos[t] % bs
         k_arena.reshape(-1, hkv, d)[row] = kp[0, t]
         v_arena.reshape(-1, hkv, d)[row] = vp[0, t]
-    k_arena, v_arena = jnp.asarray(k_arena), jnp.asarray(v_arena)
+    k_arena = _pages(jnp.asarray(k_arena))
+    v_arena = _pages(jnp.asarray(v_arena))
     tbl_tok = jnp.asarray(tbl[seg])
 
     intra, lse_i = attention_with_lse(
@@ -489,4 +496,5 @@ def test_tp2_paged_kernel_no_fallback_greedy_identical(gpt):
     assert kernel_fallbacks().get("serving_decode", 0) == fb_before
     assert eng.generate_many(prompts, sp) == want
     assert trace_counts().get("serving_step", 0) - before == 1
+    assert eng.step_executables() == 1      # one trace AND one compile
     assert want == [_ref(model, params, p, 6) for p in prompts]

@@ -34,10 +34,6 @@ from hetu_tpu.tools.galvatron import ModelDims, TPUTopology, search_uniform
 from hetu_tpu.tools.galvatron.cost_model import estimate
 
 
-JAX_PRE_06 = tuple(int(x) for x in jax.__version__.split(".")[:2]) \
-    < (0, 6)
-
-
 @pytest.fixture(autouse=True)
 def _clean_ledgers():
     ov.reset_comm_stats()
@@ -174,13 +170,6 @@ def test_chunked_overlap_model_composes_remat():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    JAX_PRE_06,
-    reason="MoE ep×tp composition aborts XLA's SPMD partitioner under "
-           "jax 0.4.37 (spmd_partitioner.cc IsManualSubgroup check — "
-           "the partial-manual shard_map + tp-auto gap, same family as "
-           "the ROADMAP pipeline PartitionId residual); pre-existing, "
-           "reproduces at seed with ep_overlap off")
 def test_chunked_overlap_model_composes_tp():
     """Chunked EP overlap composed with tp sharding: bitwise parity
     with the serialized EP path."""

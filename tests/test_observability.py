@@ -636,7 +636,10 @@ def test_serving_loop_watchdog_trips_on_stalled_step(telem, tmp_path):
     eng = ServingEngine(model, params, slots=2, max_len=32,
                         prefill_chunk=8, watchdog=True,
                         watchdog_factor=4.0,
-                        watchdog_min_timeout_s=0.15)
+                        # the floor sits well above any scheduling
+                        # stall of a loaded host (six xdist workers):
+                        # the healthy phase must not be able to trip
+                        watchdog_min_timeout_s=2.0)
     eng.watchdog.poll_s = 0.02
     eng.watchdog.dump_dir = str(tmp_path)   # keep dumps out of the cwd
     S = eng.pool.slots
@@ -645,7 +648,7 @@ def test_serving_loop_watchdog_trips_on_stalled_step(telem, tmp_path):
 
     def fake_fn(params, caches, ctl, pf, bt, cow, spec, wq, lora):
         if hang.is_set():
-            time.sleep(1.2)          # the stalled fake step
+            time.sleep(4.0)          # the stalled fake step (2x floor)
         # the 9-operand/7-result contract (ISSUE 17 sampled verify
         # lane + ISSUE 20 adapter arena): committed tokens (S, K+1) +
         # per-slot commit counts + prefill first tokens +

@@ -7,7 +7,6 @@ from hetu_tpu import optim
 # adafactor's factored second moment oscillates under jax 0.4.x numerics
 # (known runtime/tree version gap, ROADMAP "residual gaps under 0.4.37");
 # the test is meaningful only on the targeted jax >= 0.6 runtime.
-from hetu_tpu.core.compat import JAX_PRE_06
 
 
 def _quadratic_params():
@@ -148,10 +147,6 @@ def test_adafactor_factored_state_and_convergence():
     assert float(loss(params)) < 0.01 * l0, float(loss(params))
 
 
-@pytest.mark.skipif(
-    JAX_PRE_06,
-    reason="adafactor loss oscillates under jax<0.6 numerics (ROADMAP "
-           "known residual gap on the 0.4.37 container runtime)")
 def test_adafactor_trains_gpt_tiny():
     """End-to-end: the memory-efficient optimizer drives the normal
     train-step machinery (sharded state incl. factored moments)."""

@@ -76,7 +76,7 @@ def test_block_manager_refcounts_and_ledger(gpt):
     with pytest.raises(ValueError):
         mgr.share(0)                          # null block is pinned
 
-    # the paged arena: (L, n_blocks, block_size, hkv, d), null included
+    # the paged arena: (L, n_blocks, block_size, hkv*d), null included
     pool = KVPool(model, slots=2, max_len=MAX_LEN, block_size=BLOCK)
     W = MAX_LEN // BLOCK
     assert pool.blocks_per_slot == W

@@ -68,6 +68,10 @@ def test_engine_zero_retraces_across_churn(gpt):
     """ACCEPTANCE: >= 8 admits/evictions churn one compiled step — the
     re-trace counter equals the initial compile count (exactly 1)."""
     cfg, model, params = gpt
+    # committed params, as a checkpoint load or a device_put leaves
+    # them: beside an arena that was born uncommitted they compiled the
+    # step three times under its one trace
+    params = jax.device_put(params, jax.devices()[0])
     eng = ServingEngine(model, params, slots=2, max_len=MAX_LEN,
                         prefill_chunk=CHUNK)
     before = trace_counts().get("serving_step", 0)
@@ -78,6 +82,7 @@ def test_engine_zero_retraces_across_churn(gpt):
     assert after - before == 1, (
         f"request churn re-traced the fused step "
         f"({after - before} traces for 10 admits/evictions)")
+    assert eng.step_executables() == 1, "one trace, several compiles"
     # second engine over the SAME model/shapes: jit cache hit, still no
     # new trace even across engine objects
     eng2 = ServingEngine(model, params, slots=2, max_len=MAX_LEN,

@@ -141,23 +141,27 @@ def test_precompile_handles_bad_candidate():
 
 
 def test_persistent_cache_wiring(tmp_path, monkeypatch):
-    """enable_persistent_compilation_cache points jax's on-disk XLA cache
-    at the given dir (restart-warm compiles); unset env + no arg = no-op."""
+    """enable_persistent_compilation_cache: with JAX_COMPILATION_CACHE_DIR
+    set nothing is set in code; without it the cache goes to the given
+    dir, by default the one fixed directory inside the checkout."""
     import os
     from hetu_tpu.engine import enable_persistent_compilation_cache
-    monkeypatch.delenv("HETU_COMPILE_CACHE_DIR", raising=False)
+    from hetu_tpu.engine.precompile import COMPILE_CACHE_DIR
     old = jax.config.jax_compilation_cache_dir
     try:
-        assert enable_persistent_compilation_cache(None) is None
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "env"))
+        assert enable_persistent_compilation_cache(str(tmp_path / "xc")) \
+            == str(tmp_path / "env")
+        assert jax.config.jax_compilation_cache_dir == old
+        assert not os.path.exists(tmp_path / "xc")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         path = enable_persistent_compilation_cache(str(tmp_path / "xc"))
         assert path == str(tmp_path / "xc")
         assert jax.config.jax_compilation_cache_dir == path
         assert os.path.isdir(path)
-        # env-var driven activation (the restart-warm flow)
-        monkeypatch.setenv("HETU_COMPILE_CACHE_DIR",
-                           str(tmp_path / "env"))
-        assert enable_persistent_compilation_cache(None) \
-            == str(tmp_path / "env")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
 

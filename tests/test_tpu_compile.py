@@ -1,0 +1,156 @@
+"""Compile-for-TPU tests: the REAL Mosaic lowerings (never interpret
+mode) of the main path's kernels at GPT-2-small widths, compiled for a
+described v5e from this CPU-only process. Nothing runs on a device; what
+the chip's compiler would refuse is refused here.
+
+The topology is described inside a fixture (one worker loads the TPU
+library, and only once a test of this file has started), every compile
+happens in the test's own process, and the persistent compilation cache
+is off around them (a TPU executable written here cannot be read back
+without a chip). ``workloads/aot_check.py`` holds the check functions
+and the full matrix.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+def _describe(name):
+    """Only ever called from a fixture: describing a topology loads the
+    TPU library, which one process at a time may hold."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name=name)
+    except Exception as e:
+        pytest.skip(f"no {name} topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    return _describe("v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def topo8():
+    return _describe("v5e:2x4")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kw", [
+    dict(),
+    dict(seg=True),
+    dict(dropout_rate=0.1),
+    dict(shape=(2, 512, 8, 64), kv_heads=2, seg=True),
+], ids=["plain", "segment_ids", "dropout", "gqa_segment_ids"])
+def test_flash_fwd_bwd_compiles_for_v5e(one_chip, kw):
+    """Flash forward + both backward kernels at (4, 1024, 12, 64)."""
+    from workloads.aot_check import check_flash
+    assert "compile_s" in check_flash(list(one_chip.device_set), **kw)
+
+
+def test_fused_ce_compiles_for_v5e(one_chip):
+    """Fused CE forward + backward at 4096 x 768 x 50257."""
+    from workloads.aot_check import check_fused_ce
+    assert "compile_s" in check_fused_ce(list(one_chip.device_set))
+
+
+@pytest.mark.parametrize("return_lse", [False, True], ids=["out", "lse"])
+@pytest.mark.parametrize("rows", [1, 5], ids=["decode", "verify5"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_paged_decode_compiles_for_v5e(one_chip, dtype, rows, return_lse):
+    """The paged decode kernel over the GPT-2-small arena: refused at
+    every layout before PR 21 (a one-head page block slices the minor
+    dims below the (8, 128) tile)."""
+    from workloads.aot_check import check_paged
+    assert "compile_s" in check_paged(list(one_chip.device_set), dtype=dtype,
+                                      rows=rows, return_lse=return_lse)
+
+
+def test_paged_decode_gqa_d128_compiles_for_v5e(one_chip):
+    from workloads.aot_check import check_paged
+    assert "compile_s" in check_paged(list(one_chip.device_set), heads=32,
+                                      kv_heads=8, head_dim=128)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_packed_prefill_lane_compiles_for_v5e(one_chip, dtype):
+    """The packed-prefill flash lane at chip_smoke.py's prefill_chunk."""
+    from workloads.aot_check import check_packed_prefill
+    assert "compile_s" in check_packed_prefill(list(one_chip.device_set),
+                                               chunk=256, dtype=dtype)
+
+
+def test_mosaic_cp_dropout_train_step_compiles_for_v5e(topo8):
+    """A full train step with ring CP AND attention dropout must pass
+    the real Mosaic+GSPMD pipeline (the SMEM seed operand rides inside
+    the ring's shard_map region — the exact class of surface
+    interpret-mode CPU tests can never validate)."""
+    from workloads.aot_check import check_step
+    from hetu_tpu.parallel.strategy import Strategy
+    r = check_step(list(topo8.devices), Strategy(dp=4, cp=2), batch=8,
+                   seq=1024, cfgkw={"attn_pdrop": 0.1})
+    assert "compile_s" in r and "error" not in r, r
+
+
+def test_pp_memory_aot_analysis_on_tpu_target(topo8):
+    """AOT topology compilation (workloads/pp_memory.py): the dp2xpp4
+    train step compiles for a REAL v5e-8 target from this host and XLA's
+    memory analysis shows remat reducing temp bytes — the compiler's
+    answer to 'does the pipeline's flush residency fit real HBM'."""
+    import json
+    import os
+
+    from workloads.pp_memory import analyze
+    from hetu_tpu.core.dtypes import Policy
+    from hetu_tpu.models import GPTConfig
+    from hetu_tpu.parallel.strategy import Strategy
+
+    devs = list(topo8.devices)
+    cfg = GPTConfig(vocab_size=512, max_positions=128, hidden_size=128,
+                    num_layers=4, num_heads=4)
+    pol = Policy(param_dtype=jnp.float32, compute_dtype=jnp.bfloat16)
+    rows = {}
+    for remat in ("none", "full"):
+        rows[remat] = analyze(
+            cfg, Strategy(dp=2, pp=4, remat=remat, num_microbatches=4),
+            devs, batch=8, seq=128, policy=pol)
+    for r in rows.values():
+        assert "error" not in r, r
+        # temp can legitimately be 0 at this toy scale (XLA fuses the
+        # few bf16 activations into scratch); args always exist
+        assert r["arg_bytes"] > 0 and r["temp_bytes"] >= 0
+        assert r["peak_bytes_est"] > 0
+    # the remat-saves-memory ordering only emerges at scale (a toy model
+    # has ~no activations to save, and remat's recompute adds temps) —
+    # assert it on the committed real-scale artifact instead
+    art = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "workloads", "out",
+        "pp_memory_L12_h768.json")
+    with open(art) as f:
+        real = {(r["name"], r["remat"]): r for r in json.load(f)["rows"]}
+    scan = "dp2 x pp4 scan"
+    assert real[(scan, "full")]["temp_bytes"] \
+        < real[(scan, "selective")]["temp_bytes"] \
+        < real[(scan, "none")]["temp_bytes"]
+    assert not real[(scan, "none")]["fits_hbm"]
+    assert real[(scan, "selective")]["fits_hbm"]

@@ -16,9 +16,9 @@ import sys
 
 sys.path.insert(0, os.environ["HETU_REPO"])
 
-import jax
+os.environ["JAX_PLATFORMS"] = "cpu"   # CPU workers by design
 
-jax.config.update("jax_platforms", "cpu")
+import jax
 
 import numpy as np
 

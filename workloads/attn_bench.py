@@ -111,7 +111,7 @@ def main():
         hq, hkv, d = 16, 16, 64
         q, k, v = _rand_qkv(key, b, s, hq, hkv, d)
 
-        # scan-looped inside one jit: per-call dispatch over the relay
+        # scan-looped inside one jit: per-call host dispatch
         # costs ~ms of host time and would swamp sub-ms kernels
         pallas_fwd = scan_loop(lambda q, k, v: flash_attention_pallas(
             q, k, v, causal=True, interpret=False), N_ITERS)

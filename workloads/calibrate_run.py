@@ -25,8 +25,6 @@ from hetu_tpu.tools.galvatron.calibrate import (
     predicted_times, validate_ranking,
 )
 
-PEAK_V5E = 197e12
-
 
 def main():
     dev = jax.devices()[0]
@@ -38,15 +36,12 @@ def main():
     opt = optim.adamw(1e-4)
     B, S = 8, 1024
     dims = ModelDims.from_config(cfg, seq_len=S, global_batch=B)
-    # hardware-true constants: peak from the actual device kind (the
-    # calibration file must not bake v5e specs onto a v5p slice), HBM
-    # from the allocator's own limit when it reports one
-    from bench import peak_flops
-    peak = peak_flops(dev) or PEAK_V5E
-    try:
-        hbm = float((dev.memory_stats() or {}).get("bytes_limit", 16e9))
-    except Exception:
-        hbm = 16e9
+    # hardware-true constants: peak by the device's kind, HBM from the
+    # allocator's own limit (an unknown kind raises — the calibration
+    # file must not bake one chip's specs onto another)
+    from hetu_tpu.tools.galvatron.cost_model import device_spec
+    spec = device_spec(dev)
+    peak, hbm = spec["peak_flops"], spec["hbm_bytes"]
     topo = TPUTopology(num_devices=1, peak_flops=peak, hbm_bytes=hbm)
 
     print(f"== device {getattr(dev, 'device_kind', '?')}: peak "

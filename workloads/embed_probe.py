@@ -3,7 +3,7 @@
 The embedding backward is a scatter-add of N token-rows into the (V, E)
 table; XLA:TPU's scatter lowering is the wildcard — if it serializes,
 the one-hot matmul formulation (2·N·V·E extra FLOPs but pure MXU) wins.
-Measures, scan-looped (relay-safe), at the bench shape:
+Measures, scan-looped inside one jit, at the bench shape:
 
 - ``scatter``: plain ``jnp.take`` (XLA's native take-VJP backward),
 - ``onehot``: ``ops.embedding.embedding_lookup(bwd="onehot")`` — gather

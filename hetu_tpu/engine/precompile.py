@@ -33,6 +33,7 @@ import dataclasses
 import os
 import threading
 import time
+import weakref
 from typing import Iterable, Optional, Sequence
 
 import jax
@@ -87,6 +88,7 @@ def _precompile_one(model, opt, strategy: Strategy, *, devices, attn_impl,
                     batch_shape, batch_keys,
                     cache: StepCache, bucket: int = 0) -> PrecompileResult:
     from hetu_tpu import telemetry
+    from hetu_tpu.telemetry import device_scopes
     t0 = time.perf_counter()
     # EVERY key-bearing field must be forwarded here (the shape-plane
     # lint asserts it): a field the enumeration drops would silently
@@ -124,6 +126,10 @@ def _precompile_one(model, opt, strategy: Strategy, *, devices, attn_impl,
                                               batch_sds).compile()
                 entry.aot[bkey] = exe
                 did_aot = True
+                # readable device scopes: the executable's text is
+                # fetched only if someone asks for the map
+                device_scopes.register_step(
+                    "train_step", _hlo_text_of(exe), key=(key, bkey))
         if telemetry.enabled():
             sp.set(cached=existed, aot=did_aot)
             if not existed or did_aot:   # count real work, not no-ops
@@ -133,6 +139,18 @@ def _precompile_one(model, opt, strategy: Strategy, *, devices, attn_impl,
     return PrecompileResult(strategy, ok=True,
                             seconds=time.perf_counter() - t0,
                             aot=did_aot, cached=existed, bucket=bucket)
+
+
+def _hlo_text_of(exe):
+    """A thunk for ``device_scopes.register_step`` that does not pin the
+    executable: the text of ``exe`` while it lives, else ``None``."""
+    ref = weakref.ref(exe)
+
+    def text():
+        live = ref()
+        return live.as_text() if live is not None else None
+
+    return text
 
 
 class _nullctx:

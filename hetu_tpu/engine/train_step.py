@@ -616,7 +616,8 @@ def build_local_grad_fn(base_loss, mesh: Mesh, ndp: int, *,
                     k = jax.random.fold_in(key_arg[0], gid[0])
                 with no_act_sharding(), \
                         ManualAxes(mesh, manual, ep_overlap=ep_overlap,
-                                   ep_chunks=ep_chunks):
+                                   ep_chunks=ep_chunks), \
+                        jax.named_scope("hetu.loss"):
                     if k is not None:
                         return base_loss(p, batch_l, dropout_key=k)
                     return base_loss(p, batch_l)
@@ -744,7 +745,10 @@ def build_train_step(model: Module, opt: Transform, plan: TrainPlan, *,
                 "or dropout silently stays off", stacklevel=2)
 
     def compute_loss(params, batch, dropout_key=None):
-        with plan.act:
+        # device scope (telemetry/device_scopes.py): forward, backward
+        # and recomputation of the loss are told apart by the wrappers
+        # autodiff puts around this name
+        with plan.act, jax.named_scope("hetu.loss"):
             if thread_dropout:
                 return base_loss(params, batch, dropout_key=dropout_key)
             return base_loss(params, batch)
@@ -849,9 +853,11 @@ def build_train_step(model: Module, opt: Transform, plan: TrainPlan, *,
         else:
             loss, grads = grad_fn(state.params, batch, key)
 
-        gnorm = global_norm(grads)
-        updates, new_opt = opt.update(grads, state.opt_state, state.params)
-        new_params = apply_updates(state.params, updates)
+        with jax.named_scope("hetu.opt"):
+            gnorm = global_norm(grads)
+            updates, new_opt = opt.update(grads, state.opt_state,
+                                          state.params)
+            new_params = apply_updates(state.params, updates)
         metrics = {"loss": loss, "grad_norm": gnorm}
         return TrainState(state.step + 1, new_params, new_opt), metrics
 
@@ -976,7 +982,7 @@ def build_grad_accum_steps(model: Module, opt: Transform, plan: TrainPlan,
     base_loss = loss_fn or default_loss_fn(model, strategy, attn_impl)
 
     def compute_loss(params, batch, key):
-        with plan.act:
+        with plan.act, jax.named_scope("hetu.loss"):
             if key is not None:
                 return base_loss(params, batch, dropout_key=key)
             return base_loss(params, batch)
@@ -1079,9 +1085,11 @@ def build_grad_accum_steps(model: Module, opt: Transform, plan: TrainPlan,
                 lambda g: jnp.sum(g, axis=0) / (ngroups * n_accum), acc)
         else:
             grads = jax.tree.map(lambda g: g / n_accum, acc)
-        gnorm = global_norm(grads)
-        updates, new_opt = opt.update(grads, state.opt_state, state.params)
-        new_params = apply_updates(state.params, updates)
+        with jax.named_scope("hetu.opt"):
+            gnorm = global_norm(grads)
+            updates, new_opt = opt.update(grads, state.opt_state,
+                                          state.params)
+            new_params = apply_updates(state.params, updates)
         return (TrainState(state.step + 1, new_params, new_opt),
                 {"grad_norm": gnorm})
 

@@ -571,117 +571,131 @@ class Trainer:
         failed: Optional[str] = None   # exception name when train() dies
         try:
             for _ in range(steps):
-                t_iter = time.perf_counter()
-                try:
-                    sbatch = next(it)
-                except StopIteration:
-                    break
-                t_fetch = time.perf_counter()
-                # waiting on the data path is a stall (the prefetcher
-                # additionally emits a "stall" span + counter itself)
-                acct.record("stall", t_fetch - t_iter)
-                width = int(sbatch["input_ids"].shape[-1]) \
-                    if "input_ids" in sbatch else None
-                if width is not None and width not in fpt_by_width:
-                    fpt_by_width[width] = self._flops_per_token(width)
-                n_traces = trace_total()
-                self.state, metrics = self._step_entry_for(sbatch)(
-                    self.state, sbatch)
-                host_step += 1
-                acct.add_step()
-                # step boundary into the black box; one beat per
-                # completed step feeds the watchdog's rolling median
-                self.flight.record("step", step=host_step)
-                if watchdog is not None:
-                    watchdog.beat()
-                ntok = int(sbatch["input_ids"].size)
-                tokens_since += ntok
-                tokens_total += ntok
-                acct.add_tokens(ntok)
-                fpt = fpt_by_width.get(width) if width is not None \
-                    else None
-                if fpt:
-                    flops_sum += fpt * ntok
-                    acct.flops_per_token = flops_sum / tokens_total
-                if self.config.log_every and \
-                        host_step % self.config.log_every == 0:
-                    loss = float(jax.device_get(metrics["loss"]))
-                    grad_norm = float(
-                        jax.device_get(metrics["grad_norm"]))
-                    now = time.perf_counter()
-                    rec = self.metrics.log(
-                        host_step, loss=loss,
-                        grad_norm=grad_norm,
-                        tokens_per_sec=round(
-                            tokens_since / (now - t_last), 1),
-                        tokens_total=tokens_total)
-                    history.append(rec)
-                    if self.slo is not None:
-                        # one observation per log interval, then run
-                        # every detector (burn rates + regressions).
-                        # Known blocking work (eval, checkpoint drain)
-                        # is subtracted — it is accounted overhead, not
-                        # a step-time regression
-                        self.slo.observe("loss", loss)
-                        self.slo.observe("grad_norm", grad_norm)
-                        self.slo.observe(
-                            "step_time_s",
-                            max(now - t_last - slo_blocked_s, 0.0)
-                            / self.config.log_every)
-                        slo_blocked_s = 0.0
-                        for a in self.slo.evaluate():
-                            get_logger().warning(f"SLO alert: "
-                                                 f"{a.message}")
-                            self.metrics.write_record(a.to_record())
-                    t_last, tokens_since = now, 0
-                    if tel:
-                        # sample the mem_*/comm_* registry series into
-                        # Perfetto counter tracks on the log cadence
-                        self.tracer.record_counters(
-                            self.registry.snapshot())
-                # step dispatch + the log boundary's blocking fetch: the
-                # productive slice of this iteration — UNLESS the step
-                # body re-traced, in which case the wall went to
-                # trace+XLA-compile (a cold/cache-disabled first step)
-                # and belongs in the compile ledger, not compute
-                step_s = time.perf_counter() - t_fetch
-                if trace_total() > n_traces:
-                    acct.record("compile", step_s)
-                    if tel:
-                        self.tracer.complete("compile", step_s,
-                                             where="step_trace")
-                else:
-                    acct.record("compute", step_s)
-                if self.config.eval_every and eval_batches is not None \
-                        and host_step % self.config.eval_every == 0:
-                    # eval/checkpoint are legitimately long blocking
-                    # operations, not hangs: suspend trip checks so a
-                    # slow eval pass or writer drain never produces a
-                    # false "the run HUNG" flight dump
+                # one span per phase (docs/OBSERVABILITY.md): in a
+                # jax.profiler trace hetu:train/step and its children
+                # sit above the ops the step dispatched
+                with telemetry.span("train/step",
+                                    step=host_step + 1) as step_span:
+                    t_iter = time.perf_counter()
+                    try:
+                        # what the loop waited for its batch (the
+                        # goodput ledger's "stall"), not what the
+                        # loader took
+                        with telemetry.span("train/next_batch"):
+                            sbatch = next(it)
+                    except StopIteration:
+                        step_span.set(exhausted=True)   # not a step
+                        break
+                    t_fetch = time.perf_counter()
+                    # waiting on the data path is a stall (the prefetcher
+                    # additionally emits a "stall" span + counter itself)
+                    acct.record("stall", t_fetch - t_iter)
+                    width = int(sbatch["input_ids"].shape[-1]) \
+                        if "input_ids" in sbatch else None
+                    if width is not None and width not in fpt_by_width:
+                        fpt_by_width[width] = self._flops_per_token(width)
+                    n_traces = trace_total()
+                    with telemetry.span("train/dispatch"):
+                        self.state, metrics = \
+                            self._step_entry_for(sbatch)(self.state,
+                                                         sbatch)
+                    host_step += 1
+                    acct.add_step()
+                    # step boundary into the black box; one beat per
+                    # completed step feeds the watchdog's rolling median
+                    self.flight.record("step", step=host_step)
                     if watchdog is not None:
-                        watchdog.pause()
-                    t0 = time.perf_counter()
-                    with telemetry.span("eval", step=host_step):
-                        ev = self.evaluate(eval_batches())
-                    ev_s = time.perf_counter() - t0
-                    acct.record("eval", ev_s)
-                    slo_blocked_s += ev_s
-                    history.append(self.metrics.log(host_step,
-                                                    eval_loss=ev))
-                    if watchdog is not None:
-                        watchdog.resume()
-                if self.config.aggregate_every and telemetry.enabled() \
-                        and host_step % self.config.aggregate_every == 0:
-                    self._aggregate_cluster(host_step)
-                if self.config.ckpt_every and self.config.ckpt_dir and \
-                        host_step % self.config.ckpt_every == 0:
-                    if watchdog is not None:
-                        watchdog.pause()
-                    t0 = time.perf_counter()
-                    self.save()   # notes "checkpoint" in the ledger
-                    slo_blocked_s += time.perf_counter() - t0
-                    if watchdog is not None:
-                        watchdog.resume()
+                        watchdog.beat()
+                    ntok = int(sbatch["input_ids"].size)
+                    tokens_since += ntok
+                    tokens_total += ntok
+                    acct.add_tokens(ntok)
+                    fpt = fpt_by_width.get(width) if width is not None \
+                        else None
+                    if fpt:
+                        flops_sum += fpt * ntok
+                        acct.flops_per_token = flops_sum / tokens_total
+                    if self.config.log_every and \
+                            host_step % self.config.log_every == 0:
+                        with telemetry.span("train/loss_fetch"):
+                            loss = float(
+                                jax.device_get(metrics["loss"]))
+                            grad_norm = float(
+                                jax.device_get(metrics["grad_norm"]))
+                        now = time.perf_counter()
+                        rec = self.metrics.log(
+                            host_step, loss=loss,
+                            grad_norm=grad_norm,
+                            tokens_per_sec=round(
+                                tokens_since / (now - t_last), 1),
+                            tokens_total=tokens_total)
+                        history.append(rec)
+                        if self.slo is not None:
+                            # one observation per log interval, then run
+                            # every detector (burn rates + regressions).
+                            # Known blocking work (eval, checkpoint drain)
+                            # is subtracted — it is accounted overhead, not
+                            # a step-time regression
+                            self.slo.observe("loss", loss)
+                            self.slo.observe("grad_norm", grad_norm)
+                            self.slo.observe(
+                                "step_time_s",
+                                max(now - t_last - slo_blocked_s, 0.0)
+                                / self.config.log_every)
+                            slo_blocked_s = 0.0
+                            for a in self.slo.evaluate():
+                                get_logger().warning(f"SLO alert: "
+                                                     f"{a.message}")
+                                self.metrics.write_record(a.to_record())
+                        t_last, tokens_since = now, 0
+                        if tel:
+                            # sample the mem_*/comm_* registry series into
+                            # Perfetto counter tracks on the log cadence
+                            self.tracer.record_counters(
+                                self.registry.snapshot())
+                    # step dispatch + the log boundary's blocking fetch: the
+                    # productive slice of this iteration — UNLESS the step
+                    # body re-traced, in which case the wall went to
+                    # trace+XLA-compile (a cold/cache-disabled first step)
+                    # and belongs in the compile ledger, not compute
+                    step_s = time.perf_counter() - t_fetch
+                    if trace_total() > n_traces:
+                        acct.record("compile", step_s)
+                        if tel:
+                            self.tracer.complete("compile", step_s,
+                                                 where="step_trace")
+                    else:
+                        acct.record("compute", step_s)
+                    if self.config.eval_every and eval_batches is not None \
+                            and host_step % self.config.eval_every == 0:
+                        # eval/checkpoint are legitimately long blocking
+                        # operations, not hangs: suspend trip checks so a
+                        # slow eval pass or writer drain never produces a
+                        # false "the run HUNG" flight dump
+                        if watchdog is not None:
+                            watchdog.pause()
+                        t0 = time.perf_counter()
+                        with telemetry.span("eval", step=host_step):
+                            ev = self.evaluate(eval_batches())
+                        ev_s = time.perf_counter() - t0
+                        acct.record("eval", ev_s)
+                        slo_blocked_s += ev_s
+                        history.append(self.metrics.log(host_step,
+                                                        eval_loss=ev))
+                        if watchdog is not None:
+                            watchdog.resume()
+                    if self.config.aggregate_every and telemetry.enabled() \
+                            and host_step % self.config.aggregate_every == 0:
+                        self._aggregate_cluster(host_step)
+                    if self.config.ckpt_every and self.config.ckpt_dir and \
+                            host_step % self.config.ckpt_every == 0:
+                        if watchdog is not None:
+                            watchdog.pause()
+                        t0 = time.perf_counter()
+                        self.save()   # notes "checkpoint" in the ledger
+                        slo_blocked_s += time.perf_counter() - t0
+                        if watchdog is not None:
+                            watchdog.resume()
             if self.config.ckpt_dir:
                 if watchdog is not None:
                     watchdog.pause()

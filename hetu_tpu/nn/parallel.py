@@ -651,14 +651,16 @@ class ParallelAttention(Module):
             from hetu_tpu.ops.quantization import (dequantize_int8,
                                                    quantize_int8)
             kq_b, ks_b, vq_b, vs_b = kv_cache
-            knew_q, knew_s = quantize_int8(k, axis=-1)
-            vnew_q, vnew_s = quantize_int8(v, axis=-1)
-            kq_b, ks_b = upd(kq_b, knew_q), upd(ks_b, knew_s)
-            vq_b, vs_b = upd(vq_b, vnew_q), upd(vs_b, vnew_s)
+            with jax.named_scope("hetu.kv_arena"):
+                knew_q, knew_s = quantize_int8(k, axis=-1)
+                vnew_q, vnew_s = quantize_int8(v, axis=-1)
+                kq_b, ks_b = upd(kq_b, knew_q), upd(ks_b, knew_s)
+                vq_b, vs_b = upd(vq_b, vnew_q), upd(vs_b, vnew_s)
             new_cache = (kq_b, ks_b, vq_b, vs_b)
         else:
             k_buf, v_buf = kv_cache
-            k_buf, v_buf = upd(k_buf, k), upd(v_buf, v)
+            with jax.named_scope("hetu.kv_arena"):
+                k_buf, v_buf = upd(k_buf, k), upd(v_buf, v)
             new_cache = (k_buf, v_buf)
 
         if paged and attn_kernel == "paged" and self.causal:
@@ -771,10 +773,11 @@ class ParallelAttention(Module):
             from hetu_tpu.ops.quantization import (dequantize_int8,
                                                    quantize_int8)
             kq_b, ks_b, vq_b, vs_b = kv_cache
-            knew_q, knew_s = quantize_int8(k, axis=-1)
-            vnew_q, vnew_s = quantize_int8(v, axis=-1)
-            kq_b, ks_b = upd(kq_b, knew_q), upd(ks_b, knew_s)
-            vq_b, vs_b = upd(vq_b, vnew_q), upd(vs_b, vnew_s)
+            with jax.named_scope("hetu.kv_arena"):
+                knew_q, knew_s = quantize_int8(k, axis=-1)
+                vnew_q, vnew_s = quantize_int8(v, axis=-1)
+                kq_b, ks_b = upd(kq_b, knew_q), upd(ks_b, knew_s)
+                vq_b, vs_b = upd(vq_b, vnew_q), upd(vs_b, vnew_s)
             new_cache = (kq_b, ks_b, vq_b, vs_b)
             # the reference per-token lane attends the arena's
             # ROUND-TRIPPED int8 values for in-pack rows — match it
@@ -782,7 +785,8 @@ class ParallelAttention(Module):
             v = dequantize_int8(vnew_q, vnew_s, q.dtype)
         else:
             k_b, v_b = kv_cache
-            k_b, v_b = upd(k_b, k), upd(v_b, v)
+            with jax.named_scope("hetu.kv_arena"):
+                k_b, v_b = upd(k_b, k), upd(v_b, v)
             new_cache = (k_b, v_b)
 
         from hetu_tpu.ops.attention import attention_with_lse

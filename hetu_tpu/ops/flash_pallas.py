@@ -308,25 +308,27 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, scale,
         pl.BlockSpec((1, 1, block_q, NUM_LANES),
                      lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
     ]
-    out, lse_l = pl.pallas_call(
-        functools.partial(kernel, causal=causal, block_q=block_q,
-                          block_k=block_k, kv_blocks=kv_blocks,
-                          q_offset=q_offset, kv_offset=kv_offset,
-                          dropout_rate=dropout_rate),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
-            pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("hetu.flash_fwd"):
+        out, lse_l = pl.pallas_call(
+            functools.partial(kernel, causal=causal, block_q=block_q,
+                              block_k=block_k, kv_blocks=kv_blocks,
+                              q_offset=q_offset, kv_offset=kv_offset,
+                              dropout_rate=dropout_rate),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=interpret,
+            name="hetu_flash_fwd",
+        )(*args)
     return out, lse_l[..., 0]
 
 
@@ -557,22 +559,24 @@ def _flash_bwd(q, k, v, q_seg, kv_seg, out, lse, do, *, causal, scale,
     ] + seg_specs_dq + seed_specs
     dq_kernel = functools.partial(_opt_refs_wrapper, _bwd_dq_kernel, 6,
                                   has_seg, has_drop)
-    dq = pl.pallas_call(
-        functools.partial(dq_kernel, causal=causal, block_q=block_q,
-                          block_k=block_k, kv_blocks=sk // block_k,
-                          q_offset=q_offset, kv_offset=kv_offset,
-                          dropout_rate=dropout_rate),
-        grid=(b, hq, sq // block_q, sk // block_k),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(*args, *seg_args, *seed_args)
+    with jax.named_scope("hetu.flash_bwd"):
+        dq = pl.pallas_call(
+            functools.partial(dq_kernel, causal=causal, block_q=block_q,
+                              block_k=block_k, kv_blocks=sk // block_k,
+                              q_offset=q_offset, kv_offset=kv_offset,
+                              dropout_rate=dropout_rate),
+            grid=(b, hq, sq // block_q, sk // block_k),
+            in_specs=dq_specs,
+            out_specs=pl.BlockSpec((1, 1, block_q, d),
+                                   lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=interpret,
+            name="hetu_flash_bwd_dq",
+        )(*args, *seg_args, *seed_args)
     dq = (dq * scale).astype(q.dtype)  # undo the q-scale folding
 
     # ---- dK/dV: grid (b, hq, kv_blocks, q_blocks), accumulate over q ----
@@ -594,23 +598,25 @@ def _flash_bwd(q, k, v, q_seg, kv_seg, out, lse, do, *, causal, scale,
                                    has_seg, has_drop)
     kv_out_spec = pl.BlockSpec((1, 1, block_k, d),
                                lambda ib, ih, ik, iq: (ib, ih, ik, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(dkv_kernel, causal=causal, block_q=block_q,
-                          block_k=block_k, q_blocks=sq // block_q,
-                          q_offset=q_offset, kv_offset=kv_offset,
-                          dropout_rate=dropout_rate),
-        grid=(b, hq, sk // block_k, sq // block_q),
-        in_specs=dkv_specs,
-        out_specs=[kv_out_spec, kv_out_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32),
-                   jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(*args, *seg_args, *seed_args)
+    with jax.named_scope("hetu.flash_bwd"):
+        dk, dv = pl.pallas_call(
+            functools.partial(dkv_kernel, causal=causal, block_q=block_q,
+                              block_k=block_k, q_blocks=sq // block_q,
+                              q_offset=q_offset, kv_offset=kv_offset,
+                              dropout_rate=dropout_rate),
+            grid=(b, hq, sk // block_k, sq // block_q),
+            in_specs=dkv_specs,
+            out_specs=[kv_out_spec, kv_out_spec],
+            out_shape=[jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32),
+                       jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=interpret,
+            name="hetu_flash_bwd_dkv",
+        )(*args, *seg_args, *seed_args)
     if rep > 1:
         dk = dk.reshape(b, hkv, rep, sk, d).sum(axis=2)
         dv = dv.reshape(b, hkv, rep, sk, d).sum(axis=2)

@@ -184,30 +184,32 @@ def _fused_fwd_impl(h, w, labels, block_n, block_v, interpret):
     lab_l = _expand_lanes(labels.astype(jnp.int32))
 
     grid = (n_blocks, v_blocks)
-    tgt_l, lse_l = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_n=block_n, block_v=block_v,
-                          v_blocks=v_blocks, vocab=vocab),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, e), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_n, NUM_LANES), lambda i, j: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, NUM_LANES), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, NUM_LANES), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, NUM_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((n, NUM_LANES), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_n, NUM_LANES), jnp.float32),
-                        pltpu.VMEM((block_n, NUM_LANES), jnp.float32),
-                        pltpu.VMEM((block_n, NUM_LANES), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(h, wp, lab_l)
+    with jax.named_scope("hetu.fused_ce"):
+        tgt_l, lse_l = pl.pallas_call(
+            functools.partial(_fwd_kernel, block_n=block_n, block_v=block_v,
+                              v_blocks=v_blocks, vocab=vocab),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
+                pl.BlockSpec((block_v, e), lambda i, j: (j, 0)),
+                pl.BlockSpec((block_n, NUM_LANES), lambda i, j: (i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_n, NUM_LANES), lambda i, j: (i, 0)),
+                pl.BlockSpec((block_n, NUM_LANES), lambda i, j: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n, NUM_LANES), jnp.float32),
+                jax.ShapeDtypeStruct((n, NUM_LANES), jnp.float32),
+            ],
+            scratch_shapes=[pltpu.VMEM((block_n, NUM_LANES), jnp.float32),
+                            pltpu.VMEM((block_n, NUM_LANES), jnp.float32),
+                            pltpu.VMEM((block_n, NUM_LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name="hetu_fused_ce_fwd",
+        )(h, wp, lab_l)
     return lse_l[:, 0], tgt_l[:, 0]
 
 
@@ -231,40 +233,44 @@ def _fused_core_bwd(block_n, block_v, interpret, res, cots):
     gtgt_l = _expand_lanes(gtgt.astype(jnp.float32))
     lane_spec = pl.BlockSpec((block_n, NUM_LANES), lambda i, j: (i, 0))
 
-    dh = pl.pallas_call(
-        functools.partial(_dh_kernel, block_n=block_n, block_v=block_v,
-                          v_blocks=v_blocks, vocab=vocab),
-        grid=(n_blocks, v_blocks),
-        in_specs=[
-            pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, e), lambda i, j: (j, 0)),
-            lane_spec, lane_spec, lane_spec, lane_spec,
-        ],
-        out_specs=pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, e), h.dtype),
-        scratch_shapes=[pltpu.VMEM((block_n, e), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(h, wp, lab_l, lse_l, glse_l, gtgt_l)
+    with jax.named_scope("hetu.fused_ce"):
+        dh = pl.pallas_call(
+            functools.partial(_dh_kernel, block_n=block_n, block_v=block_v,
+                              v_blocks=v_blocks, vocab=vocab),
+            grid=(n_blocks, v_blocks),
+            in_specs=[
+                pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
+                pl.BlockSpec((block_v, e), lambda i, j: (j, 0)),
+                lane_spec, lane_spec, lane_spec, lane_spec,
+            ],
+            out_specs=pl.BlockSpec((block_n, e), lambda i, j: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((n, e), h.dtype),
+            scratch_shapes=[pltpu.VMEM((block_n, e), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name="hetu_fused_ce_dh",
+        )(h, wp, lab_l, lse_l, glse_l, gtgt_l)
 
     lane_spec_vn = pl.BlockSpec((block_n, NUM_LANES), lambda j, i: (i, 0))
-    dwp = pl.pallas_call(
-        functools.partial(_dw_kernel, block_n=block_n, block_v=block_v,
-                          n_blocks=n_blocks, vocab=vocab),
-        grid=(v_blocks, n_blocks),
-        in_specs=[
-            pl.BlockSpec((block_n, e), lambda j, i: (i, 0)),
-            pl.BlockSpec((block_v, e), lambda j, i: (j, 0)),
-            lane_spec_vn, lane_spec_vn, lane_spec_vn, lane_spec_vn,
-        ],
-        out_specs=pl.BlockSpec((block_v, e), lambda j, i: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((vocab + v_pad, e), w.dtype),
-        scratch_shapes=[pltpu.VMEM((block_v, e), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(h, wp, lab_l, lse_l, glse_l, gtgt_l)
+    with jax.named_scope("hetu.fused_ce"):
+        dwp = pl.pallas_call(
+            functools.partial(_dw_kernel, block_n=block_n, block_v=block_v,
+                              n_blocks=n_blocks, vocab=vocab),
+            grid=(v_blocks, n_blocks),
+            in_specs=[
+                pl.BlockSpec((block_n, e), lambda j, i: (i, 0)),
+                pl.BlockSpec((block_v, e), lambda j, i: (j, 0)),
+                lane_spec_vn, lane_spec_vn, lane_spec_vn, lane_spec_vn,
+            ],
+            out_specs=pl.BlockSpec((block_v, e), lambda j, i: (j, 0)),
+            out_shape=jax.ShapeDtypeStruct((vocab + v_pad, e), w.dtype),
+            scratch_shapes=[pltpu.VMEM((block_v, e), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name="hetu_fused_ce_dw",
+        )(h, wp, lab_l, lse_l, glse_l, gtgt_l)
     dw = dwp[:vocab] if v_pad else dwp
     return dh, dw, None
 

@@ -274,15 +274,17 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
             pltpu.VMEM((hkv, rows, d), jnp.float32),
         ],
     )
-    out, lse_l = pl.pallas_call(
-        functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
-                          hkv=hkv, n_steps=n_steps, quant=quant),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_tables, q_offset, *args)
+    with jax.named_scope("hetu.paged_attn"):
+        out, lse_l = pl.pallas_call(
+            functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
+                              hkv=hkv, n_steps=n_steps, quant=quant),
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name="hetu_paged_attn",
+        )(block_tables, q_offset, *args)
 
     # (S, hkv, R*g, d) → (S, R, hq, d)
     out = out.reshape(S, hkv, R, g, d).transpose(0, 2, 1, 3, 4) \

@@ -87,6 +87,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+import types
 from typing import Optional, Sequence, Union
 
 import jax
@@ -109,6 +110,114 @@ from hetu_tpu.serving.tenancy import AdapterArenaFull
 from hetu_tpu.telemetry.flight import HangWatchdog, flight_record
 from hetu_tpu.telemetry.slo import SLOEngine, default_serving_rules
 from hetu_tpu.telemetry.spans import REQ_TRACK_BASE  # noqa: F401 — re-export
+
+
+def _bind_metrics(reg) -> types.SimpleNamespace:
+    """The engine's metric handles, taken from the registry ONCE (the
+    help strings live here): the loop calls ``.inc/.set/.observe`` on
+    them and never does a locked get-or-create per token or per
+    iteration. ``MetricRegistry.clear()`` keeps the metric objects, so
+    the handles stay live across ``telemetry.reset()``."""
+    return types.SimpleNamespace(
+        tokens=reg.counter(
+            "serving_tokens_total",
+            "serving tokens by kind"),
+        requests=reg.counter(
+            "serving_requests_total",
+            "serving requests by outcome"),
+        ttft=reg.histogram(
+            "serving_ttft_seconds",
+            "time submit -> first token"),
+        tpot=reg.histogram(
+            "serving_tpot_seconds",
+            "per-output-token time after the first"),
+        step_seconds=reg.histogram(
+            "serving_step_seconds",
+            "one fused engine iteration"),
+        slot_steps=reg.counter(
+            "serving_decode_slot_steps_total",
+            "slot×iteration decode opportunities (each active slot in "
+            "each fused step counts once); 1 + accepted/this is the "
+            "mean tokens committed per slot-step — the speculation "
+            "win, 1.0 without drafts"),
+        attn_kernel=reg.counter(
+            "serving_attn_kernel_total",
+            "fused decode/verify steps by attention path (paged = "
+            "Pallas block-table kernel, reference = XLA gather)"),
+        prefill_kernel=reg.counter(
+            "prefill_attn_kernel_total",
+            "prefill-lane executions by attention path (flash = "
+            "packed/CP flash lane, reference = per-token gather math)"),
+        draft=reg.counter(
+            "serving_draft_tokens_total",
+            "draft tokens proposed to the verify lane"),
+        accepted=reg.counter(
+            "serving_accepted_tokens_total",
+            "draft tokens the verify lane accepted (committed without "
+            "their own decode iteration)"),
+        sampled_accepted=reg.counter(
+            "serving_sampled_accepted_tokens_total",
+            "draft tokens accepted by the rejection-sampling verify "
+            "lane (temperature > 0 slots)"),
+        resample=reg.counter(
+            "serving_resample_tokens_total",
+            "tokens drawn from the rejection-sampling residual after "
+            "a draft was rejected (sampled speculation)"),
+        acceptance=reg.histogram(
+            "serving_draft_acceptance_ratio",
+            "per-request accepted/drafted ratio at finish (the "
+            "speculation win tracks this), split by verify path "
+            "(greedy match vs rejection sampling)"),
+        prefix_hit=reg.counter(
+            "serving_prefix_hit_tokens_total",
+            "prompt tokens served from the prefix cache (prefill "
+            "skipped)"),
+        prefix_miss=reg.counter(
+            "serving_prefix_miss_tokens_total",
+            "prompt tokens that had to be prefilled"),
+        evictions=reg.counter(
+            "serving_block_evictions_total",
+            "prefix-cache blocks LRU-evicted to refill the free list"),
+        cp_requests=reg.counter(
+            "serving_cp_prefill_requests_total",
+            "long prompts prefilled through the CP lane (one cp- "
+            "sharded pass instead of rejection)"),
+        cp_tokens=reg.counter(
+            "serving_cp_prefill_tokens_total",
+            "prompt tokens prefilled through the CP lane"),
+        spilled=reg.counter(
+            "serving_kv_spilled_blocks_total",
+            "KV blocks copied device→host when a request was "
+            "preempted (resumable eviction)"),
+        resumed=reg.counter(
+            "serving_kv_resumed_blocks_total",
+            "spilled KV blocks mapped back into fresh arena blocks on "
+            "resume (prefill skipped entirely)"),
+        preemptions=reg.counter(
+            "serving_preemptions_total",
+            "running requests evicted for more-urgent arrivals, by "
+            "the VICTIM's priority class"),
+        queue_depth=reg.gauge(
+            "serving_queue_depth",
+            "requests waiting for a slot"),
+        occupancy=reg.gauge(
+            "serving_slot_occupancy",
+            "fraction of KV-pool slots in use"),
+        kv_in_use=reg.gauge(
+            "serving_kv_blocks_in_use",
+            "live KV blocks (slot tables + prefix cache)"),
+        spill_arena=reg.gauge(
+            "serving_kv_spill_arena_blocks",
+            "KV blocks parked in the host spill arena (preempted "
+            "requests awaiting resume)"),
+        spill_tiers=reg.gauge(
+            "spill_tier_blocks",
+            "KV blocks parked per spill tier (host arena, peer tier, "
+            "buddy replica store) — the tier chain of ISSUE 18"),
+        adapter_pages=reg.gauge(
+            "adapter_pages_in_use",
+            "adapter arena pages holding a resident adapter"),
+    )
 
 
 def sample_slots(logits, temperature, top_k, top_p, rng):
@@ -506,10 +615,44 @@ class ServingEngine:
         # serve old parameters) instead of per fused step
         self._w8a8_wq = self._prequantize_decode_weights()
 
+        self._m = _bind_metrics(telemetry.get_registry())
+        self._prefill_path = "flash" if prefill_attn != "reference" \
+            else "reference"
         self._fn = self._build_step()
+        self._scopes_registered = False
         self._cp_fn = self._build_cp_prefill() \
             if self._cp_buckets is not None else None
         self._spill_fn, self._resume_fn = self._build_spill_resume()
+
+    def _register_device_scopes(self, args) -> None:
+        """At the first dispatch: keep the fused step's ABSTRACT
+        operands (shapes, dtypes, shardings — no device array) so that
+        ``telemetry.device_scopes`` can fetch the step's optimized HLO
+        later, on demand: ``lower`` from abstract operands finds the
+        trace of the first call (the Python body does not run again,
+        ``trace_counts()["serving_step"]`` stays 1) and ``compile``
+        yields a second, separate executable object
+        (``step_executables()`` stays 1). Nothing is lowered, compiled
+        or parsed unless someone asks for the map."""
+        from hetu_tpu.telemetry import device_scopes
+        self._scopes_registered = True
+
+        def abstract(x):      # numpy operands are uncommitted: no home
+            committed = isinstance(x, jax.Array) and x.committed
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype,
+                sharding=x.sharding if committed else None)
+
+        sds = jax.tree.map(abstract, args)
+        fn, plan = self._fn, self._plan
+
+        def hlo_text() -> str:
+            ctx = plan.act if plan is not None \
+                else contextlib.nullcontext()
+            with ctx:
+                return fn.lower(*sds).compile().as_text()
+
+        device_scopes.register_step("serving_step", hlo_text)
 
     def step_executables(self) -> int:
         """How many executables the ONE fused step holds (the jit's
@@ -591,8 +734,12 @@ class ServingEngine:
                     return c.at[:, cow["dst"]].set(src, mode="drop")
                 return jax.tree.map(one, cs)
 
-            caches = jax.lax.cond(cow["run"], apply_cow,
-                                  lambda cs: cs, caches)
+            # device scopes (telemetry/device_scopes.py) go around the
+            # cond CALLS: a decorated branch function costs seconds of
+            # tracing on the chip's host (PERF.md, PR 24)
+            with jax.named_scope("hetu.kv_arena"):
+                caches = jax.lax.cond(cow["run"], apply_cow,
+                                      lambda cs: cs, caches)
 
             # the decode lane is a VERIFY lane (speculative decoding):
             # every slot feeds its last token plus up to K drafted
@@ -641,11 +788,12 @@ class ServingEngine:
                                             dtype=jnp.float32)
                 else:
                     qprobs = spec["q"].astype(jnp.float32)
-                committed, ncommit, last_tok, new_kd = jax.vmap(
-                    speculative_verify)(
-                    logits, spec["tok"], spec["len"], qprobs,
-                    ctl["temp"], ctl["topk"], ctl["topp"],
-                    ctl["key"])
+                with jax.named_scope("hetu.sample"):
+                    committed, ncommit, last_tok, new_kd = jax.vmap(
+                        speculative_verify)(
+                        logits, spec["tok"], spec["len"], qprobs,
+                        ctl["temp"], ctl["topk"], ctl["topp"],
+                        ctl["key"])
                 # inactive slots must not burn PRNG state — their
                 # sampling stream has to match one-shot generate
                 new_kd = jnp.where(ctl["active"][:, None],
@@ -658,8 +806,10 @@ class ServingEngine:
                 return (caches, jnp.zeros((S, K + 1), jnp.int32),
                         z, z, ctl["key"])
 
-            caches, committed, ncommit, last_tok, new_kd = jax.lax.cond(
-                ctl["active"].any(), do_decode, no_decode, caches)
+            with jax.named_scope("hetu.decode_lane"):
+                caches, committed, ncommit, last_tok, new_kd = \
+                    jax.lax.cond(ctl["active"].any(), do_decode,
+                                 no_decode, caches)
 
             # packed prefill: a C-token budget shared by every
             # admitting request — per-token (slot, position) operands
@@ -729,19 +879,21 @@ class ServingEngine:
                     return (tok.astype(jnp.int32),
                             jax.random.key_data(k))
 
-                firsts, pf_kd = jax.vmap(sample_row)(
-                    lg, jnp.take(ctl["temp"], fs),
-                    jnp.take(ctl["topk"], fs),
-                    jnp.take(ctl["topp"], fs),
-                    jnp.take(ctl["key"], fs, axis=0))
+                with jax.named_scope("hetu.sample"):
+                    firsts, pf_kd = jax.vmap(sample_row)(
+                        lg, jnp.take(ctl["temp"], fs),
+                        jnp.take(ctl["topk"], fs),
+                        jnp.take(ctl["topp"], fs),
+                        jnp.take(ctl["key"], fs, axis=0))
                 return caches, firsts, pf_kd
 
             def no_prefill(caches):
                 return (caches, jnp.zeros((R,), jnp.int32),
                         jnp.take(ctl["key"], pf["fin_slot"], axis=0))
 
-            caches, first_toks, pf_kd = jax.lax.cond(
-                pf["run"], do_prefill, no_prefill, caches)
+            with jax.named_scope("hetu.prefill_lane"):
+                caches, first_toks, pf_kd = jax.lax.cond(
+                    pf["run"], do_prefill, no_prefill, caches)
             # prefill completions ADOPT their post-sample key state:
             # scatter the <= R finished rows' keys over the slot axis
             # (unused fin rows target S and drop)
@@ -884,7 +1036,7 @@ class ServingEngine:
                 # per-request stream the packed lane would have
                 "key": self._key_state[slot].copy()}
 
-    def _exec_cp_prefill(self, job: dict, t0: float, reg) -> None:
+    def _exec_cp_prefill(self, job: dict, t0: float) -> None:
         """Run one prepared CP-lane prefill. The device call happens
         WITHOUT ``self._lock`` (submit()/load stay responsive through a
         multi-second cold-bucket compile or a 100k-token forward; the
@@ -911,35 +1063,25 @@ class ServingEngine:
             self._ctl_dirty = True
             req.status = "decode"
             req.first_token_s = now
-            req.mark("prefill_chunk", dur_s=now - t0, ts_s=t0)
+            req.mark("prefill_chunk", dur_s=now - t0, ts_s=t0,
+                     iter=self._iter + 1)
             req.mark("first_token", ts_s=now)
             ttft = now - req.submit_s
-            reg.histogram("serving_ttft_seconds",
-                          "time submit -> first token").observe(ttft)
+            m = self._m
+            m.ttft.observe(ttft)
             if self.slo is not None:
                 self.slo.observe("serving_ttft_seconds", ttft)
-            reg.counter("serving_tokens_total",
-                        "serving tokens by kind").inc(P, kind="prompt")
-            reg.counter(
-                "serving_cp_prefill_requests_total",
-                "long prompts prefilled through the CP lane (one "
-                "cp-sharded pass instead of rejection)").inc()
-            reg.counter(
-                "serving_cp_prefill_tokens_total",
-                "prompt tokens prefilled through the CP lane").inc(P)
-            reg.counter(
-                "prefill_attn_kernel_total",
-                "prefill-lane executions by attention path (flash "
-                "= packed/CP flash lane, reference = per-token "
-                "gather math)").inc(
-                path="flash" if self.prefill_attn != "reference"
-                else "reference")
+            m.tokens.inc(P, kind="prompt")
+            m.cp_requests.inc()
+            m.cp_tokens.inc(P)
+            m.prefill_kernel.inc(path=self._prefill_path)
             flight_record("serving_cp_prefill", req=req.id,
                           trace=req.trace_id, slot=slot, tokens=P,
                           bucket=job["bucket"])
             # no prefix-cache insert: lane blocks stay private to the
             # slot (long-prompt prefix sharing is future work)
-            self._on_token(slot, int(tok), now, reg)
+            self._on_token(slot, int(tok), now)
+            m.tokens.inc(kind="generated")
 
     # -- resumable preemption (QoS) -----------------------------------------
     def _plan_preemption_locked(self) -> Optional[dict]:
@@ -993,7 +1135,7 @@ class ServingEngine:
         self._adapter_page[slot] = 0
         self._ctl_dirty = True
 
-    def _exec_spill(self, job: dict, reg) -> None:
+    def _exec_spill(self, job: dict) -> None:
         """Evict one running request into the host arena and requeue it
         at the head of its class — the resumable half of preemption."""
         req, slot, nb = job["req"], job["slot"], job["nb"]
@@ -1017,20 +1159,14 @@ class ServingEngine:
             self._detach_locked(req, slot)
             self.scheduler.requeue_preempted(req)
             self.scheduler.preemptions_total += 1
-            reg.counter(
-                "serving_kv_spilled_blocks_total",
-                "KV blocks copied device→host when a request was "
-                "preempted (resumable eviction)").inc(nb)
-            reg.counter(
-                "serving_preemptions_total",
-                "running requests evicted for more-urgent arrivals, "
-                "by the VICTIM's priority class").inc(
+            self._m.spilled.inc(nb)
+            self._m.preemptions.inc(
                 priority=str(req.sampling.priority))
         flight_record("serving_preempt", req=req.id, trace=req.trace_id,
                       slot=slot, blocks=nb,
                       priority=req.sampling.priority)
 
-    def _exec_resume(self, job: dict, reg) -> None:
+    def _exec_resume(self, job: dict) -> None:
         """Map one spilled request's KV back into its freshly allocated
         blocks and flip its slot live — ZERO prefill-lane work (the
         acceptance bar for resumable preemption)."""
@@ -1075,10 +1211,7 @@ class ServingEngine:
             if self._draftsman is not None:
                 self._draftsman.reset(
                     slot, req.prompt.tolist() + list(req.tokens))
-            reg.counter(
-                "serving_kv_resumed_blocks_total",
-                "spilled KV blocks mapped back into fresh arena blocks "
-                "on resume (prefill skipped entirely)").inc(nb)
+            self._m.resumed.inc(nb)
         flight_record("serving_resume", req=req.id, trace=req.trace_id,
                       slot=slot, blocks=nb, pos=entry.pos)
 
@@ -2016,7 +2149,7 @@ class ServingEngine:
         with self._step_lock:
             return self._step_locked()
 
-    def _admit_locked(self, now: float, reg) -> list[tuple[int, int]]:
+    def _admit_locked(self, now: float) -> list[tuple[int, int]]:
         """Admit every admissible queued request (slots + free blocks
         permitting): map its prefix-cache plan into the slot's block
         table and queue its prefill. Returns this iteration's CoW
@@ -2067,45 +2200,51 @@ class ServingEngine:
             self._ctl_dirty = True           # new sampling params + bt
             hit = req.cached_tokens
             if hit:
-                reg.counter("serving_prefix_hit_tokens_total",
-                            "prompt tokens served from the prefix "
-                            "cache (prefill skipped)").inc(hit)
-            reg.counter("serving_prefix_miss_tokens_total",
-                        "prompt tokens that had to be prefilled").inc(
-                len(req.prompt) - hit)
+                self._m.prefix_hit.inc(hit)
+            self._m.prefix_miss.inc(len(req.prompt) - hit)
             flight_record("serving_admit", req=req.id,
                           trace=req.trace_id, slot=slot,
                           cached_tokens=hit, cp_lane=req.cp_lane,
                           queued_s=round(now - req.submit_s, 4))
         ev = self.scheduler.evictions_total
         if ev > self._evictions_synced:
-            reg.counter("serving_block_evictions_total",
-                        "prefix-cache blocks LRU-evicted to refill the "
-                        "free list").inc(ev - self._evictions_synced)
+            self._m.evictions.inc(ev - self._evictions_synced)
             self._evictions_synced = ev
         return cows
 
     def _step_locked(self) -> bool:
+        if not self.has_work():
+            return False            # an idle turn records nothing
+        # one span per phase, none per token: serve/step and its
+        # children are what a jax.profiler trace shows above the fused
+        # step's ops (docs/OBSERVABILITY.md); the tracer records them
+        # too when telemetry is on
+        with telemetry.span("serve/step", iter=self._iter + 1) as sp:
+            return self._step_spanned(sp)
+
+    def _step_spanned(self, step_span) -> bool:
         t0 = time.monotonic()
-        reg = telemetry.get_registry()
+        span = telemetry.span
+        m = self._m
         C = self.prefill_chunk
         R = self._fin_cap
         K = self.spec_depth
         S = self.pool.slots
-        with self._lock:
-            cows = self._admit_locked(t0, reg)
+        with span("serve/admit"), self._lock:
+            cows = self._admit_locked(t0)
             # preemption runs AFTER admission, so it fires only when
             # the deficit-selected head genuinely could not admit —
             # prefix-cache credit and cache eviction (which _page_plan
             # already spends) admit for free before anyone is evicted
             spill_job = self._plan_preemption_locked()
         if spill_job is not None:
-            self._exec_spill(spill_job, reg)
+            with span("serve/aux", what="spill"):
+                self._exec_spill(spill_job)
         with self._lock:
             if spill_job is not None:
                 # second admission pass picks up the freed slot/blocks
                 # in THIS iteration (the urgent head does not wait one)
-                cows += self._admit_locked(t0, reg)
+                cows += self._admit_locked(t0)
             # CP-lane prefills run as their own (bucket-audited)
             # executables before the fused step — at most ONE per
             # iteration, device call OUTSIDE the lock. Spill-resumes
@@ -2116,10 +2255,12 @@ class ServingEngine:
                 if self._resume_pending else None
         did_aux = spill_job is not None
         if resume_job is not None:
-            self._exec_resume(resume_job, reg)
+            with span("serve/aux", what="resume"):
+                self._exec_resume(resume_job)
             did_aux = True
         if cp_job is not None:
-            self._exec_cp_prefill(cp_job, t0, reg)
+            with span("serve/aux", what="cp_prefill"):
+                self._exec_cp_prefill(cp_job, t0)
             did_aux = True
         with self._lock:
             active_prev = np.nonzero(self._active)[0]
@@ -2128,73 +2269,32 @@ class ServingEngine:
                 if did_aux:
                     self._record_gauges()
                 return did_aux
-            # speculative drafts: per-slot depth + tokens are DATA
-            # operands rebuilt every iteration. Depth clamps: never
-            # beyond the request's remaining token budget - 1 (so
-            # commits can't blow past max_tokens or the slot's
-            # allocated blocks). Sampled (temperature > 0) slots
-            # speculate too — the rejection-sampling verify lane keeps
-            # their output distribution exact (``speculative_verify``).
-            # The n-gram index is host-only and proposes here; the
-            # model draftsman's DEVICE step runs between the lock
-            # windows below (submit()/load stay responsive through it —
-            # the iteration lock we hold keeps its inputs frozen).
-            d_tok = np.zeros((S, K), np.int32)
-            d_len = np.zeros(S, np.int32)
-            d_q = None
-            if K and self._draftsman is not None \
-                    and not self._draftsman.host_only:
-                # device draftsman: its q rows ride the spec operand —
-                # ALWAYS present so the step's pytree signature (and
-                # the 1-compile audit) never depends on churn
-                d_q = np.zeros((S, K, self.model.cfg.vocab_size),
-                               np.float32)
-            model_draft_in = None
-            if K and active_prev.size:
-                budget = np.zeros(S, np.int32)
-                for r in active_prev:
-                    req = self._slot_req[r]
-                    sp = req.sampling
-                    budget[r] = max(0, min(
-                        K, sp.max_tokens - len(req.tokens) - 1))
-                if self._draftsman is not None and budget.any():
-                    if self._draftsman.host_only:
-                        for r in active_prev:
-                            b = int(budget[r])
-                            if b <= 0:
-                                continue
-                            prop = self._draftsman.propose(int(r), b)
-                            if prop:
-                                n = min(len(prop), b)
-                                d_tok[r, :n] = prop[:n]
-                                d_len[r] = n
-                    else:
-                        seqs: list = [None] * S
-                        for r in active_prev:
-                            req = self._slot_req[r]
-                            seqs[r] = req.prompt.tolist() \
-                                + list(req.tokens)
-                        model_draft_in = (seqs, self._pos.copy(),
-                                          self._active.copy(), budget,
-                                          self._temp.copy(),
-                                          self._topk.copy(),
-                                          self._topp.copy(),
-                                          self._key_state.copy())
-        if model_draft_in is not None:
-            d_tok, d_len, dq = self._draftsman.propose_all(
-                *model_draft_in[:4], temps=model_draft_in[4],
-                topks=model_draft_in[5], topps=model_draft_in[6],
-                keys=model_draft_in[7])
-            d_tok = np.asarray(d_tok)
-            d_len = np.minimum(np.asarray(d_len), model_draft_in[3])
-            d_q = np.asarray(dq, np.float32)
-            # a zoo draft model may have a larger vocab than the
-            # target: clamp (the draftsman already masks its sampling
-            # to the target vocab; this guards legacy draft paths)
-            v = getattr(self.model.cfg, "vocab_size", None)
-            if v:
-                np.clip(d_tok, 0, v - 1, out=d_tok)
-        with self._lock:
+        # speculative drafts: per-slot depth + tokens are DATA
+        # operands rebuilt every iteration. Depth clamps: never
+        # beyond the request's remaining token budget - 1 (so
+        # commits can't blow past max_tokens or the slot's
+        # allocated blocks). Sampled (temperature > 0) slots
+        # speculate too — the rejection-sampling verify lane keeps
+        # their output distribution exact (``speculative_verify``).
+        # The n-gram index is host-only and proposes here; the
+        # model draftsman's DEVICE step runs between the lock
+        # windows below (submit()/load stay responsive through it —
+        # the iteration lock we hold keeps its inputs frozen).
+        d_tok = np.zeros((S, K), np.int32)
+        d_len = np.zeros(S, np.int32)
+        d_q = None
+        if K and self._draftsman is not None \
+                and not self._draftsman.host_only:
+            # device draftsman: its q rows ride the spec operand —
+            # ALWAYS present so the step's pytree signature (and
+            # the 1-compile audit) never depends on churn
+            d_q = np.zeros((S, K, self.model.cfg.vocab_size),
+                           np.float32)
+        if K and active_prev.size and self._draftsman is not None:
+            with span("serve/draft"):
+                d_tok, d_len, d_q = self._draft(
+                    active_prev, d_tok, d_len, d_q)
+        with span("serve/pack"), self._lock:
             if self._ctl_dirty:
                 # uploaded to the step's home: pos/last_tok/key come
                 # back from the step on it, and a differently-typed
@@ -2266,18 +2366,24 @@ class ServingEngine:
         spec = {"tok": d_tok, "len": d_len}
         if d_q is not None:
             spec["q"] = d_q
-        with ctx:
-            (caches, committed, ncommit, first_toks, pos_dev,
-             last_dev, key_dev) = self._fn(
-                self.params, self.pool.caches, ctl, pf, bt, cow, spec,
+        step_span.set(active=int(active_prev.size), prefill_tokens=used)
+        args = (self.params, self.pool.caches, ctl, pf, bt, cow, spec,
                 self._w8a8_wq, self._lora_pages)
+        if not self._scopes_registered:
+            self._register_device_scopes(args)
+        with span("serve/dispatch"), ctx:
+            (caches, committed, ncommit, first_toks, pos_dev,
+             last_dev, key_dev) = self._fn(*args)
+        del args                    # the arena was donated
         self.pool.caches = caches
-        em = np.asarray(committed)               # (S, K+1)
-        nc = np.asarray(ncommit)                 # (S,)
-        ft = np.asarray(first_toks)
+        with span("serve/device_wait"):
+            em = np.asarray(committed)           # (S, K+1)
+            nc = np.asarray(ncommit)             # (S,)
+            ft = np.asarray(first_toks)
         now = time.monotonic()
 
-        with self._lock:
+        n_generated = 0
+        with span("serve/commit"), self._lock:
             self._iter += 1
             # the host mirror of the per-slot commit keys always tracks
             # the device: the step advanced them (verify consumption +
@@ -2285,26 +2391,10 @@ class ServingEngine:
             # sampled this iteration
             self._key_state[:] = np.asarray(key_dev)
             if active_prev.size:
-                reg.counter(
-                    "serving_decode_slot_steps_total",
-                    "slot×iteration decode opportunities (each active "
-                    "slot in each fused step counts once); 1 + "
-                    "accepted/this is the mean tokens committed per "
-                    "slot-step — the speculation win, 1.0 without "
-                    "drafts").inc(int(active_prev.size))
-                reg.counter(
-                    "serving_attn_kernel_total",
-                    "fused decode/verify steps by attention path "
-                    "(paged = Pallas block-table kernel, reference = "
-                    "XLA gather)").inc(path=self.attn_kernel)
+                m.slot_steps.inc(int(active_prev.size))
+                m.attn_kernel.inc(path=self.attn_kernel)
             if used:
-                reg.counter(
-                    "prefill_attn_kernel_total",
-                    "prefill-lane executions by attention path (flash "
-                    "= packed/CP flash lane, reference = per-token "
-                    "gather math)").inc(
-                    path="flash" if self.prefill_attn != "reference"
-                    else "reference")
+                m.prefill_kernel.inc(path=self._prefill_path)
             # decode results for the slots that were active going in:
             # each commits ncommit tokens (accepted drafts + bonus) —
             # EOS or budget can finish the request mid-commit, in which
@@ -2318,10 +2408,11 @@ class ServingEngine:
                     continue
                 taken = 0
                 for j in range(n):
-                    self._on_token(int(r), int(em[r, j]), now, reg)
+                    self._on_token(int(r), int(em[r, j]), now)
                     taken += 1
                     if self._slot_req[int(r)] is not req:
                         break                    # finished mid-commit
+                n_generated += taken
                 dr = int(d_len[r])
                 if dr:
                     # count only what the request KEPT: of the `taken`
@@ -2333,40 +2424,23 @@ class ServingEngine:
                     sampled = float(self._temp[r]) > 0.0
                     req.drafted += dr
                     req.accepted += kept
-                    reg.counter(
-                        "serving_draft_tokens_total",
-                        "draft tokens proposed to the verify "
-                        "lane").inc(dr)
+                    m.draft.inc(dr)
                     if kept:
-                        reg.counter(
-                            "serving_accepted_tokens_total",
-                            "draft tokens the verify lane accepted "
-                            "(committed without their own decode "
-                            "iteration)").inc(kept)
+                        m.accepted.inc(kept)
                         if sampled:
-                            reg.counter(
-                                "serving_sampled_accepted_tokens_total",
-                                "draft tokens accepted by the "
-                                "rejection-sampling verify lane "
-                                "(temperature > 0 slots)").inc(kept)
+                            m.sampled_accepted.inc(kept)
                     if sampled and n - 1 < dr:
                         # the device rejected draft column n-1 and
                         # drew the commit token from the normalized
                         # residual max(0, p - q)
-                        reg.counter(
-                            "serving_resample_tokens_total",
-                            "tokens drawn from the rejection-"
-                            "sampling residual after a draft was "
-                            "rejected (sampled speculation)").inc(1)
+                        m.resample.inc(1)
             # prefill progress for every request that got pack tokens
             for ent, n in fills:
                 ent["off"] += n
                 ent["req"].mark("prefill_chunk", dur_s=now - t0,
-                                ts_s=t0)
+                                ts_s=t0, iter=self._iter)
             if used:
-                reg.counter("serving_tokens_total",
-                            "serving tokens by kind").inc(
-                    used, kind="prompt")
+                m.tokens.inc(used, kind="prompt")
             for i, ent in enumerate(fin_ents):
                 req, slot = ent["req"], ent["slot"]
                 self._pos[slot] = len(req.prompt)
@@ -2376,9 +2450,7 @@ class ServingEngine:
                 req.first_token_s = now
                 req.mark("first_token", ts_s=now)
                 ttft = now - req.submit_s
-                reg.histogram(
-                    "serving_ttft_seconds",
-                    "time submit -> first token").observe(ttft)
+                m.ttft.observe(ttft)
                 if self.slo is not None:
                     self.slo.observe("serving_ttft_seconds", ttft)
                 # the finished prompt's whole blocks enter the radix
@@ -2387,8 +2459,13 @@ class ServingEngine:
                     self.prefix_cache.insert(req.prompt.tolist(),
                                              self._bt[slot],
                                              adapter=req.kv_adapter)
-                self._on_token(slot, int(ft[i]), now, reg)
+                self._on_token(slot, int(ft[i]), now)
                 self._prefilling.remove(ent)
+            n_generated += len(fin_ents)
+            if n_generated:
+                # once per iteration by the number committed, not once
+                # per token
+                m.tokens.inc(n_generated, kind="generated")
             # steady decode: adopt the step's own control advance (no
             # host→device upload next iteration). Any event above set
             # _ctl_dirty, which forces a rebuild from the np mirrors.
@@ -2396,20 +2473,76 @@ class ServingEngine:
                 self._ctl_dev = dict(self._ctl_dev, pos=pos_dev,
                                      last_tok=last_dev, key=key_dev)
             self._record_gauges()
-        self._pump_stream_subs()
+        with span("serve/pump"):
+            self._pump_stream_subs()
         step_s = time.monotonic() - t0
-        reg.histogram("serving_step_seconds",
-                      "one fused engine iteration").observe(step_s)
+        m.step_seconds.observe(step_s)
         if self.slo is not None:
             self.slo.observe("serving_step_seconds", step_s)
         if self._counter_sample_every and \
                 self._iter % self._counter_sample_every == 0:
-            telemetry.get_tracer().record_counters(reg.snapshot())
+            telemetry.get_tracer().record_counters(
+                telemetry.get_registry().snapshot())
         return True
 
-    def _on_token(self, slot: int, tok: int, now: float, reg) -> None:
+    def _draft(self, active_prev, d_tok, d_len, d_q):
+        """This iteration's draft proposals (speculation only): the
+        n-gram index proposes on the host under the lock; the model
+        draftsman's DEVICE step runs outside it."""
+        K = self.spec_depth
+        S = self.pool.slots
+        model_draft_in = None
+        with self._lock:
+            budget = np.zeros(S, np.int32)
+            for r in active_prev:
+                req = self._slot_req[r]
+                sp = req.sampling
+                budget[r] = max(0, min(
+                    K, sp.max_tokens - len(req.tokens) - 1))
+            if budget.any():
+                if self._draftsman.host_only:
+                    for r in active_prev:
+                        b = int(budget[r])
+                        if b <= 0:
+                            continue
+                        prop = self._draftsman.propose(int(r), b)
+                        if prop:
+                            n = min(len(prop), b)
+                            d_tok[r, :n] = prop[:n]
+                            d_len[r] = n
+                else:
+                    seqs: list = [None] * S
+                    for r in active_prev:
+                        req = self._slot_req[r]
+                        seqs[r] = req.prompt.tolist() \
+                            + list(req.tokens)
+                    model_draft_in = (seqs, self._pos.copy(),
+                                      self._active.copy(), budget,
+                                      self._temp.copy(),
+                                      self._topk.copy(),
+                                      self._topp.copy(),
+                                      self._key_state.copy())
+        if model_draft_in is not None:
+            d_tok, d_len, dq = self._draftsman.propose_all(
+                *model_draft_in[:4], temps=model_draft_in[4],
+                topks=model_draft_in[5], topps=model_draft_in[6],
+                keys=model_draft_in[7])
+            d_tok = np.asarray(d_tok)
+            d_len = np.minimum(np.asarray(d_len), model_draft_in[3])
+            d_q = np.asarray(dq, np.float32)
+            # a zoo draft model may have a larger vocab than the
+            # target: clamp (the draftsman already masks its sampling
+            # to the target vocab; this guards legacy draft paths)
+            v = getattr(self.model.cfg, "vocab_size", None)
+            if v:
+                np.clip(d_tok, 0, v - 1, out=d_tok)
+        return d_tok, d_len, d_q
+
+    def _on_token(self, slot: int, tok: int, now: float) -> None:
         """Record one sampled token for ``slot`` (caller holds lock):
-        append, advance the slot cursor, finish on EOS / budget."""
+        append, advance the slot cursor, finish on EOS / budget. The
+        caller counts it into ``serving_tokens_total{kind=generated}``
+        — once per iteration by the number committed, not per token."""
         req = self._slot_req[slot]
         req.tokens.append(tok)
         self._last_tok[slot] = tok
@@ -2419,12 +2552,10 @@ class ServingEngine:
             self._pos[slot] += 1
         if self._draftsman is not None and self._draftsman.host_only:
             self._draftsman.extend(slot, (tok,))
-        reg.counter("serving_tokens_total",
-                    "serving tokens by kind").inc(kind="generated")
         sp = req.sampling
         hit_eos = sp.eos_id is not None and tok == sp.eos_id
         if hit_eos or len(req.tokens) >= sp.max_tokens:
-            self._finish(slot, now, reg)
+            self._finish(slot, now)
         elif req.handoff and req.status == "decode":
             # prefill-tier park (P/D disaggregation): the first token
             # landed, so prefill is DONE — stop decoding here. The slot
@@ -2439,7 +2570,7 @@ class ServingEngine:
                           trace=req.trace_id, slot=slot,
                           prompt_len=len(req.prompt))
 
-    def _finish(self, slot: int, now: float, reg) -> None:
+    def _finish(self, slot: int, now: float) -> None:
         req = self._slot_req[slot]
         req.status = "done"
         req.finish_s = now
@@ -2453,23 +2584,15 @@ class ServingEngine:
         # prefix cache adopted stay resident (trie refs), the rest free
         self.scheduler.release(slot, table=self._bt[slot])
         self._bt[slot, :] = 0
-        reg.counter("serving_requests_total",
-                    "serving requests by outcome").inc(
-            outcome="completed")
+        self._m.requests.inc(outcome="completed")
         n = len(req.tokens)
         if n > 1 and req.first_token_s is not None:
             tpot = (now - req.first_token_s) / (n - 1)
-            reg.histogram("serving_tpot_seconds",
-                          "per-output-token time after the first").observe(
-                tpot)
+            self._m.tpot.observe(tpot)
             if self.slo is not None:
                 self.slo.observe("serving_tpot_seconds", tpot)
         if req.drafted:
-            reg.histogram(
-                "serving_draft_acceptance_ratio",
-                "per-request accepted/drafted ratio at finish (the "
-                "speculation win tracks this), split by verify path "
-                "(greedy match vs rejection sampling)").observe(
+            self._m.acceptance.observe(
                 req.accepted / req.drafted,
                 path="sampled" if req.sampling.temperature > 0
                 else "greedy")
@@ -2507,41 +2630,28 @@ class ServingEngine:
         admit = next((t for p, t, _ in req.events if p == "admit"), None)
         if admit is not None:
             span("queued", req.submit_s, admit - req.submit_s)
-        for phase, ts, dur in req.events:
-            if phase == "prefill_chunk":
-                span("prefill_chunk", ts, dur)
+        chunks = [(ts, dur) for phase, ts, dur in req.events
+                  if phase == "prefill_chunk"]
+        for (ts, dur), it in zip(chunks, req.chunk_iters):
+            # iter: the serve/step span that ran this chunk
+            span("prefill_chunk", ts, dur, iter=it)
         if req.first_token_s is not None and req.finish_s is not None:
             span("decode", req.first_token_s,
                  req.finish_s - req.first_token_s,
                  tokens=len(req.tokens))
 
     def _record_gauges(self) -> None:
-        reg = telemetry.get_registry()
-        reg.gauge("serving_queue_depth",
-                  "requests waiting for a slot").set(self.scheduler.depth)
-        reg.gauge("serving_slot_occupancy",
-                  "fraction of KV-pool slots in use").set(
-            self.scheduler.occupancy)
-        reg.gauge("serving_kv_blocks_in_use",
-                  "live KV blocks (slot tables + prefix cache)").set(
-            self.blocks.blocks_in_use)
-        reg.gauge("serving_kv_spill_arena_blocks",
-                  "KV blocks parked in the host spill arena "
-                  "(preempted requests awaiting resume)").set(
-            self.spill_arena.blocks_held)
-        tiers = dict(self.spill_arena.tier_counts())
-        tiers["replica"] = self.kv_replica_store.blocks_held
-        g = reg.gauge(
-            "spill_tier_blocks",
-            "KV blocks parked per spill tier (host arena, peer tier, "
-            "buddy replica store) — the tier chain of ISSUE 18")
-        for tier, n in tiers.items():
-            g.set(n, tier=tier)
+        m = self._m
+        m.queue_depth.set(self.scheduler.depth)
+        m.occupancy.set(self.scheduler.occupancy)
+        m.kv_in_use.set(self.blocks.blocks_in_use)
+        m.spill_arena.set(self.spill_arena.blocks_held)
+        for tier, n in self.spill_arena.tier_counts().items():
+            m.spill_tiers.set(n, tier=tier)
+        m.spill_tiers.set(self.kv_replica_store.blocks_held,
+                          tier="replica")
         if self.tenancy is not None:
-            reg.gauge(
-                "adapter_pages_in_use",
-                "adapter arena pages holding a resident adapter").set(
-                self.tenancy.registry.pages_in_use)
+            m.adapter_pages.set(self.tenancy.registry.pages_in_use)
 
     def run_until_drained(self, max_steps: int = 1_000_000) -> int:
         """Drive :meth:`step` until queue + slots are empty; returns the

@@ -145,16 +145,26 @@ class Request:
     #                                    originating id
     events: list = dataclasses.field(default_factory=list,
                                      repr=False, compare=False)
+    chunk_iters: list = dataclasses.field(
+        default_factory=list, repr=False, compare=False)  # engine
+    #                                    iteration of each
+    #                                    "prefill_chunk" event, in order
     done: threading.Event = dataclasses.field(
         default_factory=threading.Event, repr=False, compare=False)
 
     def mark(self, phase: str, dur_s: float = 0.0,
-             ts_s: Optional[float] = None) -> None:
+             ts_s: Optional[float] = None,
+             iter: Optional[int] = None) -> None:
         """Append one lifecycle event (``ts_s`` defaults to now; the
-        clock is ``time.monotonic`` — the same one ``submit_s`` uses)."""
+        clock is ``time.monotonic`` — the same one ``submit_s`` uses).
+        ``iter`` is the engine iteration that ran a prefill chunk: the
+        request's track then names the ``serve/step`` span that caused
+        it."""
         self.events.append(
             (phase, time.monotonic() if ts_s is None else ts_s,
              float(dur_s)))
+        if iter is not None:
+            self.chunk_iters.append(int(iter))
 
     def timing(self) -> dict:
         """Phase breakdown in milliseconds for the RESULT verb: queued

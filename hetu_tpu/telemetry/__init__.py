@@ -10,7 +10,11 @@ with (``utils/logging.py`` JSONL sink, ``utils/profiler.py`` step stats):
   with snapshot-to-dict and Prometheus-text exposition;
 - :mod:`~hetu_tpu.telemetry.aggregate` — per-host snapshots fanned
   through the coordinator KV; rank 0 emits cluster min/max/mean;
-- :mod:`~hetu_tpu.telemetry.goodput` — goodput / MFU accountant.
+- :mod:`~hetu_tpu.telemetry.goodput` — goodput / MFU accountant;
+- :mod:`~hetu_tpu.telemetry.device_scopes` — the ``hetu.*`` named
+  scopes of the compiled steps, read back from their optimized HLO;
+- :mod:`~hetu_tpu.telemetry.compile_events` — what JAX spent tracing,
+  lowering and compiling, by function (always on).
 
 Process-global default instances live here (the Prometheus
 default-registry idiom): instrumented hot paths write through
@@ -28,6 +32,9 @@ from typing import Optional
 from hetu_tpu.telemetry.aggregate import (
     aggregate_snapshots, cluster_aggregate, collect_snapshots,
     publish_snapshot,
+)
+from hetu_tpu.telemetry.compile_events import (
+    CompileEvent, compile_events,
 )
 from hetu_tpu.telemetry.federation import (
     health_rollup, merge_prometheus, parse_prometheus,
@@ -48,7 +55,7 @@ from hetu_tpu.telemetry.slo import (
     health_status,
 )
 from hetu_tpu.telemetry.spans import (
-    DEFAULT_COUNTER_TRACK_PREFIXES, NULL_SPAN, SpanEvent, Tracer,
+    DEFAULT_COUNTER_TRACK_PREFIXES, SpanEvent, Tracer,
 )
 from hetu_tpu.telemetry.tracecontext import (
     TRACEPARENT_VERBS, current_traceparent, make_traceparent,
@@ -131,7 +138,7 @@ def export_dir(path: str, *, extra_records=(),
 
 
 __all__ = [
-    "Tracer", "SpanEvent", "NULL_SPAN",
+    "Tracer", "SpanEvent",
     "DEFAULT_COUNTER_TRACK_PREFIXES",
     "MetricRegistry", "Counter", "Gauge", "Histogram", "percentile",
     "GoodputAccountant", "GoodputReport", "CATEGORIES",
@@ -146,6 +153,7 @@ __all__ = [
     "TRACEPARENT_VERBS", "make_traceparent", "parse_traceparent",
     "new_span_id", "current_traceparent", "use_trace",
     "parse_prometheus", "merge_prometheus", "health_rollup",
+    "CompileEvent", "compile_events",
     "get_tracer", "get_registry", "enable", "enabled", "reset", "span",
     "export_dir",
 ]

@@ -80,6 +80,12 @@ class _Metric:
     def _on(self) -> bool:
         return self._reg.enabled
 
+    def _clear(self) -> None:
+        self._values.clear()
+
+    def _empty(self) -> bool:
+        return not self._values
+
 
 class Counter(_Metric):
     kind = "counter"
@@ -179,6 +185,12 @@ class Histogram(_Metric):
                 if j < self.max_samples:
                     s.sample[j] = value
 
+    def _clear(self) -> None:
+        self._series.clear()
+
+    def _empty(self) -> bool:
+        return not self._series
+
     def percentiles(self, qs: Sequence[float] = (0.5, 0.9, 0.99),
                     **labels) -> dict[float, float]:
         s = self._series.get(_label_key(labels))
@@ -221,6 +233,8 @@ class MetricRegistry:
             elif not isinstance(m, cls):
                 raise ValueError(
                     f"metric {name!r} already registered as {m.kind}")
+            elif help and not m.help:
+                m.help = help       # first taken by a reader, without
             return m
 
     def counter(self, name: str, help: str = "") -> Counter:
@@ -237,8 +251,14 @@ class MetricRegistry:
         return self._metrics.get(name)
 
     def clear(self) -> None:
+        """Drop every series. The metric OBJECTS stay registered, so a
+        handle bound once (``c = reg.counter(...)`` at construction, the
+        hot-path idiom) keeps writing into this registry after a
+        ``telemetry.reset()``; a metric without series is left out of
+        every exposition."""
         with self._lock:
-            self._metrics.clear()
+            for m in self._metrics.values():
+                m._clear()
 
     # -- exposition ---------------------------------------------------------
     def snapshot(self) -> dict:
@@ -261,6 +281,8 @@ class MetricRegistry:
         lines: list[str] = []
         with self._lock:
             for m in self._metrics.values():
+                if m._empty():
+                    continue
                 if m.help:
                     lines.append(
                         f"# HELP {m.name} {_escape_help(m.help)}")

@@ -8,10 +8,18 @@ switches, checkpoint writes, prefetch stalls — which is exactly what this
 tracer records. Traces export as Chrome-trace JSON (``traceEvents``) so
 they open in Perfetto / ``chrome://tracing`` next to the xplane traces.
 
+Every span is ALSO a ``jax.profiler.TraceAnnotation`` named
+``hetu:<name>``: while a ``jax.profiler`` trace runs, the program's own
+spans land in the xplane's host plane on the device trace's clock, one
+line per thread, above the ops they dispatched — so an idle gap of the
+device can be put down to the span that covered it
+(``docs/OBSERVABILITY.md``, "host spans on the profiler's clock").
+
 Design constraints:
 
-- near-zero cost when disabled: ``span()`` on a disabled tracer returns a
-  shared no-op context manager (no allocation, no clock read);
+- near-zero cost when disabled: ``span()`` on a disabled tracer returns
+  the bare annotation (a TraceMe costs nanoseconds while no profile
+  runs; no clock read, nothing recorded);
 - thread-safe: spans nest per-thread (checkpoint writer threads and the
   data prefetcher record concurrently with the train loop);
 - bounded: at most ``max_events`` are kept; later events are counted as
@@ -26,6 +34,30 @@ import os
 import threading
 import time
 from typing import Any, Iterator, Optional
+
+import jax
+
+#: prefix of the program's spans in a ``jax.profiler`` trace's host plane
+PROFILER_PREFIX = "hetu:"
+
+
+class _Annotation(jax.profiler.TraceAnnotation):
+    """The profiler-side half of a span: ``hetu:<name>`` in the xplane's
+    host plane while a ``jax.profiler`` trace runs, nothing otherwise.
+    ``set`` is what call sites use on whatever ``span()`` returns: a
+    TraceMe takes metadata until it is left, and drops it at once while
+    no profile runs."""
+
+    __slots__ = ()
+
+    def set(self, **attrs):
+        self.set_metadata(**attrs)
+        return self
+
+
+def _annotation(name: str, attrs: dict) -> _Annotation:
+    # a TraceMe encodes its keyword arguments only while a profile runs
+    return _Annotation(PROFILER_PREFIX + name, **attrs)
 
 
 @dataclasses.dataclass
@@ -47,23 +79,6 @@ class SpanEvent:
                 "tid": self.tid, "depth": self.depth, "attrs": self.attrs}
 
 
-class _NullSpan:
-    """Shared no-op context manager for disabled tracers."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **attrs):
-        return self
-
-
-NULL_SPAN = _NullSpan()
-
 #: registry series sampled into Perfetto counter tracks by default: the
 #: memory-plane gauges, the data-plane byte/sync counters and the
 #: control-plane cache counters — the series an operator scrubs against
@@ -79,28 +94,33 @@ DEFAULT_COUNTER_TRACK_PREFIXES = (
 class _Span:
     """Live span handle; records a SpanEvent on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_depth",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.attrs = attrs
+        self._ann = _annotation(name, attrs)
 
     def set(self, **attrs):
         """Attach attributes mid-span (e.g. bytes moved, once known)."""
         self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self):
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -143,9 +163,11 @@ class Tracer:
         return st
 
     def span(self, name: str, cat: str = "span", **attrs):
-        """``with tracer.span("compile", plan=...):`` — times the block."""
+        """``with tracer.span("compile", plan=...):`` — times the block,
+        and marks it ``hetu:<name>`` on a running ``jax.profiler``
+        trace's host plane whether or not this tracer records."""
         if not self.enabled:
-            return NULL_SPAN
+            return _annotation(name, attrs)
         return _Span(self, name, cat, attrs)
 
     def complete(self, name: str, dur_s: float, *, cat: str = "span",

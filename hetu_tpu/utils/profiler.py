@@ -1,13 +1,13 @@
-"""Profiling: per-step timing, compile-time separation, device memory,
-XLA trace capture.
+"""Profiling: per-step timing, compile-time separation, device memory.
 
 Parity target: the reference's op profiler (``impl/profiler/profiler.h:25``),
 graph/memory profiler (``graph/profiler.h:40`` — mempool peaks, per-micro-
 batch ``MicroBatchMemoryInfo``) and subgraph fwd/bwd/update timing
 (``subgraph.h:53-56``). On TPU the op/stream layer belongs to XLA, so the
 equivalents are: wall-step statistics with first-step (compile) isolation,
-``device.memory_stats()`` peaks, and ``jax.profiler`` xplane traces for
-op-level drill-down.
+``device.memory_stats()`` peaks; op-level drill-down is a
+``jax.profiler`` trace read through the program's own names
+(``telemetry/device_scopes.py``, ``docs/OBSERVABILITY.md``).
 """
 
 from __future__ import annotations
@@ -96,17 +96,6 @@ def device_memory_stats(device=None) -> dict[str, Any]:
     return {k: stats[k] for k in keep if k in stats}
 
 
-@contextlib.contextmanager
-def xla_trace(logdir: str):
-    """Capture an XLA/xplane trace viewable in TensorBoard/Perfetto —
-    replaces the reference's nsys hook (``rpc/pssh_start.py:55``)."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
 def live_array_bytes() -> int:
     """Total bytes of live device arrays (coarse leak/occupancy check)."""
     return sum(x.nbytes for x in jax.live_arrays())
@@ -116,10 +105,12 @@ def live_array_bytes() -> int:
 #
 # The reference records per-subgraph fwd/bwd/update times via CUDA events on
 # the module tree (``subgraph.h:53-56``, ``Graph::SubGraphProfiling``). XLA
-# fuses across module boundaries inside one jit, so the TPU-native
-# equivalent measures each module *as its own jit* on real shapes — embed /
-# one transformer block / LM head — which is also exactly the decomposition
-# the Galvatron cost model needs for calibration.
+# fuses across module boundaries inside one jit; what each phase costs
+# INSIDE the real step is read off a device trace through the hetu.* named
+# scopes (telemetry/device_scopes.py). What stays here measures each module
+# *as its own jit* on real shapes — embed / one transformer block / LM head
+# — the decomposition the Galvatron cost model calibrates against
+# (tools/galvatron/calibrate.py, its one reader).
 
 @dataclasses.dataclass
 class ModuleTiming:
@@ -234,18 +225,6 @@ def profile_modules(model, params, batch, *, iters: int = 10,
                  warmup=warmup),
         head_bytes))
     return out
-
-
-def format_module_table(timings: list[ModuleTiming]) -> str:
-    lines = [f"{'module':<8} {'n':>3} {'fwd ms':>8} {'fwd+bwd ms':>11} "
-             f"{'params MB':>10}"]
-    for t in timings:
-        lines.append(f"{t.name:<8} {t.count:>3} {t.fwd_ms:>8.2f} "
-                     f"{t.bwd_ms:>11.2f} {t.param_bytes/2**20:>10.1f}")
-    tot_f = sum(t.total_fwd_ms for t in timings)
-    tot_b = sum(t.total_bwd_ms for t in timings)
-    lines.append(f"{'TOTAL':<8} {'':>3} {tot_f:>8.2f} {tot_b:>11.2f}")
-    return "\n".join(lines)
 
 
 def memory_breakdown(state, batch: Optional[dict] = None,

@@ -184,7 +184,6 @@ SLOW_TESTS = {
     "test_calibration_pipeline_cpu",
     "test_topp_sampling_restricts_support",
     "test_unroll_parity",
-    "test_profile_modules_table",
     "test_flash_grads_segment_ids",
     "test_quantized_sharded_checkpoint",
     "test_split_phase_grad_accumulation",

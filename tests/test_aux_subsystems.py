@@ -177,26 +177,6 @@ def test_elastic_recovery_plan_hetero_uses_all_survivors():
     assert isinstance(s1, Strategy)
 
 
-def test_profile_modules_table():
-    """Per-module fwd/bwd timing (subgraph.h:53-56 parity): all entries
-    positive, block count = num_layers, table renders."""
-    from hetu_tpu.models import GPTLMHeadModel
-    from hetu_tpu.utils.profiler import format_module_table, profile_modules
-    cfg = GPTConfig.tiny()
-    model = GPTLMHeadModel(cfg)
-    params = model.init(jax.random.key(0))
-    ids = jax.random.randint(jax.random.key(1), (2, 64), 0, cfg.vocab_size)
-    t = profile_modules(model, params,
-                        {"input_ids": ids, "labels": ids},
-                        iters=2, warmup=1)
-    names = [x.name for x in t]
-    assert names == ["embed", "block", "head"]
-    assert t[1].count == cfg.num_layers
-    assert all(x.fwd_ms > 0 and x.bwd_ms > 0 for x in t)
-    table = format_module_table(t)
-    assert "TOTAL" in table and "block" in table
-
-
 def test_yaml_experiment_configs():
     """YAML configs (SURVEY §5.6 parity) compile to framework objects;
     every shipped example config builds and validates."""

@@ -22,7 +22,7 @@ exactly like the reference's per-block recompute config
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -370,6 +370,53 @@ class ParallelMLP(Module):
         return lora_apply(lora, "fc_out", h, y)
 
 
+def _at_layer(buf, layer):
+    """One layer of a stacked cache leaf, ``buf[layer]`` — a COPY of
+    that layer where ``layer`` is traced: for the dense caches and the
+    gather reference, never for the paged kernel's arena."""
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
+def _scatter_layer_rows(buf, layer, rows, new):
+    """Write ``new``'s rows into the stacked paged leaf ``(layers,
+    n_blocks, block_size, H)`` at ``[layer, rows]``, where a row is
+    ``block * block_size + offset`` and one past the arena drops. The
+    arena merges (hkv, d) into one minor dim (serving/kv_pool.py), so
+    rows take the buffer's. One scatter into the donated buffer: the
+    leaf is neither sliced nor rebuilt."""
+    L, n_blk, blk = buf.shape[:3]
+    flat = buf.reshape((L, n_blk * blk) + buf.shape[3:])
+    flat = flat.at[layer, rows].set(
+        new.reshape((-1,) + buf.shape[3:]).astype(buf.dtype), mode="drop")
+    return flat.reshape(buf.shape)
+
+
+def _scatter_layer_scales(buf, layer, rows, new):
+    """:func:`_scatter_layer_rows` for the int8 arena's scale leaves
+    ``(layers, n_blocks, block_size, hkv)``; returns the stacked leaf
+    and this layer's ``(n_blocks, block_size, hkv)``, which is what the
+    attention then reads. Their minor dim is under a lane tile, so the
+    TPU stores the stack blocks-minor: the scatter and the paged kernel
+    each want a re-tiled copy, and taking the layer out first re-tiles
+    a layer (a tenth of a K leaf's layer) instead of the whole stack
+    per layer. The K/V leaves never take this route."""
+    one = _at_layer(buf, layer)
+    one = _scatter_layer_rows(one[None], 0, rows, new)[0]
+    return jax.lax.dynamic_update_index_in_dim(buf, one, layer, 0), one
+
+
+class LayerKV(NamedTuple):
+    """What the attention gets as ``kv_cache`` from the layer scan
+    (:meth:`StackedBlocks.decode`): the STACKED cache leaves — every
+    layer's, ``(layers, ...)`` — and which layer this call is. The
+    attention writes its rows in place at ``[layer, ...]`` and hands
+    the same stacked leaves back, so the scan carries ONE buffer per
+    leaf and never slices a layer out or stacks one back in (for the
+    paged arena each of those is a copy of a layer's whole leaf)."""
+    leaves: tuple
+    layer: jax.Array
+
+
 class ParallelAttention(Module):
     """Multi-head attention with GQA, RoPE and flash-kernel dispatch, heads
     sharded over tp.
@@ -514,17 +561,18 @@ class ParallelAttention(Module):
                 attn_kernel: str = "reference", pack=None, lora=None):
         """Incremental decoding with a KV cache.
 
-        ``kv_cache``: (k_buf, v_buf) of shape (b, max_len, hkv, d); the
-        write ``index`` arrives via ``positions[:, 0]``-style absolute
-        positions (all rows share the index — batched decode). Replaces
-        the reference's dynamic-concat KV append op (inference path of
-        ``graph/ops``: dynamic concat).
-
-        ``kv_cache``: (k_buf, v_buf) of shape (b, max_len, hkv, d), or
-        the QUANTIZED 4-tuple (k int8, k scales, v int8, v scales) with
-        (b, max_len, hkv, 1) fp32 scales (``generation.init_kv_caches``
+        ``kv_cache``: a :class:`LayerKV` — the STACKED leaves of every
+        layer and this call's layer index. The leaves are (k_buf,
+        v_buf) of shape (layers, b, max_len, hkv, d), or the QUANTIZED
+        4-tuple (k int8, k scales, v int8, v scales) with (layers, b,
+        max_len, hkv, 1) fp32 scales (``generation.init_kv_caches``
         with dtype=jnp.int8) — new rows quantize on write, the read
-        dequant fuses into the attention einsum.
+        dequant fuses into the attention einsum. This layer's rows are
+        written at ``[layer]`` and the stacked leaves come back as the
+        new cache. The write ``index`` arrives via ``positions[:,
+        0]``-style absolute positions (all rows share the index —
+        batched decode). Replaces the reference's dynamic-concat KV
+        append op (inference path of ``graph/ops``: dynamic concat).
 
         ``slot_mask`` switches to PER-ROW decode (the serving engine's
         slot-pooled path): every batch row writes at its own
@@ -535,14 +583,14 @@ class ParallelAttention(Module):
         the caller).
 
         ``block_tables`` (b, W) switches the cache to the PAGED layout:
-        leaves are ``(n_blocks, block_size, hkv*d)`` arenas shared by
-        every row, and row ``r``'s position ``p`` lives at arena row
-        ``block_tables[r, p // bs] * bs + p % bs``. Writes become flat
-        scatters (rows with ``slot_mask=False`` scatter out of bounds
-        and are dropped), reads gather through the table
-        (:func:`~hetu_tpu.ops.attention.gather_block_rows`). Requires
-        ``slot_mask`` (per-row positions are the only meaningful paged
-        mode).
+        leaves are ``(layers, n_blocks, block_size, hkv*d)`` arenas
+        shared by every row, and row ``r``'s position ``p`` lives at
+        arena row ``block_tables[r, p // bs] * bs + p % bs`` of this
+        layer. Writes become scatters at ``[layer, row]`` into the
+        stacked leaf, in place (rows with ``slot_mask=False`` scatter
+        out of bounds and are dropped), reads go through the table at
+        ``(layer, page)``. Requires ``slot_mask`` (per-row positions
+        are the only meaningful paged mode).
 
         ``row_mask`` (b, s) bool refines ``slot_mask`` WITHIN a row's
         ``s`` positions: only masked-true cells write their KV (the
@@ -559,8 +607,9 @@ class ParallelAttention(Module):
         :func:`~hetu_tpu.ops.attention.gather_block_rows`, the
         CPU path), "paged" streams KV tiles through the
         block tables inside the Pallas kernel
-        (:func:`~hetu_tpu.ops.paged_pallas.paged_attention_pallas` —
-        no materialized gather, cost ∝ live context). Resolve requests
+        (:func:`~hetu_tpu.ops.paged_pallas.paged_attention_pallas`,
+        which takes the stacked leaf and the layer — no materialized
+        gather, no slice of the arena, cost ∝ live context). Resolve requests
         with :func:`~hetu_tpu.ops.attention.resolve_decode_kernel`.
 
         ``pack`` switches to the PACKED-PREFILL flash mode
@@ -576,7 +625,8 @@ class ParallelAttention(Module):
                                        pack=pack,
                                        attn_kernel=attn_kernel,
                                        lora=lora)
-        quant = len(kv_cache) == 4
+        leaves, layer = kv_cache
+        quant = len(leaves) == 4
         b, s, _ = x.shape
         per_row = slot_mask is not None
         paged = block_tables is not None
@@ -607,7 +657,7 @@ class ParallelAttention(Module):
             k = apply_rotary(k, cos, sin, positions=pos)
 
         if paged:
-            n_blk, blk = kv_cache[0].shape[0], kv_cache[0].shape[1]
+            n_blk, blk = leaves[0].shape[1], leaves[0].shape[2]
             pos_rows = index[:, None] + jnp.arange(s)[None, :]  # (b, s)
             blk_ids = jnp.take_along_axis(block_tables,
                                           pos_rows // blk, axis=1)
@@ -621,24 +671,21 @@ class ParallelAttention(Module):
 
         def upd(buf, new):
             if paged:
-                # the arena merges (hkv, d) into one minor dim
-                # (serving/kv_pool.py): rows take the buffer's shape
-                flat = buf.reshape((n_blk * blk,) + buf.shape[2:])
-                flat = flat.at[rows].set(
-                    new.reshape((-1,) + buf.shape[2:]).astype(buf.dtype),
-                    mode="drop")
-                return flat.reshape(buf.shape)
+                return _scatter_layer_rows(buf, layer, rows, new)
+            new = new.astype(buf.dtype)
             if per_row:
                 # per-slot scatter: row r writes its s new entries at
                 # index[r]; inactive slots select their old rows back
+                old = _at_layer(buf, layer)
                 written = jax.vmap(
                     lambda bb, nn, ii: jax.lax.dynamic_update_slice_in_dim(
-                        bb, nn, ii, axis=0))(buf, new.astype(buf.dtype),
-                                             index)
-                keep = slot_mask.reshape((b,) + (1,) * (buf.ndim - 1))
-                return jnp.where(keep, written, buf)
-            return jax.lax.dynamic_update_slice_in_dim(
-                buf, new.astype(buf.dtype), index, axis=1)
+                        bb, nn, ii, axis=0))(old, new, index)
+                keep = slot_mask.reshape((b,) + (1,) * (old.ndim - 1))
+                return jax.lax.dynamic_update_index_in_dim(
+                    buf, jnp.where(keep, written, old), layer, 0)
+            at = [jnp.zeros((), jnp.int32)] * buf.ndim
+            at[0], at[2] = layer, jnp.asarray(index, jnp.int32)
+            return jax.lax.dynamic_update_slice(buf, new[None], at)
 
         if quant:
             # int8 KV cache: decode is HBM-bound on the cache read, so
@@ -650,57 +697,60 @@ class ParallelAttention(Module):
             # the fp cache's zeros.
             from hetu_tpu.ops.quantization import (dequantize_int8,
                                                    quantize_int8)
-            kq_b, ks_b, vq_b, vs_b = kv_cache
+            kq_b, ks_b, vq_b, vs_b = leaves
             with jax.named_scope("hetu.kv_arena"):
                 knew_q, knew_s = quantize_int8(k, axis=-1)
                 vnew_q, vnew_s = quantize_int8(v, axis=-1)
-                kq_b, ks_b = upd(kq_b, knew_q), upd(ks_b, knew_s)
-                vq_b, vs_b = upd(vq_b, vnew_q), upd(vs_b, vnew_s)
+                kq_b, vq_b = upd(kq_b, knew_q), upd(vq_b, vnew_q)
+                if paged:
+                    ks_b, ks_l = _scatter_layer_scales(ks_b, layer, rows,
+                                                       knew_s)
+                    vs_b, vs_l = _scatter_layer_scales(vs_b, layer, rows,
+                                                       vnew_s)
+                else:
+                    ks_b, vs_b = upd(ks_b, knew_s), upd(vs_b, vnew_s)
+                    ks_l, vs_l = _at_layer(ks_b, layer), \
+                        _at_layer(vs_b, layer)
             new_cache = (kq_b, ks_b, vq_b, vs_b)
+            arena = dict(k_scale=ks_l, v_scale=vs_l)   # this layer's
+            k_buf, v_buf = kq_b, vq_b
         else:
-            k_buf, v_buf = kv_cache
+            k_buf, v_buf = leaves
             with jax.named_scope("hetu.kv_arena"):
                 k_buf, v_buf = upd(k_buf, k), upd(v_buf, v)
             new_cache = (k_buf, v_buf)
+            arena = {}
 
         if paged and attn_kernel == "paged" and self.causal:
             # the Pallas kernel streams arena tiles through the block
-            # tables — no materialized gather, dead lanes skipped, int8
-            # pages dequantized per tile in VMEM; the _auto wrapper
-            # shard_maps the call over a tp-sharded plan's head axis
-            # (Mosaic kernels cannot be GSPMD-auto-partitioned)
+            # tables at (layer, page) of the stacked leaves — no
+            # materialized gather, no slice of the arena, dead lanes
+            # skipped, int8 pages dequantized per tile in VMEM; the
+            # _auto wrapper shard_maps the call over a tp-sharded plan's
+            # head axis (Mosaic kernels cannot be GSPMD-auto-partitioned)
             from hetu_tpu.ops.paged_pallas import paged_attention_auto
-            if quant:
-                out = paged_attention_auto(
-                    q, kq_b, vq_b, block_tables, index,
-                    k_scale=ks_b, v_scale=vs_b)
-            else:
-                out = paged_attention_auto(
-                    q, k_buf, v_buf, block_tables, index)
+            out = paged_attention_auto(q, k_buf, v_buf, block_tables,
+                                       index, layer=layer, **arena)
         elif paged:
             if attn_kernel == "paged":
                 from hetu_tpu.ops.attention import record_kernel_fallback
                 record_kernel_fallback(
                     "decode_non_causal",
                     "the paged kernel implements causal decode only")
-            # the XLA-gather twin (int8 arenas gather quantized rows +
-            # scales — 1/4 the bytes — and dequantize after); causal
-            # offsets mask both the future and never-written slots
+            # the XLA-gather twin, on this layer's leaves (int8 arenas
+            # gather quantized rows + scales — 1/4 the bytes — and
+            # dequantize after); causal offsets mask both the future and
+            # never-written slots
             from hetu_tpu.ops.paged_pallas import \
                 paged_attention_reference
-            if quant:
-                out = paged_attention_reference(
-                    q, kq_b, vq_b, block_tables, index,
-                    k_scale=ks_b, v_scale=vs_b, causal=self.causal)
-            else:
-                out = paged_attention_reference(
-                    q, k_buf, v_buf, block_tables, index,
-                    causal=self.causal)
+            out = paged_attention_reference(
+                q, _at_layer(k_buf, layer), _at_layer(v_buf, layer),
+                block_tables, index, causal=self.causal, **arena)
         else:
+            k_buf, v_buf = _at_layer(k_buf, layer), _at_layer(v_buf, layer)
             if quant:
-                from hetu_tpu.ops.quantization import dequantize_int8
-                k_buf = dequantize_int8(kq_b, ks_b, q.dtype)
-                v_buf = dequantize_int8(vq_b, vs_b, q.dtype)
+                k_buf = dequantize_int8(k_buf, ks_l, q.dtype)
+                v_buf = dequantize_int8(v_buf, vs_l, q.dtype)
             out = attention_reference(
                 q, k_buf, v_buf, causal=self.causal,
                 q_offset=index, kv_offset=0)
@@ -732,17 +782,20 @@ class ParallelAttention(Module):
           excluded: the intra part owns them).
 
         KV writes stay per-token scatters through the tables (pads drop
-        out of bounds), bit-identical to the per-token reference lane —
-        only the attention READ changes formulation."""
+        out of bounds) at ``[layer, row]`` of the stacked leaves
+        (``kv_cache`` is a :class:`LayerKV`), bit-identical to the
+        per-token reference lane — only the attention READ changes
+        formulation."""
         if not self.causal:
             raise ValueError(
                 "the packed-prefill flash lane requires causal "
                 "attention: its intra-pack/arena-history split relies "
                 "on the causal position mask to keep the two KV sets "
                 "disjoint (use prefill_attn='reference')")
-        quant = len(kv_cache) == 4
+        leaves, layer = kv_cache
+        quant = len(leaves) == 4
         b, C, _ = x.shape
-        n_blk, blk = kv_cache[0].shape[0], kv_cache[0].shape[1]
+        n_blk, blk = leaves[0].shape[1], leaves[0].shape[2]
         q = lora_apply(lora, "q_proj", x,
                        self.q_proj(params["q_proj"], x)).reshape(
             b, C, self.num_heads, self.head_dim)
@@ -763,31 +816,34 @@ class ParallelAttention(Module):
                          n_blk * blk)                    # pad → dropped
 
         def upd(buf, new):
-            flat = buf.reshape((n_blk * blk,) + buf.shape[2:])
-            flat = flat.at[rows].set(
-                new[0].reshape((-1,) + buf.shape[2:]).astype(buf.dtype),
-                mode="drop")
-            return flat.reshape(buf.shape)
+            return _scatter_layer_rows(buf, layer, rows, new[0])
 
         if quant:
             from hetu_tpu.ops.quantization import (dequantize_int8,
                                                    quantize_int8)
-            kq_b, ks_b, vq_b, vs_b = kv_cache
+            kq_b, ks_b, vq_b, vs_b = leaves
             with jax.named_scope("hetu.kv_arena"):
                 knew_q, knew_s = quantize_int8(k, axis=-1)
                 vnew_q, vnew_s = quantize_int8(v, axis=-1)
-                kq_b, ks_b = upd(kq_b, knew_q), upd(ks_b, knew_s)
-                vq_b, vs_b = upd(vq_b, vnew_q), upd(vs_b, vnew_s)
+                kq_b, vq_b = upd(kq_b, knew_q), upd(vq_b, vnew_q)
+                ks_b, ks_l = _scatter_layer_scales(ks_b, layer, rows,
+                                                   knew_s[0])
+                vs_b, vs_l = _scatter_layer_scales(vs_b, layer, rows,
+                                                   vnew_s[0])
             new_cache = (kq_b, ks_b, vq_b, vs_b)
             # the reference per-token lane attends the arena's
             # ROUND-TRIPPED int8 values for in-pack rows — match it
             k = dequantize_int8(knew_q, knew_s, q.dtype)
             v = dequantize_int8(vnew_q, vnew_s, q.dtype)
+            arena = dict(k_scale=ks_l, v_scale=vs_l)   # this layer's
+            ka, va = kq_b, vq_b
         else:
-            k_b, v_b = kv_cache
+            k_b, v_b = leaves
             with jax.named_scope("hetu.kv_arena"):
                 k_b, v_b = upd(k_b, k), upd(v_b, v)
             new_cache = (k_b, v_b)
+            arena = {}
+            ka, va = k_b, v_b
 
         from hetu_tpu.ops.attention import attention_with_lse
         from hetu_tpu.ops.paged_pallas import (
@@ -800,20 +856,14 @@ class ParallelAttention(Module):
 
         qh = q[0][:, None]                       # (C, 1, hq, d) rows
         hist_off = pack["hist"].astype(jnp.int32) - 1   # kpos <= hist-1
-        if quant:
-            arena = dict(k_scale=ks_b, v_scale=vs_b)
-            ka, va = kq_b, vq_b
-        else:
-            arena = {}
-            ka, va = k_b, v_b
         if attn_kernel == "paged":
             hist, lse_h = paged_attention_auto(
-                qh, ka, va, block_tables, hist_off, return_lse=True,
-                **arena)
+                qh, ka, va, block_tables, hist_off, layer=layer,
+                return_lse=True, **arena)
         else:
             hist, lse_h = paged_attention_reference(
-                qh, ka, va, block_tables, hist_off, return_lse=True,
-                **arena)
+                qh, _at_layer(ka, layer), _at_layer(va, layer),
+                block_tables, hist_off, return_lse=True, **arena)
         hist = hist[:, 0][None]                  # (1, C, hq, d)
         lse_h = lse_h[:, :, 0].T[None]           # (C, hq, 1) → (1, hq, C)
         out = combine_attention_lse(intra, lse_i, hist, lse_h)
@@ -1110,8 +1160,15 @@ class StackedBlocks(Module):
 
     def decode(self, params, x, caches, *, w8a8_mask=None,
                w8a8_wq=None, lora=None, **kwargs):
-        """Incremental decoding: scan layers threading per-layer KV caches
-        (leaves shaped (layers, b, max_len, hkv, d)).
+        """Incremental decoding: scan layers CARRYING the stacked KV
+        caches (leaves shaped (layers, b, max_len, hkv, d), or the
+        paged arena's (layers, n_blocks, block_size, hkv*d)). The
+        caches are part of the scan's carry beside the activations, and
+        layer ``l``'s attention gets all of them with its index
+        (:class:`LayerKV`), writes its rows at ``[l]`` and reads at
+        ``[l]`` — as xs and ys the scan would slice a layer's leaf out
+        and stack it back per layer, two copies of a leaf as large as
+        the arena is, and hold a second arena for the stacked output.
 
         ``w8a8_mask`` ((layers,) bool, optional) rides the scan as xs:
         layer ``l``'s decode FFN takes the W8A8 int8 lane iff
@@ -1129,7 +1186,8 @@ class StackedBlocks(Module):
         as xs (each layer sees its (P, ...) slice) while the per-token
         page ids close over the body; each layer's targeted
         projections add the :func:`lora_apply` BGMV delta."""
-        xs = {"p": params, "c": caches}
+        xs = {"p": params,
+              "layer": jnp.arange(self.num_layers, dtype=jnp.int32)}
         lora_ids = None
         if w8a8_mask is not None:
             xs["w8a8"] = jnp.asarray(w8a8_mask, bool)
@@ -1139,7 +1197,8 @@ class StackedBlocks(Module):
             xs["lora"] = lora["pages"]
             lora_ids = lora["ids"]
 
-        def body(h, inputs):
+        def body(carry, inputs):
+            h, caches = carry
             kw = dict(kwargs)
             if "w8a8" in inputs:
                 kw["w8a8"] = inputs["w8a8"]
@@ -1147,12 +1206,13 @@ class StackedBlocks(Module):
                 kw["w8a8_wq"] = inputs["wq"]
             if "lora" in inputs:
                 kw["lora"] = {"ids": lora_ids, "pages": inputs["lora"]}
-            h, new_cache = self._block(inputs["p"], h,
-                                       kv_cache=inputs["c"], **kw)
-            return h, new_cache
+            return self._block(
+                inputs["p"], h,
+                kv_cache=LayerKV(caches, inputs["layer"]),
+                **kw), None
 
-        x, new_caches = jax.lax.scan(body, x, xs)
-        return x, new_caches
+        (x, caches), _ = jax.lax.scan(body, (x, tuple(caches)), xs)
+        return x, caches
 
     def prefill(self, params, x, *, positions=None, segment_ids=None,
                 attn_impl: str = "auto"):

@@ -15,6 +15,14 @@ use:
   operand, so each grid step's K/V BlockSpec ``index_map`` reads the
   table and DMAs the *physical* arena page straight into VMEM — the
   indirection costs an SMEM lookup, not a materialized gather;
+- **layer-indexed pages**: the arena operand is the STACKED leaf
+  ``(layers, n_blocks, block_size, hkv*d)`` and the layer is one more
+  scalar-prefetch operand, so a page's ``index_map`` is ``(layer,
+  table[s, w], 0, 0)`` — the layer scan of the fused serving step
+  carries the whole arena and never slices a layer out for the kernel
+  (a slice is a copy of the layer's leaf, per layer, per lane). A 3-D
+  ``(n_blocks, block_size, hkv*d)`` arena is the one-layer case of the
+  same call;
 - **online softmax** over table lanes (the KV grid axis is
   "arbitrary"): running max / denominator / accumulator live in VMEM
   scratch exactly like ``flash_pallas``;
@@ -83,13 +91,15 @@ def default_pages_per_step(block_size: int) -> int:
     return max(1, min(8, 128 // max(1, int(block_size))))
 
 
-def _paged_kernel(tbl_ref, off_ref, q_ref, *refs, rows, g, bs, L,
-                  hkv, n_steps, quant):
+def _paged_kernel(tbl_ref, off_ref, lyr_ref, q_ref, *refs, rows, g, bs,
+                  L, hkv, n_steps, quant):
     """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
-    ``(bs, hkv*d)``, every kv head — a TPU block's last two dims must be
-    (8, 128)-tiled or span the array's, so heads are lane slices taken
-    inside the kernel). Online softmax across chunks (grid axis 1 is
-    "arbitrary")."""
+    ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
+    page dims are squeezed out of the block — every kv head: a TPU
+    block's last two dims must be (8, 128)-tiled or span the array's,
+    so heads are lane slices taken inside the kernel). Online softmax
+    across chunks (grid axis 1 is "arbitrary")."""
+    del lyr_ref                     # read by the page index maps only
     s_i = pl.program_id(0)
     w = pl.program_id(1)
 
@@ -130,13 +140,13 @@ def _paged_kernel(tbl_ref, off_ref, q_ref, *refs, rows, g, bs, L,
                 head = slice(h * d, (h + 1) * d)
                 if quant:
                     # (bs, d) dequant in VMEM
-                    k = k_pages[j][0, :, head].astype(jnp.float32) \
-                        * ks_pages[j][0, :, h:h + 1]
-                    v = v_pages[j][0, :, head].astype(jnp.float32) \
-                        * vs_pages[j][0, :, h:h + 1]
+                    k = k_pages[j][:, head].astype(jnp.float32) \
+                        * ks_pages[j][:, h:h + 1]
+                    v = v_pages[j][:, head].astype(jnp.float32) \
+                        * vs_pages[j][:, h:h + 1]
                 else:
-                    k = k_pages[j][0, :, head]   # (bs, d)
-                    v = v_pages[j][0, :, head]
+                    k = k_pages[j][:, head]      # (bs, d)
+                    v = v_pages[j][:, head]
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -172,8 +182,18 @@ def _paged_kernel(tbl_ref, off_ref, q_ref, *refs, rows, g, bs, L,
         lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
 
+def _stacked(x):
+    """An arena operand as a stacked ``(layers, n_blocks, block_size,
+    H)`` leaf: one layer's 3-D leaf is the one-layer stack."""
+    if x.ndim not in (3, 4):
+        raise ValueError(
+            f"a paged arena operand is (n_blocks, block_size, H) or "
+            f"(layers, n_blocks, block_size, H); got {x.shape}")
+    return x[None] if x.ndim == 3 else x
+
+
 def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
-                           k_scale=None, v_scale=None,
+                           layer=None, k_scale=None, v_scale=None,
                            scale: Optional[float] = None,
                            pages_per_step: Optional[int] = None,
                            interpret: Optional[bool] = None,
@@ -184,12 +204,20 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
       k+1 for the speculative verify lane, C×1 for the packed-prefill
       per-token rows); row ``i`` of slot ``s`` attends absolute
       positions ``<= q_offset[s] + i``.
-    - ``k``/``v``: the paged arena as stored, ``(n_blocks, block_size,
-      hkv*d)`` (``models.generation.init_paged_caches`` — the ONE
-      layout: a ``(hkv, d)`` minor pair would make the TPU re-tile the
-      whole arena around the kernel); int8 when ``k_scale``/``v_scale``
-      (``(n_blocks, block_size, hkv)`` fp32) are given — pages
-      dequantize per tile in VMEM.
+    - ``k``/``v``: the paged arena as stored — the STACKED leaves
+      ``(layers, n_blocks, block_size, hkv*d)`` of
+      ``models.generation.init_paged_caches`` with ``layer`` (an int32
+      scalar, traced inside the layer scan) naming the layer to read,
+      or one layer's ``(n_blocks, block_size, hkv*d)`` without
+      ``layer``. The minor dim is the ONE layout: a ``(hkv, d)`` pair
+      would make the TPU re-tile the whole arena around the kernel.
+      int8 when ``k_scale``/``v_scale`` (minor ``hkv``, fp32) are
+      given — pages dequantize per tile in VMEM. Each operand's rank
+      says whether ``layer`` indexes it: the scales may be ONE layer's
+      3-D leaves beside a stacked ``k``/``v`` (their minor dim is under
+      a lane tile, so the TPU stores the stack blocks-minor and only a
+      re-tiled copy has whole pages — ``ParallelAttention._decode``
+      re-tiles one layer, not the stack).
     - ``block_tables``: ``(S, W)`` int32 — logical lane ``w`` of slot
       ``s`` holds positions ``[w*block_size, (w+1)*block_size)`` at
       physical page ``block_tables[s, w]``.
@@ -201,11 +229,14 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     :func:`paged_attention_reference` up to fp associativity.
     """
     S, R, hq, d = q.shape
-    if k.ndim != 3:
+    if (layer is None) != (k.ndim == 3) or k.ndim != v.ndim:
         raise ValueError(
-            f"the paged arena is (n_blocks, block_size, hkv*d); got "
-            f"{k.shape}")
-    n_blocks, bs, hkv = k.shape[0], k.shape[1], k.shape[2] // d
+            f"layer= goes with stacked (layers, n_blocks, block_size, "
+            f"hkv*d) k/v and only with them; got k {k.shape}, v "
+            f"{v.shape}, layer={layer!r}")
+    layer = jnp.asarray(0 if layer is None else layer,
+                        jnp.int32).reshape(1)
+    bs, hkv = k.shape[-2], k.shape[-1] // d
     g = hq // hkv
     rows = R * g
     quant = k_scale is not None
@@ -231,40 +262,38 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         .reshape(S, hkv, rows, d)
 
     q_spec = pl.BlockSpec((1, hkv, rows, d),
-                          lambda s, w, tbl, off: (s, 0, 0, 0))
+                          lambda s, w, tbl, off, lyr: (s, 0, 0, 0))
 
-    def page_spec(j, scalar=False):
-        # one whole page, all kv heads: the block's last two dims span
-        # the arena's (block_size, hkv*d) — a one-head block would
-        # slice the minor dims below the TPU's (8, 128) tile
+    def page_spec(j, x):
+        # one whole page of one layer, all kv heads: the block's last
+        # two dims span the arena's (block_size, hkv*d) — a one-head
+        # block would slice the minor dims below the TPU's (8, 128)
+        # tile. A 3-D operand is one layer already: its pages are read
+        # at layer 0 of the one-layer stack.
+        stacked = x.ndim == 4
         return pl.BlockSpec(
-            (1, bs, hkv if scalar else hkv * d),
-            lambda s, w, tbl, off, j=j: (tbl[s, w * L + j], 0, 0))
+            (None, None, bs, x.shape[-1]),
+            lambda s, w, tbl, off, lyr, j=j:
+                (lyr[0] if stacked else 0, tbl[s, w * L + j], 0, 0))
 
     in_specs = [q_spec]
     args = [qh]
-    in_specs += [page_spec(j) for j in range(L)]
-    args += [k] * L
-    in_specs += [page_spec(j) for j in range(L)]
-    args += [v] * L
-    if quant:
-        in_specs += [page_spec(j, scalar=True) for j in range(L)]
-        args += [k_scale] * L
-        in_specs += [page_spec(j, scalar=True) for j in range(L)]
-        args += [v_scale] * L
+    for x in (k, v) + ((k_scale, v_scale) if quant else ()):
+        in_specs += [page_spec(j, x) for j in range(L)]
+        args += [_stacked(x)] * L
 
     out_specs = [
         pl.BlockSpec((1, hkv, rows, d),
-                     lambda s, w, tbl, off: (s, 0, 0, 0)),
+                     lambda s, w, tbl, off, lyr: (s, 0, 0, 0)),
         pl.BlockSpec((1, hkv, rows, NUM_LANES),
-                     lambda s, w, tbl, off: (s, 0, 0, 0)),
+                     lambda s, w, tbl, off, lyr: (s, 0, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((S, hkv, rows, d), q.dtype),
         jax.ShapeDtypeStruct((S, hkv, rows, NUM_LANES), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, n_steps),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -284,7 +313,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
             name="hetu_paged_attn",
-        )(block_tables, q_offset, *args)
+        )(block_tables, q_offset, layer, *args)
 
     # (S, hkv, R*g, d) → (S, R, hq, d)
     out = out.reshape(S, hkv, R, g, d).transpose(0, 2, 1, 3, 4) \
@@ -299,7 +328,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
 
 
 def paged_attention_auto(q, k, v, block_tables, q_offset, *,
-                         k_scale=None, v_scale=None,
+                         layer=None, k_scale=None, v_scale=None,
                          scale: Optional[float] = None,
                          pages_per_step: Optional[int] = None,
                          interpret: Optional[bool] = None,
@@ -312,58 +341,57 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
     This wrapper closes that gap: when the current plan binds a tp axis
     of size > 1 and both head counts divide it, the kernel call is
     wrapped in ``shard_map`` over that axis — each shard streams only
-    its LOCAL head slice of the paged arena (block tables and offsets
-    ride replicated; the GQA group layout is head-major, so an even
-    hkv split keeps q-head groups contiguous per shard). Everything
-    else (no context, tp == 1, ragged heads — which
+    its LOCAL head slice of the stacked arena (block tables, offsets
+    and the layer ride replicated; the GQA group layout is head-major,
+    so an even hkv split keeps q-head groups contiguous per shard).
+    Everything else (no context, tp == 1, ragged heads — which
     ``resolve_decode_kernel`` already degrades) is the plain call."""
     from hetu_tpu.parallel.sharding import (
         _axis_size, current_act_sharding,
     )
 
-    def plain(q=q, k=k, v=v, tbl=block_tables, off=q_offset,
-              ks=k_scale, vs=v_scale):
+    def call(q, k, v, tbl, off, layer, ks, vs):
         return paged_attention_pallas(
-            q, k, v, tbl, off, k_scale=ks, v_scale=vs, scale=scale,
-            pages_per_step=pages_per_step, interpret=interpret,
-            return_lse=return_lse)
+            q, k, v, tbl, off, layer=layer, k_scale=ks, v_scale=vs,
+            scale=scale, pages_per_step=pages_per_step,
+            interpret=interpret, return_lse=return_lse)
 
     ctx = current_act_sharding()
-    if ctx is None:
-        return plain()
-    mesh = ctx.mesh
-    head_ax = ctx.tp if isinstance(ctx.tp, str) else None
-    nh = _axis_size(mesh, head_ax)
-    if nh <= 1:
-        return plain()
-    hq, hkv = q.shape[2], k.shape[2] // q.shape[3]
-    if hq % nh or hkv % nh:
+    head_ax = ctx.tp if ctx is not None and isinstance(ctx.tp, str) \
+        else None
+    nh = _axis_size(ctx.mesh, head_ax) if ctx is not None else 1
+    hq, hkv = q.shape[2], k.shape[-1] // q.shape[3]
+    if nh <= 1 or hq % nh or hkv % nh:
         # resolve_decode_kernel degrades ragged head counts before the
         # trace ever reaches here; keep the plain call as the safe twin
-        return plain()
+        return call(q, k, v, block_tables, q_offset, layer, k_scale,
+                    v_scale)
 
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    head_spec = P(None, None, head_ax, None)   # q/out: heads dim 2
-    page_spec = P(None, None, head_ax)         # arena: head-major dim 2
-    in_specs = (head_spec, page_spec, page_spec, P(None, None), P(None))
-    args = (q, k, v, block_tables, jnp.asarray(q_offset, jnp.int32))
+    if layer is None:                # one layer: the one-layer stack
+        k, v, layer = k[None], v[None], 0
+
+    def page_spec(x):                # arena: head-major minor dim
+        return P(*(None,) * (x.ndim - 1), head_ax)
+
+    head_spec = P(None, None, head_ax, None)     # q/out: heads dim 2
+    in_specs = (head_spec, page_spec(k), page_spec(v), P(None, None),
+                P(None), P(None))
+    args = (q, k, v, block_tables, jnp.asarray(q_offset, jnp.int32),
+            jnp.asarray(layer, jnp.int32).reshape(1))
     if k_scale is not None:
-        in_specs += (page_spec, page_spec)
+        in_specs += (page_spec(k_scale), page_spec(v_scale))
         args += (k_scale, v_scale)
     out_specs = (head_spec, P(None, head_ax, None)) if return_lse \
         else head_spec
 
-    def local(q, k, v, tbl, off, *scales):
-        ks, vs = scales if scales else (None, None)
-        return paged_attention_pallas(
-            q, k, v, tbl, off, k_scale=ks, v_scale=vs, scale=scale,
-            pages_per_step=pages_per_step, interpret=interpret,
-            return_lse=return_lse)
+    def local(q, k, v, tbl, off, lyr, ks=None, vs=None):
+        return call(q, k, v, tbl, off, lyr[0], ks, vs)
 
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, axis_names=set(mesh.shape),
+    fn = shard_map(local, mesh=ctx.mesh, in_specs=in_specs,
+                   out_specs=out_specs, axis_names=set(ctx.mesh.shape),
                    check_vma=False)
     return fn(*args)
 

@@ -724,15 +724,27 @@ class ServingEngine:
             record_trace("serving_step")    # churn must never re-enter
 
             # copy-on-write block copies for this iteration's partial
-            # prefix hits: dst indexes are the arena size (dropped) on
-            # unused lanes, and the whole pass is cond-gated — the
-            # common decode-only iteration never pays the per-leaf
-            # gather/scatter. The copies land BEFORE any lane writes.
+            # prefix hits, and the whole pass is cond-gated — the
+            # common decode-only iteration never pays it. The copies
+            # land BEFORE any lane writes. Lane by lane, a block at a
+            # time, in place: a gather of all lanes' blocks makes XLA
+            # slice or re-tile the whole leaf first (gpt2-large's
+            # 1280-wide leaf in 384-wide strips, the int8 arena's
+            # scales), which the step would hold as temporaries even
+            # where the pass never runs. An unused lane (dst = the
+            # arena's size) rewrites its source block with itself; a
+            # dst is a fresh private block, never a later lane's src.
             def apply_cow(cs):
-                def one(c):
-                    src = jnp.take(c, cow["src"], axis=1)
-                    return c.at[:, cow["dst"]].set(src, mode="drop")
-                return jax.tree.map(one, cs)
+                def lane(i, cs):
+                    src, dst = cow["src"][i], cow["dst"][i]
+
+                    def one(c):
+                        blk = jax.lax.dynamic_slice_in_dim(c, src, 1, 1)
+                        return jax.lax.dynamic_update_slice_in_dim(
+                            c, blk, jnp.where(dst < c.shape[1], dst, src),
+                            1)
+                    return jax.tree.map(one, cs)
+                return jax.lax.fori_loop(0, cow["src"].shape[0], lane, cs)
 
             # device scopes (telemetry/device_scopes.py) go around the
             # cond CALLS: a decorated branch function costs seconds of

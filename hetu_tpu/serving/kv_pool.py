@@ -12,9 +12,15 @@ discipline:
   (heads merged into the minor dim — the TPU-tileable page layout),
   allocated once; a request maps onto a per-slot BLOCK TABLE (fixed
   ``max_len/block_size`` width, padded with the null block 0), and the
-  compiled step indexes KV through a gather on the table
-  (``ops.attention.gather_block_rows``) — tables are DATA, never
-  shapes, so block churn never recompiles;
+  compiled step indexes KV through the table — tables are DATA, never
+  shapes, so block churn never recompiles. Inside the fused step the
+  arena stays this ONE donated buffer: the layer scan carries the
+  stacked leaves (``nn.parallel.StackedBlocks.decode``), layer ``l``
+  scatters its new rows at ``[l, block*block_size + offset]`` and
+  reads pages at ``(l, block)`` — the paged kernel takes the stacked
+  leaf and the layer (``ops.paged_pallas``), the CPU path gathers
+  from ``leaf[l]`` (``ops.attention.gather_block_rows``). No layer's
+  leaf is sliced out or stacked back;
 - blocks are refcounted (:class:`BlockManager`): the radix-tree prefix
   cache (``serving/prefix_cache.py``) maps one physical block into many
   slots' tables, so a fleet-wide system prompt is prefilled once and

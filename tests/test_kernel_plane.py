@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from hetu_tpu import telemetry
 from hetu_tpu.models import GPTConfig, GPTLMHeadModel, generate
 from hetu_tpu.ops.paged_pallas import (
-    combine_attention_lse, paged_attention_pallas,
+    combine_attention_lse, paged_attention_auto, paged_attention_pallas,
     paged_attention_reference,
 )
 
@@ -94,6 +94,120 @@ def test_paged_kernel_int8_arena_lane():
     ref = paged_attention_reference(q, kq, vq, tbl, off,
                                     k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5)
+
+
+def _stacked_arena(rng, layers, *, dtype=jnp.float32, **kw):
+    """``_arena`` with ``layers`` DIFFERENT layers stacked: what the
+    layer scan carries. int8 → (q, kq, vq, tbl, scales dict)."""
+    from hetu_tpu.ops.quantization import quantize_int8
+    quant = dtype == jnp.int8
+    q, _, _, tbl = _arena(rng, dtype=jnp.float32 if quant else dtype,
+                          **kw)
+    n_blocks, bs = kw.get("n_blocks", 9), kw.get("bs", 4)
+    hkv, d = kw.get("hkv", 2), kw.get("d", 16)
+    k, v = (jnp.asarray(rng.normal(size=(layers, n_blocks, bs, hkv, d)),
+                        jnp.float32) for _ in range(2))
+    if not quant:
+        return (q, k.reshape(k.shape[:3] + (-1,)).astype(dtype),
+                v.reshape(v.shape[:3] + (-1,)).astype(dtype), tbl, {})
+    merge = lambda x: x.reshape(x.shape[:3] + (-1,))   # noqa: E731
+    (kq, ks), (vq, vs) = (map(merge, quantize_int8(x, axis=-1))
+                          for x in (k, v))
+    return q, kq, vq, tbl, dict(k_scale=ks, v_scale=vs)
+
+
+@pytest.mark.parametrize("rows", [1, 3], ids=["decode", "verify3"])
+@pytest.mark.parametrize("arena", ["bf16", "int8", "int8_layer_scales"])
+def test_paged_kernel_layer_indexed_in_a_scan(arena, rows):
+    """The stacked 4-D arena read at a TRACED layer inside a layer scan
+    (how ``StackedBlocks.decode`` carries it) == the gather oracle on
+    ``leaf[layer]``, layer by layer, on layers that differ — outputs
+    and LSE. ``int8_layer_scales``: the scales as ONE layer's 3-D
+    leaves beside the stacked int8 pages, the fused step's form."""
+    layers = 3
+    rng = np.random.default_rng(11)
+    dtype = jnp.bfloat16 if arena == "bf16" else jnp.int8
+    q, k, v, tbl, scales = _stacked_arena(rng, layers, dtype=dtype,
+                                          R=rows)
+    off = jnp.asarray([0, 5, 17], jnp.int32)
+
+    def one(_, layer):
+        sc = {n: jax.lax.dynamic_index_in_dim(x, layer, 0, False)
+              for n, x in scales.items()} \
+            if arena == "int8_layer_scales" else scales
+        return None, paged_attention_pallas(q, k, v, tbl, off,
+                                            layer=layer, return_lse=True,
+                                            **sc)
+
+    _, (outs, lses) = jax.jit(lambda: jax.lax.scan(
+        one, None, jnp.arange(layers, dtype=jnp.int32)))()
+    tol = 2e-2 if arena == "bf16" else 1e-5
+    for l in range(layers):
+        ref, lse_r = paged_attention_reference(
+            q, k[l], v[l], tbl, off, return_lse=True,
+            **{n: x[l] for n, x in scales.items()})
+        np.testing.assert_allclose(
+            np.asarray(outs[l], np.float32), np.asarray(ref, np.float32),
+            atol=tol)
+        np.testing.assert_allclose(np.asarray(lses[l]),
+                                   np.asarray(lse_r), atol=tol)
+    # the layers really differ: layer 0's answer is not layer 1's
+    assert not np.allclose(np.asarray(outs[0], np.float32),
+                           np.asarray(outs[1], np.float32), atol=tol)
+
+
+def test_paged_kernel_one_layer_arena_is_the_stacked_call():
+    """The 3-D arena without ``layer`` returns what it returned: the
+    same numbers, bit for bit, as that layer of a stack read at
+    ``layer`` — and the two forms do not mix."""
+    rng = np.random.default_rng(12)
+    q, k, v, tbl, _ = _stacked_arena(rng, 2, R=2)
+    off = jnp.asarray([3, 0, 9], jnp.int32)
+    for l in range(2):
+        flat, lse_f = paged_attention_pallas(q, k[l], v[l], tbl, off,
+                                             return_lse=True)
+        stacked, lse_s = paged_attention_pallas(q, k, v, tbl, off,
+                                                layer=l, return_lse=True)
+        assert np.array_equal(np.asarray(flat), np.asarray(stacked))
+        assert np.array_equal(np.asarray(lse_f), np.asarray(lse_s))
+        ref = paged_attention_reference(q, k[l], v[l], tbl, off)
+        np.testing.assert_allclose(np.asarray(flat), np.asarray(ref),
+                                   atol=1e-5)
+    with pytest.raises(ValueError, match="layer="):
+        paged_attention_pallas(q, k, v, tbl, off)          # no layer
+    with pytest.raises(ValueError, match="layer="):
+        paged_attention_pallas(q, k[0], v[0], tbl, off, layer=0)
+
+
+def test_paged_attention_auto_stacked_arena_under_tp_mesh(gpt):
+    """Under a tp=2 plan the wrapper shard_maps the kernel over the
+    head axis with the STACKED arena's specs (leading layers dim, the
+    layer replicated): each shard reads its head slice at (layer,
+    page), and the result is the oracle's on ``leaf[layer]``."""
+    from hetu_tpu import optim
+    from hetu_tpu.engine import make_plan
+    from hetu_tpu.parallel.strategy import Strategy
+    _, model, _ = gpt
+    plan = make_plan(model, optim.adamw(1e-3), Strategy(tp=2))
+    rng = np.random.default_rng(13)
+    q, k, v, tbl, _ = _stacked_arena(rng, 2, R=2)
+    off = jnp.asarray([3, 0, 9], jnp.int32)
+
+    @jax.jit
+    def f(q, k, v, layer):
+        return paged_attention_auto(q, k, v, tbl, off, layer=layer,
+                                    return_lse=True)
+
+    with plan.act:
+        out, lse = f(q, k, v, jnp.asarray(1, jnp.int32))
+        assert "shard_map" in str(jax.make_jaxpr(f)(
+            q, k, v, jnp.asarray(1, jnp.int32)))
+    ref, lse_r = paged_attention_reference(q, k[1], v[1], tbl, off,
+                                           return_lse=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r),
                                atol=1e-5)
 
 

@@ -91,6 +91,72 @@ def test_paged_decode_gqa_d128_compiles_for_v5e(one_chip):
                                       kv_heads=8, head_dim=128)
 
 
+def test_paged_decode_one_layer_arena_compiles_for_v5e(one_chip):
+    """The 3-D ``(n_blocks, block_size, hkv*d)`` arena without ``layer``
+    is the one-layer case of the same call."""
+    from workloads.aot_check import check_paged
+    assert "compile_s" in check_paged(list(one_chip.device_set),
+                                      layers=None)
+
+
+_XS_YS_SCAN = """
+  %gte.5 = bf16[12,600,16,768]{3,2,1,0:T(8,128)(2,1)} get-tuple-element(%p), index=1
+  %constant_dynamic-slice_fusion.31 = bf16[1,600,16,768]{3,2,1,0:T(8,128)(2,1)S(1)} fusion(%gte.5, %select_n.3), kind=kLoop, calls=%fused_computation.1
+  %bitcast.7 = bf16[1,600,16,768]{3,2,1,0:T(8,128)(2,1)} bitcast(%fusion.9)
+  %constant_dynamic-update-slice_fusion.10 = bf16[12,600,16,768]{3,2,1,0:T(8,128)(2,1)} fusion(%gte.5, %bitcast.7, %select_n.3), kind=kLoop, calls=%fused_computation.2
+  %copy.259 = bf16[12,600,16,768]{3,2,1,0:T(8,128)(2,1)} copy(%gte.5)
+  %copy-start.8 = (bf16[1024,768]{1,0:T(8,128)(2,1)S(1)}, bf16[1024,768]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%wpe)
+"""
+_CARRIED = """
+  %gte.5 = bf16[12,600,16,768]{3,2,1,0:T(8,128)(2,1)} get-tuple-element(%p), index=1
+  %dynamic_slice.3 = bf16[12,1,16,768]{3,2,1,0:T(8,128)(2,1)} dynamic-slice(%gte.5, %c0, %src, %c0, %c0), dynamic_slice_sizes={12,1,16,768}
+  ROOT %dynamic_update_slice.15 = bf16[12,600,16,768]{3,2,1,0:T(8,128)(2,1)} dynamic-update-slice(%gte.5, %dynamic_slice.3, %c0, %dst, %c0, %c0)
+  %fusion.479 = bf16[12,9600,768]{2,1,0:T(8,128)(2,1)} fusion(%bitcast.469, %fusion.477, %add_convert_fusion.10), kind=kCustom, calls=%fused_computation.28
+"""
+
+
+def test_arena_moves_reads_the_copies_of_the_xs_ys_scan():
+    """The guard itself: on the instructions the xs/ys layer scan
+    compiled to (PERF.md, PR 24) it names the per-layer slice, the
+    update that writes a layer's leaf into the stacked output and the
+    whole-arena copy, and not a small table's prefetch; on the carried
+    arena's it names nothing — an in-place update of one block (the CoW
+    pass) and the row scatter move no leaf."""
+    from workloads.aot_check import arena_moves
+    leaf = 600 * 16 * 768
+    assert sorted(arena_moves(_XS_YS_SCAN, leaf)) == [
+        "constant_dynamic-slice_fusion.31",
+        "constant_dynamic-update-slice_fusion.10", "copy.259"]
+    assert arena_moves(_CARRIED, leaf) == {}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("lane", ["decode", "prefill"])
+def test_serving_lane_keeps_the_arena_in_place_on_v5e(one_chip, lane,
+                                                      dtype):
+    """The fused serving step's lanes over a donated GPT-2-small arena:
+    the layer scan carries it, so the optimized HLO has no copy, slice
+    or fusion of them whose result holds a layer's leaf, and the
+    program's temporaries stay below one layer's leaf (they were a
+    whole second arena while the caches rode the scan as xs and ys)."""
+    from workloads.aot_check import check_serving_lane
+    r = check_serving_lane(list(one_chip.device_set), lane=lane,
+                           dtype=dtype)
+    assert r["arena_moves"] == {}, r
+    assert r["temp_bytes"] < r["layer_leaf_bytes"], r
+
+
+def test_fused_serving_step_keeps_the_arena_in_place_on_v5e(one_chip):
+    """The REAL fused step (CoW pass, both lanes, sampling) at the chat
+    cell's sizes — 148 slots, 9,473 blocks: nothing moves a layer's
+    leaf, and the temporaries (logits, sampling) stay below one."""
+    from workloads.aot_check import check_serving_step
+    r = check_serving_step(list(one_chip.device_set))
+    assert r["arena_moves"] == {}, r
+    assert r["temp_bytes"] < r["layer_leaf_bytes"], r
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "fp32"])
 def test_packed_prefill_lane_compiles_for_v5e(one_chip, dtype):

@@ -15,13 +15,13 @@ import jax.numpy as jnp
 
 
 def scan_loop(fn, n_iters: int):
-    """jit(run(q, k, v)) executing ``fn`` n_iters times, iterations
-    chained through the first argument. ``fn(q, k, v) -> out`` with out
-    broadcast-compatible to q."""
+    """jit(run(q, k, v, *rest)) executing ``fn`` n_iters times,
+    iterations chained through the first argument. ``fn(q, k, v, *rest)
+    -> out`` with out broadcast-compatible to q."""
 
-    def run(q, k, v):
+    def run(q, k, v, *rest):
         def body(carry, _):
-            return fn(q + 1e-30 * carry, k, v), None
+            return fn(q + 1e-30 * carry, k, v, *rest), None
         out, _ = jax.lax.scan(body, jnp.zeros_like(q), None,
                               length=n_iters)
         return out

@@ -33,6 +33,8 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import contextlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,47 +115,242 @@ def _compile_kernel(f, args):
 
 def check_paged(devs, *, dtype=jnp.bfloat16, rows=1, return_lse=False,
                 slots=8, n_blocks=2048, block_size=16, table_width=64,
-                heads=12, kv_heads=None, head_dim=64):
+                heads=12, kv_heads=None, head_dim=64, layers=12):
     """The paged decode kernel over an arena in its stored layout
-    (``serving/kv_pool.py``: ``(n_blocks, block_size, hkv*d)``, int8
-    scales ``(n_blocks, block_size, hkv)``) — by default GPT-2 small's.
-    ``rows``: 1 = classic decode, k+1 = the speculative verify lane."""
+    (``serving/kv_pool.py``: the stacked ``(layers, n_blocks,
+    block_size, hkv*d)`` leaf read at a traced ``layer``, as the fused
+    serving step calls it; int8 scales ``(..., hkv)``) — by default
+    GPT-2 small's. ``layers=None`` is one layer's 3-D arena, the
+    one-layer case of the same call. ``rows``: 1 = classic decode, k+1
+    = the speculative verify lane."""
     from hetu_tpu.ops.paged_pallas import paged_attention_pallas
     mesh = _one_dev_mesh(devs)
     hkv = kv_heads or heads
     quant = jnp.dtype(dtype) == jnp.int8
+    lead = (n_blocks,) if layers is None else (layers, n_blocks)
     q = _sds((slots, rows, heads, head_dim),
              jnp.bfloat16 if quant else dtype, mesh)
-    page = _sds((n_blocks, block_size, hkv * head_dim), dtype, mesh)
+    page = _sds(lead + (block_size, hkv * head_dim), dtype, mesh)
     args = [q, page, page, _sds((slots, table_width), jnp.int32, mesh),
-            _sds((slots,), jnp.int32, mesh)]
+            _sds((slots,), jnp.int32, mesh), _sds((), jnp.int32, mesh)]
     if quant:
-        args += [_sds((n_blocks, block_size, hkv), jnp.float32, mesh)] * 2
+        args += [_sds(lead + (block_size, hkv), jnp.float32, mesh)] * 2
 
-    def f(q, k, v, tbl, off, *scales):
+    def f(q, k, v, tbl, off, layer, *scales):
         ks, vs = scales if scales else (None, None)
-        return paged_attention_pallas(q, k, v, tbl, off, k_scale=ks,
-                                      v_scale=vs, interpret=False,
-                                      return_lse=return_lse)
+        return paged_attention_pallas(
+            q, k, v, tbl, off, layer=None if layers is None else layer,
+            k_scale=ks, v_scale=vs, interpret=False,
+            return_lse=return_lse)
 
     return _compile_kernel(f, args)
 
 
 def check_packed_prefill(devs, *, chunk=256, dtype=jnp.bfloat16, heads=12,
-                         head_dim=64):
-    """The packed-prefill lane's intra-pack part: one ``(1, chunk)`` row
-    of pack tokens through the flash forward kernel with segment ids,
-    returning the LSE the arena-history combine consumes."""
+                         head_dim=64, layers=12, n_blocks=2048,
+                         block_size=16, table_width=64):
+    """The packed-prefill lane's attention as ``ParallelAttention.
+    _decode_packed`` runs it: one ``(1, chunk)`` row of pack tokens
+    through the flash forward kernel with segment ids, LSE-combined
+    with each token's arena history through the paged kernel — ``chunk``
+    one-row slots over the stacked arena at a traced ``layer``."""
     from hetu_tpu.ops.attention import attention_with_lse
+    from hetu_tpu.ops.paged_pallas import (
+        combine_attention_lse, paged_attention_pallas,
+    )
     mesh = _one_dev_mesh(devs)
     qkv = _sds((1, chunk, heads, head_dim), dtype, mesh)
     seg = _sds((1, chunk), jnp.int32, mesh)
+    page = _sds((layers, n_blocks, block_size, heads * head_dim), dtype,
+                mesh)
+    tbl = _sds((chunk, table_width), jnp.int32, mesh)
+    hist = _sds((chunk,), jnp.int32, mesh)
 
-    def f(q, k, v, seg):
-        return attention_with_lse(q, k, v, causal=True, segment_ids=seg,
-                                  impl="pallas", interpret=False)
+    def f(q, k, v, seg, ka, va, tbl, hist, layer):
+        intra, lse_i = attention_with_lse(
+            q, k, v, causal=True, segment_ids=seg, impl="pallas",
+            interpret=False)
+        past, lse_h = paged_attention_pallas(
+            q[0][:, None], ka, va, tbl, hist - 1, layer=layer,
+            interpret=False, return_lse=True)
+        return combine_attention_lse(intra, lse_i, past[:, 0][None],
+                                     lse_h[:, :, 0].T[None])
 
-    return _compile_kernel(f, (qkv, qkv, qkv, seg))
+    return _compile_kernel(f, (qkv, qkv, qkv, seg, page, page, tbl, hist,
+                               _sds((), jnp.int32, mesh)))
+
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?)\s([a-z][\w\-]*)\((.*)$")
+_HLO_SHAPE = re.compile(r"[a-z]\w*\[([\d,]*)\]")
+
+
+def arena_moves(hlo: str, leaf_elements: int) -> dict:
+    """Instructions of an optimized HLO that MOVE at least one layer's
+    leaf of the KV arena (``leaf_elements`` = n_blocks x block_size x
+    hkv*d): name -> elements moved. The benchmark's rule
+    (``benchmark/program_trace.py``: the name says copy or slice and
+    the result holds a layer's leaf), with one refinement for a text
+    that has the operands: an in-place ``dynamic-update-slice`` moves
+    its UPDATE, not the buffer it returns (the CoW pass writes one
+    block into the whole arena; the xs/ys layer scan wrote a layer's
+    leaf into the stacked output)."""
+    def elements(shapes: str) -> int:
+        best = 0
+        for dims in _HLO_SHAPE.findall(shapes):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d) if d else 1
+            best = max(best, n)
+        return best
+
+    instrs = {}
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m:
+            name, shapes, _, rest = m.groups()
+            instrs[name] = (elements(shapes), rest)
+    moves = {}
+    for name, (n, rest) in instrs.items():
+        if not re.search(r"copy|slice", name):
+            continue
+        if "update" in name:
+            ops = [instrs[o][0] for o in re.findall(r"%([\w.\-]+)",
+                                                     rest.split("),")[0])
+                   if o in instrs]
+            n = max((e for e in ops if e < n), default=0)
+        if n >= leaf_elements:
+            moves[name] = n
+    return moves
+
+
+def _serving_report(compiled, caches, t0) -> dict:
+    """Memory analysis and arena moves of a compiled serving program
+    whose donated operand ``caches`` is the stacked arena."""
+    leaf = max(caches, key=lambda c: math.prod(c.shape))
+    leaf_elements = math.prod(leaf.shape[1:])
+    ma = compiled.memory_analysis()
+    return {
+        "compile_s": round(time.perf_counter() - t0, 1),
+        "temp_bytes": int(ma.temp_size_in_bytes),
+        "peak_bytes_est": int(ma.temp_size_in_bytes
+                              + ma.argument_size_in_bytes
+                              + ma.output_size_in_bytes
+                              - ma.alias_size_in_bytes),
+        "arena_bytes": sum(math.prod(c.shape) * c.dtype.itemsize
+                           for c in caches),
+        "layer_leaf_bytes": leaf_elements * leaf.dtype.itemsize,
+        "arena_moves": arena_moves(compiled.as_text(), leaf_elements),
+    }
+
+
+def check_serving_lane(devs, *, lane="decode", dtype=jnp.bfloat16,
+                       n_blocks=2048, slots=8, chunk=256, block_size=16,
+                       table_width=64):
+    """One lane of the fused serving step — GPT-2 small's layer scan
+    (``StackedBlocks.decode``) over a donated paged arena, under the
+    ``cond`` the step wraps it in: ``lane="decode"`` is ``slots``
+    one-token rows, ``"prefill"`` the packed flash chunk. Returns the
+    program's temporaries and the instructions that still move a
+    layer's leaf of the arena (none, while the scan carries it). The
+    arena is abstract, so its size costs nothing here; at a few hundred
+    blocks a whole int8 leaf fits the chip's fast memory and the
+    compiler prefetches it there, a copy no serving arena sees."""
+    from hetu_tpu.models import GPTConfig, GPTLMHeadModel
+    from hetu_tpu.models.generation import init_paged_caches
+    mesh = _one_dev_mesh(devs)
+    model = GPTLMHeadModel(GPTConfig.small())
+    embed = model.cfg.hidden_size
+
+    def abstract(tree):
+        return jax.tree.map(lambda s: _sds(s.shape, s.dtype, mesh), tree)
+
+    blocks = abstract(jax.eval_shape(
+        lambda k: model.init(k, dtype=jnp.bfloat16),
+        jax.random.key(0))["blocks"])
+    caches = abstract(jax.eval_shape(
+        lambda: init_paged_caches(model, n_blocks, block_size, dtype)))
+    n, shape = (slots, (slots, 1)) if lane == "decode" \
+        else (chunk, (1, chunk))
+    args = (_sds((), jnp.bool_, mesh), blocks, caches,
+            # float32 activations over bf16 weights: the engine's loop
+            # thread never enters autocast (PERF.md section 4)
+            _sds(shape + (embed,), jnp.float32, mesh),
+            _sds(shape, jnp.int32, mesh),
+            _sds((n, table_width), jnp.int32, mesh),
+            _sds((n,), jnp.bool_, mesh), _sds(shape, jnp.int32, mesh),
+            _sds((n,), jnp.int32, mesh))
+
+    def f(run, blocks, caches, h, pos, tbl, valid, seg, hist):
+        def go(caches):
+            kw = dict(slot_mask=valid) if lane == "decode" else dict(
+                pack={"segment_ids": seg, "hist": hist, "valid": valid,
+                      "impl": "pallas"})
+            return model.blocks.decode(blocks, h, caches, positions=pos,
+                                       block_tables=tbl,
+                                       attn_kernel="paged", **kw)
+        return jax.lax.cond(run, go, lambda c: (h, c), caches)
+
+    t0 = time.perf_counter()
+    with _mosaic_aot_env():
+        c = jax.jit(f, donate_argnums=(2,)).lower(*args).compile()
+    return _serving_report(c, caches, t0)
+
+
+def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
+                       slots=148, n_blocks=9473, max_len=1024, chunk=256):
+    """The REAL fused serving step (``ServingEngine._build_step``: CoW
+    pass, decode lane, packed flash prefill lane, sampling) compiled
+    for the target at a benchmark cell's sizes (defaults: GPT-2 small,
+    148 slots, 9,473 blocks; ``config="large"`` with 32 / 2,049 is
+    ``gpt2-large.backlog``). The engine is built on this host with a
+    toy arena; the operands of its first dispatch are recorded, not
+    run, and the step is lowered from their abstract shapes with the
+    arena at ``n_blocks`` on the described device."""
+    from jax.sharding import SingleDeviceSharding
+    from hetu_tpu.models import GPTConfig, GPTLMHeadModel
+    from hetu_tpu.serving import ServingEngine
+
+    cfg = GPTConfig.small() if config == "small" else GPTConfig(
+        vocab_size=50257, max_positions=1024, hidden_size=1280,
+        num_layers=36, num_heads=20)
+    model = GPTLMHeadModel(cfg)
+    params = jax.jit(lambda k: model.init(k, dtype=jnp.bfloat16))(
+        jax.random.key(0))
+    eng = ServingEngine(model, params, max_len=max_len,
+                        prefill_chunk=chunk, cache_dtype=dtype,
+                        block_size=16, slots=slots, kv_blocks=max_len // 16 + 1,
+                        attn_kernel="paged", prefill_attn="flash_pallas")
+
+    class Recorded(Exception):
+        pass
+
+    def record(*args):
+        raise Recorded(args)
+
+    eng._fn = record
+    eng.submit([1, 2, 3])
+    try:
+        eng.step()
+    except Recorded as r:
+        args, = r.args
+    # the step again, at home on the described device
+    sh = SingleDeviceSharding(devs[0])
+    eng._rep = eng._arena_sh = sh
+    fn = eng._build_step()
+
+    def abstract(x):
+        x = x if hasattr(x, "dtype") else np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+
+    sds = list(jax.tree.map(abstract, args))
+    sds[1] = tuple(jax.ShapeDtypeStruct(
+        (c.shape[0], n_blocks) + c.shape[2:], c.dtype, sharding=sh)
+        for c in sds[1])
+    t0 = time.perf_counter()
+    with _mosaic_aot_env():
+        c = fn.lower(*sds).compile()
+    return _serving_report(c, sds[1], t0)
 
 
 def check_fused_ce(devs, *, n=4096, e=768, v=50257):
@@ -374,7 +571,12 @@ def main():
                              return_lse=True)),
         ("paged_gqa32x8_d128",
          lambda: check_paged(d1, heads=32, kv_heads=8, head_dim=128)),
+        ("paged_decode_one_layer_3d",
+         lambda: check_paged(d1, layers=None)),
         ("packed_prefill_c256", lambda: check_packed_prefill(d1)),
+        ("serving_lane_decode_bf16", lambda: check_serving_lane(d1)),
+        ("serving_lane_prefill_int8",
+         lambda: check_serving_lane(d1, lane="prefill", dtype=jnp.int8)),
     ]
     checks += [(name, lambda kw=kw: check_flash(d1, **kw))
                for name, kw in tuned_block_checks()]
@@ -432,6 +634,13 @@ def main():
                                 batch=8, seq=1024)),
             # inference: prefill + lax.scan KV-cache decode
             ("decode_kv_cache_v5e", lambda: check_decode(d1[:1])),
+            # the fused serving step at the benchmark cells' sizes:
+            # memory analysis and what still moves the arena
+            ("serving_step_gpt2_small_chat",
+             lambda: check_serving_step(d1[:1])),
+            ("serving_step_gpt2_large_backlog",
+             lambda: check_serving_step(d1[:1], config="large", slots=32,
+                                        n_blocks=2049)),
         ]
 
     rows = []
@@ -445,6 +654,9 @@ def main():
         extra = ""
         if "peak_bytes_est" in r:
             extra = f"  peak {r['peak_bytes_est'] / 1024**3:.2f} GiB"
+        if "arena_moves" in r:
+            extra += (f"  temp {r['temp_bytes'] / 1e6:.1f} MB"
+                      f"  arena moves {sorted(r['arena_moves'])}")
         print(f"{name:>32}: {status}{extra}", flush=True)
 
     # --quick covers only the kernel rows: keep it out of the full

@@ -48,6 +48,10 @@ SHAPES = [
 
 PAGES = (1, 2, 4, 8, 16)
 
+#: the arena is the engine's stacked leaf, read at a traced layer: a
+#: page's index map is (layer, table[s, w], 0, 0), as in the fused step
+LAYERS = 2
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -65,10 +69,11 @@ def main():
         W = table_len // bs
         n_blocks = 1 + S * (-(-ctx // bs))
         q = jnp.asarray(rng.normal(size=(S, R, hq, d)), jnp.bfloat16)
-        k = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv * d)),
+        k = jnp.asarray(rng.normal(size=(LAYERS, n_blocks, bs, hkv * d)),
                         jnp.bfloat16)
-        v = jnp.asarray(rng.normal(size=(n_blocks, bs, hkv * d)),
+        v = jnp.asarray(rng.normal(size=(LAYERS, n_blocks, bs, hkv * d)),
                         jnp.bfloat16)
+        layer = jnp.asarray(LAYERS - 1, jnp.int32)
         tbl = np.zeros((S, W), np.int32)
         per = -(-ctx // bs)
         for s in range(S):
@@ -80,14 +85,14 @@ def main():
             if L > W:
                 continue
 
-            def f(q, k, v, L=L):
+            def f(q, k, v, layer, L=L):
                 return paged_attention_pallas(
-                    q, k, v, tbl, off, pages_per_step=L,
+                    q, k, v, tbl, off, layer=layer, pages_per_step=L,
                     interpret=False)
 
             try:
-                ms = time_loop_ms(scan_loop(f, args.iters), (q, k, v),
-                                  args.iters)
+                ms = time_loop_ms(scan_loop(f, args.iters),
+                                  (q, k, v, layer), args.iters)
             except Exception as e:                  # noqa: BLE001
                 rows.append({"pages": L, "error": str(e)[:80]})
                 continue

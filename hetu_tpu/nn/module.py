@@ -15,7 +15,7 @@ but declared once per parameter instead of propagated through a C++ pass.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Mapping, Optional, Sequence
+from typing import Any, Callable, Mapping, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +42,17 @@ class ParamSpec:
     def instantiate(self, key: jax.Array, dtype=None) -> jax.Array:
         dtype = self.dtype or dtype or current_policy().param_dtype
         return self.init(key, self.shape, dtype)
+
+
+class StackedLeaf(NamedTuple):
+    """A parameter a layer scan does NOT slice: every layer's values,
+    ``(layers, ...)``, and which layer this call is
+    (``StackedBlocks.decode`` hands it in place of the layer's slice for
+    the paths a block lists as ``unsliced``). For an operand of a
+    kernel that cannot read through a dynamic slice — the slice would
+    be a copy of the layer's values, per layer, per call."""
+    stack: Any
+    layer: Any
 
 
 class Module:

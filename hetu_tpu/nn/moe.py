@@ -32,6 +32,11 @@ has no MoE — this module is the capability re-designed TPU-first:
   to vanish silently), and aux-loss/overflow-fraction histograms are
   emitted through a trace-time-gated ``jax.debug.callback`` when
   telemetry is enabled.
+- :class:`ExpertShareMoE` is the SERVING form of expert parallelism on
+  one chip of a wide-EP deployment: the layer is told which experts it
+  holds, routes over all of them without dropping a token, and computes
+  its own experts' part of the result through a sorted, grouped matmul
+  (``jax.lax.ragged_dot``). Nothing stands in for the absent chips.
 """
 
 from __future__ import annotations
@@ -45,7 +50,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from hetu_tpu.nn.module import Module, normal_init
+from hetu_tpu.nn.module import Module, StackedLeaf, normal_init
 from hetu_tpu.ops import activations as act_ops
 from hetu_tpu.parallel.sharding import (
     act_constrain, current_act_sharding, current_manual_axes,
@@ -788,3 +793,142 @@ def _ep_dispatch(x, idx, wgt, eparams, *, ep, num_experts, k,
                       ye.astype(jnp.float32))  # (Tk, d)
     w = (wgt.reshape(T * k) * keep)[:, None]
     return jnp.sum((outk * w).reshape(T, k, -1), axis=1)
+
+
+# -- one chip's share of an expert-parallel layer (serving) -------------------
+def count_local_share(sizes) -> None:
+    """:class:`ExpertShareMoE`'s counters, on the host: ``sizes``
+    ``(layer calls, held experts)`` int — the group sizes of every
+    layer of one executed scan (``return_sizes=True``; the serving
+    engine calls this with what its step returned)."""
+    from hetu_tpu import telemetry
+    import numpy as np
+    reg = telemetry.get_registry()
+    sizes = np.asarray(sizes)
+    reg.counter(
+        "moe_local_calls_total",
+        "executed calls of an expert-share MoE layer").inc(
+            float(sizes.shape[0]))
+    reg.counter(
+        "moe_local_assignments_total",
+        "(token, choice) pairs routed to an expert this chip holds").inc(
+            float(sizes.sum()))
+    reg.counter(
+        "moe_local_experts_touched_total",
+        "local experts that got at least one token, summed over layer "
+        "calls (their weights are what a call has to read)").inc(
+            float((sizes > 0).sum()))
+    per = reg.counter(
+        "moe_local_expert_tokens",
+        "(token, choice) pairs routed to each local expert; max over "
+        "mean is the share's load imbalance")
+    for e, n in enumerate(sizes.sum(0).tolist()):
+        if n:
+            per.inc(float(n), expert=str(e))
+
+
+class ExpertShareMoE(Module):
+    """A routed-expert layer that holds ``local_experts = (first,
+    count)`` of ``num_experts`` SwiGLU experts — one chip's share of an
+    expert-parallel deployment (default: all of them).
+
+    The router keeps its full width: ``s = sigmoid(x W_r)``, the ``k``
+    largest are chosen and weighted ``w_e = s_e / sum_chosen s``
+    (normalised over ALL k chosen, held here or not). The layer returns
+    ``sum_{e chosen, first <= e < first + count} w_e E_e(x)`` — what the
+    absent experts would have added is left out, here and in the
+    reference alike (``benchmark/reference/cohere2_moe.py``). No token
+    is dropped: there is no capacity. The (token, choice) pairs are
+    sorted by local expert and the three expert matmuls run as grouped
+    matmuls over the sorted rows (``jax.lax.ragged_dot``: a row costs
+    one expert's arithmetic, an expert's weights are read once) — never
+    a per-token gather of expert weights. Inside a layer scan the expert
+    weights come as :class:`~hetu_tpu.nn.module.StackedLeaf` (the
+    model's block lists them as ``unsliced``): the grouped matmul then
+    runs over the groups of ALL layers, every other layer's empty — the
+    kernel reads no page of an empty group, and no layer's 1.6 GB of
+    experts is sliced out (a copy) for it.
+
+    Operands: the router in float32 at the highest matmul precision on
+    the input as given (a top-k flips on rounding; the input is the
+    block's float32 norm), the experts in the module's compute dtype
+    with float32 accumulation.
+    """
+
+    returns_aux = False
+
+    def __init__(self, features: int, hidden: int, num_experts: int, *,
+                 k: int, local_experts: Optional[tuple] = None, init=None):
+        super().__init__()
+        first, count = local_experts or (0, num_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= num_experts):
+            raise ValueError(f"local_experts {local_experts} outside "
+                             f"the {num_experts} experts")
+        if k > num_experts:
+            raise ValueError(f"top-{k} of {num_experts} experts")
+        self.num_experts, self.k = num_experts, k
+        self.local_experts = (int(first), int(count))
+        init = init or normal_init(0.02)
+        self.param("router", (features, num_experts), init,
+                   axes=("embed", None))
+        self.param("wg", (count, features, hidden), init,
+                   axes=("expert", "embed", "mlp"))
+        self.param("wi", (count, features, hidden), init,
+                   axes=("expert", "embed", "mlp"))
+        self.param("wo", (count, hidden, features), init,
+                   axes=("expert", "mlp", "embed"))
+
+    def route(self, params, x):
+        """x (T, d) -> (experts (T, k) int32, weights (T, k) float32)."""
+        z = jnp.matmul(x.astype(jnp.float32),
+                       params["router"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        top, idx = jax.lax.top_k(jax.nn.sigmoid(z), self.k)
+        return idx.astype(jnp.int32), top / top.sum(-1, keepdims=True)
+
+    def __call__(self, params, x, *, return_sizes: bool = False):
+        """``return_sizes``: also the ``(count,)`` int32 numbers of
+        (token, choice) pairs each held expert got — ``(out, sizes)``."""
+        dt = self.compute_dtype()
+        d = x.shape[-1]
+        xf = x.reshape(-1, d)
+        k = self.k
+        M = xf.shape[0] * k
+        first, count = self.local_experts
+        with jax.named_scope("hetu.moe_route"):
+            idx, w = self.route(params, xf)
+            local = idx - first
+            mine = (local >= 0) & (local < count)
+            # pairs held elsewhere sort behind every local group
+            key = jnp.where(mine, local, count).reshape(M)
+            order = jnp.argsort(key, stable=True)
+            sizes = jnp.sum(
+                key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
+                axis=0, dtype=jnp.int32)
+            rows = jnp.take(xf, order // k, axis=0).astype(dt)
+            w_rows = jnp.take(w.reshape(M), order)
+            valid = jnp.arange(M) < sizes.sum()
+            # where each pair's row went, to bring its result back
+            back = jnp.zeros((M,), jnp.int32).at[order].set(
+                jnp.arange(M, dtype=jnp.int32))
+        with jax.named_scope("hetu.moe_experts"):
+            def grouped(a, name):
+                w, groups = params[name], sizes
+                if isinstance(w, StackedLeaf):
+                    w, layer = w
+                    groups = jax.lax.dynamic_update_slice(
+                        jnp.zeros((w.shape[0] * count,), jnp.int32),
+                        sizes, (layer * count,))
+                    w = w.reshape((-1,) + w.shape[2:])
+                return jax.lax.ragged_dot(
+                    a, w.astype(dt), groups,
+                    preferred_element_type=jnp.float32)
+            h = (jax.nn.silu(grouped(rows, "wg"))
+                 * grouped(rows, "wi")).astype(dt)
+            y = grouped(h, "wo")
+            # rows behind the last group were never computed
+            y = jnp.where(valid[:, None], y * w_rows[:, None], 0.0)
+            out = jnp.take(y, back, axis=0).reshape(-1, k, d).sum(1)
+        out = out.astype(dt).reshape(x.shape)
+        return (out, sizes) if return_sizes else out

@@ -29,7 +29,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
-from hetu_tpu.nn.module import Module, ParamSpec, normal_init, zeros_init
+from hetu_tpu.nn.module import (
+    Module, ParamSpec, StackedLeaf, normal_init, zeros_init,
+)
 from hetu_tpu.ops import activations as act_ops
 from hetu_tpu.ops import embedding as embed_ops
 from hetu_tpu.ops.attention import attention_reference, flash_attention
@@ -370,6 +372,23 @@ class ParallelMLP(Module):
         return lora_apply(lora, "fc_out", h, y)
 
 
+def _pop_path(tree: dict, path: tuple):
+    """``tree`` without the leaf at ``path`` (the dicts on the way
+    copied, nothing else), and the leaf."""
+    if len(path) == 1:
+        rest = dict(tree)
+        return rest, rest.pop(path[0])
+    sub, leaf = _pop_path(tree[path[0]], path[1:])
+    return {**tree, path[0]: sub}, leaf
+
+
+def _set_path(tree: dict, path: tuple, leaf):
+    if len(path) == 1:
+        return {**tree, path[0]: leaf}
+    return {**tree, path[0]: _set_path(tree.get(path[0], {}), path[1:],
+                                       leaf)}
+
+
 def _at_layer(buf, layer):
     """One layer of a stacked cache leaf, ``buf[layer]`` — a COPY of
     that layer where ``layer`` is traced: for the dense caches and the
@@ -434,8 +453,14 @@ class ParallelAttention(Module):
                  head_dim: Optional[int] = None,
                  bias: bool = True, causal: bool = True,
                  use_rope: bool = False, rope_theta: float = 10000.0,
+                 rope_interleaved: bool = False,
+                 min_window: Optional[int] = None,
                  max_positions: int = 4096, init=None):
         super().__init__()
+        self.rope_interleaved = rope_interleaved
+        #: the smallest ``window=`` any layer is called with (static;
+        #: the packed prefill lane checks its chunk against it)
+        self.min_window = min_window
         self.num_heads = num_heads
         self.num_kv_heads = num_kv_heads or num_heads
         if num_heads % self.num_kv_heads != 0:
@@ -462,12 +487,35 @@ class ParallelAttention(Module):
         else:
             self._rope = None
 
+    def _rotate(self, q, k, positions, rope_on):
+        """RoPE on q and k where the module has it; ``rope_on`` (a
+        traced bool from a layer scan whose layers differ by it, else
+        ``None``) says whether THIS layer rotates."""
+        if self._rope is None:
+            return q, k
+        cos, sin = self._rope
+        qr = apply_rotary(q, cos, sin, positions=positions,
+                          interleaved=self.rope_interleaved)
+        kr = apply_rotary(k, cos, sin, positions=positions,
+                          interleaved=self.rope_interleaved)
+        if rope_on is None:
+            return qr, kr
+        return jnp.where(rope_on, qr, q), jnp.where(rope_on, kr, k)
+
     def __call__(self, params, x, *, positions=None, segment_ids=None,
                  attn_impl: str = "auto", kv_cache=None, slot_mask=None,
                  block_tables=None, row_mask=None, attn_kernel="reference",
                  pack=None, dropout_rate: float = 0.0, dropout_key=None,
-                 return_kv: bool = False, lora=None):
-        """``return_kv=True`` (train path only) additionally returns the
+                 return_kv: bool = False, lora=None, window=None,
+                 rope_on=None):
+        """``window`` / ``rope_on`` (``None`` on every model whose
+        layers are alike): this layer's attention window (query ``p``
+        sees keys ``p - window < j <= p``; an int32 scalar, traced in a
+        layer scan) and whether it rotates q and k. The whole-sequence
+        forward honours a window through the reference attention path
+        (the flash kernel has none).
+
+        ``return_kv=True`` (train path only) additionally returns the
         rotary-applied per-head ``(k, v)`` of this call — the exact
         values the decode path would have written to a KV cache — as
         ``(out, (k, v))``. The serving CP-prefill lane uses this to run
@@ -484,7 +532,8 @@ class ParallelAttention(Module):
                                 block_tables=block_tables,
                                 row_mask=row_mask,
                                 attn_kernel=attn_kernel, pack=pack,
-                                lora=lora)
+                                lora=lora, window=window,
+                                rope_on=rope_on)
         b, s, _ = x.shape
         q = self.q_proj(params["q_proj"], x).reshape(
             b, s, self.num_heads, self.head_dim)
@@ -492,10 +541,7 @@ class ParallelAttention(Module):
             b, s, self.num_kv_heads, self.head_dim)
         v = self.v_proj(params["v_proj"], x).reshape(
             b, s, self.num_kv_heads, self.head_dim)
-        if self._rope is not None:
-            cos, sin = self._rope
-            q = apply_rotary(q, cos, sin, positions=positions)
-            k = apply_rotary(k, cos, sin, positions=positions)
+        q, k = self._rotate(q, k, positions, rope_on)
         q = act_constrain(q, "heads")
         k = act_constrain(k, "heads")
         v = act_constrain(v, "heads")
@@ -505,7 +551,15 @@ class ParallelAttention(Module):
                      and "cp" in mctx.axes and mctx.mesh.shape["cp"] > 1)
         gspmd_cp = (ctx is not None and isinstance(ctx.seq, str)
                     and ctx.mesh.shape[ctx.seq] > 1)
-        if manual_cp:
+        if window is not None:
+            if manual_cp or gspmd_cp:
+                raise NotImplementedError(
+                    "a windowed layer under context parallelism")
+            out = attention_reference(
+                q, k, v, causal=self.causal, segment_ids=segment_ids,
+                window=window, dropout_rate=dropout_rate,
+                dropout_key=dropout_key)
+        elif manual_cp:
             # inside a manual region (pipeline executor) with cp bound:
             # run the cp attention core directly on the bound axis —
             # x/q/k/v here are the per-device local seq chunks
@@ -558,7 +612,8 @@ class ParallelAttention(Module):
 
     def _decode(self, params, x, kv_cache, *, positions=None,
                 slot_mask=None, block_tables=None, row_mask=None,
-                attn_kernel: str = "reference", pack=None, lora=None):
+                attn_kernel: str = "reference", pack=None, lora=None,
+                window=None, rope_on=None):
         """Incremental decoding with a KV cache.
 
         ``kv_cache``: a :class:`LayerKV` — the STACKED leaves of every
@@ -624,7 +679,8 @@ class ParallelAttention(Module):
                                        block_tables=block_tables,
                                        pack=pack,
                                        attn_kernel=attn_kernel,
-                                       lora=lora)
+                                       lora=lora, window=window,
+                                       rope_on=rope_on)
         leaves, layer = kv_cache
         quant = len(leaves) == 4
         b, s, _ = x.shape
@@ -650,11 +706,9 @@ class ParallelAttention(Module):
                        self.v_proj(params["v_proj"], x)).reshape(
             b, s, self.num_kv_heads, self.head_dim)
         if self._rope is not None:
-            cos, sin = self._rope
-            pos = positions if positions is not None \
-                else jnp.arange(s)[None, :]
-            q = apply_rotary(q, cos, sin, positions=pos)
-            k = apply_rotary(k, cos, sin, positions=pos)
+            q, k = self._rotate(
+                q, k, positions if positions is not None
+                else jnp.arange(s)[None, :], rope_on)
 
         if paged:
             n_blk, blk = leaves[0].shape[1], leaves[0].shape[2]
@@ -730,7 +784,8 @@ class ParallelAttention(Module):
             # head axis (Mosaic kernels cannot be GSPMD-auto-partitioned)
             from hetu_tpu.ops.paged_pallas import paged_attention_auto
             out = paged_attention_auto(q, k_buf, v_buf, block_tables,
-                                       index, layer=layer, **arena)
+                                       index, layer=layer, window=window,
+                                       **arena)
         elif paged:
             if attn_kernel == "paged":
                 from hetu_tpu.ops.attention import record_kernel_fallback
@@ -745,7 +800,8 @@ class ParallelAttention(Module):
                 paged_attention_reference
             out = paged_attention_reference(
                 q, _at_layer(k_buf, layer), _at_layer(v_buf, layer),
-                block_tables, index, causal=self.causal, **arena)
+                block_tables, index, causal=self.causal, window=window,
+                **arena)
         else:
             k_buf, v_buf = _at_layer(k_buf, layer), _at_layer(v_buf, layer)
             if quant:
@@ -753,14 +809,15 @@ class ParallelAttention(Module):
                 v_buf = dequantize_int8(v_buf, vs_l, q.dtype)
             out = attention_reference(
                 q, k_buf, v_buf, causal=self.causal,
-                q_offset=index, kv_offset=0)
+                q_offset=index, kv_offset=0, window=window)
         out = out.reshape(b, s, self.num_heads * self.head_dim)
         return lora_apply(lora, "out_proj", out,
                           self.out_proj(params["out_proj"], out)), \
             new_cache
 
     def _decode_packed(self, params, x, kv_cache, *, positions,
-                       block_tables, pack, attn_kernel, lora=None):
+                       block_tables, pack, attn_kernel, lora=None,
+                       window=None, rope_on=None):
         """Packed-prefill FLASH mode: the serving engine's prefill pack
         as ONE ``(1, C, embed)`` row instead of C one-token batch rows.
 
@@ -785,7 +842,11 @@ class ParallelAttention(Module):
         out of bounds) at ``[layer, row]`` of the stacked leaves
         (``kv_cache`` is a :class:`LayerKV`), bit-identical to the
         per-token reference lane — only the attention READ changes
-        formulation."""
+        formulation.
+
+        A ``window`` cuts only the history part: the chunk is no longer
+        than the window (asserted), so no in-pack key of a token's own
+        request lies below it."""
         if not self.causal:
             raise ValueError(
                 "the packed-prefill flash lane requires causal "
@@ -805,10 +866,7 @@ class ParallelAttention(Module):
         v = lora_apply(lora, "v_proj", x,
                        self.v_proj(params["v_proj"], x)).reshape(
             b, C, self.num_kv_heads, self.head_dim)
-        if self._rope is not None:
-            cos, sin = self._rope
-            q = apply_rotary(q, cos, sin, positions=positions)
-            k = apply_rotary(k, cos, sin, positions=positions)
+        q, k = self._rotate(q, k, positions, rope_on)
         pos = positions[0]                               # (C,)
         blk_ids = jnp.take_along_axis(block_tables,
                                       (pos // blk)[:, None], axis=1)[:, 0]
@@ -856,6 +914,17 @@ class ParallelAttention(Module):
 
         qh = q[0][:, None]                       # (C, 1, hq, d) rows
         hist_off = pack["hist"].astype(jnp.int32) - 1   # kpos <= hist-1
+        if window is not None:
+            if self.min_window is not None and C > self.min_window:
+                raise ValueError(
+                    f"a prefill pack of {C} tokens is longer than the "
+                    f"model's window {self.min_window}: in-pack keys "
+                    f"would fall below it")
+            # the history lane's row sits at hist - 1 and the token at
+            # pos: keys > pos - window are keys > (hist - 1) - (window
+            # - (pos - hist + 1)) — a window shorter by the token's
+            # depth in its chunk, one per token
+            arena["window"] = window - (pos - hist_off)
         if attn_kernel == "paged":
             hist, lse_h = paged_attention_auto(
                 qh, ka, va, block_tables, hist_off, layer=layer,
@@ -914,12 +983,25 @@ class StackedBlocks(Module):
     is a single block traced once and scanned, with the stacked ``layers``
     axis available to the pipeline executor (axis rule ``"layers" → "pp"``)
     and ``jax.checkpoint`` applied per block for recompute parity.
+
+    ``layer_data`` (``{name: one value per layer}``, ``None`` on every
+    model whose layers are alike) is how layers of ONE scanned block
+    differ: each array rides the scan as xs and layer ``l``'s block is
+    called with ``name=value[l]`` (a traced scalar) — a window, a flag
+    — so a model that mixes layer kinds keeps one block, one scan and
+    one stacked cache.
     """
 
-    def __init__(self, make_block: Callable[[], Module], num_layers: int):
+    def __init__(self, make_block: Callable[[], Module], num_layers: int,
+                 layer_data: Optional[dict] = None):
         super().__init__()
         self.num_layers = num_layers
         self._block = make_block()  # underscore: excluded from children()
+        if layer_data is not None:
+            layer_data = {k: jnp.asarray(v) for k, v in layer_data.items()}
+            if any(v.shape[0] != num_layers for v in layer_data.values()):
+                raise ValueError("layer_data needs one value per layer")
+        self.layer_data = layer_data
 
     @property
     def block(self) -> Module:
@@ -974,12 +1056,18 @@ class StackedBlocks(Module):
         dropout_key = kwargs.pop("dropout_key", None)
         layer_keys = None if dropout_key is None \
             else jax.random.split(dropout_key, n_layers)
+        layer_data = self.layer_data
+        if layer_data is not None and n_layers != self.num_layers:
+            raise NotImplementedError(
+                "a chunk of the layers of a model whose layers differ "
+                "by layer_data")
 
-        def call_block(layer_params, h, xs_key):
+        def call_block(layer_params, h, xs_key, ld=None):
+            kw = kwargs if ld is None else {**kwargs, **ld}
             if xs_key is not None:
                 return self._block(layer_params, h, dropout_key=xs_key,
-                                   **kwargs)
-            return self._block(layer_params, h, **kwargs)
+                                   **kw)
+            return self._block(layer_params, h, **kw)
 
         # per-layer ZeRO-3 gather ring (Strategy(fsdp_overlap="ring")):
         # block params arrive dp-sharded on inner dims and each layer is
@@ -990,6 +1078,9 @@ class StackedBlocks(Module):
                 and getattr(ctx, "fsdp_overlap", "off") == "ring"
                 and getattr(ctx, "fsdp_specs", None) is not None
                 and ctx.mesh.shape.get("dp", 1) > 1):
+            if layer_data is not None:
+                raise NotImplementedError(
+                    "the ZeRO-3 gather ring with layer_data")
             return self._fsdp_ring_scan(
                 params, x, ctx, remat=remat, remat_mask=remat_mask,
                 unroll=unroll, n_layers=n_layers, layer_keys=layer_keys,
@@ -997,14 +1088,14 @@ class StackedBlocks(Module):
 
         if self._block.returns_aux:
             def body(carry, xs):
-                layer_params, xs_key = xs
+                layer_params, xs_key, ld = xs
                 h, aux = carry
-                h, a = call_block(layer_params, h, xs_key)
+                h, a = call_block(layer_params, h, xs_key, ld)
                 return (h, aux + a), None
         else:
             def body(carry, xs):
-                layer_params, xs_key = xs
-                return call_block(layer_params, carry, xs_key), None
+                layer_params, xs_key, ld = xs
+                return call_block(layer_params, carry, xs_key, ld), None
 
         def rematted(b, policy_name):
             return jax.checkpoint(b, policy=remat_policy(policy_name),
@@ -1030,8 +1121,10 @@ class StackedBlocks(Module):
             for lo, hi, flag in runs:
                 seg = jax.tree.map(lambda p: p[lo:hi], params)
                 seg_keys = None if layer_keys is None else layer_keys[lo:hi]
+                seg_ld = None if layer_data is None else \
+                    {k: v[lo:hi] for k, v in layer_data.items()}
                 b = rematted(body, policy_name) if flag else body
-                carry, _ = jax.lax.scan(b, carry, (seg, seg_keys),
+                carry, _ = jax.lax.scan(b, carry, (seg, seg_keys, seg_ld),
                                         unroll=hi - lo if unroll else 1)
             if self._block.returns_aux:
                 return carry
@@ -1040,10 +1133,12 @@ class StackedBlocks(Module):
         if remat != "none":
             body = rematted(body, remat)
         if self._block.returns_aux:
-            (x, aux), _ = jax.lax.scan(body, carry0, (params, layer_keys),
-                                       unroll=unroll_n)
+            (x, aux), _ = jax.lax.scan(
+                body, carry0, (params, layer_keys, layer_data),
+                unroll=unroll_n)
             return x, aux
-        x, _ = jax.lax.scan(body, x, (params, layer_keys), unroll=unroll_n)
+        x, _ = jax.lax.scan(body, x, (params, layer_keys, layer_data),
+                            unroll=unroll_n)
         return x
 
     def _fsdp_ring_scan(self, params, x, ctx, *, remat, remat_mask,
@@ -1159,7 +1254,7 @@ class StackedBlocks(Module):
         return carry
 
     def decode(self, params, x, caches, *, w8a8_mask=None,
-               w8a8_wq=None, lora=None, **kwargs):
+               w8a8_wq=None, lora=None, with_stats=False, **kwargs):
         """Incremental decoding: scan layers CARRYING the stacked KV
         caches (leaves shaped (layers, b, max_len, hkv, d), or the
         paged arena's (layers, n_blocks, block_size, hkv*d)). The
@@ -1185,7 +1280,19 @@ class StackedBlocks(Module):
         r), "B": (L, P, r, out)}}}``. The stacked pages ride the scan
         as xs (each layer sees its (P, ...) slice) while the per-token
         page ids close over the body; each layer's targeted
-        projections add the :func:`lora_apply` BGMV delta."""
+        projections add the :func:`lora_apply` BGMV delta.
+
+        A block whose layers differ by ``layer_data`` gets each value
+        as a keyword (they ride the scan as xs). A block's ``unsliced``
+        parameter paths pass the scan whole and reach it as
+        ``StackedLeaf(all layers, this layer)``: an operand a kernel
+        cannot read through a dynamic slice would be copied per layer.
+        A block that declares ``layer_stats`` (``{name: (shape, dtype,
+        emit)}``) returns ``{name: value}`` as a third result of its
+        decode call; ``with_stats=True`` returns them stacked over the
+        layers as a third result here (``{}`` from any other block) —
+        the serving step hands them out and the engine gives each
+        executed lane's to ``emit`` on the host."""
         xs = {"p": params,
               "layer": jnp.arange(self.num_layers, dtype=jnp.int32)}
         lora_ids = None
@@ -1196,23 +1303,40 @@ class StackedBlocks(Module):
         if lora:
             xs["lora"] = lora["pages"]
             lora_ids = lora["ids"]
+        if self.layer_data is not None:
+            xs["ld"] = self.layer_data
+        whole = {}
+        for path in getattr(self._block, "unsliced", ()):
+            xs["p"], whole[path] = _pop_path(xs["p"], path)
 
         def body(carry, inputs):
             h, caches = carry
-            kw = dict(kwargs)
+            kw = dict(kwargs, **inputs.get("ld", {}))
             if "w8a8" in inputs:
                 kw["w8a8"] = inputs["w8a8"]
             if "wq" in inputs:
                 kw["w8a8_wq"] = inputs["wq"]
             if "lora" in inputs:
                 kw["lora"] = {"ids": lora_ids, "pages": inputs["lora"]}
-            return self._block(
-                inputs["p"], h,
-                kv_cache=LayerKV(caches, inputs["layer"]),
-                **kw), None
+            layer_params = inputs["p"]
+            for path, stack in whole.items():
+                layer_params = _set_path(
+                    layer_params, path, StackedLeaf(stack, inputs["layer"]))
+            h, caches, *stats = self._block(
+                layer_params, h,
+                kv_cache=LayerKV(caches, inputs["layer"]), **kw)
+            return (h, caches), (stats[0] if stats else None)
 
-        (x, caches), _ = jax.lax.scan(body, (x, tuple(caches)), xs)
-        return x, caches
+        (x, caches), stats = jax.lax.scan(body, (x, tuple(caches)), xs)
+        return (x, caches, stats or {}) if with_stats else (x, caches)
+
+    def layer_stats_zeros(self) -> dict:
+        """Zeros with the shape of :meth:`decode`'s third result (``{}``
+        for a block that reports nothing): what a lane that did not run
+        returns in their place."""
+        return {name: jnp.zeros((self.num_layers,) + tuple(shape), dtype)
+                for name, (shape, dtype, _) in
+                getattr(self._block, "layer_stats", {}).items()}
 
     def prefill(self, params, x, *, positions=None, segment_ids=None,
                 attn_impl: str = "auto"):
@@ -1226,14 +1350,16 @@ class StackedBlocks(Module):
         mesh's cp axis — and the stacked KV is what the caller scatters
         into the paged serving arena. Inference-only by construction
         (no dropout, no remat; MoE aux losses are discarded)."""
-        def body(h, layer_params):
+        def body(h, xs):
+            layer_params, ld = xs
             out = self._block(layer_params, h, positions=positions,
                               segment_ids=segment_ids,
-                              attn_impl=attn_impl, return_kv=True)
+                              attn_impl=attn_impl, return_kv=True,
+                              **(ld or {}))
             out, kv = out
             if self._block.returns_aux:
                 out, _ = out
             return out, kv
 
-        x, kvs = jax.lax.scan(body, x, params)
+        x, kvs = jax.lax.scan(body, x, (params, self.layer_data))
         return x, kvs

@@ -138,8 +138,13 @@ def attention_reference(q, k, v, *, causal: bool = False,
                         q_offset: int | jnp.ndarray = 0,
                         kv_offset: int | jnp.ndarray = 0,
                         dropout_rate: float = 0.0,
-                        dropout_key: Optional[jax.Array] = None):
+                        dropout_key: Optional[jax.Array] = None,
+                        window=None):
     """Pure-jnp attention oracle, fp32 softmax.
+
+    ``window`` (causal only; an int, a traced int32 scalar or one per
+    batch row, ``None`` = no window): a query at absolute position ``p``
+    sees keys ``p - window < j <= p``.
 
     ``q_offset``/``kv_offset`` shift the absolute positions used by the causal
     mask — needed when q/kv are chunks of a longer sequence (ring attention).
@@ -168,17 +173,28 @@ def attention_reference(q, k, v, *, causal: bool = False,
     if causal:
         qoff = jnp.asarray(q_offset)
         koff = jnp.asarray(kv_offset)
-        if qoff.ndim or koff.ndim:
+        per_row_window = window is not None and jnp.ndim(window) > 0
+        if per_row_window:
+            window = jnp.reshape(window, (-1, 1, 1))
+        if qoff.ndim or koff.ndim or per_row_window:
             # per-batch-row offsets (serving: every KV-pool slot decodes
             # at its own absolute position) — (b,) or scalar, broadcast
             # to (b, sq, sk) then into the (b, 1, sq, sk) mask layout
             qpos = jnp.arange(sq)[None, :, None] + qoff.reshape(-1, 1, 1)
             kpos = jnp.arange(sk)[None, None, :] + koff.reshape(-1, 1, 1)
-            mask = mask & (qpos >= kpos)[:, None]
+            seen = qpos >= kpos
+            if window is not None:
+                seen = seen & (kpos > qpos - window)
+            mask = mask & seen[:, None]
         else:
             qpos = jnp.arange(sq)[:, None] + q_offset
             kpos = jnp.arange(sk)[None, :] + kv_offset
-            mask = mask & (qpos >= kpos)[None, None]
+            seen = qpos >= kpos
+            if window is not None:
+                seen = seen & (kpos > qpos - window)
+            mask = mask & seen[None, None]
+    elif window is not None:
+        raise ValueError("a window needs causal attention")
     if segment_ids is not None:
         kv_seg = kv_segment_ids if kv_segment_ids is not None else segment_ids
         mask = mask & (segment_ids[:, None, :, None] == kv_seg[:, None, None, :])

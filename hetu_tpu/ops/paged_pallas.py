@@ -29,7 +29,10 @@ use:
 - **dead-lane skip**: a ``pl.when`` on the scalar-prefetched per-slot
   position skips every page beyond the slot's live context, so cost
   scales with ``ceil(context / block_size)`` pages, not ``table_width``
-  (the long-prompt lane's wide tables ride free);
+  (the long-prompt lane's wide tables ride free); with a ``window``
+  (static ``None`` on models that have none: their program is the same
+  as before the option) the pages wholly below it are skipped too, and
+  not fetched;
 - **per-row ``q_offset`` semantics**: q row ``i`` of slot ``s`` attends
   absolute positions ``<= q_offset[s] + i`` — the speculative verify
   lane's k+1 rows (PR 11) and the packed-prefill per-token rows are the
@@ -56,6 +59,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -91,15 +95,20 @@ def default_pages_per_step(block_size: int) -> int:
     return max(1, min(8, 128 // max(1, int(block_size))))
 
 
-def _paged_kernel(tbl_ref, off_ref, lyr_ref, q_ref, *refs, rows, g, bs,
-                  L, hkv, n_steps, quant):
+def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
+                  n_steps, quant, windowed):
     """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
     ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
     page dims are squeezed out of the block — every kv head: a TPU
     block's last two dims must be (8, 128)-tiled or span the array's,
     so heads are lane slices taken inside the kernel). Online softmax
-    across chunks (grid axis 1 is "arbitrary")."""
+    across chunks (grid axis 1 is "arbitrary"). ``windowed``: a fourth
+    scalar operand holds each slot's attention window."""
     del lyr_ref                     # read by the page index maps only
+    win_ref = None
+    if windowed:
+        win_ref, *refs = refs
+    q_ref, *refs = refs
     s_i = pl.program_id(0)
     w = pl.program_id(1)
 
@@ -127,6 +136,19 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, q_ref, *refs, rows, g, bs,
     # attends absolute positions <= off + r // g
     qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // g
     last_q = off + (rows // g - 1)
+    if windowed:
+        # the first key the slot's FIRST row sees (the lowest any sees)
+        first_k = off - win_ref[s_i] + 1
+
+    # the online-softmax lines below speak lax, not jnp: this body is
+    # traced L * hkv times and each jnp call pays the jit machinery
+    # again (most of this kernel's trace time: seconds of every
+    # serving cell's warm-up); the jaxpr is the same
+    def rowwise(x):                 # (rows,) -> (rows, 1)
+        return lax.broadcast_in_dim(x, (rows, 1), (0,))
+
+    def lanes(x):                   # (rows, 1) -> (rows, NUM_LANES)
+        return lax.broadcast_in_dim(x, (rows, NUM_LANES), (0, 1))
 
     for j in range(L):
         page_start = (w * L + j) * bs
@@ -135,8 +157,14 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, q_ref, *refs, rows, g, bs,
             kpos = page_start + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bs), 1)
             mask = kpos <= qpos
-            for h in range(hkv):
-                q = q_ref[0, h]                  # (rows, d), scale folded
+            if windowed:
+                mask &= kpos > qpos - win_ref[s_i]
+            # a head's index as an array, made once a page: an int
+            # index is converted again at each of its eight uses, a
+            # third of this kernel's trace time (a literal either way)
+            heads = [jnp.asarray(h) for h in range(hkv)]
+            for h, at in enumerate(heads):
+                q = q_ref[heads[0], at]          # (rows, d), scale folded
                 head = slice(h * d, (h + 1) * d)
                 if quant:
                     # (bs, d) dequant in VMEM
@@ -151,26 +179,28 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, q_ref, *refs, rows, g, bs,
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
                 s = jnp.where(mask, s, NEG_INF)
-                m_prev = m_scr[h, :, :1]
-                l_prev = l_scr[h, :, :1]
-                m_cur = jnp.max(s, axis=1, keepdims=True)
-                m_next = jnp.maximum(m_prev, m_cur)
-                p = jnp.exp(s - m_next)
+                m_prev = m_scr[at, :, :1]
+                l_prev = l_scr[at, :, :1]
+                m_next = lax.max(m_prev, rowwise(lax.reduce_max(s, (1,))))
+                p = lax.exp(lax.sub(s, m_next))
                 p = jnp.where(mask, p, 0.0)
-                l_cur = jnp.sum(p, axis=1, keepdims=True)
-                alpha = jnp.exp(m_prev - m_next)
-                m_scr[h] = jnp.broadcast_to(m_next, m_scr.shape[1:])
-                l_scr[h] = jnp.broadcast_to(alpha * l_prev + l_cur,
-                                            l_scr.shape[1:])
+                l_cur = rowwise(lax.reduce_sum(p, (1,)))
+                alpha = lax.exp(lax.sub(m_prev, m_next))
+                m_scr[at] = lanes(m_next)
+                l_scr[at] = lanes(lax.add(lax.mul(alpha, l_prev), l_cur))
                 pv = jax.lax.dot_general(
                     p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-                acc_scr[h] = acc_scr[h] * alpha + pv
+                acc_scr[at] = lax.add(lax.mul(acc_scr[at], alpha), pv)
 
         # dead-lane skip: pages wholly beyond the slot's last live
         # position never touch the MXU (cost ∝ context, not table
-        # width; the table's null-block pad lanes land here too)
-        pl.when(page_start <= last_q)(compute)
+        # width; the table's null-block pad lanes land here too), nor
+        # do pages wholly below the window
+        live = page_start <= last_q
+        if windowed:
+            live &= page_start + bs > first_k
+        pl.when(live)(compute)
 
     @pl.when(w == n_steps - 1)
     def _finalize():
@@ -197,7 +227,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                            scale: Optional[float] = None,
                            pages_per_step: Optional[int] = None,
                            interpret: Optional[bool] = None,
-                           return_lse: bool = False):
+                           return_lse: bool = False, window=None):
     """Decode attention through per-slot block tables, in-kernel.
 
     - ``q``: ``(S, R, hq, d)`` — S slots × R rows (1 for classic decode,
@@ -222,6 +252,16 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
       ``s`` holds positions ``[w*block_size, (w+1)*block_size)`` at
       physical page ``block_tables[s, w]``.
     - ``q_offset``: ``(S,)`` int32 per-slot base position.
+    - ``window`` (``None`` = none; a static choice): row ``i`` of slot
+      ``s`` sees only keys ``> q_offset[s] + i - window`` — an int32
+      scalar or one value per slot ``(S,)``, traced (the layers of a
+      scanned block differ by it; the packed prefill lane's history
+      read gives every token its own), a FOURTH scalar-prefetch
+      operand. Pages wholly below a slot's window are neither computed
+      nor fetched: their index maps name the window's first page again,
+      and a block whose index did not change is not copied. A
+      full-attention layer of a model that has window layers passes a
+      window no key is ever below (``2 ** 30``).
 
     Returns ``(S, R, hq, d)`` in q's dtype (plus the fp32
     ``(S, R*… )``-shaped LSE ``(S, hq, R)`` when ``return_lse`` — the
@@ -252,6 +292,11 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         block_tables = jnp.pad(block_tables, ((0, 0), (0, Wp - W)))
     block_tables = block_tables.astype(jnp.int32)
     q_offset = jnp.asarray(q_offset, jnp.int32).reshape(S)
+    scalars = (block_tables, q_offset, layer)
+    windowed = window is not None
+    if windowed:
+        scalars += (jnp.broadcast_to(jnp.asarray(window, jnp.int32),
+                                     (S,)),)
     interpret = _interpret_default() if interpret is None else interpret
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
@@ -261,8 +306,8 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     qh = qf.reshape(S, R, hkv, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(S, hkv, rows, d)
 
-    q_spec = pl.BlockSpec((1, hkv, rows, d),
-                          lambda s, w, tbl, off, lyr: (s, 0, 0, 0))
+    def whole(s, w, *scalars):
+        return (s, 0, 0, 0)
 
     def page_spec(j, x):
         # one whole page of one layer, all kv heads: the block's last
@@ -271,29 +316,31 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         # tile. A 3-D operand is one layer already: its pages are read
         # at layer 0 of the one-layer stack.
         stacked = x.ndim == 4
-        return pl.BlockSpec(
-            (None, None, bs, x.shape[-1]),
-            lambda s, w, tbl, off, lyr, j=j:
-                (lyr[0] if stacked else 0, tbl[s, w * L + j], 0, 0))
 
-    in_specs = [q_spec]
+        def index(s, w, tbl, off, lyr, win=None):
+            lane = w * L + j
+            if windowed:
+                # table lanes below the slot's window name its first
+                lane = jnp.maximum(
+                    lane, jnp.maximum(off[s] - win[s] + 1, 0) // bs)
+            return (lyr[0] if stacked else 0, tbl[s, lane], 0, 0)
+
+        return pl.BlockSpec((None, None, bs, x.shape[-1]), index)
+
+    in_specs = [pl.BlockSpec((1, hkv, rows, d), whole)]
     args = [qh]
     for x in (k, v) + ((k_scale, v_scale) if quant else ()):
         in_specs += [page_spec(j, x) for j in range(L)]
         args += [_stacked(x)] * L
 
-    out_specs = [
-        pl.BlockSpec((1, hkv, rows, d),
-                     lambda s, w, tbl, off, lyr: (s, 0, 0, 0)),
-        pl.BlockSpec((1, hkv, rows, NUM_LANES),
-                     lambda s, w, tbl, off, lyr: (s, 0, 0, 0)),
-    ]
+    out_specs = [pl.BlockSpec((1, hkv, rows, d), whole),
+                 pl.BlockSpec((1, hkv, rows, NUM_LANES), whole)]
     out_shape = [
         jax.ShapeDtypeStruct((S, hkv, rows, d), q.dtype),
         jax.ShapeDtypeStruct((S, hkv, rows, NUM_LANES), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(S, n_steps),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -306,14 +353,15 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     with jax.named_scope("hetu.paged_attn"):
         out, lse_l = pl.pallas_call(
             functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
-                              hkv=hkv, n_steps=n_steps, quant=quant),
+                              hkv=hkv, n_steps=n_steps, quant=quant,
+                              windowed=windowed),
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
             name="hetu_paged_attn",
-        )(block_tables, q_offset, layer, *args)
+        )(*scalars, *args)
 
     # (S, hkv, R*g, d) → (S, R, hq, d)
     out = out.reshape(S, hkv, R, g, d).transpose(0, 2, 1, 3, 4) \
@@ -332,7 +380,7 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
                          scale: Optional[float] = None,
                          pages_per_step: Optional[int] = None,
                          interpret: Optional[bool] = None,
-                         return_lse: bool = False):
+                         return_lse: bool = False, window=None):
     """:func:`paged_attention_pallas`, tp-aware.
 
     Mosaic kernels cannot be GSPMD-auto-partitioned, so under a
@@ -354,7 +402,7 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
         return paged_attention_pallas(
             q, k, v, tbl, off, layer=layer, k_scale=ks, v_scale=vs,
             scale=scale, pages_per_step=pages_per_step,
-            interpret=interpret, return_lse=return_lse)
+            interpret=interpret, return_lse=return_lse, window=window)
 
     ctx = current_act_sharding()
     head_ax = ctx.tp if ctx is not None and isinstance(ctx.tp, str) \
@@ -366,6 +414,10 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
         # trace ever reaches here; keep the plain call as the safe twin
         return call(q, k, v, block_tables, q_offset, layer, k_scale,
                     v_scale)
+    if window is not None:
+        raise NotImplementedError(
+            "a windowed paged call under a tp-sharded plan: the window "
+            "would have to ride the shard_map as an operand")
 
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -400,7 +452,7 @@ def paged_attention_reference(q, k, v, block_tables, q_offset, *,
                               k_scale=None, v_scale=None,
                               scale: Optional[float] = None,
                               causal: bool = True,
-                              return_lse: bool = False):
+                              return_lse: bool = False, window=None):
     """The XLA-gather twin (and parity oracle): materialize each slot's
     table view with :func:`~hetu_tpu.ops.attention.gather_block_rows`
     and run the dense reference — exactly what ``ParallelAttention.
@@ -427,7 +479,8 @@ def paged_attention_reference(q, k, v, block_tables, q_offset, *,
         k_buf, v_buf = rows(k, d), rows(v, d)
     return attention_reference(q, k_buf, v_buf, causal=causal,
                                q_offset=q_offset, kv_offset=0,
-                               scale=scale, return_lse=return_lse)
+                               scale=scale, return_lse=return_lse,
+                               window=window)
 
 
 def combine_attention_lse(o1, lse1, o2, lse2):

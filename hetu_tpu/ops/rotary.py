@@ -23,12 +23,15 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float = 10000.0,
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
 
 
-def apply_rotary(x, cos, sin, positions: Optional[jnp.ndarray] = None):
+def apply_rotary(x, cos, sin, positions: Optional[jnp.ndarray] = None,
+                 *, interleaved: bool = False):
     """Apply RoPE to ``x`` of shape (..., seq, heads, head_dim).
 
     ``cos``/``sin``: (max_len, head_dim//2) tables. ``positions``: optional
     (..., seq) int array for packed sequences; defaults to arange(seq).
-    Rotation uses the "split-half" convention (Llama/NeoX style).
+    Rotation uses the "split-half" convention (Llama/NeoX style: pair
+    ``i`` is dims ``i, i + head_dim/2``) unless ``interleaved``, the
+    GPT-J convention (``rope_gptj``): pair ``i`` is dims ``2i, 2i+1``.
     """
     seq = x.shape[-3]
     if positions is None:
@@ -41,8 +44,15 @@ def apply_rotary(x, cos, sin, positions: Optional[jnp.ndarray] = None):
         cos_t = jnp.take(cos, positions, axis=0)[..., :, None, :]
         sin_t = jnp.take(sin, positions, axis=0)[..., :, None, :]
     half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+    if interleaved:
+        pairs = x.reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out1 = xf1 * cos_t - xf2 * sin_t
     out2 = xf2 * cos_t + xf1 * sin_t
+    if interleaved:
+        return jnp.stack([out1, out2], axis=-1).reshape(x.shape) \
+            .astype(x.dtype)
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)

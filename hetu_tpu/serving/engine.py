@@ -217,6 +217,11 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
         adapter_pages=reg.gauge(
             "adapter_pages_in_use",
             "adapter arena pages holding a resident adapter"),
+        window_dead=reg.gauge(
+            "kv_window_dead_blocks",
+            "blocks held by decoding slots that lie wholly below the "
+            "attention window: no query of a window layer can see them, "
+            "and the uniform arena holds their rows for every layer"),
     )
 
 
@@ -616,6 +621,11 @@ class ServingEngine:
         self._w8a8_wq = self._prequantize_decode_weights()
 
         self._m = _bind_metrics(telemetry.get_registry())
+        # the smallest window of the model's layers (None: no layer has
+        # one) — for the kv_window_dead_blocks gauge only
+        ld = getattr(model.blocks, "layer_data", None) or {}
+        self._min_window = int(np.min(ld["window"])) \
+            if "window" in ld else None
         self._prefill_path = "flash" if prefill_attn != "reference" \
             else "reference"
         self._fn = self._build_step()
@@ -781,14 +791,15 @@ class ServingEngine:
                 # multi-tenant BGMV: every token row carries its slot's
                 # adapter arena page as DATA (page 0 = base, bitwise) —
                 # adapter load/evict/mixed-tenant churn never retraces
-                logits, caches = generation.decode(
+                logits, caches, stats = generation.decode(
                     model, params, tok_in, positions, caches,
                     slot_mask=ctl["active"], block_tables=bt,
                     row_mask=row_valid, attn_kernel=kern,
                     w8a8_mask=w8a8_mask, w8a8_wq=wq,
                     lora={"ids": jnp.broadcast_to(
                         ctl["adapter"][:, None], tok_in.shape),
-                        "pages": lora} if lora else None)
+                        "pages": lora} if lora else None,
+                    with_stats=True)
                 # proposal probs q: host draftsmen propose
                 # deterministically — their q is the one-hot of the
                 # draft, synthesized here so the host never ships a
@@ -810,18 +821,24 @@ class ServingEngine:
                 # sampling stream has to match one-shot generate
                 new_kd = jnp.where(ctl["active"][:, None],
                                    new_kd, ctl["key"])
-                return caches, committed, ncommit, last_tok, new_kd
+                return caches, committed, ncommit, last_tok, new_kd, stats
 
             def no_decode(caches):
                 S = ctl["pos"].shape[0]
                 z = jnp.zeros((S,), jnp.int32)
                 return (caches, jnp.zeros((S, K + 1), jnp.int32),
-                        z, z, ctl["key"])
+                        z, z, ctl["key"],
+                        model.blocks.layer_stats_zeros())
 
+            # what the layers of each lane report beside their result
+            # (``StackedBlocks.decode(with_stats=)``; nothing, from most
+            # models) leaves the step among its results: a host callback
+            # inside it would hold the device and keep the executable
+            # out of the compile cache
             with jax.named_scope("hetu.decode_lane"):
-                caches, committed, ncommit, last_tok, new_kd = \
-                    jax.lax.cond(ctl["active"].any(), do_decode,
-                                 no_decode, caches)
+                caches, committed, ncommit, last_tok, new_kd, dec_stats \
+                    = jax.lax.cond(ctl["active"].any(), do_decode,
+                                   no_decode, caches)
 
             # packed prefill: a C-token budget shared by every
             # admitting request — per-token (slot, position) operands
@@ -841,7 +858,7 @@ class ServingEngine:
                     pos = pf["pos"][None, :]                 # (1, C)
                     h = model.embed(params, pf["tokens"][None, :],
                                     positions=pos)
-                    h, caches = model.blocks.decode(
+                    h, caches, stats = model.blocks.decode(
                         params["blocks"], h, caches, positions=pos,
                         block_tables=jnp.take(bt, pf["slot"], axis=0),
                         attn_kernel=kern,
@@ -851,20 +868,22 @@ class ServingEngine:
                               "impl": pack_impl},
                         lora={"ids": jnp.take(ctl["adapter"],
                                               pf["slot"])[None, :],
-                              "pages": lora} if lora else None)
+                              "pages": lora} if lora else None,
+                        with_stats=True)
                     hrow = h[0]                              # (C, E)
                 else:
                     pos = pf["pos"][:, None]                 # (C, 1)
                     h = model.embed(params, pf["tokens"][:, None],
                                     positions=pos)
-                    h, caches = model.blocks.decode(
+                    h, caches, stats = model.blocks.decode(
                         params["blocks"], h, caches, positions=pos,
                         slot_mask=pf["valid"],
                         block_tables=jnp.take(bt, pf["slot"], axis=0),
                         attn_kernel=kern,
                         lora={"ids": jnp.take(ctl["adapter"],
                                               pf["slot"])[:, None],
-                              "pages": lora} if lora else None)
+                              "pages": lora} if lora else None,
+                        with_stats=True)
                     hrow = h[:, 0]                           # (C, E)
                 # FIRST tokens for the <= R requests whose prefill
                 # completes this iteration: head only on their last
@@ -897,14 +916,15 @@ class ServingEngine:
                         jnp.take(ctl["topk"], fs),
                         jnp.take(ctl["topp"], fs),
                         jnp.take(ctl["key"], fs, axis=0))
-                return caches, firsts, pf_kd
+                return caches, firsts, pf_kd, stats
 
             def no_prefill(caches):
                 return (caches, jnp.zeros((R,), jnp.int32),
-                        jnp.take(ctl["key"], pf["fin_slot"], axis=0))
+                        jnp.take(ctl["key"], pf["fin_slot"], axis=0),
+                        model.blocks.layer_stats_zeros())
 
             with jax.named_scope("hetu.prefill_lane"):
-                caches, first_toks, pf_kd = jax.lax.cond(
+                caches, first_toks, pf_kd, pf_stats = jax.lax.cond(
                     pf["run"], do_prefill, no_prefill, caches)
             # prefill completions ADOPT their post-sample key state:
             # scatter the <= R finished rows' keys over the slot axis
@@ -925,13 +945,13 @@ class ServingEngine:
             new_last = jnp.where(ctl["active"], last_tok,
                                  ctl["last_tok"])
             return (caches, committed, ncommit, first_toks,
-                    new_pos, new_last, new_key)
+                    new_pos, new_last, new_key, (dec_stats, pf_stats))
 
         # what the step returns AND takes again keeps its home
         # (__init__): the arena, and the advanced pos/last_tok/key
         rep = self._rep
         return jax.jit(step, donate_argnums=(1,), out_shardings=(
-            self._arena_sh, None, None, None, rep, rep, rep))
+            self._arena_sh, None, None, None, rep, rep, rep, None))
 
     # -- the CP-prefill lane ------------------------------------------------
     def _build_cp_prefill(self):
@@ -2385,7 +2405,7 @@ class ServingEngine:
             self._register_device_scopes(args)
         with span("serve/dispatch"), ctx:
             (caches, committed, ncommit, first_toks, pos_dev,
-             last_dev, key_dev) = self._fn(*args)
+             last_dev, key_dev, lane_stats) = self._fn(*args)
         del args                    # the arena was donated
         self.pool.caches = caches
         with span("serve/device_wait"):
@@ -2407,6 +2427,14 @@ class ServingEngine:
                 m.attn_kernel.inc(path=self.attn_kernel)
             if used:
                 m.prefill_kernel.inc(path=self._prefill_path)
+            if telemetry.enabled():
+                # the layers' stats of the lanes that ran, to the host
+                # functions the model's block names for them
+                emit = getattr(self.model.blocks.block, "layer_stats", {})
+                for ran, stats in zip((active_prev.size, used),
+                                      lane_stats):
+                    for name, values in stats.items() if ran else ():
+                        emit[name][2](np.asarray(values))
             # decode results for the slots that were active going in:
             # each commits ncommit tokens (accepted drafts + bonus) —
             # EOS or budget can finish the request mid-commit, in which
@@ -2664,6 +2692,12 @@ class ServingEngine:
                           tier="replica")
         if self.tenancy is not None:
             m.adapter_pages.set(self.tenancy.registry.pages_in_use)
+        if self._min_window is not None:
+            # the next query of an active slot sits at pos: blocks whose
+            # last row is at or below pos - window are dead to it
+            below = self._pos[self._active] - self._min_window + 1
+            m.window_dead.set(int(
+                (np.maximum(below, 0) // self.pool.block_size).sum()))
 
     def run_until_drained(self, max_steps: int = 1_000_000) -> int:
         """Drive :meth:`step` until queue + slots are empty; returns the

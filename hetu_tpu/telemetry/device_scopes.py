@@ -23,6 +23,9 @@ The vocabulary (all start ``hetu.``; ``docs/OBSERVABILITY.md``):
 ``hetu.decode_lane``  the fused serving step's decode/verify lane
 ``hetu.kv_arena``     KV arena writes (paged scatters, CoW copies)
 ``hetu.sample``       sampling: logits adjustment, draws, verify
+``hetu.moe_route``    expert-share MoE: router, top-k, sort, row gather
+``hetu.moe_experts``  expert-share MoE: grouped matmuls, weighting, unsort
+``hetu.moe_shared``   the averaged shared experts' gated MLP
 ====================  ================================================
 
 The rule (:func:`classify`): an instruction belongs to the INNERMOST
@@ -32,7 +35,11 @@ looked into); under ``hetu.loss`` it is ``bwd`` when the path holds
 ``transpose(`` or a remat recomputation (``rematted_computation``), else
 ``fwd`` (a flash kernel whose name lost the loss's wrapper is in the pass
 its own name says); a fusion without metadata of its own is its root's;
-no ``hetu.`` component at all is ``unscoped``.
+an instruction the COMPILER made from a program's op and named after
+itself (``op_name="ragged-dot-none"``: no name stack at all;
+``COMPILER_NAMED`` lists them) is its first consumer's that has a
+scope; no ``hetu.`` component at all is
+``unscoped``.
 
 Steps make themselves readable through :func:`register_step` when they
 are first built (``engine.precompile`` for the AOT train step, the
@@ -55,7 +62,13 @@ VOCABULARY = (
     "hetu.loss", "hetu.opt", "hetu.flash_fwd", "hetu.flash_bwd",
     "hetu.paged_attn", "hetu.fused_ce", "hetu.prefill_lane",
     "hetu.decode_lane", "hetu.kv_arena", "hetu.sample",
+    "hetu.moe_route", "hetu.moe_experts", "hetu.moe_shared",
 )
+
+#: ``op_name``s the TPU compiler gives an op it made from a program's
+#: op, in place of the name stack (listed, not guessed: parameters and
+#: ``reduce_sum`` carry a bare name too, and stay ``unscoped``)
+COMPILER_NAMED = ("ragged-dot",)
 
 _SCOPE = re.compile(r"hetu\.[a-z_0-9]+")
 _KERNEL_PHASE = {"hetu.flash_fwd": "fwd", "hetu.flash_bwd": "bwd"}
@@ -65,6 +78,7 @@ _INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
 #: registered steps kept (oldest dropped): a thunk pins its executable
 MAX_REGISTERED = 32
 
@@ -114,6 +128,7 @@ def describe(hlo_text: str) -> dict[str, Scope]:
     """``{instruction name: Scope}`` for every instruction of an
     optimized HLO module's text (``compiled.as_text()``)."""
     op_names: dict[str, str] = {}
+    users: dict[str, list] = {}
     calls: dict[str, str] = {}
     roots: dict[str, str] = {}       # computation -> its ROOT instruction
     computation = None
@@ -128,6 +143,8 @@ def describe(hlo_text: str) -> dict[str, Scope]:
         is_root, name, rest = m.groups()
         if is_root and computation is not None:
             roots[computation] = name
+        for operand in _OPERAND.findall(rest):
+            users.setdefault(operand, []).append(name)
         op = _OP_NAME.search(rest)
         if op is not None:
             op_names[name] = op.group(1)
@@ -136,9 +153,26 @@ def describe(hlo_text: str) -> dict[str, Scope]:
             called = _CALLS.search(rest)
             if called is not None:
                 calls[name] = called.group(1)
+    def consumers_op(name: str, depth: int) -> str:
+        """The first consumer's op_name that has a scope, looking
+        through consumers that have no metadata at all (a
+        get-tuple-element)."""
+        for user in users.get(name, ()):
+            found = op_of(user, depth + 1)
+            if not found and depth < 8:
+                found = consumers_op(user, depth + 1)
+            if "hetu." in found:
+                return found
+        return ""
+
     def op_of(name: str, depth: int = 0) -> str:
         op = op_names.get(name, "")
-        if op or depth > 8:
+        if depth > 8:
+            return op
+        if op.startswith(COMPILER_NAMED):
+            # the compiler's own op, named after itself: its consumer's
+            return consumers_op(name, depth) or op
+        if op:
             return op
         # a fusion without metadata of its own is its root's
         root = roots.get(calls.get(name, ""))

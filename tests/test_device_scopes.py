@@ -106,6 +106,33 @@ ENTRY %main.3 (a: f32[8]) -> f32[8] {
     assert got["copy.2"] == "hetu.sample" and got["a"] == "unscoped"
 
 
+def test_a_compiler_made_op_is_its_consumers():
+    """The TPU compiler rewrites ``jax.lax.ragged_dot`` into custom
+    calls named after themselves (``op_name="ragged-dot-none"``: the
+    program's name stack is gone): they take the scope of their first
+    consumer that has one — through a get-tuple-element too. An op with
+    a name stack and no ``hetu.`` in it stays unscoped."""
+    hlo = """HloModule jit_f
+
+ENTRY %main.9 (x: bf16[8,4], w: bf16[2,4,4], gs: s32[2]) -> f32[8,4] {
+  %x = bf16[8,4]{1,0} parameter(0)
+  %w = bf16[2,4,4]{2,1,0} parameter(1)
+  %gs = s32[2]{0} parameter(2)
+  %ragged-dot-metadata = (s32[3]{0}, s32[1]{0}) custom-call(%gs), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-metadata"}
+  %get-tuple-element = s32[1]{0} get-tuple-element(%ragged-dot-metadata), index=1
+  %ragged-dot-none = f32[8,4]{1,0} custom-call(%get-tuple-element, %x, %w), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %convert.1 = f32[8,4]{1,0} convert(%x), metadata={op_name="jit(f)/convert_element_type"}
+  ROOT %multiply.2 = f32[8,4]{1,0} multiply(%ragged-dot-none, %convert.1), metadata={op_name="jit(f)/hetu.decode_lane/hetu.moe_experts/mul"}
+}
+"""
+    got = device_scopes.describe(hlo)
+    assert got["ragged-dot-none"].label == "hetu.moe_experts"
+    assert got["ragged-dot-none"].path == ("hetu.decode_lane",
+                                           "hetu.moe_experts")
+    assert got["ragged-dot-metadata"].label == "hetu.moe_experts"
+    assert got["convert.1"].label == "unscoped"
+
+
 # -- the compiled steps -------------------------------------------------------
 def test_train_step_scopes_and_lazy_map(fresh_scopes):
     tr = _trainer(attn_impl="pallas")

@@ -79,6 +79,35 @@ def test_paged_kernel_matches_reference_gqa_and_verify_rows():
                                    atol=1e-5)
 
 
+@pytest.mark.parametrize("window", [3, 4, 9, [2, 8, 30], 2 ** 30])
+def test_paged_kernel_window_matches_reference(window):
+    """A windowed call (row ``i`` of slot ``s`` sees keys ``> offset +
+    i - window``; one scalar, or one per slot as the prefill lane's
+    history read passes it) == the XLA-gather oracle with the same
+    window: edges inside a page (3, 9) and on a page boundary (4, pages
+    of 4), verify rows, every ``pages_per_step`` tiling — pages wholly
+    below the window are skipped and their index maps name the window's
+    first page, which must not change the result. ``2 ** 30`` is the
+    full-attention layer of a model that mixes both kinds."""
+    rng = np.random.default_rng(7)
+    q, k, v, tbl = _arena(rng, R=3)
+    off = jnp.asarray([0, 5, 17], jnp.int32)
+    win = jnp.asarray(window, jnp.int32)
+    ref, lse_r = paged_attention_reference(q, k, v, tbl, off,
+                                           return_lse=True, window=win)
+    full = paged_attention_reference(q, k, v, tbl, off)
+    if np.max(window) < 17:
+        assert np.abs(np.asarray(ref) - np.asarray(full)).max() > 1e-2
+    for pages in (1, 3, 8):
+        out, lse = paged_attention_pallas(q, k, v, tbl, off, window=win,
+                                          pages_per_step=pages,
+                                          return_lse=True)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r),
+                                   atol=1e-5)
+
+
 def test_paged_kernel_int8_arena_lane():
     """Int8 arenas stream quantized pages + fp32 scales and dequantize
     per tile — same numbers as gather-then-dequantize."""

@@ -649,13 +649,14 @@ def test_serving_loop_watchdog_trips_on_stalled_step(telem, tmp_path):
     def fake_fn(params, caches, ctl, pf, bt, cow, spec, wq, lora):
         if hang.is_set():
             time.sleep(4.0)          # the stalled fake step (2x floor)
-        # the 9-operand/7-result contract (ISSUE 17 sampled verify
+        # the 9-operand/8-result contract (ISSUE 17 sampled verify
         # lane + ISSUE 20 adapter arena): committed tokens (S, K+1) +
         # per-slot commit counts + prefill first tokens +
-        # pos/last_tok/key carries
+        # pos/last_tok/key carries + the two lanes' layer stats
+        # (PR 26; none from a GPT-2 block)
         return (caches, np.zeros((S, 1), np.int32),
                 np.ones(S, np.int32), np.zeros(R, np.int32),
-                ctl["pos"], ctl["last_tok"], ctl["key"])
+                ctl["pos"], ctl["last_tok"], ctl["key"], ({}, {}))
 
     eng._fn = fake_fn
     eng.start(idle_sleep_s=0.001)

@@ -91,6 +91,22 @@ def test_paged_decode_gqa_d128_compiles_for_v5e(one_chip):
                                       kv_heads=8, head_dim=128)
 
 
+@pytest.mark.parametrize("slots,return_lse", [(48, False), (256, True)],
+                         ids=["decode", "history"])
+def test_paged_decode_windowed_group16_compiles_for_v5e(one_chip, slots,
+                                                        return_lse):
+    """The windowed call at ``command-a-plus-ep8``'s widths: 128 query
+    heads over 8 KV heads of 128 (16 rows a KV head), pages of 64, a
+    128-page table, a window per slot — the decode rows, and the
+    prefill lane's history read (one row group per packed token, with
+    the LSE)."""
+    from workloads.aot_check import check_paged
+    assert "compile_s" in check_paged(
+        list(one_chip.device_set), heads=128, kv_heads=8, head_dim=128,
+        layers=4, block_size=64, table_width=128, n_blocks=4250,
+        slots=slots, return_lse=return_lse, windowed=True)
+
+
 def test_paged_decode_one_layer_arena_compiles_for_v5e(one_chip):
     """The 3-D ``(n_blocks, block_size, hkv*d)`` arena without ``layer``
     is the one-layer case of the same call."""

@@ -115,14 +115,16 @@ def _compile_kernel(f, args):
 
 def check_paged(devs, *, dtype=jnp.bfloat16, rows=1, return_lse=False,
                 slots=8, n_blocks=2048, block_size=16, table_width=64,
-                heads=12, kv_heads=None, head_dim=64, layers=12):
+                heads=12, kv_heads=None, head_dim=64, layers=12,
+                windowed=False):
     """The paged decode kernel over an arena in its stored layout
     (``serving/kv_pool.py``: the stacked ``(layers, n_blocks,
     block_size, hkv*d)`` leaf read at a traced ``layer``, as the fused
     serving step calls it; int8 scales ``(..., hkv)``) — by default
     GPT-2 small's. ``layers=None`` is one layer's 3-D arena, the
     one-layer case of the same call. ``rows``: 1 = classic decode, k+1
-    = the speculative verify lane."""
+    = the speculative verify lane. ``windowed``: the call with a
+    per-slot window operand (a model with window layers)."""
     from hetu_tpu.ops.paged_pallas import paged_attention_pallas
     mesh = _one_dev_mesh(devs)
     hkv = kv_heads or heads
@@ -132,16 +134,17 @@ def check_paged(devs, *, dtype=jnp.bfloat16, rows=1, return_lse=False,
              jnp.bfloat16 if quant else dtype, mesh)
     page = _sds(lead + (block_size, hkv * head_dim), dtype, mesh)
     args = [q, page, page, _sds((slots, table_width), jnp.int32, mesh),
-            _sds((slots,), jnp.int32, mesh), _sds((), jnp.int32, mesh)]
+            _sds((slots,), jnp.int32, mesh), _sds((), jnp.int32, mesh),
+            _sds((slots,), jnp.int32, mesh)]
     if quant:
         args += [_sds(lead + (block_size, hkv), jnp.float32, mesh)] * 2
 
-    def f(q, k, v, tbl, off, layer, *scales):
+    def f(q, k, v, tbl, off, layer, window, *scales):
         ks, vs = scales if scales else (None, None)
         return paged_attention_pallas(
             q, k, v, tbl, off, layer=None if layers is None else layer,
             k_scale=ks, v_scale=vs, interpret=False,
-            return_lse=return_lse)
+            return_lse=return_lse, window=window if windowed else None)
 
     return _compile_kernel(f, args)
 
@@ -298,7 +301,8 @@ def check_serving_lane(devs, *, lane="decode", dtype=jnp.bfloat16,
 
 
 def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
-                       slots=148, n_blocks=9473, max_len=1024, chunk=256):
+                       slots=148, n_blocks=9473, max_len=1024, chunk=256,
+                       model=None, block_size=16):
     """The REAL fused serving step (``ServingEngine._build_step``: CoW
     pass, decode lane, packed flash prefill lane, sampling) compiled
     for the target at a benchmark cell's sizes (defaults: GPT-2 small,
@@ -306,20 +310,29 @@ def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
     ``gpt2-large.backlog``). The engine is built on this host with a
     toy arena; the operands of its first dispatch are recorded, not
     run, and the step is lowered from their abstract shapes with the
-    arena at ``n_blocks`` on the described device."""
+    arena at ``n_blocks`` on the described device. ``model=`` is any
+    other model with the engine's interface (its weights are zeros
+    here: only their shapes reach the compiler)."""
     from jax.sharding import SingleDeviceSharding
     from hetu_tpu.models import GPTConfig, GPTLMHeadModel
     from hetu_tpu.serving import ServingEngine
 
-    cfg = GPTConfig.small() if config == "small" else GPTConfig(
-        vocab_size=50257, max_positions=1024, hidden_size=1280,
-        num_layers=36, num_heads=20)
-    model = GPTLMHeadModel(cfg)
-    params = jax.jit(lambda k: model.init(k, dtype=jnp.bfloat16))(
-        jax.random.key(0))
+    if model is None:
+        cfg = GPTConfig.small() if config == "small" else GPTConfig(
+            vocab_size=50257, max_positions=1024, hidden_size=1280,
+            num_layers=36, num_heads=20)
+        model = GPTLMHeadModel(cfg)
+        params = jax.jit(lambda k: model.init(k, dtype=jnp.bfloat16))(
+            jax.random.key(0))
+    else:
+        params = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
+                           jax.random.key(0)))
     eng = ServingEngine(model, params, max_len=max_len,
                         prefill_chunk=chunk, cache_dtype=dtype,
-                        block_size=16, slots=slots, kv_blocks=max_len // 16 + 1,
+                        block_size=block_size, slots=slots,
+                        kv_blocks=max_len // block_size + 1,
                         attn_kernel="paged", prefill_attn="flash_pallas")
 
     class Recorded(Exception):

@@ -670,9 +670,13 @@ class ParallelAttention(Module):
         ``pack`` switches to the PACKED-PREFILL flash mode
         (:meth:`_decode_packed`): ``x`` is one ``(1, C, embed)`` row of
         C pack tokens from many requests, with per-token
-        ``block_tables`` (C, W) / ``positions`` (1, C) and pack dict
-        keys ``segment_ids`` (1, C), ``hist`` (C,), ``valid`` (C,),
-        ``impl``."""
+        ``block_tables`` (C, W) / ``positions`` (1, C) (the KV writes'
+        and the gather path's) and pack dict keys ``segment_ids``
+        (1, C), ``hist`` (C,), ``valid`` (C,), ``impl`` and, for
+        ``attn_kernel="paged"``, ``tiles``: ``map``, the pack's tile
+        map (``ops.paged_pallas.pack_history_tiles``), ``tables``
+        (G, W), each tile's request's block table, and ``rows``, the
+        static tile size."""
         if pack is not None:
             return self._decode_packed(params, x, kv_cache,
                                        positions=positions,
@@ -836,7 +840,11 @@ class ParallelAttention(Module):
           prompt, prefix-cache hits — through its block table, masked
           to positions ``< pack["hist"][t]`` (the token's chunk-start
           offset, so the rows this very pack just scattered are
-          excluded: the intra part owns them).
+          excluded: the intra part owns them). On the kernel path the
+          tokens of one request's run share ONE pass over its pages per
+          tile of the chunk (``pack["tiles"]``:
+          ``ops.paged_pallas.paged_history_attention``); the gather
+          path keeps one row per token.
 
         KV writes stay per-token scatters through the tables (pads drop
         out of bounds) at ``[layer, row]`` of the stacked leaves
@@ -905,36 +913,44 @@ class ParallelAttention(Module):
 
         from hetu_tpu.ops.attention import attention_with_lse
         from hetu_tpu.ops.paged_pallas import (
-            combine_attention_lse, paged_attention_auto,
-            paged_attention_reference,
+            combine_attention_lse, paged_attention_reference,
+            paged_history_attention,
         )
         intra, lse_i = attention_with_lse(
             q, k, v, causal=self.causal,
             segment_ids=pack["segment_ids"], impl=pack["impl"])
 
-        qh = q[0][:, None]                       # (C, 1, hq, d) rows
-        hist_off = pack["hist"].astype(jnp.int32) - 1   # kpos <= hist-1
-        if window is not None:
-            if self.min_window is not None and C > self.min_window:
-                raise ValueError(
-                    f"a prefill pack of {C} tokens is longer than the "
-                    f"model's window {self.min_window}: in-pack keys "
-                    f"would fall below it")
-            # the history lane's row sits at hist - 1 and the token at
-            # pos: keys > pos - window are keys > (hist - 1) - (window
-            # - (pos - hist + 1)) — a window shorter by the token's
-            # depth in its chunk, one per token
-            arena["window"] = window - (pos - hist_off)
+        if window is not None and self.min_window is not None \
+                and C > self.min_window:
+            raise ValueError(
+                f"a prefill pack of {C} tokens is longer than the "
+                f"model's window {self.min_window}: in-pack keys "
+                f"would fall below it")
         if attn_kernel == "paged":
-            hist, lse_h = paged_attention_auto(
-                qh, ka, va, block_tables, hist_off, layer=layer,
-                return_lse=True, **arena)
+            # one pass over a request's pages per TILE of its chunk:
+            # the tile's rows sit at their own positions under a key
+            # cap of hist - 1, so the layer's window is the model's
+            tiles = pack["tiles"]
+            hist, lse_h = paged_history_attention(
+                q[0], ka, va, tiles["tables"], pack["hist"],
+                tiles["map"], tile_rows=tiles["rows"], layer=layer,
+                window=window, **arena)
+            hist, lse_h = hist[None], lse_h.T[None]  # (1,C,hq,d) (1,hq,C)
         else:
+            # the per-token formulation (the CPU path and the parity
+            # oracle): every token is a one-row slot at hist - 1
+            hist_off = pack["hist"].astype(jnp.int32) - 1
+            if window is not None:
+                # the row sits at hist - 1 and the token at pos: keys
+                # > pos - window are keys > (hist - 1) - (window - (pos
+                # - hist + 1)) — a window shorter by the token's depth
+                # in its chunk, one per token
+                arena["window"] = window - (pos - hist_off)
             hist, lse_h = paged_attention_reference(
-                qh, _at_layer(ka, layer), _at_layer(va, layer),
+                q[0][:, None], _at_layer(ka, layer), _at_layer(va, layer),
                 block_tables, hist_off, return_lse=True, **arena)
-        hist = hist[:, 0][None]                  # (1, C, hq, d)
-        lse_h = lse_h[:, :, 0].T[None]           # (C, hq, 1) → (1, hq, C)
+            hist = hist[:, 0][None]                  # (1, C, hq, d)
+            lse_h = lse_h[:, :, 0].T[None]           # (C, hq, 1) → (1, hq, C)
         out = combine_attention_lse(intra, lse_i, hist, lse_h)
         out = out.reshape(b, C, self.num_heads * self.head_dim)
         return lora_apply(lora, "out_proj", out,

@@ -35,8 +35,14 @@ use:
   not fetched;
 - **per-row ``q_offset`` semantics**: q row ``i`` of slot ``s`` attends
   absolute positions ``<= q_offset[s] + i`` — the speculative verify
-  lane's k+1 rows (PR 11) and the packed-prefill per-token rows are the
-  same contract ``attention_reference(q_offset=array)`` speaks;
+  lane's k+1 rows (PR 11) are the contract
+  ``attention_reference(q_offset=array)`` speaks;
+- **tiles of a prefill pack** (:func:`paged_history_attention`): the
+  packed-prefill lane's read of each request's RESIDENT history. The
+  tokens of one request's run share one pass over that request's pages
+  per tile of the chunk — the same kernel under a key cap, on a grid
+  whose two bounds are data (the tiles that have history x the table
+  chunks under the deepest cap);
 - **arena-layout lanes**: fp32/bf16 arenas stream directly; the int8
   arena streams quantized pages + their fp32 scales and dequantizes
   per tile in VMEM (1/4 the HBM bytes of a dequantized gather).
@@ -96,18 +102,25 @@ def default_pages_per_step(block_size: int) -> int:
 
 
 def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
-                  n_steps, quant, windowed):
+                  n_steps, quant, windowed, tiled=False):
     """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
     ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
     page dims are squeezed out of the block — every kv head: a TPU
     block's last two dims must be (8, 128)-tiled or span the array's,
     so heads are lane slices taken inside the kernel). Online softmax
     across chunks (grid axis 1 is "arbitrary"). ``windowed``: a fourth
-    scalar operand holds each slot's attention window."""
+    scalar operand holds each slot's attention window. ``tiled``: slot
+    ``s`` is a TILE of a prefill pack — four more scalar operands hold
+    its key cap, the q cell it reads (the index maps' business) and the
+    cell's rows ``[lo, hi)`` that are its own; the grid's bounds are
+    data."""
     del lyr_ref                     # read by the page index maps only
     win_ref = None
     if windowed:
         win_ref, *refs = refs
+    if tiled:
+        cap_ref, _, lo_ref, hi_ref, *refs = refs
+        n_steps = pl.num_programs(1)
     q_ref, *refs = refs
     s_i = pl.program_id(0)
     w = pl.program_id(1)
@@ -136,6 +149,12 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     # attends absolute positions <= off + r // g
     qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // g
     last_q = off + (rows // g - 1)
+    if tiled:
+        # the tile's own rows are [lo, hi) of the cell and none sees a
+        # key above the cap (they sit ABOVE the keys they read)
+        cap, lo, hi = cap_ref[s_i], lo_ref[s_i], hi_ref[s_i]
+        last_q = jnp.minimum(off + hi - 1, cap)
+        off = off + lo
     if windowed:
         # the first key the slot's FIRST row sees (the lowest any sees)
         first_k = off - win_ref[s_i] + 1
@@ -157,6 +176,8 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
             kpos = page_start + jax.lax.broadcasted_iota(
                 jnp.int32, (rows, bs), 1)
             mask = kpos <= qpos
+            if tiled:
+                mask &= kpos <= cap
             if windowed:
                 mask &= kpos > qpos - win_ref[s_i]
             # a head's index as an array, made once a page: an int
@@ -206,10 +227,21 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     def _finalize():
         l = l_scr[:, :, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        if tiled:
+            # the cell's other rows are another tile's (the block stays
+            # put between tiles of one cell) or nobody's
+            row = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
+            own = (row >= lo * g) & (row < hi * g)
+
+            def put(ref, new):
+                ref[0] = jnp.where(own, new.astype(ref.dtype), ref[0])
+        else:
+            def put(ref, new):
+                ref[0] = new.astype(ref.dtype)
+        put(o_ref, acc_scr[...] / l_safe)
         lse = jnp.where(l == 0.0, NEG_INF,
                         m_scr[:, :, :1] + jnp.log(l_safe))
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        put(lse_ref, jnp.broadcast_to(lse, lse_ref.shape[1:]))
 
 
 def _stacked(x):
@@ -227,13 +259,13 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                            scale: Optional[float] = None,
                            pages_per_step: Optional[int] = None,
                            interpret: Optional[bool] = None,
-                           return_lse: bool = False, window=None):
+                           return_lse: bool = False, window=None,
+                           tiles=None):
     """Decode attention through per-slot block tables, in-kernel.
 
     - ``q``: ``(S, R, hq, d)`` — S slots × R rows (1 for classic decode,
-      k+1 for the speculative verify lane, C×1 for the packed-prefill
-      per-token rows); row ``i`` of slot ``s`` attends absolute
-      positions ``<= q_offset[s] + i``.
+      k+1 for the speculative verify lane); row ``i`` of slot ``s``
+      attends absolute positions ``<= q_offset[s] + i``.
     - ``k``/``v``: the paged arena as stored — the STACKED leaves
       ``(layers, n_blocks, block_size, hkv*d)`` of
       ``models.generation.init_paged_caches`` with ``layer`` (an int32
@@ -255,13 +287,27 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     - ``window`` (``None`` = none; a static choice): row ``i`` of slot
       ``s`` sees only keys ``> q_offset[s] + i - window`` — an int32
       scalar or one value per slot ``(S,)``, traced (the layers of a
-      scanned block differ by it; the packed prefill lane's history
-      read gives every token its own), a FOURTH scalar-prefetch
+      scanned block differ by it), a FOURTH scalar-prefetch
       operand. Pages wholly below a slot's window are neither computed
       nor fetched: their index maps name the window's first page again,
       and a block whose index did not change is not copied. A
       full-attention layer of a model that has window layers passes a
       window no key is ever below (``2 ** 30``).
+    - ``tiles`` (``None`` = none; a static choice like ``window``): the
+      slots are TILES of a prefill pack, cut on the host
+      (:func:`pack_history_tiles`). ``q`` is then the pack in CELLS of
+      R rows ``(cells, R, hq, d)`` and every other per-slot operand is
+      per tile ``(G, ...)``: tile ``t`` reads cell ``tiles["cell"][t]``,
+      owns its rows ``[lo[t], hi[t])``, sits with the cell's row 0 at
+      ``q_offset[t]`` and sees keys ``<= min(q_offset[t] + i,
+      cap[t])`` — the rows stand at their true positions ABOVE the keys
+      they read, so a ``window`` is the layer's own. Four more
+      scalar-prefetch operands. The grid is DATA: tiles ``[0, last
+      tile with cap >= 0]`` x the table chunks under the deepest cap,
+      and above a tile's cap no page is fetched. A tile writes its own
+      rows only (tiles of one cell are adjacent: the block stays put
+      between them); rows no tile owns are never written, the caller
+      masks them.
 
     Returns ``(S, R, hq, d)`` in q's dtype (plus the fp32
     ``(S, R*… )``-shaped LSE ``(S, hq, R)`` when ``return_lse`` — the
@@ -291,12 +337,23 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         # skip) keeps them inert
         block_tables = jnp.pad(block_tables, ((0, 0), (0, Wp - W)))
     block_tables = block_tables.astype(jnp.int32)
-    q_offset = jnp.asarray(q_offset, jnp.int32).reshape(S)
+    T = block_tables.shape[0]       # slots; tiles, where q is in cells
+    q_offset = jnp.asarray(q_offset, jnp.int32).reshape(T)
     scalars = (block_tables, q_offset, layer)
     windowed = window is not None
     if windowed:
         scalars += (jnp.broadcast_to(jnp.asarray(window, jnp.int32),
-                                     (S,)),)
+                                     (T,)),)
+    tiled = tiles is not None
+    grid = (S, n_steps)
+    if tiled:
+        cap = jnp.asarray(tiles["cap"], jnp.int32)
+        scalars += (cap,) + tuple(jnp.asarray(tiles[n], jnp.int32)
+                                  for n in ("cell", "lo", "hi"))
+        # tiles with history come first (the host packs them so) and
+        # table chunks above the deepest cap hold nothing for anyone
+        grid = (jnp.max(jnp.where(cap >= 0, jnp.arange(T) + 1, 1)),
+                jnp.clip(jnp.max(cap) // (L * bs) + 1, 1, n_steps))
     interpret = _interpret_default() if interpret is None else interpret
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
@@ -307,6 +364,8 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         .reshape(S, hkv, rows, d)
 
     def whole(s, w, *scalars):
+        if tiled:
+            s = scalars[-3][s]              # (.., cap, CELL, lo, hi)
         return (s, 0, 0, 0)
 
     def page_spec(j, x):
@@ -317,12 +376,20 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         # at layer 0 of the one-layer stack.
         stacked = x.ndim == 4
 
-        def index(s, w, tbl, off, lyr, win=None):
+        def index(s, w, tbl, off, lyr, *more):
             lane = w * L + j
+            first = off[s]
+            if tiled:
+                cap, _, lo, _ = more[-4:]
+                first += lo[s]      # a tile's first row: the cell's lo
             if windowed:
                 # table lanes below the slot's window name its first
                 lane = jnp.maximum(
-                    lane, jnp.maximum(off[s] - win[s] + 1, 0) // bs)
+                    lane, jnp.maximum(first - more[0][s] + 1, 0) // bs)
+            if tiled:
+                # ... and lanes above its cap its last: a block whose
+                # index did not change is not copied
+                lane = jnp.minimum(lane, jnp.maximum(cap[s], 0) // bs)
             return (lyr[0] if stacked else 0, tbl[s, lane], 0, 0)
 
         return pl.BlockSpec((None, None, bs, x.shape[-1]), index)
@@ -341,7 +408,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(S, n_steps),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
@@ -354,11 +421,14 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         out, lse_l = pl.pallas_call(
             functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
                               hkv=hkv, n_steps=n_steps, quant=quant,
-                              windowed=windowed),
+                              windowed=windowed,
+                              **({"tiled": True} if tiled else {})),
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                # tiles of one cell share its output block: in order
+                dimension_semantics=("arbitrary" if tiled
+                                     else "parallel", "arbitrary")),
             interpret=interpret,
             name="hetu_paged_attn",
         )(*scalars, *args)
@@ -380,7 +450,8 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
                          scale: Optional[float] = None,
                          pages_per_step: Optional[int] = None,
                          interpret: Optional[bool] = None,
-                         return_lse: bool = False, window=None):
+                         return_lse: bool = False, window=None,
+                         tiles=None):
     """:func:`paged_attention_pallas`, tp-aware.
 
     Mosaic kernels cannot be GSPMD-auto-partitioned, so under a
@@ -398,11 +469,12 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
         _axis_size, current_act_sharding,
     )
 
-    def call(q, k, v, tbl, off, layer, ks, vs):
+    def call(q, k, v, tbl, off, layer, ks, vs, tiles=tiles):
         return paged_attention_pallas(
             q, k, v, tbl, off, layer=layer, k_scale=ks, v_scale=vs,
             scale=scale, pages_per_step=pages_per_step,
-            interpret=interpret, return_lse=return_lse, window=window)
+            interpret=interpret, return_lse=return_lse, window=window,
+            **({} if tiles is None else {"tiles": tiles}))
 
     ctx = current_act_sharding()
     head_ax = ctx.tp if ctx is not None and isinstance(ctx.tp, str) \
@@ -436,16 +508,148 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
     if k_scale is not None:
         in_specs += (page_spec(k_scale), page_spec(v_scale))
         args += (k_scale, v_scale)
+    tile_keys = sorted(tiles) if tiles is not None else []
+    in_specs += (P(None),) * len(tile_keys)      # the tile map rides
+    args += tuple(jnp.asarray(tiles[n], jnp.int32) for n in tile_keys)
     out_specs = (head_spec, P(None, head_ax, None)) if return_lse \
         else head_spec
 
-    def local(q, k, v, tbl, off, lyr, ks=None, vs=None):
-        return call(q, k, v, tbl, off, lyr[0], ks, vs)
+    def local(q, k, v, tbl, off, lyr, *rest):
+        ks, vs = rest[:2] if k_scale is not None else (None, None)
+        tile_map = dict(zip(tile_keys, rest[len(rest) - len(tile_keys):]))
+        return call(q, k, v, tbl, off, lyr[0], ks, vs, tile_map or None)
 
     fn = shard_map(local, mesh=ctx.mesh, in_specs=in_specs,
                    out_specs=out_specs, axis_names=set(ctx.mesh.shape),
                    check_vma=False)
     return fn(*args)
+
+
+# ---------------------------------------------------------------------------
+# the packed-prefill lane's history read: tiles of one request's chunk
+# ---------------------------------------------------------------------------
+
+#: what a tile's blocks may take of the 16 MiB a kernel gets of VMEM
+#: by default (the rest: the compiler's own temporaries)
+_TILE_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def history_tile_rows(g: int, d: int, hkv: int, block_size: int, *,
+                      kv_itemsize: int = 2) -> int:
+    """Tokens per tile of the prefill lane's history read
+    (:func:`paged_history_attention`), from shapes alone: the largest
+    power of two up to 128 (a tile of ``Tq`` tokens is ``Tq * g`` rows
+    per kv head on the MXU, where a decode row gives it ``g``) whose
+    blocks fit the kernel's VMEM — the q cell and the two outputs
+    double buffered (priced at float32), the three online-softmax
+    scratch buffers, two sets of one K and one V page. 128 for GPT-2
+    (``g`` = 1, 12 or 20 heads of 64), 16 for 128 q heads over 8 kv
+    heads of 128."""
+    pages = 2 * 2 * block_size * hkv * d * kv_itemsize
+    tq = 128
+    while tq > 8:
+        cell = hkv * tq * g * 4 * (2 * d + 2 * d          # q, out: x2
+                                   + 2 * NUM_LANES        # lse: x2
+                                   + 2 * NUM_LANES + d)   # m, l, acc
+        if cell + pages <= _TILE_VMEM_BUDGET:
+            break
+        tq //= 2
+    return tq
+
+
+def history_tile_count(chunk: int, tile_rows: int, max_runs: int) -> int:
+    """The most tiles a pack of ``chunk`` tokens in at most ``max_runs``
+    runs can cut into: its cells, and every run after the first may
+    split one more cell."""
+    return -(-chunk // tile_rows) + max(1, max_runs) - 1
+
+
+#: the rows of a tile map (:func:`pack_history_tiles`): whose block
+#: table, which cell of the pack, the cell's rows that are the tile's,
+#: the position the cell's row 0 would have in the tile's run, and the
+#: last key the tile may see (``hist - 1``; -1: a dead tile)
+TILE_FIELDS = ("slot", "cell", "lo", "hi", "off", "cap")
+
+
+def pack_history_tiles(runs, *, tile_rows: int, n_tiles: int):
+    """The tile map of one prefill pack, on the host (numpy).
+
+    The pack lies in CELLS of ``tile_rows`` rows; ``runs`` is ``(slot,
+    first pack row, tokens, hist)`` per request of the pack (one
+    contiguous run each, positions ascending from ``hist``: what lies
+    below a run's first token is its history). A run WITH
+    history gives one tile per cell it touches — a tile never straddles
+    two runs — in pack order, so the tiles of one cell are adjacent;
+    they come first, and every other tile of the static ``n_tiles`` is
+    dead (``cap`` -1: the grid ends before it). Returns the map as ONE
+    ``(len(TILE_FIELDS), n_tiles)`` int32 array, a row per field (one
+    upload a step), and the counts ``(live tiles, empty tiles, rows in
+    live tiles)``: an empty tile is one a run WITHOUT history would
+    have been."""
+    import numpy as np
+    t = np.zeros((len(TILE_FIELDS), n_tiles), np.int32)
+    t[-1] = -1
+    live = empty = rows = 0
+    for slot, first, n, hist in runs:
+        cells = range(first // tile_rows,
+                      (first + n - 1) // tile_rows + 1)
+        if hist <= 0:
+            empty += len(cells)
+            continue
+        for c in cells:
+            base = c * tile_rows
+            lo = max(first, base) - base
+            hi = min(first + n, base + tile_rows) - base
+            t[:, live] = (slot, c, lo, hi, hist + base - first, hist - 1)
+            live += 1
+        rows += n
+    return t, (live, empty, rows)
+
+
+def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
+                            tile_rows: int, layer=None, k_scale=None,
+                            v_scale=None, window=None,
+                            scale: Optional[float] = None,
+                            interpret: Optional[bool] = None):
+    """Each pack token's attention over its request's RESIDENT history
+    (arena positions ``< hist[t]``: earlier chunks, prefix-cache hits),
+    one pass over a request's pages per TILE of its chunk.
+
+    - ``q``: ``(C, hq, d)`` pack rows; ``hist`` ``(C,)`` each token's
+      run's start offset (0: no history);
+    - ``tiles``: the pack's tile map (:func:`pack_history_tiles`, the
+      array or its rows by name) with ``tile_tables`` ``(G, W)``, each
+      tile's request's block table (``bt[tiles["slot"]]``).
+      The pack is handed to :func:`paged_attention_pallas` in cells of
+      ``tile_rows`` rows, in place — no row is gathered, nothing is
+      padded to the tile count.
+
+    Returns ``(C, hq, d)`` and the fp32 LSE ``(C, hq)``; a token
+    without history gets the empty part (0, ``NEG_INF``), which
+    :func:`combine_attention_lse` weighs 0. The per-token formulation
+    (``paged_attention_reference`` with one slot a token at ``hist -
+    1``) is the parity oracle."""
+    C, hq, d = q.shape
+    if not isinstance(tiles, dict):
+        tiles = dict(zip(TILE_FIELDS, tiles))
+    pad = -C % tile_rows
+    cells = jnp.pad(q, ((0, pad), (0, 0), (0, 0))).reshape(
+        -1, tile_rows, hq, d)
+    out, lse = paged_attention_auto(
+        cells, k, v, tile_tables, tiles["off"], layer=layer,
+        k_scale=k_scale, v_scale=v_scale, scale=scale,
+        # a page a step: a tile gives the MXU Tq x g rows per page, and
+        # more pages a step only unroll the body again (the kernel's
+        # time is the same at 1 / 2 / 4 / 8 on the chip, its trace and
+        # compile are not: PERF.md, PR 27)
+        pages_per_step=1, interpret=interpret, return_lse=True,
+        window=window,
+        tiles={n: tiles[n] for n in ("cap", "cell", "lo", "hi")})
+    live = hist > 0
+    out = out.reshape(-1, hq, d)[:C]
+    lse = jnp.moveaxis(lse, 1, 2).reshape(-1, hq)[:C]
+    return (jnp.where(live[:, None, None], out, 0),
+            jnp.where(live[:, None], lse, NEG_INF))
 
 
 def paged_attention_reference(q, k, v, block_tables, q_offset, *,

@@ -148,6 +148,16 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "prefill_attn_kernel_total",
             "prefill-lane executions by attention path (flash = "
             "packed/CP flash lane, reference = per-token gather math)"),
+        hist_tiles=reg.counter(
+            "serving_prefill_hist_tiles_total",
+            "tiles of the flash prefill lane's history read by state "
+            "(live = a run with resident history, one pass over its "
+            "pages per tile; empty = a run without: its tiles stream "
+            "and compute nothing)"),
+        hist_rows=reg.counter(
+            "serving_prefill_hist_rows_total",
+            "pack tokens in live history tiles (over the live tiles: "
+            "rows that share one pass over a request's pages)"),
         draft=reg.counter(
             "serving_draft_tokens_total",
             "draft tokens proposed to the verify lane"),
@@ -589,6 +599,24 @@ class ServingEngine:
             prefill_attn == "flash_pallas"
             or (prefill_attn == "flash"
                 and jax.default_backend() == "tpu")) else "reference"
+        # the flash lane's history read on the kernel path: the tokens
+        # of a request's run share one pass over its pages per TILE of
+        # the chunk (ops.paged_pallas.paged_history_attention). Tile
+        # size from the head shapes; the tile count is static: a pack
+        # holds at most _fin_cap runs, each may open one more tile
+        from hetu_tpu.ops.paged_pallas import (
+            history_tile_count, history_tile_rows, pack_history_tiles,
+        )
+        self._pack_tiles = pack_history_tiles
+        self._hist_tile = history_tile_rows(
+            _attn_mod.num_heads // _attn_mod.num_kv_heads,
+            _attn_mod.head_dim, _attn_mod.num_kv_heads,
+            self.pool.block_size,
+            kv_itemsize=jnp.dtype(self.pool.caches[0].dtype).itemsize)
+        self._hist_tiles = history_tile_count(
+            self.prefill_chunk, self._hist_tile, self._fin_cap) \
+            if prefill_attn != "reference" \
+            and self.attn_kernel == "paged" else 0
         # W8A8 decode-FFN compute: per-layer A/B as a (layers,) bool
         # baked into the step. Gated on the int8 arena — an operator
         # who priced the KV at 8 bits has already accepted 8-bit error
@@ -723,6 +751,7 @@ class ServingEngine:
         w8a8_mask = self._w8a8_mask
         flash_lane = self.prefill_attn != "reference"
         pack_impl = self._pack_impl
+        tile_rows = self._hist_tile
         # the draftsman's q rows: host-only draftsmen (and no
         # draftsman) propose deterministically, so q is the one-hot of
         # the draft — synthesized on-device; a device draftsman ships
@@ -842,11 +871,14 @@ class ServingEngine:
 
             # packed prefill: a C-token budget shared by every
             # admitting request — per-token (slot, position) operands
-            # are the cu_seqlens of this lane. Each pack token is one
-            # batch row of the per-row paged decode: layer l writes
-            # every row's K/V before attending, so rows of the same
-            # request see their in-pack predecessors exactly like a
-            # dense chunk. (cond keeps idle iterations free.)
+            # are the cu_seqlens of this lane. On the reference lane
+            # each pack token is one batch row of the per-row paged
+            # decode: layer l writes every row's K/V before attending,
+            # so rows of the same request see their in-pack
+            # predecessors exactly like a dense chunk. The flash lane
+            # is one (1, C) row: flash inside the pack, and the
+            # resident history read once per TILE of a request's run
+            # (pf["tiles"]). (cond keeps idle iterations free.)
             def do_prefill(caches):
                 if flash_lane:
                     # packed FLASH prefill: the whole chunk as ONE
@@ -858,14 +890,19 @@ class ServingEngine:
                     pos = pf["pos"][None, :]                 # (1, C)
                     h = model.embed(params, pf["tokens"][None, :],
                                     positions=pos)
+                    pack = {"segment_ids": pf["seg"][None, :],
+                            "hist": pf["hist"], "valid": pf["valid"],
+                            "impl": pack_impl}
+                    if "tiles" in pf:
+                        # (fields, tiles): row 0 is each tile's slot
+                        pack["tiles"] = {
+                            "map": pf["tiles"], "rows": tile_rows,
+                            "tables": jnp.take(bt, pf["tiles"][0],
+                                               axis=0)}
                     h, caches, stats = model.blocks.decode(
                         params["blocks"], h, caches, positions=pos,
                         block_tables=jnp.take(bt, pf["slot"], axis=0),
-                        attn_kernel=kern,
-                        pack={"segment_ids": pf["seg"][None, :],
-                              "hist": pf["hist"],
-                              "valid": pf["valid"],
-                              "impl": pack_impl},
+                        attn_kernel=kern, pack=pack,
                         lora={"ids": jnp.take(ctl["adapter"],
                                               pf["slot"])[None, :],
                               "pages": lora} if lora else None,
@@ -2356,6 +2393,7 @@ class ServingEngine:
             fin_valid = np.zeros(R, bool)        # rows really finishing
             fills: list[tuple[dict, int]] = []   # (entry, n) this iter
             fin_ents: list[dict] = []            # completes this iter
+            runs = []                # (slot, first row, tokens, hist)
             used = 0
             for ent in self._prefilling:         # empty on the common
                 if used >= C:                    # decode-only iteration
@@ -2373,6 +2411,7 @@ class ServingEngine:
                 # prefix-cache hits) belong to the history part
                 tseg[used:used + n] = ent["slot"]
                 thist[used:used + n] = off
+                runs.append((ent["slot"], used, n, off))
                 if off + n >= len(req.prompt):
                     fin_row[len(fin_ents)] = used + n - 1
                     fin_slot[len(fin_ents)] = ent["slot"]
@@ -2384,6 +2423,17 @@ class ServingEngine:
                   "pos": tpos, "slot": tslot, "valid": tvalid,
                   "seg": tseg, "hist": thist, "fin_row": fin_row,
                   "fin_slot": fin_slot, "fin_valid": fin_valid}
+            if self._hist_tiles:
+                # the history read's tile map: runs with history first,
+                # every other tile dead — data, on the one upload
+                pf["tiles"], (live, empty, rows) = self._pack_tiles(
+                    runs, tile_rows=self._hist_tile,
+                    n_tiles=self._hist_tiles)
+                if live:
+                    m.hist_tiles.inc(live, state="live")
+                    m.hist_rows.inc(rows)
+                if empty:
+                    m.hist_tiles.inc(empty, state="empty")
             # CoW lanes: unused dst = n_blocks scatters out of bounds
             cow_src = np.zeros(S, np.int32)
             cow_dst = np.full(S, self.pool.n_blocks, np.int32)

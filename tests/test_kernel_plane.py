@@ -21,8 +21,10 @@ import jax.numpy as jnp
 from hetu_tpu import telemetry
 from hetu_tpu.models import GPTConfig, GPTLMHeadModel, generate
 from hetu_tpu.ops.paged_pallas import (
-    combine_attention_lse, paged_attention_auto, paged_attention_pallas,
-    paged_attention_reference,
+    NEG_INF, combine_attention_lse, history_tile_count,
+    history_tile_rows, pack_history_tiles, paged_attention_auto,
+    paged_attention_pallas, paged_attention_reference,
+    paged_history_attention,
 )
 
 MAX_LEN = 32
@@ -82,8 +84,8 @@ def test_paged_kernel_matches_reference_gqa_and_verify_rows():
 @pytest.mark.parametrize("window", [3, 4, 9, [2, 8, 30], 2 ** 30])
 def test_paged_kernel_window_matches_reference(window):
     """A windowed call (row ``i`` of slot ``s`` sees keys ``> offset +
-    i - window``; one scalar, or one per slot as the prefill lane's
-    history read passes it) == the XLA-gather oracle with the same
+    i - window``; one scalar, or one per slot as the gather lane's
+    per-token history read passes it) == the XLA-gather oracle with the same
     window: edges inside a page (3, 9) and on a page boundary (4, pages
     of 4), verify rows, every ``pages_per_step`` tiling — pages wholly
     below the window are skipped and their index maps name the window's
@@ -238,6 +240,78 @@ def test_paged_attention_auto_stacked_arena_under_tp_mesh(gpt):
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r),
                                atol=1e-5)
+    # the prefill lane's history read under the same plan: the tile
+    # map rides the shard_map replicated, not dropped
+    qh, kh, vh, tblh, _, _, hist, runs, _ = _history_pack(
+        rng, [(6, 9), (5, 0)])
+    tiles, _ = pack_history_tiles(runs, tile_rows=TQ, n_tiles=5)
+    tj = jnp.asarray(tiles)
+    tables = jnp.take(tblh, tiles[0], axis=0)
+
+    def h(q, k, v):
+        return paged_history_attention(q, k, v, tables, hist, tj,
+                                       tile_rows=TQ)
+
+    want = h(qh, kh, vh)
+    with plan.act:
+        got = jax.jit(h)(qh, kh, vh)
+        assert "shard_map" in str(jax.make_jaxpr(h)(qh, kh, vh))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5)
+
+
+#: sha256(str(jaxpr))[:16] of the decode lane's (1 row) and the verify
+#: lane's (k + 1 = 4 rows) kernel calls at commit 2e91042 (PR 26), the
+#: parent of the PR that gave the kernel its tiled mode, traced under
+#: this suite's configuration (conftest sets the default matmul
+#: precision to highest, which the jaxpr prints; under JAX's defaults
+#: they read 15734148f5b2ff8e, db91fee71ce8e73b, 5990c06ab3c7c47c,
+#: 95364a637a3ed8a6 — at both commits): ``tiles=None`` must leave those
+#: programs as they were, operand for operand
+PARENT_JAXPR = {(1, "bf16"): "92988cda9a2ea733",
+                (1, "int8"): "5d710ce1524ffa80",
+                (4, "bf16"): "88a07083a38b1053",
+                (4, "int8"): "917b0e144e2a3fc4"}
+
+
+@pytest.mark.parametrize("rows,arena", list(PARENT_JAXPR))
+def test_decode_and_verify_lane_calls_are_the_parents_program(rows, arena):
+    """The decode-lane and verify-lane calls did not change when the
+    kernel learned tiles: three scalar-prefetch operands (tables,
+    offsets, layer), a static grid, and the parent's jaxpr to the
+    character. The history read's call has seven and a grid of two
+    traced bounds."""
+    import hashlib
+    S, hq, hkv, d, L, nb, bs, W = 4, 4, 2, 16, 3, 9, 4, 8
+    quant = arena == "int8"
+    sds = jax.ShapeDtypeStruct
+    page = sds((L, nb, bs, hkv * d), jnp.int8 if quant else jnp.bfloat16)
+    args = (sds((S, rows, hq, d), jnp.bfloat16), page, page,
+            sds((S, W), jnp.int32), sds((S,), jnp.int32),
+            sds((), jnp.int32)) \
+        + ((sds((L, nb, bs, hkv), jnp.float32),) * 2 if quant else ())
+
+    def f(q, k, v, tbl, off, lyr, *scales, **kw):
+        ks, vs = scales if scales else (None, None)
+        return paged_attention_pallas(q, k, v, tbl, off, layer=lyr,
+                                      k_scale=ks, v_scale=vs,
+                                      interpret=True, **kw)
+
+    def pallas_eqn(jaxpr):
+        eqn, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+        return eqn.params["grid_mapping"]
+
+    jaxpr = jax.make_jaxpr(f)(*args)
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] \
+        == PARENT_JAXPR[rows, arena]
+    gm = pallas_eqn(jaxpr.jaxpr)
+    assert gm.num_index_operands == 3 and gm.num_dynamic_grid_bounds == 0
+    tiles = {n: jnp.zeros((S,), jnp.int32)
+             for n in ("cap", "cell", "lo", "hi")}
+    gm = pallas_eqn(jax.make_jaxpr(
+        lambda *a: f(*a, tiles=tiles))(*args).jaxpr)
+    assert gm.num_index_operands == 7 and gm.num_dynamic_grid_bounds == 2
 
 
 def test_paged_kernel_dead_lanes_inert():
@@ -257,6 +331,177 @@ def test_paged_kernel_dead_lanes_inert():
     # positions < block 2 are unchanged by the poisoning at all
     np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                atol=1e-5)
+
+
+# -- the prefill lane's history read: one pass per tile of a run ------------
+
+TQ = 4          # tile size of the cases below (a run of TQ is one tile)
+
+#: runs of a pack as (tokens, hist); pack rows in order, pads after
+HIST_CASES = {
+    "run_1": [(1, 9)],
+    "run_tq_minus_1": [(TQ - 1, 9)],
+    "run_tq": [(TQ, 9)],
+    "run_tq_plus_1": [(TQ + 1, 9)],
+    "several_tiles": [(3 * TQ + 2, 6)],
+    "runs_with_and_without_history": [(5, 0), (6, 11), (1, 0), (3, 4)],
+    "every_run_with_history": [(2, 3), (7, 10), (5, 16)],
+    "no_history_anywhere": [(9, 0), (3, 0)],
+    "page_boundary_history": [(6, 8), (4, 4)],
+}
+
+
+def _history_pack(rng, runs, *, C=16, hq=4, hkv=2, d=16, bs=4, W=8,
+                  quant=False):
+    """A pack of ``runs`` over an arena whose history rows are random:
+    the operands of the per-token formulation and of the tiles."""
+    from hetu_tpu.ops.quantization import quantize_int8
+    n_blocks = 1 + len(runs) * W
+    k, v = (rng.normal(size=(n_blocks, bs, hkv, d)).astype(np.float32)
+            for _ in range(2))
+    tbl = 1 + np.arange(len(runs) * W, dtype=np.int32).reshape(-1, W)
+    slot, pos, hist = (np.zeros(C, np.int32) for _ in range(3))
+    run_list, used = [], 0
+    for s, (n, h) in enumerate(runs):
+        slot[used:used + n], hist[used:used + n] = s, h
+        pos[used:used + n] = h + np.arange(n)
+        run_list.append((s, used, n, h))
+        used += n
+    assert used <= C
+    q = jnp.asarray(rng.normal(size=(C, hq, d)), jnp.float32)
+    arena = {}
+    if quant:
+        (k, ks), (v, vs) = (map(_pages, quantize_int8(jnp.asarray(x),
+                                                      axis=-1))
+                            for x in (k, v))
+        arena = dict(k_scale=ks, v_scale=vs)
+    else:
+        k, v = _pages(jnp.asarray(k)), _pages(jnp.asarray(v))
+    return (q, k, v, jnp.asarray(tbl), jnp.asarray(slot),
+            jnp.asarray(pos), jnp.asarray(hist), run_list, arena)
+
+
+def _assert_history_read_matches_per_token(case, *, window=None, tq=TQ,
+                                           max_runs=4, **kw):
+    """``paged_history_attention`` == one reference slot per token at
+    ``hist - 1`` (outputs AND lse); tokens without history and pad
+    lanes get the empty part exactly."""
+    q, k, v, tbl, slot, pos, hist, runs, arena = case
+    C = q.shape[0]
+    G = history_tile_count(C, tq, max_runs)
+    tiles, counts = pack_history_tiles(runs, tile_rows=tq, n_tiles=G)
+    out, lse = paged_history_attention(
+        q, k, v, jnp.take(tbl, tiles[0], axis=0), hist,
+        jnp.asarray(tiles), tile_rows=tq, window=window, **arena, **kw)
+    ref_kw = dict(arena)
+    if window is not None:
+        ref_kw["window"] = window - (pos - (hist - 1))
+    ref, lse_r = paged_attention_reference(
+        q[:, None], k, v, jnp.take(tbl, slot, axis=0), hist - 1,
+        return_lse=True, **ref_kw)
+    live = np.asarray(hist) > 0
+    assert counts[0] == sum((f + n - 1) // tq - f // tq + 1
+                            for _, f, n, h in runs if h)
+    assert counts[2] == sum(n for _, _, n, h in runs if h)
+    np.testing.assert_allclose(np.asarray(out)[live],
+                               np.asarray(ref)[live, 0], atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse)[live],
+                               np.asarray(lse_r)[live, :, 0], atol=1e-5)
+    assert not np.asarray(out)[~live].any()
+    assert (np.asarray(lse)[~live] == NEG_INF).all()
+    return out, lse, ref
+
+
+@pytest.mark.parametrize("case", list(HIST_CASES))
+def test_history_read_tiles_match_per_token_reference(case):
+    """Run lengths around the tile size, several tiles, packs of
+    several runs with and without history, pad lanes, and a pack where
+    nothing has history (every tile dead: out 0, lse NEG_INF)."""
+    rng = np.random.default_rng(21)
+    out, _, _ = _assert_history_read_matches_per_token(
+        _history_pack(rng, HIST_CASES[case]))
+    if not any(h for _, h in HIST_CASES[case]):
+        assert not np.asarray(out).any()
+
+
+@pytest.mark.parametrize("g", [1, 16])
+def test_history_read_group_sizes(g):
+    """``g`` = 1 (GPT-2: a tile is Tq rows per head) and ``g`` = 16
+    (128 q heads over 8: Tq x 16 rows)."""
+    rng = np.random.default_rng(22)
+    case = _history_pack(rng, [(6, 11), (5, 0), (3, 7)], hq=2 * g,
+                         hkv=2)
+    _assert_history_read_matches_per_token(case)
+
+
+@pytest.mark.parametrize("window", [3, 6, 8, 2 ** 30])
+def test_history_read_window_applies_to_the_rows_own_position(window):
+    """A windowed layer: the row sits at its TRUE position, so the
+    layer's own window cuts its history — rows with history on both
+    sides of it (a run of 7 over 12 resident tokens: window 6 leaves
+    the deeper rows nothing, 8 cuts inside a page, 3 leaves only the
+    first rows any) — and ``2 ** 30``, the full layer of a model that
+    mixes both kinds, cuts nothing."""
+    rng = np.random.default_rng(23)
+    case = _history_pack(rng, [(7, 12), (4, 0), (5, 2)])
+    w = jnp.asarray(window, jnp.int32)
+    out, _, _ = _assert_history_read_matches_per_token(case, window=w)
+    full, _, _ = _assert_history_read_matches_per_token(case)
+    differs = np.abs(np.asarray(out) - np.asarray(full)).max() > 1e-3
+    assert differs == (window < 2 ** 30)
+
+
+def test_history_read_int8_arena():
+    """The int8 arena's scales page the same way under the cap."""
+    rng = np.random.default_rng(24)
+    _assert_history_read_matches_per_token(
+        _history_pack(rng, [(6, 9), (5, 0), (5, 14)], quant=True))
+
+
+def test_history_read_in_a_layer_scan_and_dead_tiles_read_nothing():
+    """The stacked arena at a traced layer, as the fused step calls it;
+    and a dead tile's table may point anywhere (a pad tile carries slot
+    0's): poisoning every table lane above the cap and every dead
+    tile's row changes nothing."""
+    rng = np.random.default_rng(25)
+    q, k, v, tbl, slot, pos, hist, runs, _ = _history_pack(
+        rng, [(6, 9), (5, 0), (5, 14)])
+    C = q.shape[0]
+    G = history_tile_count(C, TQ, 4)
+    tiles, _ = pack_history_tiles(runs, tile_rows=TQ, n_tiles=G)
+    tj = jnp.asarray(tiles)
+    tables = np.asarray(jnp.take(tbl, tiles[0], axis=0)).copy()
+    ks = jnp.stack([k * 0, k])
+    vs = jnp.stack([v * 0, v])
+
+    @jax.jit
+    def f(tables, layer):
+        return paged_history_attention(q, ks, vs, tables, hist, tj,
+                                       tile_rows=TQ, layer=layer)
+    base, lse = f(jnp.asarray(tables), jnp.asarray(1, jnp.int32))
+    one, lse1 = paged_history_attention(q, k, v, jnp.asarray(tables),
+                                        hist, tj, tile_rows=TQ)
+    np.testing.assert_allclose(np.asarray(base), np.asarray(one),
+                               atol=1e-6)
+    for t in range(G):
+        cap = tiles[-1][t]
+        tables[t, (cap // 4 + 1 if cap >= 0 else 0):] = 0
+    out, lse2 = f(jnp.asarray(tables), jnp.asarray(1, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
+    np.testing.assert_array_equal(np.asarray(lse2), np.asarray(lse))
+
+
+def test_history_tile_rows_from_shapes():
+    """The tile size is a function of the head shapes: 128 tokens for
+    GPT-2 (one row a token and head), 16 for 128 q heads over 8 kv
+    heads of 128 (256 rows a kv head) — and the static tile count is
+    the chunk's tiles plus one more for every run after the first."""
+    assert history_tile_rows(1, 64, 12, 16) == 128
+    assert history_tile_rows(1, 64, 20, 16) == 128
+    assert history_tile_rows(16, 128, 8, 64) == 16
+    assert history_tile_count(256, 128, 32) == 33
+    assert history_tile_count(512, 16, 48) == 79
+    assert history_tile_count(8, 128, 3) == 3
 
 
 def test_combine_attention_lse_matches_joint_softmax():
@@ -285,7 +530,8 @@ def test_combine_attention_lse_matches_joint_softmax():
 
 def test_packed_flash_formulation_matches_per_token_gather():
     """Ops-level packed-prefill parity: intra-pack (segment-isolated
-    flash PALLAS kernel, interpret) + arena-history, LSE-combined, ==
+    flash PALLAS kernel, interpret) + arena-history (read per TILE of
+    a run, ``paged_history_attention``), LSE-combined, ==
     the per-token union through the tables — and a token of request A
     is PROVABLY blind to request B's pack rows (segment isolation)."""
     from hetu_tpu.ops.attention import attention_with_lse
@@ -317,11 +563,19 @@ def test_packed_flash_formulation_matches_per_token_gather():
     intra, lse_i = attention_with_lse(
         jnp.asarray(qp), jnp.asarray(kp), jnp.asarray(vp), causal=True,
         segment_ids=jnp.asarray(seg)[None, :], impl="pallas")
-    hist_o, lse_h = paged_attention_pallas(
-        jnp.asarray(qp)[0][:, None], k_arena, v_arena, tbl_tok,
-        jnp.full((C,), hist - 1, jnp.int32), return_lse=True)
-    out = combine_attention_lse(intra, lse_i, hist_o[:, 0][None],
-                                lse_h[:, :, 0].T[None])
+    # the history part as the lane reads it: tiles of TQ tokens, a run
+    # of 6 is two of them, each one pass over the request's pages
+    tiles, counts = pack_history_tiles(
+        [(r, r * per_req, per_req, hist) for r in range(n_req)],
+        tile_rows=TQ, n_tiles=history_tile_count(C, TQ, n_req))
+    assert counts == (4, 0, C)          # rows 4-7 are two runs' cell
+    hist_o, lse_h = paged_history_attention(
+        jnp.asarray(qp)[0], k_arena, v_arena,
+        jnp.take(jnp.asarray(tbl), tiles[0], axis=0),
+        jnp.full((C,), hist, jnp.int32), jnp.asarray(tiles),
+        tile_rows=TQ)
+    hist_o, lse_h = hist_o[None], lse_h.T[None]
+    out = combine_attention_lse(intra, lse_i, hist_o, lse_h)
     ref = paged_attention_reference(
         jnp.asarray(qp)[0][:, None], k_arena, v_arena, tbl_tok,
         jnp.asarray(pos))[:, 0][None]
@@ -334,8 +588,7 @@ def test_packed_flash_formulation_matches_per_token_gather():
     intra2, lse_i2 = attention_with_lse(
         jnp.asarray(qp), jnp.asarray(kp2), jnp.asarray(vp), causal=True,
         segment_ids=jnp.asarray(seg)[None, :], impl="pallas")
-    out2 = combine_attention_lse(intra2, lse_i2, hist_o[:, 0][None],
-                                 lse_h[:, :, 0].T[None])
+    out2 = combine_attention_lse(intra2, lse_i2, hist_o, lse_h)
     assert np.array_equal(np.asarray(out2[:, :per_req]),
                           np.asarray(out[:, :per_req]))
     assert not np.allclose(np.asarray(out2[:, per_req:]),
@@ -565,6 +818,26 @@ def test_engine_packed_flash_prefill_identity_and_isolation(gpt):
         solo = build(prefill_attn="flash_pallas").generate_many(
             [p], sp)[0]
         assert solo == toks
+    # a prompt of three chunks shares its packs with short requests
+    # (its 2nd and 3rd runs have history, theirs none; tiles of 4 cut
+    # its runs in two): every request's tokens are its SOLO run's
+    from hetu_tpu.ops import paged_pallas
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(paged_pallas, "history_tile_rows",
+                   lambda *a, **kw: 4)
+        mixed = _prompts(cfg, (2 * CHUNK + 5, 3, 4, 2), seed=17)
+        eng = build(prefill_attn="flash_pallas", attn_kernel="paged")
+        assert eng._hist_tile == 4 and eng._hist_tiles == 2 + 3 - 1
+        reqs = [eng.submit(mixed[0], sp)]
+        eng.step()                      # chunk 1 of the long prompt
+        reqs += [eng.submit(p, sp) for p in mixed[1:]]
+        eng.run_until_drained()
+        got = [r.tokens for r in reqs]
+        assert got == ref_eng.generate_many(mixed, sp)
+        for p, toks in zip(mixed, got):
+            assert build(prefill_attn="flash_pallas",
+                         attn_kernel="paged").generate_many(
+                [p], sp)[0] == toks
     # prefill KV parity: a single max_tokens=1 request writes ONLY
     # prefill rows — the two lanes' arenas must agree to fp noise
     one = SamplingParams(max_tokens=1)
@@ -577,6 +850,45 @@ def test_engine_packed_flash_prefill_identity_and_isolation(gpt):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             atol=1e-6)
+
+
+def test_engine_counts_history_tiles_on_the_host(gpt, monkeypatch):
+    """``serving_prefill_hist_tiles_total{state}`` and
+    ``serving_prefill_hist_rows_total`` from a scripted sequence of
+    packs (chunk 8 in cells of 4): a 19-token prompt takes three
+    iterations beside two short ones that wait for room in the pack.
+    Iteration 1: its first chunk, no history — 2 empty tiles. 2: rows
+    8-15 over 8 resident tokens — 2 live tiles, 8 rows. 3: its last 3
+    tokens (1 live tile, 3 rows) beside a 3-token run across both cells
+    (2 empty) and a 2-token run (1 empty). Decode iterations add
+    nothing; the tokens are the reference engine's."""
+    from hetu_tpu.ops import paged_pallas
+    from hetu_tpu.serving import SamplingParams, ServingEngine
+    cfg, model, params = gpt
+    monkeypatch.setattr(paged_pallas, "history_tile_rows",
+                        lambda *a, **kw: 4)
+    prompts = _prompts(cfg, (19, 3, 2), seed=29)
+    sp = SamplingParams(max_tokens=2)
+    kw = dict(slots=3, max_len=MAX_LEN, prefill_chunk=CHUNK,
+              block_size=BLOCK)
+    want = ServingEngine(model, params, **kw).generate_many(prompts, sp)
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        eng = ServingEngine(model, params, prefill_attn="flash_pallas",
+                            attn_kernel="paged", **kw)
+        assert eng.generate_many(prompts, sp) == want
+        reg = telemetry.get_registry()
+        tiles = reg.counter("serving_prefill_hist_tiles_total")
+        assert tiles.value(state="live") == 3
+        assert tiles.value(state="empty") == 5
+        assert reg.counter("serving_prefill_hist_rows_total").value() == 11
+        # the gather lane cuts no tiles
+        ref = ServingEngine(model, params, attn_kernel="paged", **kw)
+        assert ref._hist_tiles == 0
+    finally:
+        telemetry.enable(False)
+        telemetry.reset()
 
 
 @pytest.mark.slow

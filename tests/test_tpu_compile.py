@@ -182,6 +182,31 @@ def test_packed_prefill_lane_compiles_for_v5e(one_chip, dtype):
                                                chunk=256, dtype=dtype)
 
 
+@pytest.mark.parametrize("cell,kw,tile_rows,tiles", [
+    ("gpt2-small.chat", dict(
+        chunk=256, dtype=jnp.float32, slots=148, n_blocks=9473,
+        table_width=65), 128, 149),
+    ("gpt2-large.backlog", dict(
+        chunk=256, dtype=jnp.float32, heads=20, layers=36, slots=32,
+        n_blocks=2049, table_width=65), 128, 33),
+    ("command-a-plus-ep8.mixed-backlog", dict(
+        chunk=512, dtype=jnp.bfloat16, heads=128, kv_heads=8,
+        head_dim=128, layers=4, slots=48, n_blocks=4250, block_size=64,
+        table_width=129, windowed=True), 16, 79),
+], ids=lambda x: x if isinstance(x, str) else "")
+def test_prefill_history_tiles_compile_for_v5e(one_chip, cell, kw,
+                                               tile_rows, tiles):
+    """The prefill lane's attention at the three serving cells' sizes:
+    the history read in tiles (a grid whose bounds are data, a key cap
+    and — Command A+ — the layer's window as scalar operands, cells of
+    128 rows x 12 or 20 heads, of 16 tokens x 16 group members x 8
+    heads) compiles for the chip, at the tile size and count the engine
+    derives from the head shapes."""
+    from workloads.aot_check import check_packed_prefill
+    r = check_packed_prefill(list(one_chip.device_set), **kw)
+    assert (r["tile_rows"], r["tiles"]) == (tile_rows, tiles), r
+
+
 def test_mosaic_cp_dropout_train_step_compiles_for_v5e(topo8):
     """A full train step with ring CP AND attention dropout must pass
     the real Mosaic+GSPMD pipeline (the SMEM seed operand rides inside

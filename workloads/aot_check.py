@@ -150,37 +150,49 @@ def check_paged(devs, *, dtype=jnp.bfloat16, rows=1, return_lse=False,
 
 
 def check_packed_prefill(devs, *, chunk=256, dtype=jnp.bfloat16, heads=12,
-                         head_dim=64, layers=12, n_blocks=2048,
-                         block_size=16, table_width=64):
+                         kv_heads=None, head_dim=64, layers=12,
+                         n_blocks=2048, block_size=16, table_width=64,
+                         slots=8, windowed=False):
     """The packed-prefill lane's attention as ``ParallelAttention.
     _decode_packed`` runs it: one ``(1, chunk)`` row of pack tokens
     through the flash forward kernel with segment ids, LSE-combined
-    with each token's arena history through the paged kernel — ``chunk``
-    one-row slots over the stacked arena at a traced ``layer``."""
+    with each token's arena history — read once per TILE of a request's
+    run (``paged_history_attention``: the paged kernel under a key cap,
+    on a grid whose bounds are data) over the stacked arena at a traced
+    ``layer``. Tile size and count as the engine derives them from the
+    head shapes and ``slots``."""
     from hetu_tpu.ops.attention import attention_with_lse
     from hetu_tpu.ops.paged_pallas import (
-        combine_attention_lse, paged_attention_pallas,
+        TILE_FIELDS, combine_attention_lse, history_tile_count,
+        history_tile_rows, paged_history_attention,
     )
     mesh = _one_dev_mesh(devs)
-    qkv = _sds((1, chunk, heads, head_dim), dtype, mesh)
+    hkv = kv_heads or heads
+    tq = history_tile_rows(heads // hkv, head_dim, hkv, block_size)
+    n_tiles = history_tile_count(chunk, tq, min(slots, chunk))
+    q = _sds((1, chunk, heads, head_dim), dtype, mesh)
+    kv = _sds((1, chunk, hkv, head_dim), dtype, mesh)
     seg = _sds((1, chunk), jnp.int32, mesh)
-    page = _sds((layers, n_blocks, block_size, heads * head_dim), dtype,
-                mesh)
-    tbl = _sds((chunk, table_width), jnp.int32, mesh)
-    hist = _sds((chunk,), jnp.int32, mesh)
+    page = _sds((layers, n_blocks, block_size, hkv * head_dim),
+                jnp.bfloat16, mesh)
+    tiles = _sds((len(TILE_FIELDS), n_tiles), jnp.int32, mesh)
 
-    def f(q, k, v, seg, ka, va, tbl, hist, layer):
+    def f(q, k, v, seg, ka, va, tbl, hist, tiles, layer, window):
         intra, lse_i = attention_with_lse(
             q, k, v, causal=True, segment_ids=seg, impl="pallas",
             interpret=False)
-        past, lse_h = paged_attention_pallas(
-            q[0][:, None], ka, va, tbl, hist - 1, layer=layer,
-            interpret=False, return_lse=True)
-        return combine_attention_lse(intra, lse_i, past[:, 0][None],
-                                     lse_h[:, :, 0].T[None])
+        past, lse_h = paged_history_attention(
+            q[0], ka, va, tbl, hist, tiles, tile_rows=tq, layer=layer,
+            interpret=False, window=window if windowed else None)
+        return combine_attention_lse(intra, lse_i, past[None],
+                                     lse_h.T[None])
 
-    return _compile_kernel(f, (qkv, qkv, qkv, seg, page, page, tbl, hist,
-                               _sds((), jnp.int32, mesh)))
+    return dict(_compile_kernel(f, (
+        q, kv, kv, seg, page, page,
+        _sds((n_tiles, table_width), jnp.int32, mesh),
+        _sds((chunk,), jnp.int32, mesh), tiles,
+        _sds((), jnp.int32, mesh), _sds((), jnp.int32, mesh))),
+        tile_rows=tq, tiles=n_tiles)
 
 
 _HLO_INSTR = re.compile(
@@ -261,6 +273,9 @@ def check_serving_lane(devs, *, lane="decode", dtype=jnp.bfloat16,
     compiler prefetches it there, a copy no serving arena sees."""
     from hetu_tpu.models import GPTConfig, GPTLMHeadModel
     from hetu_tpu.models.generation import init_paged_caches
+    from hetu_tpu.ops.paged_pallas import (
+        TILE_FIELDS, history_tile_count, history_tile_rows,
+    )
     mesh = _one_dev_mesh(devs)
     model = GPTLMHeadModel(GPTConfig.small())
     embed = model.cfg.hidden_size
@@ -275,6 +290,12 @@ def check_serving_lane(devs, *, lane="decode", dtype=jnp.bfloat16,
         lambda: init_paged_caches(model, n_blocks, block_size, dtype)))
     n, shape = (slots, (slots, 1)) if lane == "decode" \
         else (chunk, (1, chunk))
+    attn = model.blocks.block.attn
+    tq = history_tile_rows(1, attn.head_dim, attn.num_kv_heads,
+                           block_size)
+    n_tiles = history_tile_count(chunk, tq, min(slots, chunk))
+    tiles = {"map": _sds((len(TILE_FIELDS), n_tiles), jnp.int32, mesh),
+             "tables": _sds((n_tiles, table_width), jnp.int32, mesh)}
     args = (_sds((), jnp.bool_, mesh), blocks, caches,
             # float32 activations over bf16 weights: the engine's loop
             # thread never enters autocast (PERF.md section 4)
@@ -282,13 +303,14 @@ def check_serving_lane(devs, *, lane="decode", dtype=jnp.bfloat16,
             _sds(shape, jnp.int32, mesh),
             _sds((n, table_width), jnp.int32, mesh),
             _sds((n,), jnp.bool_, mesh), _sds(shape, jnp.int32, mesh),
-            _sds((n,), jnp.int32, mesh))
+            _sds((n,), jnp.int32, mesh), tiles)
 
-    def f(run, blocks, caches, h, pos, tbl, valid, seg, hist):
+    def f(run, blocks, caches, h, pos, tbl, valid, seg, hist, tiles):
         def go(caches):
             kw = dict(slot_mask=valid) if lane == "decode" else dict(
                 pack={"segment_ids": seg, "hist": hist, "valid": valid,
-                      "impl": "pallas"})
+                      "impl": "pallas",
+                      "tiles": dict(tiles, rows=tq)})
             return model.blocks.decode(blocks, h, caches, positions=pos,
                                        block_tables=tbl,
                                        attn_kernel="paged", **kw)
@@ -587,6 +609,16 @@ def main():
         ("paged_decode_one_layer_3d",
          lambda: check_paged(d1, layers=None)),
         ("packed_prefill_c256", lambda: check_packed_prefill(d1)),
+        # the history read's tiles at the serving cells' head shapes
+        ("packed_prefill_gpt2_large_c256",
+         lambda: check_packed_prefill(
+             d1, dtype=jnp.float32, heads=20, layers=36, slots=32,
+             n_blocks=2049, table_width=65)),
+        ("packed_prefill_gqa128x8_d128_c512_window",
+         lambda: check_packed_prefill(
+             d1, chunk=512, heads=128, kv_heads=8, head_dim=128,
+             layers=4, slots=48, n_blocks=4250, block_size=64,
+             table_width=129, windowed=True)),
         ("serving_lane_decode_bf16", lambda: check_serving_lane(d1)),
         ("serving_lane_prefill_int8",
          lambda: check_serving_lane(d1, lane="prefill", dtype=jnp.int8)),

@@ -12,7 +12,7 @@ sides consume:
   Korthikanti et al.; ZeRO shard divisors per Rajbhandari et al. SC'20);
 - the **runtime recorder** (:func:`record_model_memory_plane`): the
   train step seeds a process-global snapshot + ``mem_*`` telemetry
-  gauges on its first call, so ``trace_summary`` / ``bench.py`` report
+  gauges on its first call, so ``tools/trace_summary.py`` reports
   the memory plane next to the control/data planes — and the Perfetto
   counter tracks render it over time;
 - the **remat policy engine** (:func:`derive_remat_mask`): given an HBM
@@ -23,7 +23,7 @@ sides consume:
 Byte numbers here are ANALYTIC (model-shape arithmetic, optionally
 scaled by the AOT-measured calibration) — the ground-truth companion is
 ``jax.local_devices()[0].memory_stats()`` where the backend exposes it
-(``bench.py`` records both).
+(the benchmark's ``memory_peak_bytes``).
 """
 
 from __future__ import annotations
@@ -494,8 +494,8 @@ def size_kv_pool(cfg, *, hbm_budget_bytes: float, max_len: int,
 
 # -- runtime ledger ----------------------------------------------------------
 #
-# Mirrors parallel.overlap's pattern: a module-level snapshot tests and
-# bench.py read without enabling telemetry, plus mem_* gauges in the
+# Mirrors parallel.overlap's pattern: a module-level snapshot tests
+# read without enabling telemetry, plus mem_* gauges in the
 # registry when it is on. Last-write-wins per class (gauge semantics —
 # the memory plane is a state, not a flow).
 

@@ -286,7 +286,7 @@ def enable_persistent_compilation_cache(
     Where ``$JAX_COMPILATION_CACHE_DIR`` is set, jax's own handling of
     it is the whole mechanism and nothing is set here. Otherwise the
     cache goes to ``path``, by default :data:`COMPILE_CACHE_DIR`. The
-    entry points (``chip_smoke.py``, ``bench.py``, ``examples/``) call
+    entry points (``chip_smoke.py``, ``benchmark/``, ``examples/``) call
     this with no argument; ``import hetu_tpu`` never does. Returns the
     directory in force."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")

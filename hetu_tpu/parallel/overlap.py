@@ -27,8 +27,7 @@ Everything here also feeds the **data-plane ledger**: analytic payload
 bytes per traced step program (`comm_bytes_total{kind=...}`), DP gradient
 sync counts from the delayed-sync wrappers in
 ``engine.train_step.build_grad_accum_steps``, and the derived
-``comm_overlap_ratio`` that ``bench.py`` and ``tools/trace_summary.py``
-report.
+``comm_overlap_ratio`` that ``tools/trace_summary.py`` reports.
 """
 
 from __future__ import annotations
@@ -47,8 +46,8 @@ from jax.sharding import PartitionSpec as P
 # `comm_bytes_total{kind}` approximates the payload bytes one *executed*
 # step/call moves for that collective kind; a re-trace of the same program
 # records again (re-traces are themselves counted by `step_traces_total`,
-# so the operator can tell). The ledger mirrors the registry so tests and
-# bench.py read it without enabling telemetry.
+# so the operator can tell). The ledger mirrors the registry so tests
+# read it without enabling telemetry.
 
 _LOCK = threading.Lock()
 _BYTES: dict[str, int] = {}          # kind -> analytic payload bytes

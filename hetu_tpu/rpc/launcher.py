@@ -412,8 +412,7 @@ def launch_serving_fleet(build_engine=None, n_replicas: int = 2, *,
     **In-process** (default): each replica is ``build_engine(i)`` — a
     fresh ServingEngine whose background loop registration starts.
     Threads share one process's devices: the single-host shape used by
-    ``workloads/rollout_loop.py``, ``bench.py --router`` and the
-    router tests.
+    ``workloads/rollout_loop.py`` and the router tests.
 
     **Multi-process** (``remote=True`` — ISSUE 15): one engine PROCESS
     per replica. ``engine_spec`` names a ``module:function`` the child

@@ -1,8 +1,8 @@
 """Persistent multiplexed stream channel over the coordinator port.
 
 The line protocol (``py_server.py`` / ``csrc/coordinator.cpp``) costs
-one round trip per RESULT poll — the fleet's dominant dispatch tax
-(BENCH_fleet.json). This module adds the push lane: a client opens ONE
+one round trip per RESULT poll (``router_result_poll_empty_total``
+counts the wasted ones). This module adds the push lane: a client opens ONE
 long-lived socket per (client, server) pair, sends the hello line
 ``HSTRM1 [token]\\n`` (sniffable by the server's existing
 ``readline()``), and both directions switch to length-framed compact

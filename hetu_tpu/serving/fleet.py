@@ -898,7 +898,7 @@ class RemoteEngineProxy:
             if doc is None:
                 # the poll cycle burned a RESULT round trip for nothing
                 # — the empty-poll fraction is the case for streaming
-                # RESULT (ROADMAP); bench.py --fleet records it
+                # RESULT (tests/test_fleet_observability.py reads it)
                 telemetry.get_registry().counter(
                     "router_result_poll_empty_total",
                     "RESULT polls that returned PEND (wasted round "

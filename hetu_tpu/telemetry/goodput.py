@@ -4,8 +4,7 @@ Every large-run report leads with two numbers the raw step log cannot
 produce: **goodput** (fraction of wall time spent on productive training
 compute — the complement of compile, hot-switch, checkpoint and data-stall
 overheads; HotSPa's switch-cost accounting is a special case) and **MFU**
-(model FLOPs utilization, Megatron/PaLM appendix-B accounting — the same
-formula ``bench.py`` uses for its headline).
+(model FLOPs utilization, Megatron/PaLM appendix-B accounting).
 
 The accountant is a category → seconds ledger the Trainer feeds from its
 loop, plus a token counter; ``report()`` folds in model FLOPs (derived
@@ -44,7 +43,7 @@ SPAN_CATEGORIES = {
 def model_flops_per_token(dims) -> float:
     """Matmul-FLOPs per trained token for a transformer LM described by a
     :class:`~hetu_tpu.tools.galvatron.cost_model.ModelDims` (PaLM
-    appendix-B accounting, identical to ``bench.py``): ``6·N`` for the
+    appendix-B accounting): ``6·N`` for the
     parameter matmuls plus the causal-attention ``6·L·H·s/2·2`` term."""
     return (6.0 * dims.total_params()
             + 6.0 * dims.num_layers * dims.hidden * dims.seq_len)

@@ -13,8 +13,8 @@ searching. This module does the TPU equivalent:
   steps for a set of single-chip-feasible strategies and check the cost
   model ranks them like the hardware does.
 
-Run on hardware via ``workloads/calibrate_run.py``; results are recorded
-in ``docs/PERF.md``.
+Run on hardware via ``workloads/calibrate_run.py``, which writes
+``workloads/out/calibration.json``.
 """
 
 from __future__ import annotations

@@ -124,8 +124,8 @@ def search_uniform(dims: ModelDims, topo: TPUTopology, *,
     instead of starving the caller — a best-effort plan beats none, and
     the warning tells the operator which regime they are in.
 
-    ``measured_path``: a telemetry JSONL (``BENCH_telemetry.jsonl``, a
-    Trainer's ``telemetry.jsonl``) whose ``measured_step`` records carry
+    ``measured_path``: a telemetry JSONL (a Trainer's
+    ``telemetry.jsonl``) whose ``measured_step`` records carry
     OBSERVED per-strategy step times — when present, the final ranking
     is re-ordered by measurement via :func:`rerank_by_measured` (the
     ROADMAP's "feed measured goodput back into the planner" loop)."""
@@ -162,7 +162,7 @@ def search_uniform(dims: ModelDims, topo: TPUTopology, *,
 def load_measured_step_times(path: str) -> dict[str, float]:
     """``{strategy-json: observed seconds/step}`` from a telemetry JSONL.
 
-    Consumes ``measured_step`` records (emitted by ``bench.py`` and by
+    Consumes ``measured_step`` records (emitted by
     ``Trainer.export_telemetry`` — strategy JSON + ``step_time_s``).
     Later records win (the freshest measurement of a strategy).
     Missing/unreadable files return ``{}`` — measurement is an overlay,

@@ -37,7 +37,7 @@ def load_jsonl(path: str) -> list[dict]:
 
 def _is_flight_file(path: str) -> bool:
     """Content check (first record is a ``flight_header``) — dumps are
-    not always named ``flight_<rank>.jsonl`` (e.g. BENCH_flight.jsonl)."""
+    not always named ``flight_<rank>.jsonl``."""
     try:
         with open(path) as f:
             first = f.readline()

@@ -92,9 +92,6 @@ SLOW_TESTS = {
     "test_generate_sampling_and_eos",
     "test_cached_decode_matches_full_forward",
     "test_generate_under_tp_mesh_matches_single_device",
-    # driver artifacts
-    "test_bench_emits_json_contract",
-    "test_bench_serving_emits_json_contract",
     # paged serving (ISSUE 7): compile-heavy parity matrices — the
     # acceptance-critical eviction-churn one-compile test, the
     # shared-system-prompt shrink test and the submission-order
@@ -105,6 +102,7 @@ SLOW_TESTS = {
     # oversubscription is admission arithmetic the quick BlockManager
     # unit already covers — the end-to-end run is a parity matrix
     "test_oversubscribed_slots_share_the_arena",
+    # driver entry points (tests/test_graft_entry.py)
     "test_graft_entry_fn_runs",
     "test_dryrun_multichip_smoke",
     # example-script smoke

@@ -1,8 +1,8 @@
 """Fused streaming LM-head+CE Pallas kernel vs the XLA oracles.
 
 Runs in interpret mode on the CPU mesh (same approach as
-test_flash_pallas.py); the real-chip timing A/B lives in
-workloads/mfu_sweep.py --ce fused.
+test_flash_pallas.py); its timing on the chip is not measured
+(workloads/ce_tune.py has not run there).
 """
 
 import jax

@@ -211,6 +211,23 @@ def test_topology_calibrated_loads_measured_json(tmp_path):
     assert r["ranking_correct"]
 
 
+def test_v5e_peaks_agree():
+    """Two tables state the chip's published figures: the package's
+    (``cost_model.DEVICE_SPECS``, peak and memory size) and the
+    benchmark's own (``benchmark/peaks.py``, peak and memory
+    bandwidth); neither imports the other. They name the same device
+    kinds and give the figure both hold, the bf16 peak, alike."""
+    from benchmark.peaks import PEAKS
+    from hetu_tpu.tools.galvatron.cost_model import DEVICE_SPECS
+
+    assert set(DEVICE_SPECS) == set(PEAKS)
+    for kind, spec in DEVICE_SPECS.items():
+        assert spec["peak_flops"] == PEAKS[kind]["bf16_flops_per_s"], kind
+    v5e = DEVICE_SPECS["TPU v5 lite"]
+    assert (v5e["peak_flops"], v5e["hbm_bytes"]) == (197e12, 16e9)
+    assert PEAKS["TPU v5 lite"]["hbm_bytes_per_s"] == 819e9
+
+
 def test_search_uniform_rank_agrees_with_recorded_calibration():
     """When a real measured calibration exists (TPU window ran), the
     cost model must rank at least one measured strategy pair the same

@@ -498,7 +498,18 @@ def test_spec_greedy_token_identical_all_patterns(gpt):
             return [0] * k           # token 0 never sampled (prompts>0)
 
     eng._draftsman = Hostile()
-    assert eng.generate_many(prompts, sp) == want
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        assert eng.generate_many(prompts, sp) == want
+        # the acceptance floor: every draft rejected commits exactly
+        # the non-speculative one token per decode slot-step
+        reg = telemetry.get_registry()
+        assert reg.counter("serving_draft_tokens_total").value() > 0
+        assert reg.counter("serving_accepted_tokens_total").value() == 0
+    finally:
+        telemetry.enable(False)
+        telemetry.reset()
     assert trace_counts().get("serving_step", 0) - before == 1, \
         "speculation churn re-traced the fused step"
     # mixed depths in one batch: depth riding per-slot data
@@ -512,7 +523,6 @@ def test_spec_greedy_token_identical_all_patterns(gpt):
     outs = eng.generate_many(prompts[:2], mixed)
     assert outs[0] == want[0]
     assert all(0 <= t < cfg.vocab_size for t in outs[1])
-    _ = telemetry
 
 
 @pytest.mark.slow
@@ -722,6 +732,9 @@ def test_model_draftsman_greedy_parity(gpt):
         assert dr > 0
         # self-drafting: once warm, acceptance is near-perfect
         assert ac / dr > 0.8, (ac, dr)
+        # greedy requests never count in the sampled verify lane
+        assert reg.counter(
+            "serving_sampled_accepted_tokens_total").value() == 0
     finally:
         telemetry.enable(False)
         telemetry.reset()

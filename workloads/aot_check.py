@@ -445,23 +445,6 @@ def check_step(devs, strategy, *, batch, seq, cfgkw=None,
             os.environ["HETU_LM_LOSS_IMPL"] = prev_ce
 
 
-def check_ctx32k(devs, batch: int = 2):
-    """AOT HBM precheck of bench_suite config 5 at the batch it
-    attempts FIRST — the model/strategy/policy come from the bench's
-    own ``config5_spec`` so the precheck can never validate a stale
-    config."""
-    from workloads.bench_suite import config5_spec
-    from workloads.pp_memory import analyze
-    from hetu_tpu.models import LlamaLMHeadModel
-
-    seq = 32768
-    cfg, strategy, pol = config5_spec(seq)
-    with _mosaic_aot_env():
-        return analyze(cfg, strategy, devs, batch=batch, seq=seq,
-                       policy=pol, attn_impl="pallas",
-                       model_cls=LlamaLMHeadModel)
-
-
 def check_decode(devs, *, batch=4, prompt=32, new=16):
     """AOT-compile the generation path (prefill + scan decode with a KV
     cache) for the TPU target — the inference surface's compile check."""
@@ -510,67 +493,10 @@ def tuned_block_checks():
     return out
 
 
-def sweep_feasibility(devs, *, seq=1024):
-    """Per-device HBM feasibility of the MFU sweep's contender configs,
-    compiled OFFLINE so the window never burns minutes compiling a
-    config the chip must then refuse. Writes
-    ``out/sweep_feasible.json``; ``mfu_sweep.py`` consults it and skips
-    configs recorded as not fitting."""
-    from workloads.mfu_sweep import CONTENDER_GRID, feasibility_key
-    from hetu_tpu.core.dtypes import Policy
-    from hetu_tpu.models import GPTConfig
-    from hetu_tpu.parallel.strategy import Strategy
-
-    cfg = GPTConfig.small()
-    grid = [(b, r, u, pdt) for (b, r, u) in CONTENDER_GRID
-            for pdt in ("fp32", "bf16")]
-    rows = {}
-    for batch, remat, unroll, pdt in grid:
-        pol = Policy(param_dtype=jnp.bfloat16 if pdt == "bf16"
-                     else jnp.float32, compute_dtype=jnp.bfloat16)
-        key = feasibility_key(batch, remat, unroll, pdt)
-        try:
-            from workloads.pp_memory import analyze
-            with _mosaic_aot_env():
-                r = analyze(cfg, Strategy(remat=remat, unroll=unroll),
-                            devs[:1], batch=batch, seq=seq,
-                            policy=pol, attn_impl="pallas")
-            if "error" in r:
-                # a compile-time HBM refusal IS the feasibility answer
-                oom = "RESOURCE_EXHAUSTED" in r["error"]
-                rows[key] = {"fits": False if oom else None, **r}
-            else:
-                rows[key] = {"fits": r["fits_hbm"], **r}
-        except Exception as e:
-            # a compile-time HBM refusal IS the feasibility answer even
-            # when it surfaces as an exception from the lowering;
-            from bench import is_oom
-            rows[key] = {"fits": False if is_oom(e) else None,
-                         "error": f"{type(e).__name__}: {str(e)[:150]}"}
-        rec = rows[key]
-        peak = rec.get("peak_bytes_est")
-        print(f"{key:>24}: fits={rec['fits']}"
-              + (f" peak {peak / 1024**3:.2f} GiB" if peak else "")
-              + (f" ({rec['error'][:60]})" if "error" in rec else ""),
-              flush=True)
-
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "out", "sweep_feasible.json")
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    with open(path, "w") as f:
-        json.dump({"seq": seq, "attn": "pallas", "rows": rows}, f,
-                  indent=1)
-    print(f"wrote {path}")
-    return rows
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="kernel checks only (skip whole-step compiles)")
-    ap.add_argument("--sweep-feasibility", action="store_true",
-                    help="compile the sweep contender grid for HBM "
-                         "feasibility (writes out/sweep_feasible.json)")
     args = ap.parse_args()
 
     # script-entry only (a module-level set would flip the backend of
@@ -587,11 +513,6 @@ def main():
     topo8 = topologies.get_topology_desc("v5e:2x4", "tpu")
     d1 = list(topo1.devices)
     d8 = list(topo8.devices)
-
-    if args.sweep_feasibility:
-        rows = sweep_feasibility(d1)
-        return 1 if any(r["fits"] is None and "error" in r
-                        for r in rows.values()) else 0
 
     checks = [
         ("flash_causal_bench", lambda: check_flash(d1)),
@@ -635,9 +556,6 @@ def main():
              lambda: check_step(d1[:1], Strategy(remat="selective",
                                                  unroll=True),
                                 batch=32, seq=1024)),
-            # BASELINE config 5 precheck: the 32k-context single-chip
-            # path must fit HBM before a window burns time finding out
-            ("step_ctx32k_feasible", lambda: check_ctx32k(d1[:1])),
             # the remaining dryrun strategy families, compiled for the
             # REAL v5e-8 target (the driver's dryrun only proves the
             # virtual CPU mesh): pipeline-in-manual-region and EP MoE

@@ -1,5 +1,5 @@
-"""Run the cost-model calibration on the real chip and print the table
-recorded in docs/PERF.md (VERDICT r2 item 7).
+"""Run the cost-model calibration on the real chip, print its table and
+write ``workloads/out/calibration.json`` (VERDICT r2 item 7).
 
 Usage: python workloads/calibrate_run.py
 """
@@ -82,8 +82,8 @@ def main():
     os.makedirs(os.path.dirname(out), exist_ok=True)
     with open(out, "w") as f:
         json.dump({
-            # "measured" marks on-chip numbers: the AOT fallback
-            # (workloads/aot_calibrate.py) refuses to overwrite them
+            # "measured" marks on-chip numbers (the file in the tree is
+            # "aot_anchored": fitted offline to an older step time)
             "source": "measured",
             "device_kind": getattr(dev, "device_kind", "tpu"),
             "peak_flops": peak,

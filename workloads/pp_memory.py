@@ -92,10 +92,8 @@ def analyze(cfg, strategy, topo_devices, *, batch, seq, policy,
         "temp_bytes": int(getattr(ma, "temp_size_in_bytes", 0)),
         "alias_bytes": int(getattr(ma, "alias_size_in_bytes", 0)),
     }
-    # XLA's own per-program cost estimate — the offline time-calibration
-    # signal (workloads/aot_calibrate.py): absolute scale is off peak,
-    # but it ranks programs by modeled flops+bytes, which an anchor
-    # measurement converts to wall-time estimates
+    # XLA's own per-program cost estimate: absolute scale is off peak,
+    # but it ranks programs by modeled flops+bytes
     try:
         ca = compiled.cost_analysis()
         ca = ca[0] if isinstance(ca, (list, tuple)) else (ca or {})
@@ -106,7 +104,7 @@ def analyze(cfg, strategy, topo_devices, *, batch, seq, policy,
                 row[dst] = float(ca[src])
     except Exception as e:                              # noqa: BLE001
         # keep the memory rows usable, but make the missing-cost cause
-        # diagnosable downstream (aot_calibrate hard-exits on no flops)
+        # diagnosable downstream
         row["cost_analysis_error"] = repr(e)
     # peak HBM ≈ args + temps (+ outputs not aliased over args); the
     # donated state aliases, so args+temp is the honest per-device bound

@@ -635,7 +635,8 @@ class ParallelAttention(Module):
         offsets, so requests at different depths decode in one batched
         call. Rows with ``slot_mask=False`` (free / prefilling slots)
         leave their cache rows untouched (their compute is discarded by
-        the caller).
+        the caller; the paged kernel spends none on them and returns
+        zeros).
 
         ``block_tables`` (b, W) switches the cache to the PAGED layout:
         leaves are ``(layers, n_blocks, block_size, hkv*d)`` arenas
@@ -782,14 +783,16 @@ class ParallelAttention(Module):
         if paged and attn_kernel == "paged" and self.causal:
             # the Pallas kernel streams arena tiles through the block
             # tables at (layer, page) of the stacked leaves — no
-            # materialized gather, no slice of the arena, dead lanes
-            # skipped, int8 pages dequantized per tile in VMEM; the
+            # materialized gather, no slice of the arena, a grid of
+            # the live (slot, chunk) pairs alone (rows of a slot that
+            # slot_mask turns off are zeros: no step, no read), int8
+            # pages dequantized per tile in VMEM; the
             # _auto wrapper shard_maps the call over a tp-sharded plan's
             # head axis (Mosaic kernels cannot be GSPMD-auto-partitioned)
             from hetu_tpu.ops.paged_pallas import paged_attention_auto
             out = paged_attention_auto(q, k_buf, v_buf, block_tables,
                                        index, layer=layer, window=window,
-                                       **arena)
+                                       live=slot_mask, **arena)
         elif paged:
             if attn_kernel == "paged":
                 from hetu_tpu.ops.attention import record_kernel_fallback

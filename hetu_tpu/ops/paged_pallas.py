@@ -23,16 +23,26 @@ use:
   (a slice is a copy of the layer's leaf, per layer, per lane). A 3-D
   ``(n_blocks, block_size, hkv*d)`` arena is the one-layer case of the
   same call;
-- **online softmax** over table lanes (the KV grid axis is
-  "arbitrary"): running max / denominator / accumulator live in VMEM
-  scratch exactly like ``flash_pallas``;
-- **dead-lane skip**: a ``pl.when`` on the scalar-prefetched per-slot
-  position skips every page beyond the slot's live context, so cost
-  scales with ``ceil(context / block_size)`` pages, not ``table_width``
-  (the long-prompt lane's wide tables ride free); with a ``window``
-  (static ``None`` on models that have none: their program is the same
-  as before the option) the pages wholly below it are skipped too, and
-  not fetched;
+- **a grid that is the work**: the decode and verify rows' call walks
+  the LIST of live (slot, table chunk) pairs (:func:`decode_work_list`,
+  made on the device with the call from the offsets, the window and
+  the slots' ``live`` flags; its length is the grid's one bound, data).
+  A freed or prefilling slot — whatever position it was left at — and
+  a chunk above a slot's context or wholly below its window cost no
+  grid step, no index map, no fetch and no compute: cost scales with
+  ``ceil(context / block_size)`` pages of the slots that decode, not
+  with ``slots x table_width`` (the long-prompt lane's wide tables ride
+  free). Rows nobody computes come back as zeros with the empty part's
+  LSE;
+- **online softmax** over a slot's chunks (successive grid steps):
+  running max / denominator / accumulator live in VMEM scratch exactly
+  like ``flash_pallas``, begun at a slot's first pair and written out
+  at its last;
+- **dead-page skip** inside a chunk: a ``pl.when`` on the
+  scalar-prefetched per-slot position skips the pages of a slot's last
+  chunk that lie beyond its context; with a ``window`` (static ``None``
+  on models that have none) the pages of its first chunk wholly below
+  the window are skipped too, and not fetched;
 - **per-row ``q_offset`` semantics**: q row ``i`` of slot ``s`` attends
   absolute positions ``<= q_offset[s] + i`` — the speculative verify
   lane's k+1 rows (PR 11) are the contract
@@ -101,19 +111,33 @@ def default_pages_per_step(block_size: int) -> int:
     return max(1, min(8, 128 // max(1, int(block_size))))
 
 
+def table_chunks(table_width: int, block_size: int,
+                 pages_per_step: Optional[int] = None) -> tuple[int, int]:
+    """``(pages a grid step streams, steps over a table)``: how the
+    paged call cuts a ``table_width``-lane block table into chunks."""
+    pages = pages_per_step or default_pages_per_step(block_size)
+    pages = max(1, min(pages, table_width))
+    return pages, -(-table_width // pages)
+
+
 def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
-                  n_steps, quant, windowed, tiled=False):
+                  quant, windowed, tiled=False):
     """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
     ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
     page dims are squeezed out of the block — every kv head: a TPU
     block's last two dims must be (8, 128)-tiled or span the array's,
     so heads are lane slices taken inside the kernel). Online softmax
-    across chunks (grid axis 1 is "arbitrary"). ``windowed``: a fourth
-    scalar operand holds each slot's attention window. ``tiled``: slot
-    ``s`` is a TILE of a prefill pack — four more scalar operands hold
-    its key cap, the q cell it reads (the index maps' business) and the
-    cell's rows ``[lo, hi)`` that are its own; the grid's bounds are
-    data."""
+    across a slot's chunks, which are successive grid steps.
+    ``windowed``: a fourth scalar operand holds each slot's attention
+    window. The decode and verify rows' call (not ``tiled``): the grid
+    is the LIST of live (slot, chunk) pairs, two more scalar operands
+    (:func:`decode_work_list`) — step ``i`` is pair ``i``, a slot's
+    first pair is the one whose neighbour below is another slot's, its
+    last the one whose neighbour above is. ``tiled``: slot ``s`` is a
+    TILE of a prefill pack — four more scalar operands hold its key
+    cap, the q cell it reads (the index maps' business) and the cell's
+    rows ``[lo, hi)`` that are its own; the grid is (tiles, chunks)
+    and its bounds are data."""
     del lyr_ref                     # read by the page index maps only
     win_ref = None
     if windowed:
@@ -121,9 +145,28 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     if tiled:
         cap_ref, _, lo_ref, hi_ref, *refs = refs
         n_steps = pl.num_programs(1)
+    else:
+        slot_ref, chunk_ref, *refs = refs
     q_ref, *refs = refs
-    s_i = pl.program_id(0)
-    w = pl.program_id(1)
+    if tiled:
+        s_i = pl.program_id(0)
+        w = pl.program_id(1)
+
+        def first():
+            return w == 0
+
+        def last():
+            return w == n_steps - 1
+    else:
+        i = pl.program_id(0)
+        s_i, w = slot_ref[i], chunk_ref[i]
+
+        def first():
+            return (i == 0) | (slot_ref[jnp.maximum(i - 1, 0)] != s_i)
+
+        def last():
+            above = jnp.minimum(i + 1, slot_ref.shape[0] - 1)
+            return (i == pl.num_programs(0) - 1) | (slot_ref[above] != s_i)
 
     # static ref layout: L k pages, L v pages, [L k scales, L v scales],
     # then outputs (o, lse) and scratch (m, l, acc)
@@ -137,7 +180,7 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     o_ref, lse_ref = refs[idx], refs[idx + 1]
     m_scr, l_scr, acc_scr = refs[idx + 2], refs[idx + 3], refs[idx + 4]
 
-    @pl.when(w == 0)
+    @pl.when(first())
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -223,7 +266,7 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
             live &= page_start + bs > first_k
         pl.when(live)(compute)
 
-    @pl.when(w == n_steps - 1)
+    @pl.when(last())
     def _finalize():
         l = l_scr[:, :, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -254,13 +297,57 @@ def _stacked(x):
     return x[None] if x.ndim == 3 else x
 
 
+def decode_work_list(q_offset, live=None, *, rows: int, span: int,
+                     n_steps: int, window=None):
+    """The decode and verify rows' work: the live (slot, table chunk)
+    pairs, in slot order, chunks ascending — the paged call's grid.
+
+    A chunk is ``span`` positions of a slot's table (``pages_per_step``
+    pages). Slot ``s`` with its ``rows`` q rows at ``q_offset[s] ..``
+    has the chunks from the one that holds the first key its first row
+    sees (position 0, or ``q_offset[s] - window + 1`` under a
+    ``window``: a scalar or one a slot) to the one that holds its last
+    row's position; a slot that is not ``live`` (``None``: all are) has
+    none, whatever its offset says. Returns ``(slot, chunk, n)``: two
+    ``(S * n_steps,)`` int32 arrays whose first ``n`` entries are the
+    pairs (the rest 0) and the count. Made on the device with the
+    call: a cumsum over the slots and one ``(pairs, slots)`` compare,
+    no gather, no sort."""
+    off = jnp.asarray(q_offset, jnp.int32)
+    S = off.shape[0]
+    last = jnp.clip((off + (rows - 1)) // span, 0, n_steps - 1)
+    if window is None:
+        first = jnp.zeros_like(last)
+    else:
+        first = jnp.minimum(
+            jnp.maximum(off - jnp.asarray(window, jnp.int32) + 1, 0)
+            // span, last)
+    count = last - first + 1
+    if live is not None:
+        count = jnp.where(live, count, 0)
+    end = jnp.cumsum(count)
+    i = jnp.arange(S * n_steps, dtype=jnp.int32)
+    # pair i is slot s's when the slots before s end at or below i: the
+    # slot is how many ends lie at or below i, and base[slot] (a
+    # slot's first chunk less its first pair's index) is base[0] plus
+    # the steps of base over those slots
+    below = end[None, :] <= i[:, None]
+    base = first - (end - count)
+    step = jnp.diff(base, append=base[-1:])
+    slot = jnp.sum(below, axis=1, dtype=jnp.int32)
+    chunk = i + base[0] + jnp.sum(jnp.where(below, step[None, :], 0),
+                                  axis=1, dtype=jnp.int32)
+    n = end[-1]
+    return jnp.where(i < n, slot, 0), jnp.where(i < n, chunk, 0), n
+
+
 def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                            layer=None, k_scale=None, v_scale=None,
                            scale: Optional[float] = None,
                            pages_per_step: Optional[int] = None,
                            interpret: Optional[bool] = None,
                            return_lse: bool = False, window=None,
-                           tiles=None):
+                           live=None, tiles=None):
     """Decode attention through per-slot block tables, in-kernel.
 
     - ``q``: ``(S, R, hq, d)`` — S slots × R rows (1 for classic decode,
@@ -293,6 +380,12 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
       and a block whose index did not change is not copied. A
       full-attention layer of a model that has window layers passes a
       window no key is ever below (``2 ** 30``).
+    - ``live`` (``None`` = every slot): ``(S,)`` bool, the slots that
+      decode. The grid is the list of live (slot, table chunk) pairs
+      (:func:`decode_work_list`), so a slot that is not live costs
+      nothing — its ``q_offset`` may be stale and its table row
+      anything that names pages of the arena — and its rows come back
+      as zeros, its LSE ``NEG_INF``. Not with ``tiles``.
     - ``tiles`` (``None`` = none; a static choice like ``window``): the
       slots are TILES of a prefill pack, cut on the host
       (:func:`pack_history_tiles`). ``q`` is then the pack in CELLS of
@@ -327,9 +420,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     rows = R * g
     quant = k_scale is not None
     W = block_tables.shape[1]
-    L = pages_per_step or default_pages_per_step(bs)
-    L = max(1, min(L, W))
-    n_steps = -(-W // L)
+    L, n_steps = table_chunks(W, bs, pages_per_step)
     Wp = n_steps * L
     if Wp != W:
         # pad lanes point at the null block; their positions start at
@@ -345,8 +436,10 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         scalars += (jnp.broadcast_to(jnp.asarray(window, jnp.int32),
                                      (T,)),)
     tiled = tiles is not None
-    grid = (S, n_steps)
     if tiled:
+        if live is not None:
+            raise ValueError("live= is per slot; a dead tile says so "
+                             "in its cap")
         cap = jnp.asarray(tiles["cap"], jnp.int32)
         scalars += (cap,) + tuple(jnp.asarray(tiles[n], jnp.int32)
                                   for n in ("cell", "lo", "hi"))
@@ -354,6 +447,17 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         # table chunks above the deepest cap hold nothing for anyone
         grid = (jnp.max(jnp.where(cap >= 0, jnp.arange(T) + 1, 1)),
                 jnp.clip(jnp.max(cap) // (L * bs) + 1, 1, n_steps))
+    else:
+        # the grid is the list of live (slot, chunk) pairs, made here
+        # on the device: no step for a dead slot or above a context.
+        # No live slot at all: one step, pair (0, 0), whose rows the
+        # mask below zeroes like every dead slot's.
+        with jax.named_scope("hetu.paged_attn"):
+            slot, chunk, n = decode_work_list(
+                q_offset, live, rows=R, span=L * bs, n_steps=n_steps,
+                window=window)
+        scalars += (slot, chunk)
+        grid = (jnp.maximum(n, 1),)
     interpret = _interpret_default() if interpret is None else interpret
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
 
@@ -363,7 +467,14 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     qh = qf.reshape(S, R, hkv, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(S, hkv, rows, d)
 
-    def whole(s, w, *scalars):
+    def pair(at):
+        """(slot or tile, chunk, the scalar operands) of a grid step."""
+        if tiled:
+            return at[0], at[1], at[2:]
+        return at[-2][at[0]], at[-1][at[0]], at[1:]
+
+    def whole(*at):
+        s, _, scalars = pair(at)
         if tiled:
             s = scalars[-3][s]              # (.., cap, CELL, lo, hi)
         return (s, 0, 0, 0)
@@ -376,7 +487,8 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         # at layer 0 of the one-layer stack.
         stacked = x.ndim == 4
 
-        def index(s, w, tbl, off, lyr, *more):
+        def index(*at):
+            s, w, (tbl, off, lyr, *more) = pair(at)
             lane = w * L + j
             first = off[s]
             if tiled:
@@ -420,15 +532,14 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     with jax.named_scope("hetu.paged_attn"):
         out, lse_l = pl.pallas_call(
             functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
-                              hkv=hkv, n_steps=n_steps, quant=quant,
-                              windowed=windowed,
-                              **({"tiled": True} if tiled else {})),
+                              hkv=hkv, quant=quant, windowed=windowed,
+                              tiled=tiled),
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                # tiles of one cell share its output block: in order
-                dimension_semantics=("arbitrary" if tiled
-                                     else "parallel", "arbitrary")),
+                # a slot's pairs, and the tiles of one cell, share an
+                # output block: in order
+                dimension_semantics=("arbitrary",) * len(grid)),
             interpret=interpret,
             name="hetu_paged_attn",
         )(*scalars, *args)
@@ -436,11 +547,17 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     # (S, hkv, R*g, d) → (S, R, hq, d)
     out = out.reshape(S, hkv, R, g, d).transpose(0, 2, 1, 3, 4) \
         .reshape(S, R, hq, d)
+    if live is not None:
+        # a dead slot's blocks were never written: nobody's rows are
+        # zeros, with the empty part's LSE
+        out = jnp.where(live[:, None, None, None], out, 0)
     if return_lse:
         # (S, hkv, R*g) rows (i*g + gj) → (S, hq, R) with head
         # h = kh*g + gj — the attention_reference LSE layout
         lse = lse_l[..., 0].reshape(S, hkv, R, g) \
             .transpose(0, 1, 3, 2).reshape(S, hq, R)
+        if live is not None:
+            lse = jnp.where(live[:, None, None], lse, NEG_INF)
         return out, lse
     return out
 
@@ -451,7 +568,7 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
                          pages_per_step: Optional[int] = None,
                          interpret: Optional[bool] = None,
                          return_lse: bool = False, window=None,
-                         tiles=None):
+                         live=None, tiles=None):
     """:func:`paged_attention_pallas`, tp-aware.
 
     Mosaic kernels cannot be GSPMD-auto-partitioned, so under a
@@ -460,21 +577,22 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
     This wrapper closes that gap: when the current plan binds a tp axis
     of size > 1 and both head counts divide it, the kernel call is
     wrapped in ``shard_map`` over that axis — each shard streams only
-    its LOCAL head slice of the stacked arena (block tables, offsets
-    and the layer ride replicated; the GQA group layout is head-major,
-    so an even hkv split keeps q-head groups contiguous per shard).
+    its LOCAL head slice of the stacked arena (block tables, offsets,
+    ``live`` and the layer ride replicated; the GQA group layout is
+    head-major, so an even hkv split keeps q-head groups contiguous per
+    shard).
     Everything else (no context, tp == 1, ragged heads — which
     ``resolve_decode_kernel`` already degrades) is the plain call."""
     from hetu_tpu.parallel.sharding import (
         _axis_size, current_act_sharding,
     )
 
-    def call(q, k, v, tbl, off, layer, ks, vs, tiles=tiles):
+    def call(q, k, v, tbl, off, layer, ks, vs, live=live, tiles=tiles):
         return paged_attention_pallas(
             q, k, v, tbl, off, layer=layer, k_scale=ks, v_scale=vs,
             scale=scale, pages_per_step=pages_per_step,
             interpret=interpret, return_lse=return_lse, window=window,
-            **({} if tiles is None else {"tiles": tiles}))
+            live=live, tiles=tiles)
 
     ctx = current_act_sharding()
     head_ax = ctx.tp if ctx is not None and isinstance(ctx.tp, str) \
@@ -508,6 +626,9 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
     if k_scale is not None:
         in_specs += (page_spec(k_scale), page_spec(v_scale))
         args += (k_scale, v_scale)
+    if live is not None:
+        in_specs += (P(None),)
+        args += (live,)
     tile_keys = sorted(tiles) if tiles is not None else []
     in_specs += (P(None),) * len(tile_keys)      # the tile map rides
     args += tuple(jnp.asarray(tiles[n], jnp.int32) for n in tile_keys)
@@ -515,9 +636,12 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
         else head_spec
 
     def local(q, k, v, tbl, off, lyr, *rest):
-        ks, vs = rest[:2] if k_scale is not None else (None, None)
-        tile_map = dict(zip(tile_keys, rest[len(rest) - len(tile_keys):]))
-        return call(q, k, v, tbl, off, lyr[0], ks, vs, tile_map or None)
+        rest = list(rest)
+        ks, vs = (rest.pop(0), rest.pop(0)) if k_scale is not None \
+            else (None, None)
+        lv = rest.pop(0) if live is not None else None
+        return call(q, k, v, tbl, off, lyr[0], ks, vs, lv,
+                    dict(zip(tile_keys, rest)) or None)
 
     fn = shard_map(local, mesh=ctx.mesh, in_specs=in_specs,
                    out_specs=out_specs, axis_names=set(ctx.mesh.shape),

@@ -158,6 +158,14 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "serving_prefill_hist_rows_total",
             "pack tokens in live history tiles (over the live tiles: "
             "rows that share one pass over a request's pages)"),
+        decode_chunks=reg.counter(
+            "serving_decode_chunks_total",
+            "(slot, table chunk) pairs of the decode rows' paged call "
+            "by state, per iteration that decodes, as a full-attention "
+            "layer sees them (live = a pair of the call's work list: "
+            "an active slot's chunk at or below its last row; skipped "
+            "= the rest of slots x chunks: freed and prefilling slots, "
+            "chunks above a context — no grid step)"),
         draft=reg.counter(
             "serving_draft_tokens_total",
             "draft tokens proposed to the verify lane"),
@@ -606,6 +614,7 @@ class ServingEngine:
         # holds at most _fin_cap runs, each may open one more tile
         from hetu_tpu.ops.paged_pallas import (
             history_tile_count, history_tile_rows, pack_history_tiles,
+            table_chunks,
         )
         self._pack_tiles = pack_history_tiles
         self._hist_tile = history_tile_rows(
@@ -617,6 +626,13 @@ class ServingEngine:
             self.prefill_chunk, self._hist_tile, self._fin_cap) \
             if prefill_attn != "reference" \
             and self.attn_kernel == "paged" else 0
+        # the decode rows' paged call walks the live (slot, chunk)
+        # pairs (ops.paged_pallas.decode_work_list): a chunk's span in
+        # positions and a table's chunks, for
+        # serving_decode_chunks_total (0: the gather path has no list)
+        pages, steps = table_chunks(W, self.pool.block_size)
+        self._chunk_span = pages * self.pool.block_size
+        self._chunk_steps = steps if self.attn_kernel == "paged" else 0
         # W8A8 decode-FFN compute: per-layer A/B as a (layers,) bool
         # baked into the step. Gated on the int8 arena — an operator
         # who priced the KV at 8 bits has already accepted 8-bit error
@@ -807,7 +823,9 @@ class ServingEngine:
             # classic one-token decode, bit for bit. Rows past a slot's
             # depth are masked from writing (row_mask) — their
             # positions may lie beyond the blocks its table owns.
-            # Free/prefilling slots compute garbage that the masks keep
+            # Free/prefilling slots attend nothing (the paged kernel
+            # walks the active slots' chunks alone; the gather path
+            # computes garbage); what their rows carry the masks keep
             # out of the pool and the host ignores; cond-gated so
             # prefill-only iterations skip the discarded forward.
             def do_decode(caches):
@@ -2377,6 +2395,15 @@ class ServingEngine:
                     self._rep)
                 self._ctl_dirty = False
             ctl = self._ctl_dev
+            if self._chunk_steps and self._active.any():
+                # an active slot's K + 1 rows end in chunk (pos + K) //
+                # span: its pairs are that chunk and those below
+                live = int(np.minimum(
+                    (self._pos[self._active] + K) // self._chunk_span + 1,
+                    self._chunk_steps).sum())
+                m.decode_chunks.inc(live, state="live")
+                m.decode_chunks.inc(S * self._chunk_steps - live,
+                                    state="skipped")
             # pack the prefill budget FCFS over in-flight prefills: the
             # oldest request fills first (so a lone request's chunk
             # count matches the PR 5 single-admission engine), the rest

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from hetu_tpu import telemetry
 from hetu_tpu.models import GPTConfig, GPTLMHeadModel, generate
 from hetu_tpu.ops.paged_pallas import (
-    NEG_INF, combine_attention_lse, history_tile_count,
+    NEG_INF, combine_attention_lse, decode_work_list, history_tile_count,
     history_tile_rows, pack_history_tiles, paged_attention_auto,
     paged_attention_pallas, paged_attention_reference,
     paged_history_attention,
@@ -261,27 +261,28 @@ def test_paged_attention_auto_stacked_arena_under_tp_mesh(gpt):
                                    atol=1e-5)
 
 
-#: sha256(str(jaxpr))[:16] of the decode lane's (1 row) and the verify
-#: lane's (k + 1 = 4 rows) kernel calls at commit 2e91042 (PR 26), the
-#: parent of the PR that gave the kernel its tiled mode, traced under
-#: this suite's configuration (conftest sets the default matmul
-#: precision to highest, which the jaxpr prints; under JAX's defaults
-#: they read 15734148f5b2ff8e, db91fee71ce8e73b, 5990c06ab3c7c47c,
-#: 95364a637a3ed8a6 — at both commits): ``tiles=None`` must leave those
-#: programs as they were, operand for operand
-PARENT_JAXPR = {(1, "bf16"): "92988cda9a2ea733",
-                (1, "int8"): "5d710ce1524ffa80",
-                (4, "bf16"): "88a07083a38b1053",
-                (4, "int8"): "917b0e144e2a3fc4"}
+#: sha256(str(jaxpr))[:16] of the prefill lane's TILED call (a page a
+#: grid step, as ``paged_history_attention`` makes it) at commit c19234f
+#: (PR 28), the parent of the PR that made the decode rows' grid a work
+#: list, at the decode (1 row a cell) and verify (4) shapes, traced
+#: under this suite's configuration (conftest sets the default matmul
+#: precision to highest, which the jaxpr prints): that PR must leave
+#: the tiled program as it was, operand for operand
+PARENT_TILED_JAXPR = {(1, "bf16"): "fdb5044bb35c102f",
+                      (1, "int8"): "940f77d91377ae01",
+                      (4, "bf16"): "4f5b45cf09c5cad5",
+                      (4, "int8"): "68960fee15e1043e"}
 
 
-@pytest.mark.parametrize("rows,arena", list(PARENT_JAXPR))
-def test_decode_and_verify_lane_calls_are_the_parents_program(rows, arena):
-    """The decode-lane and verify-lane calls did not change when the
-    kernel learned tiles: three scalar-prefetch operands (tables,
-    offsets, layer), a static grid, and the parent's jaxpr to the
-    character. The history read's call has seven and a grid of two
-    traced bounds."""
+@pytest.mark.parametrize("rows,arena", list(PARENT_TILED_JAXPR))
+def test_decode_call_walks_a_work_list_and_tiled_call_is_the_parents(
+        rows, arena):
+    """The decode-lane and verify-lane calls walk the list of live
+    (slot, chunk) pairs: ONE grid dimension whose bound is data, five
+    scalar-prefetch operands (tables, offsets, layer, the pairs' slots
+    and chunks; six under a window) — no static ``(S, n_steps)`` grid
+    is left. The history read's tiled call keeps seven operands, two
+    traced bounds and the parent's jaxpr to the character."""
     import hashlib
     S, hq, hkv, d, L, nb, bs, W = 4, 4, 2, 16, 3, 9, 4, 8
     quant = arena == "int8"
@@ -298,20 +299,252 @@ def test_decode_and_verify_lane_calls_are_the_parents_program(rows, arena):
                                       k_scale=ks, v_scale=vs,
                                       interpret=True, **kw)
 
-    def pallas_eqn(jaxpr):
+    def grid_mapping(jaxpr):
         eqn, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
         return eqn.params["grid_mapping"]
 
-    jaxpr = jax.make_jaxpr(f)(*args)
-    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] \
-        == PARENT_JAXPR[rows, arena]
-    gm = pallas_eqn(jaxpr.jaxpr)
-    assert gm.num_index_operands == 3 and gm.num_dynamic_grid_bounds == 0
+    live = jnp.asarray([True, False, True, True])
+    for kw, operands in (({}, 5), ({"live": live}, 5),
+                         ({"window": jnp.asarray(6, jnp.int32)}, 6)):
+        gm = grid_mapping(jax.make_jaxpr(
+            lambda *a: f(*a, pages_per_step=2, **kw))(*args).jaxpr)
+        assert gm.num_index_operands == operands
+        assert gm.num_dynamic_grid_bounds == 1 and len(gm.grid) == 1
+        assert not any(isinstance(b, int) for b in gm.grid)
     tiles = {n: jnp.zeros((S,), jnp.int32)
              for n in ("cap", "cell", "lo", "hi")}
-    gm = pallas_eqn(jax.make_jaxpr(
-        lambda *a: f(*a, tiles=tiles))(*args).jaxpr)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: f(*a, pages_per_step=1, tiles=tiles))(*args)
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] \
+        == PARENT_TILED_JAXPR[rows, arena]
+    gm = grid_mapping(jaxpr.jaxpr)
     assert gm.num_index_operands == 7 and gm.num_dynamic_grid_bounds == 2
+    with pytest.raises(ValueError, match="live="):
+        f(*(jnp.zeros(a.shape, a.dtype) for a in args), tiles=tiles,
+          live=live)
+
+
+# -- the decode rows' work list: live (slot, chunk) pairs -------------------
+
+def _pairs(off, live, *, rows, span, n_steps, window=None):
+    """The live pairs in plain Python, from the definition."""
+    want = []
+    for s, o in enumerate(off):
+        if live is not None and not live[s]:
+            continue
+        w = window if window is None or np.ndim(window) == 0 \
+            else window[s]
+        lo = 0 if w is None else max(o - int(w) + 1, 0) // span
+        hi = min((o + rows - 1) // span, n_steps - 1)
+        want += [(s, c) for c in range(min(lo, hi), hi + 1)]
+    return want
+
+
+WORK_LISTS = {
+    "all_live": dict(off=[0, 5, 17, 31], live=None, rows=1),
+    "live_none_is_all_true": dict(off=[0, 5, 17, 31],
+                                  live=[True] * 4, rows=1),
+    "dead_slots_at_stale_offsets": dict(
+        off=[30, 5, 17, 31], live=[False, True, False, True], rows=1),
+    "first_and_last_dead": dict(
+        off=[9, 0, 12, 3], live=[False, True, True, False], rows=1),
+    "verify_rows_cross_a_chunk": dict(
+        off=[7, 6, 15, 28], live=[True, True, False, True], rows=4),
+    "window": dict(off=[0, 9, 20, 31], live=[True, True, True, False],
+                   rows=1, window=6),
+    "window_per_slot": dict(off=[3, 9, 20, 31], live=None, rows=2,
+                            window=[2, 30, 5, 9]),
+    "full_layer_of_a_windowed_model": dict(
+        off=[3, 9, 20, 31], live=[True, False, True, True], rows=1,
+        window=2 ** 30),
+    "all_dead": dict(off=[9, 0, 12, 3], live=[False] * 4, rows=1),
+    "one_live": dict(off=[9, 0, 12, 3],
+                     live=[False, False, True, False], rows=4),
+}
+
+
+@pytest.mark.parametrize("case", list(WORK_LISTS))
+@pytest.mark.parametrize("span", [4, 8, 12])
+def test_decode_work_list_is_the_live_pairs_in_slot_order(case, span):
+    """``decode_work_list`` as a pure function: for given offsets /
+    ``live`` / window / rows it lists exactly the live (slot, chunk)
+    pairs — slot order, chunks ascending, from the chunk of the first
+    key a slot's first row sees to the chunk of its last row, none for
+    a dead slot whatever its offset says — and zeros after them."""
+    kw = dict(WORK_LISTS[case])
+    off, live = kw.pop("off"), kw.pop("live")
+    n_steps = -(-32 // span)
+    want = _pairs(off, live, span=span, n_steps=n_steps, **kw)
+    if "window" in kw:
+        kw["window"] = jnp.asarray(kw["window"], jnp.int32)
+    slot, chunk, n = jax.jit(
+        lambda o, lv: decode_work_list(o, lv, span=span, n_steps=n_steps,
+                                       **kw))(
+        jnp.asarray(off, jnp.int32),
+        None if live is None else jnp.asarray(live))
+    assert slot.shape == chunk.shape == (len(off) * n_steps,)
+    assert int(n) == len(want)
+    got = list(zip(np.asarray(slot).tolist(), np.asarray(chunk).tolist()))
+    assert got[:len(want)] == want
+    assert got[len(want):] == [(0, 0)] * (len(got) - len(want))
+    if case == "all_dead":
+        assert not want
+    if case == "live_none_is_all_true":
+        assert want == _pairs(off, None, rows=1, span=span,
+                              n_steps=n_steps)
+
+
+def _poisoned_dead_slots(rng, live, *, R=1, dtype=jnp.float32, **kw):
+    """An arena whose DEAD slots stand at stale offsets over garbage
+    table rows (pages full of NaN): what a freed or prefilling slot
+    leaves behind."""
+    q, k, v, tbl = _arena(rng, S=len(live), R=R, dtype=dtype, **kw)
+    k, v = (jnp.concatenate([x, jnp.full_like(x[:3], jnp.nan)])
+            for x in (k, v))            # pages 9..11: nobody's
+    tbl = np.asarray(tbl).copy()
+    off = rng.integers(0, 28 - R, size=len(live)).astype(np.int32)
+    for s, alive in enumerate(live):
+        if not alive:
+            tbl[s] = rng.integers(9, 12, size=tbl.shape[1])
+            off[s] = 27 - R
+    return q, k, v, jnp.asarray(tbl), jnp.asarray(off)
+
+
+@pytest.mark.parametrize("live", [
+    [True, False, True, False, False, True],
+    [False, False, True, True, True, False],
+    [False, True, False, False, False, False],
+], ids=["interleaved", "dead_at_both_ends", "one_live"])
+@pytest.mark.parametrize("rows,pages", [(1, 1), (1, 3), (4, 2), (4, 8)])
+def test_paged_kernel_dead_slots_give_zero_rows(live, rows, pages):
+    """A dead slot costs no grid step: its stale offset and garbage
+    table (pages of NaN) are never read, its rows are zeros and its LSE
+    ``NEG_INF`` — while every live slot equals the gather oracle,
+    decode and verify rows, every tiling."""
+    rng = np.random.default_rng(31)
+    q, k, v, tbl, off = _poisoned_dead_slots(rng, live, R=rows)
+    lv = np.asarray(live)
+    out, lse = paged_attention_pallas(q, k, v, tbl, off, live=jnp.asarray(lv),
+                                      pages_per_step=pages,
+                                      return_lse=True)
+    ref, lse_r = paged_attention_reference(q[lv], k, v, tbl[lv], off[lv],
+                                           return_lse=True)
+    np.testing.assert_allclose(np.asarray(out)[lv], np.asarray(ref),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse)[lv], np.asarray(lse_r),
+                               atol=1e-5)
+    assert not np.asarray(out)[~lv].any()
+    assert (np.asarray(lse)[~lv] == NEG_INF).all()
+
+
+@pytest.mark.parametrize("window", [3, 9, [2, 8, 30, 5, 5, 12], 2 ** 30])
+def test_paged_kernel_window_layers_start_above_chunk_zero(window):
+    """Under a window a slot's list starts at the chunk of the first
+    key it sees (chunks below are no steps at all, not skipped ones):
+    equal to the windowed oracle with dead slots beside, verify rows,
+    and ``2 ** 30`` (the full layer of a mixed model) cuts nothing."""
+    rng = np.random.default_rng(32)
+    live = [True, True, False, True, False, True]
+    q, k, v, tbl, off = _poisoned_dead_slots(rng, live, R=4)
+    off = off.at[0].set(23)             # far above a window of 3
+    lv = np.asarray(live)
+    win = jnp.asarray(window, jnp.int32)
+    for pages in (1, 2, 8):
+        _, chunk, n = decode_work_list(off, jnp.asarray(lv), rows=4,
+                                       span=pages * 4, n_steps=-(-8 // pages),
+                                       window=win)
+        if pages == 1 and np.max(window) < 10:
+            assert int(chunk[0]) > 0
+        out, lse = paged_attention_pallas(
+            q, k, v, tbl, off, live=jnp.asarray(lv), window=win,
+            pages_per_step=pages, return_lse=True)
+        ref, lse_r = paged_attention_reference(
+            q[lv], k, v, tbl[lv], off[lv], return_lse=True,
+            window=win if win.ndim == 0 else win[lv])
+        np.testing.assert_allclose(np.asarray(out)[lv], np.asarray(ref),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(lse)[lv], np.asarray(lse_r),
+                                   atol=1e-5)
+        assert not np.asarray(out)[~lv].any()
+
+
+@pytest.mark.parametrize("rows", [1, 4], ids=["decode", "verify4"])
+@pytest.mark.parametrize("arena", ["bf16", "int8"])
+def test_paged_kernel_work_list_in_a_layer_scan(arena, rows):
+    """The list inside a layer scan, as the fused step makes it: the
+    stacked arena (int8: quantized pages and their scales) at a traced
+    layer whose window is traced too, dead slots beside live ones."""
+    layers = 3
+    rng = np.random.default_rng(33)
+    dtype = jnp.bfloat16 if arena == "bf16" else jnp.int8
+    q, k, v, tbl, scales = _stacked_arena(rng, layers, dtype=dtype,
+                                          R=rows, S=4)
+    off = jnp.asarray([0, 27 - rows, 17, 9], jnp.int32)
+    live = jnp.asarray([True, False, True, True])
+    lv = np.asarray(live)
+    windows = jnp.asarray([5, 2 ** 30, 11], jnp.int32)
+
+    def one(_, x):
+        layer, win = x
+        return None, paged_attention_pallas(
+            q, k, v, tbl, off, layer=layer, window=win, live=live,
+            pages_per_step=2, return_lse=True, **scales)
+
+    _, (outs, lses) = jax.jit(lambda: jax.lax.scan(
+        one, None, (jnp.arange(layers, dtype=jnp.int32), windows)))()
+    tol = 2e-2 if arena == "bf16" else 1e-5
+    for l in range(layers):
+        ref, lse_r = paged_attention_reference(
+            q[lv], k[l], v[l], tbl[lv], off[lv], return_lse=True,
+            window=windows[l], **{n: x[l] for n, x in scales.items()})
+        np.testing.assert_allclose(
+            np.asarray(outs[l], np.float32)[lv],
+            np.asarray(ref, np.float32), atol=tol)
+        np.testing.assert_allclose(np.asarray(lses[l])[lv],
+                                   np.asarray(lse_r), atol=tol)
+        assert not np.asarray(outs[l], np.float32)[~lv].any()
+        assert (np.asarray(lses[l])[~lv] == NEG_INF).all()
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_paged_kernel_all_slots_dead_is_one_harmless_step(rows):
+    """No live slot: the grid is one step (pair (0, 0), slot 0's stale
+    rows) whose result the mask drops — all zeros, all ``NEG_INF``,
+    nothing non-finite although every page the tables name is NaN."""
+    rng = np.random.default_rng(34)
+    q, k, v, tbl, off = _poisoned_dead_slots(rng, [False] * 3, R=rows)
+    live = jnp.zeros((3,), bool)
+    _, _, n = decode_work_list(off, live, rows=rows, span=8, n_steps=4)
+    assert int(n) == 0
+    out, lse = paged_attention_pallas(q, k, v, tbl, off, live=live,
+                                      return_lse=True)
+    assert not np.asarray(out).any()
+    assert (np.asarray(lse) == NEG_INF).all()
+
+
+@pytest.mark.parametrize("arena", ["f32", "int8"])
+def test_paged_kernel_live_none_is_all_true(arena):
+    """``live=None`` (the tuner, the AOT check, every older caller) is
+    every slot live: the same numbers, bit for bit, as ``live`` all
+    true."""
+    from hetu_tpu.ops.quantization import quantize_int8
+    rng = np.random.default_rng(35)
+    q, k, v, tbl = _arena(rng, R=2)
+    off = jnp.asarray([3, 0, 19], jnp.int32)
+    kw = {}
+    if arena == "int8":
+        k, kw["k_scale"] = map(_pages, quantize_int8(
+            k.reshape(9, 4, 2, 16), axis=-1))
+        v, kw["v_scale"] = map(_pages, quantize_int8(
+            v.reshape(9, 4, 2, 16), axis=-1))
+    a, lse_a = paged_attention_pallas(q, k, v, tbl, off, return_lse=True,
+                                      **kw)
+    b, lse_b = paged_attention_pallas(q, k, v, tbl, off, return_lse=True,
+                                      live=jnp.ones((3,), bool), **kw)
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert np.array_equal(np.asarray(lse_a), np.asarray(lse_b))
+    ref = paged_attention_reference(q, k, v, tbl, off, **kw)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(ref), atol=1e-5)
 
 
 def test_paged_kernel_dead_lanes_inert():
@@ -886,6 +1119,66 @@ def test_engine_counts_history_tiles_on_the_host(gpt, monkeypatch):
         # the gather lane cuts no tiles
         ref = ServingEngine(model, params, attn_kernel="paged", **kw)
         assert ref._hist_tiles == 0
+    finally:
+        telemetry.enable(False)
+        telemetry.reset()
+
+
+def test_engine_counts_decode_chunks_on_the_host(gpt, monkeypatch):
+    """``serving_decode_chunks_total{state}``: per decoding iteration
+    the live (slot, chunk) pairs of a full-attention layer against
+    slots x chunks (3 x 4 at a page a step). Once the short request is
+    finished its slot stands at its last position and is NOT counted:
+    the next iteration's live pairs are the long request's alone, its
+    chunks are ``skipped``."""
+    from hetu_tpu.ops import paged_pallas
+    from hetu_tpu.serving import SamplingParams, ServingEngine
+    cfg, model, params = gpt
+    monkeypatch.setattr(paged_pallas, "default_pages_per_step",
+                        lambda block_size: 1)
+    short, long_ = _prompts(cfg, (11, 6), seed=41)
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        eng = ServingEngine(model, params, slots=3, max_len=MAX_LEN,
+                            prefill_chunk=CHUNK, block_size=BLOCK,
+                            attn_kernel="paged")
+        chunks = telemetry.get_registry().counter(
+            "serving_decode_chunks_total")
+
+        def counts():
+            return (chunks.value(state="live"),
+                    chunks.value(state="skipped"))
+
+        a = eng.submit(short, SamplingParams(max_tokens=2))
+        b = eng.submit(long_, SamplingParams(max_tokens=14))
+        while not a.done.is_set():
+            # both decode: each iteration counts every active slot's
+            # chunks up to its position's, 12 pairs in all
+            before, pos = counts(), eng._pos.copy()
+            act = eng._active.copy()
+            eng.step()
+            live, skipped = np.subtract(counts(), before)
+            if act.any():
+                assert live == (pos[act] // BLOCK + 1).sum()
+                assert live + skipped == 12
+        slot_a = int(np.flatnonzero(~eng._active & (eng._pos > 0))[0])
+        assert eng._pos[slot_a] >= len(short)    # stale, and left so
+        while not b.done.is_set():
+            before, pos_b = counts(), int(eng._pos[eng._active][0])
+            eng.step()
+            live, skipped = np.subtract(counts(), before)
+            # the freed slot's two chunks are skipped, not live
+            assert live == pos_b // BLOCK + 1
+            assert skipped == 12 - live
+        assert counts()[0] > 0
+        # the gather path has no work list to count
+        ref = ServingEngine(model, params, slots=3, max_len=MAX_LEN,
+                            prefill_chunk=CHUNK, block_size=BLOCK)
+        assert ref._chunk_steps == 0
+        want = ref.generate_many([short, long_], [
+            SamplingParams(max_tokens=2), SamplingParams(max_tokens=14)])
+        assert [a.tokens, b.tokens] == want
     finally:
         telemetry.enable(False)
         telemetry.reset()

@@ -1,4 +1,6 @@
-"""Model zoo: GPT-2, Llama and Command A+ (cohere2_moe) families.
+"""Model zoo: GPT-2, Llama, Command A+ (cohere2_moe) and latent-attention
+(MLA) expert decoders (``mla_moe``: loaded on first use, so that the
+cells that never build one do not pay for its import).
 
 Parity targets: ``python/hetu/models/gpt`` and
 ``python/hetu/models/llama/llama_model.py`` (LlamaModel :385,
@@ -16,6 +18,18 @@ from hetu_tpu.models.vision import (
 )
 from hetu_tpu.models.generation import generate, decode, init_kv_caches
 
+_LAZY = {"MLAMoEConfig": "hetu_tpu.models.mla_moe",
+         "MLAMoEForCausalLM": "hetu_tpu.models.mla_moe"}
+
+
+def __getattr__(name):
+    if name in _LAZY:
+        import importlib
+        return getattr(importlib.import_module(_LAZY[name]), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
 __all__ = ["GPTConfig", "GPTLMHeadModel", "LlamaConfig", "BertConfig", "BertModel", "CNNConfig", "SimpleCNN", "MLPClassifier", "RNNConfig", "SimpleRNN", "LlamaLMHeadModel",
            "Cohere2MoEConfig", "Cohere2MoEForCausalLM",
+           "MLAMoEConfig", "MLAMoEForCausalLM",
            "generate", "decode", "init_kv_caches"]

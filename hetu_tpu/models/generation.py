@@ -54,7 +54,10 @@ def _head_weight(model, params):
 
 
 def init_kv_caches(model, batch: int, max_len: int, dtype=jnp.float32):
-    """(k, v) buffers stacked over layers: (L, b, max_len, hkv, d).
+    """(k, v) buffers stacked over layers: (L, b, max_len, hkv, d) — or
+    whatever leaves the model's attention declares (its
+    ``kv_leaf_shapes()``: a latent attention has ONE, ``(L, b, max_len,
+    1, row)``).
 
     ``dtype=jnp.int8`` builds the QUANTIZED cache — (k int8, k scales,
     v int8, v scales) with per-(position, head) fp32 scales — the
@@ -62,13 +65,23 @@ def init_kv_caches(model, batch: int, max_len: int, dtype=jnp.float32):
     decode bottleneck (the per-step cache read is pure HBM bandwidth;
     int8 halves it vs bf16 and quarters it vs fp32)."""
     attn = model.blocks.block.attn
-    L = model.blocks.num_layers
-    shape = (L, batch, max_len, attn.num_kv_heads, attn.head_dim)
+    lead = (model.blocks.num_layers, batch, max_len)
+    # the model's attention says what a token's cache leaves are
+    # (``kv_leaf_shapes``): K and V rows per kv head, or ONE latent row
+    shapes = [lead + tuple(t) for t in attn.kv_leaf_shapes()]
     if dtype == jnp.int8:
-        sshape = shape[:-1] + (1,)
-        return (jnp.zeros(shape, jnp.int8), jnp.zeros(sshape, jnp.float32),
-                jnp.zeros(shape, jnp.int8), jnp.zeros(sshape, jnp.float32))
-    return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        if len(shapes) != 2:
+            from hetu_tpu.nn.parallel import LatentKVNotSupported
+            raise LatentKVNotSupported(
+                "the int8 cache keeps one scale per (position, kv head) "
+                "of a K and a V leaf; this model's attention caches "
+                f"{len(shapes)} leaf of {shapes[0][3:]} a token")
+        out = ()
+        for shape in shapes:
+            out += (jnp.zeros(shape, jnp.int8),
+                    jnp.zeros(shape[:-1] + (1,), jnp.float32))
+        return out
+    return tuple(jnp.zeros(shape, dtype) for shape in shapes)
 
 
 def init_paged_caches(model, n_blocks: int, block_size: int,

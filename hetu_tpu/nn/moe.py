@@ -834,7 +834,10 @@ class ExpertShareMoE(Module):
 
     The router keeps its full width: ``s = sigmoid(x W_r)``, the ``k``
     largest are chosen and weighted ``w_e = s_e / sum_chosen s``
-    (normalised over ALL k chosen, held here or not). The layer returns
+    (normalised over ALL k chosen, held here or not). With
+    ``select_bias`` the choice is by ``s + b`` (a per-expert bias, a
+    parameter) while the weights stay ``s``'s; ``scale`` multiplies
+    them. The layer returns
     ``sum_{e chosen, first <= e < first + count} w_e E_e(x)`` — what the
     absent experts would have added is left out, here and in the
     reference alike (``benchmark/reference/cohere2_moe.py``). No token
@@ -858,7 +861,9 @@ class ExpertShareMoE(Module):
     returns_aux = False
 
     def __init__(self, features: int, hidden: int, num_experts: int, *,
-                 k: int, local_experts: Optional[tuple] = None, init=None):
+                 k: int, local_experts: Optional[tuple] = None,
+                 select_bias: bool = False,
+                 scale: Optional[float] = None, init=None):
         super().__init__()
         first, count = local_experts or (0, num_experts)
         if not (0 <= first and count >= 1
@@ -872,6 +877,14 @@ class ExpertShareMoE(Module):
         init = init or normal_init(0.02)
         self.param("router", (features, num_experts), init,
                    axes=("embed", None))
+        # bias-corrected routing (both static None / False on a model
+        # that has neither): the k experts with the largest ``s + b``
+        # are CHOSEN, their weights still come from ``s`` alone, times
+        # ``scale``. The bias is drawn like a weight, not zeros: a
+        # program that leaves it out must differ
+        self.select_bias, self.scale = bool(select_bias), scale
+        if select_bias:
+            self.param("select_bias", (num_experts,), init, axes=(None,))
         self.param("wg", (count, features, hidden), init,
                    axes=("expert", "embed", "mlp"))
         self.param("wi", (count, features, hidden), init,
@@ -884,8 +897,17 @@ class ExpertShareMoE(Module):
         z = jnp.matmul(x.astype(jnp.float32),
                        params["router"].astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
-        top, idx = jax.lax.top_k(jax.nn.sigmoid(z), self.k)
-        return idx.astype(jnp.int32), top / top.sum(-1, keepdims=True)
+        if self.select_bias:
+            s = jax.nn.sigmoid(z)
+            _, idx = jax.lax.top_k(
+                s + params["select_bias"].astype(jnp.float32), self.k)
+            top = jnp.take_along_axis(s, idx, axis=-1)
+        else:
+            top, idx = jax.lax.top_k(jax.nn.sigmoid(z), self.k)
+        w = top / top.sum(-1, keepdims=True)
+        if self.scale is not None:
+            w = w * self.scale
+        return idx.astype(jnp.int32), w
 
     def __call__(self, params, x, *, return_sizes: bool = False):
         """``return_sizes``: also the ``(count,)`` int32 numbers of

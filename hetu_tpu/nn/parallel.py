@@ -487,6 +487,18 @@ class ParallelAttention(Module):
         else:
             self._rope = None
 
+    def kv_leaf_shapes(self) -> tuple:
+        """The cache spec: the trailing dims of each cache leaf, a
+        token and layer — what ``generation.init_kv_caches`` builds the
+        arena's leaves from (K and V rows per kv head here; an int8
+        arena adds one float32 scale a head to each)."""
+        return ((self.num_kv_heads, self.head_dim),) * 2
+
+    def kv_needed_elements(self) -> int:
+        """Numbers a token's cache NEEDS in one layer (what is stored
+        may be padded to a lane tile)."""
+        return 2 * self.num_kv_heads * self.head_dim
+
     def _rotate(self, q, k, positions, rope_on):
         """RoPE on q and k where the module has it; ``rope_on`` (a
         traced bool from a layer scan whose layers differ by it, else
@@ -961,6 +973,301 @@ class ParallelAttention(Module):
             new_cache
 
 
+class LatentKVNotSupported(NotImplementedError):
+    """A feature asked for a per-head (K, V) cache of a model whose
+    attention caches ONE latent row a token (:class:`LatentAttention`):
+    the int8 arena (its scales are per head), the dense ``generate``
+    cache, the CP-prefill lane's ``return_kv``."""
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention (MLA, no query compression): what is
+    cached is ONE row a token and layer, ``[c ‖ k_r]`` — the RMS-normed
+    ``kv_rank``-wide compression of the keys and values of all heads
+    and the one RoPE key (``rope_dim`` wide) they share.
+
+    ``q = u W_q`` per head is ``[q_nope (nope_dim) ‖ q_rope]``;
+    ``[c ‖ k_r] = u W_dkv``, ``c <- RMSNorm(c)``, RoPE on ``k_r`` and
+    ``q_rope`` in adjacent pairs; per head ``[k_nope ‖ v] = c W_ukv``;
+    scores ``(q_nope . k_nope + q_rope . k_r) / sqrt(nope_dim +
+    rope_dim)``. The whole-sequence forward computes exactly that (the
+    EXPANDED form, through the reference attention: the flash kernel
+    has one head size). Every cached path computes the ABSORBED form,
+    the same arithmetic reassociated: ``q~_h = [q_nope,h W_uk,h^T ‖
+    q_rope,h]`` scores against the cached rows themselves, ``o~_h =
+    sum p c`` and ``o_h = o~_h W_uv,h`` — one key head that all query
+    heads share, whose first ``kv_rank`` columns are also the value
+    (``ops.paged_pallas``: ``v_width``), so no K or V of a context is
+    ever expanded.
+
+    ``stored_row`` (default ``kv_rank + rope_dim``) is the arena row's
+    width; columns beyond ``kv_rank + rope_dim`` are zeros in the rows
+    and in ``q~`` alike. To the engine this module is an attention of
+    ``num_heads`` query heads over ONE kv head of ``head_dim =
+    stored_row``; :meth:`kv_leaf_shapes` says what its arena holds.
+    """
+
+    def __init__(self, embed_dim: int, num_heads: int, *, kv_rank: int,
+                 nope_dim: int, rope_dim: int, v_dim: int,
+                 stored_row: Optional[int] = None,
+                 rope_theta: float = 10000.0, norm_eps: float = 1e-6,
+                 max_positions: int = 4096, init=None):
+        super().__init__()
+        from hetu_tpu.nn.layers import RMSNorm
+        self.num_heads, self.num_kv_heads = num_heads, 1
+        self.kv_rank, self.nope_dim = kv_rank, nope_dim
+        self.rope_dim, self.v_dim = rope_dim, v_dim
+        self.row = kv_rank + rope_dim
+        self.head_dim = stored_row or self.row
+        if self.head_dim < self.row:
+            raise ValueError(f"stored_row {stored_row} is narrower than "
+                             f"the latent row {self.row}")
+        self.min_window = None
+        self.scale = 1.0 / (nope_dim + rope_dim) ** 0.5
+        init = init or normal_init(0.02)
+        self.q_proj = ColumnParallelLinear(
+            embed_dim, num_heads * (nope_dim + rope_dim), bias=False,
+            init=init, axis="heads", out_kind="hidden")
+        self.kv_down = ColumnParallelLinear(
+            embed_dim, self.row, bias=False, init=init, axis=None,
+            out_kind="hidden")
+        self.kv_norm = RMSNorm(kv_rank, eps=norm_eps)
+        self.kv_up = ColumnParallelLinear(
+            kv_rank, num_heads * (nope_dim + v_dim), bias=False,
+            init=init, axis="heads", out_kind="hidden")
+        self.out_proj = RowParallelLinear(
+            num_heads * v_dim, embed_dim, bias=False, init=init,
+            axis="heads")
+        self._rope = rope_frequencies(rope_dim, max_positions,
+                                      theta=rope_theta)
+
+    def kv_leaf_shapes(self) -> tuple:
+        """The trailing dims of each cache leaf, a token and layer."""
+        return ((1, self.head_dim),)
+
+    def kv_needed_elements(self) -> int:
+        return self.row
+
+    def _rotate(self, x, positions):
+        cos, sin = self._rope
+        return apply_rotary(x, cos, sin, positions=positions,
+                            interleaved=True)
+
+    def _latent_rows(self, params, x, positions):
+        """``[RMSNorm(c) ‖ RoPE(k_r) ‖ zeros]`` of every token: ``(b, s,
+        stored_row)`` in the compute dtype — what the cache stores."""
+        with jax.named_scope("hetu.mla_down"):
+            ckr = self.kv_down(params["kv_down"], x)
+            c = self.kv_norm(params["kv_norm"], ckr[..., :self.kv_rank])
+            kr = self._rotate(ckr[..., None, self.kv_rank:],
+                              positions)[..., 0, :]
+            return self._pad(
+                jnp.concatenate([c, kr.astype(c.dtype)], axis=-1))
+
+    def _queries(self, params, x, positions):
+        """``(q_nope (b, s, H, nope), RoPE(q_rope) (b, s, H, rope))``."""
+        b, s, _ = x.shape
+        q = self.q_proj(params["q_proj"], x).reshape(
+            b, s, self.num_heads, self.nope_dim + self.rope_dim)
+        return q[..., :self.nope_dim], \
+            self._rotate(q[..., self.nope_dim:], positions)
+
+    def _up(self, params, dt):
+        """``(W_uk (rank, H, nope), W_uv (rank, H, v))``."""
+        w = params["kv_up"]["weight"].astype(dt).reshape(
+            self.kv_rank, self.num_heads, self.nope_dim + self.v_dim)
+        return w[..., :self.nope_dim], w[..., self.nope_dim:]
+
+    def _pad(self, x):
+        """Zeros up to the stored row's width."""
+        pad = self.head_dim - self.row
+        return x if not pad else jnp.pad(
+            x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+    def _absorbed_queries(self, params, x, positions):
+        """``q~ (b, s, H, stored_row)``."""
+        q_nope, q_rope = self._queries(params, x, positions)
+        with jax.named_scope("hetu.mla_absorb"):
+            w_uk, _ = self._up(params, q_nope.dtype)
+            q_lat = jnp.einsum("bshn,chn->bshc", q_nope, w_uk,
+                               preferred_element_type=jnp.float32)
+            return self._pad(jnp.concatenate(
+                [q_lat.astype(q_nope.dtype), q_rope], axis=-1))
+
+    def _output(self, params, o_lat):
+        """``o~ (b, s, H, rank)`` -> the attention's result."""
+        b, s = o_lat.shape[:2]
+        dt = self.compute_dtype()
+        with jax.named_scope("hetu.mla_absorb"):
+            _, w_uv = self._up(params, dt)
+            o = jnp.einsum("bshc,chv->bshv", o_lat.astype(dt), w_uv,
+                           preferred_element_type=jnp.float32).astype(dt)
+        return self.out_proj(params["out_proj"],
+                             o.reshape(b, s, self.num_heads * self.v_dim))
+
+    def __call__(self, params, x, *, positions=None, segment_ids=None,
+                 attn_impl: str = "auto", kv_cache=None, slot_mask=None,
+                 block_tables=None, row_mask=None, attn_kernel="reference",
+                 pack=None, return_kv: bool = False):
+        del attn_impl          # one head size in the flash kernel
+        if kv_cache is not None:
+            if pack is not None:
+                return self._decode_packed(
+                    params, x, kv_cache, positions=positions,
+                    block_tables=block_tables, pack=pack,
+                    attn_kernel=attn_kernel)
+            return self._decode(params, x, kv_cache, positions=positions,
+                                slot_mask=slot_mask,
+                                block_tables=block_tables,
+                                row_mask=row_mask, attn_kernel=attn_kernel)
+        if return_kv:
+            raise LatentKVNotSupported(
+                "return_kv (the CP-prefill lane) wants per-head (k, v); "
+                "a latent attention caches one row a token")
+        b, s, _ = x.shape
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        q_nope, q_rope = self._queries(params, x, positions)
+        rows = self._latent_rows(params, x, positions)
+        with jax.named_scope("hetu.mla_expand"):
+            kv = self.kv_up(params["kv_up"], rows[..., :self.kv_rank]) \
+                .reshape(b, s, self.num_heads, self.nope_dim + self.v_dim)
+            k_rope = jnp.broadcast_to(
+                rows[..., None, self.kv_rank:self.row],
+                (b, s, self.num_heads, self.rope_dim))
+            k = jnp.concatenate([kv[..., :self.nope_dim], k_rope], -1)
+            v = kv[..., self.nope_dim:]
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        out = attention_reference(q, k, v, causal=True,
+                                  segment_ids=segment_ids,
+                                  scale=self.scale)
+        return self.out_proj(
+            params["out_proj"],
+            out.reshape(b, s, self.num_heads * self.v_dim))
+
+    def _write(self, buf, layer, rows, new):
+        with jax.named_scope("hetu.kv_arena"):
+            return _scatter_layer_rows(buf, layer, rows, new)
+
+    def _decode(self, params, x, kv_cache, *, positions, slot_mask,
+                block_tables, row_mask, attn_kernel):
+        """The decode and verify rows (``ParallelAttention._decode``'s
+        per-row paged mode): every slot writes its rows' latent rows at
+        ``[layer, page]`` of the ONE stacked leaf and attends, absorbed,
+        the rows its table names — through the paged kernel in place
+        (``attn_kernel="paged"``) or the gather reference."""
+        (buf,), layer = kv_cache
+        if block_tables is None or slot_mask is None:
+            raise LatentKVNotSupported(
+                "latent attention decodes from the paged arena, per "
+                "slot (block_tables= and slot_mask=); it has no dense "
+                "cache")
+        b, s, _ = x.shape
+        n_blk, blk = buf.shape[1], buf.shape[2]
+        index = positions[:, 0]
+        pos_rows = index[:, None] + jnp.arange(s)[None, :]
+        blk_ids = jnp.take_along_axis(block_tables, pos_rows // blk, axis=1)
+        keep = slot_mask[:, None]
+        if row_mask is not None:
+            keep = keep & row_mask
+        at = jnp.where(keep, blk_ids * blk + pos_rows % blk,
+                       n_blk * blk).reshape(-1)
+        buf = self._write(buf, layer, at,
+                          self._latent_rows(params, x, positions))
+        q = self._absorbed_queries(params, x, positions)
+        if attn_kernel == "paged":
+            from hetu_tpu.ops.paged_pallas import paged_attention_auto
+            o = paged_attention_auto(
+                q, buf, None, block_tables, index, layer=layer,
+                live=slot_mask, scale=self.scale, v_width=self.kv_rank)
+        else:
+            from hetu_tpu.ops.paged_pallas import \
+                paged_attention_reference
+            o = paged_attention_reference(
+                q, _at_layer(buf, layer), None, block_tables, index,
+                scale=self.scale, v_width=self.kv_rank)
+        return self._output(params, o), (buf,)
+
+    def _chunk_attention(self, q, rows, seg, block: int = 256):
+        """The in-pack part of the packed prefill lane, absorbed, in
+        XLA (the flash kernel takes one head size): ``q`` ``(C, H,
+        stored_row)`` against the pack's own latent ``rows`` ``(C,
+        stored_row)``, causal by pack index within a segment. Blocks of
+        queries bound the ``(H, block, C)`` scores. Returns ``(C, H,
+        kv_rank)`` and the LSE ``(H, C)``."""
+        C, H, _ = q.shape
+        block = min(block, C)
+        pad = -C % block
+        idx = jnp.arange(C + pad)
+        qb = jnp.pad(q, ((0, pad), (0, 0), (0, 0))).reshape(
+            -1, block, H, q.shape[-1])
+        segq = jnp.pad(seg, (0, pad), constant_values=-1)
+        val = rows[:, :self.kv_rank]
+
+        def one(args):
+            qi, ii, si = args
+            s = jnp.einsum("qhd,kd->hqk", qi, rows,
+                           preferred_element_type=jnp.float32) * self.scale
+            seen = (idx[None, :C] <= ii[:, None]) \
+                & (seg[None, :] == si[:, None])
+            s = jnp.where(seen[None], s, -1e30)
+            m = s.max(-1, keepdims=True)
+            p = jnp.where(seen[None], jnp.exp(s - m), 0.0)
+            l = p.sum(-1, keepdims=True)
+            o = jnp.einsum("hqk,kc->qhc", (p / jnp.where(l == 0, 1.0, l))
+                           .astype(val.dtype), val,
+                           preferred_element_type=jnp.float32)
+            lse = jnp.where(l == 0, -1e30, m + jnp.log(
+                jnp.where(l == 0, 1.0, l)))[..., 0]
+            return o.astype(q.dtype), lse
+
+        o, lse = jax.lax.map(one, (qb, idx.reshape(-1, block),
+                                   segq.reshape(-1, block)))
+        return o.reshape(-1, H, self.kv_rank)[:C], \
+            jnp.moveaxis(lse, 1, 0).reshape(H, -1)[:, :C]
+
+    def _decode_packed(self, params, x, kv_cache, *, positions,
+                       block_tables, pack, attn_kernel):
+        """The packed prefill lane (``ParallelAttention._decode_packed``:
+        an in-pack part and each token's resident history, LSE-combined)
+        in the absorbed form: the pack's rows are written per token, the
+        in-pack part runs in XLA over those rows, and the history is
+        read in place in TILES by the same latent paged call."""
+        (buf,), layer = kv_cache
+        b, C, _ = x.shape
+        n_blk, blk = buf.shape[1], buf.shape[2]
+        pos = positions[0]
+        blk_ids = jnp.take_along_axis(block_tables,
+                                      (pos // blk)[:, None], axis=1)[:, 0]
+        at = jnp.where(pack["valid"], blk_ids * blk + pos % blk,
+                       n_blk * blk)
+        rows = self._latent_rows(params, x, positions)
+        buf = self._write(buf, layer, at, rows[0])
+        q = self._absorbed_queries(params, x, positions)
+        from hetu_tpu.ops.paged_pallas import (
+            combine_attention_lse, paged_attention_reference,
+            paged_history_attention,
+        )
+        intra, lse_i = self._chunk_attention(
+            q[0], rows[0].astype(q.dtype), pack["segment_ids"][0])
+        if attn_kernel == "paged":
+            tiles = pack["tiles"]
+            hist, lse_h = paged_history_attention(
+                q[0], buf, None, tiles["tables"], pack["hist"],
+                tiles["map"], tile_rows=tiles["rows"], layer=layer,
+                scale=self.scale, v_width=self.kv_rank)
+            lse_h = lse_h.T
+        else:
+            hist, lse_h = paged_attention_reference(
+                q[0][:, None], _at_layer(buf, layer), None, block_tables,
+                pack["hist"].astype(jnp.int32) - 1, scale=self.scale,
+                v_width=self.kv_rank, return_lse=True)
+            hist, lse_h = hist[:, 0], lse_h[:, :, 0].T
+        o = combine_attention_lse(intra[None], lse_i[None], hist[None],
+                                  lse_h[None])
+        return self._output(params, o), (buf,)
+
+
 def remat_policy(name: str):
     """Map a Strategy remat/offload name to a ``jax.checkpoint`` policy.
 
@@ -1003,6 +1310,9 @@ class StackedBlocks(Module):
     axis available to the pipeline executor (axis rule ``"layers" → "pp"``)
     and ``jax.checkpoint`` applied per block for recompute parity.
 
+    ``first_layer`` (0 on every model whose layers are all this block):
+    the cache layer :meth:`decode` gives its first layer.
+
     ``layer_data`` (``{name: one value per layer}``, ``None`` on every
     model whose layers are alike) is how layers of ONE scanned block
     differ: each array rides the scan as xs and layer ``l``'s block is
@@ -1012,9 +1322,13 @@ class StackedBlocks(Module):
     """
 
     def __init__(self, make_block: Callable[[], Module], num_layers: int,
-                 layer_data: Optional[dict] = None):
+                 layer_data: Optional[dict] = None, first_layer: int = 0):
         super().__init__()
         self.num_layers = num_layers
+        #: where this stack's layer 0 sits in the caches it decodes
+        #: into (a model whose leading layers are another block keeps
+        #: them outside the scan, in the same stacked arena)
+        self.first_layer = int(first_layer)
         self._block = make_block()  # underscore: excluded from children()
         if layer_data is not None:
             layer_data = {k: jnp.asarray(v) for k, v in layer_data.items()}
@@ -1314,6 +1628,9 @@ class StackedBlocks(Module):
         executed lane's to ``emit`` on the host."""
         xs = {"p": params,
               "layer": jnp.arange(self.num_layers, dtype=jnp.int32)}
+        if self.first_layer:
+            # the caches' layer; ``unsliced`` leaves are this stack's
+            xs["cache_layer"] = xs["layer"] + self.first_layer
         lora_ids = None
         if w8a8_mask is not None:
             xs["w8a8"] = jnp.asarray(w8a8_mask, bool)
@@ -1342,8 +1659,9 @@ class StackedBlocks(Module):
                 layer_params = _set_path(
                     layer_params, path, StackedLeaf(stack, inputs["layer"]))
             h, caches, *stats = self._block(
-                layer_params, h,
-                kv_cache=LayerKV(caches, inputs["layer"]), **kw)
+                layer_params, h, kv_cache=LayerKV(
+                    caches, inputs.get("cache_layer", inputs["layer"])),
+                **kw)
             return (h, caches), (stats[0] if stats else None)
 
         (x, caches), stats = jax.lax.scan(body, (x, tuple(caches)), xs)

@@ -121,7 +121,7 @@ def table_chunks(table_width: int, block_size: int,
 
 
 def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
-                  quant, windowed, tiled=False):
+                  quant, windowed, tiled=False, v_width=None):
     """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
     ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
     page dims are squeezed out of the block — every kv head: a TPU
@@ -137,7 +137,9 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     TILE of a prefill pack — four more scalar operands hold its key
     cap, the q cell it reads (the index maps' business) and the cell's
     rows ``[lo, hi)`` that are its own; the grid is (tiles, chunks)
-    and its bounds are data."""
+    and its bounds are data. ``v_width`` (a latent arena): there are
+    no value pages — a key row's first ``v_width`` columns are its
+    value, taken from the key page the step already holds."""
     del lyr_ref                     # read by the page index maps only
     win_ref = None
     if windowed:
@@ -171,8 +173,11 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     # static ref layout: L k pages, L v pages, [L k scales, L v scales],
     # then outputs (o, lse) and scratch (m, l, acc)
     k_pages = refs[:L]
-    v_pages = refs[L:2 * L]
-    idx = 2 * L
+    if v_width is None:
+        v_pages = refs[L:2 * L]
+        idx = 2 * L
+    else:
+        v_pages, idx = k_pages, L
     if quant:
         ks_pages = refs[idx:idx + L]
         vs_pages = refs[idx + L:idx + 2 * L]
@@ -236,6 +241,9 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
                         * ks_pages[j][:, h:h + 1]
                     v = v_pages[j][:, head].astype(jnp.float32) \
                         * vs_pages[j][:, h:h + 1]
+                elif v_width is not None:
+                    k = k_pages[j][:, head]      # (bs, d), fetched once
+                    v = k[:, :v_width]
                 else:
                     k = k_pages[j][:, head]      # (bs, d)
                     v = v_pages[j][:, head]
@@ -347,7 +355,8 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                            pages_per_step: Optional[int] = None,
                            interpret: Optional[bool] = None,
                            return_lse: bool = False, window=None,
-                           live=None, tiles=None):
+                           live=None, tiles=None,
+                           v_width: Optional[int] = None):
     """Decode attention through per-slot block tables, in-kernel.
 
     - ``q``: ``(S, R, hq, d)`` — S slots × R rows (1 for classic decode,
@@ -401,6 +410,13 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
       rows only (tiles of one cell are adjacent: the block stays put
       between them); rows no tile owns are never written, the caller
       masks them.
+    - ``v_width`` (``None`` = none; a static choice): a LATENT arena —
+      ``v`` is ``None`` and a key row's first ``v_width`` columns are
+      also its value (MLA: ONE key head ``[c ‖ k_rope ‖ pad]`` that all
+      query heads share, ``q`` in the absorbed form of the same width).
+      A page is fetched once; the result is ``v_width`` wide. ``scale``
+      must be given: the row's width says nothing about it. No int8
+      form.
 
     Returns ``(S, R, hq, d)`` in q's dtype (plus the fp32
     ``(S, R*… )``-shaped LSE ``(S, hq, R)`` when ``return_lse`` — the
@@ -408,6 +424,16 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     :func:`paged_attention_reference` up to fp associativity.
     """
     S, R, hq, d = q.shape
+    latent = v_width is not None
+    if latent:
+        if v is not None or k_scale is not None or scale is None \
+                or not 0 < v_width <= d:
+            raise ValueError(
+                "v_width= is the latent arena's call: v=None (the value "
+                "is a prefix of the key row), no int8 scales, scale= "
+                f"given, 0 < v_width <= {d}; got v_width={v_width}")
+        v = k
+    dv = v_width if latent else d
     if (layer is None) != (k.ndim == 3) or k.ndim != v.ndim:
         raise ValueError(
             f"layer= goes with stacked (layers, n_blocks, block_size, "
@@ -508,14 +534,15 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
 
     in_specs = [pl.BlockSpec((1, hkv, rows, d), whole)]
     args = [qh]
-    for x in (k, v) + ((k_scale, v_scale) if quant else ()):
+    for x in ((k,) if latent else (k, v)) \
+            + ((k_scale, v_scale) if quant else ()):
         in_specs += [page_spec(j, x) for j in range(L)]
         args += [_stacked(x)] * L
 
-    out_specs = [pl.BlockSpec((1, hkv, rows, d), whole),
+    out_specs = [pl.BlockSpec((1, hkv, rows, dv), whole),
                  pl.BlockSpec((1, hkv, rows, NUM_LANES), whole)]
     out_shape = [
-        jax.ShapeDtypeStruct((S, hkv, rows, d), q.dtype),
+        jax.ShapeDtypeStruct((S, hkv, rows, dv), q.dtype),
         jax.ShapeDtypeStruct((S, hkv, rows, NUM_LANES), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -526,14 +553,14 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         scratch_shapes=[
             pltpu.VMEM((hkv, rows, NUM_LANES), jnp.float32),
             pltpu.VMEM((hkv, rows, NUM_LANES), jnp.float32),
-            pltpu.VMEM((hkv, rows, d), jnp.float32),
+            pltpu.VMEM((hkv, rows, dv), jnp.float32),
         ],
     )
     with jax.named_scope("hetu.paged_attn"):
         out, lse_l = pl.pallas_call(
             functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
                               hkv=hkv, quant=quant, windowed=windowed,
-                              tiled=tiled),
+                              tiled=tiled, v_width=v_width),
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
@@ -545,8 +572,8 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         )(*scalars, *args)
 
     # (S, hkv, R*g, d) → (S, R, hq, d)
-    out = out.reshape(S, hkv, R, g, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(S, R, hq, d)
+    out = out.reshape(S, hkv, R, g, dv).transpose(0, 2, 1, 3, 4) \
+        .reshape(S, R, hq, dv)
     if live is not None:
         # a dead slot's blocks were never written: nobody's rows are
         # zeros, with the empty part's LSE
@@ -568,7 +595,8 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
                          pages_per_step: Optional[int] = None,
                          interpret: Optional[bool] = None,
                          return_lse: bool = False, window=None,
-                         live=None, tiles=None):
+                         live=None, tiles=None,
+                         v_width: Optional[int] = None):
     """:func:`paged_attention_pallas`, tp-aware.
 
     Mosaic kernels cannot be GSPMD-auto-partitioned, so under a
@@ -592,7 +620,7 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
             q, k, v, tbl, off, layer=layer, k_scale=ks, v_scale=vs,
             scale=scale, pages_per_step=pages_per_step,
             interpret=interpret, return_lse=return_lse, window=window,
-            live=live, tiles=tiles)
+            live=live, tiles=tiles, v_width=v_width)
 
     ctx = current_act_sharding()
     head_ax = ctx.tp if ctx is not None and isinstance(ctx.tp, str) \
@@ -604,10 +632,11 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
         # trace ever reaches here; keep the plain call as the safe twin
         return call(q, k, v, block_tables, q_offset, layer, k_scale,
                     v_scale)
-    if window is not None:
+    if window is not None or v_width is not None:
         raise NotImplementedError(
-            "a windowed paged call under a tp-sharded plan: the window "
-            "would have to ride the shard_map as an operand")
+            "a windowed or latent paged call under a tp-sharded plan: "
+            "the window would have to ride the shard_map as an operand, "
+            "and a latent row has one key head to split")
 
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -734,7 +763,8 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
                             tile_rows: int, layer=None, k_scale=None,
                             v_scale=None, window=None,
                             scale: Optional[float] = None,
-                            interpret: Optional[bool] = None):
+                            interpret: Optional[bool] = None,
+                            v_width: Optional[int] = None):
     """Each pack token's attention over its request's RESIDENT history
     (arena positions ``< hist[t]``: earlier chunks, prefix-cache hits),
     one pass over a request's pages per TILE of its chunk.
@@ -747,6 +777,9 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
       The pack is handed to :func:`paged_attention_pallas` in cells of
       ``tile_rows`` rows, in place — no row is gathered, nothing is
       padded to the tile count.
+
+    ``v_width``: the latent arena's call (``v`` is ``None``; the result
+    is ``v_width`` wide; :func:`paged_attention_pallas`).
 
     Returns ``(C, hq, d)`` and the fp32 LSE ``(C, hq)``; a token
     without history gets the empty part (0, ``NEG_INF``), which
@@ -767,10 +800,10 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
         # time is the same at 1 / 2 / 4 / 8 on the chip, its trace and
         # compile are not: PERF.md, PR 27)
         pages_per_step=1, interpret=interpret, return_lse=True,
-        window=window,
+        window=window, v_width=v_width,
         tiles={n: tiles[n] for n in ("cap", "cell", "lo", "hi")})
     live = hist > 0
-    out = out.reshape(-1, hq, d)[:C]
+    out = out.reshape(-1, hq, out.shape[-1])[:C]
     lse = jnp.moveaxis(lse, 1, 2).reshape(-1, hq)[:C]
     return (jnp.where(live[:, None, None], out, 0),
             jnp.where(live[:, None], lse, NEG_INF))
@@ -780,7 +813,8 @@ def paged_attention_reference(q, k, v, block_tables, q_offset, *,
                               k_scale=None, v_scale=None,
                               scale: Optional[float] = None,
                               causal: bool = True,
-                              return_lse: bool = False, window=None):
+                              return_lse: bool = False, window=None,
+                              v_width: Optional[int] = None):
     """The XLA-gather twin (and parity oracle): materialize each slot's
     table view with :func:`~hetu_tpu.ops.attention.gather_block_rows`
     and run the dense reference — exactly what ``ParallelAttention.
@@ -800,7 +834,11 @@ def paged_attention_reference(q, k, v, block_tables, q_offset, *,
         return x.reshape(x.shape[:2] + (-1, w))
 
     d = q.shape[-1]
-    if k_scale is not None:
+    if v_width is not None:
+        # the latent arena: one gather, the value a prefix of the key
+        k_buf = rows(k, d)
+        v_buf = k_buf[..., :v_width]
+    elif k_scale is not None:
         k_buf = dequantize_int8(rows(k, d), rows(k_scale, 1), q.dtype)
         v_buf = dequantize_int8(rows(v, d), rows(v_scale, 1), q.dtype)
     else:

@@ -235,6 +235,13 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
         adapter_pages=reg.gauge(
             "adapter_pages_in_use",
             "adapter arena pages holding a resident adapter"),
+        kv_row=reg.gauge(
+            "kv_row_bytes",
+            "bytes one token holds in one layer of the KV arena, by "
+            "kind: stored = the arena leaves' rows as allocated, needed "
+            "= what the model's attention has to keep (K and V of every "
+            "kv head; ONE latent row under MLA — a row padded to a lane "
+            "tile stores more than it needs)"),
         window_dead=reg.gauge(
             "kv_window_dead_blocks",
             "blocks held by decoding slots that lie wholly below the "
@@ -314,6 +321,22 @@ class ServingEngine:
         # The lane's prompt lengths snap to a small geometric bucket
         # ladder so its executable count is bounded (the
         # record_trace("serving_cp_prefill") audit: <= n lane buckets).
+        if len(model.blocks.block.attn.kv_leaf_shapes()) != 2:
+            # a latent arena (one leaf a token): what needs per-head
+            # (K, V) refuses here, by name — the int8 arena does in
+            # generation.init_kv_caches
+            from hetu_tpu.nn.parallel import LatentKVNotSupported
+            for what, on in (("long_max_len (the CP-prefill lane)",
+                              long_max_len is not None),
+                             ("draft_model", draft_model is not None),
+                             ("w8a8", w8a8 not in (None, False, "off")),
+                             ("tenancy (LoRA)", bool(tenancy)),
+                             ("a tp plan", plan is not None
+                              and plan.strategy.tp > 1)):
+                if on:
+                    raise LatentKVNotSupported(
+                        f"{what} is not available over a latent KV "
+                        f"arena")
         self._cp = plan.strategy.cp if plan is not None else 1
         self._cp_zigzag = (
             plan is not None and self._cp > 1
@@ -665,6 +688,13 @@ class ServingEngine:
         self._w8a8_wq = self._prequantize_decode_weights()
 
         self._m = _bind_metrics(telemetry.get_registry())
+        stored = sum(int(np.prod(c.shape[3:])) * c.dtype.itemsize
+                     for c in self.pool.caches)
+        self._m.kv_row.set(stored, kind="stored")
+        self._m.kv_row.set(
+            stored if self.pool.quantized
+            else _attn_mod.kv_needed_elements()
+            * self.pool.caches[0].dtype.itemsize, kind="needed")
         # the smallest window of the model's layers (None: no layer has
         # one) — for the kv_window_dead_blocks gauge only
         ld = getattr(model.blocks, "layer_data", None) or {}

@@ -25,7 +25,10 @@ The vocabulary (all start ``hetu.``; ``docs/OBSERVABILITY.md``):
 ``hetu.sample``       sampling: logits adjustment, draws, verify
 ``hetu.moe_route``    expert-share MoE: router, top-k, sort, row gather
 ``hetu.moe_experts``  expert-share MoE: grouped matmuls, weighting, unsort
-``hetu.moe_shared``   the averaged shared experts' gated MLP
+``hetu.moe_shared``   the shared experts' gated MLP (averaged or summed)
+``hetu.mla_down``     latent attention: down-projection, latent norm, RoPE key
+``hetu.mla_absorb``   latent attention: q through W_uk, results through W_uv
+``hetu.mla_expand``   latent attention: per-head K and V from the latent rows
 ====================  ================================================
 
 The rule (:func:`classify`): an instruction belongs to the INNERMOST
@@ -63,6 +66,7 @@ VOCABULARY = (
     "hetu.paged_attn", "hetu.fused_ce", "hetu.prefill_lane",
     "hetu.decode_lane", "hetu.kv_arena", "hetu.sample",
     "hetu.moe_route", "hetu.moe_experts", "hetu.moe_shared",
+    "hetu.mla_down", "hetu.mla_absorb", "hetu.mla_expand",
 )
 
 #: ``op_name``s the TPU compiler gives an op it made from a program's

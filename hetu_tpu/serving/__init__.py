@@ -35,7 +35,7 @@ cache with radix-tree prefix sharing.
 the fleet state machines.
 """
 
-from hetu_tpu.serving.engine import ServingEngine, sample_slots
+from hetu_tpu.serving.engine import ServingEngine
 from hetu_tpu.serving.fleet import (
     RemoteEngineProxy, RemoteReplicaHandle, RemoteRequest,
     spill_from_wire, spill_to_wire,
@@ -57,7 +57,7 @@ from hetu_tpu.serving.speculative import (
 )
 
 __all__ = [
-    "ServingEngine", "sample_slots",
+    "ServingEngine",
     "KVPool", "BlockManager", "NULL_BLOCK", "cache_dtype_name",
     "HostSpillArena", "SpillEntry",
     "PrefixCache",

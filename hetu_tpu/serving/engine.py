@@ -103,8 +103,9 @@ from hetu_tpu.serving.kv_pool import (
 from hetu_tpu.serving.prefix_cache import PrefixCache
 from hetu_tpu.serving.scheduler import Request, SamplingParams, Scheduler
 from hetu_tpu.serving.speculative import (
-    ModelDraftsman, NgramDraftsman, adjust_logits, check_draft_depth,
-    check_sampled_draft, speculative_verify,
+    ModelDraftsman, NgramDraftsman, check_draft_depth,
+    check_sampled_draft, sample_needs, sample_path, sample_rows,
+    verify_slots,
 )
 from hetu_tpu.serving.tenancy import AdapterArenaFull
 from hetu_tpu.telemetry.flight import HangWatchdog, flight_record
@@ -166,6 +167,14 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "an active slot's chunk at or below its last row; skipped "
             "= the rest of slots x chunks: freed and prefilling slots, "
             "chunks above a context — no grid step)"),
+        sample_path=reg.counter(
+            "serving_sample_path_total",
+            "sampler executions of the fused step by lane (decode, "
+            "prefill) and by the path its LIVE rows' knobs selected, "
+            "once per iteration the lane runs (greedy = argmax alone: "
+            "no live row has a temperature; draw = scaled logits, "
+            "softmax and categorical draws; sort = draw plus the "
+            "top-k / top-p sort, because some live sampling row masks)"),
         draft=reg.counter(
             "serving_draft_tokens_total",
             "draft tokens proposed to the verify lane"),
@@ -248,23 +257,6 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "attention window: no query of a window layer can see them, "
             "and the uniform arena holds their rows for every layer"),
     )
-
-
-def sample_slots(logits, temperature, top_k, top_p, rng):
-    """Per-slot sampling with TRACED knobs: (S, V) logits + (S,) params
-    → (S,) int32 tokens. Mirrors ``generation._sample`` semantics
-    (greedy at temperature 0, top-k keeps values >= the kth, nucleus
-    keeps the smallest prefix whose prior mass < top_p) but every knob
-    is data, not Python — one compile covers every request mix. The
-    masking arithmetic lives in ``speculative.adjust_logits`` so the
-    rejection-sampling verify lane's target distribution p is bitwise
-    THIS sampler's."""
-    S = logits.shape[0]
-    greedy = jnp.argmax(logits, axis=-1)
-    masked = adjust_logits(logits, temperature, top_k, top_p)
-    drawn = jax.vmap(jax.random.categorical)(
-        jax.random.split(rng, S), masked)
-    return jnp.where(temperature == 0.0, greedy, drawn).astype(jnp.int32)
 
 
 class ServingEngine:
@@ -879,21 +871,19 @@ class ServingEngine:
                     with_stats=True)
                 # proposal probs q: host draftsmen propose
                 # deterministically — their q is the one-hot of the
-                # draft, synthesized here so the host never ships a
-                # (S, K, V) table; a device draftsman's sampled
-                # softmax rows ride in through spec["q"]
-                V = logits.shape[-1]
-                if host_q:
-                    qprobs = jax.nn.one_hot(spec["tok"], V,
-                                            dtype=jnp.float32)
-                else:
-                    qprobs = spec["q"].astype(jnp.float32)
+                # draft, synthesized on the device (q=None) so the host
+                # never ships a (S, K, V) table; a device draftsman's
+                # sampled softmax rows ride in through spec["q"]. The
+                # verify does what the ACTIVE slots' knobs need and no
+                # more (a freed slot keeps its last request's): the
+                # scope goes around the call, its conds' branches
+                # inherit it
                 with jax.named_scope("hetu.sample"):
-                    committed, ncommit, last_tok, new_kd = jax.vmap(
-                        speculative_verify)(
-                        logits, spec["tok"], spec["len"], qprobs,
+                    committed, ncommit, last_tok, new_kd = verify_slots(
+                        logits, spec["tok"], spec["len"],
+                        None if host_q else spec["q"],
                         ctl["temp"], ctl["topk"], ctl["topp"],
-                        ctl["key"])
+                        ctl["key"], live=ctl["active"])
                 # inactive slots must not burn PRNG state — their
                 # sampling stream has to match one-shot generate
                 new_kd = jnp.where(ctl["active"][:, None],
@@ -983,24 +973,16 @@ class ServingEngine:
                 # first-token sampling mirrors generate's prefill
                 # exactly: split the slot's key once, draw with the
                 # sub — so an identical-seed request's whole sampling
-                # stream is bitwise the one-shot generate stream
-                def sample_row(lg_row, temp, tk, tp, kdr):
-                    k = jax.random.wrap_key_data(kdr)
-                    k, sub = jax.random.split(k)
-                    masked = adjust_logits(lg_row, temp, tk, tp)
-                    drawn = jax.random.categorical(sub, masked)
-                    tok = jnp.where(temp == 0.0,
-                                    jnp.argmax(lg_row, axis=-1),
-                                    drawn)
-                    return (tok.astype(jnp.int32),
-                            jax.random.key_data(k))
-
+                # stream is bitwise the one-shot generate stream. Only
+                # the rows that really finish count towards what the
+                # sampler has to do
                 with jax.named_scope("hetu.sample"):
-                    firsts, pf_kd = jax.vmap(sample_row)(
+                    firsts, pf_kd = sample_rows(
                         lg, jnp.take(ctl["temp"], fs),
                         jnp.take(ctl["topk"], fs),
                         jnp.take(ctl["topp"], fs),
-                        jnp.take(ctl["key"], fs, axis=0))
+                        jnp.take(ctl["key"], fs, axis=0),
+                        live=pf["fin_valid"])
                 return caches, firsts, pf_kd, stats
 
             def no_prefill(caches):
@@ -1114,14 +1096,9 @@ class ServingEngine:
                             w.astype(jnp.float32))[:, 0]
             # per-request key chain, same as the packed lane: split
             # once, draw with the sub, return the advanced state
-            k = jax.random.wrap_key_data(key)
-            k, sub = jax.random.split(k)
-            masked = adjust_logits(lg[0], temp[0], topk[0], topp[0])
-            drawn = jax.random.categorical(sub, masked)
-            tok = jnp.where(temp[0] == 0.0,
-                            jnp.argmax(lg[0], axis=-1), drawn)
-            return caches, tok.astype(jnp.int32), \
-                jax.random.key_data(k)
+            tok, kd = sample_rows(lg, temp, topk, topp, key[None],
+                                  live=jnp.ones((1,), bool))
+            return caches, tok[0], kd[0]
 
         return jax.jit(cp_prefill, donate_argnums=(1,),
                        out_shardings=(self._arena_sh, None, None))
@@ -2425,15 +2402,23 @@ class ServingEngine:
                     self._rep)
                 self._ctl_dirty = False
             ctl = self._ctl_dev
-            if self._chunk_steps and self._active.any():
-                # an active slot's K + 1 rows end in chunk (pos + K) //
-                # span: its pairs are that chunk and those below
-                live = int(np.minimum(
-                    (self._pos[self._active] + K) // self._chunk_span + 1,
-                    self._chunk_steps).sum())
-                m.decode_chunks.inc(live, state="live")
-                m.decode_chunks.inc(S * self._chunk_steps - live,
-                                    state="skipped")
+            if self._active.any():
+                # the decode lane's sampler: the step's own predicate
+                # (speculative.sample_needs), on the uploaded vectors
+                m.sample_path.inc(lane="decode", path=sample_path(
+                    *sample_needs(self._active, self._temp,
+                                  self._topk, self._topp)))
+                if self._chunk_steps:
+                    # an active slot's K + 1 rows end in chunk (pos +
+                    # K) // span: its pairs are that chunk and those
+                    # below
+                    live = int(np.minimum(
+                        (self._pos[self._active] + K)
+                        // self._chunk_span + 1,
+                        self._chunk_steps).sum())
+                    m.decode_chunks.inc(live, state="live")
+                    m.decode_chunks.inc(S * self._chunk_steps - live,
+                                        state="skipped")
             # pack the prefill budget FCFS over in-flight prefills: the
             # oldest request fills first (so a lone request's chunk
             # count matches the PR 5 single-admission engine), the rest
@@ -2476,6 +2461,12 @@ class ServingEngine:
                     fin_ents.append(ent)
                 fills.append((ent, n))
                 used += n
+            if used:
+                # the prefill lane's sampler sees the finishing rows
+                m.sample_path.inc(lane="prefill", path=sample_path(
+                    *sample_needs(fin_valid, self._temp[fin_slot],
+                                  self._topk[fin_slot],
+                                  self._topp[fin_slot])))
             pf = {"run": np.bool_(used > 0), "tokens": tokens,
                   "pos": tpos, "slot": tslot, "valid": tvalid,
                   "seg": tseg, "hist": thist, "fin_row": fin_row,

@@ -54,57 +54,153 @@ from typing import Optional, Sequence
 import numpy as np
 
 
-def adjust_logits(logits, temperature, top_k, top_p):
+def sample_needs(live, temperature, top_k, top_p):
+    """What the LIVE rows' knobs ask of the sampler, reduced over the
+    batch: ``(draws, sorts)`` scalars. ``draws``: some live row is not
+    greedy (the complement of the ``temperature == 0.0`` its token is
+    selected on); ``sorts``: some such row masks by top-k or top-p.
+    Rows that are not live do not count — a freed slot keeps its last
+    request's knobs.
+
+    One expression for both sides: the fused step gates the sampler's
+    work on it from device arrays, the engine's loop counts
+    ``serving_sample_path_total`` with it from its numpy mirrors."""
+    hot = live & (temperature != 0)
+    masks = (top_k > 0) | ((top_p > 0) & (top_p < 1))
+    return hot.any(), (hot & masks).any()
+
+
+def sample_path(draws, sorts) -> str:
+    """The branch :func:`sample_needs`' answer selects, by name."""
+    return "sort" if sorts else "draw" if draws else "greedy"
+
+
+def adjust_logits(logits, temperature, top_k, top_p, sorts=None):
     """Apply the serving sampler's temperature/top-k/top-p masking to
     ``logits`` (..., V) and return the masked, scaled logits.
 
     This is the single source of truth for BOTH the fused verify lane's
     target distribution p and the draft models' proposal distribution q
-    — bitwise identical arithmetic to the engine's ``sample_slots`` (and
-    value-identical to ``generation._sample``), so a sampled serving
+    — value-identical to ``generation._sample``, so a sampled serving
     token drawn from these logits matches the one-shot reference.
 
     ``temperature``/``top_k``/``top_p`` are traced scalars or arrays
     broadcastable against the leading dims of ``logits`` — knob churn
-    is DATA, never a recompile."""
+    is DATA, never a recompile.
+
+    ``sorts`` (a traced scalar bool, :func:`sample_needs`) says whether
+    ANY row that counts masks at all: where none does, the sort, the
+    sorted softmax and the cumulative sum are not run, the thresholds
+    read -inf and the result is the scaled logits — to the bit what
+    the masks leave of a row without top-k and top-p. It has to be a
+    scalar of the whole batch, taken OUTSIDE any ``vmap`` — a per-row
+    predicate under ``vmap`` turns the ``cond`` into a select that runs
+    both sides. ``None``: always mask."""
     import jax
     import jax.numpy as jnp
 
-    V = logits.shape[-1]
     temperature = jnp.asarray(temperature, jnp.float32)
     top_k = jnp.asarray(top_k, jnp.int32)
     top_p = jnp.asarray(top_p, jnp.float32)
     t = jnp.where(temperature > 0, temperature, 1.0)
     scaled = logits / t[..., None].astype(logits.dtype)
-    sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-    kth = jnp.take_along_axis(
-        sorted_desc,
-        jnp.broadcast_to(jnp.clip(top_k - 1, 0, V - 1)[..., None],
-                         scaled.shape[:-1] + (1,)),
-        axis=-1)
+
+    def thresholds():
+        """Per row, the k-th largest value and the nucleus' smallest:
+        all that the masks below need of the sorted table."""
+        V = scaled.shape[-1]
+        sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
+        kth = jnp.take_along_axis(
+            sorted_desc,
+            jnp.broadcast_to(jnp.clip(top_k - 1, 0, V - 1)[..., None],
+                             scaled.shape[:-1] + (1,)),
+            axis=-1)
+        sd = jnp.where((top_k <= 0)[..., None] | (sorted_desc >= kth),
+                       sorted_desc, -jnp.inf)
+        probs = jax.nn.softmax(sd, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = cum - probs < top_p[..., None]
+        cutoff = jnp.min(jnp.where(keep, sd, jnp.inf), axis=-1,
+                         keepdims=True)
+        return kth, cutoff
+
+    def keep_all():
+        low = jnp.full(scaled.shape[:-1] + (1,), -jnp.inf, scaled.dtype)
+        return low, low
+
+    # the cond hands back two numbers a row, never a table: the masks
+    # fuse into whatever reads them, as they do without the gate (the
+    # barrier keeps the compiler from moving them INTO the branches,
+    # which made the cond return two tables)
+    kth, cutoff = thresholds() if sorts is None \
+        else jax.lax.optimization_barrier(
+            jax.lax.cond(sorts, thresholds, keep_all))
     keep_k = (top_k <= 0)[..., None] | (scaled >= kth)
     masked = jnp.where(keep_k, scaled, -jnp.inf)
-    sd = jnp.where((top_k <= 0)[..., None] | (sorted_desc >= kth),
-                   sorted_desc, -jnp.inf)
-    probs = jax.nn.softmax(sd, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = cum - probs < top_p[..., None]
-    cutoff = jnp.min(jnp.where(keep, sd, jnp.inf), axis=-1,
-                     keepdims=True)
     use_p = ((top_p > 0) & (top_p < 1))[..., None]
     return jnp.where(use_p & (masked < cutoff), -jnp.inf, masked)
 
 
+def _split_chain(key_data, n):
+    """``n`` successive splits of one slot's raw key state: ``ks[i]``
+    is the carry after i+1 splits (the new key state if i+1 tokens
+    commit), ``subs[i]`` the subkey that samples token i — both
+    ``(n, KW)`` raw key data."""
+    import jax
+    import jax.numpy as jnp
+
+    carry = jax.random.wrap_key_data(key_data)
+    ks, subs = [], []
+    for _ in range(n):
+        carry, sub = jax.random.split(carry)
+        ks.append(jax.random.key_data(carry))
+        subs.append(jax.random.key_data(sub))
+    return jnp.stack(ks), jnp.stack(subs)
+
+
+def _commit(drafts, a, tok_a, ks):
+    """One slot's results: the ``a`` accepted drafts, then ``tok_a`` at
+    column ``a``; the key state after ``a + 1`` splits."""
+    import jax.numpy as jnp
+
+    cols = jnp.arange(drafts.shape[0] + 1)
+    drafts_pad = jnp.concatenate(
+        [drafts.astype(jnp.int32), jnp.zeros((1,), jnp.int32)])
+    committed = jnp.where(cols < a, drafts_pad, 0)
+    committed = jnp.where(cols == a, tok_a, committed)
+    return (committed.astype(jnp.int32), (a + 1).astype(jnp.int32),
+            tok_a, jnp.take(ks, a, axis=0))
+
+
+def greedy_verify(logits, drafts, depth, key_data):
+    """What :func:`speculative_verify` yields at temperature 0, to the
+    bit, for ONE slot, and nothing else computed: the argmax of the raw
+    rows, drafts accepted by leading match under ``depth``, the key
+    state advanced one split per committed token (a greedy slot burns
+    its stream like a sampled one). No adjusted logits, no softmax, no
+    q, no draw."""
+    import jax.numpy as jnp
+
+    K = drafts.shape[0]
+    ks, _ = _split_chain(key_data, K + 1)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # (K+1,)
+    ok = (drafts == greedy[:K]) & (jnp.arange(K) < depth)
+    a = jnp.sum(jnp.cumprod(ok.astype(jnp.int32)))  # accepted count
+    return _commit(drafts, a, jnp.take(greedy, a), ks)
+
+
 def speculative_verify(logits, drafts, depth, q, temperature, top_k,
-                       top_p, key_data):
+                       top_p, key_data, sorts=None):
     """Rejection-sampling verify for ONE slot — traced, vmapped over
-    the slot axis by the engine's fused step.
+    the slot axis by :func:`verify_slots`.
 
     Inputs: ``logits`` (K+1, V) target rows over the draft window,
     ``drafts`` (K,) proposed tokens, ``depth`` scalar per-slot draft
     length, ``q`` (K, V) proposal probabilities the drafts were sampled
-    from, scalar sampling knobs, and ``key_data`` (KW,) the slot's raw
-    PRNG key state (``jax.random.key_data`` layout).
+    from, scalar sampling knobs, ``key_data`` (KW,) the slot's raw
+    PRNG key state (``jax.random.key_data`` layout), and ``sorts``, the
+    BATCH's scalar for :func:`adjust_logits` (closed over by the
+    ``vmap``, never a row's own).
 
     Per Leviathan et al.: draft i is accepted with probability
     ``min(1, p_i[d_i] / q_i[d_i])`` (evaluated as ``u * q < p`` with an
@@ -112,7 +208,7 @@ def speculative_verify(logits, drafts, depth, q, temperature, top_k,
     from the normalized residual ``max(0, p - q)``; if every draft is
     accepted the bonus token is a fresh sample from the last row. At
     temperature 0 the accept test collapses to ``draft == argmax`` and
-    the emitted values are bitwise the greedy verify lane's.
+    the emitted values are bitwise :func:`greedy_verify`'s.
 
     PRNG discipline mirrors ``generation.generate``: exactly ONE
     ``jax.random.split`` is consumed per COMMITTED token (so a slot
@@ -130,23 +226,12 @@ def speculative_verify(logits, drafts, depth, q, temperature, top_k,
     V = logits.shape[-1]
     temperature = jnp.asarray(temperature, jnp.float32)
 
-    # one split per potentially-committed token: ks[i] is the carry
-    # after i+1 splits (the new key state if i+1 tokens commit),
-    # subs[i] the subkey that samples token i
-    carry = jax.random.wrap_key_data(key_data)
-    ks, subs, accept_u = [], [], []
-    for i in range(K + 1):
-        carry, sub = jax.random.split(carry)
-        ks.append(jax.random.key_data(carry))
-        subs.append(jax.random.key_data(sub))
-        if i < K:
-            accept_u.append(jax.random.uniform(
-                jax.random.fold_in(sub, 0xACC)))
-    ks = jnp.stack(ks)                       # (K+1, KW)
-    subs = jnp.stack(subs)                   # (K+1, KW)
-    u = jnp.stack(accept_u) if K else jnp.zeros((0,), jnp.float32)
+    # one split per potentially-committed token
+    ks, subs = _split_chain(key_data, K + 1)
+    u = jax.vmap(lambda sub: jax.random.uniform(jax.random.fold_in(
+        jax.random.wrap_key_data(sub), 0xACC)))(subs[:K])
 
-    masked = adjust_logits(logits, temperature, top_k, top_p)
+    masked = adjust_logits(logits, temperature, top_k, top_p, sorts)
     p = jax.nn.softmax(masked.astype(jnp.float32), axis=-1)  # (K+1, V)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # (K+1,)
 
@@ -173,15 +258,67 @@ def speculative_verify(logits, drafts, depth, q, temperature, top_k,
     tok_a = jnp.where(
         temperature == 0.0, jnp.take(greedy, a),
         jnp.where(use_resid, drawn_resid, drawn_full)).astype(jnp.int32)
+    return _commit(drafts, a, tok_a, ks)
 
-    cols = jnp.arange(K + 1)
-    drafts_pad = jnp.concatenate(
-        [drafts.astype(jnp.int32), jnp.zeros((1,), jnp.int32)])
-    committed = jnp.where(cols < a, drafts_pad, 0)
-    committed = jnp.where(cols == a, tok_a, committed)
-    new_key_data = jnp.take(ks, a, axis=0)
-    return (committed.astype(jnp.int32), (a + 1).astype(jnp.int32),
-            tok_a, new_key_data)
+
+def verify_slots(logits, drafts, depth, q, temperature, top_k, top_p,
+                 key_data, live):
+    """The fused step's verify over the slot axis, doing only what the
+    LIVE slots' knobs need (:func:`sample_needs`): a batch whose live
+    slots are all greedy takes :func:`greedy_verify`; one sampling live
+    slot and every slot takes :func:`speculative_verify`, the sort
+    inside it only where a live sampling slot masks. The gates are
+    ``cond``s on scalars of the batch, outside the ``vmap``s, so the
+    branch not taken is not run; either way a live slot's results are
+    the ungated ``jax.vmap(speculative_verify)``'s to the bit (a slot
+    that is not live gets SOME branch's: its results are dropped).
+
+    Leading slot axis on everything; ``q`` ``None`` is the one-hot of
+    the drafts (a deterministic proposer), made only where it is read.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    draws, sorts = sample_needs(live, temperature, top_k, top_p)
+
+    def sampled():
+        qp = jax.nn.one_hot(drafts, logits.shape[-1], dtype=jnp.float32) \
+            if q is None else q.astype(jnp.float32)
+        return jax.vmap(
+            lambda *slot: speculative_verify(*slot, sorts=sorts))(
+            logits, drafts, depth, qp, temperature, top_k, top_p,
+            key_data)
+
+    def greedy():
+        return jax.vmap(greedy_verify)(logits, drafts, depth, key_data)
+
+    return jax.lax.cond(draws, sampled, greedy)
+
+
+def sample_rows(logits, temperature, top_k, top_p, key_data, live):
+    """First tokens for ``(R, V)`` rows, mirroring ``generate``'s
+    prefill: split each row's key once, draw with the sub — gated like
+    :func:`verify_slots` on what the live rows need, so greedy rows
+    cost an argmax. Returns ``(tokens (R,) int32, advanced key data
+    (R, KW))``."""
+    import jax
+    import jax.numpy as jnp
+
+    def split(kd):
+        k, sub = jax.random.split(jax.random.wrap_key_data(kd))
+        return jax.random.key_data(k), sub
+
+    new_kd, subs = jax.vmap(split)(key_data)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    draws, sorts = sample_needs(live, temperature, top_k, top_p)
+
+    def drawn():
+        masked = adjust_logits(logits, temperature, top_k, top_p, sorts)
+        toks = jax.vmap(jax.random.categorical)(subs, masked)
+        return jnp.where(temperature == 0.0, greedy,
+                         toks).astype(jnp.int32)
+
+    return jax.lax.cond(draws, drawn, lambda: greedy), new_kd
 
 
 def check_sampled_draft(draftsman) -> None:

@@ -392,6 +392,144 @@ def test_online_submit_during_decode(gpt):
     assert list(r2.tokens) == _ref(model, params, p2, 8)
 
 
+def _sampler_eqns(jaxpr, vocab, conds=()):
+    """``(what, conds)`` for every ``sort`` and every random draw of
+    vocabulary width under ``jaxpr``; ``conds`` are the ``cond``
+    equations it is nested in, outermost first."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        wide = any(vocab in getattr(v.aval, "shape", ())
+                   for v in eqn.outvars)
+        if name == "sort":
+            yield "sort", conds
+        elif wide and name.startswith(("random_", "threefry")):
+            yield "draw", conds
+        inner = conds + (id(eqn),) if name == "cond" else conds
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (tuple, list)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _sampler_eqns(sub, vocab, inner)
+
+
+@pytest.mark.parametrize("spec_depth", [0, 2])
+def test_fused_step_sampler_runs_only_under_its_gates(gpt, spec_depth):
+    """ISSUE 31, structure: in the fused step's program every sort and
+    every random draw of vocabulary width sits inside a ``cond`` branch
+    of its lane — the lane's own cond, then the batch's ``draws`` gate,
+    the sorts under the ``sorts`` gate besides — in BOTH lanes, so a
+    batch of greedy rows runs neither (a gate under ``vmap`` would have
+    become a select and show here as no cond at all). The per-token key
+    splits are small and stay outside."""
+    import dataclasses
+    cfg, _, _ = gpt
+    cfg = dataclasses.replace(cfg, vocab_size=251)   # no other dim's
+    model = GPTLMHeadModel(cfg)
+    params = model.init(jax.random.key(0), dtype=jnp.float32)
+    eng = ServingEngine(model, params, slots=2, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK, spec_depth=spec_depth)
+    step, seen = eng._fn, []
+
+    def spy(*args):
+        seen.append(jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x),
+                                           np.result_type(x)), args))
+        return step(*args)
+
+    eng._fn = spy
+    prompt = _prompts(cfg, [5], seed=4)[0]
+    assert eng.generate_many([prompt], SamplingParams(max_tokens=3)) \
+        == [_ref(model, params, prompt, 3)]
+    found = list(_sampler_eqns(jax.make_jaxpr(step)(*seen[0]).jaxpr,
+                               cfg.vocab_size))
+    lanes = {"sort": set(), "draw": set()}
+    for what, conds in found:
+        assert len(conds) >= (3 if what == "sort" else 2), (
+            f"a {what} of the sampler runs whatever the knobs are "
+            f"({len(conds)} conds around it)")
+        lanes[what].add(conds[0])
+    # one gated sort and gated draws in each of the two lanes
+    assert len(lanes["sort"]) == 2 and lanes["draw"] == lanes["sort"]
+
+
+def test_sample_path_counter_follows_the_live_knobs(gpt):
+    """ISSUE 31: ``serving_sample_path_total{lane, path}`` — counted on
+    the host from the control vectors it uploads, with the step's own
+    predicate — reads ``greedy`` on an all-greedy run, ``sort`` in the
+    decode lane from the iteration a top-p request turns active until
+    it finishes, and ``greedy`` again once its slot is freed, though
+    the slot keeps the request's temperature. The prefill lane reads
+    ``sort`` in the one iteration the top-p prompt finishes, not in the
+    next, whose unused finishing rows point at that (now decoding)
+    slot. Greedy -> top-p -> greedy on a running engine is one trace."""
+    from hetu_tpu.serving.speculative import sample_path
+    cfg, model, params = gpt
+    telemetry.reset()
+    telemetry.enable(True)
+    try:
+        eng = ServingEngine(model, params, slots=3, max_len=MAX_LEN,
+                            prefill_chunk=CHUNK)
+        traces = trace_counts().get("serving_step", 0)
+        paths = telemetry.get_registry().counter(
+            "serving_sample_path_total")
+
+        def counts():
+            return {(lane, path): paths.value(lane=lane, path=path)
+                    for lane in ("decode", "prefill")
+                    for path in ("greedy", "draw", "sort")}
+
+        def stepped():
+            """One iteration; the series it counted, one a lane."""
+            before = counts()
+            eng.step()
+            ran = {k: v - before[k] for k, v in counts().items()
+                   if v != before[k]}
+            assert all(n == 1 for n in ran.values())
+            assert len({lane for lane, _ in ran}) == len(ran)
+            return dict(ran.keys())
+
+        *p_first, p_samp, p_long = _prompts(cfg, [3, 2, 2, 5, 11],
+                                            seed=9)
+        first = [eng.submit(p, SamplingParams(max_tokens=2 + i))
+                 for i, p in enumerate(p_first)]
+        while not first[-1].done.is_set():
+            assert set(stepped().values()) == {"greedy"}
+        # slots come free in the order 0, 1, 2: the top-p request takes
+        # slot 0 and finishes its prompt in the pack's first iteration;
+        # the greedy request's prompt runs on into a second, whose
+        # spare finishing rows name slot 0
+        samp = eng.submit(p_samp, SamplingParams(
+            temperature=0.8, top_p=0.9, max_tokens=4, seed=3))
+        long_ = eng.submit(p_long, SamplingParams(max_tokens=12))
+        assert stepped() == {"prefill": "sort"}
+        assert samp.slot == 0 and eng._active[0] \
+            and not eng._active[long_.slot]
+        assert stepped() == {"prefill": "greedy", "decode": "sort"}
+        while not samp.done.is_set():
+            assert stepped() == {"decode": "sort"}
+        # freed, and its knobs left standing: they no longer count
+        assert not eng._active[0] and eng._temp[0] > 0 \
+            and 0 < eng._topp[0] < 1
+        while not long_.done.is_set():
+            assert stepped() == {"decode": "greedy"}
+        assert long_.tokens == _ref(model, params, p_long, 12)
+        assert [r.tokens for r in first] == [
+            _ref(model, params, p, 2 + i) for i, p in enumerate(p_first)]
+        assert len(samp.tokens) == 4
+        assert paths.value(lane="decode", path="draw") == 0
+        # temperature alone: draws, and sorts nothing
+        eng.generate_many([p_samp], SamplingParams(
+            temperature=0.8, max_tokens=3, seed=3))
+        assert paths.value(lane="decode", path="draw") == 2
+        assert paths.value(lane="prefill", path="draw") == 1
+        assert sample_path(True, False) == "draw"
+        assert trace_counts().get("serving_step", 0) - traces == 1
+        assert eng.step_executables() == 1
+    finally:
+        telemetry.enable(False)
+        telemetry.reset()
+
+
 @pytest.mark.slow
 def test_serving_under_tp2_mesh_matches_single_device(gpt):
     """ACCEPTANCE (degree-2 mesh): TP-sharded serving via the existing

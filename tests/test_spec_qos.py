@@ -234,6 +234,142 @@ def test_adjust_logits_matches_generation_sampler():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+# knob mixes over four rows: (temperature, top_k, top_p, live, the path
+# the LIVE rows select). The last two hold a sampling row that is not
+# live — a freed slot keeping its last request's knobs; a finishing row
+# that is not valid — beside live greedy rows.
+_KNOB_MIXES = {
+    "all_greedy": ([0, 0, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0],
+                   [1, 1, 1, 1], "greedy"),
+    "temperature": ([.7, 1., 1.3, .5], [0, 0, 0, 0], [0, 1., 0, 0],
+                    [1, 1, 1, 1], "draw"),
+    "top_k": ([1., 1., .6, 1.], [5, 3, 0, 7], [0, 0, 0, 0],
+              [1, 1, 1, 1], "sort"),
+    "top_p": ([1., .8, 1., 1.], [0, 0, 0, 0], [.9, .5, 0, .8],
+              [1, 1, 1, 1], "sort"),
+    "top_k_and_top_p": ([1., .8, 1.2, 1.], [5, 0, 3, 9], [.9, .5, 0, 1.],
+                        [1, 1, 1, 1], "sort"),
+    "one_sampling_row": ([0, 0, .8, 0], [0, 0, 4, 0], [0, 0, .9, 0],
+                         [1, 1, 1, 1], "sort"),
+    "stale_freed_slot": ([0, .8, 0, 0], [0, 4, 0, 0], [0, .9, 0, 0],
+                         [1, 0, 1, 1], "greedy"),
+    "stale_unmasked_row": ([1.1, 0, 0, .9], [0, 0, 0, 6], [0, 0, 0, 0],
+                           [0, 1, 1, 0], "greedy"),
+}
+
+
+@pytest.mark.parametrize("depth", [0, 2])
+@pytest.mark.parametrize("mix", sorted(_KNOB_MIXES))
+def test_gated_sampler_is_bitwise_the_ungated(mix, depth):
+    """ISSUE 31: the fused step's sampler does only what the live rows'
+    knobs need, and what it yields for a live row is the ungated
+    arithmetic's to the bit — ``jax.vmap(speculative_verify)`` in the
+    verify lane, a split + ``adjust_logits`` + categorical per row in
+    the prefill lane — tokens, ``ncommit``, ``last_tok`` and key data,
+    for every mix of knobs at verify depth 0 and 2. A row that is not
+    live never selects the sampled path, whatever knobs it kept."""
+    import jax
+    import jax.numpy as jnp
+
+    from hetu_tpu.serving.speculative import (
+        adjust_logits, sample_needs, sample_path, sample_rows,
+        speculative_verify, verify_slots,
+    )
+
+    temp, topk, topp, live, path = _KNOB_MIXES[mix]
+    temp = np.asarray(temp, np.float32)
+    topk = np.asarray(topk, np.int32)
+    topp = np.asarray(topp, np.float32)
+    live = np.asarray(live, bool)
+    # what the engine's loop counts is the step's own predicate
+    assert sample_path(*sample_needs(live, temp, topk, topp)) == path
+    assert sample_path(*jax.jit(sample_needs)(live, temp, topk, topp)) \
+        == path
+
+    S, K, V = 4, depth, 23
+    rng = np.random.default_rng(31)
+    logits = jnp.asarray(rng.normal(0.0, 2.0, (S, K + 1, V)), jnp.float32)
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    # every accept pattern: all drafts match, the first alone, none
+    drafts = rng.integers(0, V, (S, K)).astype(np.int32)
+    drafts[0] = greedy[0, :K]
+    drafts[1, :1] = greedy[1, :1]
+    depths = np.asarray([K, K, min(1, K), 0], np.int32)
+    keys = np.asarray(jax.vmap(lambda s: jax.random.key_data(
+        jax.random.key(s)))(jnp.arange(S)))
+
+    want = jax.jit(jax.vmap(speculative_verify))(
+        logits, drafts, depths, jax.nn.one_hot(drafts, V), temp, topk,
+        topp, keys)
+    got = jax.jit(lambda *a: verify_slots(
+        logits, drafts, depths, None, *a))(temp, topk, topp, keys, live)
+    for w, g in zip(want, got):
+        assert w.dtype == g.dtype and w.shape == g.shape
+        np.testing.assert_array_equal(np.asarray(g)[live],
+                                      np.asarray(w)[live])
+    # a device draftsman's q rows take the same gate
+    if path != "greedy":
+        q = jax.nn.softmax(jnp.asarray(
+            rng.normal(0.0, 1.0, (S, K, V)), jnp.float32))
+        want_q = jax.jit(jax.vmap(speculative_verify))(
+            logits, drafts, depths, q, temp, topk, topp, keys)
+        got_q = jax.jit(verify_slots)(logits, drafts, depths, q, temp,
+                                      topk, topp, keys, live)
+        for w, g in zip(want_q, got_q):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    def sample_row(lg, t, k, p, kd):              # the parent's lane
+        key, sub = jax.random.split(jax.random.wrap_key_data(kd))
+        drawn = jax.random.categorical(sub, adjust_logits(lg, t, k, p))
+        tok = jnp.where(t == 0.0, jnp.argmax(lg, axis=-1), drawn)
+        return tok.astype(jnp.int32), jax.random.key_data(key)
+
+    rows = logits[:, 0]
+    want_f = jax.jit(jax.vmap(sample_row))(rows, temp, topk, topp, keys)
+    got_f = jax.jit(sample_rows)(rows, temp, topk, topp, keys, live)
+    for w, g in zip(want_f, got_f):
+        assert w.dtype == g.dtype and w.shape == g.shape
+        np.testing.assert_array_equal(np.asarray(g)[live],
+                                      np.asarray(w)[live])
+    if path == "greedy":
+        # the branch that ran drew nothing, for the stale rows either
+        np.testing.assert_array_equal(
+            np.asarray(got[2]), greedy[np.arange(S), np.asarray(got[1]) - 1])
+        np.testing.assert_array_equal(np.asarray(got_f[0]), greedy[:, 0])
+
+
+def test_sample_needs_is_one_predicate_on_host_and_device():
+    """SATELLITE: the host counts ``serving_sample_path_total`` with
+    the expression the step gates on — numpy mirrors in, device arrays
+    in, the same two scalars out, over random control vectors with
+    stale knobs on rows that are not live."""
+    import jax
+
+    from hetu_tpu.serving.speculative import sample_needs, sample_path
+
+    rng = np.random.default_rng(5)
+    on_device = jax.jit(sample_needs)
+    seen = set()
+    for _ in range(200):
+        S = int(rng.integers(1, 9))
+        live = rng.random(S) < rng.random()
+        temp = np.where(rng.random(S) < rng.random(),
+                        rng.random(S) * 2, 0).astype(np.float32)
+        topk = np.where(rng.random(S) < 0.3,
+                        rng.integers(1, 50, S), 0).astype(np.int32)
+        topp = rng.choice(np.asarray([0, 0, .5, .9, 1.], np.float32), S)
+        host = sample_needs(live, temp, topk, topp)
+        dev = on_device(live, temp, topk, topp)
+        assert (bool(host[0]), bool(host[1])) \
+            == (bool(dev[0]), bool(dev[1]))
+        hot = live & (temp > 0)
+        assert bool(host[0]) == bool(hot.any())
+        assert bool(host[1]) == bool(
+            (hot & ((topk > 0) | ((topp > 0) & (topp < 1)))).any())
+        seen.add(sample_path(*host))
+    assert seen == {"greedy", "draw", "sort"}
+
+
 # ---------------------------------------------------------------------------
 # host-side: QoS scheduler
 # ---------------------------------------------------------------------------

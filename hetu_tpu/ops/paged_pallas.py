@@ -38,11 +38,20 @@ use:
   running max / denominator / accumulator live in VMEM scratch exactly
   like ``flash_pallas``, begun at a slot's first pair and written out
   at its last;
-- **dead-page skip** inside a chunk: a ``pl.when`` on the
-  scalar-prefetched per-slot position skips the pages of a slot's last
-  chunk that lie beyond its context; with a ``window`` (static ``None``
-  on models that have none) the pages of its first chunk wholly below
-  the window are skipped too, and not fetched;
+- **one tile a (head, chunk)**: inside a grid step the chunk's
+  ``pages_per_step`` pages are ONE key tile per kv head — the head's
+  lane slice of each page block, joined along the key axis in VMEM —
+  so a head costs one ``(rows, pages * block_size)`` score product,
+  one mask, one online-softmax update and one value product a chunk,
+  not one of each a page (a decode row against a page of 16 keys fills
+  16 of the MXU's 128 lanes and pays a full update for it). The pages
+  of a slot's last chunk that lie beyond its context are MASKED with
+  the rest of the page its context ends in, not skipped: they hold
+  whatever finite numbers the pages the table names hold. With a
+  ``window`` (static ``None`` on models that have none) the pages of
+  its first chunk wholly below the window are masked too, and not
+  fetched. A whole chunk nobody sees is no grid step (the work list)
+  or a skipped one (a tile under a lower cap than the deepest);
 - **per-row ``q_offset`` semantics**: q row ``i`` of slot ``s`` attends
   absolute positions ``<= q_offset[s] + i`` — the speculative verify
   lane's k+1 rows (PR 11) are the contract
@@ -57,9 +66,10 @@ use:
   arena streams quantized pages + their fp32 scales and dequantizes
   per tile in VMEM (1/4 the HBM bytes of a dequantized gather).
 
-``pages_per_step`` (how many table lanes one grid step streams) is the
-kernel's tunable: ``workloads/paged_tune.py`` measures winners per
-block size on the real chip into ``workloads/out/paged_blocks.json``
+``pages_per_step`` (how many table lanes one grid step streams and
+joins: the keys of a tile) is the kernel's tunable:
+``workloads/paged_tune.py`` measures winners per block size on the real
+chip into ``workloads/out/paged_blocks.json``
 (``core.measured.read_measured``, the same persistence the flash block
 sweep uses).
 
@@ -102,13 +112,20 @@ def _tuned_pages(block_size: int) -> Optional[int]:
 
 
 def default_pages_per_step(block_size: int) -> int:
-    """Tuned winner when measured, else stream ~128 KV rows per grid
-    step (a full MXU contraction's worth) capped at 8 parallel page
-    DMAs."""
+    """Tuned winner when measured, else the pages of a head's key tile:
+    at most 128 keys (one lane tile of scores), from at most 3 pages.
+    The cap is the benchmark's, not the chip's: the sweep of the joined
+    body (PERF.md, PR 34) has a 16-key page's call faster with every
+    page joined up to 8 — 1,818 / 1,224 / 882 / 738 / 461 us a layer
+    call at 1 / 2 / 3 / 4 / 8 pages in ``gpt2-large.backlog``'s shape —
+    and at 4 that cell serves its 400-request backlog to within 7
+    requests of empty, at 8 it empties it before the window ends and
+    the run cannot be judged. ``min(8, 128 // block_size)`` is for the
+    PR after the benchmark deepens that backlog."""
     tuned = _tuned_pages(block_size)
     if tuned is not None:
         return max(1, tuned)
-    return max(1, min(8, 128 // max(1, int(block_size))))
+    return max(1, min(3, 128 // max(1, int(block_size))))
 
 
 def table_chunks(table_width: int, block_size: int,
@@ -126,8 +143,12 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
     page dims are squeezed out of the block — every kv head: a TPU
     block's last two dims must be (8, 128)-tiled or span the array's,
-    so heads are lane slices taken inside the kernel). Online softmax
-    across a slot's chunks, which are successive grid steps.
+    so heads are lane slices taken inside the kernel). Per kv head the
+    chunk is ONE ``(L*bs, d)`` key tile (the head's slice of the L
+    pages, joined): one score product, one mask over the chunk's
+    positions, one online-softmax update, one value product — at
+    ``L`` = 1 there is nothing to join and the page is the tile. Online
+    softmax across a slot's chunks, which are successive grid steps.
     ``windowed``: a fourth scalar operand holds each slot's attention
     window. The decode and verify rows' call (not ``tiled``): the grid
     is the LIST of live (slot, chunk) pairs, two more scalar operands
@@ -192,10 +213,11 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     d = q_ref.shape[-1]
+    span = L * bs                   # the chunk's keys: ONE tile a head
     off = off_ref[s_i]
     # q row r of the (rows = R*g) tile belongs to verify row r // g and
     # attends absolute positions <= off + r // g
-    qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0) // g
+    qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0) // g
     last_q = off + (rows // g - 1)
     if tiled:
         # the tile's own rows are [lo, hi) of the cell and none sees a
@@ -208,8 +230,8 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
         first_k = off - win_ref[s_i] + 1
 
     # the online-softmax lines below speak lax, not jnp: this body is
-    # traced L * hkv times and each jnp call pays the jit machinery
-    # again (most of this kernel's trace time: seconds of every
+    # traced hkv times and each jnp call pays the jit machinery again
+    # (a good part of this kernel's trace time: seconds of every
     # serving cell's warm-up); the jaxpr is the same
     def rowwise(x):                 # (rows,) -> (rows, 1)
         return lax.broadcast_in_dim(x, (rows, 1), (0,))
@@ -217,62 +239,76 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     def lanes(x):                   # (rows, 1) -> (rows, NUM_LANES)
         return lax.broadcast_in_dim(x, (rows, NUM_LANES), (0, 1))
 
-    for j in range(L):
-        page_start = (w * L + j) * bs
+    def joined(pages, h, scales=None):
+        """Head ``h``'s ``(span, d)`` tile of the chunk: its lane slice
+        of the L pages, joined along the key axis (an int8 page
+        dequantised in VMEM on the way)."""
+        parts = []
+        for j, page in enumerate(pages):
+            x = page[:, h * d:(h + 1) * d]       # (bs, d)
+            if scales is not None:
+                x = x.astype(jnp.float32) * scales[j][:, h:h + 1]
+            parts.append(x)
+        return jnp.concatenate(parts, axis=0)    # one page: itself
 
-        def compute(j=j, page_start=page_start):
-            kpos = page_start + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, bs), 1)
-            mask = kpos <= qpos
-            if tiled:
-                mask &= kpos <= cap
-            if windowed:
-                mask &= kpos > qpos - win_ref[s_i]
-            # a head's index as an array, made once a page: an int
-            # index is converted again at each of its eight uses, a
-            # third of this kernel's trace time (a literal either way)
-            heads = [jnp.asarray(h) for h in range(hkv)]
-            for h, at in enumerate(heads):
-                q = q_ref[heads[0], at]          # (rows, d), scale folded
-                head = slice(h * d, (h + 1) * d)
-                if quant:
-                    # (bs, d) dequant in VMEM
-                    k = k_pages[j][:, head].astype(jnp.float32) \
-                        * ks_pages[j][:, h:h + 1]
-                    v = v_pages[j][:, head].astype(jnp.float32) \
-                        * vs_pages[j][:, h:h + 1]
-                elif v_width is not None:
-                    k = k_pages[j][:, head]      # (bs, d), fetched once
-                    v = k[:, :v_width]
-                else:
-                    k = k_pages[j][:, head]      # (bs, d)
-                    v = v_pages[j][:, head]
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                s = jnp.where(mask, s, NEG_INF)
-                m_prev = m_scr[at, :, :1]
-                l_prev = l_scr[at, :, :1]
-                m_next = lax.max(m_prev, rowwise(lax.reduce_max(s, (1,))))
-                p = lax.exp(lax.sub(s, m_next))
-                p = jnp.where(mask, p, 0.0)
-                l_cur = rowwise(lax.reduce_sum(p, (1,)))
-                alpha = lax.exp(lax.sub(m_prev, m_next))
-                m_scr[at] = lanes(m_next)
-                l_scr[at] = lanes(lax.add(lax.mul(alpha, l_prev), l_cur))
-                pv = jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                acc_scr[at] = lax.add(lax.mul(acc_scr[at], alpha), pv)
+    # the chunk's first position, written as the start of its page
+    # j = 0 was when the body looped over pages: the tiled call's jaxpr
+    # (L = 1) stays the pinned one to the character
+    chunk_start = (w * L + 0) * bs
 
-        # dead-lane skip: pages wholly beyond the slot's last live
-        # position never touch the MXU (cost ∝ context, not table
-        # width; the table's null-block pad lanes land here too), nor
-        # do pages wholly below the window
-        live = page_start <= last_q
+    def compute():
+        # ONE mask over the chunk's positions: the pages of a slot's
+        # last chunk above its context (and of its first below the
+        # window) are masked like the rest of the page its context
+        # ends in; what they hold is never seen
+        kpos = chunk_start + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, span), 1)
+        mask = kpos <= qpos
+        if tiled:
+            mask &= kpos <= cap
         if windowed:
-            live &= page_start + bs > first_k
-        pl.when(live)(compute)
+            mask &= kpos > qpos - win_ref[s_i]
+        # a head's index as an array, made once a chunk: an int index
+        # is converted again at each of its eight uses (a literal
+        # either way)
+        heads = [jnp.asarray(h) for h in range(hkv)]
+        for h, at in enumerate(heads):
+            q = q_ref[heads[0], at]              # (rows, d), scale folded
+            if quant:
+                k = joined(k_pages, h, ks_pages)
+                v = joined(v_pages, h, vs_pages)
+            elif v_width is not None:
+                k = joined(k_pages, h)           # (span, d), fetched once
+                v = k[:, :v_width]
+            else:
+                k = joined(k_pages, h)           # (span, d)
+                v = joined(v_pages, h)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev = m_scr[at, :, :1]
+            l_prev = l_scr[at, :, :1]
+            m_next = lax.max(m_prev, rowwise(lax.reduce_max(s, (1,))))
+            p = lax.exp(lax.sub(s, m_next))
+            p = jnp.where(mask, p, 0.0)
+            l_cur = rowwise(lax.reduce_sum(p, (1,)))
+            alpha = lax.exp(lax.sub(m_prev, m_next))
+            m_scr[at] = lanes(m_next)
+            l_scr[at] = lanes(lax.add(lax.mul(alpha, l_prev), l_cur))
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_scr[at] = lax.add(lax.mul(acc_scr[at], alpha), pv)
+
+    # a chunk wholly beyond the last live position, or wholly below the
+    # window, never touches the MXU. The decode rows' work list holds
+    # no such chunk; a tile of a prefill pack under a lower cap than
+    # the deepest (the grid's bound) has them
+    live = chunk_start <= last_q
+    if windowed:
+        live &= chunk_start + span > first_k
+    pl.when(live)(compute)
 
     @pl.when(last())
     def _finalize():
@@ -450,8 +486,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
     Wp = n_steps * L
     if Wp != W:
         # pad lanes point at the null block; their positions start at
-        # W*bs > any live q position, so the mask (and the dead-lane
-        # skip) keeps them inert
+        # W*bs > any live q position, so the mask keeps them inert
         block_tables = jnp.pad(block_tables, ((0, 0), (0, Wp - W)))
     block_tables = block_tables.astype(jnp.int32)
     T = block_tables.shape[0]       # slots; tiles, where q is in cells

@@ -167,6 +167,12 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "an active slot's chunk at or below its last row; skipped "
             "= the rest of slots x chunks: freed and prefilling slots, "
             "chunks above a context — no grid step)"),
+        decode_tile_keys=reg.counter(
+            "serving_decode_tile_keys_total",
+            "key positions in the live pairs' tiles by state (the "
+            "paged call scores a chunk's span of keys per head as one "
+            "tile): live = the keys an active slot's rows see, masked "
+            "= the rest of its last chunk, above its context"),
         sample_path=reg.counter(
             "serving_sample_path_total",
             "sampler executions of the fused step by lane (decode, "
@@ -2409,16 +2415,19 @@ class ServingEngine:
                     *sample_needs(self._active, self._temp,
                                   self._topk, self._topp)))
                 if self._chunk_steps:
-                    # an active slot's K + 1 rows end in chunk (pos +
-                    # K) // span: its pairs are that chunk and those
-                    # below
-                    live = int(np.minimum(
-                        (self._pos[self._active] + K)
-                        // self._chunk_span + 1,
-                        self._chunk_steps).sum())
+                    # an active slot's K + 1 rows end at position pos +
+                    # K, in chunk (pos + K) // span: its pairs are that
+                    # chunk and those below, each a tile of chunk-span keys
+                    # of which the rows see those up to that position
+                    tile, steps = self._chunk_span, self._chunk_steps
+                    last = self._pos[self._active] + K
+                    live = int(np.minimum(last // tile + 1, steps).sum())
                     m.decode_chunks.inc(live, state="live")
-                    m.decode_chunks.inc(S * self._chunk_steps - live,
-                                        state="skipped")
+                    m.decode_chunks.inc(S * steps - live, state="skipped")
+                    keys = int(np.minimum(last + 1, steps * tile).sum())
+                    m.decode_tile_keys.inc(keys, state="live")
+                    m.decode_tile_keys.inc(live * tile - keys,
+                                           state="masked")
             # pack the prefill budget FCFS over in-flight prefills: the
             # oldest request fills first (so a lone request's chunk
             # count matches the PR 5 single-admission engine), the rest

@@ -566,6 +566,141 @@ def test_paged_kernel_dead_lanes_inert():
                                atol=1e-5)
 
 
+# -- the decode rows' tile: a chunk's pages joined, one update a head --------
+
+#: last positions of the joined-tile cases' slots (pages of 4, a table
+#: of 16 lanes): inside chunk 0, the last key of EVERY page of the upper
+#: chunk of 8 pages (both pages of a chunk of 2), and mid-page
+TILE_ENDS = [0, 6] + [4 * p + 3 for p in range(8, 16)] + [37, 50]
+GARBAGE = 3e4       # large and finite: what a reused page may hold
+
+TILE_FORMS = {"g1": dict(hq=2, hkv=2, d=16),
+              "g4": dict(hq=8, hkv=2, d=16),
+              "latent": dict(hq=4, hkv=1, d=32, v_width=24),
+              "int8": dict(hq=4, hkv=2, d=16, quant=True)}
+
+
+def _garbage_arena(rng, *, rows, hq, hkv, d, bs=4, W=16, v_width=None,
+                   quant=False):
+    """Slots whose last rows stand at ``TILE_ENDS`` over an arena that
+    is GARBAGE wherever no row may look: every position above a slot's
+    context (the rest of its last page, and the pages its table names
+    above it) and every page no table names below a context."""
+    from hetu_tpu.ops.quantization import quantize_int8
+    S = len(TILE_ENDS)
+    off = np.maximum(np.asarray(TILE_ENDS) - (rows - 1), 0).astype(np.int32)
+    ends = off + rows - 1
+    own = ends // bs + 1                 # a slot's pages with live keys
+    n_blocks = 4 + int(own.sum())        # pages 0..3: nobody's
+    k, v = (GARBAGE * rng.choice([-1.0, 1.0], size=(n_blocks, bs, hkv, d))
+            for _ in range(2))
+    tbl = rng.integers(0, 4, size=(S, W)).astype(np.int32)
+    nxt = 4
+    for s in range(S):
+        tbl[s, :own[s]] = nxt + np.arange(own[s])
+        for x in (k, v):
+            live = x[nxt:nxt + own[s]].reshape(-1, hkv, d)
+            live[:ends[s] + 1] = rng.normal(size=(ends[s] + 1, hkv, d))
+        nxt += own[s]
+    q = jnp.asarray(rng.normal(size=(S, rows, hq, d)), jnp.float32)
+    kw = {}
+    if v_width is not None:
+        k, v = _pages(jnp.asarray(k, jnp.float32)), None
+        kw = dict(v_width=v_width, scale=0.2)
+    elif quant:
+        (k, ks), (v, vs) = (map(_pages, quantize_int8(
+            jnp.asarray(x, jnp.float32), axis=-1)) for x in (k, v))
+        kw = dict(k_scale=ks, v_scale=vs)
+    else:
+        k, v = (_pages(jnp.asarray(x, jnp.float32)) for x in (k, v))
+    return q, k, v, jnp.asarray(tbl), jnp.asarray(off), kw
+
+
+@pytest.mark.parametrize("form,pages", [
+    (f, p) for f in ("g1", "g4", "latent") for p in (1, 2, 8)]
+    + [("int8", 8)])
+@pytest.mark.parametrize("rows", [1, 4], ids=["decode", "verify4"])
+def test_paged_kernel_joined_chunk_tile_matches_reference(form, pages,
+                                                          rows):
+    """One score tile, one mask and one online-softmax update a (head,
+    chunk) == the gather oracle, outputs and LSE, whatever
+    ``pages_per_step`` joins: contexts that end at every page of a
+    chunk and mid-page (the pages above are MASKED now, not skipped:
+    what they and the arena's unreferenced pages hold — large finite
+    garbage — must not be seen), alone and under a window whose first
+    key lies mid-chunk (13 keys: the pages below it are not fetched,
+    their lanes name the window's first page again)."""
+    rng = np.random.default_rng(51)
+    q, k, v, tbl, off, kw = _garbage_arena(rng, rows=rows,
+                                           **TILE_FORMS[form])
+    for window in (None, jnp.asarray(13, jnp.int32)):
+        ref, lse_r = paged_attention_reference(
+            q, k, v, tbl, off, return_lse=True, window=window, **kw)
+        out, lse = paged_attention_pallas(
+            q, k, v, tbl, off, pages_per_step=pages, return_lse=True,
+            window=window, **kw)
+        assert np.abs(np.asarray(ref)).max() < 10     # no garbage in it
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r),
+                                   atol=1e-5)
+
+
+@pytest.mark.parametrize("block_size,width,pages,steps", [
+    (16, 64, 3, 22), (64, 128, 2, 64), (64, 256, 2, 128), (128, 8, 1, 8),
+    (16, 2, 2, 1)])
+def test_default_tile_is_a_lane_tile_of_keys_from_at_most_three_pages(
+        block_size, width, pages, steps):
+    """The rule behind ``pages_per_step=None``: at most 128 keys a
+    tile, at most 3 pages (the cells' block sizes and table widths; a
+    table narrower than the rule's pages is one chunk)."""
+    from hetu_tpu.ops.paged_pallas import table_chunks
+    assert table_chunks(width, block_size) == (pages, steps)
+
+
+def _kernel_eqns(jaxpr):
+    """Every equation of the paged call's kernel body, conditionals
+    looked into."""
+    def walk(j):
+        for e in j.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+    call, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+    return list(walk(call.params["jaxpr"]))
+
+
+@pytest.mark.parametrize("pages", [1, 2, 8])
+@pytest.mark.parametrize("form", ["g1", "g4", "latent", "int8"])
+def test_decode_call_scores_a_chunk_per_head_in_one_tile(form, pages):
+    """The kernel's jaxpr at ``pages_per_step`` pages a grid step holds
+    ``hkv`` score products ``(rows, d) x (pages * bs, d)`` and ``hkv``
+    value products — not ``pages x hkv`` of a page each — and one exp
+    of a score tile a head; at one page there is nothing to join and no
+    ``concatenate`` (the tiled call's program is the parent's:
+    ``PARENT_TILED_JAXPR``)."""
+    rng = np.random.default_rng(52)
+    form = TILE_FORMS[form]
+    hkv, d, bs = form["hkv"], form["d"], 4
+    q, k, v, tbl, off, kw = _garbage_arena(rng, rows=1, **form)
+    eqns = _kernel_eqns(jax.make_jaxpr(
+        lambda q, k, v: paged_attention_pallas(
+            q, k, v, tbl, off, pages_per_step=pages, interpret=True,
+            **kw))(q, k, v).jaxpr)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    g, dv = form["hq"] // hkv, form.get("v_width", d)
+    assert sorted((e.invars[1].aval.shape, e.outvars[0].aval.shape)
+                  for e in dots) == sorted(
+        [((pages * bs, d), (g, pages * bs))] * hkv         # scores
+        + [((pages * bs, dv), (g, dv))] * hkv)             # values
+    joins = [e for e in eqns if e.primitive.name == "concatenate"]
+    per_head = (1 if "v_width" in form else 2)
+    assert len(joins) == (0 if pages == 1 else per_head * hkv)
+    assert sum(e.primitive.name == "exp"
+               and e.outvars[0].aval.shape == (g, pages * bs)
+               for e in eqns) == hkv
+
+
 # -- the prefill lane's history read: one pass per tile of a run ------------
 
 TQ = 4          # tile size of the cases below (a run of TQ is one tile)
@@ -1130,7 +1265,10 @@ def test_engine_counts_decode_chunks_on_the_host(gpt, monkeypatch):
     slots x chunks (3 x 4 at a page a step). Once the short request is
     finished its slot stands at its last position and is NOT counted:
     the next iteration's live pairs are the long request's alone, its
-    chunks are ``skipped``."""
+    chunks are ``skipped``. ``serving_decode_tile_keys_total{state}``
+    beside it: the keys the active slots' rows see (``pos + 1`` each)
+    are ``live``, the rest of the live pairs' tiles (a pair is BLOCK
+    keys here) ``masked``."""
     from hetu_tpu.ops import paged_pallas
     from hetu_tpu.serving import SamplingParams, ServingEngine
     cfg, model, params = gpt
@@ -1146,9 +1284,14 @@ def test_engine_counts_decode_chunks_on_the_host(gpt, monkeypatch):
         chunks = telemetry.get_registry().counter(
             "serving_decode_chunks_total")
 
+        keys = telemetry.get_registry().counter(
+            "serving_decode_tile_keys_total")
+
         def counts():
             return (chunks.value(state="live"),
-                    chunks.value(state="skipped"))
+                    chunks.value(state="skipped"),
+                    keys.value(state="live"),
+                    keys.value(state="masked"))
 
         a = eng.submit(short, SamplingParams(max_tokens=2))
         b = eng.submit(long_, SamplingParams(max_tokens=14))
@@ -1158,20 +1301,25 @@ def test_engine_counts_decode_chunks_on_the_host(gpt, monkeypatch):
             before, pos = counts(), eng._pos.copy()
             act = eng._active.copy()
             eng.step()
-            live, skipped = np.subtract(counts(), before)
+            live, skipped, seen, masked = np.subtract(counts(), before)
             if act.any():
                 assert live == (pos[act] // BLOCK + 1).sum()
                 assert live + skipped == 12
+                assert seen == (pos[act] + 1).sum()
+                assert seen + masked == live * BLOCK
         slot_a = int(np.flatnonzero(~eng._active & (eng._pos > 0))[0])
         assert eng._pos[slot_a] >= len(short)    # stale, and left so
         while not b.done.is_set():
             before, pos_b = counts(), int(eng._pos[eng._active][0])
             eng.step()
-            live, skipped = np.subtract(counts(), before)
-            # the freed slot's two chunks are skipped, not live
+            live, skipped, seen, masked = np.subtract(counts(), before)
+            # the freed slot's two chunks are skipped, not live, and
+            # its stale position's keys are nobody's
             assert live == pos_b // BLOCK + 1
             assert skipped == 12 - live
-        assert counts()[0] > 0
+            assert seen == pos_b + 1
+            assert masked == live * BLOCK - seen
+        assert min(counts()) > 0
         # the gather path has no work list to count
         ref = ServingEngine(model, params, slots=3, max_len=MAX_LEN,
                             prefill_chunk=CHUNK, block_size=BLOCK)

@@ -46,6 +46,22 @@ def _rpc_server_observe(verb: str, dur_ms: float,
     c.inc(n_out, verb=verb, dir="out")
 
 
+def _wire_event(name: str, t0: float, cpu0: float, **attrs) -> None:
+    """ONE tracer event for what a wire thread took (``cat="wire"``):
+    its wall time from ``t0`` (``perf_counter``) and the thread's CPU
+    time from ``cpu0`` (``thread_time``) as ``cpu_s`` — a drainer at
+    its exit, a handler per submit it served; none per frame. Read
+    beside the loop's ``serve/step`` account: they share one
+    interpreter (``docs/OBSERVABILITY.md``)."""
+    try:
+        from hetu_tpu import telemetry
+        telemetry.get_tracer().complete(
+            name, time.perf_counter() - t0, cat="wire",
+            cpu_s=time.thread_time() - cpu0, **attrs)
+    except Exception:                                 # noqa: BLE001
+        pass
+
+
 class _State:
     def __init__(self):
         self.lock = threading.Lock()
@@ -355,8 +371,11 @@ class _Handler(socketserver.StreamRequestHandler):
                          "msg": "serving disabled"}, direction="out")
             return
         from hetu_tpu.serving.server import handle_stream_submit
+        t0, cpu0 = time.perf_counter(), time.thread_time()
         req, err = handle_stream_submit(serving,
                                         str(fr.get("payload", "")))
+        _wire_event("server/submit", t0, cpu0,
+                    lock_wait_s=getattr(req, "lock_wait_s", 0.0))
         if err is not None:
             write_frame(self.wfile, wlock,
                         {"k": "err", "sid": sid, "msg": err},
@@ -374,6 +393,8 @@ class _Handler(socketserver.StreamRequestHandler):
         consumer) sends one ``drop`` frame and stops — the client
         falls back to RESULT polling."""
         from hetu_tpu.rpc.stream import write_frame
+        t0, cpu0 = time.perf_counter(), time.thread_time()
+        frames = 0
         try:
             while not closed.is_set():
                 ev = sub.get(timeout=0.25)
@@ -390,10 +411,14 @@ class _Handler(socketserver.StreamRequestHandler):
                 write_frame(self.wfile, wlock,
                             {"k": "ev", "sid": sid, **ev},
                             direction="out")
+                frames += 1
                 if ev.get("done") or ev.get("end"):
                     return
         except (OSError, ValueError):
             sub.close()                 # connection gone — stop feeding
+        finally:
+            _wire_event("stream/drain", t0, cpu0, frames=frames,
+                        req=getattr(sub, "req_id", None))
 
     def _send(self, s: str):
         self.wfile.write((s + "\n").encode())

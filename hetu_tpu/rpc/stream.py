@@ -42,6 +42,7 @@ bytes decide the protocol, nothing else changes.
 
 from __future__ import annotations
 
+import functools
 import itertools
 import json
 import os
@@ -55,16 +56,25 @@ MAGIC = "HSTRM1"
 MAX_FRAME = 64 * 1024 * 1024
 
 
+@functools.cache
+def _frames_counter():
+    """``rpc_stream_frames_total``'s handle, taken from the registry
+    once (``MetricRegistry.clear()`` keeps the metric objects)."""
+    from hetu_tpu import telemetry
+    return telemetry.get_registry().counter(
+        "rpc_stream_frames_total",
+        "stream-channel frames by kind and direction (client: "
+        "tx/rx, server: in/out)")
+
+
 def _count_frame(kind: str, direction: str) -> None:
     """Wire instrumentation (never breaks the protocol): stream frames
     by kind and direction — client uses tx/rx, server in/out, matching
-    ``rpc_payload_bytes_total``'s convention."""
+    ``rpc_payload_bytes_total``'s convention. Called per frame on the
+    drainer threads, so the handle is bound once: no locked
+    get-or-create in the registry per frame."""
     try:
-        from hetu_tpu import telemetry
-        telemetry.get_registry().counter(
-            "rpc_stream_frames_total",
-            "stream-channel frames by kind and direction (client: "
-            "tx/rx, server: in/out)").inc(kind=kind, dir=direction)
+        _frames_counter().inc(kind=kind, dir=direction)
     except Exception:                                 # noqa: BLE001
         pass
 

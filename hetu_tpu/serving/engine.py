@@ -265,6 +265,33 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
     )
 
 
+#: iterations between two refreshes of the spill-tier, replica-store and
+#: adapter-page gauges when no submit or auxiliary job sets them sooner
+_TIER_GAUGES_EVERY = 32
+
+
+class _TimedLock:
+    """``with`` on the engine's lock that adds what the ACQUIRE took to
+    ``waited_s`` (a ``perf_counter`` pair; the hold time is the
+    enclosing span's). One per engine, entered by the thread that holds
+    the iteration lock only, which zeroes ``waited_s`` at a step's
+    start."""
+
+    __slots__ = ("_lock", "waited_s")
+
+    def __init__(self, lock):
+        self._lock = lock
+        self.waited_s = 0.0
+
+    def __enter__(self):
+        t = time.perf_counter()
+        self._lock.acquire()
+        self.waited_s += time.perf_counter() - t
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
 class ServingEngine:
     """Slot-pooled continuous-batching engine over one model + params.
 
@@ -571,6 +598,11 @@ class ServingEngine:
         self._next_id = 0
         self._requests_by_id: dict[int, Request] = {}  # RPC poll map
         self._lock = threading.RLock()
+        # the step's own way into _lock: what its acquisitions waited
+        # is the serve/step span's lock_wait_s
+        self._loop_lock = _TimedLock(self._lock)
+        self._n_admitted = 0                     # admissions, ever
+        self._step_end_pc: Optional[float] = None  # last step's end
         # serializes whole engine ITERATIONS: step() mutates _prefill
         # and passes pool.caches to a buffer-DONATING jit — two drivers
         # (the start() background loop + a direct run_until_drained)
@@ -1156,7 +1188,7 @@ class ServingEngine:
                 np.asarray([sp.top_p], np.float32), job["key"])
         self.pool.caches = caches
         now = time.monotonic()
-        with self._lock:
+        with self._loop_lock:
             self._key_state[slot] = np.asarray(kd)
             self._pos[slot] = P
             self._active[slot] = True
@@ -1241,7 +1273,7 @@ class ServingEngine:
         req, slot, nb = job["req"], job["slot"], job["nb"]
         data = self._spill_blocks(job["ids"], nb)
         now = time.monotonic()
-        with self._lock:
+        with self._loop_lock:
             entry = SpillEntry(
                 req_id=req.id, data=data, n_blocks=nb,
                 block_size=self.pool.block_size,
@@ -1287,7 +1319,7 @@ class ServingEngine:
             self.pool.caches = self._resume_fn(
                 self.pool.caches, tuple(data), jnp.asarray(lane_ids))
         now = time.monotonic()
-        with self._lock:
+        with self._loop_lock:
             if self.spill_arena.get(req.id) is entry:
                 self.spill_arena.pop(req.id)
             req.spill = None
@@ -1773,11 +1805,15 @@ class ServingEngine:
         # prefill tier stamped into the KV stream) — ISSUE 16
         tp = traceparent or (resume.traceparent
                              if resume is not None else None)
+        # what this handler thread waits for the loop's lock goes on
+        # the request (the wire's server/submit event carries it)
+        t_lock = time.perf_counter()
         with self._lock:
             req = Request(id=self._next_id,
                           prompt=np.asarray(prompt, np.int32).ravel(),
                           sampling=sampling, submit_s=time.monotonic(),
-                          handoff=bool(handoff))
+                          handoff=bool(handoff),
+                          lock_wait_s=time.perf_counter() - t_lock)
             self._next_id += 1
             if tp:
                 tid, _span = telemetry.parse_traceparent(tp)
@@ -1820,14 +1856,12 @@ class ServingEngine:
                         "the prompt or raise max_len")
                     req.done.set()
                     admitted = False
-        reg = telemetry.get_registry()
-        reg.counter("serving_requests_total",
-                    "serving requests by outcome").inc(
+        self._m.requests.inc(
             outcome="submitted" if admitted else "rejected")
         flight_record("serving_submit", req=req.id, trace=req.trace_id,
                       prompt_len=len(req.prompt),
                       outcome="queued" if admitted else "rejected")
-        self._record_gauges()
+        self._record_gauges(tiers=True)
         return req
 
     def result(self, req: Request,
@@ -1862,27 +1896,30 @@ class ServingEngine:
                     ent[1].append(sub)
         return sub
 
-    def _pump_stream_subs(self) -> None:
+    def _pump_stream_subs(self) -> int:
         """End-of-step push: fold each subscribed request's newly
         committed tokens (and finish/interrupt markers) into its
-        subscriber queues. Enqueue-only, pure host work — the fused
+        subscriber queues; returns the number of events made (the
+        step's ``frames``). Enqueue-only, pure host work — the fused
         step's 1-compile audit is untouched and a slow subscriber
         overflows its own bounded queue instead of stalling the
         iteration (drop-to-poll, counted)."""
         if not self._stream_subs:
-            return
+            return 0
         from hetu_tpu.serving.streaming import push_delta
+        n = 0
         with self._stream_lock:
             for rid in list(self._stream_subs):
                 req, subs = self._stream_subs[rid]
                 for sub in subs:
-                    push_delta(req, sub)
+                    n += push_delta(req, sub) is not None
                 live = [s for s in subs
                         if not (s.closed or s.dropped)]
                 if live:
                     self._stream_subs[rid] = (req, live)
                 else:
                     del self._stream_subs[rid]
+        return n
 
     def _stream_interrupt(self, req: Request) -> None:
         """Close ``req``'s subscriptions after an out-of-band exit
@@ -2263,6 +2300,7 @@ class ServingEngine:
             if self.tenancy is not None \
                     and not self._bind_adapter_locked(req, slot):
                 continue
+            self._n_admitted += 1
             req.weight_version = self.weight_version
             sp = req.sampling
             self._temp[slot] = sp.temperature
@@ -2312,6 +2350,16 @@ class ServingEngine:
             self._evictions_synced = ev
         return cows
 
+    def _aux_jobs_locked(self) -> tuple:
+        """This iteration's CP-lane prefill and spill-resume, if any
+        (caller holds ``self._lock``). They run as their own
+        (bucket-audited) executables before the fused step — at most
+        ONE of each per iteration, device call and upload OUTSIDE the
+        lock."""
+        return (self._prep_cp_prefill_locked(),
+                self._resume_pending.pop(0)
+                if self._resume_pending else None)
+
     def _step_locked(self) -> bool:
         if not self.has_work():
             return False            # an idle turn records nothing
@@ -2323,6 +2371,15 @@ class ServingEngine:
             return self._step_spanned(sp)
 
     def _step_spanned(self, step_span) -> bool:
+        # the iteration's own account (docs/OBSERVABILITY.md, "the
+        # serving loop's account"): the loop thread's CPU clock beside
+        # the spans' wall clock, and what acquiring self._lock cost —
+        # a dozen clock reads an iteration, nothing per token or slot
+        pc0 = time.perf_counter()
+        cpu0 = time.thread_time()
+        lock = self._loop_lock
+        lock.waited_s = 0.0
+        admitted0 = self._n_admitted
         t0 = time.monotonic()
         span = telemetry.span
         m = self._m
@@ -2330,45 +2387,47 @@ class ServingEngine:
         R = self._fin_cap
         K = self.spec_depth
         S = self.pool.slots
-        with span("serve/admit"), self._lock:
+        it = self._iter + 1
+        active_prev = None
+        with span("serve/admit"), lock:
             cows = self._admit_locked(t0)
             # preemption runs AFTER admission, so it fires only when
             # the deficit-selected head genuinely could not admit —
             # prefix-cache credit and cache eviction (which _page_plan
             # already spends) admit for free before anyone is evicted
             spill_job = self._plan_preemption_locked()
-        if spill_job is not None:
-            with span("serve/aux", what="spill"):
-                self._exec_spill(spill_job)
-        with self._lock:
+            if spill_job is None:
+                cp_job, resume_job = self._aux_jobs_locked()
+                if cp_job is None and resume_job is None:
+                    # the common iteration: no executable of its own
+                    # runs before the fused step
+                    active_prev = np.nonzero(self._active)[0]
+        did_aux = active_prev is None
+        if did_aux:
             if spill_job is not None:
-                # second admission pass picks up the freed slot/blocks
-                # in THIS iteration (the urgent head does not wait one)
-                cows += self._admit_locked(t0)
-            # CP-lane prefills run as their own (bucket-audited)
-            # executables before the fused step — at most ONE per
-            # iteration, device call OUTSIDE the lock. Spill-resumes
-            # follow the same discipline (one per iteration, upload
-            # outside the lock).
-            cp_job = self._prep_cp_prefill_locked()
-            resume_job = self._resume_pending.pop(0) \
-                if self._resume_pending else None
-        did_aux = spill_job is not None
-        if resume_job is not None:
-            with span("serve/aux", what="resume"):
-                self._exec_resume(resume_job)
-            did_aux = True
-        if cp_job is not None:
-            with span("serve/aux", what="cp_prefill"):
-                self._exec_cp_prefill(cp_job, t0)
-            did_aux = True
-        with self._lock:
-            active_prev = np.nonzero(self._active)[0]
-            if not self._prefilling and active_prev.size == 0 \
-                    and not cows:
-                if did_aux:
-                    self._record_gauges()
-                return did_aux
+                with span("serve/aux", what="spill"):
+                    self._exec_spill(spill_job)
+                with span("serve/admit"), lock:
+                    # second admission pass picks up the freed
+                    # slot/blocks in THIS iteration (the urgent head
+                    # does not wait one)
+                    cows += self._admit_locked(t0)
+                    cp_job, resume_job = self._aux_jobs_locked()
+            if resume_job is not None:
+                with span("serve/aux", what="resume"):
+                    self._exec_resume(resume_job)
+            if cp_job is not None:
+                with span("serve/aux", what="cp_prefill"):
+                    self._exec_cp_prefill(cp_job, t0)
+            with span("serve/admit"), lock:
+                active_prev = np.nonzero(self._active)[0]
+        if not self._prefilling and active_prev.size == 0 and not cows:
+            # nothing for the fused step (the loop thread alone changes
+            # _prefilling, under the iteration lock it holds)
+            if did_aux:
+                with lock:
+                    self._record_gauges(tiers=True)
+            return did_aux
         # speculative drafts: per-slot depth + tokens are DATA
         # operands rebuilt every iteration. Depth clamps: never
         # beyond the request's remaining token budget - 1 (so
@@ -2380,21 +2439,25 @@ class ServingEngine:
         # model draftsman's DEVICE step runs between the lock
         # windows below (submit()/load stay responsive through it —
         # the iteration lock we hold keeps its inputs frozen).
-        d_tok = np.zeros((S, K), np.int32)
-        d_len = np.zeros(S, np.int32)
-        d_q = None
-        if K and self._draftsman is not None \
-                and not self._draftsman.host_only:
-            # device draftsman: its q rows ride the spec operand —
-            # ALWAYS present so the step's pytree signature (and
-            # the 1-compile audit) never depends on churn
-            d_q = np.zeros((S, K, self.model.cfg.vocab_size),
-                           np.float32)
-        if K and active_prev.size and self._draftsman is not None:
+        d_tok = d_len = d_q = None
+        if K and self._draftsman is not None:
             with span("serve/draft"):
-                d_tok, d_len, d_q = self._draft(
-                    active_prev, d_tok, d_len, d_q)
-        with span("serve/pack"), self._lock:
+                d_tok = np.zeros((S, K), np.int32)
+                d_len = np.zeros(S, np.int32)
+                if not self._draftsman.host_only:
+                    # device draftsman: its q rows ride the spec
+                    # operand — ALWAYS present so the step's pytree
+                    # signature (and the 1-compile audit) never
+                    # depends on churn
+                    d_q = np.zeros((S, K, self.model.cfg.vocab_size),
+                                   np.float32)
+                if active_prev.size:
+                    d_tok, d_len, d_q = self._draft(
+                        active_prev, d_tok, d_len, d_q)
+        with span("serve/pack"), lock:
+            if d_tok is None:                    # no draftsman
+                d_tok = np.zeros((S, K), np.int32)
+                d_len = np.zeros(S, np.int32)
             if self._ctl_dirty:
                 # uploaded to the step's home: pos/last_tok/key come
                 # back from the step on it, and a differently-typed
@@ -2499,30 +2562,34 @@ class ServingEngine:
             cow = {"run": np.bool_(bool(cows)), "src": cow_src,
                    "dst": cow_dst}
             bt = self._bt_dev
-
-        ctx = self._plan.act if self._plan is not None \
-            else contextlib.nullcontext()
-        spec = {"tok": d_tok, "len": d_len}
-        if d_q is not None:
-            spec["q"] = d_q
-        step_span.set(active=int(active_prev.size), prefill_tokens=used)
-        args = (self.params, self.pool.caches, ctl, pf, bt, cow, spec,
-                self._w8a8_wq, self._lora_pages)
+            spec = {"tok": d_tok, "len": d_len}
+            if d_q is not None:
+                spec["q"] = d_q
+            args = (self.params, self.pool.caches, ctl, pf, bt, cow,
+                    spec, self._w8a8_wq, self._lora_pages)
         if not self._scopes_registered:
             self._register_device_scopes(args)
-        with span("serve/dispatch"), ctx:
+        ctx = self._plan.act if self._plan is not None \
+            else contextlib.nullcontext()
+        # ``iter`` on both: a reader of the profiler's trace pairs this
+        # iteration's program on the device with the two spans around it
+        # (launch lag, fetch lag). The host operands (pf, cow, spec) are
+        # uploaded inside the call, by jit's own argument handling
+        with span("serve/dispatch", iter=it), ctx:
             (caches, committed, ncommit, first_toks, pos_dev,
              last_dev, key_dev, lane_stats) = self._fn(*args)
-        del args                    # the arena was donated
-        self.pool.caches = caches
-        with span("serve/device_wait"):
+            del args                # the arena was donated
+            self.pool.caches = caches
+        with span("serve/device_wait", iter=it):
+            cpu_w = time.thread_time()
             em = np.asarray(committed)           # (S, K+1)
             nc = np.asarray(ncommit)             # (S,)
             ft = np.asarray(first_toks)
-        now = time.monotonic()
+            cpu_w = time.thread_time() - cpu_w
+            now = time.monotonic()
 
         n_generated = 0
-        with span("serve/commit"), self._lock:
+        with span("serve/commit"), lock:
             self._iter += 1
             # the host mirror of the per-slot commit keys always tracks
             # the device: the step advanced them (verify consumption +
@@ -2619,17 +2686,38 @@ class ServingEngine:
             if not self._ctl_dirty:
                 self._ctl_dev = dict(self._ctl_dev, pos=pos_dev,
                                      last_tok=last_dev, key=key_dev)
-            self._record_gauges()
+            self._record_gauges(
+                tiers=did_aux or self._iter % _TIER_GAUGES_EVERY == 0)
         with span("serve/pump"):
-            self._pump_stream_subs()
-        step_s = time.monotonic() - t0
-        m.step_seconds.observe(step_s)
-        if self.slo is not None:
-            self.slo.observe("serving_step_seconds", step_s)
-        if self._counter_sample_every and \
-                self._iter % self._counter_sample_every == 0:
-            telemetry.get_tracer().record_counters(
-                telemetry.get_registry().snapshot())
+            frames = self._pump_stream_subs()
+        with span("serve/account"):
+            # the step's device results are let go HERE, not when the
+            # function returns: their release hands the interpreter to
+            # the wire threads the pump just woke (2-3 ms of a backlog
+            # iteration on the chip), and that wait belongs to a child
+            del committed, ncommit, first_toks, pos_dev, last_dev, \
+                key_dev, lane_stats, caches
+            step_s = time.monotonic() - t0
+            m.step_seconds.observe(step_s)
+            if self.slo is not None:
+                self.slo.observe("serving_step_seconds", step_s)
+            if self._counter_sample_every and \
+                    self._iter % self._counter_sample_every == 0:
+                telemetry.get_tracer().record_counters(
+                    telemetry.get_registry().scalars())
+        # set once, at the end: host CPU = cpu_s - wait_cpu_s, host wall
+        # = the step - serve/device_wait, and what is off the CPU is
+        # the lock (lock_wait_s) or the interpreter and blocking calls
+        # (the rest). since_prev_s is the loop's turn between two steps
+        # (has_work, the watchdog's beat, the SLO rules, idle sleeps)
+        end = self._step_end_pc
+        self._step_end_pc = time.perf_counter()
+        step_span.set(
+            active=int(active_prev.size), prefill_tokens=used,
+            cpu_s=time.thread_time() - cpu0, wait_cpu_s=cpu_w,
+            lock_wait_s=lock.waited_s,
+            admitted=self._n_admitted - admitted0, frames=frames,
+            since_prev_s=pc0 - end if end is not None else 0.0)
         return True
 
     def _draft(self, active_prev, d_tok, d_len, d_q):
@@ -2639,7 +2727,7 @@ class ServingEngine:
         K = self.spec_depth
         S = self.pool.slots
         model_draft_in = None
-        with self._lock:
+        with self._loop_lock:
             budget = np.zeros(S, np.int32)
             for r in active_prev:
                 req = self._slot_req[r]
@@ -2787,11 +2875,27 @@ class ServingEngine:
                  req.finish_s - req.first_token_s,
                  tokens=len(req.tokens))
 
-    def _record_gauges(self) -> None:
+    def _record_gauges(self, tiers: bool = False) -> None:
+        """The gauges an operator scrapes (``docs/OBSERVABILITY.md``
+        names each one's reader). Every iteration: the three that move
+        with every admission and finish, and the window's dead blocks.
+        ``tiers``: the spill tiers, the replica store and the adapter
+        pages too — they move with a preemption, a resume, a push from
+        a peer or an adapter load, so they are set on a submit, after an
+        iteration that ran such a job and every ``_TIER_GAUGES_EVERY``
+        iterations, not six to nine ``set`` calls in every one."""
         m = self._m
         m.queue_depth.set(self.scheduler.depth)
         m.occupancy.set(self.scheduler.occupancy)
         m.kv_in_use.set(self.blocks.blocks_in_use)
+        if self._min_window is not None:
+            # the next query of an active slot sits at pos: blocks whose
+            # last row is at or below pos - window are dead to it
+            below = self._pos[self._active] - self._min_window + 1
+            m.window_dead.set(int(
+                (np.maximum(below, 0) // self.pool.block_size).sum()))
+        if not tiers:
+            return
         m.spill_arena.set(self.spill_arena.blocks_held)
         for tier, n in self.spill_arena.tier_counts().items():
             m.spill_tiers.set(n, tier=tier)
@@ -2799,12 +2903,6 @@ class ServingEngine:
                           tier="replica")
         if self.tenancy is not None:
             m.adapter_pages.set(self.tenancy.registry.pages_in_use)
-        if self._min_window is not None:
-            # the next query of an active slot sits at pos: blocks whose
-            # last row is at or below pos - window are dead to it
-            below = self._pos[self._active] - self._min_window + 1
-            m.window_dead.set(int(
-                (np.maximum(below, 0) // self.pool.block_size).sum()))
 
     def run_until_drained(self, max_steps: int = 1_000_000) -> int:
         """Drive :meth:`step` until queue + slots are empty; returns the

@@ -100,6 +100,9 @@ class Request:
     #                                    — swaps only land on drained
     #                                    engines, so one request is one
     #                                    version, end to end
+    lock_wait_s: float = 0.0           # what submit() waited for the
+    #                                    engine's lock (the loop holds
+    #                                    it through admit, pack, commit)
     handoff: bool = False              # prefill-tier mode (ISSUE 15):
     #                                    the engine parks the request
     #                                    after its FIRST token (status

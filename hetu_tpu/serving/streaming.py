@@ -21,6 +21,7 @@ Pure stdlib — importable by the jax-free coordinator.
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -30,6 +31,22 @@ from typing import Optional
 def _registry():
     from hetu_tpu import telemetry
     return telemetry.get_registry()
+
+
+@functools.cache
+def _pushed_counters() -> tuple:
+    """The two counters of ``push_delta``, taken from the registry
+    once: the engine's loop calls it per (request, iteration)."""
+    reg = _registry()
+    return (
+        reg.counter(
+            "serving_stream_events_total",
+            "token events pushed into subscriber queues (one per "
+            "request per step with news)"),
+        reg.counter(
+            "serving_stream_tokens_total",
+            "tokens delivered via push subscriptions (vs the "
+            "RESULT poll lane)"))
 
 
 def count_subscribe(mode: str) -> None:
@@ -152,16 +169,10 @@ def push_delta(req, sub: TokenSubscription, *,
         return None
     if sub.emit(ev):
         try:
-            reg = _registry()
-            reg.counter(
-                "serving_stream_events_total",
-                "token events pushed into subscriber queues (one per "
-                "request per step with news)").inc()
+            events, tokens = _pushed_counters()
+            events.inc()
             if ev["toks"]:
-                reg.counter(
-                    "serving_stream_tokens_total",
-                    "tokens delivered via push subscriptions (vs the "
-                    "RESULT poll lane)").inc(len(ev["toks"]))
+                tokens.inc(len(ev["toks"]))
         except Exception:                             # noqa: BLE001
             pass
     if ev.get("done") or ev.get("end"):

@@ -270,6 +270,17 @@ class MetricRegistry:
                 out.update(m._snapshot())
         return out
 
+    def scalars(self) -> dict:
+        """The counters' and gauges' series of :meth:`snapshot` — what
+        a counter track samples. A histogram's summary sorts its
+        reservoir, which is no work for a serving iteration to do."""
+        out: dict = {}
+        with self._lock:
+            for m in self._metrics.values():
+                if not isinstance(m, Histogram):
+                    out.update(m._snapshot())
+        return out
+
     def to_record(self) -> dict:
         return {"kind": "metrics_snapshot", "metrics": self.snapshot()}
 

@@ -191,7 +191,8 @@ def test_serving_step_scopes_without_retrace(fresh_scopes):
 
 # -- host spans ---------------------------------------------------------------
 SERVE_CHILDREN = ["serve/admit", "serve/pack", "serve/dispatch",
-                  "serve/device_wait", "serve/commit", "serve/pump"]
+                  "serve/device_wait", "serve/commit", "serve/pump",
+                  "serve/account"]
 
 
 def test_engine_emits_serve_spans_in_order(telem):

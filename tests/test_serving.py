@@ -553,3 +553,222 @@ def test_serving_under_tp2_mesh_matches_single_device(gpt):
     assert eng.generate_many(prompts, sp) == want
     # and every request still matches its one-shot generate
     assert want == [_ref(model, params, p, 6) for p in prompts]
+
+
+# -- the loop's account of its own iteration (ISSUE 35) -----------------------
+@pytest.fixture
+def telem():
+    telemetry.reset()
+    telemetry.enable(True)
+    yield telemetry
+    telemetry.enable(False)
+    telemetry.reset()
+
+
+def _events(name):
+    return [e for e in telemetry.get_tracer().events() if e.name == name]
+
+
+def _host(step):
+    """(host wall, host CPU) of one ``serve/step`` event: the step less
+    its child ``serve/device_wait``, on both clocks."""
+    wait = sum(e.dur_s for e in _events("serve/device_wait")
+               if e.attrs["iter"] == step.attrs["iter"])
+    return (step.dur_s - wait,
+            step.attrs["cpu_s"] - step.attrs["wait_cpu_s"])
+
+
+def test_step_span_carries_the_loops_account(gpt, telem):
+    """Every ``serve/step`` event has the loop thread's CPU clock beside
+    its wall clock, what acquiring the engine's lock cost, what it
+    admitted and pushed, and the loop's turn since the step before; the
+    two spans around the device carry the iteration they belong to."""
+    cfg, model, params = gpt
+    eng = ServingEngine(model, params, slots=2, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK)
+    prompts = _prompts(cfg, [5, 11, 3, 8], seed=5)
+    eng.generate_many(prompts, SamplingParams(max_tokens=4))
+    steps = _events("serve/step")
+    assert len(steps) >= 4
+    slack = 1e-4                   # two clocks, read microseconds apart
+    for e in steps:
+        a = e.attrs
+        assert {"cpu_s", "wait_cpu_s", "lock_wait_s", "admitted",
+                "frames", "since_prev_s", "active",
+                "prefill_tokens"} <= set(a)
+        assert 0 <= a["wait_cpu_s"] <= a["cpu_s"] <= e.dur_s + slack
+        assert 0 <= a["lock_wait_s"] <= e.dur_s
+        assert a["since_prev_s"] >= 0 and a["frames"] == 0
+        wall, cpu = _host(e)
+        assert -slack <= cpu <= wall + slack
+    assert steps[0].attrs["since_prev_s"] == 0.0     # nothing before it
+    assert all(e.attrs["since_prev_s"] > 0 for e in steps[1:])
+    assert sum(e.attrs["admitted"] for e in steps) == len(prompts)
+    iters = [e.attrs["iter"] for e in steps]
+    for name in ("serve/dispatch", "serve/device_wait"):
+        assert [e.attrs["iter"] for e in _events(name)] == iters
+
+
+def test_a_held_lock_shows_as_lock_wait_and_off_cpu(gpt, telem):
+    """A thread that holds the engine's lock for 50 ms while a step
+    wants it: the step's ``lock_wait_s`` has it, and it is off-CPU host
+    time, not CPU time."""
+    import threading
+    import time
+
+    cfg, model, params = gpt
+    eng = ServingEngine(model, params, slots=2, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK)
+    eng.submit(_prompts(cfg, [6], seed=2)[0], SamplingParams(max_tokens=6))
+    eng.step()                                  # compiles
+    eng.step()
+    calm = _events("serve/step")[-1]
+    assert calm.attrs["lock_wait_s"] < 0.005
+    holding = threading.Event()
+
+    def hold():
+        with eng._lock:
+            holding.set()
+            time.sleep(0.05)
+
+    holder = threading.Thread(target=hold)
+    fn = eng._fn
+
+    def step_then_hold(*args):
+        # after the dispatch (made outside the lock) and before the
+        # commit (which needs it)
+        out = fn(*args)
+        holder.start()
+        holding.wait()
+        return out
+
+    eng._fn = step_then_hold
+    eng.step()
+    holder.join()
+    eng._fn = fn
+    held = _events("serve/step")[-1]
+    assert held.attrs["lock_wait_s"] >= 0.04
+    wall, cpu = _host(held)
+    assert wall - cpu >= 0.04                   # off the CPU ...
+    assert cpu <= wall - 0.04                   # ... and not on it
+    assert held.dur_s >= 0.04 > calm.dur_s
+    eng.run_until_drained()
+
+
+def test_children_cover_the_step(gpt, telem):
+    """The span tree is closed: the children of ``serve/step`` cover at
+    least 95 % of it (16 slots and a pack of 64 make an iteration of a
+    few ms here, against ~10 us of span bookkeeping between two
+    children), and ``serve/account`` is the last of them."""
+    cfg, model, params = gpt
+    eng = ServingEngine(model, params, slots=16, max_len=128,
+                        prefill_chunk=64)
+    eng.generate_many(_prompts(cfg, [90] * 24, seed=9),
+                      SamplingParams(max_tokens=12))
+    evs = [e for e in telemetry.get_tracer().events()
+           if e.name.startswith("serve/")]
+    steps = [e for e in evs if e.name == "serve/step"][1:]  # compiled
+    cover = []
+    for st in steps:
+        kids = sorted((e for e in evs if e.depth == st.depth + 1
+                       and st.ts_s <= e.ts_s
+                       and e.ts_s + e.dur_s <= st.ts_s + st.dur_s + 1e-9),
+                      key=lambda e: e.ts_s)
+        assert kids[0].name == "serve/admit"
+        assert kids[-1].name == "serve/account"
+        cover.append(sum(e.dur_s for e in kids) / st.dur_s)
+    assert len(cover) >= 20
+    assert float(np.median(cover)) >= 0.95, sorted(cover)[:5]
+
+
+def test_wire_threads_say_what_they_took(gpt, telem):
+    """One ``stream/drain`` event per finished subscription (its
+    lifetime, its thread's CPU time, the frames it wrote) and one
+    ``server/submit`` per submit served — none per frame; the frames
+    counter's handle is bound once and survives a registry reset."""
+    import socket
+    import threading
+
+    from hetu_tpu.rpc import stream
+    from hetu_tpu.rpc.stream import StreamChannel
+    from hetu_tpu.serving.server import ServingServer, encode_payload
+
+    cfg, model, params = gpt
+    eng = ServingEngine(model, params, slots=2, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK)
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    srv = ServingServer(eng, port)
+    srv.start()
+    srv.wait_ready()
+    n, got, done = 5, [], threading.Semaphore(0)
+
+    def sink(fr):
+        got.append(fr)
+        if fr.get("done"):
+            done.release()
+
+    try:
+        ch = StreamChannel(port)
+        for i, p in enumerate(_prompts(cfg, [6] * n, seed=4)):
+            ch.stream_submit(encode_payload(
+                {"prompt": p, "max_tokens": 5, "temperature": 0.0,
+                 "idem": f"acct{i}"}), sink=sink)
+        for _ in range(n):
+            assert done.acquire(timeout=60)
+        for _ in range(200):            # the drainers record as they exit
+            if len(_events("stream/drain")) == n:
+                break
+            threading.Event().wait(0.01)
+        ch.close()
+    finally:
+        srv.stop()
+    drains, submits = _events("stream/drain"), _events("server/submit")
+    assert len(drains) == n and len(submits) == n
+    pushed = sum(e.attrs["frames"] for e in _events("serve/step"))
+    frames = sum(e.attrs["frames"] for e in drains)
+    assert frames == pushed == len([f for f in got if f["k"] == "ev"])
+    for e in drains + submits:
+        assert e.cat == "wire" and 0 <= e.attrs["cpu_s"] <= e.dur_s + 1e-4
+    assert sorted(e.attrs["req"] for e in drains) == list(range(n))
+    assert all(e.attrs["lock_wait_s"] >= 0 for e in submits)
+    # the per-frame counter: the server's and the client's ends share
+    # this process's registry
+    count = telemetry.get_registry().counter("rpc_stream_frames_total")
+    assert count.value(kind="ev", dir="out") == frames
+    assert count is stream._frames_counter()
+    telemetry.reset()
+    stream._count_frame("ev", "out")
+    assert count.value(kind="ev", dir="out") == 1
+
+
+def test_tier_gauges_move_with_their_events_not_every_iteration(gpt, telem):
+    """The spill tiers, the replica store and the adapter pages are set
+    on a submit and every ``_TIER_GAUGES_EVERY`` iterations (sooner
+    after an iteration that ran a spill, resume or CP job); the gauges
+    every admission and finish move are set in every iteration."""
+    from hetu_tpu.serving import engine as engine_mod
+
+    cfg, model, params = gpt
+    eng = ServingEngine(model, params, slots=1, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK)
+    walks = []
+    counts = eng.spill_arena.tier_counts
+    eng.spill_arena.tier_counts = lambda: walks.append(eng._iter) \
+        or counts()
+    reg = telemetry.get_registry()
+    every = engine_mod._TIER_GAUGES_EVERY
+    for p in _prompts(cfg, [4, 4], seed=6):        # one after the other
+        eng.submit(p, SamplingParams(max_tokens=26))
+    assert walks == [0, 0]                      # one walk a submit
+    assert reg.gauge("spill_tier_blocks").value(tier="host") == 0
+    assert reg.gauge("serving_queue_depth").value() == 2
+    in_use = []
+    while eng.has_work():
+        eng.step()
+        in_use.append(reg.gauge("serving_kv_blocks_in_use").value())
+    assert eng._iter > every
+    assert walks[2:] == list(range(every, eng._iter + 1, every))
+    assert in_use[0] > 0 and in_use[-1] == 0    # set in every iteration
+    assert reg.gauge("serving_queue_depth").value() == 0

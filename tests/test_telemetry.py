@@ -181,6 +181,23 @@ def test_counter_gauge_snapshot_and_prometheus():
         reg.counter("steps_total").inc(-1)
 
 
+def test_scalars_are_the_snapshot_without_histograms():
+    """What a counter track samples per serving iteration: every
+    counter and gauge series, no histogram summary (no sort)."""
+    reg = MetricRegistry()
+    reg.counter("steps_total").inc(3)
+    reg.gauge("queue_depth").set(4, loader="train")
+    reg.histogram("step_seconds").observe(0.5)
+    snap, scal = reg.snapshot(), reg.scalars()
+    assert scal == {"steps_total": 3.0, 'queue_depth{loader="train"}': 4.0}
+    assert scal == {k: v for k, v in snap.items()
+                    if not isinstance(v, dict)}
+    assert isinstance(snap["step_seconds"], dict)
+    tr = Tracer()
+    assert tr.record_counters(scal, prefixes=None) == \
+        tr.record_counters(snap, prefixes=None) == 2
+
+
 def test_disabled_registry_is_noop():
     reg = MetricRegistry(enabled=False)
     reg.counter("c").inc(5)

@@ -107,6 +107,7 @@ from hetu_tpu.serving.speculative import (
     check_sampled_draft, sample_needs, sample_path, sample_rows,
     verify_slots,
 )
+from hetu_tpu.serving.step_io import PackedFields
 from hetu_tpu.serving.tenancy import AdapterArenaFull
 from hetu_tpu.telemetry.flight import HangWatchdog, flight_record
 from hetu_tpu.telemetry.slo import SLOEngine, default_serving_rules
@@ -181,6 +182,15 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "no live row has a temperature; draw = scaled logits, "
             "softmax and categorical draws; sort = draw plus the "
             "top-k / top-p sort, because some live sampling row masks)"),
+        transfers=reg.counter(
+            "serving_step_transfers_total",
+            "host<->device transfers the serving loop issued, by "
+            "direction (up = host to device, down = device to host). "
+            "The common iteration: down 1 — the fused step's packed "
+            "result vector — and up one per host array of the step's "
+            "operands (pf, cow, spec: jit uploads each by itself); "
+            "rewritten control state (nine up), a spill, a resume, a "
+            "CP-lane prefill and a device draftsman add their own"),
         draft=reg.counter(
             "serving_draft_tokens_total",
             "draft tokens proposed to the verify lane"),
@@ -732,6 +742,7 @@ class ServingEngine:
             if "window" in ld else None
         self._prefill_path = "flash" if prefill_attn != "reference" \
             else "reference"
+        self._results = self._result_layout()
         self._fn = self._build_step()
         self._scopes_registered = False
         self._cp_fn = self._build_cp_prefill() \
@@ -834,6 +845,7 @@ class ServingEngine:
         # its sampled softmax rows through spec["q"]
         host_q = self._draftsman is None \
             or getattr(self._draftsman, "host_only", True)
+        results = self._results
 
         def step(params, caches, ctl, pf, bt, cow, spec, wq, lora):
             record_trace("serving_step")    # churn must never re-enter
@@ -1049,14 +1061,35 @@ class ServingEngine:
             new_pos = ctl["pos"] + jnp.where(ctl["active"], ncommit, 0)
             new_last = jnp.where(ctl["active"], last_tok,
                                  ctl["last_tok"])
-            return (caches, committed, ncommit, first_toks,
-                    new_pos, new_last, new_key, (dec_stats, pf_stats))
+            # the iteration's ONE fetch: what the host reads of it
+            out = results.pack_device({
+                "committed": committed, "ncommit": ncommit,
+                "first_toks": first_toks, "key": new_key,
+                "stats": (dec_stats, pf_stats)})
+            return caches, new_pos, new_last, new_key, out
 
         # what the step returns AND takes again keeps its home
         # (__init__): the arena, and the advanced pos/last_tok/key
         rep = self._rep
         return jax.jit(step, donate_argnums=(1,), out_shardings=(
-            self._arena_sh, None, None, None, rep, rep, rep, None))
+            self._arena_sh, rep, rep, rep, None))
+
+    def _result_layout(self) -> PackedFields:
+        """The layout of the ONE vector the fused step hands the host
+        (``step_io.PackedFields``; ``docs/OBSERVABILITY.md`` has the
+        table), from what the engine knows at construction — slots,
+        draft depth, the finishing rows, the key's words, the block's
+        ``layer_stats``."""
+        S, R, K = self.pool.slots, self._fin_cap, self.spec_depth
+        stats = jax.eval_shape(self.model.blocks.layer_stats_zeros)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, np.int32)
+
+        return PackedFields({
+            "committed": i32(S, K + 1), "ncommit": i32(S),
+            "first_toks": i32(R), "key": self._key_state,
+            "stats": (stats, stats)})
 
     # -- the CP-prefill lane ------------------------------------------------
     def _build_cp_prefill(self):
@@ -1188,6 +1221,9 @@ class ServingEngine:
                 np.asarray([sp.top_p], np.float32), job["key"])
         self.pool.caches = caches
         now = time.monotonic()
+        # the lane's eight numpy operands up, its key and token down
+        self._m.transfers.inc(8, dir="up")
+        self._m.transfers.inc(2, dir="down")
         with self._loop_lock:
             self._key_state[slot] = np.asarray(kd)
             self._pos[slot] = P
@@ -1272,6 +1308,8 @@ class ServingEngine:
         at the head of its class — the resumable half of preemption."""
         req, slot, nb = job["req"], job["slot"], job["nb"]
         data = self._spill_blocks(job["ids"], nb)
+        self._m.transfers.inc(dir="up")                  # the block ids
+        self._m.transfers.inc(len(data), dir="down")     # a leaf each
         now = time.monotonic()
         with self._loop_lock:
             entry = SpillEntry(
@@ -1318,6 +1356,7 @@ class ServingEngine:
         with ctx:
             self.pool.caches = self._resume_fn(
                 self.pool.caches, tuple(data), jnp.asarray(lane_ids))
+        self._m.transfers.inc(len(data) + 1, dir="up")   # leaves + ids
         now = time.monotonic()
         with self._loop_lock:
             if self.spill_arena.get(req.id) is entry:
@@ -2470,6 +2509,7 @@ class ServingEngine:
                       "adapter": self._adapter_page}, self._bt),
                     self._rep)
                 self._ctl_dirty = False
+                m.transfers.inc(9, dir="up")
             ctl = self._ctl_dev
             if self._active.any():
                 # the decode lane's sampler: the step's own predicate
@@ -2574,28 +2614,34 @@ class ServingEngine:
         # ``iter`` on both: a reader of the profiler's trace pairs this
         # iteration's program on the device with the two spans around it
         # (launch lag, fetch lag). The host operands (pf, cow, spec) are
-        # uploaded inside the call, by jit's own argument handling
+        # uploaded inside the call, an array at a time, by jit's own
+        # argument handling; the ONE fetch of what the host reads of
+        # the step starts as soon as the call returns
         with span("serve/dispatch", iter=it), ctx:
-            (caches, committed, ncommit, first_toks, pos_dev,
-             last_dev, key_dev, lane_stats) = self._fn(*args)
+            caches, pos_dev, last_dev, key_dev, out = self._fn(*args)
             del args                # the arena was donated
             self.pool.caches = caches
+            out.copy_to_host_async()
+            m.transfers.inc(len(pf) + len(cow) + len(spec), dir="up")
         with span("serve/device_wait", iter=it):
             cpu_w = time.thread_time()
-            em = np.asarray(committed)           # (S, K+1)
-            nc = np.asarray(ncommit)             # (S,)
-            ft = np.asarray(first_toks)
+            out_host = np.asarray(out)
             cpu_w = time.thread_time() - cpu_w
             now = time.monotonic()
+            m.transfers.inc(dir="down")
 
         n_generated = 0
         with span("serve/commit"), lock:
             self._iter += 1
+            res = self._results.unpack_host(out_host)
+            em = res["committed"]                # (S, K+1)
+            nc = res["ncommit"]                  # (S,)
+            ft = res["first_toks"]
             # the host mirror of the per-slot commit keys always tracks
             # the device: the step advanced them (verify consumption +
             # prefill first-token draws) for exactly the slots that
             # sampled this iteration
-            self._key_state[:] = np.asarray(key_dev)
+            self._key_state[:] = res["key"]
             if active_prev.size:
                 m.slot_steps.inc(int(active_prev.size))
                 m.attn_kernel.inc(path=self.attn_kernel)
@@ -2606,9 +2652,9 @@ class ServingEngine:
                 # functions the model's block names for them
                 emit = getattr(self.model.blocks.block, "layer_stats", {})
                 for ran, stats in zip((active_prev.size, used),
-                                      lane_stats):
+                                      res["stats"]):
                     for name, values in stats.items() if ran else ():
-                        emit[name][2](np.asarray(values))
+                        emit[name][2](values)
             # decode results for the slots that were active going in:
             # each commits ncommit tokens (accepted drafts + bonus) —
             # EOS or budget can finish the request mid-commit, in which
@@ -2695,8 +2741,7 @@ class ServingEngine:
             # function returns: their release hands the interpreter to
             # the wire threads the pump just woke (2-3 ms of a backlog
             # iteration on the chip), and that wait belongs to a child
-            del committed, ncommit, first_toks, pos_dev, last_dev, \
-                key_dev, lane_stats, caches
+            del caches, pos_dev, last_dev, key_dev, out
             step_s = time.monotonic() - t0
             m.step_seconds.observe(step_s)
             if self.slo is not None:
@@ -2765,6 +2810,9 @@ class ServingEngine:
             d_tok = np.asarray(d_tok)
             d_len = np.minimum(np.asarray(d_len), model_draft_in[3])
             d_q = np.asarray(dq, np.float32)
+            # the q rows come to the host (and go up again as
+            # spec["q"]); the draftsman's own step is not the loop's
+            self._m.transfers.inc(dir="down")
             # a zoo draft model may have a larger vocab than the
             # target: clamp (the draftsman already masks its sampling
             # to the target vocab; this guards legacy draft paths)

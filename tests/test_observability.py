@@ -642,21 +642,23 @@ def test_serving_loop_watchdog_trips_on_stalled_step(telem, tmp_path):
                         watchdog_min_timeout_s=2.0)
     eng.watchdog.poll_s = 0.02
     eng.watchdog.dump_dir = str(tmp_path)   # keep dumps out of the cwd
-    S = eng.pool.slots
-    R = eng._fin_cap
     hang = threading.Event()
 
     def fake_fn(params, caches, ctl, pf, bt, cow, spec, wq, lora):
         if hang.is_set():
             time.sleep(4.0)          # the stalled fake step (2x floor)
-        # the 9-operand/8-result contract (ISSUE 17 sampled verify
-        # lane + ISSUE 20 adapter arena): committed tokens (S, K+1) +
-        # per-slot commit counts + prefill first tokens +
-        # pos/last_tok/key carries + the two lanes' layer stats
-        # (PR 26; none from a GPT-2 block)
-        return (caches, np.zeros((S, 1), np.int32),
-                np.ones(S, np.int32), np.zeros(R, np.int32),
-                ctl["pos"], ctl["last_tok"], ctl["key"], ({}, {}))
+        # the 9-operand/5-result contract (ISSUE 17 sampled verify
+        # lane + ISSUE 20 adapter arena + ISSUE 38): the arena, the
+        # pos/last_tok/key carries, and ONE packed vector of what the
+        # host reads (the engine's ``_results`` layout): no committed
+        # or first token, one commit a slot, the key state as it was;
+        # no layer stats from a GPT-2 block
+        out = np.zeros(eng._results.size, np.int32)
+        res = eng._results.unpack_host(out)         # views of `out`
+        res["ncommit"][:] = 1
+        res["key"][:] = np.asarray(ctl["key"])
+        return (caches, ctl["pos"], ctl["last_tok"], ctl["key"],
+                jnp.asarray(out))
 
     eng._fn = fake_fn
     eng.start(idle_sleep_s=0.001)

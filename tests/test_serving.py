@@ -106,6 +106,13 @@ def test_engine_int8_pool_matches_int8_generate(gpt):
     assert eng.generate_many(prompts, sp) == want
 
 
+PARENT_TOKENS = {
+    "mixed": [[170] * 8, [239, 203, 203, 76, 221, 236, 248, 83],
+              [52] * 8],
+    "seeded": [[192, 161, 245, 245, 232, 8, 28, 16],
+               [225, 225, 225, 225, 225, 225, 88, 88]]}
+
+
 def test_engine_eos_and_sampling_params(gpt):
     """Per-slot sampling params are traced operands: mixed greedy and
     sampled requests run in one batch without retracing, EOS stops a
@@ -123,6 +130,15 @@ def test_engine_eos_and_sampling_params(gpt):
     assert outs[0] == _ref(model, params, prompts[0], 8)
     assert outs[2] == _ref(model, params, prompts[2], 8)
     assert all(0 <= t < cfg.vocab_size for t in outs[1])
+    # ISSUE 38: the packed step draws the tokens the nine-operand step
+    # drew — these are the parent's (1ef4e70) outputs of this very
+    # traffic, the sampled request's key from the engine's seed and its
+    # id, and of a request with a seed of its own beside a greedy one
+    assert outs == PARENT_TOKENS["mixed"]
+    seeded = SamplingParams(temperature=0.8, top_p=0.95, max_tokens=8,
+                            seed=38)
+    assert eng.generate_many(prompts[:2], [seeded, greedy]) \
+        == PARENT_TOKENS["seeded"]
     # EOS: pick the greedy run's first token as eos — request finishes
     # after exactly one token
     eos = outs[0][0]
@@ -450,6 +466,141 @@ def test_fused_step_sampler_runs_only_under_its_gates(gpt, spec_depth):
         lanes[what].add(conds[0])
     # one gated sort and gated draws in each of the two lanes
     assert len(lanes["sort"]) == 2 and lanes["draw"] == lanes["sort"]
+
+
+def _packed_traffic(eng, cfg):
+    """Churn that makes every field of the step's result vector carry
+    news: two prompts finishing their prefill in one pack, a sampled
+    request with a seed beside greedy ones (the key state moves),
+    accepted drafts, and a prompt whose cached prefix ends inside a
+    block."""
+    short = _prompts(cfg, [3, 4], seed=5)
+    shared = _prompts(cfg, [12], seed=6)[0]     # a block of 8 + 4 rows
+    eng.generate_many(
+        short + [shared + [7, 9, 7, 9, 7]],      # two whole blocks
+        [SamplingParams(max_tokens=6),
+         SamplingParams(temperature=0.7, top_k=5, top_p=0.9,
+                        max_tokens=6, seed=3),
+         SamplingParams(max_tokens=6)])
+    eng.generate_many([shared + [11]], SamplingParams(max_tokens=4))
+
+
+def test_packed_results_reach_the_host_bit_for_bit(gpt):
+    """ISSUE 38 (a): the ONE vector the step returns is its results,
+    field for field — the layout carries float32 and uint32 by their
+    bits through a jit and back; in the engine's own step the key
+    words sliced out of the vector are the key state the step also
+    returns on the device, in every iteration of a churn, and the
+    vector's other fields say what the requests received."""
+    from hetu_tpu.serving.step_io import PackedFields
+    knobs = {"temp": np.float32([0.7, -0.0, np.nan, 1e-42]),
+             "key": np.uint32([[0, 2 ** 32 - 1], [38, 2 ** 31]]),
+             "stats": ({"n": np.int32([[1, -2], [3, 4]])}, {})}
+    lay = PackedFields(knobs)
+    assert [f[:2] for f in lay.fields] == [
+        ("['key']", 0), ("['stats'][0]['n']", 4), ("['temp']", 8)] \
+        and lay.size == 12
+    back = lay.unpack_host(np.asarray(jax.jit(lay.pack_device)(knobs)))
+    assert jax.tree.structure(back) == jax.tree.structure(knobs)
+    for want, have in zip(jax.tree.leaves(knobs), jax.tree.leaves(back)):
+        assert (have.dtype, have.shape) == (want.dtype, want.shape)
+        assert have.tobytes() == want.tobytes()
+    # a field that is not 32 bits wide has no place in the vector, and
+    # a value of another type than its field's is not cast on the way
+    with pytest.raises(ValueError, match="32-bit"):
+        PackedFields({"x": np.zeros(2, np.bool_)})
+    with pytest.raises(ValueError, match="laid out as"):
+        jax.jit(lay.pack_device)(
+            dict(knobs, temp=knobs["temp"].astype(np.float16)))
+    with pytest.raises(ValueError, match="laid out as"):
+        lay.unpack_host(np.zeros(lay.size + 1, np.int32))
+
+    cfg, model, params = gpt
+    eng = ServingEngine(model, params, slots=3, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK, block_size=8, spec_depth=2)
+    step, seen = eng._fn, []
+
+    def spy(*args):
+        out = step(*args)
+        seen.append((np.asarray(out[3]), np.asarray(out[4])))
+        return out
+
+    eng._fn = spy
+    _packed_traffic(eng, cfg)
+    news = set()
+    for key_dev, vec in seen:
+        res = eng._results.unpack_host(vec)
+        assert res["key"].dtype == np.uint32
+        assert res["key"].tobytes() == key_dev.tobytes()
+        assert res["stats"] == ({}, {})          # a GPT-2 block: none
+        news |= {name for name, hit in {
+            "key": bool(res["key"].any()),
+            "accepted drafts": bool((res["ncommit"] > 1).any()),
+            "two first tokens": int((res["first_toks"] > 0).sum()) >= 2,
+            "tokens": bool(res["committed"].any())}.items() if hit}
+    assert news == {"key", "accepted drafts", "two first tokens", "tokens"}
+
+
+def test_common_iteration_is_one_fetch(gpt, telem, monkeypatch):
+    """ISSUE 38 (b): over a common iteration — slots decoding, nothing
+    admitted or finished — the loop issues ONE ``np.asarray`` of a
+    device array and no ``jax.device_put``, and
+    ``serving_step_transfers_total`` says so: down 1, and up one per
+    host array it hands the step (jit uploads each by itself: the
+    packed upload is a later PR's — CHANGES.md, PR 38)."""
+    cfg, model, params = gpt
+    eng = ServingEngine(model, params, slots=2, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK)
+    for p in _prompts(cfg, [5, 6], seed=8):
+        eng.submit(p, SamplingParams(max_tokens=12))
+    for _ in range(3):          # admit, prefill, turn both slots on
+        eng.step()
+    assert eng._active.all() and not eng._ctl_dirty
+    count = telemetry.get_registry().counter(
+        "serving_step_transfers_total").value
+    before = {d: count(dir=d) for d in ("up", "down")}
+    calls = {"put": 0, "down": 0, "host_operands": 0}
+    put, asarray, step = jax.device_put, np.asarray, eng._fn
+
+    def counting_put(x, *a, **kw):
+        calls["put"] += 1
+        return put(x, *a, **kw)
+
+    def counting_asarray(x, *a, **kw):
+        calls["down"] += isinstance(x, jax.Array)
+        return asarray(x, *a, **kw)
+
+    def spy(*args):
+        calls["host_operands"] += sum(
+            not isinstance(x, jax.Array) for x in jax.tree.leaves(args))
+        return step(*args)
+
+    eng._fn = spy
+    monkeypatch.setattr(jax, "device_put", counting_put)
+    monkeypatch.setattr(np, "asarray", counting_asarray)
+    eng.step()
+    eng.step()
+    monkeypatch.undo()
+    assert calls == {"put": 0, "down": 2, "host_operands": 2 * 15}
+    assert {d: count(dir=d) - before[d] for d in before} \
+        == {"up": 2 * 15, "down": 2}
+    eng._fn = step
+    eng.run_until_drained()
+
+
+def test_packed_step_is_one_trace_one_executable(gpt):
+    """ISSUE 38 (d): the step with the packed result vector traces
+    once (``record_trace("serving_step")``) and holds one executable
+    through dirty and clean control state, empty and full packs, CoW
+    and drafts."""
+    cfg, model, params = gpt
+    before = trace_counts().get("serving_step", 0)
+    eng = ServingEngine(model, params, slots=3, max_len=MAX_LEN,
+                        prefill_chunk=CHUNK, block_size=8, spec_depth=2)
+    for _ in range(2):          # the second round hits the prefix cache
+        _packed_traffic(eng, cfg)
+        assert trace_counts().get("serving_step", 0) - before == 1
+        assert eng.step_executables() == 1
 
 
 def test_sample_path_counter_follows_the_live_knobs(gpt):

@@ -1,0 +1,12 @@
+"""Device self time per engine iteration under ``hetu.linear_scan`` —
+the prefill pack's chunk scan over the slots'
+states, all lightning layers (``longctx.ms_per_step``: the scope anywhere in an
+instruction's path)."""
+NAME, UNIT = "step_linear_scan_ms.longctx", "ms"
+LAYER = "lightning attention (nn/parallel.py, ops/linear_attention.py)"
+MOVES = "serve_tokens_per_s"
+
+
+def read(run):
+    from benchmark import longctx
+    return longctx.ms_per_step(run, "hetu.linear_scan")

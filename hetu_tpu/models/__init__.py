@@ -1,6 +1,7 @@
 """Model zoo: GPT-2, Llama, Command A+ (cohere2_moe) and latent-attention
-(MLA) expert decoders (``mla_moe``: loaded on first use, so that the
-cells that never build one do not pay for its import).
+(MLA) expert decoders and MiniCPM-SALA (``mla_moe``, ``minicpm_sala``:
+loaded on first use, so that the cells that never build one do not pay
+for its import).
 
 Parity targets: ``python/hetu/models/gpt`` and
 ``python/hetu/models/llama/llama_model.py`` (LlamaModel :385,
@@ -19,7 +20,9 @@ from hetu_tpu.models.vision import (
 from hetu_tpu.models.generation import generate, decode, init_kv_caches
 
 _LAZY = {"MLAMoEConfig": "hetu_tpu.models.mla_moe",
-         "MLAMoEForCausalLM": "hetu_tpu.models.mla_moe"}
+         "MLAMoEForCausalLM": "hetu_tpu.models.mla_moe",
+         "MiniCPMSALAConfig": "hetu_tpu.models.minicpm_sala",
+         "MiniCPMSALAForCausalLM": "hetu_tpu.models.minicpm_sala"}
 
 
 def __getattr__(name):
@@ -32,4 +35,5 @@ def __getattr__(name):
 __all__ = ["GPTConfig", "GPTLMHeadModel", "LlamaConfig", "BertConfig", "BertModel", "CNNConfig", "SimpleCNN", "MLPClassifier", "RNNConfig", "SimpleRNN", "LlamaLMHeadModel",
            "Cohere2MoEConfig", "Cohere2MoEForCausalLM",
            "MLAMoEConfig", "MLAMoEForCausalLM",
+           "MiniCPMSALAConfig", "MiniCPMSALAForCausalLM",
            "generate", "decode", "init_kv_caches"]

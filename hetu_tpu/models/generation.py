@@ -64,6 +64,11 @@ def init_kv_caches(model, batch: int, max_len: int, dtype=jnp.float32):
     reference's inference-side weight/state compression applied to the
     decode bottleneck (the per-step cache read is pure HBM bandwidth;
     int8 halves it vs bf16 and quarters it vs fp32)."""
+    if hasattr(model.blocks, "init_paged_caches"):
+        from hetu_tpu.nn.parallel import SlotStateNotSupported
+        raise SlotStateNotSupported(
+            "the dense cache (generate, the draft model): this model "
+            "caches pages for some layers and a slot's state for others")
     attn = model.blocks.block.attn
     lead = (model.blocks.num_layers, batch, max_len)
     # the model's attention says what a token's cache leaves are
@@ -85,7 +90,7 @@ def init_kv_caches(model, batch: int, max_len: int, dtype=jnp.float32):
 
 
 def init_paged_caches(model, n_blocks: int, block_size: int,
-                      dtype=jnp.float32, sharding=None):
+                      dtype=jnp.float32, sharding=None, slots: int = 0):
     """The block-paged arena: :func:`init_kv_caches` leaves with
     (batch, max_len) := (n_blocks, block_size) and the trailing
     ``(hkv, d)`` — ``(hkv, 1)`` for int8 scales — merged into ONE minor
@@ -94,7 +99,15 @@ def init_paged_caches(model, n_blocks: int, block_size: int,
     make XLA pick a blocks-minor layout the paged kernel cannot take a
     page from. Allocated in the stored shape and, where ``sharding``
     is given, in place on it — a reshape or a ``device_put`` of finished
-    zeros would hold the arena twice on the device."""
+    zeros would hold the arena twice on the device.
+
+    A model whose layers cache different things says so itself
+    (``model.blocks.init_paged_caches``: paged leaves over the layers
+    that have keys, and a state per SLOT over those that have none —
+    ``slots`` is for that leaf)."""
+    own = getattr(model.blocks, "init_paged_caches", None)
+    if own is not None:
+        return own(n_blocks, block_size, dtype, slots, sharding)
     leaves = jax.eval_shape(
         lambda: init_kv_caches(model, n_blocks, block_size, dtype))
     return tuple(

@@ -1268,6 +1268,544 @@ class LatentAttention(Module):
         return self._output(params, o), (buf,)
 
 
+class SlotStateNotSupported(NotImplementedError):
+    """A feature asked for a cache made of token rows in pages alone of
+    a model that also keeps a RECURRENT STATE per slot
+    (:class:`LightningAttention`) or picks its pages from a
+    compressed-key leaf (:class:`BlockSparseAttention`): the prefix
+    cache, preemption and spill, the fleet's KV export and replication,
+    the verify lane (a rejected draft would need the state rolled
+    back), the int8 arena, ``long_max_len``, the dense ``generate``
+    cache."""
+
+
+def _gain(params, name, x, eps, dtype):
+    """RMSNorm over the last dim with the learned gain ``name``."""
+    from hetu_tpu.ops.normalization import rms_norm
+    return rms_norm(x.astype(jnp.float32), params[name], eps).astype(dtype)
+
+
+def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack):
+    """The rows of either cached lane, flat: ``(u (N, E), pos (N,),
+    valid (N,), tables (N, W), slot (N,) or None)`` — the decode rows
+    (row ``r`` is slot ``r``: ``slot`` is ``None``) or a prefill pack
+    (``pack["slot"]`` names each token's slot)."""
+    if pack is not None:
+        if "slot" not in pack:
+            raise SlotStateNotSupported(
+                "a prefill pack without its tokens' slots "
+                "(pack['slot'], pack['slot_tables']): the engine hands "
+                "them to a model whose blocks have refuse_serving")
+        return x[0], positions[0], pack["valid"], block_tables, \
+            pack["slot"]
+    if x.shape[1] != 1:
+        raise SlotStateNotSupported(
+            "the verify lane (spec_depth > 0): a slot's rows beyond its "
+            "first would advance a state that a rejected draft cannot "
+            "roll back")
+    if block_tables is None or slot_mask is None:
+        raise SlotStateNotSupported(
+            "this attention decodes from the paged arena and the slot "
+            "states, per slot (block_tables= and slot_mask=); it has no "
+            "dense cache")
+    valid = slot_mask if row_mask is None else slot_mask & row_mask[:, 0]
+    return x[:, 0], positions[:, 0], valid, block_tables, None
+
+
+class BlockSparseAttention(Module):
+    """Block-sparse GQA over a compressed-key cache, NoPE, with RMSNorm
+    on every q and k head and an output gate (``mixer: minicpm4``).
+
+    Cached a token: ``k``, ``v`` after the norm; every ``kernel_stride``
+    tokens and kv head the mean of those tokens' keys (a *stride mean*).
+    A query at ``t`` attends, per kv group, the keys ``<= t`` of its
+    ``topk`` best blocks of ``block_size`` tokens (one block = one
+    page), chosen from the compressed keys (``ops.sparse_select`` has
+    the rule); then ``o <- o ⊙ sigmoid(u W_g)`` and ``W_o``.
+
+    Every cached path — the decode rows and a prefill pack alike — is
+    rows ``(slot table, position)``: a row's K, V land in the arena, a
+    row that completes a stride writes its mean, every row chooses its
+    pages (``hetu.sparse_select``) and reads them through the paged
+    attention call as a ``topk``-lane table of its own
+    (``hetu.sparse_attn``; a pack is no longer than the forced window,
+    so a token's in-pack keys are among its chosen pages, written
+    before they are read). The whole-sequence forward is the same rule
+    with a dense mask.
+
+    The arena's leaves (:meth:`init_leaves`): K and V ``(layers,
+    n_blocks, hkv * block_size, d)`` — a page holds ONE kv head,
+    head-minor within its block, so the read streams the chosen head's
+    bytes alone — and the stride means ``(layers, n_blocks, block_size
+    // stride * hkv * d)``.
+    """
+
+    #: sizes of the cached read: pages a grid step of the paged call
+    #: joins, virtual slots a call (its tables ride SMEM), and pack
+    #: tokens a block of the selection's scores
+    PAGES_PER_STEP, ROWS_PER_CALL, SELECT_ROWS = 8, 512, 256
+
+    def __init__(self, embed_dim: int, num_heads: int, *,
+                 num_kv_heads: int, head_dim: int, block_size: int = 64,
+                 kernel_size: int = 32, kernel_stride: int = 16,
+                 topk: int = 64, init_blocks: int = 1,
+                 window_size: int = 2048, norm_eps: float = 1e-6,
+                 qk_gain: float = 1.0, init=None):
+        super().__init__()
+        from hetu_tpu.nn.module import constant_init
+        if kernel_size % kernel_stride or block_size % kernel_stride \
+                or window_size % block_size:
+            raise ValueError(
+                f"kernel_size {kernel_size} and block_size {block_size} "
+                f"must be multiples of kernel_stride {kernel_stride}, "
+                f"window_size {window_size} of block_size")
+        self.window_blocks = window_size // block_size
+        if init_blocks + self.window_blocks + 1 > topk:
+            raise ValueError(
+                f"topk {topk} is under the forced blocks: {init_blocks} "
+                f"first + {self.window_blocks} before the query's + its "
+                f"own")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.block_size = head_dim, block_size
+        self.kernel_size, self.kernel_stride = kernel_size, kernel_stride
+        self.topk, self.init_blocks = topk, init_blocks
+        self.window_size = window_size
+        self.norm_eps = norm_eps
+        self.min_window = None
+        self.scale = 1.0 / head_dim ** 0.5
+        init = init or normal_init(0.02)
+        self.q_proj = ColumnParallelLinear(
+            embed_dim, num_heads * head_dim, bias=False, init=init,
+            axis="heads", out_kind="hidden")
+        self.k_proj = ColumnParallelLinear(
+            embed_dim, num_kv_heads * head_dim, bias=False, init=init,
+            axis="kv_heads", out_kind="hidden")
+        self.v_proj = ColumnParallelLinear(
+            embed_dim, num_kv_heads * head_dim, bias=False, init=init,
+            axis="kv_heads", out_kind="hidden")
+        self.gate_proj = ColumnParallelLinear(
+            embed_dim, num_heads * head_dim, bias=False, init=init,
+            axis="heads", out_kind="hidden")
+        self.out_proj = RowParallelLinear(
+            num_heads * head_dim, embed_dim, bias=False, init=init,
+            axis="heads")
+        self.param("q_gain", (head_dim,), constant_init(qk_gain))
+        self.param("k_gain", (head_dim,), constant_init(qk_gain))
+
+    # -- the cache spec ----------------------------------------------------
+    @property
+    def per_block(self) -> int:
+        return self.block_size // self.kernel_stride
+
+    def kv_leaf_shapes(self) -> tuple:
+        """K and V rows per kv head, a token and layer (the stride
+        means are a leaf of their own, a row a stride:
+        :meth:`init_leaves`)."""
+        return ((self.num_kv_heads, self.head_dim),) * 2
+
+    def kv_needed_elements(self) -> int:
+        return 2 * self.num_kv_heads * self.head_dim
+
+    def row_bytes(self, itemsize: int) -> dict:
+        """Bytes a token holds in one layer, by leaf."""
+        row = self.num_kv_heads * self.head_dim * itemsize
+        return {"k": row, "v": row,
+                "compressed_k": row // self.kernel_stride}
+
+    def init_leaves(self, layers: int, n_blocks: int, block_size: int,
+                    dtype, sharding=None) -> tuple:
+        if block_size != self.block_size:
+            raise ValueError(
+                f"one page is one selection block: the arena's "
+                f"block_size {block_size} must be the model's "
+                f"{self.block_size}")
+        if dtype == jnp.int8:
+            raise SlotStateNotSupported(
+                "the int8 arena: the compressed keys are means of the "
+                "stored keys and a page holds one kv head")
+        hkv, d = self.num_kv_heads, self.head_dim
+        page = (layers, n_blocks, hkv * block_size, d)
+        return (jnp.zeros(page, dtype, device=sharding),
+                jnp.zeros(page, dtype, device=sharding),
+                jnp.zeros((layers, n_blocks, self.per_block * hkv * d),
+                          dtype, device=sharding))
+
+    # -- projections ---------------------------------------------------------
+    def _qkv(self, params, u):
+        dt = self.compute_dtype()
+        lead = u.shape[:-1]
+        q = self.q_proj(params["q_proj"], u).reshape(
+            lead + (self.num_heads, self.head_dim))
+        k = self.k_proj(params["k_proj"], u).reshape(
+            lead + (self.num_kv_heads, self.head_dim))
+        v = self.v_proj(params["v_proj"], u).reshape(
+            lead + (self.num_kv_heads, self.head_dim))
+        return (_gain(params, "q_gain", q, self.norm_eps, dt),
+                _gain(params, "k_gain", k, self.norm_eps, dt), v)
+
+    def _output(self, params, o, u):
+        gate = jax.nn.sigmoid(self.gate_proj(params["gate_proj"], u)
+                              .astype(jnp.float32))
+        o = (o.astype(jnp.float32) * gate).astype(self.compute_dtype())
+        return self.out_proj(params["out_proj"], o)
+
+    def _scores(self, q, kbar, pos):
+        """Window scores ``(N, hkv, J)`` of rows that read ``kbar``."""
+        from hetu_tpu.ops.sparse_select import window_scores
+        return window_scores(
+            q.reshape(q.shape[0], self.num_kv_heads, -1, self.head_dim),
+            kbar, pos, stride=self.kernel_stride, kernel=self.kernel_size,
+            scale=self.scale)
+
+    def _choose(self, s, pos):
+        from hetu_tpu.ops.sparse_select import choose_blocks
+        return choose_blocks(
+            s, pos, block_size=self.block_size, stride=self.kernel_stride,
+            kernel=self.kernel_size, topk=self.topk,
+            init_blocks=self.init_blocks,
+            window_blocks=self.window_blocks)
+
+    # -- the whole-sequence forward -------------------------------------------
+    def __call__(self, params, x, *, positions=None, segment_ids=None,
+                 attn_impl: str = "auto", kv_cache=None, slot_mask=None,
+                 block_tables=None, row_mask=None, attn_kernel="reference",
+                 pack=None, return_kv: bool = False):
+        del attn_impl                    # the rule has no flash form
+        if kv_cache is not None:
+            return self._cached(params, x, kv_cache, positions=positions,
+                                slot_mask=slot_mask,
+                                block_tables=block_tables,
+                                row_mask=row_mask, attn_kernel=attn_kernel,
+                                pack=pack)
+        if return_kv or segment_ids is not None:
+            raise SlotStateNotSupported(
+                "return_kv (the CP-prefill lane) and packed documents: "
+                "the whole-sequence forward of the block-sparse "
+                "attention is one document a row, from position 0")
+        from hetu_tpu.ops.sparse_select import compressed_keys
+        q, k, v = self._qkv(params, x)
+        T = x.shape[1]
+        bs, st = self.block_size, self.kernel_stride
+        Tp = -(-T // bs) * bs
+        pos = jnp.arange(T, dtype=jnp.int32)
+
+        def one(q, k, v):
+            kp = jnp.pad(k.astype(jnp.float32),
+                         ((0, Tp - T), (0, 0), (0, 0)))
+            cmean = kp.reshape((Tp // st, st) + kp.shape[1:]).mean(1)
+            kbar = compressed_keys(cmean.astype(k.dtype),
+                                   self.kernel_size // st)
+            ids, _ = self._choose(self._scores(q, kbar, pos), pos)
+            chosen = jnp.any(ids[..., None] == jnp.arange(Tp // bs),
+                             axis=2)                       # (T, hkv, W)
+            seen = jnp.repeat(chosen, bs, axis=-1)[..., :T] \
+                & (pos[None, :] <= pos[:, None])[:, None, :]
+            qg = q.reshape(T, self.num_kv_heads, -1, self.head_dim)
+            sc = jnp.einsum("tkgd,jkd->tkgj", qg, k,
+                            preferred_element_type=jnp.float32) * self.scale
+            p = jax.nn.softmax(
+                jnp.where(seen[:, :, None, :], sc, -jnp.inf), axis=-1)
+            o = jnp.einsum("tkgj,jkd->tkgd", p.astype(v.dtype), v,
+                           preferred_element_type=jnp.float32)
+            return o.reshape(T, -1)
+
+        return self._output(params, jax.vmap(one)(q, k, v), x)
+
+    # -- the cached lanes ----------------------------------------------------
+    def _cached(self, params, x, kv_cache, *, positions, slot_mask,
+                block_tables, row_mask, attn_kernel, pack):
+        """Either cached lane (the class docstring). Returns ``(out,
+        (k, v, means), stats)``: ``stats`` is ``(chosen, visible)``
+        pages summed over the live rows and kv heads."""
+        from hetu_tpu.ops import sparse_select as ss
+        (k_buf, v_buf, c_buf), layer = kv_cache
+        layer = jnp.asarray(layer, jnp.int32)
+        u, pos, valid, tables, slot = _cached_rows(
+            x, positions, slot_mask, block_tables, row_mask, pack)
+        N = u.shape[0]
+        hkv, d, bs = self.num_kv_heads, self.head_dim, self.block_size
+        st, per = self.kernel_stride, self.per_block
+        L, n_blk = k_buf.shape[:2]
+        q, k, v = self._qkv(params, u)
+
+        blk = jnp.take_along_axis(tables, (pos // bs)[:, None],
+                                  axis=1)[:, 0]
+        heads = jnp.arange(hkv, dtype=jnp.int32)[None, :] * bs
+        base = (blk * (hkv * bs) + pos % bs)[:, None] + heads   # (N, hkv)
+        gone = n_blk * hkv * bs
+        with jax.named_scope("hetu.kv_arena"):
+            rows = jnp.where(valid[:, None], base, gone).reshape(-1)
+            k_buf = _scatter_layer_rows(k_buf, layer, rows, k)
+            v_buf = _scatter_layer_rows(v_buf, layer, rows, v)
+            # a row that completes a stride writes the stride's mean,
+            # from the arena's own rows (the stride may have begun in
+            # an earlier pack, or a token at a time)
+            back = jnp.arange(st - 1, -1, -1, dtype=jnp.int32)
+            flat_k = k_buf.reshape(L, gone, d)
+            seg = flat_k[layer, jnp.clip(
+                base[:, :, None] - back[None, None, :], 0, gone - 1)]
+            mean = jnp.mean(seg.astype(jnp.float32), axis=2)    # (N,hkv,d)
+            done = valid & (pos % st == st - 1)
+            c_rows = jnp.where(done, blk * per + (pos % bs) // st,
+                               n_blk * per)
+            c_buf = _scatter_layer_rows(
+                c_buf.reshape(L, n_blk, per, hkv * d), layer, c_rows,
+                mean.reshape(N, hkv * d)).reshape(c_buf.shape)
+
+        with jax.named_scope("hetu.sparse_select"):
+            ratio = self.kernel_size // st
+            W = tables.shape[1]
+
+            def means_of(tbl):            # (..., W) -> (..., J, hkv, d)
+                got = c_buf[layer, tbl]
+                return got.reshape(tbl.shape[:-1] + (W * per, hkv, d))
+
+            if slot is None:
+                s = self._scores(
+                    q, ss.compressed_keys(means_of(tables), ratio), pos)
+            else:
+                s = self._pack_scores(
+                    q, ss.compressed_keys(means_of(pack["slot_tables"]),
+                                          ratio), slot, pos, valid)
+            ids, n = self._choose(s, pos)
+            vt, voff = ss.virtual_tables(ids, n, tables, pos,
+                                         block_size=bs)
+            own = pos // bs + 1
+            stats = jnp.stack([
+                jnp.sum(jnp.where(valid, n, 0)),
+                jnp.sum(jnp.where(valid, own, 0))]).astype(jnp.int32) * hkv
+
+        with jax.named_scope("hetu.sparse_attn"):
+            o, k_buf, v_buf = self._read(
+                q, k_buf, v_buf, layer, vt, voff, jnp.repeat(valid, hkv),
+                attn_kernel)
+        out = self._output(params, o.reshape(N, -1), u)
+        out = out[None] if pack is not None else out[:, None]
+        return out, (k_buf, v_buf, c_buf), stats
+
+    def _pack_scores(self, q, kbar, slot, pos, valid):
+        """Window scores of a pack's tokens, each against ITS slot's
+        compressed keys ``kbar (S, J, hkv, d)``: blocks of
+        ``SELECT_ROWS`` tokens, and within a block one pass a slot that
+        has a token in it (a pack's runs are contiguous: one, seldom
+        two)."""
+        C = q.shape[0]
+        B = min(self.SELECT_ROWS, C)
+        pad = -C % B
+        if pad:
+            q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+            slot, pos = jnp.pad(slot, (0, pad)), jnp.pad(pos, (0, pad))
+            valid = jnp.pad(valid, (0, pad))
+        S, J = kbar.shape[:2]
+
+        def block(args):
+            qb, sb, pb, vb = args
+
+            def per_slot(i, acc):
+                mine = vb & (sb == i)
+                return jax.lax.cond(
+                    jnp.any(mine),
+                    lambda a: jnp.where(
+                        mine[:, None, None],
+                        self._scores(qb, jax.lax.dynamic_index_in_dim(
+                            kbar, i, 0, keepdims=False), pb), a),
+                    lambda a: a, acc)
+
+            return jax.lax.fori_loop(
+                0, S, per_slot,
+                jnp.zeros((B, self.num_kv_heads, J), jnp.float32))
+
+        s = jax.lax.map(block, tuple(
+            a.reshape((-1, B) + a.shape[1:])
+            for a in (q, slot, pos, valid)))
+        return s.reshape((-1,) + s.shape[2:])[:C]
+
+    def _read(self, q, k_buf, v_buf, layer, vt, voff, live, attn_kernel):
+        """The chosen pages' attention: one virtual slot a (row, kv
+        head), ``G`` query heads over the head's own pages — the paged
+        call, ``ROWS_PER_CALL`` virtual slots at a time (their tables
+        ride SMEM). Returns ``(o, k_buf, v_buf)``: the leaves are the
+        CARRY of the loop over the calls and come back as they went in
+        — closed over, the loop would read a copy of each."""
+        from hetu_tpu.ops.paged_pallas import (
+            paged_attention_auto, paged_attention_reference,
+        )
+        hkv, d, bs = self.num_kv_heads, self.head_dim, self.block_size
+        L, n_blk = k_buf.shape[:2]
+        M = vt.shape[0]
+        qv = q.reshape(M, 1, self.num_heads // hkv, d)
+        pages = (L, n_blk * hkv, bs, d)
+
+        def call(kp, vp, qg, tg, og, lg):
+            if attn_kernel == "paged":
+                return paged_attention_auto(
+                    qg, kp, vp, tg, og, layer=layer, live=lg,
+                    scale=self.scale, pages_per_step=step)
+            return paged_attention_reference(
+                qg, _at_layer(kp, layer), _at_layer(vp, layer), tg, og,
+                scale=self.scale)
+
+        # one more chunk of lanes than any row can fill: the paged call's
+        # work list is then never FULL. The kernel's pipeline looks one
+        # pair ahead; past a full list that is past the list itself, a
+        # page of nowhere, and the chip halts (PERF.md section 6, PR 39:
+        # every row at 64 chosen pages fills it; no other cell's slots
+        # all stand in their last table chunk at once)
+        step = min(self.PAGES_PER_STEP, self.topk)
+        vt = jnp.pad(vt, ((0, 0), (0, step)))
+        R = self.ROWS_PER_CALL
+        if M <= R:
+            return call(k_buf.reshape(pages), v_buf.reshape(pages), qv,
+                        vt, voff, live), k_buf, v_buf
+        pad = -M % R
+        if pad:
+            qv = jnp.pad(qv, ((0, pad),) + ((0, 0),) * 3)
+            vt = jnp.pad(vt, ((0, pad), (0, 0)))
+            voff, live = jnp.pad(voff, (0, pad)), jnp.pad(live, (0, pad))
+
+        def body(kv, xs):
+            # the barrier keeps the leaves IN the loop's state: taken
+            # out as invariants they are read by the loop and written
+            # in place by the next layer, and XLA copies each first
+            o = call(*kv, *xs)
+            return jax.lax.optimization_barrier(kv), o
+
+        (kp, vp), o = jax.lax.scan(
+            body, (k_buf.reshape(pages), v_buf.reshape(pages)), tuple(
+                a.reshape((-1, R) + a.shape[1:])
+                for a in (qv, vt, voff, live)))
+        return o.reshape((-1,) + o.shape[2:])[:M], \
+            kp.reshape(k_buf.shape), vp.reshape(v_buf.shape)
+
+
+class LightningAttention(Module):
+    """Linear attention with a per-head decay over a per-slot recurrent
+    state (``mixer: lightning-attn``): ``q = RoPE(RMSNorm(u W_q))``,
+    ``k`` likewise (split-half pairs over the whole head), ``v = u
+    W_v``; per head a float32 state ``S_t = e^{-s_h} S_{t-1} + k_t
+    v_t^T`` and ``o_t = S_t^T q_t * scale``; then RMSNorm over the
+    joined heads, ``⊙ sigmoid(u W_g)`` and ``W_o``
+    (``ops.linear_attention``).
+
+    What is cached is a SLOT's, not a token's: ONE leaf ``(layers,
+    slots, H, dk, dv)`` float32 (:meth:`init_leaves`), whatever the
+    context. The decode rows advance their slot's state by a token
+    (``hetu.linear_update``); a prefill pack's tokens advance theirs in
+    blocks (``hetu.linear_scan``), a slot whose run starts at position
+    0 from zeros. No page is ever read or written."""
+
+    #: pack tokens a block of the chunk scan
+    SCAN_BLOCK = 256
+
+    def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
+                 rope_theta: float = 10000.0, max_positions: int = 4096,
+                 norm_eps: float = 1e-6, qk_gain: float = 1.0,
+                 init=None):
+        super().__init__()
+        from hetu_tpu.nn.module import constant_init, ones_init
+        self.num_heads = self.num_kv_heads = num_heads
+        self.head_dim = head_dim
+        self.norm_eps = norm_eps
+        self.min_window = None
+        self.scale = 1.0 / head_dim ** 0.5
+        init = init or normal_init(0.02)
+        inner = num_heads * head_dim
+        for name in ("q_proj", "k_proj", "v_proj", "gate_proj"):
+            setattr(self, name, ColumnParallelLinear(
+                embed_dim, inner, bias=False, init=init, axis="heads",
+                out_kind="hidden"))
+        self.out_proj = RowParallelLinear(inner, embed_dim, bias=False,
+                                          init=init, axis="heads")
+        self.param("q_gain", (head_dim,), constant_init(qk_gain))
+        self.param("k_gain", (head_dim,), constant_init(qk_gain))
+        self.param("o_gain", (inner,), ones_init())
+        self._rope = rope_frequencies(head_dim, max_positions,
+                                      theta=rope_theta)
+
+    def kv_leaf_shapes(self) -> tuple:
+        """No leaf a token: the state is a slot's."""
+        return ()
+
+    def state_bytes(self) -> int:
+        """Bytes a slot's state holds in one layer."""
+        return self.num_heads * self.head_dim * self.head_dim * 4
+
+    def init_leaves(self, layers: int, slots: int, sharding=None) -> tuple:
+        return (jnp.zeros((layers, slots, self.num_heads, self.head_dim,
+                           self.head_dim), jnp.float32, device=sharding),)
+
+    def _slopes(self):
+        from hetu_tpu.ops.linear_attention import decay_slopes
+        return decay_slopes(self.num_heads)
+
+    def _qkv(self, params, u, positions):
+        """``u (b, s, E)``, ``positions (b, s)``."""
+        dt = self.compute_dtype()
+        shape = u.shape[:-1] + (self.num_heads, self.head_dim)
+        cos, sin = self._rope
+        q = _gain(params, "q_gain", self.q_proj(params["q_proj"], u)
+                  .reshape(shape), self.norm_eps, dt)
+        k = _gain(params, "k_gain", self.k_proj(params["k_proj"], u)
+                  .reshape(shape), self.norm_eps, dt)
+        v = self.v_proj(params["v_proj"], u).reshape(shape)
+        return (apply_rotary(q, cos, sin, positions=positions),
+                apply_rotary(k, cos, sin, positions=positions), v)
+
+    def _output(self, params, o, u):
+        """``o (..., H, dv)`` float32."""
+        o = _gain(params, "o_gain", o.reshape(o.shape[:-2] + (-1,)),
+                  self.norm_eps, jnp.float32)
+        gate = jax.nn.sigmoid(self.gate_proj(params["gate_proj"], u)
+                              .astype(jnp.float32))
+        return self.out_proj(params["out_proj"],
+                             (o * gate).astype(self.compute_dtype()))
+
+    def __call__(self, params, x, *, positions=None, segment_ids=None,
+                 attn_impl: str = "auto", kv_cache=None, slot_mask=None,
+                 block_tables=None, row_mask=None, attn_kernel="reference",
+                 pack=None, return_kv: bool = False):
+        del attn_impl, attn_kernel       # no attention kernel here
+        from hetu_tpu.ops import linear_attention as la
+        b, s, _ = x.shape
+        if kv_cache is None:
+            if return_kv or segment_ids is not None:
+                raise SlotStateNotSupported(
+                    "return_kv (the CP-prefill lane) and packed "
+                    "documents: a linear attention has no (k, v) to "
+                    "hand out, and its whole-sequence forward is one "
+                    "document a row")
+            if positions is None:
+                positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+            q, k, v = self._qkv(params, x, positions)
+            o = jax.vmap(lambda q, k, v: la.linear_recurrence(
+                q, k, v, self._slopes(), scale=self.scale)[0])(q, k, v)
+            return self._output(params, o, x)
+        (buf,), layer = kv_cache
+        _, pos, valid, _, slot = _cached_rows(
+            x, positions, slot_mask, block_tables, row_mask, pack)
+        q, k, v = self._qkv(params, x, positions)
+        # (the state's read out of its leaf and its write back are the
+        # scope's: they are most of what the update moves)
+        if slot is None:
+            with jax.named_scope("hetu.linear_update"):
+                o, state = la.linear_update(
+                    q[:, 0], k[:, 0], v[:, 0], _at_layer(buf, layer),
+                    valid, self._slopes(), scale=self.scale)
+                buf = jax.lax.dynamic_update_index_in_dim(buf, state,
+                                                          layer, 0)
+            o = o[:, None]
+        else:
+            with jax.named_scope("hetu.linear_scan"):
+                o, state = la.linear_scan(
+                    q[0], k[0], v[0], _at_layer(buf, layer), slot, pos,
+                    valid, self._slopes(), scale=self.scale,
+                    block=self.SCAN_BLOCK)
+                buf = jax.lax.dynamic_update_index_in_dim(buf, state,
+                                                          layer, 0)
+            o = o[None]
+        return self._output(params, o, x), (buf,)
+
+
 def remat_policy(name: str):
     """Map a Strategy remat/offload name to a ``jax.checkpoint`` policy.
 

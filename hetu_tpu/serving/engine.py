@@ -267,6 +267,11 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "= what the model's attention has to keep (K and V of every "
             "kv head; ONE latent row under MLA — a row padded to a lane "
             "tile stores more than it needs)"),
+        kv_state=reg.gauge(
+            "kv_state_bytes",
+            "bytes of recurrent state one SLOT holds beside the arena "
+            "(kind=slot: all the layers that keep one), whatever its "
+            "context — 0 series on a model that keeps none"),
         window_dead=reg.gauge(
             "kv_window_dead_blocks",
             "blocks held by decoding slots that lie wholly below the "
@@ -316,12 +321,12 @@ class ServingEngine:
                  hbm_budget_bytes: Optional[float] = None,
                  block_size: Optional[int] = None,
                  kv_blocks: Optional[int] = None,
-                 prefix_cache: bool = True,
+                 prefix_cache: Optional[bool] = None,
                  long_max_len: Optional[int] = None,
                  spec_depth: int = 0, draft: str = "ngram",
                  draft_ngram: int = 3,
                  draft_model=None, draft_params=None,
-                 preempt: bool = True,
+                 preempt: Optional[bool] = None,
                  spill_host_budget_bytes: Optional[float] = None,
                  spill_peer=None,
                  class_weights: Optional[dict] = None,
@@ -372,6 +377,33 @@ class ServingEngine:
                     raise LatentKVNotSupported(
                         f"{what} is not available over a latent KV "
                         f"arena")
+        # a model that keeps a recurrent state per slot beside the
+        # arena (``model.blocks.refuse_serving``): what assumes that a
+        # request's cache is token rows in pages alone refuses here, by
+        # name. ``prefix_cache`` / ``preempt`` left at None are on for
+        # every other model and off for such a one; asked for, they
+        # refuse like the rest
+        refuse = getattr(model.blocks, "refuse_serving", None)
+        self._slot_state = refuse is not None
+        if refuse is not None:
+            refuse(**{
+                "prefix_cache": bool(prefix_cache),
+                "preempt (preemption and spill)": bool(preempt),
+                "spill_host_budget_bytes (the spill arena)":
+                    spill_host_budget_bytes is not None,
+                "long_max_len (the CP-prefill lane)":
+                    long_max_len is not None,
+                "spec_depth (the verify lane)": bool(spec_depth),
+                "draft_model": draft_model is not None,
+                "cache_dtype=int8 (the int8 arena)":
+                    cache_dtype == jnp.int8,
+                "w8a8": w8a8 not in (None, False, "off"),
+                "tenancy (LoRA)": bool(tenancy),
+                "a tp plan": plan is not None and plan.strategy.tp > 1})
+        if prefix_cache is None:
+            prefix_cache = not self._slot_state
+        if preempt is None:
+            preempt = not self._slot_state
         self._cp = plan.strategy.cp if plan is not None else 1
         self._cp_zigzag = (
             plan is not None and self._cp > 1
@@ -658,9 +690,18 @@ class ServingEngine:
         # token's arena history through its block table; "reference" is
         # the historical per-token paged lane. "flash_pallas" forces the
         # Pallas intra kernel (interpret on CPU — quick-tier coverage).
+        wants_slots = self._slot_state
         if prefill_attn == "auto":
+            # (a model that advances a slot's state through a pack
+            # needs the pack as ONE row with its tokens' slots)
             prefill_attn = "flash" if jax.default_backend() == "tpu" \
-                else "reference"
+                or wants_slots else "reference"
+        if wants_slots and prefill_attn == "reference":
+            from hetu_tpu.nn.parallel import SlotStateNotSupported
+            raise SlotStateNotSupported(
+                "prefill_attn='reference' (a batch row a pack token) is "
+                "not available over a per-slot recurrent state: a "
+                "pack's tokens advance their slot's state in order")
         if prefill_attn not in ("reference", "flash", "flash_pallas"):
             raise ValueError(
                 f"prefill_attn must be auto|reference|flash|"
@@ -688,7 +729,7 @@ class ServingEngine:
         self._hist_tiles = history_tile_count(
             self.prefill_chunk, self._hist_tile, self._fin_cap) \
             if prefill_attn != "reference" \
-            and self.attn_kernel == "paged" else 0
+            and self.attn_kernel == "paged" and not wants_slots else 0
         # the decode rows' paged call walks the live (slot, chunk)
         # pairs (ops.paged_pallas.decode_work_list): a chunk's span in
         # positions and a table's chunks, for
@@ -728,13 +769,23 @@ class ServingEngine:
         self._w8a8_wq = self._prequantize_decode_weights()
 
         self._m = _bind_metrics(telemetry.get_registry())
-        stored = sum(int(np.prod(c.shape[3:])) * c.dtype.itemsize
-                     for c in self.pool.caches)
-        self._m.kv_row.set(stored, kind="stored")
-        self._m.kv_row.set(
-            stored if self.pool.quantized
-            else _attn_mod.kv_needed_elements()
-            * self.pool.caches[0].dtype.itemsize, kind="needed")
+        own_bytes = getattr(model.blocks, "cache_bytes", None)
+        if own_bytes is not None:
+            # leaves of different kinds: a token's bytes by leaf (all
+            # of the layers that keep it), and a slot's state
+            got = own_bytes(self.pool.caches[0].dtype.itemsize)
+            for kind, n in got["row"].items():
+                self._m.kv_row.set(n, kind=kind)
+            for kind, n in got["state"].items():
+                self._m.kv_state.set(n, kind=kind)
+        else:
+            stored = sum(int(np.prod(c.shape[3:])) * c.dtype.itemsize
+                         for c in self.pool.caches)
+            self._m.kv_row.set(stored, kind="stored")
+            self._m.kv_row.set(
+                stored if self.pool.quantized
+                else _attn_mod.kv_needed_elements()
+                * self.pool.caches[0].dtype.itemsize, kind="needed")
         # the smallest window of the model's layers (None: no layer has
         # one) — for the kv_window_dead_blocks gauge only
         ld = getattr(model.blocks, "layer_data", None) or {}
@@ -778,6 +829,13 @@ class ServingEngine:
                 return fn.lower(*sds).compile().as_text()
 
         device_scopes.register_step("serving_step", hlo_text)
+
+    def _refuse_slot_state(self, what: str) -> None:
+        """What hands a request's cache to another engine, or takes one
+        in, moves pages; a model that also keeps a state per slot
+        refuses it by name (``model.blocks.refuse_serving``)."""
+        if self._slot_state:
+            self.model.blocks.refuse_serving(**{what: True})
 
     def step_executables(self) -> int:
         """How many executables the ONE fused step holds (the jit's
@@ -837,6 +895,7 @@ class ServingEngine:
         kern = self.attn_kernel
         w8a8_mask = self._w8a8_mask
         flash_lane = self.prefill_attn != "reference"
+        wants_slots = self._slot_state
         pack_impl = self._pack_impl
         tile_rows = self._hist_tile
         # the draftsman's q rows: host-only draftsmen (and no
@@ -981,6 +1040,10 @@ class ServingEngine:
                     pack = {"segment_ids": pf["seg"][None, :],
                             "hist": pf["hist"], "valid": pf["valid"],
                             "impl": pack_impl}
+                    if wants_slots:
+                        # a model that keeps a state per slot: whose
+                        # each token is, and the slots' own tables
+                        pack["slot"], pack["slot_tables"] = pf["slot"], bt
                     if "tiles" in pf:
                         # (fields, tiles): row 0 is each tile's slot
                         pack["tiles"] = {
@@ -1405,6 +1468,8 @@ class ServingEngine:
         blocked on it forever would freeze whatever it holds — the
         router passes a small timeout and degrades to a fresh requeue
         (the pre-spill behavior) when salvage cannot be had."""
+        self._refuse_slot_state(
+            "evict_request (a request's KV handed to a peer)")
         got = self._step_lock.acquire(
             timeout=-1 if lock_timeout_s is None else lock_timeout_s)
         if not got:
@@ -1525,6 +1590,7 @@ class ServingEngine:
         flush all run step-locked), so no pin/unpin dance is needed.
         None on a whole-block miss or a wedged step (``lock_timeout_s``
         bounds the wait — a pull is best-effort, the puller prefills)."""
+        self._refuse_slot_state("export_prefix (the fleet's KV export)")
         if self.prefix_cache is None:
             return None
         got = self._step_lock.acquire(
@@ -1565,6 +1631,7 @@ class ServingEngine:
         rule: a weight push between export and import MUST degrade to
         a prefill, never silently serve old weights' KV), and degrades
         the same way when no blocks can be freed."""
+        self._refuse_slot_state("import_prefix (the fleet's KV import)")
         if self.prefix_cache is None or entry is None:
             return False
         if not entry.compatible_with(self.pool, self.weight_version):
@@ -1638,6 +1705,8 @@ class ServingEngine:
         closure installed by the KVBUDDY verb). ``sink=None`` stops the
         stream. The router (re)wires this whenever rendezvous buddy
         assignment changes."""
+        self._refuse_slot_state(
+            "configure_replication (decode-KV replication)")
         with self._lock:
             self._repl_sink = sink
             self._repl_origin = origin
@@ -1777,6 +1846,8 @@ class ServingEngine:
 
         Works both driven (no background loop: iterations run here)
         and with :meth:`start` running (this just waits)."""
+        self._refuse_slot_state(
+            "prefill_only (prefill/decode disaggregation)")
         req = self.submit(prompt, sampling, handoff=True,
                           traceparent=traceparent)
         if req.status == "rejected":

@@ -184,9 +184,12 @@ class KVPool:
         self.weight_version = 0
         # ``sharding``: where the engine's compiled steps keep the
         # arena (ServingEngine places it with its params)
+        # (a model that also keeps a state per slot gets ``slots`` of
+        # them beside the arena: generation.init_paged_caches)
         self.caches = init_paged_caches(model, self.n_blocks,
                                         self.block_size, cache_dtype,
-                                        sharding=sharding)
+                                        sharding=sharding,
+                                        slots=self.slots)
 
     @classmethod
     def sized_for(cls, model, *, hbm_budget_bytes: float, max_len: int,
@@ -218,7 +221,7 @@ class KVPool:
 
     @property
     def quantized(self) -> bool:
-        return len(self.caches) == 4
+        return self.cache_dtype == jnp.int8
 
     def nbytes(self) -> int:
         return sum(int(x.size) * x.dtype.itemsize for x in self.caches)

@@ -1,0 +1,375 @@
+"""CPU rehearsal of ``arch: minicpm_sala`` (``benchmark/archs/
+minicpm_sala.py``) under the ``serve_arch_ties`` runner: the model and
+its plain reference end to end at a tiny size through a manifest, a
+configuration and a mix of their own (new files HERE only), with and
+without ``--trace``; what ``BENCHMARK.json`` says of the cell and of the
+cells before it; the configuration against the catalog's row; and the
+arithmetic of ``benchmark/flops_minicpm_sala.py`` and
+``benchmark/longctx.py``."""
+
+import json
+import os
+import sys
+import time
+import types
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path.insert(0, ROOT)
+
+from benchmark import flops, flops_minicpm_sala as fs, harness  # noqa: E402
+from benchmark import longctx  # noqa: E402
+from benchmark.peaks import peaks_for  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import test_iteration_account as acc  # noqa: E402
+
+MANIFEST = os.path.join(HERE, "manifest_sala.json")
+CELL = "minicpm-sala-pp2.longdoc-32k-backlog"
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+COUNTED = {"sparse_chosen_share_pct.longctx", "engine_iter_ms.longctx",
+           "engine_host_ms.longctx", "setup_compile_s", "kv_used_peak_pct"}
+LONGCTX = [
+    "step_decode_ms", "step_prefill_ms", "step_sparse_select_ms",
+    "step_sparse_attn_ms", "step_linear_scan_ms", "step_linear_update_ms",
+    "sparse_attn_roofline_pct", "linear_scan_roofline_pct",
+    "linear_update_roofline_pct", "sparse_chosen_share_pct",
+    "step_state_copies_ms", "step_kv_arena_ms", "step_sample_ms",
+    "engine_host_ms", "engine_iter_ms"]
+#: read without a device plane: a counter, the window's iterations, the
+#: host's spans in the slice
+NO_DEVICE = {"sparse_chosen_share_pct", "engine_iter_ms", "engine_host_ms"}
+
+
+def _config():
+    with open(os.path.join(
+            ROOT, "benchmark/configs/minicpm-sala-pp2.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_serve_arch_sala_cell_end_to_end_at_tiny_size(trace):
+    import jax
+    out = harness.run_cell(
+        harness.load_manifest(MANIFEST), ROOT, "tiny.longctx",
+        seed=2**31 + 39, seconds=1.5, trace=trace, devices=jax.devices(),
+        on_chip=False, t_process=time.perf_counter())
+    assert not out["why_incorrect"]
+    line = out["line"]
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    ref = out["info"]["reference"]
+    assert ref["compared_positions"] > 0
+    assert ref["max_logit_gap"] <= 1e-3     # float32 on both sides
+    assert ref["near_ties_over_logit_tol"] == 0 and ref["limits"]
+    assert len(ref["compared_prompt_lens"]) == 8
+    # K and V pages of one kv head each, the stride means, the states:
+    # 2 sparse layers x 65 blocks, 3 lightning layers x 4 slots, float32
+    pages = 2 * 65 * (2 * 4) * 16 * 4
+    assert out["info"]["arena_bytes"] == \
+        2 * pages + 2 * 65 * (4 * 2 * 16) * 4 + 3 * 4 * 4 * 16 * 16 * 4
+    if not trace:
+        assert set(line["metrics"]) == {"serve_tokens_per_s", "setup_s"}
+        assert line["metrics"]["serve_tokens_per_s"]["value"] > 0
+    else:
+        # no device plane on the CPU: the metrics that read device
+        # scopes are left out, the counted ones are there
+        assert set(line["metrics"]) == COUNTED
+        share = line["metrics"]["sparse_chosen_share_pct.longctx"]["value"]
+        assert 20 < share < 100           # 4 of up to 14 pages chosen
+        assert line["device"]["busy_s"] == 0.0
+    json.dumps(line)
+
+
+@pytest.mark.parametrize("control", ["operands", "forced_only", "no_decay"])
+def test_a_planted_control_is_refused_through_the_harness(control):
+    """The three computations the limits must refuse (``reference.
+    CONTROL``), each planted in the reference's seat of a whole
+    ``harness.run_cell``: the program's tokens are then NOT that
+    computation's, and the run comes out ``correct: false`` by the
+    limits of ``archs/minicpm_sala.py``. (The tiny configuration draws
+    its weights at 0.16, the 0.02 of the published width scaled to 64
+    columns: at 0.02 the mixers move a tiny model's logits by less
+    than ``LOGIT_TOL``.)"""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.reference import minicpm_sala as reference
+    planted = {"operands": {"operands": jnp.float8_e4m3fn},
+               "forced_only": {"forced_only": True},
+               "no_decay": {"no_decay": True}}[control]
+    reference.CONTROL.update(planted)
+    try:
+        out = harness.run_cell(
+            harness.load_manifest(MANIFEST), ROOT, "tiny.longctx",
+            seed=2**31 + 39, seconds=1.5, trace=False,
+            devices=jax.devices(), on_chip=False,
+            t_process=time.perf_counter())
+    finally:
+        reference.CONTROL.clear()
+    assert out["line"]["correct"] is False and out["line"]["failed"] == 0
+    assert out["why_incorrect"]
+    assert "below the float32 reference's top logit" in \
+        out["why_incorrect"][0]
+    ref = out["info"]["reference"]
+    assert max(ref["max_logit_gap"],
+               ref["max_logit_gap_at_near_ties"]) > 0.1
+
+
+def test_manifest_names_what_the_longctx_cell_needs():
+    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    cell = m["workloads"][-1]
+    assert cell["name"] == CELL and cell["chips"] == 1
+    assert cell["config"] == m["configs"][-1]["name"] == "minicpm-sala-pp2"
+    assert m["configs"][-1]["reduced"] == ["num_hidden_layers",
+                                           "mixer_types"]
+    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
+    with open(os.path.join(ROOT, "benchmark/traffic",
+                           f"{cell['traffic']}.json")) as f:
+        mix = json.load(f)
+    assert mix["kind"] == "serve_arch_ties" and mix["schedule_seed"] == 39
+    assert mix["arrivals"] == {"process": "backlog", "count": 160}
+    assert (mix["ramp_s"], mix["drain_s"]) == (120, 0)
+    assert (mix["prompt_len"]["dist"], mix["prompt_len"]["value"]) == \
+        ("fixed", 32000)
+    assert (mix["output_len"]["dist"], mix["output_len"]["value"],
+            mix["output_len"]["max"]) == ("fixed", 256, 256)
+    mine = m["per_layer"][-len(LONGCTX):]
+    assert [x["name"] for x in mine] == [n + ".longctx" for n in LONGCTX]
+    rehearsed = {x["name"] for x in
+                 harness.load_manifest(MANIFEST)["per_layer"]}
+    for x in mine:
+        mod = harness.find_reader(ROOT, m, x["name"])
+        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
+            (x["name"], x["unit"], x["layer"], x["moves"])
+        assert x["moves"] == "serve_tokens_per_s"
+        assert x["workloads"] == [CELL] and x["name"] in rehearsed
+        assert x["source"] in ("device_trace", "host_clock")
+        if "roofline" in x["name"]:
+            assert x["unit"] == "%" and x["better"] == "higher"
+    for name in ("serve_tokens_per_s", "setup_compile_s",
+                 "kv_used_peak_pct"):
+        entry = next(x for x in m["end_to_end"] + m["per_layer"]
+                     if x["name"] == name)
+        assert entry["workloads"][-1] == CELL
+
+
+def test_the_cells_before_keep_their_places_in_the_manifest():
+    """The file's REAL order (``tests/conftest.py`` shows two older
+    tests another): new entries stand at the END of their lists — the
+    Kimi cell right before this cell wherever both are listed, PR 35's
+    fourteen entries together right before this PR's — and every
+    ``.backlogs`` account metric lists ALL ``serve_tokens_per_s`` cells,
+    this one included. ``later_entries_first`` only reorders."""
+    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    kimi = "kimi-vl-a3b-pp4.longdoc-backlog"
+    e2e = {x["name"]: x for x in m["end_to_end"]}
+    backlogs = e2e["serve_tokens_per_s"]["workloads"]
+    assert backlogs[-2:] == [kimi, CELL]
+    listed = [x for x in m["end_to_end"] + m["per_layer"]
+              if CELL in x.get("workloads", []) and x["workloads"] != [CELL]]
+    assert [x["name"] for x in listed] == [
+        "serve_tokens_per_s", "kv_used_peak_pct", "setup_compile_s"] + [
+        n for n in acc.ALL if n.endswith(".backlogs")]
+    for x in listed:
+        assert x["workloads"][-2:] == [kimi, CELL]
+    before = m["per_layer"][-len(LONGCTX) - len(acc.ALL):-len(LONGCTX)]
+    assert [x["name"] for x in before] == acc.ALL
+    for x in before:
+        assert x["workloads"] == (
+            e2e["gap_p95_ms"]["workloads"] if x["name"].endswith(".chat")
+            else backlogs)
+    assert [w["name"] for w in m["workloads"][:-1]] == [
+        "gpt2-small.pretrain", "gpt2-small.chat", "gpt2-large.backlog",
+        "command-a-plus-ep8.mixed-backlog", kimi]
+    sys.path.insert(0, os.path.dirname(HERE))
+    from conftest import later_entries_first
+    shown = later_entries_first(m)
+
+    def canon(x):
+        return json.dumps(dict(x, workloads=sorted(x.get("workloads", []))),
+                          sort_keys=True)
+    for kind in ("end_to_end", "per_layer"):
+        assert sorted(map(canon, shown[kind])) == \
+            sorted(map(canon, m[kind]))
+    assert [x["name"] for x in shown["per_layer"][-len(acc.ALL):]] \
+        == acc.ALL
+    assert {k: v for k, v in shown.items() if k not in (
+        "end_to_end", "per_layer")} == {k: v for k, v in m.items() if k
+                                        not in ("end_to_end", "per_layer")}
+
+
+def test_published_widths_are_in_the_sala_configuration():
+    c = _config()
+    rows = []
+    if os.path.exists(CATALOG):
+        with open(CATALOG) as f:
+            rows = [json.loads(x) for x in f if '"MiniCPM-SALA"' in x]
+    for row in rows:                # every key of the catalog's config
+        assert c["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            assert c[k] == v or k in c["reduced"], k
+        assert c["mixer_types"] == row["config"]["mixer_types"][0::2]
+    assert (c["hidden_size"], c["num_attention_heads"],
+            c["num_key_value_heads"], c["head_dim"], c["lightning_nh"],
+            c["lightning_head_dim"], c["intermediate_size"],
+            c["vocab_size"], c["tie_word_embeddings"], c["attn_use_rope"],
+            c["lightning_use_rope"], c["scale_emb"], c["scale_depth"],
+            c["dim_model_base"]) == (4096, 32, 2, 128, 32, 128, 16384,
+                                     73448, False, False, True, 12, 1.4,
+                                     256)
+    assert c["reduced"] == ["num_hidden_layers", "mixer_types"]
+    assert (c["num_hidden_layers"], len(c["mixer_types"]),
+            c["published"]["num_hidden_layers"]) == (16, 16, 32)
+    # the published ratio, 1 sparse to 3 lightning
+    assert fs.layers(c) == (4, 12)
+    assert fs.layers({"mixer_types": c["published"]["mixer_types"]}) \
+        == (8, 24)
+    a = c["assumed"]
+    assert (a["kernel_size"], a["kernel_stride"], a["block_size"],
+            a["topk"], a["init_blocks"], a["window_size"]) == \
+        (32, 16, 64, 64, 1, 2048)
+    s = c["serve"]
+    assert (s["max_len"], s["slots"], s["kv_blocks"], s["block_size"],
+            s["prefill_chunk"]) == (33280, 20, 10400, 64, 2048)
+    assert s["block_size"] == a["block_size"]       # a page is a block
+    assert s["prefill_chunk"] <= a["window_size"]   # in-pack keys forced
+    # parameters held here, in bf16, the arena and the states, against
+    # the chip's 16.91 GB
+    from benchmark.runners.serve_arch import load_arch
+    import jax
+    arch = load_arch(c["arch"])
+    model = arch.build(c)
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(
+        jax.eval_shape(model.init, jax.random.key(0))))
+    assert abs(n - c["sizes"]["held_parameters"]) < 1e6   # the gains
+    assert abs(n - 5.039e9) < 2e6
+    leaves = jax.eval_shape(lambda: model.blocks.init_paged_caches(
+        s["kv_blocks"], s["block_size"], jax.numpy.bfloat16, s["slots"]))
+    cache = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves)
+    assert cache == c["sizes"]["arena_bytes"] + c["sizes"]["state_bytes"]
+    assert arch.arena_row_elements(c) * 2 * 4 == \
+        c["sizes"]["cache_bytes_a_token"] == 4224
+    assert 0.78 <= (2 * n + cache) / 16.91e9 <= 0.80
+
+
+def test_flops_minicpm_sala_arithmetic():
+    c = _config()
+    peaks = peaks_for("TPU v5 lite")
+    assert fs.page_bytes(c) == 64 * 512
+    # 991,000 chosen pages an iteration: 32.5 GB, 39.6 ms at the
+    # bandwidth peak; 16 heads x 512 operations a key is 16 a byte,
+    # under the chip's 240 — bound by the bytes
+    call = fs.sparse_attn_call(c, 991000)
+    assert call["bytes"] == 991000 * 32768
+    assert call["flops"] == 991000 * 64 * 16 * 512
+    assert flops.roofline_seconds(call["flops"], call["bytes"], peaks) \
+        == pytest.approx(991000 * 32768 / 819e9)
+    assert fs.state_bytes(c) == 32 * 128 * 128 * 4 == 2097152
+    scan = fs.linear_scan_call(c, 2048)
+    assert scan["bytes"] == 2048 * 4096 * 10 + 2 * 2097152
+    assert scan["flops"] == 2048 * 32 * 4 * 128 * 128
+    upd = fs.linear_update_call(c, 16)
+    assert upd["bytes"] == 2 * 16 * 2097152
+    run = types.SimpleNamespace(config=c, peaks=peaks, trace=None,
+                                cell={"name": "none"}, records={})
+    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    # readers of device scopes return nothing without a device plane
+    for name in LONGCTX:
+        if name not in NO_DEVICE:
+            assert harness.find_reader(ROOT, m, name + ".longctx") \
+                .read(run) is None, name
+
+
+def test_longctx_counts_and_path_seconds(monkeypatch):
+    from hetu_tpu import telemetry
+    from hetu_tpu.telemetry.metrics import MetricRegistry
+    reg = MetricRegistry()
+    monkeypatch.setattr(telemetry, "get_registry", lambda: reg)
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    # two iterations in the window (16 and 17 rows, 2,048 and 1,280
+    # tokens), one before it (the ramp: 3 rows) that does not count
+    steps = [acc._ev("serve/step", ts, 0.4, iter=i, active=a,
+                     prefill_tokens=t, cpu_s=0.01, wait_cpu_s=0.001,
+                     lock_wait_s=0.0, frames=1, since_prev_s=0.001)
+             for i, (ts, a, t) in enumerate(
+                 [(9.0, 3, 2048), (10.0, 16, 2048), (10.5, 17, 1280)])]
+    tracer = types.SimpleNamespace(events=lambda: steps, epoch=100.0)
+    monkeypatch.setattr(telemetry, "get_tracer", lambda: tracer)
+    run = types.SimpleNamespace(records={"window": (110.0, 111.0)})
+    try:
+        assert longctx.counts(run) is None      # a program without them
+        assert longctx.chosen_share() is None
+        reg.counter("serving_decode_slot_steps_total").inc(160)
+        reg.counter("serving_tokens_total").inc(16384, kind="prompt")
+        pages = reg.counter("serving_sparse_pages_total")
+        for lane, chosen, visible in (("decode", 81920, 642560),
+                                      ("prefill", 7.6e6, 3.3e7)):
+            pages.inc(chosen, state="chosen", lane=lane)
+            pages.inc(visible, state="visible", lane=lane)
+        c = longctx.counts(run)
+        assert longctx.chosen_share() == 81920 / 642560
+        run.records = {}                        # no window: nothing
+        assert longctx.counts(run) is None
+    finally:
+        telemetry.enable(was)
+    # the process's pages a row (512 chosen, 4,016 visible) on the
+    # WINDOW's 16.5 rows an iteration
+    assert c["decode"] == {"units": 16.5, "chosen": 16.5 * 512,
+                           "visible": 16.5 * 4016}
+    assert c["prefill"]["units"] == 1664.0
+    assert c["prefill"]["chosen"] == 1664.0 * 7.6e6 / 16384
+    # a scope counts wherever it stands in an instruction's path
+    from hetu_tpu.telemetry.device_scopes import classify
+    scopes = {("serving_step", 0): {
+        "custom-call.1": classify(
+            "jit(step)/hetu.prefill_lane/hetu.sparse_attn/hetu.paged_attn/x"),
+        "fusion.2": classify("jit(step)/hetu.decode_lane/hetu.sparse_select/y"),
+        "fusion.3": classify("jit(step)/hetu.decode_lane/mul")}}
+    from benchmark import program_trace
+    monkeypatch.setattr(program_trace, "_registered_scopes", lambda: scopes)
+    run = types.SimpleNamespace(trace={"n_devices": 1, "op_seconds": {
+        "custom-call.1": 0.5, "fusion.2": 0.25, "fusion.3": 1.0}})
+    assert longctx.path_seconds(run, "hetu.sparse_attn") == 0.5
+    assert longctx.path_seconds(run, "hetu.sparse_select") == 0.25
+    assert longctx.path_seconds(run, "hetu.linear_scan") is None
+    run.trace = None
+    assert longctx.path_seconds(run, "hetu.sparse_attn") is None
+
+
+def test_state_copies_are_named_by_what_they_move(monkeypatch):
+    """``copy*`` instructions with a float32 result of whole layers of
+    every slot's state, outside the lightning scopes."""
+    from benchmark import program_trace
+    from hetu_tpu.telemetry.device_scopes import classify
+    c = _config()
+    scopes = {("serving_step", 0): {
+        "copy.9": classify("jit(step)/hetu.decode_lane/hetu.linear_update/c"),
+        "copy.7": classify("jit(step)/while/body/x")}}
+    monkeypatch.setattr(program_trace, "_registered_scopes", lambda: scopes)
+    monkeypatch.setattr(program_trace, "read", lambda run: {
+        "host": {"steps_in_slice": 4}})
+    text = "%{} = {}[{}]{{4,3,2,1,0}} copy({}[{}] %p)"
+
+    def op(name, dtype, dims):
+        return text.format(name, dtype, dims, dtype, dims)
+    ops = {"copy.5": (0.040, op("copy.5", "f32", "3,20,32,128,128")),
+           "copy.7": (0.020, op("copy.7", "f32", "20,3,32,128,128")),
+           # inside the scope that already counts it; the arena (bf16);
+           # a state of one slot; no copy
+           "copy.9": (1.0, op("copy.9", "f32", "3,20,32,128,128")),
+           "copy.11": (1.0, op("copy.11", "bf16", "4,10400,64,256")),
+           "copy.13": (1.0, op("copy.13", "f32", "32,128,128")),
+           "fusion.1": (1.0, op("fusion.1", "f32", "3,20,32,128,128"))}
+    run = types.SimpleNamespace(config=c, trace={
+        "n_devices": 1, "op_seconds": {k: v[0] for k, v in ops.items()},
+        "op_text": {k: v[1] for k, v in ops.items()}})
+    assert longctx.state_copies_ms_per_step(run) == \
+        pytest.approx(1e3 * 0.060 / 4)
+    run.config = {"n_embd": 1}                  # another architecture
+    assert longctx.state_copies_ms_per_step(run) is None
+    run.config, run.trace = c, None
+    assert longctx.state_copies_ms_per_step(run) is None

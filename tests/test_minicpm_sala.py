@@ -1,0 +1,432 @@
+"""MiniCPM-SALA on the CPU at a tiny size (ISSUE 39): the model against
+the plain reference; chunked prefill then decode through the arena and
+the slot states against the reference's ONE forward pass (logits); the
+chunked lightning form against the recurrence; the selection against a
+direct argsort; the refusals, by name; the paged call on a table that
+selects ALL pages (the other cells' path)."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.reference import minicpm_sala as reference  # noqa: E402
+from hetu_tpu.models import generation  # noqa: E402
+from hetu_tpu.models.minicpm_sala import (  # noqa: E402
+    MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
+)
+from hetu_tpu.nn.parallel import SlotStateNotSupported  # noqa: E402
+from hetu_tpu.ops import linear_attention as la  # noqa: E402
+from hetu_tpu.ops import sparse_select as ss  # noqa: E402
+
+
+def ref_config(cfg: MiniCPMSALAConfig) -> dict:
+    """``cfg`` under the published keys the reference reads."""
+    return dict(
+        mixer_types=list(cfg.mixer_types),
+        num_attention_heads=cfg.num_attention_heads,
+        num_key_value_heads=cfg.num_key_value_heads,
+        head_dim=cfg.head_dim, lightning_nh=cfg.lightning_nh,
+        lightning_head_dim=cfg.lightning_head_dim,
+        rms_norm_eps=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
+        scale_emb=cfg.scale_emb, scale_depth=cfg.scale_depth,
+        dim_model_base=cfg.dim_model_base, hidden_size=cfg.hidden_size,
+        published={"num_hidden_layers": cfg.published_depth},
+        assumed=dict(kernel_size=cfg.kernel_size,
+                     kernel_stride=cfg.kernel_stride,
+                     block_size=cfg.block_size, topk=cfg.topk,
+                     init_blocks=cfg.init_blocks,
+                     window_size=cfg.window_size))
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = MiniCPMSALAConfig.tiny()
+    model = MiniCPMSALAForCausalLM(cfg)
+    return cfg, model, model.init(jax.random.key(39))
+
+
+def test_runs_and_caches_count_their_own_layers(tiny):
+    cfg, model, params = tiny
+    assert cfg.runs() == [("minicpm4", 1), ("lightning-attn", 2),
+                          ("minicpm4", 1), ("lightning-attn", 1)]
+    assert (model.blocks.n_sparse, model.blocks.n_linear) == (2, 3)
+    k, v, c, s = generation.init_paged_caches(model, 9, 4, jnp.float32,
+                                              slots=3)
+    # a page holds one kv head (head-minor in its block); 4 stride
+    # means a page of 4 tokens at stride 1; a state a slot and layer
+    assert k.shape == v.shape == (2, 9, 2 * 4, 16)
+    assert c.shape == (2, 9, 4 * 2 * 16)
+    assert s.shape == (3, 3, 4, 16, 16) and s.dtype == jnp.float32
+    got = model.blocks.cache_bytes(2)
+    assert got["row"] == {"k": 2 * 64, "v": 2 * 64, "compressed_k": 2 * 64}
+    assert got["state"] == {"slot": 3 * 4 * 16 * 16 * 4}
+
+
+def test_model_matches_the_reference_where_selection_drops_blocks(tiny):
+    cfg, model, params = tiny
+    ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
+    got = model(params, ids)
+    config = ref_config(cfg)
+    for b in range(2):
+        want = reference.logits(params, ids[b], config, q_block=16)
+        np.testing.assert_allclose(got[b], want, atol=5e-6)
+    # 12 blocks of 4 at the last position, 4 chosen: blocks ARE dropped,
+    # and the controls the benchmark's limits must refuse move the logits
+    _, margin = reference.hidden_states(params, ids[0], config,
+                                        with_margins=True)
+    assert np.isinf(np.asarray(margin[:16])).all()      # <= 4 visible
+    assert np.isfinite(np.asarray(margin[16:])).all()
+    base = reference.logits(params, ids[0], config)
+    for control in ({"forced_only": True}, {"no_decay": True},
+                    {"operands": jnp.float8_e4m3fn}):
+        moved = reference.logits(params, ids[0], config, **control)
+        assert float(jnp.abs(moved - base).max()) > 0.02, control
+
+
+def _serve_logits(model, params, requests, *, slots, chunk, block_size,
+                  n_blocks, max_len, attn_kernel="reference"):
+    """Drive ``generation.decode`` the way the fused step does — a
+    prefill pack of at most ``chunk`` tokens a call (FCFS, runs of
+    several requests in one pack), then decode rows, a token a call —
+    and collect every position's logits. ``requests``: ``(slot, ids,
+    n_decode)`` in admission order; a slot named twice is REUSED once
+    its first request is done."""
+    caches = generation.init_paged_caches(model, n_blocks, block_size,
+                                          jnp.float32, slots=slots)
+    W = max_len // block_size
+    bt = np.zeros((slots, W), np.int32)
+    free = list(range(1, n_blocks))
+    out = {}
+    pending = [dict(i=i, slot=s, ids=np.asarray(ids), off=0, n=n)
+               for i, (s, ids, n) in enumerate(requests)]
+    busy, prefilling, decoding = set(), [], []
+    while pending or prefilling or decoding:
+        for r in list(pending):              # admit where the slot is free
+            if r["slot"] not in busy:
+                busy.add(r["slot"])
+                need = -(-len(r["ids"]) // block_size)
+                bt[r["slot"]] = 0
+                bt[r["slot"], :need] = [free.pop(0) for _ in range(need)]
+                prefilling.append(r)
+                pending.remove(r)
+                out[r["i"]] = np.zeros((len(r["ids"]), model.cfg.vocab_size),
+                                       np.float32)
+        btd = jnp.asarray(bt)
+        if decoding:                         # the decode rows first
+            pos = np.zeros(slots, np.int32)
+            tok = np.zeros(slots, np.int32)
+            act = np.zeros(slots, bool)
+            for r in decoding:
+                pos[r["slot"]], act[r["slot"]] = r["off"], True
+                tok[r["slot"]] = r["ids"][r["off"]]
+            lg, caches = generation.decode(
+                model, params, jnp.asarray(tok)[:, None],
+                jnp.asarray(pos)[:, None], caches,
+                slot_mask=jnp.asarray(act), block_tables=btd,
+                row_mask=jnp.asarray(act)[:, None],
+                attn_kernel=attn_kernel)
+            for r in list(decoding):
+                out[r["i"]][r["off"]] = np.asarray(lg[r["slot"], 0])
+                r["off"] += 1
+                if r["off"] == len(r["ids"]):
+                    decoding.remove(r)
+                    busy.discard(r["slot"])
+                    free += [b for b in bt[r["slot"]] if b]
+        if prefilling:                       # then one pack
+            tokens = np.zeros(chunk, np.int32)
+            tpos = np.zeros(chunk, np.int32)
+            tslot = np.zeros(chunk, np.int32)
+            valid = np.zeros(chunk, bool)
+            seg = np.full(chunk, -1, np.int32)
+            hist = np.zeros(chunk, np.int32)
+            used, fills = 0, []
+            for r in prefilling:
+                if used >= chunk:
+                    break
+                n = min(chunk - used, len(r["ids"]) - r["n"] - r["off"])
+                sl = slice(used, used + n)
+                tokens[sl] = r["ids"][r["off"]:r["off"] + n]
+                tpos[sl] = np.arange(r["off"], r["off"] + n)
+                tslot[sl], valid[sl], seg[sl] = r["slot"], True, r["slot"]
+                hist[sl] = r["off"]
+                fills.append((r, used, n))
+                used += n
+            pack = {"segment_ids": jnp.asarray(seg)[None],
+                    "hist": jnp.asarray(hist), "valid": jnp.asarray(valid),
+                    "impl": "reference", "slot": jnp.asarray(tslot),
+                    "slot_tables": btd}
+            lg, caches = _decode_pack(model, params, tokens, tpos, caches,
+                                      btd, tslot, pack, attn_kernel)
+            for r, at, n in fills:
+                out[r["i"]][r["off"]:r["off"] + n] = \
+                    np.asarray(lg[0, at:at + n])
+                r["off"] += n
+                if r["off"] == len(r["ids"]) - r["n"]:
+                    prefilling.remove(r)
+                    decoding.append(r)
+    return out
+
+
+def _decode_pack(model, params, tokens, tpos, caches, btd, tslot, pack,
+                 attn_kernel):
+    pos = jnp.asarray(tpos)[None]
+    h = model.embed(params, jnp.asarray(tokens)[None], positions=pos)
+    h, caches = model.blocks.decode(
+        params["blocks"], h, caches, positions=pos,
+        block_tables=jnp.take(btd, jnp.asarray(tslot), axis=0),
+        attn_kernel=attn_kernel, pack=pack)
+    h = model.hidden_norm(params, h)
+    return jnp.einsum("bse,ve->bsv", h, params["lm_head"]["weight"]), \
+        caches
+
+
+@pytest.mark.parametrize("attn_kernel,looped", [
+    ("reference", False), ("paged", False), ("reference", True)])
+def test_chunked_prefill_then_decode_equals_one_forward_pass(
+        tiny, attn_kernel, looped, monkeypatch):
+    """Logits, not tokens: two slots of different lengths in one pack,
+    chunks that cut strides and pages, and slot 0 REUSED by a third
+    request (its state must start from zeros, its pages be its own).
+    ``looped``: at sizes under a pack's, so that the read goes call by
+    call over padded rows and the scores and the scan block by block,
+    as they do at the served size."""
+    if looped:
+        from hetu_tpu.nn.parallel import (
+            BlockSparseAttention, LightningAttention,
+        )
+        monkeypatch.setattr(BlockSparseAttention, "ROWS_PER_CALL", 8)
+        monkeypatch.setattr(BlockSparseAttention, "SELECT_ROWS", 4)
+        monkeypatch.setattr(LightningAttention, "SCAN_BLOCK", 4)
+    cfg, model, params = tiny
+    rng = np.random.default_rng(39)
+    reqs = [(0, rng.integers(1, 128, 31), 5),
+            (1, rng.integers(1, 128, 22), 7),
+            (0, rng.integers(1, 128, 27), 6)]
+    got = _serve_logits(model, params, reqs, slots=2, chunk=10,
+                        block_size=4, n_blocks=24, max_len=32,
+                        attn_kernel=attn_kernel)
+    config = ref_config(cfg)
+    for i, (_, ids, _) in enumerate(reqs):
+        want = reference.logits(params, jnp.asarray(ids), config)
+        np.testing.assert_allclose(got[i], want, atol=2e-5)
+
+
+def test_engine_serves_tokens_the_reference_puts_on_top(tiny):
+    from hetu_tpu.serving import SamplingParams, ServingEngine
+    cfg, model, params = tiny
+    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                        block_size=4, slots=3, kv_blocks=40, seed=0)
+    assert eng.prefix_cache is None and eng.preempt is False
+    assert eng.prefill_attn == "flash"        # the pack as one row
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 128, n).tolist() for n in (21, 13, 30, 23, 7)]
+    outs = eng.generate_many(prompts, SamplingParams(max_tokens=6))
+    assert eng.step_executables() == 1
+    config = ref_config(cfg)
+    for p, toks in zip(prompts, outs):
+        lg = np.asarray(reference.logits(
+            params, jnp.asarray(p + toks), config))[len(p) - 1:-1]
+        gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
+        assert gap.max() <= 1e-4, (len(p), gap)
+    from hetu_tpu import telemetry
+    if telemetry.enabled():
+        c = telemetry.get_registry().counter("serving_sparse_pages_total")
+        assert c.value(state="visible", lane="decode") >= \
+            c.value(state="chosen", lane="decode") > 0
+
+
+def test_chunk_scan_equals_the_recurrence_for_ragged_runs():
+    """A chunk that does not divide the sequence; two slots' runs in one
+    pack; a state carried in; a slot that starts at 0 over a stale
+    state."""
+    H, d, S = 3, 8, 4
+    key = jax.random.split(jax.random.key(3), 8)
+    slopes = la.decay_slopes(H)
+    T0, T1 = 23, 9
+    q, k, v = (jax.random.normal(kk, (T0 + T1, H, d)) for kk in key[:3])
+    # slot 2 continues from position 11 with a state; slot 0 starts
+    # at 0 and must ignore what its state held
+    hist = 11
+    qa, ka, va = (jax.random.normal(kk, (hist, H, d)) for kk in key[3:6])
+    _, carried = la.linear_recurrence(qa, ka, va, slopes, scale=0.5)
+    state = jnp.zeros((S, H, d, d)).at[2].set(carried) \
+        .at[0].set(jax.random.normal(key[6], (H, d, d)))
+    slot = jnp.concatenate([jnp.full(T0, 2), jnp.full(T1, 0)])
+    pos = jnp.concatenate([hist + jnp.arange(T0), jnp.arange(T1)])
+    valid = jnp.ones(T0 + T1, bool).at[-2:].set(False)      # pad lanes
+    o, new = la.linear_scan(q, k, v, state, slot, pos, valid, slopes,
+                            scale=0.5, block=5)
+    w0, s2 = la.linear_recurrence(q[:T0], k[:T0], v[:T0], slopes,
+                                  scale=0.5, state=carried)
+    w1, s0 = la.linear_recurrence(q[T0:-2], k[T0:-2], v[T0:-2], slopes,
+                                  scale=0.5)
+    np.testing.assert_allclose(o[:T0], w0, atol=2e-5)
+    np.testing.assert_allclose(o[T0:-2], w1, atol=2e-5)
+    np.testing.assert_allclose(new[2], s2, atol=2e-5)
+    np.testing.assert_allclose(new[0], s0, atol=2e-5)
+    assert (np.asarray(new[1]) == 0).all() and (np.asarray(new[3]) == 0).all()
+    # the decode rows' one-token update is the recurrence's step
+    live = jnp.array([True, False, True, False])
+    o1, st1 = la.linear_update(q[:S], k[:S], v[:S], new, live, slopes,
+                               scale=0.5)
+    w, s = la.linear_recurrence(q[2:3], k[2:3], v[2:3], slopes, scale=0.5,
+                                state=new[2])
+    np.testing.assert_allclose(o1[2], w[0], atol=2e-5)
+    np.testing.assert_allclose(st1[2], s, atol=2e-5)
+    np.testing.assert_array_equal(st1[1], new[1])          # not live
+
+
+@pytest.mark.parametrize("topk", [4, 7, 64])
+def test_selection_against_a_direct_argsort_ties_included(topk):
+    """Scores drawn from a few values so that ties are the rule: the
+    lowest index wins among equals, on both sides."""
+    rng = np.random.default_rng(topk)
+    B, st, ks, N, hkv, W = 4, 2, 4, 37, 2, 16
+    J = W * (B // st)
+    s = jnp.asarray(rng.integers(0, 5, (N, hkv, J)) / 4.0, jnp.float32)
+    pos = jnp.asarray(rng.integers(0, W * B, N), jnp.int32)
+    ids, n = ss.choose_blocks(s, pos, block_size=B, stride=st, kernel=ks,
+                              topk=topk, init_blocks=1, window_blocks=1)
+    s_np = np.asarray(s)
+    for r in range(N):
+        own = int(pos[r]) // B
+        for g in range(hkv):
+            score = np.full(W, -np.inf)
+            for b in range(own + 1):
+                over = [j for j in range(J)
+                        if st * j < B * (b + 1) and st * j + ks > B * b]
+                score[b] = max(s_np[r, g, j] for j in over)
+                if b < 1 or own - 1 <= b <= own:
+                    score[b] = np.inf
+            want = np.argsort(-score, kind="stable")[:topk]
+            want = sorted(int(b) for b in want if score[b] > -np.inf)
+            got = [int(b) for b in np.asarray(ids[r, g]) if b < W]
+            assert got == want, (r, g)
+            assert int(n[r]) == len(want) == min(topk, own + 1)
+            assert (np.asarray(ids[r, g])[len(want):] == W).all()
+
+
+def test_window_scores_sum_to_the_group_size_over_visible_windows():
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(5, 2, 3, 8)), jnp.float32)
+    cm = jnp.asarray(rng.normal(size=(12, 2, 8)), jnp.float32)
+    kbar = ss.compressed_keys(cm, 2)
+    np.testing.assert_allclose(kbar[3], (cm[3] + cm[4]) / 2, atol=1e-6)
+    pos = jnp.asarray([0, 1, 2, 7, 11], jnp.int32)
+    s = ss.window_scores(q, kbar, pos, stride=1, kernel=2, scale=0.3)
+    # window j is visible once j + 2 <= t + 1: none at t = 0
+    assert float(jnp.abs(s[0]).max()) == 0.0
+    for r, t in enumerate([1, 2, 7, 11]):
+        np.testing.assert_allclose(s[r + 1].sum(-1), 3.0, atol=1e-5)
+        assert float(jnp.abs(s[r + 1][:, t:]).max()) == 0.0
+
+
+@pytest.mark.parametrize("d,bs,g", [(16, 4, 2), (128, 16, 4), (64, 8, 1)])
+def test_paged_call_on_a_table_that_selects_all_pages(d, bs, g):
+    """The sparse read IS the other cells' decode call: with every
+    visible page chosen (ascending, the slot's own table) the virtual
+    table's result equals the call on the slot's table and position —
+    kernel and gather reference alike."""
+    from hetu_tpu.ops.paged_pallas import (
+        paged_attention_pallas, paged_attention_reference,
+    )
+    rng = np.random.default_rng(d)
+    S, W, n_blk = 3, 6, 20
+    k = jnp.asarray(rng.normal(size=(2, n_blk, bs, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, n_blk, bs, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(S, 1, g, d)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(np.arange(1, n_blk))[:S * W]
+                         .reshape(S, W), jnp.int32)
+    pos = jnp.asarray([bs * W - 1, 2 * bs + 1, 0], jnp.int32)
+    want = paged_attention_reference(q, k[1], v[1], tables, pos)
+    # every visible block "chosen": ids ascending, W past the last
+    own = pos // bs
+    ids = jnp.where(jnp.arange(W)[None, :] <= own[:, None],
+                    jnp.arange(W)[None, :], W)[:, None, :]
+    vt, voff = ss.virtual_tables(ids, own + 1, tables, pos, block_size=bs)
+    np.testing.assert_array_equal(voff, pos)
+    got_ref = paged_attention_reference(q, k[1], v[1], vt, voff)
+    got = paged_attention_pallas(q, k, v, vt, voff, layer=1,
+                                 live=jnp.ones(S, bool), pages_per_step=4)
+    np.testing.assert_allclose(got_ref, want, atol=1e-5)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+REFUSED = [
+    ("prefix_cache", dict(prefix_cache=True)),
+    ("preempt", dict(preempt=True)),
+    ("spill_host_budget_bytes", dict(spill_host_budget_bytes=1e6)),
+    ("long_max_len", dict(long_max_len=128)),
+    ("spec_depth", dict(spec_depth=2)),
+    ("int8", dict(cache_dtype=jnp.int8)),
+    ("w8a8", dict(w8a8="on")),
+    ("tenancy", dict(tenancy=True)),
+    ("prefill_attn='reference'", dict(prefill_attn="reference")),
+]
+
+
+@pytest.mark.parametrize("name,kw", REFUSED, ids=[n for n, _ in REFUSED])
+def test_what_assumes_block_kv_refuses_at_construction_by_name(
+        tiny, name, kw):
+    from hetu_tpu.serving import ServingEngine
+    _, model, params = tiny
+    with pytest.raises(SlotStateNotSupported, match=name):
+        ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                      block_size=4, slots=2, kv_blocks=40, **kw)
+
+
+@pytest.mark.parametrize("call", [
+    "export_prefix", "import_prefix", "configure_replication",
+    "evict_request", "prefill_only"])
+def test_what_moves_a_requests_pages_refuses_when_called(tiny, call):
+    from hetu_tpu.serving import ServingEngine
+    _, model, params = tiny
+    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                        block_size=4, slots=2, kv_blocks=40)
+    args = {"export_prefix": ([1, 2, 3],), "import_prefix": (None,),
+            "configure_replication": (lambda doc: None,),
+            "evict_request": (None,), "prefill_only": ([1, 2, 3],)}[call]
+    with pytest.raises(SlotStateNotSupported, match=call):
+        getattr(eng, call)(*args)
+
+
+def test_dense_cache_and_cp_prefill_refuse_by_name(tiny):
+    _, model, params = tiny
+    with pytest.raises(SlotStateNotSupported, match="dense cache"):
+        generation.init_kv_caches(model, 1, 16)
+    with pytest.raises(SlotStateNotSupported, match="CP-prefill"):
+        model.blocks.prefill(params["blocks"], None)
+    with pytest.raises(ValueError, match="block_size"):
+        model.blocks.init_paged_caches(9, 8, jnp.float32, 2)
+
+
+def test_other_models_keep_their_defaults_and_their_programs():
+    """``prefix_cache`` / ``preempt`` left at None are ON for a model
+    without slot state, and its pack carries no slot operand."""
+    from hetu_tpu.models import GPTConfig, GPTLMHeadModel
+    from hetu_tpu.serving import ServingEngine
+    model = GPTLMHeadModel(GPTConfig.tiny())
+    eng = ServingEngine(model, model.init(jax.random.key(0)), max_len=32,
+                        prefill_chunk=8, block_size=4, slots=2)
+    assert eng.prefix_cache is not None and eng.preempt is True
+    assert eng._slot_state is False and eng.pool.quantized is False
+    assert len(eng.pool.caches) == 2
+
+
+def test_importing_the_package_loads_none_of_the_new_modules():
+    import subprocess
+    code = ("import sys, hetu_tpu, hetu_tpu.serving, hetu_tpu.models; "
+            "bad = [m for m in ('hetu_tpu.models.minicpm_sala', "
+            "'hetu_tpu.ops.sparse_select', 'hetu_tpu.ops.linear_attention')"
+            " if m in sys.modules]; print(bad); sys.exit(bool(bad))")
+    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       capture_output=True, text=True)
+    assert r.returncode == 0, r.stdout + r.stderr

@@ -379,8 +379,12 @@ def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
 
     sds = list(jax.tree.map(abstract, args))
+    # (a leaf over the SLOTS — a model's recurrent state — keeps its
+    # own second axis: only the pages grow to the cell's arena)
+    toy = eng.pool.n_blocks
     sds[1] = tuple(jax.ShapeDtypeStruct(
-        (c.shape[0], n_blocks) + c.shape[2:], c.dtype, sharding=sh)
+        (c.shape[0], n_blocks if c.shape[1] == toy else c.shape[1])
+        + c.shape[2:], c.dtype, sharding=sh)
         for c in sds[1])
     t0 = time.perf_counter()
     with _mosaic_aot_env():

@@ -18,6 +18,7 @@ from typing import Any, Iterable, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from hetu_tpu import telemetry
 from hetu_tpu.core.dtypes import BF16_COMPUTE, FP32, Policy, autocast
@@ -556,6 +557,10 @@ class Trainer:
             # per bucket
             fit = self.bucketer.fit
             batches = (fit(b) for b in batches)
+        if tel:
+            # counted where the loader's batch is taken: the prefetch
+            # thread when there is one, never the dispatch path
+            batches = map(self._count_flash_tiles, batches)
         prefetcher = None
         if self.config.prefetch > 0:
             from hetu_tpu.data.prefetch import DevicePrefetcher
@@ -833,6 +838,26 @@ class Trainer:
                "ranks": ranks, "metrics": agg}
         self.metrics.write_record(rec)
         return agg
+
+    def _count_flash_tiles(self, batch: dict) -> dict:
+        """``flash_tiles_total{pass, class}``: the compute tiles one
+        head's causal flash calls make of this packed batch, by the class
+        the kernels walk them in (``ops.flash_pallas.tile_classes``, from
+        the host's ``segment_ids``; ``bwd`` = dq's + dk/dv's). Returns the
+        batch."""
+        seg = batch.get("segment_ids") if isinstance(batch, dict) else None
+        if seg is None:
+            return batch
+        from hetu_tpu.ops.flash_pallas import train_tile_classes
+        counter = self.registry.counter(
+            "flash_tiles_total",
+            "flash attention compute tiles of one head over the packed "
+            "batches, by pass and class (dead: never visited; interior: "
+            "no mask built; edge: masked)")
+        for which, n in train_tile_classes(np.asarray(seg)).items():
+            for cls, x in zip(("dead", "interior", "edge"), n):
+                counter.inc(int(x), **{"pass": which, "class": cls})
+        return batch
 
     def _flops_per_token(self, seq_len: int) -> Optional[float]:
         """Model FLOPs/token from the config shapes (cost-model dims);

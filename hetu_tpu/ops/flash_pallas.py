@@ -6,19 +6,52 @@ TPU-native replacement for the reference's FlashAttention wrapper
 ``ParallelAttentionOp`` (``hetu/graph/ops/ParallelAttention.h:711``).
 
 Design (TPU-first, not a translation):
-- Online-softmax streaming over KV blocks; grid ``(batch, q_heads, q_blocks,
-  kv_blocks)`` with the KV axis innermost ("arbitrary" semantics) so running
-  max / denominator / accumulator live in VMEM scratch across KV iterations.
+- The streamed axis of each kernel is NOT a grid axis. A grid step holds a
+  MAJOR block of the streamed operands resident in VMEM — the whole of a
+  (batch, kv head)'s K and V for the forward and dq (grid ``(batch,
+  q_heads, q_blocks, kv_majors)``), the whole of a head's Q, dO, LSE and
+  delta for dk/dv (grid ``(batch, q_heads, kv_blocks, q_majors)``) —
+  wherever they fit ``_RESIDENT_BYTES`` (double buffered, lane padded), a
+  rule on the operands' SHAPES: a 1k row gets one major block, whose index
+  does not depend on the other block axis, so it is fetched once a head; a
+  32k ring hop streams major blocks ("arbitrary" axis, running state in
+  VMEM scratch), and a major block wholly above the diagonal is not
+  fetched (its index is clamped to the last live one).
+- An in-kernel ``fori_loop`` walks the compute sub-tiles (``block_q`` x
+  ``block_k``) of the resident block BY CLASS (:func:`tile_classes`):
+  tiles wholly below the causal diagonal first, with no causal mask built;
+  then the ones that cross it; tiles above it are outside the loops'
+  bounds — no grid step, no DMA, no branch. With segment ids the wrapper
+  reduces each block's ids to a (min, max) range, handed over by scalar
+  prefetch: two blocks whose ranges do not meet are skipped on one scalar
+  test (true for ANY ids), two blocks of one equal id build no segment
+  mask, the rest read the full-width id operands.
+- A visited tile costs ~0.4 µs before its first element (two dependent
+  matmuls' latency; nothing overlaps across the loop's iterations), so
+  tiles are LARGE (``_default_blocks``: up to 1024 a side, the whole row
+  of a 1k batch) and each is worked in static strips of 128 queries
+  (:func:`_pieces`): straight-line code whose matmuls overlap, and in the
+  tile that starts on the diagonal a strip stops at its last row's key —
+  the dead half of that tile is not computed either.
+- Forward: the exact softmax of a (query block, major block) in two passes
+  over its live sub-tiles — scores kept in VMEM and a per-lane running max;
+  then probs, per-lane sums and the value product — merged into the
+  running (max, sum, accumulator) once a MAJOR block: one online-softmax
+  update and two cross-lane reductions a query block, not a sub-tile.
 - GQA without materializing repeated KV: the K/V BlockSpec index_map divides
   the q-head program id by the group size.
 - Packing / varlen is expressed with segment ids (TPU formulation of the
   reference's cu_seqlens varlen path): q ids broadcast to 128 lanes, kv ids
   to 8 sublanes, the same layout the proven TPU kernels use.
-- Backward = two kernels: dq streams KV blocks per Q block; dK/dV stream Q
-  blocks per KV block (dK/dV produced per q-head then group-summed for GQA).
-- ``q_offset``/``kv_offset`` shift absolute positions for the causal mask so
-  ring-attention CP (``hetu_tpu.parallel.ring_attention``) can reuse these
-  kernels per hop and combine with the returned LSE.
+- Backward = two kernels: dq loops key sub-tiles per Q block; dK/dV loop
+  query sub-tiles per KV block, from the diagonal on, with tiles KEYS DOWN
+  (``k qᵀ``: LSE and delta ride as rows of 8 sublanes, and neither dV =
+  Pᵀ dO nor dK = dSᵀ Q transposes a tile); dK/dV produced per q-head then
+  group-summed for GQA.
+- ``q_offset``/``kv_offset`` shift absolute positions for the causal mask
+  and the loops' bounds so ring-attention CP
+  (``hetu_tpu.parallel.ring_attention``) can reuse these kernels per hop
+  and combine with the returned LSE.
 
 The softmax scale is folded into Q once on entry; masked logits use a finite
 ``NEG_INF`` so fully-masked rows stay NaN-free (output 0, LSE = NEG_INF),
@@ -33,6 +66,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -41,11 +75,19 @@ from hetu_tpu.core.bits import fmix32
 NEG_INF = -1e30
 NUM_LANES = 128
 NUM_SUBLANES = 8
+# VMEM the resident (major) blocks of one grid step's streamed operands may
+# take, double buffered and lane padded: K and V of a head up to 8k keys of
+# width 128 in bf16; a 32k ring hop streams major blocks of that size
+_RESIDENT_BYTES = 8 * 2 ** 20
+# queries (or keys) a strip of a compute tile: see ``_pieces``
+_STRIP = 128
+# the compute tile's side where the lengths allow: see ``_default_blocks``
+_TILE = 1024
 
 
 def _pick_block(n: int, target: int = 512) -> int:
-    for b in (target, 256, 128):
-        if n % b == 0 and b <= n:
+    for b in (target, 512, 256, 128):
+        if n % b == 0 and b <= n and b <= target:
             return b
     return n
 
@@ -64,16 +106,25 @@ def _tuned_entries() -> tuple:
 
 
 def _default_blocks(sq: int, sk: int, kind: str) -> tuple:
-    """Tuned (block_q, block_k) for this q/kv length if measured (exact
-    q-seq match whose blocks divide both lengths), else the static
-    heuristic. ``kind``: "fwd" | "bwd"."""
+    """The compute tile (block_q, block_k) of one kernel — ``kind``:
+    "fwd" | "dq" | "dkv" ("bwd": either backward kernel): tuned for this
+    q/kv length if measured (exact q-seq match whose blocks divide both
+    lengths; the file's "bwd" entry serves dq and dk/dv), else the static
+    rule: the largest of 1024 / 512 / 256 / 128 that divides each
+    length, for all three kernels. Swept on a v5e over {128, 256, 512,
+    1024}² at (32, 1024, 12, 64) and (4, 2048, 16, 64) on rows packed as
+    ``pretrain-packed-1k`` packs them (``workloads/flash_tune.py``;
+    PERF.md, PR 40): a visited tile costs ~0.4 µs whatever its size, so at
+    1k rows 128² tiles — 55 % of the square dead — take 2.5–3.7 x the
+    time of one 1024² tile a head worked in strips."""
+    tuned = "fwd" if kind == "fwd" else "bwd"
     for items in _tuned_entries():
         e = dict(items)
-        if e.get("seq") == sq and kind in e:
-            bq, bk = e[kind]
+        if e.get("seq") == sq and tuned in e:
+            bq, bk = e[tuned]
             if sq % bq == 0 and sk % bk == 0:
                 return bq, bk
-    return _pick_block(sq), _pick_block(sk)
+    return _pick_block(sq, _TILE), _pick_block(sk, _TILE)
 
 
 def _interpret_default() -> bool:
@@ -107,7 +158,16 @@ def _expand_kv_ids(seg: jnp.ndarray) -> jnp.ndarray:
 
 def _dropout_keep(seed, ib, ih, iq, ik, *, rate, block_q, block_k,
                   q_offset, kv_offset):
-    """(block_q, block_k) bool keep-mask from a counter-based RNG.
+    """(block_q, block_k) bool keep-mask of block (iq, ik): see
+    :func:`_keep_at`."""
+    return _keep_at(seed, ib, ih, iq * block_q + q_offset,
+                    ik * block_k + kv_offset, block_q, block_k, rate=rate)
+
+
+def _keep_at(seed, ib, ih, q0, k0, nq, nk, *, rate, transposed=False):
+    """(nq, nk) bool keep-mask from a counter-based RNG for the queries
+    from absolute position ``q0`` and the keys from ``k0``
+    (``transposed``: the same mask as (nk, nq), keys down).
 
     Addressed by ABSOLUTE (q, k) position + (batch, head) + seed — not by
     block indices — so the forward and both backward kernels regenerate
@@ -116,10 +176,11 @@ def _dropout_keep(seed, ib, ih, iq, ik, *, rate, block_q, block_k,
     ``hetu/impl/kernel/FlashAttention.cu:1-50``). Pure uint32 jnp ops:
     one code path for Mosaic and interpret modes.
     """
-    qpos = jnp.uint32(iq * block_q + q_offset) + jax.lax.broadcasted_iota(
-        jnp.uint32, (block_q, block_k), 0)
-    kpos = jnp.uint32(ik * block_k + kv_offset) + jax.lax.broadcasted_iota(
-        jnp.uint32, (block_q, block_k), 1)
+    shape = (nk, nq) if transposed else (nq, nk)
+    qpos = jnp.uint32(q0) + jax.lax.broadcasted_iota(
+        jnp.uint32, shape, int(transposed))
+    kpos = jnp.uint32(k0) + jax.lax.broadcasted_iota(
+        jnp.uint32, shape, int(not transposed))
     salt = fmix32(jnp.uint32(seed)
                    ^ (jnp.uint32(ib) * jnp.uint32(0x27D4EB2F))
                    ^ (jnp.uint32(ih) * jnp.uint32(0x165667B1)))
@@ -148,110 +209,384 @@ def dropout_keep_bh(seed, nb, nh, sq, sk, *, rate):
     return u >= threshold
 
 
-def _block_live(iq, ik, *, causal, block_q, block_k, q_offset, kv_offset):
-    """Scalar predicate: does this (q_block, kv_block) cell have any live
-    causal entry? Cells entirely above the diagonal are skipped with
-    ``pl.when`` so the MXU never sees them (~2x FLOPs saved at long seq —
-    the flash-attn tiling trick the reference gets from the CUDA kernels).
-    Returns None when nothing can be skipped statically (non-causal)."""
+def _major_block(n: int, tile: int, row_bytes: int) -> int:
+    """Rows of a streamed operand one grid step holds resident: all ``n``
+    when they fit ``_RESIDENT_BYTES`` double buffered, else the largest
+    divisor of ``n`` made of whole compute tiles that does."""
+    tiles = n // tile
+    for g in range(tiles, 1, -1):
+        if tiles % g == 0 and 2 * g * tile * row_bytes <= _RESIDENT_BYTES:
+            return g * tile
+    return tile
+
+
+def _row_bytes(d: int, dtype, n: int) -> int:
+    """VMEM bytes a row of ``n`` (rows, d) operands takes, lane padded."""
+    return n * -(-d // NUM_LANES) * NUM_LANES * jnp.dtype(dtype).itemsize
+
+
+def _block_ranges(seg, block: int):
+    """Each block's (min, max) id: ``seg`` (b, s) -> two (b, s // block)
+    arrays. numpy in, numpy out; jax in, jax out. Two blocks whose ranges
+    do not meet hold no equal pair of ids, whatever the order of ids."""
+    blocks = seg.reshape(seg.shape[0], seg.shape[1] // block, block)
+    return blocks.min(axis=-1), blocks.max(axis=-1)
+
+
+def tile_classes(q_seg, kv_seg, *, sq, sk, block_q, block_k, causal,
+                 q_offset=0, kv_offset=0):
+    """How many (block_q, block_k) tiles of one head's call are ``dead``
+    (above the diagonal, or the two blocks' id ranges do not meet: never
+    computed), ``interior`` (wholly below the diagonal and one id on both
+    sides: no mask built) and ``edge`` (the rest). numpy in, ``(dead,
+    interior, edge)`` out, summed over the batch; ``q_seg`` / ``kv_seg``
+    ``None`` = no ids (one row, causal classes only). The kernels' loop
+    bounds (:func:`_key_bounds`, :func:`_query_bounds`) and their test on
+    the prefetched ranges walk exactly these classes."""
+    q0 = np.arange(sq // block_q)[:, None] * block_q + q_offset
+    k0 = np.arange(sk // block_k)[None, :] * block_k + kv_offset
+    above = (k0 > q0 + block_q - 1) if causal else np.zeros(
+        (sq // block_q, sk // block_k), bool)
+    below = (k0 + block_k - 1 <= q0) if causal else ~above
+    if q_seg is None:
+        meet, one = ~above[None], below[None]
+    else:
+        qlo, qhi = (x[:, :, None] for x in _block_ranges(q_seg, block_q))
+        klo, khi = (x[:, None, :] for x in _block_ranges(kv_seg, block_k))
+        meet = (qlo <= khi) & (klo <= qhi) & ~above
+        one = meet & below & (qlo == qhi) & (klo == khi)
+    dead, interior = int((~meet).sum()), int(one.sum())
+    return np.array([dead, interior, meet.size - dead - interior])
+
+
+def train_tile_classes(segment_ids) -> dict:
+    """``{"fwd": (dead, interior, edge), "bwd": ...}`` of one head's
+    causal self-attention over a packed batch at the kernels' default
+    tiles (``bwd`` = the dq call's tiles + the dk/dv call's)."""
+    s = segment_ids.shape[1]
+    out = {}
+    for kind in ("fwd", "dq", "dkv"):
+        bq, bk = _default_blocks(s, s, kind)
+        n = tile_classes(segment_ids, segment_ids, sq=s, sk=s, block_q=bq,
+                         block_k=bk, causal=True)
+        key = "fwd" if kind == "fwd" else "bwd"
+        out[key] = out.get(key, 0) + n
+    return out
+
+
+def _floordiv(x, n: int):
+    """floor(max(x, 0) / n) of an int32 scalar, traced or a Python int
+    (a grid axis of one step: the loops' bounds are then static)."""
+    if isinstance(x, int):
+        return max(x, 0) // n
+    return jax.lax.div(jnp.maximum(x, 0), jnp.int32(n))
+
+
+def _at_most(x, n: int):
+    return min(x, n) if isinstance(x, int) else jnp.minimum(x, n)
+
+
+def _grid_index(axis: int, steps: int):
+    """This grid step's index along ``axis``; the Python 0 where the axis
+    has one step."""
+    return pl.program_id(axis) if steps > 1 else 0
+
+
+def _key_bounds(q_first, k_base, *, block_q, block_k, n_tiles, causal):
+    """Key tiles ``[0, full)`` of a major block that starts at absolute
+    key ``k_base`` lie wholly below the diagonal of the query block that
+    starts at ``q_first``; ``[full, live)`` cross it; the rest lie above
+    it and are outside the loops."""
     if not causal:
+        return n_tiles, n_tiles
+    full = _at_most(_floordiv(q_first - k_base + 1, block_k), n_tiles)
+    live = _at_most(
+        _floordiv(q_first + block_q - 1 - k_base + block_k, block_k),
+        n_tiles)
+    return full, live
+
+
+def _query_bounds(k_first, q_base, *, block_q, block_k, n_tiles, causal):
+    """Query tiles ``[first, full)`` of a major block that starts at
+    absolute query ``q_base`` cross the diagonal of the key block that
+    starts at ``k_first``; ``[full, n_tiles)`` lie wholly below it; the
+    ones before ``first`` lie above it and are outside the loops."""
+    if not causal:
+        return 0, 0
+    first = _at_most(_floordiv(k_first - q_base, block_q), n_tiles)
+    full = _at_most(
+        _floordiv(k_first + block_k - 1 - q_base + block_q - 1, block_q),
+        n_tiles)
+    return first, full
+
+
+def _walk(start, stop, tile, ranges, causal_mask: bool, seg_classes=True):
+    """Run ``tile(j, causal_mask, seg_mask)`` for sub-tiles ``[start,
+    stop)`` by class: ``ranges(j)`` gives the two blocks' prefetched id
+    ranges — disjoint: skipped; below the diagonal (``causal_mask``
+    False) with one id on both sides: interior, no mask at all; else an
+    edge tile with the segment mask too. ``seg_classes`` False: the tile
+    does the same for both (one body). A range that is empty when traced
+    costs nothing."""
+    if isinstance(start, int) and isinstance(stop, int) and stop <= start:
+        return
+
+    def body(j, carry):
+        if ranges is None:
+            tile(j, causal_mask, False)
+            return carry
+        qlo, qhi, klo, khi = ranges(j)
+        meet = (qlo <= khi) & (klo <= qhi)
+        if causal_mask or not seg_classes:
+            pl.when(meet)(lambda: tile(j, causal_mask, True))
+            return carry
+        one = (qlo == qhi) & (klo == khi) & (qlo == klo)
+        pl.when(one)(lambda: tile(j, False, False))
+        pl.when(meet & jnp.logical_not(one))(lambda: tile(j, False, True))
+        return carry
+
+    jax.lax.fori_loop(start, stop, body, 0)
+
+
+def _id_ranges(rng, q_at, k_at):
+    """``ranges(j)`` for :func:`_walk` from the prefetched (q min, q max,
+    kv min, kv max) arrays: ``q_at(j)`` / ``k_at(j)`` index sub-tile
+    ``j``'s two blocks; None without ids."""
+    if rng is None:
         return None
-    last_q = iq * block_q + (block_q - 1) + q_offset
-    first_k = ik * block_k + kv_offset
-    return last_q >= first_k
+    return lambda j: (rng[0][q_at(j)], rng[1][q_at(j)],
+                      rng[2][k_at(j)], rng[3][k_at(j)])
 
 
-def _mask_for_block(iq, ik, *, block_q, block_k, causal,
-                    q_offset, kv_offset, q_ids, kv_ids):
-    """Returns bool mask (block_q, block_k) or None if nothing masks."""
+def _pieces(block_q, block_k, diagonal: bool, keys_down: bool = False):
+    """Static windows ``(q0, nq, k0, nk)`` that cover a tile's live
+    pairs: strips of ``_STRIP`` queries against the tile's keys
+    (``keys_down``: strips of keys against its queries) — straight-line
+    code whose matmuls overlap and whose temporaries stay a strip's,
+    whatever the tile. In a tile whose first query and first key are the
+    SAME position (``diagonal``) a strip of queries stops at the keys of
+    its last row (a strip of keys starts at the queries of its first):
+    the half of the tile above the diagonal is neither scored nor
+    exponentiated."""
+    down, across = (block_k, block_q) if keys_down else (block_q, block_k)
+    strip = _STRIP if down % _STRIP == 0 else down
+    diagonal = diagonal and block_q == block_k
+    if keys_down:
+        return [(i if diagonal else 0, across - (i if diagonal else 0), i,
+                 strip) for i in range(0, down, strip)]
+    return [(i, strip, 0, i + strip if diagonal else across)
+            for i in range(0, down, strip)]
+
+
+def _at(j, block, start, size):
+    """``size`` rows from row ``start`` of sub-tile ``j`` of a resident
+    operand (lane aligned whenever a tile is cut into strips)."""
+    align = NUM_LANES if (block % NUM_LANES == 0
+                          and start % NUM_LANES == 0) else block
+    return pl.ds(pl.multiple_of(j * block + start, align), size)
+
+
+def _tile_mask(q0, k0, block_q, block_k, *, causal, q_ids, kv_ids,
+               transposed=False):
+    """bool (block_q, block_k) for the tile whose first query is absolute
+    position ``q0`` and first key ``k0`` (``transposed``: (block_k,
+    block_q), keys down); None if nothing masks."""
     mask = None
     if causal:
-        qpos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0) + q_offset
-        kpos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1) + kv_offset
-        mask = qpos >= kpos
+        shape = (block_k, block_q) if transposed else (block_q, block_k)
+        q_at = jax.lax.broadcasted_iota(jnp.int32, shape, int(transposed))
+        k_at = jax.lax.broadcasted_iota(jnp.int32, shape,
+                                        int(not transposed))
+        mask = q_at - k_at >= k0 - q0
     if q_ids is not None:
-        smask = q_ids == kv_ids  # (block_q,1) == (1,block_k)
+        # (block_q,1) == (1,block_k), or (1,block_q) == (block_k,1)
+        smask = q_ids == kv_ids
         mask = smask if mask is None else mask & smask
     return mask
+
+
+def _split_refs(refs, n_tensor, has_seg, has_drop):
+    """(ranges, tensors, qseg, kseg, seed, outs and scratch) of a call:
+    pallas passes the prefetched scalars first and only the refs that
+    were given specs, in order."""
+    refs = list(refs)
+    rng = [refs.pop(0) for _ in range(4)] if has_seg else None
+    tensors = [refs.pop(0) for _ in range(n_tensor)]
+    qseg, kseg = (refs.pop(0), refs.pop(0)) if has_seg else (None, None)
+    seed = refs.pop(0) if has_drop else None
+    return rng, tensors, qseg, kseg, seed, refs
 
 
 # --------------------------------------------------------------------------
 # Forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, qseg_ref, kseg_ref, seed_ref,
-                o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                causal, block_q, block_k, kv_blocks, q_offset, kv_offset,
-                dropout_rate=0.0):
-    ib = pl.program_id(0)
-    ih = pl.program_id(1)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+def _lane_fold(x, op, pad):
+    """(rows, cols) -> (rows, NUM_LANES): ``op`` over the column chunks of
+    128, elementwise — what is left for ONE cross-lane reduction a query
+    block. A width that is no multiple of 128 is reduced at once into
+    lane 0 (the other lanes hold ``pad``, the reduction's identity)."""
+    cols = x.shape[1]
+    if cols % NUM_LANES == 0:
+        return functools.reduce(op, [x[:, c:c + NUM_LANES]
+                                     for c in range(0, cols, NUM_LANES)])
+    lane = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], NUM_LANES), 1)
+    red = jnp.max if op is jnp.maximum else jnp.sum
+    return jnp.where(lane == 0, red(x, axis=1, keepdims=True), pad)
 
-    @pl.when(ik == 0)
+
+def _fwd_score_tile(q, k, s_out, mpart_scr, mask):
+    """First pass of a sub-tile: its masked scores kept in VMEM, the
+    running per-lane max updated (no cross-lane work)."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    s_out[...] = s
+    mpart_scr[...] = jnp.maximum(mpart_scr[...],
+                                 _lane_fold(s, jnp.maximum, NEG_INF))
+
+
+def _fwd_value_tile(s, v, m, lpart_scr, acc_scr, keep, dropout_rate):
+    """Second pass of a sub-tile: probs against the block's row max
+    ``m`` (a masked score is NEG_INF and ``m`` at least NEG_INF / 2: its
+    prob is an exact zero), the per-lane sums and the value product."""
+    p = jnp.exp(s - m)
+    lpart_scr[...] += _lane_fold(p, jnp.add, 0.0)
+    if keep is not None:
+        # dropout on the (later-normalized) probs: mask only the VALUE
+        # accumulation — the denominator l stays un-dropped, so
+        # out = Σ mask∘softmax∘V / keep and LSE is unchanged
+        p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
+    acc_scr[...] += jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(*refs, causal, has_seg, block_q, block_k, kv_major,
+                q_blocks, k_blocks, q_offset, kv_offset, dropout_rate):
+    """One query block against one resident major block of K and V: the
+    exact softmax of the block in two passes over its live sub-tiles
+    (scores and the row max; probs, sums and values), merged into the
+    running (max, sum, accumulator) once a MAJOR block — the online
+    update and both cross-lane reductions leave the per-tile path."""
+    rng, (q_ref, k_ref, v_ref), qseg_ref, kseg_ref, seed_ref, \
+        (o_ref, lse_ref, m_scr, mpart_scr, lpart_scr, acc_scr, s_scr) = \
+        _split_refs(refs, 3, has_seg, dropout_rate > 0.0)
+    n_tiles = kv_major // block_k
+    ib, ih = pl.program_id(0), pl.program_id(1)
+    iq, ikm = _grid_index(2, q_blocks), _grid_index(3, k_blocks // n_tiles)
+
+    @pl.when(pl.program_id(3) == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        lpart_scr[...] = jnp.zeros_like(lpart_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def compute():
-        q = q_ref[0, 0]  # (block_q, d), scale already folded in
-        k = k_ref[0, 0]  # (block_k, d)
-        v = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    mpart_scr[...] = jnp.full_like(mpart_scr, NEG_INF)
 
-        q_ids = qseg_ref[0][:, :1] if qseg_ref is not None else None
-        kv_ids = kseg_ref[0][:1, :] if kseg_ref is not None else None
-        mask = _mask_for_block(iq, ik, block_q=block_q, block_k=block_k,
-                               causal=causal, q_offset=q_offset,
-                               kv_offset=kv_offset, q_ids=q_ids,
-                               kv_ids=kv_ids)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
+    # with equal offsets and square tiles the one tile that crosses the
+    # diagonal starts ON it
+    aligned = q_offset == kv_offset
 
-        m_prev = m_scr[:, :1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_next)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)  # exact zero for fully-masked rows
-        l_cur = jnp.sum(p, axis=1, keepdims=True)
-        alpha = jnp.exp(m_prev - m_next)
-        m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(alpha * l_prev + l_cur, l_scr.shape)
-        if dropout_rate > 0.0:
-            # dropout on the (later-normalized) probs: mask only the
-            # VALUE accumulation — the denominator l stays un-dropped,
-            # so out = Σ mask∘softmax∘V / keep and LSE is unchanged
-            keep = _dropout_keep(seed_ref[0], ib, ih, iq, ik,
-                                 rate=dropout_rate, block_q=block_q,
-                                 block_k=block_k, q_offset=q_offset,
-                                 kv_offset=kv_offset)
-            p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv
+    def score_tile(j, causal_mask, seg_mask):
+        for q0, nq, k0, nk in _pieces(block_q, block_k,
+                                      causal_mask and aligned):
+            mask = _tile_mask(
+                iq * block_q + q_offset + q0,
+                (ikm * n_tiles + j) * block_k + kv_offset + k0, nq, nk,
+                causal=causal_mask,
+                q_ids=qseg_ref[0, q0:q0 + nq, :1] if seg_mask else None,
+                kv_ids=kseg_ref[0, :1, _at(j, block_k, k0, nk)]
+                if seg_mask else None)
+            # q has the scale folded in already
+            _fwd_score_tile(
+                q_ref[0, 0, q0:q0 + nq, :],
+                k_ref[0, 0, _at(j, block_k, k0, nk), :],
+                s_scr.at[j, q0:q0 + nq, k0:k0 + nk],
+                mpart_scr.at[q0:q0 + nq, :], mask)
 
-    live = _block_live(iq, ik, causal=causal, block_q=block_q,
-                       block_k=block_k, q_offset=q_offset,
-                       kv_offset=kv_offset)
-    if live is None:
-        compute()
-    else:
-        pl.when(live)(compute)
+    ranges = _id_ranges(rng, lambda j: ib * q_blocks + iq,
+                        lambda j: ib * k_blocks + ikm * n_tiles + j)
 
-    @pl.when(ik == kv_blocks - 1)
+    full, live = _key_bounds(
+        iq * block_q + q_offset, ikm * kv_major + kv_offset,
+        block_q=block_q, block_k=block_k, n_tiles=n_tiles, causal=causal)
+
+    def walk(tile, seg_classes=True):
+        _walk(0, full, tile, ranges, False, seg_classes)
+        if causal:
+            _walk(full, live, tile, ranges, True)
+
+    walk(score_tile)
+    m_prev = m_scr[:, :1]
+    m_next = jnp.maximum(m_prev, jnp.max(mpart_scr[...], axis=1,
+                                         keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
+    lpart_scr[...] = lpart_scr[...] * alpha
+    acc_scr[...] = acc_scr[...] * alpha
+    m_safe = jnp.maximum(m_next, NEG_INF / 2)
+
+    def value_tile(j, causal_mask, seg_mask):
+        for q0, nq, k0, nk in _pieces(block_q, block_k,
+                                      causal_mask and aligned):
+            keep = None
+            if dropout_rate > 0.0:
+                keep = _keep_at(
+                    seed_ref[0], ib, ih, iq * block_q + q_offset + q0,
+                    (ikm * n_tiles + j) * block_k + kv_offset + k0, nq, nk,
+                    rate=dropout_rate)
+            _fwd_value_tile(
+                s_scr[j, q0:q0 + nq, k0:k0 + nk],
+                v_ref[0, 0, _at(j, block_k, k0, nk), :],
+                m_safe[q0:q0 + nq], lpart_scr.at[q0:q0 + nq, :],
+                acc_scr.at[q0:q0 + nq, :], keep, dropout_rate)
+
+    walk(value_tile, seg_classes=False)     # the scores are masked already
+
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = jnp.sum(lpart_scr[...], axis=1, keepdims=True)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
         lse = jnp.where(l == 0.0, NEG_INF, m_scr[:, :1] + jnp.log(l_safe))
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+
+
+def _resolve_blocks(sq, sk, kind, block_q, block_k):
+    if block_q is None and block_k is None:
+        return _default_blocks(sq, sk, kind)
+    return (block_q or _pick_block(sq, _TILE),
+            block_k or _pick_block(sk, _TILE))
+
+
+def _seg_ranges(q_seg, kv_seg, block_q, block_k):
+    """The prefetched per-block id ranges: flat int32, q's (min, max)
+    then kv's; none without ids."""
+    if q_seg is None:
+        return []
+    return [r.reshape(-1).astype(jnp.int32)
+            for r in (*_block_ranges(q_seg, block_q),
+                      *_block_ranges(kv_seg, block_k))]
+
+
+def _seed_operand(seed, dropout_rate):
+    if dropout_rate <= 0.0:
+        return [], []
+    return ([pl.BlockSpec(memory_space=pltpu.SMEM)],
+            [jnp.asarray(seed, jnp.int32).reshape(1)])
+
+
+def _last_live_major(iq, ikm, *, causal, block_q, kv_major, q_offset,
+                     kv_offset):
+    """The major key block grid step ``(iq, ikm)`` reads: ``ikm``, or —
+    when that one lies wholly above the query block's diagonal — the last
+    that does not (a block whose index did not change is not copied)."""
+    if not causal:
+        return ikm
+    return jnp.minimum(ikm, _floordiv(
+        iq * block_q + block_q - 1 + q_offset - kv_offset, kv_major))
 
 
 def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, scale,
@@ -266,226 +601,214 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, scale,
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     rep = hq // hkv
-    if block_q is None and block_k is None:
-        block_q, block_k = _default_blocks(sq, sk, "fwd")
-    else:
-        block_q = block_q or _pick_block(sq)
-        block_k = block_k or _pick_block(sk)
-    kv_blocks = sk // block_k
+    block_q, block_k = _resolve_blocks(sq, sk, "fwd", block_q, block_k)
+    has_seg = q_seg is not None
+    # resident a key: K and V rows, its ids, and (not double buffered) a
+    # query block's float32 scores
+    kv_major = _major_block(
+        sk, block_k, _row_bytes(d, k.dtype, 2)
+        + (NUM_SUBLANES * 4 if has_seg else 0) + block_q * 2)
     interpret = _interpret_default() if interpret is None else interpret
 
     qf = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    grid = (b, hq, sq // block_q, kv_blocks)
+    grid = (b, hq, sq // block_q, sk // kv_major)
+    major = functools.partial(
+        _last_live_major, causal=causal, block_q=block_q,
+        kv_major=kv_major, q_offset=q_offset, kv_offset=kv_offset)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, iq, ik: (ib, ih // rep, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, iq, ik: (ib, ih // rep, ik, 0)),
-    ]
-    args = [qf, k, v]
-    has_seg = q_seg is not None
-    has_drop = dropout_rate > 0.0
-    if has_seg:
-        in_specs.append(pl.BlockSpec(
-            (1, block_q, NUM_LANES), lambda ib, ih, iq, ik: (ib, iq, 0)))
-        in_specs.append(pl.BlockSpec(
-            (1, NUM_SUBLANES, block_k), lambda ib, ih, iq, ik: (ib, 0, ik)))
-        args += [_expand_q_ids(q_seg), _expand_kv_ids(kv_seg)]
-    if has_drop:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        args.append(jnp.asarray(seed, jnp.int32).reshape(1))
-    kernel = functools.partial(_opt_refs_wrapper, _fwd_kernel, 3,
-                               has_seg, has_drop)
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda ib, ih, iq, ikm, *_: (ib, ih, iq, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, kv_major, d),
+        lambda ib, ih, iq, ikm, *_: (ib, ih // rep, major(iq, ikm), 0))
+    ranges = _seg_ranges(q_seg, kv_seg, block_q, block_k)
+    # the full-width ids the edge tiles read: q's a column, kv's a row
+    seg_args = [_expand_q_ids(q_seg), _expand_kv_ids(kv_seg)] \
+        if has_seg else []
+    seg_specs = [
+        pl.BlockSpec((1, block_q, NUM_LANES),
+                     lambda ib, ih, iq, ikm, *_: (ib, iq, 0)),
+        pl.BlockSpec((1, NUM_SUBLANES, kv_major),
+                     lambda ib, ih, iq, ikm, *_: (ib, 0, major(iq, ikm))),
+    ] if has_seg else []
+    seed_specs, seed_args = _seed_operand(seed, dropout_rate)
 
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        jax.ShapeDtypeStruct((b, hq, sq, NUM_LANES), jnp.float32),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, 1, block_q, NUM_LANES),
-                     lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-    ]
+    lane_spec = pl.BlockSpec((1, 1, block_q, NUM_LANES),
+                             lambda ib, ih, iq, ikm, *_: (ib, ih, iq, 0))
     with jax.named_scope("hetu.flash_fwd"):
         out, lse_l = pl.pallas_call(
-            functools.partial(kernel, causal=causal, block_q=block_q,
-                              block_k=block_k, kv_blocks=kv_blocks,
-                              q_offset=q_offset, kv_offset=kv_offset,
-                              dropout_rate=dropout_rate),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
-                pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
-                pltpu.VMEM((block_q, d), jnp.float32),
+            functools.partial(
+                _fwd_kernel, causal=causal, has_seg=has_seg,
+                block_q=block_q, block_k=block_k, kv_major=kv_major,
+                q_blocks=sq // block_q, k_blocks=sk // block_k,
+                q_offset=q_offset, kv_offset=kv_offset,
+                dropout_rate=dropout_rate),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(ranges),
+                grid=grid,
+                in_specs=[q_spec, kv_spec, kv_spec] + seg_specs
+                + seed_specs,
+                out_specs=[q_spec, lane_spec],
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, NUM_LANES), jnp.float32),  # max
+                    pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                    pltpu.VMEM((block_q, NUM_LANES), jnp.float32),
+                    pltpu.VMEM((block_q, d), jnp.float32),
+                    pltpu.VMEM((kv_major // block_k, block_q, block_k),
+                               jnp.float32),
+                ]),
+            out_shape=[
+                jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+                jax.ShapeDtypeStruct((b, hq, sq, NUM_LANES), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
             name="hetu_flash_fwd",
-        )(*args)
+        )(*ranges, qf, k, v, *seg_args, *seed_args)
     return out, lse_l[..., 0]
-
-
-def _opt_refs_wrapper(kernel, n_tensor, has_seg, has_seed, *refs, **kw):
-    """Adapts a kernel expecting (tensor refs..., qseg, kseg, seed,
-    outs/scratch...) to a call where the optional refs may be absent —
-    pallas passes only the refs that were given specs, in order."""
-    idx = n_tensor
-    if has_seg:
-        qseg, kseg = refs[idx], refs[idx + 1]
-        idx += 2
-    else:
-        qseg = kseg = None
-    if has_seed:
-        seed = refs[idx]
-        idx += 1
-    else:
-        seed = None
-    kernel(*refs[:n_tensor], qseg, kseg, seed, *refs[idx:], **kw)
 
 
 # --------------------------------------------------------------------------
 # Backward
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   qseg_ref, kseg_ref, seed_ref, dq_ref, dq_scr, *,
-                   causal, block_q, block_k, kv_blocks, q_offset,
-                   kv_offset, dropout_rate=0.0):
-    ib = pl.program_id(0)
-    ih = pl.program_id(1)
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+def _bwd_tile(x, y, dx, dy, lse, delta, mask, keep, dropout_rate):
+    """The recomputed probs as dV sees them and dS of one tile, fp32:
+    ``(p_v, ds)`` as (rows of x, rows of y) — dq passes (q, k, dO, v) with
+    ``lse`` / ``delta`` columns, dk/dv (k, q, v, dO) with rows, so its
+    tiles come out keys down and neither product needs a transpose."""
+    nt = (((1,), (1,)), ((), ()))
+    s = jax.lax.dot_general(x, y, nt, preferred_element_type=jnp.float32)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    p = jnp.exp(s - lse)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    dp = jax.lax.dot_general(dx, dy, nt, preferred_element_type=jnp.float32)
+    p_v = p
+    if keep is not None:
+        # dA = mask ∘ (dO Vᵀ) / keep; delta = Σ dO∘O is invariant under
+        # dropout (see _flash_bwd docnote), so ds keeps its form; dV
+        # mixes the DROPPED probs — what the forward output mixed
+        dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
+        p_v = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
+    return p_v, p * (dp - delta)
 
-    @pl.when(ik == 0)
+
+def _bwd_dq_kernel(*refs, causal, has_seg, block_q, block_k, kv_major,
+                   q_blocks, k_blocks, q_offset, kv_offset, dropout_rate,
+                   scale):
+    rng, (q_ref, k_ref, v_ref, do_ref, col_ref), qseg_ref, \
+        kseg_ref, seed_ref, (dq_ref, dq_scr) = _split_refs(
+            refs, 5, has_seg, dropout_rate > 0.0)
+    n_tiles = kv_major // block_k
+    ib, ih = pl.program_id(0), pl.program_id(1)
+    iq, ikm = _grid_index(2, q_blocks), _grid_index(3, k_blocks // n_tiles)
+
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def compute():
-        q = q_ref[0, 0]          # (bq, d) pre-scaled
-        k = k_ref[0, 0]          # (bk, d)
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]        # (bq, d)
-        lse = lse_ref[0, 0][:, :1]     # (bq, 1)
-        delta = delta_ref[0, 0][:, :1]  # (bq, 1)
+    aligned = q_offset == kv_offset
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        q_ids = qseg_ref[0][:, :1] if qseg_ref is not None else None
-        kv_ids = kseg_ref[0][:1, :] if kseg_ref is not None else None
-        mask = _mask_for_block(iq, ik, block_q=block_q, block_k=block_k,
-                               causal=causal, q_offset=q_offset,
-                               kv_offset=kv_offset, q_ids=q_ids,
-                               kv_ids=kv_ids)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
+    def tile(j, causal_mask, seg_mask):
+        for q0, nq, k0, nk in _pieces(block_q, block_k,
+                                      causal_mask and aligned):
+            q_at = iq * block_q + q_offset + q0
+            k_at = (ikm * n_tiles + j) * block_k + kv_offset + k0
+            mask = _tile_mask(
+                q_at, k_at, nq, nk, causal=causal_mask,
+                q_ids=qseg_ref[0, q0:q0 + nq, :1] if seg_mask else None,
+                kv_ids=kseg_ref[0, :1, _at(j, block_k, k0, nk)]
+                if seg_mask else None)
+            keep = None
+            if dropout_rate > 0.0:
+                keep = _keep_at(seed_ref[0], ib, ih, q_at, k_at, nq, nk,
+                                rate=dropout_rate)
+            k = k_ref[0, 0, _at(j, block_k, k0, nk), :]
+            cols = col_ref[0, 0, q0:q0 + nq, :]  # lane 0: LSE, then delta
+            _, ds = _bwd_tile(
+                q_ref[0, 0, q0:q0 + nq, :], k, do_ref[0, 0, q0:q0 + nq, :],
+                v_ref[0, 0, _at(j, block_k, k0, nk), :], cols[:, :1],
+                cols[:, 1:2], mask, keep, dropout_rate)
+            dq_scr[q0:q0 + nq, :] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            # dA = mask ∘ (dO Vᵀ) / keep; delta = Σ dO∘O is invariant
-            # under dropout (see _flash_bwd docnote), so ds keeps form
-            keep = _dropout_keep(seed_ref[0], ib, ih, iq, ik,
-                                 rate=dropout_rate, block_q=block_q,
-                                 block_k=block_k, q_offset=q_offset,
-                                 kv_offset=kv_offset)
-            dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-        ds = p * (dp - delta)    # (bq, bk), fp32
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    ranges = _id_ranges(rng, lambda j: ib * q_blocks + iq,
+                        lambda j: ib * k_blocks + ikm * n_tiles + j)
 
-    live = _block_live(iq, ik, causal=causal, block_q=block_q,
-                       block_k=block_k, q_offset=q_offset,
-                       kv_offset=kv_offset)
-    if live is None:
-        compute()
-    else:
-        pl.when(live)(compute)
+    full, live = _key_bounds(
+        iq * block_q + q_offset, ikm * kv_major + kv_offset,
+        block_q=block_q, block_k=block_k, n_tiles=n_tiles, causal=causal)
+    _walk(0, full, tile, ranges, False)
+    if causal:
+        _walk(full, live, tile, ranges, True)
 
-    @pl.when(ik == kv_blocks - 1)
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _finalize():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+        # undo the q-scale folding
+        dq_ref[0, 0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    qseg_ref, kseg_ref, seed_ref, dk_ref, dv_ref,
-                    dk_scr, dv_scr, *,
-                    causal, block_q, block_k, q_blocks, q_offset,
-                    kv_offset, dropout_rate=0.0):
-    ib = pl.program_id(0)
-    ih = pl.program_id(1)
-    ik = pl.program_id(2)
-    iq = pl.program_id(3)
+def _bwd_dkv_kernel(*refs, causal, has_seg, block_q, block_k, q_major,
+                    q_blocks, k_blocks, q_offset, kv_offset, dropout_rate):
+    rng, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), qseg_ref, \
+        kseg_ref, seed_ref, (dk_ref, dv_ref, dk_scr, dv_scr) = _split_refs(
+            refs, 6, has_seg, dropout_rate > 0.0)
+    n_tiles = q_major // block_q
+    ib, ih = pl.program_id(0), pl.program_id(1)
+    ik, iqm = _grid_index(2, k_blocks), _grid_index(3, q_blocks // n_tiles)
 
-    @pl.when(iq == 0)
+    @pl.when(pl.program_id(3) == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def compute():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
+    aligned = q_offset == kv_offset
 
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        q_ids = qseg_ref[0][:, :1] if qseg_ref is not None else None
-        kv_ids = kseg_ref[0][:1, :] if kseg_ref is not None else None
-        mask = _mask_for_block(iq, ik, block_q=block_q, block_k=block_k,
-                               causal=causal, q_offset=q_offset,
-                               kv_offset=kv_offset, q_ids=q_ids,
-                               kv_ids=kv_ids)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
+    def tile(j, causal_mask, seg_mask):
+        # keys down, queries across: (nk, nq) pieces
+        for q0, nq, k0, nk in _pieces(block_q, block_k,
+                                      causal_mask and aligned,
+                                      keys_down=True):
+            q_at = (iqm * n_tiles + j) * block_q + q_offset + q0
+            k_at = ik * block_k + kv_offset + k0
+            at = _at(j, block_q, q0, nq)
+            mask = _tile_mask(
+                q_at, k_at, nq, nk, causal=causal_mask, transposed=True,
+                q_ids=qseg_ref[0, :1, at] if seg_mask else None,
+                kv_ids=kseg_ref[0, k0:k0 + nk, :1] if seg_mask else None)
+            keep = None
+            if dropout_rate > 0.0:
+                keep = _keep_at(seed_ref[0], ib, ih, q_at, k_at, nq, nk,
+                                rate=dropout_rate, transposed=True)
+            q, do = q_ref[0, 0, at, :], do_ref[0, 0, at, :]
+            p_v, ds = _bwd_tile(
+                k_ref[0, 0, k0:k0 + nk, :], q, v_ref[0, 0, k0:k0 + nk, :],
+                do, lse_ref[0, 0, :1, at], delta_ref[0, 0, :1, at], mask,
+                keep, dropout_rate)
+            # dV += Ad^T @ dO;  dK += dS^T @ Q
+            dv_scr[k0:k0 + nk, :] += jax.lax.dot_general(
+                p_v.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[k0:k0 + nk, :] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-        keep = None
-        if dropout_rate > 0.0:
-            keep = _dropout_keep(seed_ref[0], ib, ih, iq, ik,
-                                 rate=dropout_rate, block_q=block_q,
-                                 block_k=block_k, q_offset=q_offset,
-                                 kv_offset=kv_offset)
-        # dV += Ad^T @ dO (Ad = dropped probs — what the forward output
-        # actually mixed)
-        p_v = p if keep is None else \
-            jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
-        dv_scr[...] += jax.lax.dot_general(
-            p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dS = P * (mask∘(dO @ V^T)/keep - delta);  dK += dS^T @ Q
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        if keep is not None:
-            dp = jnp.where(keep, dp / (1.0 - dropout_rate), 0.0)
-        ds = p * (dp - delta)
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    ranges = _id_ranges(rng, lambda j: ib * q_blocks + iqm * n_tiles + j,
+                        lambda j: ib * k_blocks + ik)
 
-    live = _block_live(iq, ik, causal=causal, block_q=block_q,
-                       block_k=block_k, q_offset=q_offset,
-                       kv_offset=kv_offset)
-    if live is None:
-        compute()
-    else:
-        pl.when(live)(compute)
+    first, full = _query_bounds(
+        ik * block_k + kv_offset, iqm * q_major + q_offset,
+        block_q=block_q, block_k=block_k, n_tiles=n_tiles, causal=causal)
+    if causal:
+        _walk(first, full, tile, ranges, True)
+    _walk(full, n_tiles, tile, ranges, False)
 
-    @pl.when(iq == q_blocks - 1)
+    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -506,117 +829,132 @@ def _flash_bwd(q, k, v, q_seg, kv_seg, out, lse, do, *, causal, scale,
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     rep = hq // hkv
-    if block_q is None and block_k is None:
-        block_q, block_k = _default_blocks(sq, sk, "bwd")
-    else:
-        block_q = block_q or _pick_block(sq)
-        block_k = block_k or _pick_block(sk)
+    has_seg = q_seg is not None
     interpret = _interpret_default() if interpret is None else interpret
 
     qf = (q.astype(jnp.float32) * scale).astype(q.dtype)
     if delta is None:
         delta = jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                         axis=-1)                                # (b,hq,sq)
-    lse_l = jax.lax.broadcast_in_dim(lse, (*lse.shape, NUM_LANES), (0, 1, 2))
-    delta_l = jax.lax.broadcast_in_dim(delta, (*delta.shape, NUM_LANES),
-                                       (0, 1, 2))
+    # the per-query scalars: COLUMNS for dq (queries down: LSE in lane 0
+    # and delta in the other lanes of ONE 128-lane operand, one fused
+    # write), ROWS for dk/dv (queries across: 8 sublanes each)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (*lse.shape, NUM_LANES), 3)
+    columns = jnp.where(lane == 0, lse[..., None], delta[..., None])
+    rows = [jax.lax.broadcast_in_dim(x, (b, hq, NUM_SUBLANES, sq), (0, 1, 3))
+            for x in (lse, delta)]
+    seed_specs, seed_args = _seed_operand(seed, dropout_rate)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel",
+                             "arbitrary"))
 
-    lane_spec_q = pl.BlockSpec((1, 1, block_q, NUM_LANES),
-                               lambda ib, ih, iq, ik: (ib, ih, iq, 0))
-    args = [qf, k, v, do, lse_l, delta_l]
-    has_seg = q_seg is not None
-    has_drop = dropout_rate > 0.0
-    seed_args, seed_specs = [], []
-    if has_drop:
-        seed_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
-        seed_args = [jnp.asarray(seed, jnp.int32).reshape(1)]
-    seg_args, seg_specs_dq, seg_specs_dkv = [], [], []
-    if q_seg is not None:
-        seg_args = [_expand_q_ids(q_seg), _expand_kv_ids(kv_seg)]
-        seg_specs_dq = [
-            pl.BlockSpec((1, block_q, NUM_LANES),
-                         lambda ib, ih, iq, ik: (ib, iq, 0)),
-            pl.BlockSpec((1, NUM_SUBLANES, block_k),
-                         lambda ib, ih, iq, ik: (ib, 0, ik)),
-        ]
-        seg_specs_dkv = [
-            pl.BlockSpec((1, block_q, NUM_LANES),
-                         lambda ib, ih, ik, iq: (ib, iq, 0)),
-            pl.BlockSpec((1, NUM_SUBLANES, block_k),
-                         lambda ib, ih, ik, iq: (ib, 0, ik)),
-        ]
-
-    # ---- dQ: grid (b, hq, q_blocks, kv_blocks), accumulate over kv ----
-    dq_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, iq, ik: (ib, ih // rep, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, iq, ik: (ib, ih // rep, ik, 0)),
-        pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        lane_spec_q,
-        lane_spec_q,
-    ] + seg_specs_dq + seed_specs
-    dq_kernel = functools.partial(_opt_refs_wrapper, _bwd_dq_kernel, 6,
-                                  has_seg, has_drop)
+    # ---- dQ: grid (b, hq, q_blocks, kv_majors); K and V resident, an
+    # in-kernel loop over key sub-tiles by class ----
+    bq, bk = _resolve_blocks(sq, sk, "dq", block_q, block_k)
+    kv_major = _major_block(
+        sk, bk, _row_bytes(d, k.dtype, 2)
+        + (NUM_SUBLANES * 4 if has_seg else 0))
+    major = functools.partial(
+        _last_live_major, causal=causal, block_q=bq, kv_major=kv_major,
+        q_offset=q_offset, kv_offset=kv_offset)
+    q_spec = pl.BlockSpec((1, 1, bq, d),
+                          lambda ib, ih, iq, ikm, *_: (ib, ih, iq, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, kv_major, d),
+        lambda ib, ih, iq, ikm, *_: (ib, ih // rep, major(iq, ikm), 0))
+    lane_spec = pl.BlockSpec((1, 1, bq, NUM_LANES),
+                             lambda ib, ih, iq, ikm, *_: (ib, ih, iq, 0))
+    ranges = _seg_ranges(q_seg, kv_seg, bq, bk)
+    seg_args = [_expand_q_ids(q_seg), _expand_kv_ids(kv_seg)] \
+        if has_seg else []
+    seg_specs = [
+        pl.BlockSpec((1, bq, NUM_LANES),
+                     lambda ib, ih, iq, ikm, *_: (ib, iq, 0)),
+        pl.BlockSpec((1, NUM_SUBLANES, kv_major),
+                     lambda ib, ih, iq, ikm, *_: (ib, 0, major(iq, ikm))),
+    ] if has_seg else []
     with jax.named_scope("hetu.flash_bwd"):
         dq = pl.pallas_call(
-            functools.partial(dq_kernel, causal=causal, block_q=block_q,
-                              block_k=block_k, kv_blocks=sk // block_k,
-                              q_offset=q_offset, kv_offset=kv_offset,
-                              dropout_rate=dropout_rate),
-            grid=(b, hq, sq // block_q, sk // block_k),
-            in_specs=dq_specs,
-            out_specs=pl.BlockSpec((1, 1, block_q, d),
-                                   lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), jnp.float32),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
+            functools.partial(
+                _bwd_dq_kernel, causal=causal, has_seg=has_seg,
+                block_q=bq, block_k=bk, kv_major=kv_major,
+                q_blocks=sq // bq, k_blocks=sk // bk, q_offset=q_offset,
+                kv_offset=kv_offset, dropout_rate=dropout_rate,
+                scale=scale),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(ranges),
+                grid=(b, hq, sq // bq, sk // kv_major),
+                in_specs=[q_spec, kv_spec, kv_spec, q_spec, lane_spec]
+                + seg_specs + seed_specs,
+                out_specs=q_spec,
+                scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+            compiler_params=params,
             interpret=interpret,
             name="hetu_flash_bwd_dq",
-        )(*args, *seg_args, *seed_args)
-    dq = (dq * scale).astype(q.dtype)  # undo the q-scale folding
+        )(*ranges, qf, k, v, do, columns, *seg_args, *seed_args)
 
-    # ---- dK/dV: grid (b, hq, kv_blocks, q_blocks), accumulate over q ----
-    # dK/dV are produced per *q* head (GQA read via index_map), then
-    # group-summed down to kv heads.
-    dkv_specs = [
-        pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, ik, iq: (ib, ih // rep, ik, 0)),
-        pl.BlockSpec((1, 1, block_k, d),
-                     lambda ib, ih, ik, iq: (ib, ih // rep, ik, 0)),
-        pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, 1, block_q, NUM_LANES),
-                     lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, 1, block_q, NUM_LANES),
-                     lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-    ] + seg_specs_dkv + seed_specs
-    dkv_kernel = functools.partial(_opt_refs_wrapper, _bwd_dkv_kernel, 6,
-                                   has_seg, has_drop)
-    kv_out_spec = pl.BlockSpec((1, 1, block_k, d),
-                               lambda ib, ih, ik, iq: (ib, ih, ik, 0))
+    # ---- dK/dV: grid (b, hq, kv_blocks, q_majors); Q, dO, lse, delta
+    # resident, an in-kernel loop over query sub-tiles from the diagonal
+    # on. Produced per *q* head (GQA read via index_map), then
+    # group-summed down to kv heads ----
+    bq, bk = _resolve_blocks(sq, sk, "dkv", block_q, block_k)
+    q_major = _major_block(
+        sq, bq, _row_bytes(d, q.dtype, 2)
+        + (3 if has_seg else 2) * NUM_SUBLANES * 4)
+
+    def major(ik, iqm):
+        # the first major query block not wholly above the key block's
+        # diagonal, for the grid steps before it
+        if not causal:
+            return iqm
+        return jnp.maximum(iqm, jnp.minimum(
+            _floordiv(ik * bk + kv_offset - q_offset, q_major),
+            sq // q_major - 1))
+
+    q_spec = pl.BlockSpec(
+        (1, 1, q_major, d),
+        lambda ib, ih, ik, iqm, *_: (ib, ih, major(ik, iqm), 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, d), lambda ib, ih, ik, iqm, *_: (ib, ih // rep, ik, 0))
+    row_spec = pl.BlockSpec(
+        (1, 1, NUM_SUBLANES, q_major),
+        lambda ib, ih, ik, iqm, *_: (ib, ih, 0, major(ik, iqm)))
+    # the ids the other way round: q's as a row, kv's as a column
+    ranges = _seg_ranges(q_seg, kv_seg, bq, bk)
+    seg_args = [_expand_kv_ids(q_seg), _expand_q_ids(kv_seg)] \
+        if has_seg else []
+    seg_specs = [
+        pl.BlockSpec((1, NUM_SUBLANES, q_major),
+                     lambda ib, ih, ik, iqm, *_: (ib, 0, major(ik, iqm))),
+        pl.BlockSpec((1, bk, NUM_LANES),
+                     lambda ib, ih, ik, iqm, *_: (ib, ik, 0)),
+    ] if has_seg else []
+    kv_out_spec = pl.BlockSpec(
+        (1, 1, bk, d), lambda ib, ih, ik, iqm, *_: (ib, ih, ik, 0))
     with jax.named_scope("hetu.flash_bwd"):
         dk, dv = pl.pallas_call(
-            functools.partial(dkv_kernel, causal=causal, block_q=block_q,
-                              block_k=block_k, q_blocks=sq // block_q,
-                              q_offset=q_offset, kv_offset=kv_offset,
-                              dropout_rate=dropout_rate),
-            grid=(b, hq, sk // block_k, sq // block_q),
-            in_specs=dkv_specs,
-            out_specs=[kv_out_spec, kv_out_spec],
-            out_shape=[jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32),
-                       jax.ShapeDtypeStruct((b, hq, sk, d), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
+            functools.partial(
+                _bwd_dkv_kernel, causal=causal, has_seg=has_seg,
+                block_q=bq, block_k=bk, q_major=q_major,
+                q_blocks=sq // bq, k_blocks=sk // bk, q_offset=q_offset,
+                kv_offset=kv_offset, dropout_rate=dropout_rate),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(ranges),
+                grid=(b, hq, sk // bk, sq // q_major),
+                in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                          row_spec] + seg_specs + seed_specs,
+                out_specs=[kv_out_spec, kv_out_spec],
+                scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                                pltpu.VMEM((bk, d), jnp.float32)]),
+            # fp32 only where the GQA group sum still follows
+            out_shape=[jax.ShapeDtypeStruct(
+                (b, hq, sk, d), jnp.float32 if rep > 1 else x.dtype)
+                for x in (k, v)],
+            compiler_params=params,
             interpret=interpret,
             name="hetu_flash_bwd_dkv",
-        )(*args, *seg_args, *seed_args)
+        )(*ranges, qf, k, v, do, *rows, *seg_args, *seed_args)
     if rep > 1:
         dk = dk.reshape(b, hkv, rep, sk, d).sum(axis=2)
         dv = dv.reshape(b, hkv, rep, sk, d).sum(axis=2)

@@ -15,8 +15,11 @@ The vocabulary (all start ``hetu.``; ``docs/OBSERVABILITY.md``):
 ====================  ================================================
 ``hetu.loss``         the differentiated loss of a train step
 ``hetu.opt``          grad norm, clip, optimizer update, apply
-``hetu.flash_fwd``    the flash attention forward kernel (its Pallas call)
-``hetu.flash_bwd``    the flash backward kernels (dq and dk/dv)
+``hetu.flash_fwd``    the flash forward kernel: ONE Pallas call a layer
+                      (``hetu_flash_fwd``; its key tiles are an in-kernel
+                      loop over a resident K / V, not grid steps)
+``hetu.flash_bwd``    the flash backward kernels: TWO Pallas calls a layer
+                      (``hetu_flash_bwd_dq``, ``hetu_flash_bwd_dkv``)
 ``hetu.paged_attn``   the paged decode attention kernel
 ``hetu.fused_ce``     the fused LM-head cross-entropy kernels
 ``hetu.prefill_lane`` the fused serving step's packed prefill lane

@@ -112,11 +112,11 @@ def test_tuned_block_defaults_lookup():
         assert fp._default_blocks(4096, 4096, "fwd") == (512, 1024)
         # unmeasured seq -> static heuristic
         assert fp._default_blocks(2048, 2048, "fwd") == \
-            (fp._pick_block(2048), fp._pick_block(2048))
+            (fp._pick_block(2048, fp._TILE), fp._pick_block(2048, fp._TILE))
         # measured q-seq but kv length the tuned block doesn't divide
         # (ring hop with ragged kv) -> fallback
         assert fp._default_blocks(1024, 384, "fwd") == \
-            (fp._pick_block(1024), fp._pick_block(384))
+            (fp._pick_block(1024, fp._TILE), fp._pick_block(384, fp._TILE))
     finally:
         fp._tuned_entries = orig
 
@@ -242,3 +242,261 @@ def test_flash_dropout_lse_and_determinism(rng):
     m = _dropout_keep(seed[0], 0, 0, 0, 0, rate=rate, block_q=256,
                       block_k=256, q_offset=0, kv_offset=0)
     assert abs(float(m.mean()) - (1 - rate)) < 0.02
+
+
+# --------------------------------------------------------------------------
+# Tiles by class: the in-kernel loops over resident major blocks
+# --------------------------------------------------------------------------
+
+def _ids(*runs):
+    """(1, s) int32 ids from (id, length) runs."""
+    return jnp.asarray(np.concatenate(
+        [np.full(n, i, np.int32) for i, n in runs])[None])
+
+
+def _kernel_fwd_bwd(q, k, v, do, q_seg, kv_seg, *, causal, q_offset=0,
+                    kv_offset=0, block=128):
+    """(out, lse, dq, dk, dv) of the three kernels at ``block``² tiles
+    ((b, s, h, d) layout, interpret mode)."""
+    from hetu_tpu.ops.flash_pallas import _flash_bwd, _flash_fwd
+    qh, kh, vh, doh = (jnp.swapaxes(x, 1, 2) for x in (q, k, v, do))
+    kw = dict(causal=causal, scale=q.shape[-1] ** -0.5, q_offset=q_offset,
+              kv_offset=kv_offset, interpret=True, block_q=block,
+              block_k=block)
+    out, lse = _flash_fwd(qh, kh, vh, q_seg, kv_seg, **kw)
+    grads = _flash_bwd(qh, kh, vh, q_seg, kv_seg, out, lse, doh, **kw)
+    return (jnp.swapaxes(out, 1, 2), lse,
+            *(jnp.swapaxes(g, 1, 2) for g in grads))
+
+
+def _reference_fwd_bwd(q, k, v, do, q_seg, kv_seg, *, causal, q_offset=0,
+                       kv_offset=0):
+    def f(q, k, v):
+        return attention_reference(
+            q, k, v, causal=causal, segment_ids=q_seg,
+            kv_segment_ids=kv_seg, q_offset=q_offset, kv_offset=kv_offset,
+            return_lse=True)
+    (out, lse), vjp = jax.vjp(f, q, k, v)
+    return (out, lse, *vjp((do, jnp.zeros_like(lse))))
+
+
+def _assert_matches(got, want):
+    for a, b, name, tol in zip(got, want, ("out", "lse", "dq", "dk", "dv"),
+                               (2e-5, 2e-5, 5e-5, 5e-5, 5e-5)):
+        np.testing.assert_allclose(a, b, atol=tol, rtol=tol, err_msg=name)
+
+
+# packed rows that meet every class boundary at tiles of 128
+_PACKED = {
+    "doc_ends_on_tile": [(0, 256), (1, 128)],
+    "doc_ends_inside_tile": [(0, 300), (1, 84)],
+    "two_half_rows": [(0, 256), (1, 256)],
+    "trailing_pad": [(0, 200), (1, 250), (2, 62)],
+    "ids_not_ascending": [(3, 130), (1, 126), (2, 128)],
+    "runs_3_3_1_1_2": [(3, 128), (3, 128), (1, 128), (1, 128), (2, 128)],
+}
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("runs", list(_PACKED.values()), ids=list(_PACKED))
+def test_flash_packed_rows_by_class_match_reference(rng, runs, causal):
+    """Forward AND gradients on packed rows whose documents end on, and
+    inside, a 128-tile: dead tiles skipped, interior tiles unmasked and
+    edge tiles masked give the oracle's result (GQA 4:2)."""
+    seg = _ids(*runs)
+    s = seg.shape[1]
+    q, k, v = _rand_qkv(rng, 1, s, s, 4, 2, 64)
+    do = jax.random.normal(jax.random.fold_in(rng, 9), q.shape, q.dtype)
+    _assert_matches(
+        _kernel_fwd_bwd(q, k, v, do, seg, seg, causal=causal),
+        _reference_fwd_bwd(q, k, v, do, seg, seg, causal=causal))
+
+
+@pytest.mark.parametrize("case", [
+    # a ring hop wholly above the diagonal: every tile dead
+    dict(sq=256, sk=256, q_offset=0, kv_offset=256, dead=True),
+    # the same by ids alone: no id on both sides
+    dict(sq=256, sk=256, q_seg=[(5, 256)], kv_seg=[(7, 256)], causal=False,
+         dead=True),
+    # the diagonal falls inside the resident block, rows 0..127 see
+    # keys 0..128+i only
+    dict(sq=256, sk=384, q_offset=128, kv_offset=0),
+    dict(sq=256, sk=384, q_offset=128, kv_offset=0,
+         q_seg=[(1, 100), (2, 156)], kv_seg=[(0, 128), (1, 100), (2, 156)]),
+    # keys start below the queries: part of every row is live
+    dict(sq=256, sk=256, q_offset=0, kv_offset=130),
+], ids=["all_dead_offsets", "all_dead_ids", "diagonal_inside",
+        "diagonal_inside_ids", "ragged_offsets"])
+def test_flash_offsets_and_dead_calls(rng, case):
+    """``q_offset`` / ``kv_offset`` move the loops' bounds; a call with
+    no live tile gives the exact zero (out 0, LSE NEG_INF, no
+    gradient)."""
+    from hetu_tpu.ops.flash_pallas import NEG_INF
+    case = dict(case)
+    dead, causal = case.pop("dead", False), case.pop("causal", True)
+    sq, sk = case.pop("sq"), case.pop("sk")
+    q_seg, kv_seg = (_ids(*case.pop(x)) if x in case else None
+                     for x in ("q_seg", "kv_seg"))
+    q, k, v = _rand_qkv(rng, 1, sq, sk, 4, 2, 64)
+    do = jax.random.normal(jax.random.fold_in(rng, 9), q.shape, q.dtype)
+    got = _kernel_fwd_bwd(q, k, v, do, q_seg, kv_seg, causal=causal, **case)
+    _assert_matches(got, _reference_fwd_bwd(q, k, v, do, q_seg, kv_seg,
+                                            causal=causal, **case))
+    if dead:
+        out, lse, *grads = got
+        assert not np.asarray(out).any()
+        assert (np.asarray(lse) == np.float32(NEG_INF)).all()
+        assert not any(np.asarray(g).any() for g in grads)
+
+
+@pytest.mark.parametrize("case", [
+    dict(causal=True, seg=[(0, 300), (1, 212)]),
+    dict(causal=False, seg=[(3, 130), (1, 254), (2, 128)]),
+    dict(causal=True, q_offset=128, kv_offset=0),
+], ids=["causal_ids", "full_ids", "offsets"])
+def test_flash_several_major_blocks_match_one(rng, monkeypatch, case):
+    """A budget too small for a head's K and V (and for a head's Q and
+    dO) streams major blocks of one tile: the same kernels, the same
+    result as the one-block form and as the oracle."""
+    from hetu_tpu.ops import flash_pallas as fp
+    case = dict(case)
+    causal = case.pop("causal")
+    seg = _ids(*case.pop("seg")) if "seg" in case else None
+    q, k, v = _rand_qkv(rng, 1, 512, 512, 4, 2, 64)
+    do = jax.random.normal(jax.random.fold_in(rng, 9), q.shape, q.dtype)
+    assert fp._major_block(512, 128, 1024) == 512
+    one = _kernel_fwd_bwd(q, k, v, do, seg, seg, causal=causal, **case)
+    monkeypatch.setattr(fp, "_RESIDENT_BYTES", 1)
+    assert fp._major_block(512, 128, 1024) == 128
+    several = _kernel_fwd_bwd(q, k, v, do, seg, seg, causal=causal, **case)
+    _assert_matches(several, one)
+    _assert_matches(several, _reference_fwd_bwd(q, k, v, do, seg, seg,
+                                                causal=causal, **case))
+
+
+def test_major_block_is_the_largest_divisor_that_fits(monkeypatch):
+    from hetu_tpu.ops import flash_pallas as fp
+    monkeypatch.setattr(fp, "_RESIDENT_BYTES", 2 * 3000 * 512)
+    # 8192 keys at 512 B each, double buffered: 3000 fit -> 2048 (the
+    # largest divisor of 8192 / 256 tiles within it), never under a tile
+    assert fp._major_block(8192, 256, 512) == 2048
+    assert fp._major_block(1024, 256, 512) == 1024
+    assert fp._major_block(768, 256, 512) == 768
+    assert fp._major_block(8192, 256, 10 ** 9) == 256
+
+
+def _count_visits(monkeypatch):
+    """Count, in interpret mode, the tiles the kernels' loops VISIT:
+    ``visits[(pass, masked)]`` — every visit is one call of the tile
+    function ``_walk`` is handed (the forward walks its tiles twice:
+    its first pass, ``score_tile``, is counted)."""
+    import collections
+    from hetu_tpu.ops import flash_pallas as fp
+    visits = collections.Counter()
+    walk = fp._walk
+
+    def counting_walk(start, stop, tile, ranges, causal_mask, *rest):
+        which = {"score_tile": "fwd", "tile": "bwd"}.get(tile.__name__)
+
+        def counted(j, causal_mask, seg_mask):
+            if which:
+                key = (which, bool(causal_mask or seg_mask))
+                jax.debug.callback(lambda: visits.update([key]))
+            tile(j, causal_mask, seg_mask)
+        return walk(start, stop, counted, ranges, causal_mask, *rest)
+
+    monkeypatch.setattr(fp, "_walk", counting_walk)
+    return visits
+
+
+def test_tile_classes_equal_the_kernels_visits(rng, monkeypatch):
+    """The host's class function counts what the kernels' loop bounds
+    and prefetched ranges visit: interior = visited without a mask, edge
+    = visited with one, dead = never visited — ids that do not ascend,
+    a document ending inside a tile, a trailing pad."""
+    from hetu_tpu.ops.flash_pallas import tile_classes
+    seg = jnp.concatenate([_ids((3, 130), (1, 254), (2, 128)),
+                           _ids((0, 384), (1, 128))])
+    heads = 2
+    q, k, v = _rand_qkv(rng, 2, 512, 512, heads, heads, 64)
+    visits = _count_visits(monkeypatch)
+    jax.block_until_ready(
+        _kernel_fwd_bwd(q, k, v, q, seg, seg, causal=True))
+    jax.effects_barrier()
+    dead, interior, edge = tile_classes(
+        np.asarray(seg), np.asarray(seg), sq=512, sk=512, block_q=128,
+        block_k=128, causal=True)
+    assert dead + interior + edge == 2 * 16 and dead and interior and edge
+    assert visits["fwd", False] == heads * interior
+    assert visits["fwd", True] == heads * edge
+    # dq and dk/dv each walk every live tile once
+    assert visits["bwd", False] == 2 * heads * interior
+    assert visits["bwd", True] == 2 * heads * edge
+
+
+def test_flash_tiles_counter_equals_the_kernels_visits(rng, monkeypatch):
+    """``flash_tiles_total{pass, class}``, counted by the trainer from a
+    hand-made batch's ``segment_ids``, equals the tiles one head's calls
+    visit at the kernels' default tiles."""
+    from hetu_tpu import telemetry
+    from hetu_tpu.engine.trainer import Trainer
+    from hetu_tpu.ops import flash_pallas as fp
+
+    s, d = 1024, 64
+    seg = np.concatenate([np.asarray(_ids((0, 700), (1, 324))),
+                          np.asarray(_ids((0, 512), (1, 512)))])
+    telemetry.enable(True)
+    try:
+        registry = telemetry.get_registry()
+        counter = registry.counter("flash_tiles_total")
+        labels = [(p, c) for p in ("fwd", "bwd")
+                  for c in ("dead", "interior", "edge")]
+        before = {pc: counter.value(**{"pass": pc[0], "class": pc[1]})
+                  for pc in labels}
+        trainer = Trainer.__new__(Trainer)      # the counter alone
+        trainer.registry = registry
+        batch = {"segment_ids": seg}
+        assert trainer._count_flash_tiles(batch) is batch
+        counted = {pc: counter.value(**{"pass": pc[0], "class": pc[1]})
+                   - before[pc] for pc in labels}
+    finally:
+        telemetry.enable(False)
+
+    visits = _count_visits(monkeypatch)
+    q, k, v = _rand_qkv(rng, 2, s, s, 1, 1, d)
+    qh = jnp.swapaxes(q, 1, 2)
+    out, lse = fp._flash_fwd(qh, qh, qh, seg, seg, causal=True, scale=1.0,
+                             interpret=True)
+    jax.block_until_ready(fp._flash_bwd(
+        qh, qh, qh, seg, seg, out, lse, qh, causal=True, scale=1.0,
+        interpret=True))
+    jax.effects_barrier()
+    assert counted["fwd", "interior"] == visits["fwd", False]
+    assert counted["fwd", "edge"] == visits["fwd", True]
+    assert counted["bwd", "interior"] == visits["bwd", False]
+    assert counted["bwd", "edge"] == visits["bwd", True]
+    for which, kinds in (("fwd", ("fwd",)), ("bwd", ("dq", "dkv"))):
+        tiles = sum(2 * (s // bq) * (s // bk) for bq, bk in
+                    (fp._default_blocks(s, s, x) for x in kinds))
+        assert sum(counted[which, c] for c in
+                   ("dead", "interior", "edge")) == tiles
+
+
+def test_flash_row_no_multiple_of_128(rng):
+    """A row of 96 is one whole tile of no 128-lane chunks: the
+    forward's per-lane folds reduce it at once (``_lane_fold``)."""
+    seg = _ids((0, 50), (1, 46))
+    q, k, v = _rand_qkv(rng, 1, 96, 96, 2, 2, 64)
+
+    def loss(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    got = loss(lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, segment_ids=seg, interpret=True))
+    want = loss(lambda q, k, v: attention_reference(
+        q, k, v, causal=True, segment_ids=seg))
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)

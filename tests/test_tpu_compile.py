@@ -59,9 +59,19 @@ def _no_compile_cache():
     dict(seg=True),
     dict(dropout_rate=0.1),
     dict(shape=(2, 512, 8, 64), kv_heads=2, seg=True),
-], ids=["plain", "segment_ids", "dropout", "gqa_segment_ids"])
+    dict(shape=(32, 1024, 12, 64), seg=True),
+    dict(shape=(1, 8192, 16, 64), seg=True),
+    dict(shape=(1, 256, 20, 64), seg=True, lse=True, dtype=jnp.float32),
+    dict(shape=(1, 512, 128, 128), kv_heads=8, seg=True, lse=True),
+], ids=["plain", "segment_ids", "dropout", "gqa_segment_ids",
+        "pretrain_cell", "major_blocks_8k", "lse_gpt2_large_row",
+        "lse_gqa128x8_d128_row"])
 def test_flash_fwd_bwd_compiles_for_v5e(one_chip, kw):
-    """Flash forward + both backward kernels at (4, 1024, 12, 64)."""
+    """Flash forward + both backward kernels at (4, 1024, 12, 64); the
+    pretrain cell's batch; a sequence of several major blocks; and the
+    forward that returns the LSE on the serving packs' one row
+    (``gpt2-large``: float32 activations, 20 heads of 64;
+    ``command-a-plus-ep8``: 128 heads of 128 over 8 KV heads)."""
     from workloads.aot_check import check_flash
     assert "compile_s" in check_flash(list(one_chip.device_set), **kw)
 

@@ -72,13 +72,23 @@ def _sds(shape, dtype, mesh, spec=P()):
 
 def check_flash(devs, *, shape=(4, 1024, 12, 64), kv_heads=None,
                 seg=False, block_q=None, block_k=None,
-                dropout_rate=0.0):
+                dropout_rate=0.0, lse=False, dtype=jnp.bfloat16):
+    """The flash forward and both backward kernels (grad of a sum), or —
+    ``lse`` — the forward that returns the LSE, as the serving prefill
+    lane calls it on one packed row (``attention_with_lse``)."""
     from hetu_tpu.ops.flash_pallas import flash_attention_pallas as fa
     mesh = _one_dev_mesh(devs)
     b, s, h, d = shape
-    q = _sds((b, s, h, d), jnp.bfloat16, mesh)
-    kv = _sds((b, s, kv_heads or h, d), jnp.bfloat16, mesh)
+    q = _sds((b, s, h, d), dtype, mesh)
+    kv = _sds((b, s, kv_heads or h, d), dtype, mesh)
     segs = _sds((b, s), jnp.int32, mesh) if seg else None
+    if lse:
+        from hetu_tpu.ops.attention import attention_with_lse
+        return _compile_kernel(
+            lambda q, k, v, *ids: attention_with_lse(
+                q, k, v, causal=True, segment_ids=ids[0] if ids else None,
+                impl="pallas", interpret=False),
+            (q, kv, kv) + ((segs,) if seg else ()))
     # dropout: the SMEM seed operand + uint32 counter-RNG must lower in
     # Mosaic (interpret mode can never catch a Mosaic-only rejection)
     key = _sds((), jnp.uint32, mesh) if dropout_rate > 0 else None
@@ -524,6 +534,16 @@ def main():
                                            kv_heads=2)),
         ("flash_packed_segids", lambda: check_flash(d1, seg=True)),
         ("flash_d128", lambda: check_flash(d1, shape=(2, 1024, 8, 128))),
+        # K and V of a head past the resident budget: major blocks
+        ("flash_8k_major_blocks",
+         lambda: check_flash(d1, shape=(1, 8192, 16, 64), seg=True)),
+        # the serving packs' one row, the forward that returns the LSE
+        ("flash_lse_gpt2_large_c256",
+         lambda: check_flash(d1, shape=(1, 256, 20, 64), seg=True,
+                             lse=True, dtype=jnp.float32)),
+        ("flash_lse_gqa128x8_d128_c512",
+         lambda: check_flash(d1, shape=(1, 512, 128, 128), kv_heads=8,
+                             seg=True, lse=True)),
         ("fused_ce_bench_vocab", lambda: check_fused_ce(d1)),
         ("paged_decode_bf16", lambda: check_paged(d1)),
         ("paged_verify5_int8_lse",

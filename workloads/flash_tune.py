@@ -1,17 +1,24 @@
-"""Flash-kernel block-size autotune on the real chip.
+"""Flash-kernel compute-tile autotune on the real chip.
 
-Sweeps (block_q, block_k) for fwd and fwd+bwd at representative shapes —
-including the bench shape (batch 32, heads 12, seq 1024) — and records the
-winners to ``workloads/out/flash_blocks.json``, which
-``ops.flash_pallas`` consults for its default tiling on TPU.
+Sweeps the compute sub-tile (block_q, block_k) of the three kernels
+APART — forward, dq, dk/dv — at representative shapes, the bench shape
+(batch 32, heads 12, seq 1024) first, on PACKED rows: segment ids drawn
+as ``pretrain-packed-1k`` draws them (documents of half a row to a whole
+row, first-fit packed, a trailing pad id), so the rows have the dead,
+interior and edge tiles the kernels walk by class
+(``ops.flash_pallas.tile_classes``, printed beside each time). The
+winners go to ``workloads/out/flash_blocks.json``, which
+``ops.flash_pallas`` consults for its default tiling on TPU ("bwd": the
+tile at which dq + dk/dv is least).
 
 Timing runs the kernel inside ONE jit via ``lax.scan`` (iterations
 chained through a negligible 1e-30 feedback term so XLA cannot hoist or
 dead-code them): per-call dispatch costs host time, which would
 otherwise swamp sub-ms kernels and make every block choice look
-identical.
+identical. The backward's two calls are timed apart by taking only dq,
+or only dk + dv, of ``_flash_bwd``: XLA drops the call nobody reads.
 
-Usage: python workloads/flash_tune.py [--iters 32]
+Usage: python workloads/flash_tune.py [--shapes N] [--seed S]
 """
 
 from __future__ import annotations
@@ -25,9 +32,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from hetu_tpu.ops.flash_pallas import flash_attention_pallas
-from workloads._timing import scan_loop, scan_loop_grad, time_loop_ms
+from hetu_tpu.data.packing import pack_sequences
+from hetu_tpu.ops.flash_pallas import _flash_bwd, _flash_fwd, tile_classes
+from workloads._timing import scan_loop, time_loop_ms
 
 OUT_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "out", "flash_blocks.json")
@@ -38,65 +47,100 @@ OUT_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 SHAPES = [(32, 1024, 12, 64, 32), (4, 2048, 16, 64, 32),
           (2, 4096, 16, 64, 16), (1, 8192, 16, 64, 8),
           (1, 32768, 16, 64, 4)]
+KERNELS = ("fwd", "dq", "dkv")
 
 
+def packed_segment_ids(rows: int, seq: int, rng) -> np.ndarray:
+    """(rows, seq) ids as the train cell's loader packs them: documents
+    of seq/2..seq tokens (uniform), first fit, a trailing pad id."""
+    docs = [np.zeros(n, np.int32)
+            for n in rng.integers(seq // 2, seq + 1, 2 * rows)]
+    return pack_sequences(docs, seq).segment_ids[:rows]
+
+
+def kernel_fn(kind, seg, scale, bq, bk):
+    """``fn(q, k, v, out, lse, do) -> (b, h, s, d)``: one kernel alone."""
+    kw = dict(causal=True, scale=scale, block_q=bq, block_k=bk)
+    if kind == "fwd":
+        return lambda q, k, v, *_: _flash_fwd(q, k, v, seg, seg, **kw)[0]
+
+    def bwd(q, k, v, out, lse, do):
+        dq, dk, dv = _flash_bwd(q, k, v, seg, seg, out, lse, do, **kw)
+        return dq if kind == "dq" else dk + dv
+    return bwd
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--iters", type=int, default=32)
+    ap.add_argument("--shapes", type=int, default=len(SHAPES),
+                    help="sweep the first N shapes")
+    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
     if jax.devices()[0].platform != "tpu":
         print(json.dumps({"error": "autotune needs the TPU chip"}))
         return
-    kind = jax.devices()[0].device_kind
+    kind_name = jax.devices()[0].device_kind
+    rng = np.random.default_rng(args.seed)
 
     entries = []
-    for b, s, h, d, iters in SHAPES:
-        q = jax.random.normal(jax.random.key(0), (b, s, h, d), jnp.bfloat16)
-        k = jax.random.normal(jax.random.key(1), (b, s, h, d), jnp.bfloat16)
-        v = jax.random.normal(jax.random.key(2), (b, s, h, d), jnp.bfloat16)
+    for b, s, h, d, iters in SHAPES[:args.shapes]:
+        q, k, v, do = (jax.random.normal(jax.random.key(i), (b, h, s, d),
+                                         jnp.bfloat16) for i in range(4))
+        seg_np = packed_segment_ids(b, s, rng)
+        seg = jnp.asarray(seg_np)
+        scale = d ** -0.5
+        out, lse = jax.jit(lambda q, k, v: _flash_fwd(
+            q, k, v, seg, seg, causal=True, scale=scale))(q, k, v)
         blocks = [x for x in (128, 256, 512, 1024) if s % x == 0]
         if s >= 16384:
             # long-context: each config costs seconds of device time plus
             # a long compile — only sweep the plausible tilings
             blocks = [x for x in blocks if x >= 512]
-        rows = []
+        rows = {}
         for bq in blocks:
             for bk in blocks:
-                def f(q, k, v, bq=bq, bk=bk):
-                    return flash_attention_pallas(
-                        q, k, v, causal=True, interpret=False,
-                        block_q=bq, block_k=bk)
-                try:
-                    f_ms = time_loop_ms(scan_loop(f, iters),
-                                        (q, k, v), iters)
-                    b_ms = time_loop_ms(scan_loop_grad(f, iters),
-                                        (q, k, v), iters)
-                except Exception as e:
-                    rows.append({"bq": bq, "bk": bk, "error": str(e)[:80]})
-                    continue
-                rec = {"bq": bq, "bk": bk, "fwd_ms": round(f_ms, 3),
-                       "bwd_ms": round(b_ms, 3)}
-                rows.append(rec)
-                print(json.dumps({"shape": [b, s, h, d], **rec}), flush=True)
-        ok = [r for r in rows if "fwd_ms" in r]
-        if ok:
-            best_f = min(ok, key=lambda r: r["fwd_ms"])
-            best_b = min(ok, key=lambda r: r["bwd_ms"])
-            entries.append({"seq": s, "batch": b, "heads": h, "head_dim": d,
-                            "fwd": [best_f["bq"], best_f["bk"]],
-                            "bwd": [best_b["bq"], best_b["bk"]],
-                            "fwd_ms": best_f["fwd_ms"],
-                            "bwd_ms": best_b["bwd_ms"]})
+                dead, interior, edge = (int(n) for n in tile_classes(
+                    seg_np, seg_np, sq=s, sk=s, block_q=bq, block_k=bk,
+                    causal=True))
+                rec = {"bq": bq, "bk": bk, "dead": dead,
+                       "interior": interior, "edge": edge}
+                for kind in KERNELS:
+                    try:
+                        rec[f"{kind}_ms"] = round(time_loop_ms(
+                            scan_loop(kernel_fn(kind, seg, scale, bq, bk),
+                                      iters),
+                            (q, k, v, out, lse, do), iters), 3)
+                    except Exception as e:
+                        rec[f"{kind}_error"] = str(e)[:80]
+                rows[bq, bk] = rec
+                print(json.dumps({"shape": [b, s, h, d], **rec}),
+                      flush=True)
+
+        def best(*kinds):
+            ok = [r for r in rows.values()
+                  if all(f"{x}_ms" in r for x in kinds)]
+            return min(ok, key=lambda r: sum(r[f"{x}_ms"] for x in kinds)) \
+                if ok else None
+
+        best_f, best_b = best("fwd"), best("dq", "dkv")
+        if best_f and best_b:
+            entries.append({
+                "seq": s, "batch": b, "heads": h, "head_dim": d,
+                "fwd": [best_f["bq"], best_f["bk"]],
+                "bwd": [best_b["bq"], best_b["bk"]],
+                "fwd_ms": best_f["fwd_ms"],
+                "bwd_ms": round(best_b["dq_ms"] + best_b["dkv_ms"], 3)})
             print(json.dumps({"seq": s, "best_fwd": best_f,
+                              "best_dq": best("dq"),
+                              "best_dkv": best("dkv"),
                               "best_bwd": best_b}), flush=True)
 
     if entries:
         os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
         with open(OUT_PATH, "w") as f:
-            json.dump({"device": kind, "entries": entries}, f, indent=1)
+            json.dump({"device": kind_name, "entries": entries}, f,
+                      indent=1)
         print(f"wrote {OUT_PATH}")
 
 

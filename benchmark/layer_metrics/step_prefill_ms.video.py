@@ -1,0 +1,13 @@
+"""Device self time per engine iteration in the packed prefill lane
+(``hetu.prefill_lane``: the chunk's projections, convolution and chunk
+scan in ten layers, the latent history read in two, the dense, shared
+and expert matmuls; arena writes and sampling not)
+(``program_trace``)."""
+NAME, UNIT = "step_prefill_ms.video", "ms"
+LAYER = "fused serving step (serving/engine.py)"
+MOVES = "serve_tokens_per_s"
+
+
+def read(run):
+    from benchmark import program_trace
+    return program_trace.device_ms_per_step(run, "prefill")

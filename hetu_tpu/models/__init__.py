@@ -1,7 +1,7 @@
 """Model zoo: GPT-2, Llama, Command A+ (cohere2_moe) and latent-attention
-(MLA) expert decoders and MiniCPM-SALA (``mla_moe``, ``minicpm_sala``:
-loaded on first use, so that the cells that never build one do not pay
-for its import).
+(MLA) expert decoders, MiniCPM-SALA and delta-rule / latent hybrids
+(``mla_moe``, ``minicpm_sala``, ``kda_mla_moe``: loaded on first use,
+so that the cells that never build one do not pay for its import).
 
 Parity targets: ``python/hetu/models/gpt`` and
 ``python/hetu/models/llama/llama_model.py`` (LlamaModel :385,
@@ -22,7 +22,9 @@ from hetu_tpu.models.generation import generate, decode, init_kv_caches
 _LAZY = {"MLAMoEConfig": "hetu_tpu.models.mla_moe",
          "MLAMoEForCausalLM": "hetu_tpu.models.mla_moe",
          "MiniCPMSALAConfig": "hetu_tpu.models.minicpm_sala",
-         "MiniCPMSALAForCausalLM": "hetu_tpu.models.minicpm_sala"}
+         "MiniCPMSALAForCausalLM": "hetu_tpu.models.minicpm_sala",
+         "KDAMLAMoEConfig": "hetu_tpu.models.kda_mla_moe",
+         "KDAMLAMoEForCausalLM": "hetu_tpu.models.kda_mla_moe"}
 
 
 def __getattr__(name):
@@ -36,4 +38,5 @@ __all__ = ["GPTConfig", "GPTLMHeadModel", "LlamaConfig", "BertConfig", "BertMode
            "Cohere2MoEConfig", "Cohere2MoEForCausalLM",
            "MLAMoEConfig", "MLAMoEForCausalLM",
            "MiniCPMSALAConfig", "MiniCPMSALAForCausalLM",
+           "KDAMLAMoEConfig", "KDAMLAMoEForCausalLM",
            "generate", "decode", "init_kv_caches"]

@@ -827,6 +827,25 @@ def count_local_share(sizes) -> None:
             per.inc(float(n), expert=str(e))
 
 
+def count_group_held(values) -> None:
+    """A group-limited :class:`ExpertShareMoE`'s counters, on the host:
+    ``values`` ``(layer calls, 2)`` int — per layer of one executed
+    scan, the tokens whose kept groups reach an expert held here, and
+    the tokens routed (``return_stats=True``)."""
+    from hetu_tpu import telemetry
+    import numpy as np
+    reg = telemetry.get_registry()
+    held, tokens = np.asarray(values, np.int64).sum(axis=0).tolist()
+    reg.counter(
+        "moe_group_held_total",
+        "tokens whose kept routing groups include one held on this "
+        "chip, summed over layer calls").inc(float(held))
+    reg.counter(
+        "moe_group_tokens_total",
+        "tokens routed by a group-limited expert layer, summed over "
+        "layer calls").inc(float(tokens))
+
+
 class ExpertShareMoE(Module):
     """A routed-expert layer that holds ``local_experts = (first,
     count)`` of ``num_experts`` SwiGLU experts — one chip's share of an
@@ -837,7 +856,13 @@ class ExpertShareMoE(Module):
     (normalised over ALL k chosen, held here or not). With
     ``select_bias`` the choice is by ``s + b`` (a per-expert bias, a
     parameter) while the weights stay ``s``'s; ``scale`` multiplies
-    them. The layer returns
+    them. With ``n_group`` > 1 the choice is GROUP-LIMITED: the experts
+    stand in ``n_group`` groups of consecutive experts, a group's score
+    is the sum of its two largest selection scores, only the
+    ``topk_group`` best groups stay, and the ``k`` largest selection
+    scores inside them are chosen (in a deployment that holds a group a
+    chip, a token reaches at most ``topk_group`` chips); at 1 / 1 the
+    route is the ungrouped one to the bit. The layer returns
     ``sum_{e chosen, first <= e < first + count} w_e E_e(x)`` — what the
     absent experts would have added is left out, here and in the
     reference alike (``benchmark/reference/cohere2_moe.py``). No token
@@ -863,9 +888,17 @@ class ExpertShareMoE(Module):
     def __init__(self, features: int, hidden: int, num_experts: int, *,
                  k: int, local_experts: Optional[tuple] = None,
                  select_bias: bool = False,
-                 scale: Optional[float] = None, init=None):
+                 scale: Optional[float] = None, n_group: int = 1,
+                 topk_group: int = 1, init=None):
         super().__init__()
         first, count = local_experts or (0, num_experts)
+        if num_experts % n_group or not 1 <= topk_group <= n_group \
+                or k > topk_group * (num_experts // n_group) \
+                or (n_group > 1 and num_experts // n_group < 2):
+            raise ValueError(
+                f"top-{k} of {topk_group} of {n_group} groups of "
+                f"{num_experts} experts")
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         if not (0 <= first and count >= 1
                 and first + count <= num_experts):
             raise ValueError(f"local_experts {local_experts} outside "
@@ -892,12 +925,44 @@ class ExpertShareMoE(Module):
         self.param("wo", (count, hidden, features), init,
                    axes=("expert", "mlp", "embed"))
 
-    def route(self, params, x):
-        """x (T, d) -> (experts (T, k) int32, weights (T, k) float32)."""
+    def _window_rows(self, pairs: int) -> int:
+        """Sorted rows a call of the grouped matmuls takes: all the
+        ``pairs`` where every expert is held; for a share twice the
+        pairs it expects (``pairs x count / num_experts``), in whole
+        lane tiles."""
+        count = self.local_experts[1]
+        if count == self.num_experts:
+            return pairs
+        want = max(2 * pairs * count // self.num_experts, 128)
+        return min(pairs, -(-want // 128) * 128)
+
+    def _kept_groups(self, sel):
+        """``sel (T, E)`` selection scores -> ``(T, n_group)`` bool: the
+        ``topk_group`` groups with the largest sum of their two best."""
+        per = sel.reshape(sel.shape[0], self.n_group, -1)
+        score = jax.lax.top_k(per, 2)[0].sum(-1)
+        _, best = jax.lax.top_k(score, self.topk_group)
+        return jnp.any(
+            best[:, :, None] == jnp.arange(self.n_group)[None, None, :],
+            axis=1)
+
+    def route(self, params, x, *, return_kept: bool = False):
+        """x (T, d) -> (experts (T, k) int32, weights (T, k) float32)
+        [, the kept groups (T, n_group) bool]."""
         z = jnp.matmul(x.astype(jnp.float32),
                        params["router"].astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
-        if self.select_bias:
+        kept = jnp.ones((x.shape[0], 1), bool)
+        if self.n_group > 1:
+            s = jax.nn.sigmoid(z)
+            sel = s + params["select_bias"].astype(jnp.float32) \
+                if self.select_bias else s
+            kept = self._kept_groups(sel)
+            _, idx = jax.lax.top_k(jnp.where(
+                jnp.repeat(kept, self.num_experts // self.n_group, axis=1),
+                sel, -jnp.inf), self.k)
+            top = jnp.take_along_axis(s, idx, axis=-1)
+        elif self.select_bias:
             s = jax.nn.sigmoid(z)
             _, idx = jax.lax.top_k(
                 s + params["select_bias"].astype(jnp.float32), self.k)
@@ -907,11 +972,17 @@ class ExpertShareMoE(Module):
         w = top / top.sum(-1, keepdims=True)
         if self.scale is not None:
             w = w * self.scale
+        if return_kept:
+            return idx.astype(jnp.int32), w, kept
         return idx.astype(jnp.int32), w
 
-    def __call__(self, params, x, *, return_sizes: bool = False):
+    def __call__(self, params, x, *, return_sizes: bool = False,
+                 return_stats: bool = False):
         """``return_sizes``: also the ``(count,)`` int32 numbers of
-        (token, choice) pairs each held expert got — ``(out, sizes)``."""
+        (token, choice) pairs each held expert got — ``(out, sizes)``.
+        ``return_stats``: ``(out, {"sizes": ..., "group_held": (2,)
+        int32})`` — beside them the tokens whose kept groups hold an
+        expert held here, and the tokens routed."""
         dt = self.compute_dtype()
         d = x.shape[-1]
         xf = x.reshape(-1, d)
@@ -919,7 +990,7 @@ class ExpertShareMoE(Module):
         M = xf.shape[0] * k
         first, count = self.local_experts
         with jax.named_scope("hetu.moe_route"):
-            idx, w = self.route(params, xf)
+            idx, w, kept = self.route(params, xf, return_kept=True)
             local = idx - first
             mine = (local >= 0) & (local < count)
             # pairs held elsewhere sort behind every local group
@@ -928,29 +999,78 @@ class ExpertShareMoE(Module):
             sizes = jnp.sum(
                 key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
                 axis=0, dtype=jnp.int32)
-            rows = jnp.take(xf, order // k, axis=0).astype(dt)
             w_rows = jnp.take(w.reshape(M), order)
-            valid = jnp.arange(M) < sizes.sum()
             # where each pair's row went, to bring its result back
             back = jnp.zeros((M,), jnp.int32).at[order].set(
                 jnp.arange(M, dtype=jnp.int32))
+            total = sizes.sum()
+
+        def grouped(a, name, sizes):
+            w, groups = params[name], sizes
+            if isinstance(w, StackedLeaf):
+                w, layer = w
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros((w.shape[0] * count,), jnp.int32),
+                    sizes, (layer * count,))
+                w = w.reshape((-1,) + w.shape[2:])
+            return jax.lax.ragged_dot(
+                a, w.astype(dt), groups,
+                preferred_element_type=jnp.float32)
+
+        def experts(at, w_at, sizes, live):
+            """The sorted pairs ``at`` (indices into the pairs) through
+            their experts, weighted; rows that are not ``live`` (behind
+            the last group: never computed) are zeros."""
+            with jax.named_scope("hetu.moe_route"):
+                rows = jnp.take(xf, at // k, axis=0).astype(dt)
+            h = (jax.nn.silu(grouped(rows, "wg", sizes))
+                 * grouped(rows, "wi", sizes)).astype(dt)
+            y = grouped(h, "wo", sizes)
+            return jnp.where(live[:, None], y * w_at[:, None], 0.0)
+
+        R = self._window_rows(M)
         with jax.named_scope("hetu.moe_experts"):
-            def grouped(a, name):
-                w, groups = params[name], sizes
-                if isinstance(w, StackedLeaf):
-                    w, layer = w
-                    groups = jax.lax.dynamic_update_slice(
-                        jnp.zeros((w.shape[0] * count,), jnp.int32),
-                        sizes, (layer * count,))
-                    w = w.reshape((-1,) + w.shape[2:])
-                return jax.lax.ragged_dot(
-                    a, w.astype(dt), groups,
-                    preferred_element_type=jnp.float32)
-            h = (jax.nn.silu(grouped(rows, "wg"))
-                 * grouped(rows, "wi")).astype(dt)
-            y = grouped(h, "wo")
-            # rows behind the last group were never computed
-            y = jnp.where(valid[:, None], y * w_rows[:, None], 0.0)
-            out = jnp.take(y, back, axis=0).reshape(-1, k, d).sum(1)
+            if R >= M:
+                y = experts(order, w_rows, sizes, jnp.arange(M) < total)
+                out = jnp.take(y, back, axis=0).reshape(-1, k, d).sum(1)
+            else:
+                # a SHARE: the pairs held here are the first ``total``
+                # sorted rows, an eighth of them or so — the grouped
+                # matmuls walk them in windows of R rows (one window,
+                # unless the tokens crowd onto this chip), never the
+                # rows behind them
+                pad = -M % R
+                order_p = jnp.pad(order, (0, pad))
+                w_p = jnp.pad(w_rows, (0, pad))
+                hi = jnp.cumsum(sizes)
+                lo = hi - sizes
+
+                def window(i, out):
+                    start = i * R
+                    inside = jnp.clip(jnp.minimum(hi, start + R)
+                                      - jnp.maximum(lo, start), 0)
+                    y = experts(
+                        jax.lax.dynamic_slice(order_p, (start,), (R,)),
+                        jax.lax.dynamic_slice(w_p, (start,), (R,)),
+                        inside, start + jnp.arange(R) < total)
+                    rel = back - start
+                    mine_w = (rel >= 0) & (rel < R) & (back < total)
+                    part = jnp.take(y, jnp.clip(rel, 0, R - 1), axis=0)
+                    return out + jnp.where(mine_w[:, None], part, 0.0) \
+                        .reshape(-1, k, d).sum(1)
+
+                out = jax.lax.fori_loop(
+                    0, (total + R - 1) // R, window,
+                    jnp.zeros((xf.shape[0], d), jnp.float32))
         out = out.astype(dt).reshape(x.shape)
+        if return_stats:
+            with jax.named_scope("hetu.moe_route"):
+                # the groups that overlap the experts held here
+                per = self.num_experts // kept.shape[1]
+                g = jnp.arange(kept.shape[1])
+                mine_g = (g * per < first + count) & ((g + 1) * per > first)
+                held = jnp.sum(jnp.any(kept & mine_g[None], axis=1),
+                               dtype=jnp.int32)
+            return out, {"sizes": sizes, "group_held": jnp.stack(
+                [held, jnp.asarray(xf.shape[0], jnp.int32)])}
         return (out, sizes) if return_sizes else out

@@ -1011,10 +1011,22 @@ class LatentAttention(Module):
                  nope_dim: int, rope_dim: int, v_dim: int,
                  stored_row: Optional[int] = None,
                  rope_theta: float = 10000.0, norm_eps: float = 1e-6,
-                 max_positions: int = 4096, init=None):
+                 max_positions: int = 4096, qk_norm: bool = False,
+                 qk_gain: float = 1.0, init=None):
         super().__init__()
         from hetu_tpu.nn.layers import RMSNorm
         self.num_heads, self.num_kv_heads = num_heads, 1
+        # RMSNorm with a learned gain on each head's query (its nope
+        # and rope parts together) and on the one RoPE key, both before
+        # the rotation: the forms the absorbed path can hold (a norm of
+        # each head's expanded key would need the key expanded). The
+        # gains are drawn at ``qk_gain`` (1: a checkpoint's are learned)
+        self.qk_norm, self.norm_eps = bool(qk_norm), norm_eps
+        if qk_norm:
+            from hetu_tpu.nn.module import constant_init
+            self.param("q_gain", (nope_dim + rope_dim,),
+                       constant_init(qk_gain))
+            self.param("kr_gain", (rope_dim,), constant_init(qk_gain))
         self.kv_rank, self.nope_dim = kv_rank, nope_dim
         self.rope_dim, self.v_dim = rope_dim, v_dim
         self.row = kv_rank + rope_dim
@@ -1059,8 +1071,10 @@ class LatentAttention(Module):
         with jax.named_scope("hetu.mla_down"):
             ckr = self.kv_down(params["kv_down"], x)
             c = self.kv_norm(params["kv_norm"], ckr[..., :self.kv_rank])
-            kr = self._rotate(ckr[..., None, self.kv_rank:],
-                              positions)[..., 0, :]
+            kr = ckr[..., None, self.kv_rank:]
+            if self.qk_norm:
+                kr = _gain(params, "kr_gain", kr, self.norm_eps, kr.dtype)
+            kr = self._rotate(kr, positions)[..., 0, :]
             return self._pad(
                 jnp.concatenate([c, kr.astype(c.dtype)], axis=-1))
 
@@ -1069,6 +1083,8 @@ class LatentAttention(Module):
         b, s, _ = x.shape
         q = self.q_proj(params["q_proj"], x).reshape(
             b, s, self.num_heads, self.nope_dim + self.rope_dim)
+        if self.qk_norm:
+            q = _gain(params, "q_gain", q, self.norm_eps, q.dtype)
         return q[..., :self.nope_dim], \
             self._rotate(q[..., self.nope_dim:], positions)
 
@@ -1344,6 +1360,9 @@ class BlockSparseAttention(Module):
     #: joins, virtual slots a call (its tables ride SMEM), and pack
     #: tokens a block of the selection's scores
     PAGES_PER_STEP, ROWS_PER_CALL, SELECT_ROWS = 8, 512, 256
+    #: the pack's history is read through each token's OWN virtual
+    #: table, not in tiles of a run (the engine builds no tile map)
+    history_tiles = False
 
     def __init__(self, embed_dim: int, num_heads: int, *,
                  num_kv_heads: int, head_dim: int, block_size: int = 64,
@@ -1785,25 +1804,189 @@ class LightningAttention(Module):
             x, positions, slot_mask, block_tables, row_mask, pack)
         q, k, v = self._qkv(params, x, positions)
         # (the state's read out of its leaf and its write back are the
-        # scope's: they are most of what the update moves)
+        # scope's: they are most of what the update moves. A gather and
+        # a scatter of this layer's slots, in place in the stacked leaf:
+        # a dynamic slice of the layer and its update made the compiler
+        # copy the WHOLE leaf once a layer and lane, PERF.md section 7)
+        every = jnp.arange(buf.shape[1])
         if slot is None:
             with jax.named_scope("hetu.linear_update"):
                 o, state = la.linear_update(
-                    q[:, 0], k[:, 0], v[:, 0], _at_layer(buf, layer),
+                    q[:, 0], k[:, 0], v[:, 0], buf.at[layer, every].get(),
                     valid, self._slopes(), scale=self.scale)
-                buf = jax.lax.dynamic_update_index_in_dim(buf, state,
-                                                          layer, 0)
+                buf = buf.at[layer, every].set(state)
             o = o[:, None]
         else:
             with jax.named_scope("hetu.linear_scan"):
                 o, state = la.linear_scan(
-                    q[0], k[0], v[0], _at_layer(buf, layer), slot, pos,
-                    valid, self._slopes(), scale=self.scale,
+                    q[0], k[0], v[0], buf.at[layer, every].get(), slot,
+                    pos, valid, self._slopes(), scale=self.scale,
                     block=self.SCAN_BLOCK)
-                buf = jax.lax.dynamic_update_index_in_dim(buf, state,
-                                                          layer, 0)
+                buf = buf.at[layer, every].set(state)
             o = o[None]
         return self._output(params, o, x), (buf,)
+
+
+class KimiDeltaAttention(Module):
+    """Kimi Delta Attention (``ops.kda``): a gated delta rule with a
+    decay per channel over a per-slot recurrent state, behind a short
+    causal convolution. With ``u`` the block's normed input::
+
+        [q | k | v] = SiLU(conv(u [W_q | W_k | W_v]))    a channel at a time
+        q <- q / |q| * dk^-1/2,  k <- k / |k|            a head
+        g = lower_bound * sigmoid(exp(A_log_h) (u W_f + dt_bias))
+        beta = sigmoid(u W_b)                             one a head
+        S_t = (I - beta k k^T) Diag(e^g) S_{t-1} + beta k v^T,  o = S_t^T q
+        out = W_o (RMSNorm_head(o) * sigmoid(u W_g))      one gate a head
+
+    What is cached is a SLOT's, in TWO leaves (:meth:`init_leaves`):
+    the float32 state ``(layers, slots, H, dk, dv)`` and the
+    convolution's TAIL ``(layers, slots, taps - 1, 3 H dk)`` — the last
+    input rows of q, k and v before the activation. A run that starts
+    at position 0 starts from a zero state AND a zero tail, whatever its
+    slot held. The decode rows advance their slot by a token
+    (``hetu.kda_update``), a prefill pack's tokens theirs in chunks
+    (``hetu.kda_scan``), both behind ``hetu.kda_conv``; each addresses
+    the live slots of its own layer in the stacked leaves in place. No
+    page is ever read or written. ``A_log``, ``dt_bias`` and the taps
+    are drawn, not constants (a program that leaves one out must
+    differ)."""
+
+    def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
+                 conv_size: int = 4, lower_bound: float = -5.0,
+                 norm_eps: float = 1e-6, init=None):
+        super().__init__()
+        from hetu_tpu.nn.module import ones_init
+        self.num_heads = self.num_kv_heads = num_heads
+        self.head_dim, self.conv_size = head_dim, conv_size
+        self.lower_bound, self.norm_eps = float(lower_bound), norm_eps
+        self.min_window = None
+        init = init or normal_init(0.02)
+        inner = num_heads * head_dim
+        self.qkv_proj = ColumnParallelLinear(
+            embed_dim, 3 * inner, bias=False, init=init, axis="heads",
+            out_kind="hidden")
+        self.decay_proj = ColumnParallelLinear(
+            embed_dim, inner, bias=False, init=init, axis="heads",
+            out_kind="hidden")
+        # [beta | gate]: one of each a head
+        self.head_proj = ColumnParallelLinear(
+            embed_dim, 2 * num_heads, bias=False, init=init, axis=None,
+            out_kind="hidden")
+        self.out_proj = RowParallelLinear(inner, embed_dim, bias=False,
+                                          init=init, axis="heads")
+        # (float32 whatever the weights are served in: they shape the
+        # decay and the window, a few thousand numbers)
+        self.param("conv", (conv_size, 3 * inner),
+                   normal_init(conv_size ** -0.5), dtype=jnp.float32)
+        self.param("A_log", (num_heads,), normal_init(0.5),
+                   dtype=jnp.float32)
+
+        def dt_bias(key, shape, dtype):
+            return (jax.random.normal(key, shape, jnp.float32)
+                    - 2.0).astype(dtype)
+        self.param("dt_bias", (inner,), dt_bias, dtype=jnp.float32)
+        self.param("o_gain", (head_dim,), ones_init())
+
+    def kv_leaf_shapes(self) -> tuple:
+        """No leaf a token: the state and the tail are a slot's."""
+        return ()
+
+    def state_bytes(self) -> int:
+        """Bytes a slot's state and tail hold in one layer."""
+        inner = self.num_heads * self.head_dim
+        return 4 * (inner * self.head_dim
+                    + (self.conv_size - 1) * 3 * inner)
+
+    def init_leaves(self, layers: int, slots: int, sharding=None) -> tuple:
+        inner = self.num_heads * self.head_dim
+        return (jnp.zeros((layers, slots, self.num_heads, self.head_dim,
+                           self.head_dim), jnp.float32, device=sharding),
+                jnp.zeros((layers, slots, self.conv_size - 1, 3 * inner),
+                          jnp.float32, device=sharding))
+
+    def _inputs(self, params, u):
+        """``u (N, E)`` -> ``(a (N, 3 H d) float32 — q, k, v before the
+        convolution —, g (N, H, d), beta (N, H), gate (N, H))``."""
+        H, d = self.num_heads, self.head_dim
+        a = self.qkv_proj(params["qkv_proj"], u).astype(jnp.float32)
+        f = self.decay_proj(params["decay_proj"], u).astype(jnp.float32)
+        f = (f + params["dt_bias"].astype(jnp.float32)).reshape(-1, H, d)
+        g = self.lower_bound * jax.nn.sigmoid(
+            jnp.exp(params["A_log"].astype(jnp.float32))[None, :, None] * f)
+        bg = jax.nn.sigmoid(self.head_proj(params["head_proj"], u)
+                            .astype(jnp.float32))
+        return a, g, bg[:, :H], bg[:, H:]
+
+    def _qkv(self, y):
+        """The convolution's result ``(N, 3 H d)`` -> ``q``, ``k``, ``v``
+        ``(N, H, d)`` float32, activated and normalised."""
+        H, d = self.num_heads, self.head_dim
+        q, k, v = (jax.nn.silu(y).reshape(-1, 3, H, d)[:, i]
+                   for i in range(3))
+
+        def unit(x):
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, axis=-1, keepdims=True) + 1e-12)
+        return unit(q) * d ** -0.5, unit(k), v
+
+    def _output(self, params, o, gate):
+        """``o (N, H, dv)`` float32, ``gate (N, H)``."""
+        o = _gain(params, "o_gain", o, self.norm_eps, jnp.float32)
+        o = (o * gate[..., None]).reshape(o.shape[0], -1)
+        return self.out_proj(params["out_proj"],
+                             o.astype(self.compute_dtype()))
+
+    def __call__(self, params, x, *, positions=None, segment_ids=None,
+                 attn_impl: str = "auto", kv_cache=None, slot_mask=None,
+                 block_tables=None, row_mask=None, attn_kernel="reference",
+                 pack=None, return_kv: bool = False):
+        del attn_impl, attn_kernel       # no attention kernel here
+        from hetu_tpu.ops import kda
+        b, s, E = x.shape
+        taps = params["conv"]
+        if kv_cache is None:
+            if return_kv or segment_ids is not None:
+                raise SlotStateNotSupported(
+                    "return_kv (the CP-prefill lane) and packed "
+                    "documents: a delta-rule attention has no (k, v) to "
+                    "hand out, and its whole-sequence forward is one "
+                    "document a row")
+            a, g, beta, gate = self._inputs(params, x.reshape(-1, E))
+            y = jax.vmap(lambda a: kda.conv_sequence(a, taps))(
+                a.reshape(b, s, -1))
+            q, k, v = self._qkv(y.reshape(b * s, -1))
+
+            def rows(t):
+                return t.reshape((b, s) + t.shape[1:])
+            o = jax.vmap(lambda *t: kda.kda_recurrence(*t)[0])(
+                rows(q), rows(k), rows(v), rows(g), rows(beta))
+            return self._output(params, o.reshape((b * s,) + o.shape[2:]),
+                                gate).reshape(b, s, E)
+        (state, tail), layer = kv_cache
+        u, pos, valid, _, slot = _cached_rows(
+            x, positions, slot_mask, block_tables, row_mask, pack)
+        a, g, beta, gate = self._inputs(params, u)
+        # (the reads of a slot's state and tail out of their leaves and
+        # the writes back are the scopes': most of what a row moves)
+        with jax.named_scope("hetu.kda_conv"):
+            if slot is None:
+                y, tail = kda.conv_rows(a, taps, tail, valid, layer=layer,
+                                        fresh=pos == 0)
+            else:
+                y, tail = kda.conv_pack(a, taps, tail, slot, pos, valid,
+                                        layer=layer)
+        q, k, v = self._qkv(y)
+        if slot is None:
+            with jax.named_scope("hetu.kda_update"):
+                o, state = kda.kda_update(q, k, v, g, beta, state, valid,
+                                          layer=layer, fresh=pos == 0)
+        else:
+            with jax.named_scope("hetu.kda_scan"):
+                o, state = kda.kda_scan(q, k, v, g, beta, state, slot,
+                                        pos, valid, layer=layer)
+        return self._output(params, o, gate).reshape(x.shape), \
+            (state, tail)
 
 
 def remat_policy(name: str):

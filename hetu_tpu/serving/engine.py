@@ -729,7 +729,8 @@ class ServingEngine:
         self._hist_tiles = history_tile_count(
             self.prefill_chunk, self._hist_tile, self._fin_cap) \
             if prefill_attn != "reference" \
-            and self.attn_kernel == "paged" and not wants_slots else 0
+            and self.attn_kernel == "paged" \
+            and getattr(_attn_mod, "history_tiles", True) else 0
         # the decode rows' paged call walks the live (slot, chunk)
         # pairs (ops.paged_pallas.decode_work_list): a chunk's span in
         # positions and a table's chunks, for

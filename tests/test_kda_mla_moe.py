@@ -1,0 +1,434 @@
+"""The KDA / MLA / group-limited-expert decoder on the CPU at a tiny
+size (ISSUE 41): the chunk form and the one-token update against the
+token recurrence; the convolution across packs against one pass; the
+model against the plain reference; chunked prefill then decode through
+the latent arena, the states and the tails against the reference's ONE
+forward pass (logits), a reused slot included; the group-limited route
+against the reference's and against the ungrouped one to the bit; the
+eight shares of an expert layer against the uncut layer; the refusals,
+by name."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from benchmark.reference import kda_mla_moe as reference  # noqa: E402
+from benchmark.runners.serve_arch import load_arch  # noqa: E402
+from hetu_tpu.models import generation  # noqa: E402
+from hetu_tpu.nn.moe import ExpertShareMoE  # noqa: E402
+from hetu_tpu.nn.parallel import SlotStateNotSupported  # noqa: E402
+from hetu_tpu.ops import kda  # noqa: E402
+import test_minicpm_sala as sala_tests  # noqa: E402
+from test_minicpm_sala import REFUSED, _serve_logits  # noqa: E402
+
+H, D = 2, 16
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    with open(os.path.join(ROOT, "tests", "benchmark", "configs",
+                           "ling-tiny.json")) as f:
+        config = json.load(f)
+    model = load_arch("kda_mla_moe").build(config)
+    return config, model, model.init(jax.random.key(41))
+
+
+def _draw(key, T, at_bound=False):
+    """Operands as the mixer makes them: unit q, k; g in (-5, 0)."""
+    ks = jax.random.split(key, 5)
+    q, k, v = (jax.random.normal(ks[i], (T, H, D)) for i in range(3))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = jnp.full((T, H, D), -5.0) if at_bound else \
+        -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (T, H, D)))
+    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
+
+
+def _pack(runs, C, slots, key):
+    """``runs``: ``(slot, first position, tokens)`` in pack order."""
+    state0 = jax.random.normal(jax.random.fold_in(key, 99),
+                               (slots, H, D, D))
+    slot, pos = np.zeros(C, np.int32), np.zeros(C, np.int32)
+    valid = np.zeros(C, bool)
+    parts, want_o, want_s, used = [], [], np.array(state0), 0
+    for i, (s, p0, n) in enumerate(runs):
+        x = _draw(jax.random.fold_in(key, i), n)
+        parts.append(x)
+        slot[used:used + n], valid[used:used + n] = s, True
+        pos[used:used + n] = np.arange(p0, p0 + n)
+        o, st = kda.kda_recurrence(*x,
+                                   state=state0[s] if p0 else None)
+        want_o.append(o)
+        want_s[s] = st
+        used += n
+    # the pad lanes hold garbage, not zeros
+    ops = [jnp.concatenate([p[j] for p in parts] + [jnp.full(
+        (C - used,) + parts[0][j].shape[1:], 7.0)]) for j in range(5)]
+    return ops, state0, (jnp.asarray(slot), jnp.asarray(pos),
+                         jnp.asarray(valid)), \
+        jnp.concatenate(want_o), want_s, used
+
+
+@pytest.mark.parametrize("runs,C,layer", [
+    ([(0, 0, 50)], 50, None),                     # one run, padded chunk
+    ([(1, 64, 130)], 130, None),                  # continuing, 3 chunks
+    # three slots' runs: one from 0, one continuing, chunk boundaries
+    # inside each; a short one; pad lanes behind
+    ([(2, 0, 70), (0, 37, 100), (1, 0, 5)], 200, None),
+    ([(2, 0, 70), (0, 37, 100), (1, 0, 5)], 200, 1),
+], ids=["one-run", "continuing", "three-slots", "three-slots-stacked"])
+def test_kda_scan_equals_the_recurrence(runs, C, layer):
+    ops, state0, where, want_o, want_s, used = _pack(
+        runs, C, 4, jax.random.key(3))
+    with jax.default_matmul_precision("highest"):
+        if layer is None:
+            o, st = jax.jit(kda.kda_scan)(*ops, state0, *where)
+        else:
+            buf = jnp.stack([state0 + 1.0, state0, state0 + 2.0])
+            o, buf = jax.jit(lambda *a: kda.kda_scan(
+                *a, layer=jnp.int32(layer)))(*ops, buf, *where)
+            assert (buf[0] == state0 + 1.0).all() \
+                and (buf[2] == state0 + 2.0).all()
+            st = buf[1]
+    np.testing.assert_allclose(o[:used], want_o, atol=5e-6)
+    np.testing.assert_allclose(st, want_s, atol=1e-5)
+    # a slot without a run keeps its state to the bit
+    idle = sorted(set(range(4)) - {s for s, _, _ in runs})
+    assert (np.asarray(st)[idle] == np.asarray(state0)[idle]).all()
+
+
+def test_kda_scan_at_the_decay_bound_for_a_whole_chunk():
+    """``g = -5`` for 64 tokens: ``e^{-G}`` alone would be ``e^{320}``."""
+    x = _draw(jax.random.key(5), kda.CHUNK, at_bound=True)
+    o, st = kda.kda_scan(
+        *x, jnp.zeros((1, H, D, D)), jnp.zeros(kda.CHUNK, jnp.int32),
+        jnp.arange(kda.CHUNK, dtype=jnp.int32),
+        jnp.ones(kda.CHUNK, bool))
+    want_o, want_s = kda.kda_recurrence(*x)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_allclose(o, want_o, atol=1e-6)
+    np.testing.assert_allclose(st[0], want_s, atol=1e-6)
+
+
+def test_kda_update_equals_one_step_of_the_recurrence():
+    S = 4
+    x = _draw(jax.random.key(6), S)
+    state = jax.random.normal(jax.random.key(7), (S, H, D, D))
+    live = jnp.array([True, False, True, True])
+    fresh = jnp.array([False, False, True, False])
+    o, new = jax.jit(kda.kda_update)(*x, state, live, fresh=fresh)
+    for s in range(S):
+        want_o, want_s = kda.kda_recurrence(
+            *(a[s:s + 1] for a in x),
+            state=None if fresh[s] else state[s])
+        if live[s]:
+            np.testing.assert_allclose(o[s], want_o[0], atol=1e-6)
+            np.testing.assert_allclose(new[s], want_s, atol=1e-6)
+        else:
+            assert (new[s] == state[s]).all()
+
+
+@pytest.mark.parametrize("cut", [1, 2, 3, 9])
+def test_convolution_across_two_packs_equals_one_pass(cut):
+    """A run cut after ``cut`` tokens (under the window too), beside
+    another slot's run from position 0 whose tail held garbage."""
+    N, K, T = 6, 4, 14
+    taps = jax.random.normal(jax.random.key(1), (K, N))
+    a = jax.random.normal(jax.random.key(2), (T, N))
+    b = jax.random.normal(jax.random.key(3), (5, N))
+    tail = jax.random.normal(jax.random.key(4), (3, K - 1, N))
+    want_a, want_b = kda.conv_sequence(a, taps), kda.conv_sequence(b, taps)
+
+    def pack(rows, slot, pos, C=20):
+        n = rows.shape[0]
+        return (jnp.pad(rows, ((0, C - n), (0, 0)), constant_values=3.0),
+                jnp.pad(slot, (0, C - n)), jnp.pad(pos, (0, C - n)),
+                jnp.arange(C) < n)
+
+    rows, slot, pos, valid = pack(
+        a[:cut], jnp.full((cut,), 2), jnp.arange(cut))
+    y1, tail = kda.conv_pack(rows, taps, tail, slot, pos, valid)
+    rest = T - cut
+    rows, slot, pos, valid = pack(
+        jnp.concatenate([a[cut:], b]),
+        jnp.concatenate([jnp.full((rest,), 2), jnp.zeros((5,), jnp.int32)]),
+        jnp.concatenate([jnp.arange(cut, T), jnp.arange(5)]))
+    y2, tail2 = kda.conv_pack(rows, taps, tail, slot, pos, valid)
+    np.testing.assert_allclose(y1[:cut], want_a[:cut], atol=1e-6)
+    np.testing.assert_allclose(y2[:rest], want_a[cut:], atol=1e-6)
+    np.testing.assert_allclose(y2[rest:rest + 5], want_b, atol=1e-6)
+    np.testing.assert_allclose(tail2[2], a[-3:], atol=0)
+    np.testing.assert_allclose(tail2[0], b[-3:], atol=0)
+    # then a decode row a slot
+    nxt = jax.random.normal(jax.random.key(5), (3, N))
+    y3, tail3 = kda.conv_rows(nxt, taps, tail2,
+                              jnp.array([True, False, True]))
+    np.testing.assert_allclose(
+        y3[2], kda.conv_sequence(jnp.concatenate([a, nxt[2:]]), taps)[-1],
+        atol=1e-6)
+    assert (tail3[1] == tail2[1]).all()
+
+
+def test_caches_are_a_latent_arena_beside_two_slot_leaves(tiny):
+    config, model, _ = tiny
+    assert model.cfg.mixer_types == ("kda", "kda", "mla", "kda", "kda",
+                                     "mla", "kda")
+    assert model.blocks.run_kinds == ["kda", "mla", "kda", "mla", "kda"]
+    assert (model.blocks.n_kda, model.blocks.n_mla) == (5, 2)
+    latent, state, tail = generation.init_paged_caches(
+        model, 9, 4, jnp.bfloat16, slots=3)
+    assert latent.shape == (2, 9, 4, 48) and latent.dtype == jnp.bfloat16
+    assert state.shape == (5, 3, 4, 16, 16) and state.dtype == jnp.float32
+    assert tail.shape == (5, 3, 3, 3 * 64) and tail.dtype == jnp.float32
+    got = model.blocks.cache_bytes(2)
+    assert got["row"] == {"stored": 2 * 48 * 2, "needed": 2 * 40 * 2}
+    assert got["state"] == {"slot": 5 * 4 * (64 * 16 + 3 * 192)}
+    assert model.blocks.block.attn.kv_leaf_shapes() == ((1, 48),)
+    assert model.blocks._kda.attn.kv_leaf_shapes() == ()
+
+
+CONTROLS = [{"no_erase": True}, {"no_conv": True}, {"head_decay": True},
+            {"no_group_limit": True}, {"ignore_bias": True},
+            {"operands": jnp.float8_e4m3fn}]
+
+
+def test_model_matches_the_reference(tiny):
+    config, model, params = tiny
+    ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
+    got = model(params, ids)
+    for b in range(2):
+        want = reference.logits(params, ids[b], config)
+        np.testing.assert_allclose(got[b], want, atol=1e-4)
+
+
+@pytest.mark.parametrize("control", CONTROLS,
+                         ids=[next(iter(c)) for c in CONTROLS])
+def test_each_planted_control_moves_the_reference(tiny, control):
+    config, _, params = tiny
+    ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
+    base = reference.logits(params, ids, config)
+    moved = reference.logits(params, ids, config, **control)
+    assert float(jnp.abs(moved - base).max()) > 0.01, control
+
+
+@pytest.mark.parametrize("chunk", [10, 7])
+def test_chunked_prefill_then_decode_equals_one_forward_pass(
+        tiny, chunk, monkeypatch):
+    """Logits, not tokens: two slots of different lengths in one pack,
+    chunks that cut the convolution's window and the scan's chunks, and
+    slot 0 REUSED by a third request — its state AND its tail must
+    start from zeros, its pages be its own."""
+    config, model, params = tiny
+    # (the helper's two calls, compiled once each instead of run op by
+    # op: the same arithmetic)
+    decode, one_pack = generation.decode, sala_tests._decode_pack
+    rows = jax.jit(lambda params, tok, pos, caches, act, bt: decode(
+        model, params, tok, pos, caches, slot_mask=act, block_tables=bt,
+        row_mask=act[:, None]))
+    packed = jax.jit(lambda params, tokens, tpos, caches, bt, tslot, pack:
+                     one_pack(model, params, tokens, tpos, caches, bt,
+                              tslot, {**pack, "impl": "reference"},
+                              "reference"))
+    monkeypatch.setattr(
+        generation, "decode",
+        lambda m, p, tok, pos, caches, *, slot_mask, block_tables,
+        row_mask, attn_kernel: rows(p, tok, pos, caches, slot_mask,
+                                    block_tables))
+    monkeypatch.setattr(
+        sala_tests, "_decode_pack",
+        lambda m, p, tokens, tpos, caches, bt, tslot, pack, kern:
+        packed(p, tokens, tpos, caches, bt, tslot,
+               {k: v for k, v in pack.items() if k != "impl"}))
+    rng = np.random.default_rng(41)
+    reqs = [(0, rng.integers(1, 128, 23), 3),
+            (1, rng.integers(1, 128, 14), 4),
+            (0, rng.integers(1, 128, 17), 3)]
+    got = _serve_logits(model, params, reqs, slots=2, chunk=chunk,
+                        block_size=4, n_blocks=24, max_len=32)
+    for i, (_, ids, _) in enumerate(reqs):
+        want = reference.logits(params, jnp.asarray(ids), config)
+        np.testing.assert_allclose(got[i], want, atol=2e-4)
+
+
+def test_engine_serves_tokens_the_reference_puts_on_top(tiny):
+    from hetu_tpu import telemetry
+    from hetu_tpu.serving import SamplingParams, ServingEngine
+    config, model, params = tiny
+    telemetry.enable(True)
+    reg = telemetry.get_registry()
+    held0 = reg.counter("moe_group_held_total").value()
+    tokens0 = reg.counter("moe_group_tokens_total").value()
+    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                        block_size=4, slots=3, kv_blocks=40, seed=0)
+    assert eng.prefix_cache is None and eng.preempt is False
+    assert eng.prefill_attn == "flash"        # the pack as one row
+    assert eng.pool.nbytes() == sum(c.nbytes for c in eng.pool.caches)
+    assert len(eng.pool.caches) == 3
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 128, n).tolist() for n in (21, 13, 30, 23, 7)]
+    outs = eng.generate_many(prompts, SamplingParams(max_tokens=6))
+    assert eng.step_executables() == 1
+    for p, toks in zip(prompts, outs):
+        lg = np.asarray(reference.logits(
+            params, jnp.asarray(p + toks), config))[len(p) - 1:-1]
+        gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
+        assert gap.max() <= 1e-4, (len(p), gap)
+    held = reg.counter("moe_group_held_total").value() - held0
+    tokens = reg.counter("moe_group_tokens_total").value() - tokens0
+    # two of four groups stay: about half the tokens reach the held one
+    assert 0 < held < tokens and 0.25 < held / tokens < 0.75
+    assert reg.gauge("kv_state_bytes").value(kind="slot") == \
+        model.blocks.cache_bytes(4)["state"]["slot"]
+
+
+def _moe(**kw):
+    return ExpertShareMoE(32, 16, 16, k=3, select_bias=True, scale=2.5,
+                          **kw)
+
+
+def test_group_limited_route_equals_the_references():
+    moe = _moe(n_group=8, topk_group=2)
+    params = moe.init(jax.random.key(0))
+    params["select_bias"] = 0.3 * jax.random.normal(jax.random.key(1), (16,))
+    u = jax.random.normal(jax.random.key(2), (200, 32))
+    idx, w, kept = moe.route(params, u, return_kept=True)
+    config = dict(num_experts_per_tok=3, n_group=8, topk_group=2,
+                  routed_scaling_factor=2.5, num_experts=16,
+                  deployment={"expert_group": 0})
+    with jax.default_matmul_precision("highest"):
+        ridx, rw, margin = reference.route(params, u, config)
+        free, _, _ = reference.route(params, u, config,
+                                     no_group_limit=True)
+    clear = np.asarray(margin) > 1e-4
+    assert clear.mean() > 0.9
+    order = np.argsort(np.asarray(idx), axis=1)
+    rorder = np.argsort(np.asarray(ridx), axis=1)
+    np.testing.assert_array_equal(
+        np.take_along_axis(np.asarray(idx), order, 1)[clear],
+        np.take_along_axis(np.asarray(ridx), rorder, 1)[clear])
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(w), order, 1)[clear],
+        np.take_along_axis(np.asarray(rw), rorder, 1)[clear], atol=1e-6)
+    # every chosen expert stands in a kept group, two groups are kept,
+    # and the limit binds: the free top-3 differs somewhere
+    assert (np.asarray(kept).sum(1) == 2).all()
+    assert np.take_along_axis(np.asarray(kept), np.asarray(idx) // 2,
+                              1).all()
+    assert (np.sort(np.asarray(free), 1)
+            != np.sort(np.asarray(ridx), 1)).any()
+
+
+@pytest.mark.parametrize("bias", [True, False])
+def test_one_group_is_the_ungrouped_route_to_the_bit(bias):
+    moe = ExpertShareMoE(32, 16, 16, k=3, select_bias=bias, scale=2.5,
+                         n_group=1, topk_group=1)
+    params = moe.init(jax.random.key(0))
+    u = jax.random.normal(jax.random.key(2), (200, 32))
+    idx, w = jax.jit(moe.route)(params, u)
+
+    @jax.jit
+    def old(params, x):        # the route as it stood before the groups
+        z = jnp.matmul(x.astype(jnp.float32),
+                       params["router"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        if bias:
+            s = jax.nn.sigmoid(z)
+            _, idx = jax.lax.top_k(
+                s + params["select_bias"].astype(jnp.float32), 3)
+            top = jnp.take_along_axis(s, idx, axis=-1)
+        else:
+            top, idx = jax.lax.top_k(jax.nn.sigmoid(z), 3)
+        return idx.astype(jnp.int32), top / top.sum(-1, keepdims=True) * 2.5
+
+    oidx, ow = old(params, u)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(oidx))
+    np.testing.assert_array_equal(np.asarray(w), np.asarray(ow))
+    with pytest.raises(ValueError, match="groups"):
+        ExpertShareMoE(32, 16, 16, k=3, n_group=3)
+    with pytest.raises(ValueError, match="groups"):
+        ExpertShareMoE(32, 16, 16, k=9, n_group=8, topk_group=4)
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """The routed sums of the eight chips of a deployment (a group of
+    two experts each), and the shared expert counted ONCE, against the
+    reference's layer with every expert held."""
+    whole = _moe(n_group=8, topk_group=4)
+    params = whole.init(jax.random.key(0))
+    params["select_bias"] = 0.3 * jax.random.normal(jax.random.key(1), (16,))
+    u = jax.random.normal(jax.random.key(2), (60, 32))
+    total, sizes, held = jnp.zeros_like(u), [], 0
+    for g in range(8):
+        share = _moe(n_group=8, topk_group=4, local_experts=(2 * g, 2))
+        part = {**params, **{n: params[n][2 * g:2 * g + 2]
+                             for n in ("wg", "wi", "wo")}}
+        out, st = share(part, u, return_stats=True)
+        total = total + out
+        sizes.append(int(st["sizes"].sum()))
+        assert int(st["group_held"][1]) == 60
+        held += int(st["group_held"][0])
+    assert sum(sizes) == 60 * 3               # every chosen pair, once
+    assert held == 60 * 4                     # a token reaches 4 chips
+    shared = {n: {"weight": 0.1 * jax.random.normal(
+        jax.random.key(i), shape)} for i, (n, shape) in enumerate(
+            [("gate_proj", (32, 16)), ("up_proj", (32, 16)),
+             ("fc_out", (16, 32))])}
+    config = dict(num_experts_per_tok=3, n_group=8, topk_group=4,
+                  routed_scaling_factor=2.5, num_experts=16,
+                  deployment={"expert_group": 0})
+    with jax.default_matmul_precision("highest"):
+        want, _ = reference.expert_ffn({"moe": params, "shared": shared},
+                                       u, config)
+        got = total + reference.gated(shared, u)
+        np.testing.assert_allclose(got, want, atol=2e-6)
+        np.testing.assert_allclose(whole(params, u), total, atol=2e-6)
+
+
+#: what the latent arena refuses before the slot state is asked
+LATENT_FIRST = ("long_max_len", "w8a8", "tenancy")
+
+
+@pytest.mark.parametrize("name,kw", REFUSED, ids=[n for n, _ in REFUSED])
+def test_what_assumes_block_kv_refuses_at_construction_by_name(
+        tiny, name, kw):
+    from hetu_tpu.nn.parallel import LatentKVNotSupported
+    from hetu_tpu.serving import ServingEngine
+    _, model, params = tiny
+    with pytest.raises(LatentKVNotSupported if name in LATENT_FIRST
+                       else SlotStateNotSupported, match=name):
+        ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                      block_size=4, slots=2, kv_blocks=40, **kw)
+
+
+def test_dense_cache_cp_prefill_and_query_compression_refuse_by_name(tiny):
+    from hetu_tpu.models.kda_mla_moe import KDAMLAMoEConfig
+    _, model, params = tiny
+    with pytest.raises(SlotStateNotSupported, match="dense cache"):
+        generation.init_kv_caches(model, 1, 16)
+    with pytest.raises(SlotStateNotSupported, match="CP-prefill"):
+        model.blocks.prefill(params["blocks"], None)
+    with pytest.raises(NotImplementedError, match="q_lora_rank"):
+        KDAMLAMoEConfig.tiny(q_lora_rank=64)
+    with pytest.raises(ValueError, match="latent layer"):
+        KDAMLAMoEConfig.tiny(num_hidden_layers=2)
+
+
+def test_importing_the_package_loads_none_of_the_new_modules():
+    import subprocess
+    code = ("import sys, hetu_tpu, hetu_tpu.serving, hetu_tpu.models; "
+            "bad = [m for m in ('hetu_tpu.models.kda_mla_moe', "
+            "'hetu_tpu.ops.kda') if m in sys.modules]; print(bad); "
+            "sys.exit(bool(bad))")
+    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       capture_output=True, text=True)
+    assert r.returncode == 0, r.stdout + r.stderr

@@ -61,7 +61,19 @@ use:
   tokens of one request's run share one pass over that request's pages
   per tile of the chunk — the same kernel under a key cap, on a grid
   whose two bounds are data (the tiles that have history x the table
-  chunks under the deepest cap);
+  chunks under the deepest cap). A grid step holds a key tile of
+  SEVERAL pages, joined as the decode call joins them
+  (:func:`history_tile_pages`: as many as fit beside the tile's rows,
+  up to 512 keys), and its mask is the cap and the window's lower edge
+  alone — a tile's rows stand above every key they read. The chip's
+  sweep of this body (PERF.md, PR 42; ms a call at 1 / 2 / 4 / 8
+  pages): 8.13 / 4.37 / 2.52 / 1.97 at 16 heads over a 640-wide latent
+  row (one page a step with the causal compare: 8.61), 19.0 / 10.0 /
+  5.71 / 4.44 at 32 heads, 6.09 / 3.58 / 2.22 / 1.99 at 128 q heads
+  over 8 kv heads under a window, 0.305 / 0.197 / 0.128 / 0.089 at
+  GPT-2 large's 20 heads over 16-key pages. A second body without any
+  mask for the chunks wholly under the cap and inside the window
+  changed no time by more than 1 % at any shape and is not there;
 - **arena-layout lanes**: fp32/bf16 arenas stream directly; the int8
   arena streams quantized pages + their fp32 scales and dequantizes
   per tile in VMEM (1/4 the HBM bytes of a dequantized gather).
@@ -158,7 +170,10 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     TILE of a prefill pack — four more scalar operands hold its key
     cap, the q cell it reads (the index maps' business) and the cell's
     rows ``[lo, hi)`` that are its own; the grid is (tiles, chunks)
-    and its bounds are data. ``v_width`` (a latent arena): there are
+    and its bounds are data; a tile's rows stand ABOVE every key they
+    read, so its mask is the cap (and a window's lower edge) with no
+    causal compare.
+    ``v_width`` (a latent arena): there are
     no value pages — a key row's first ``v_width`` columns are its
     value, taken from the key page the step already holds."""
     del lyr_ref                     # read by the page index maps only
@@ -215,9 +230,12 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     d = q_ref.shape[-1]
     span = L * bs                   # the chunk's keys: ONE tile a head
     off = off_ref[s_i]
-    # q row r of the (rows = R*g) tile belongs to verify row r // g and
-    # attends absolute positions <= off + r // g
-    qpos = off + jax.lax.broadcasted_iota(jnp.int32, (rows, span), 0) // g
+    if not tiled or windowed:
+        # q row r of the (rows = R*g) tile belongs to verify row r // g
+        # and attends absolute positions <= off + r // g (a tile's rows
+        # need theirs for a window's lower edge only)
+        qpos = off + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, span), 0) // g
     last_q = off + (rows // g - 1)
     if tiled:
         # the tile's own rows are [lo, hi) of the cell and none sees a
@@ -252,22 +270,26 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
         return jnp.concatenate(parts, axis=0)    # one page: itself
 
     # the chunk's first position, written as the start of its page
-    # j = 0 was when the body looped over pages: the tiled call's jaxpr
-    # (L = 1) stays the pinned one to the character
+    # j = 0 was when the body looped over pages: the decode call's
+    # jaxpr stays the pinned one to the character
     chunk_start = (w * L + 0) * bs
 
     def compute():
         # ONE mask over the chunk's positions: the pages of a slot's
         # last chunk above its context (and of its first below the
         # window) are masked like the rest of the page its context
-        # ends in; what they hold is never seen
+        # ends in; what they hold is never seen. A tile's rows stand
+        # above the cap: the causal compare would be true everywhere
         kpos = chunk_start + jax.lax.broadcasted_iota(
             jnp.int32, (rows, span), 1)
-        mask = kpos <= qpos
-        if tiled:
-            mask &= kpos <= cap
+        mask = kpos <= cap if tiled else kpos <= qpos
         if windowed:
             mask &= kpos > qpos - win_ref[s_i]
+        # a row whose keys of this chunk are ALL masked would weigh
+        # them 1 (exp(NEG_INF - NEG_INF)): p is masked again — but a
+        # live chunk's first key is under the cap, so without a window
+        # every row of a tile sees one and exp(NEG_INF - m) is 0
+        remask = not tiled or windowed
         # a head's index as an array, made once a chunk: an int index
         # is converted again at each of its eight uses (a literal
         # either way)
@@ -291,7 +313,8 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
             l_prev = l_scr[at, :, :1]
             m_next = lax.max(m_prev, rowwise(lax.reduce_max(s, (1,))))
             p = lax.exp(lax.sub(s, m_next))
-            p = jnp.where(mask, p, 0.0)
+            if remask:
+                p = jnp.where(mask, p, 0.0)
             l_cur = rowwise(lax.reduce_sum(p, (1,)))
             alpha = lax.exp(lax.sub(m_prev, m_next))
             m_scr[at] = lanes(m_next)
@@ -735,14 +758,52 @@ def history_tile_rows(g: int, d: int, hkv: int, block_size: int, *,
     heads of 128."""
     pages = 2 * 2 * block_size * hkv * d * kv_itemsize
     tq = 128
-    while tq > 8:
-        cell = hkv * tq * g * 4 * (2 * d + 2 * d          # q, out: x2
-                                   + 2 * NUM_LANES        # lse: x2
-                                   + 2 * NUM_LANES + d)   # m, l, acc
-        if cell + pages <= _TILE_VMEM_BUDGET:
-            break
+    while tq > 8 and _tile_cell_bytes(tq * g, d, hkv) + pages \
+            > _TILE_VMEM_BUDGET:
         tq //= 2
     return tq
+
+
+def _tile_cell_bytes(rows: int, d: int, hkv: int) -> int:
+    """What a tile's cell of ``rows`` rows a kv head holds in VMEM,
+    priced at float32: the q cell and the two outputs double buffered,
+    the three online-softmax scratch buffers."""
+    return hkv * rows * 4 * (2 * d + 2 * d          # q, out: x2
+                             + 2 * NUM_LANES        # lse: x2
+                             + 2 * NUM_LANES + d)   # m, l, acc
+
+
+#: the most keys a tile of the history read scores a grid step (one
+#: score tile a kv head), from at most this many pages
+_TILE_KEYS, _TILE_PAGES = 512, 8
+
+
+def history_tile_pages(g: int, d: int, hkv: int, block_size: int, *,
+                       tile_rows: int, latent: bool = False,
+                       kv_itemsize: int = 2) -> int:
+    """Pages a grid step of the history read streams and joins into
+    ONE key tile a kv head, from shapes alone — the rows came first
+    (:func:`history_tile_rows`, the same pricing of the cell): the
+    largest power of two up to 8 pages and 512 keys whose blocks fit
+    next to the cell — the pages double buffered (one set under a
+    ``latent`` arena, whose value is its key), one head's joined key
+    and value tiles and its score and probability tiles ``(tile_rows x
+    g, keys)``, priced at float32. 8 pages of 64 keys beside the latent
+    cells (16 or 32 heads over one 640-wide row), 4 beside 128 q heads
+    over 8 kv heads of 128, 8 of GPT-2's 16-key pages."""
+    rows = tile_rows * g
+    room = _TILE_VMEM_BUDGET - _tile_cell_bytes(rows, d, hkv)
+    leaves = 1 if latent else 2
+
+    def blocks(keys):
+        return 2 * leaves * keys * hkv * d * kv_itemsize \
+            + 4 * keys * (leaves * d + 2 * rows)
+
+    pages = _TILE_PAGES
+    while pages > 1 and (pages * block_size > _TILE_KEYS
+                         or blocks(pages * block_size) > room):
+        pages //= 2
+    return pages
 
 
 def history_tile_count(chunk: int, tile_rows: int, max_runs: int) -> int:
@@ -799,7 +860,8 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
                             v_scale=None, window=None,
                             scale: Optional[float] = None,
                             interpret: Optional[bool] = None,
-                            v_width: Optional[int] = None):
+                            v_width: Optional[int] = None,
+                            pages_per_step: Optional[int] = None):
     """Each pack token's attention over its request's RESIDENT history
     (arena positions ``< hist[t]``: earlier chunks, prefix-cache hits),
     one pass over a request's pages per TILE of its chunk.
@@ -815,6 +877,8 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
 
     ``v_width``: the latent arena's call (``v`` is ``None``; the result
     is ``v_width`` wide; :func:`paged_attention_pallas`).
+    ``pages_per_step`` (``None``: :func:`history_tile_pages` of the
+    operands' shapes): the pages of a grid step's key tile.
 
     Returns ``(C, hq, d)`` and the fp32 LSE ``(C, hq)``; a token
     without history gets the empty part (0, ``NEG_INF``), which
@@ -824,17 +888,19 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
     C, hq, d = q.shape
     if not isinstance(tiles, dict):
         tiles = dict(zip(TILE_FIELDS, tiles))
+    if pages_per_step is None:
+        hkv = k.shape[-1] // d
+        pages_per_step = history_tile_pages(
+            hq // hkv, d, hkv, k.shape[-2], tile_rows=tile_rows,
+            latent=v_width is not None, kv_itemsize=k.dtype.itemsize)
     pad = -C % tile_rows
     cells = jnp.pad(q, ((0, pad), (0, 0), (0, 0))).reshape(
         -1, tile_rows, hq, d)
     out, lse = paged_attention_auto(
         cells, k, v, tile_tables, tiles["off"], layer=layer,
         k_scale=k_scale, v_scale=v_scale, scale=scale,
-        # a page a step: a tile gives the MXU Tq x g rows per page, and
-        # more pages a step only unroll the body again (the kernel's
-        # time is the same at 1 / 2 / 4 / 8 on the chip, its trace and
-        # compile are not: PERF.md, PR 27)
-        pages_per_step=1, interpret=interpret, return_lse=True,
+        pages_per_step=pages_per_step, interpret=interpret,
+        return_lse=True,
         window=window, v_width=v_width,
         tiles={n: tiles[n] for n in ("cap", "cell", "lo", "hi")})
     live = hist > 0

@@ -160,6 +160,22 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
             "serving_prefill_hist_rows_total",
             "pack tokens in live history tiles (over the live tiles: "
             "rows that share one pass over a request's pages)"),
+        hist_chunks=reg.counter(
+            "serving_prefill_hist_chunks_total",
+            "grid steps of ONE call of the history read by state and "
+            "by the kind of layer that makes it (layer = full: no "
+            "window; window: the model's smallest, where it has one). "
+            "A step is a live tile's key tile of history_tile_pages "
+            "pages: live = it computes, dead = the tile walks it only "
+            "because another tile's cap is deeper (the grid's second "
+            "bound is the deepest cap) or its window starts above it"),
+        hist_tile_keys=reg.counter(
+            "serving_prefill_hist_tile_keys_total",
+            "key positions in the history read's live steps by state "
+            "and layer kind: live = the keys some row of the tile may "
+            "see, masked = the rest of the chunk its cap ends in and "
+            "of the chunk its window starts in (what joining the "
+            "pages costs)"),
         decode_chunks=reg.counter(
             "serving_decode_chunks_total",
             "(slot, table chunk) pairs of the decode rows' paged call "
@@ -717,20 +733,28 @@ class ServingEngine:
         # size from the head shapes; the tile count is static: a pack
         # holds at most _fin_cap runs, each may open one more tile
         from hetu_tpu.ops.paged_pallas import (
-            history_tile_count, history_tile_rows, pack_history_tiles,
-            table_chunks,
+            history_tile_count, history_tile_pages, history_tile_rows,
+            pack_history_tiles, table_chunks,
         )
         self._pack_tiles = pack_history_tiles
-        self._hist_tile = history_tile_rows(
-            _attn_mod.num_heads // _attn_mod.num_kv_heads,
-            _attn_mod.head_dim, _attn_mod.num_kv_heads,
-            self.pool.block_size,
-            kv_itemsize=jnp.dtype(self.pool.caches[0].dtype).itemsize)
+        shapes = (_attn_mod.num_heads // _attn_mod.num_kv_heads,
+                  _attn_mod.head_dim, _attn_mod.num_kv_heads,
+                  self.pool.block_size)
+        itemsize = jnp.dtype(self.pool.caches[0].dtype).itemsize
+        self._hist_tile = history_tile_rows(*shapes, kv_itemsize=itemsize)
         self._hist_tiles = history_tile_count(
             self.prefill_chunk, self._hist_tile, self._fin_cap) \
             if prefill_attn != "reference" \
             and self.attn_kernel == "paged" \
             and getattr(_attn_mod, "history_tiles", True) else 0
+        # a grid step of that read: a key tile of as many pages as fit
+        # beside the cell — its span in positions and a table's steps,
+        # for serving_prefill_hist_chunks_total
+        pages, self._hist_steps = table_chunks(
+            W, self.pool.block_size, history_tile_pages(
+                *shapes, tile_rows=self._hist_tile, kv_itemsize=itemsize,
+                latent=len(_attn_mod.kv_leaf_shapes()) == 1))
+        self._hist_span = pages * self.pool.block_size
         # the decode rows' paged call walks the live (slot, chunk)
         # pairs (ops.paged_pallas.decode_work_list): a chunk's span in
         # positions and a table's chunks, for
@@ -887,6 +911,33 @@ class ServingEngine:
         mlp = self.model.blocks.block.mlp
         return mlp.prequantize(self.params["blocks"]["mlp"],
                                stacked=True)
+
+    def _count_hist_chunks(self, tiles):
+        """``serving_prefill_hist_chunks_total`` and ``..._tile_keys_
+        total`` of one pack, from its live tiles' map (the kernel's own
+        predicates, vectorised): a tile walks the table's steps under
+        the DEEPEST cap; it computes those from the one its first row's
+        window starts in to the one its cap ends in, and of their keys
+        its rows may see the ones from that window's start to the
+        cap."""
+        m, span = self._m, self._hist_span
+        _, _, lo, _, off, cap = tiles
+        walked = tiles.shape[1] * min(int(cap.max()) // span + 1,
+                                      self._hist_steps)
+        kinds = [("full", np.zeros_like(cap))]
+        if self._min_window is not None:
+            # the first key the tile's FIRST row sees (the lowest any
+            # does); above the cap: the window leaves the tile nothing
+            kinds.append(("window", np.clip(
+                off + lo - self._min_window + 1, 0, cap + 1)))
+        for kind, first in kinds:
+            live = int((cap // span - first // span + 1)[first <= cap].sum())
+            keys = int((cap + 1 - first).sum())
+            m.hist_chunks.inc(live, state="live", layer=kind)
+            m.hist_chunks.inc(walked - live, state="dead", layer=kind)
+            m.hist_tile_keys.inc(keys, state="live", layer=kind)
+            m.hist_tile_keys.inc(live * span - keys, state="masked",
+                                 layer=kind)
 
     # -- the jit-once fused step --------------------------------------------
     def _build_step(self):
@@ -2664,6 +2715,7 @@ class ServingEngine:
                 if live:
                     m.hist_tiles.inc(live, state="live")
                     m.hist_rows.inc(rows)
+                    self._count_hist_chunks(pf["tiles"][:, :live])
                 if empty:
                     m.hist_tiles.inc(empty, state="empty")
             # CoW lanes: unused dst = n_blocks scatters out of bounds

@@ -261,28 +261,32 @@ def test_paged_attention_auto_stacked_arena_under_tp_mesh(gpt):
                                    atol=1e-5)
 
 
-#: sha256(str(jaxpr))[:16] of the prefill lane's TILED call (a page a
-#: grid step, as ``paged_history_attention`` makes it) at commit c19234f
-#: (PR 28), the parent of the PR that made the decode rows' grid a work
-#: list, at the decode (1 row a cell) and verify (4) shapes, traced
-#: under this suite's configuration (conftest sets the default matmul
-#: precision to highest, which the jaxpr prints): that PR must leave
-#: the tiled program as it was, operand for operand
-PARENT_TILED_JAXPR = {(1, "bf16"): "fdb5044bb35c102f",
-                      (1, "int8"): "940f77d91377ae01",
-                      (4, "bf16"): "4f5b45cf09c5cad5",
-                      (4, "int8"): "68960fee15e1043e"}
+#: sha256(str(jaxpr))[:16] of the DECODE rows' call (``tiles=None``, two
+#: pages a grid step; plain | under a window) at commit 9503357 (PR 41),
+#: the parent of the PR that gave the history read's TILED call a key
+#: tile of several pages and its chunk classes, at the decode (1 row a
+#: slot) and verify (4) shapes, traced under this suite's configuration
+#: (conftest sets the default matmul precision to highest, which the
+#: jaxpr prints): that PR must leave the decode, verify and sparse-read
+#: program as it was, operand for operand. (Until then the TILED call's
+#: was pinned here, to its parent at c19234f: PRs 29 and 34 left it.)
+PARENT_DECODE_JAXPR = {
+    (1, "bf16"): ("da733211951e86ba", "abf2819644e1ff84"),
+    (1, "int8"): ("db7232807ec3b8f1", "969ef517b4108f6c"),
+    (4, "bf16"): ("2ebd2959388f611e", "eeeb5af50fe1ca1b"),
+    (4, "int8"): ("468f2491561dd42a", "b58727f74513f6ab")}
 
 
-@pytest.mark.parametrize("rows,arena", list(PARENT_TILED_JAXPR))
+@pytest.mark.parametrize("rows,arena", list(PARENT_DECODE_JAXPR))
 def test_decode_call_walks_a_work_list_and_tiled_call_is_the_parents(
         rows, arena):
     """The decode-lane and verify-lane calls walk the list of live
     (slot, chunk) pairs: ONE grid dimension whose bound is data, five
     scalar-prefetch operands (tables, offsets, layer, the pairs' slots
     and chunks; six under a window) — no static ``(S, n_steps)`` grid
-    is left. The history read's tiled call keeps seven operands, two
-    traced bounds and the parent's jaxpr to the character."""
+    is left — and the program is the parent's to the character
+    (``PARENT_DECODE_JAXPR``). The history read's tiled call keeps
+    seven operands and two traced bounds."""
     import hashlib
     S, hq, hkv, d, L, nb, bs, W = 4, 4, 2, 16, 3, 9, 4, 8
     quant = arena == "int8"
@@ -304,21 +308,26 @@ def test_decode_call_walks_a_work_list_and_tiled_call_is_the_parents(
         return eqn.params["grid_mapping"]
 
     live = jnp.asarray([True, False, True, True])
-    for kw, operands in (({}, 5), ({"live": live}, 5),
-                         ({"window": jnp.asarray(6, jnp.int32)}, 6)):
-        gm = grid_mapping(jax.make_jaxpr(
-            lambda *a: f(*a, pages_per_step=2, **kw))(*args).jaxpr)
+    plain, windowed = PARENT_DECODE_JAXPR[rows, arena]
+    for kw, operands, parents in (
+            ({}, 5, plain), ({"live": live}, 5, None),
+            ({"window": jnp.asarray(6, jnp.int32)}, 6, windowed)):
+        jaxpr = jax.make_jaxpr(
+            lambda *a: f(*a, pages_per_step=2, **kw))(*args)
+        gm = grid_mapping(jaxpr.jaxpr)
         assert gm.num_index_operands == operands
         assert gm.num_dynamic_grid_bounds == 1 and len(gm.grid) == 1
         assert not any(isinstance(b, int) for b in gm.grid)
+        assert parents in (None, hashlib.sha256(
+            str(jaxpr).encode()).hexdigest()[:16])
     tiles = {n: jnp.zeros((S,), jnp.int32)
              for n in ("cap", "cell", "lo", "hi")}
-    jaxpr = jax.make_jaxpr(
-        lambda *a: f(*a, pages_per_step=1, tiles=tiles))(*args)
-    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] \
-        == PARENT_TILED_JAXPR[rows, arena]
-    gm = grid_mapping(jaxpr.jaxpr)
-    assert gm.num_index_operands == 7 and gm.num_dynamic_grid_bounds == 2
+    for pages in (1, 2):
+        gm = grid_mapping(jax.make_jaxpr(
+            lambda *a: f(*a, pages_per_step=pages, tiles=tiles))(
+                *args).jaxpr)
+        assert gm.num_index_operands == 7
+        assert gm.num_dynamic_grid_bounds == 2
     with pytest.raises(ValueError, match="live="):
         f(*(jnp.zeros(a.shape, a.dtype) for a in args), tiles=tiles,
           live=live)
@@ -677,8 +686,7 @@ def test_decode_call_scores_a_chunk_per_head_in_one_tile(form, pages):
     ``hkv`` score products ``(rows, d) x (pages * bs, d)`` and ``hkv``
     value products — not ``pages x hkv`` of a page each — and one exp
     of a score tile a head; at one page there is nothing to join and no
-    ``concatenate`` (the tiled call's program is the parent's:
-    ``PARENT_TILED_JAXPR``)."""
+    ``concatenate``."""
     rng = np.random.default_rng(52)
     form = TILE_FORMS[form]
     hkv, d, bs = form["hkv"], form["d"], 4
@@ -720,14 +728,27 @@ HIST_CASES = {
 
 
 def _history_pack(rng, runs, *, C=16, hq=4, hkv=2, d=16, bs=4, W=8,
-                  quant=False):
+                  quant=False, v_width=None, garbage=False, window=None):
     """A pack of ``runs`` over an arena whose history rows are random:
-    the operands of the per-token formulation and of the tiles."""
+    the operands of the per-token formulation and of the tiles.
+    ``v_width``: a latent arena (no value leaf). ``garbage``: whatever
+    no row of a run may look at is ±GARBAGE — the null block, a
+    request's positions from its history's end up and, under a
+    ``window``, those below its FIRST token's."""
     from hetu_tpu.ops.quantization import quantize_int8
     n_blocks = 1 + len(runs) * W
     k, v = (rng.normal(size=(n_blocks, bs, hkv, d)).astype(np.float32)
             for _ in range(2))
     tbl = 1 + np.arange(len(runs) * W, dtype=np.int32).reshape(-1, W)
+    if garbage:
+        seen = np.zeros((n_blocks * bs,), bool)
+        for s, (_, h) in enumerate(runs):
+            first = 0 if window is None else max(h - int(window) + 1, 0)
+            seen[(1 + s * W) * bs + first:(1 + s * W) * bs + h] = True
+        for x in (k, v):
+            flat = x.reshape(-1, hkv, d)
+            flat[~seen] = GARBAGE * rng.choice(
+                [-1.0, 1.0], size=((~seen).sum(), hkv, d))
     slot, pos, hist = (np.zeros(C, np.int32) for _ in range(3))
     run_list, used = [], 0
     for s, (n, h) in enumerate(runs):
@@ -743,6 +764,9 @@ def _history_pack(rng, runs, *, C=16, hq=4, hkv=2, d=16, bs=4, W=8,
                                                       axis=-1))
                             for x in (k, v))
         arena = dict(k_scale=ks, v_scale=vs)
+    elif v_width is not None:
+        k, v = _pages(jnp.asarray(k)), None
+        arena = dict(v_width=v_width, scale=0.2)
     else:
         k, v = _pages(jnp.asarray(k)), _pages(jnp.asarray(v))
     return (q, k, v, jnp.asarray(tbl), jnp.asarray(slot),
@@ -857,6 +881,96 @@ def test_history_read_in_a_layer_scan_and_dead_tiles_read_nothing():
     out, lse2 = f(jnp.asarray(tables), jnp.asarray(1, jnp.int32))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
     np.testing.assert_array_equal(np.asarray(lse2), np.asarray(lse))
+
+
+#: runs (tokens, hist) of the joined-tile cases' pack, over pages of 4
+#: and tables of 16 lanes: histories that end inside chunk 0, at the
+#: last key of EVERY page of the upper chunk of 8 pages and mid-page
+#: (tiles of different caps in one call: the shallow ones walk dead
+#: chunks under the deepest), runs that split cells of 4 rows between
+#: them, and one without history
+TILE_RUNS = [(3, 1), (5, 7), (2, 36), (4, 40), (1, 44), (6, 48), (2, 52),
+             (3, 56), (3, 60), (5, 38), (2, 51), (2, 0)]
+
+
+@pytest.mark.parametrize("form,pages", [
+    (f, p) for f in ("g1", "g4", "latent") for p in (1, 2, 4, 8)]
+    + [("int8", 8)])
+def test_history_read_key_tile_of_several_pages_matches_per_token(
+        form, pages):
+    """The tiled call at ``pages`` pages a grid step == one reference
+    slot a token, outputs and LSE: the chunk a cap ends in is masked
+    above it (the pages the table names there are fetched at most as
+    the cap's own), a window starting mid-chunk (13 keys) masks its
+    first chunk from below, ``2 ** 30`` cuts nothing — and what no row
+    may see (±3e4) is never seen."""
+    for window in (None, 13, 2 ** 30):
+        rng = np.random.default_rng(61)
+        case = _history_pack(rng, TILE_RUNS, C=40, W=16, garbage=True,
+                             window=window, **TILE_FORMS[form])
+        w = None if window is None else jnp.asarray(window, jnp.int32)
+        _, _, ref = _assert_history_read_matches_per_token(
+            case, window=w, max_runs=len(TILE_RUNS),
+            pages_per_step=pages)
+        live = np.asarray(case[6]) > 0
+        assert np.abs(np.asarray(ref)[live]).max() < 10   # no garbage
+
+
+@pytest.mark.parametrize("pages", [1, 2, 8])
+@pytest.mark.parametrize("form", ["g1", "g4", "latent", "int8"])
+def test_history_read_scores_a_key_tile_per_head(form, pages):
+    """The tiled call's kernel at ``pages`` pages a grid step: ``hkv``
+    score products ``(rows, d) x (pages * bs, d)``, ``hkv`` value
+    products and ``hkv`` exps of a score tile — not one a page — and
+    the mask that is left: ONE select a head on the cap (a tile's rows
+    stand above every key they read: no causal compare, no row
+    positions), a second and the rows' positions only under a
+    window."""
+    rng = np.random.default_rng(62)
+    form = TILE_FORMS[form]
+    hkv, d, bs = form["hkv"], form["d"], 4
+    q, k, v, tbl, _, _, hist, runs, arena = _history_pack(
+        rng, [(6, 9), (5, 14)], W=16, **form)
+    tiles, _ = pack_history_tiles(runs, tile_rows=TQ, n_tiles=5)
+    rows, dv = TQ * form["hq"] // hkv, form.get("v_width", d)
+    for window in (None, jnp.asarray(6, jnp.int32)):
+        eqns = _kernel_eqns(jax.make_jaxpr(
+            lambda q, k, v: paged_history_attention(
+                q, k, v, jnp.take(tbl, tiles[0], axis=0), hist,
+                jnp.asarray(tiles), tile_rows=TQ, pages_per_step=pages,
+                window=window, interpret=True, **arena))(q, k, v).jaxpr)
+        dots = [e for e in eqns if e.primitive.name == "dot_general"]
+        assert sorted((e.invars[1].aval.shape, e.outvars[0].aval.shape)
+                      for e in dots) == sorted(
+            [((pages * bs, d), (rows, pages * bs))] * hkv
+            + [((pages * bs, dv), (rows, dv))] * hkv)
+        tile = (rows, pages * bs)
+
+        def count(name):
+            return sum(e.primitive.name == name
+                       and e.outvars[0].aval.shape == tile for e in eqns)
+        assert count("exp") == hkv
+        # (under a window one more: the rows' positions floor-divide)
+        assert count("select_n") == (hkv if window is None
+                                     else 2 * hkv + 1)
+        assert count("iota") == (1 if window is None else 2)
+
+
+def test_history_tile_pages_from_shapes():
+    """The pages of a grid step's key tile are a function of shapes,
+    rows first: 8 pages beside the latent cells (16 or 32 heads over
+    one 640-wide row: 512 keys), 4 beside 128 q heads over 8 kv heads
+    of 128 (8 would not fit beside the cell), 8 of GPT-2's 16-key
+    pages (128 keys); never above 512 keys, never under one page."""
+    from hetu_tpu.ops.paged_pallas import history_tile_pages
+    for g, d, hkv, bs, latent, pages in (
+            (1, 64, 12, 16, False, 8), (1, 64, 20, 16, False, 8),
+            (16, 128, 8, 64, False, 4), (16, 640, 1, 64, True, 8),
+            (32, 640, 1, 64, True, 8), (1, 64, 12, 128, False, 4),
+            (1, 64, 12, 1024, False, 1)):
+        tq = history_tile_rows(g, d, hkv, bs)
+        assert history_tile_pages(g, d, hkv, bs, tile_rows=tq,
+                                  latent=latent) == pages
 
 
 def test_history_tile_rows_from_shapes():
@@ -1229,12 +1343,23 @@ def test_engine_counts_history_tiles_on_the_host(gpt, monkeypatch):
     8-15 over 8 resident tokens — 2 live tiles, 8 rows. 3: its last 3
     tokens (1 live tile, 3 rows) beside a 3-token run across both cells
     (2 empty) and a 2-token run (1 empty). Decode iterations add
-    nothing; the tokens are the reference engine's."""
+    nothing; the tokens are the reference engine's.
+    ``serving_prefill_hist_chunks_total{state, layer}`` and
+    ``serving_prefill_hist_tile_keys_total{state, layer}`` beside them,
+    at a key tile of 2 pages (16 keys) a grid step: iteration 2's two
+    tiles stand under a cap of 7 (a step each, 8 of its 16 keys seen),
+    iteration 3's under 15 (a step, every key). Then a pack of TWO
+    runs with different histories (prefix hits of 24 and of 8 tokens:
+    the deepest cap, 23, makes the grid two steps deep and the
+    shallower run's two tiles walk a dead one each), counted again as
+    a layer with a window of 6 sees it."""
     from hetu_tpu.ops import paged_pallas
     from hetu_tpu.serving import SamplingParams, ServingEngine
     cfg, model, params = gpt
     monkeypatch.setattr(paged_pallas, "history_tile_rows",
                         lambda *a, **kw: 4)
+    monkeypatch.setattr(paged_pallas, "history_tile_pages",
+                        lambda *a, **kw: 2)
     prompts = _prompts(cfg, (19, 3, 2), seed=29)
     sp = SamplingParams(max_tokens=2)
     kw = dict(slots=3, max_len=MAX_LEN, prefill_chunk=CHUNK,
@@ -1251,6 +1376,39 @@ def test_engine_counts_history_tiles_on_the_host(gpt, monkeypatch):
         assert tiles.value(state="live") == 3
         assert tiles.value(state="empty") == 5
         assert reg.counter("serving_prefill_hist_rows_total").value() == 11
+        chunks = reg.counter("serving_prefill_hist_chunks_total")
+        keys = reg.counter("serving_prefill_hist_tile_keys_total")
+
+        def counts(layer):
+            return [c.value(state=st, layer=layer) for c, st in (
+                (chunks, "live"), (chunks, "dead"), (keys, "live"),
+                (keys, "masked"))]
+        assert eng._hist_span == 2 * BLOCK and eng._min_window is None
+        assert counts("full") == [3, 0, 32, 16]
+        assert counts("window") == [0, 0, 0, 0]
+        # two runs with history in ONE pack: a served 27-token prompt
+        # leaves three blocks in the prefix cache; D shares all three
+        # (2 tokens over 24), E the first (3 tokens over 8, across two
+        # cells) — tiles (D, cell 0), (E, cell 0), (E, cell 1)
+        long_, = _prompts(cfg, (27,), seed=31)
+        other = [t % (cfg.vocab_size - 1) + 1 for t in long_]
+        assert eng.generate_many([long_], sp) \
+            == ServingEngine(model, params, **kw).generate_many(
+                [long_], sp)
+        before = counts("full")
+        eng._min_window = 6     # the host's count of a windowed layer
+        pair = [long_[:24] + other[24:26], long_[:8] + other[8:11]]
+        reqs = [eng.submit(p, sp) for p in pair]
+        eng.run_until_drained()
+        assert [r.tokens for r in reqs] == ServingEngine(
+            model, params, **kw).generate_many(pair, sp)
+        assert tiles.value(state="live") == 3 + 5 + 3
+        # full: D computes both steps, E's tiles one and walk one dead
+        assert list(np.subtract(counts("full"), before)) \
+            == [4, 2, 24 + 8 + 8, 4 * 16 - 40]
+        # window 6: D's rows start at 24 and see 19..23 (one step of
+        # two), E's first tile's at 8 (3..7), its second's at 10 (5..7)
+        assert counts("window") == [3, 3, 5 + 5 + 3, 3 * 16 - 13]
         # the gather lane cuts no tiles
         ref = ServingEngine(model, params, attn_kernel="paged", **kw)
         assert ref._hist_tiles == 0

@@ -192,29 +192,41 @@ def test_packed_prefill_lane_compiles_for_v5e(one_chip, dtype):
                                                chunk=256, dtype=dtype)
 
 
-@pytest.mark.parametrize("cell,kw,tile_rows,tiles", [
+@pytest.mark.parametrize("cell,kw,tile_rows,tiles,pages", [
     ("gpt2-small.chat", dict(
         chunk=256, dtype=jnp.float32, slots=148, n_blocks=9473,
-        table_width=65), 128, 149),
+        table_width=65), 128, 149, 8),
     ("gpt2-large.backlog", dict(
         chunk=256, dtype=jnp.float32, heads=20, layers=36, slots=32,
-        n_blocks=2049, table_width=65), 128, 33),
+        n_blocks=2049, table_width=65), 128, 33, 8),
     ("command-a-plus-ep8.mixed-backlog", dict(
         chunk=512, dtype=jnp.bfloat16, heads=128, kv_heads=8,
         head_dim=128, layers=4, slots=48, n_blocks=4250, block_size=64,
-        table_width=129, windowed=True), 16, 79),
+        table_width=129, windowed=True), 16, 79, 4),
+    ("kimi-vl-a3b-pp4.longdoc-backlog", dict(
+        chunk=2048, dtype=jnp.bfloat16, heads=16, kv_heads=1,
+        head_dim=640, v_width=512, layers=7, slots=48, n_blocks=9400,
+        block_size=64, table_width=257), 32, 111, 8),
+    ("ling-3.0-flash-vl-ep8.video-8k-backlog", dict(
+        chunk=2048, dtype=jnp.bfloat16, heads=32, kv_heads=1,
+        head_dim=640, v_width=512, layers=2, slots=72, n_blocks=9600,
+        block_size=64, table_width=133), 16, 199, 8),
 ], ids=lambda x: x if isinstance(x, str) else "")
 def test_prefill_history_tiles_compile_for_v5e(one_chip, cell, kw,
-                                               tile_rows, tiles):
-    """The prefill lane's attention at the three serving cells' sizes:
+                                               tile_rows, tiles, pages):
+    """The prefill lane's attention at the five serving cells' sizes:
     the history read in tiles (a grid whose bounds are data, a key cap
     and — Command A+ — the layer's window as scalar operands, cells of
     128 rows x 12 or 20 heads, of 16 tokens x 16 group members x 8
-    heads) compiles for the chip, at the tile size and count the engine
-    derives from the head shapes."""
+    heads, of 32 or 16 tokens x 16 or 32 heads over ONE latent row)
+    compiles for the chip under the default VMEM limit, at the tile
+    size, tile count and pages of a grid step's key tile (128 keys of
+    GPT-2's pages, 256 of Command A+'s, 512 of a latent arena's) the
+    engine derives from the shapes."""
     from workloads.aot_check import check_packed_prefill
     r = check_packed_prefill(list(one_chip.device_set), **kw)
-    assert (r["tile_rows"], r["tiles"]) == (tile_rows, tiles), r
+    assert (r["tile_rows"], r["tiles"], r["pages"]) \
+        == (tile_rows, tiles, pages), r
 
 
 def test_mosaic_cp_dropout_train_step_compiles_for_v5e(topo8):

@@ -162,24 +162,29 @@ def check_paged(devs, *, dtype=jnp.bfloat16, rows=1, return_lse=False,
 def check_packed_prefill(devs, *, chunk=256, dtype=jnp.bfloat16, heads=12,
                          kv_heads=None, head_dim=64, layers=12,
                          n_blocks=2048, block_size=16, table_width=64,
-                         slots=8, windowed=False):
+                         slots=8, windowed=False, v_width=None):
     """The packed-prefill lane's attention as ``ParallelAttention.
     _decode_packed`` runs it: one ``(1, chunk)`` row of pack tokens
     through the flash forward kernel with segment ids, LSE-combined
     with each token's arena history — read once per TILE of a request's
     run (``paged_history_attention``: the paged kernel under a key cap,
     on a grid whose bounds are data) over the stacked arena at a traced
-    ``layer``. Tile size and count as the engine derives them from the
-    head shapes and ``slots``."""
+    ``layer``. Tile size, tile count and the pages of a grid step's
+    key tile as the engine derives them from the head shapes and
+    ``slots``. ``v_width``: a LATENT arena's history read (one key
+    head ``head_dim`` wide whose first ``v_width`` columns are the
+    value) — the read alone, the in-pack part is not a kernel."""
     from hetu_tpu.ops.attention import attention_with_lse
     from hetu_tpu.ops.paged_pallas import (
         TILE_FIELDS, combine_attention_lse, history_tile_count,
-        history_tile_rows, paged_history_attention,
+        history_tile_pages, history_tile_rows, paged_history_attention,
     )
     mesh = _one_dev_mesh(devs)
     hkv = kv_heads or heads
     tq = history_tile_rows(heads // hkv, head_dim, hkv, block_size)
     n_tiles = history_tile_count(chunk, tq, min(slots, chunk))
+    pages = history_tile_pages(heads // hkv, head_dim, hkv, block_size,
+                               tile_rows=tq, latent=v_width is not None)
     q = _sds((1, chunk, heads, head_dim), dtype, mesh)
     kv = _sds((1, chunk, hkv, head_dim), dtype, mesh)
     seg = _sds((1, chunk), jnp.int32, mesh)
@@ -188,6 +193,11 @@ def check_packed_prefill(devs, *, chunk=256, dtype=jnp.bfloat16, heads=12,
     tiles = _sds((len(TILE_FIELDS), n_tiles), jnp.int32, mesh)
 
     def f(q, k, v, seg, ka, va, tbl, hist, tiles, layer, window):
+        if v_width is not None:
+            return paged_history_attention(
+                q[0], ka, None, tbl, hist, tiles, tile_rows=tq,
+                layer=layer, interpret=False, v_width=v_width,
+                scale=head_dim ** -0.5)
         intra, lse_i = attention_with_lse(
             q, k, v, causal=True, segment_ids=seg, impl="pallas",
             interpret=False)
@@ -202,7 +212,7 @@ def check_packed_prefill(devs, *, chunk=256, dtype=jnp.bfloat16, heads=12,
         _sds((n_tiles, table_width), jnp.int32, mesh),
         _sds((chunk,), jnp.int32, mesh), tiles,
         _sds((), jnp.int32, mesh), _sds((), jnp.int32, mesh))),
-        tile_rows=tq, tiles=n_tiles)
+        tile_rows=tq, tiles=n_tiles, pages=pages)
 
 
 _HLO_INSTR = re.compile(

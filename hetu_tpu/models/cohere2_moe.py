@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from hetu_tpu.core.dtypes import autocast
 from hetu_tpu.nn.layers import LayerNorm
 from hetu_tpu.nn.module import Module, normal_init
-from hetu_tpu.nn.moe import ExpertShareMoE, count_local_share
+from hetu_tpu.nn.moe import ExpertShareMoE
 from hetu_tpu.nn.parallel import (
     ParallelAttention, ParallelMLP, StackedBlocks, VocabParallelEmbedding,
 )
@@ -143,7 +143,7 @@ class Cohere2MoEBlock(Module):
         #: (``StackedBlocks.decode(with_stats=True)``): the expert
         #: layer's group sizes, counted on the host
         self.layer_stats = {"moe_local_sizes": (
-            (self.moe.local_experts[1],), jnp.int32, count_local_share)}
+            (self.moe.local_experts[1],), jnp.int32, self.moe.count_share)}
         self._shared_mean = 1.0 / cfg.num_shared_experts
         self._policy = {"float32": "fp32",
                         "bfloat16": "bf16"}[cfg.compute_dtype]

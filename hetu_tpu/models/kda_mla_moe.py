@@ -50,9 +50,7 @@ import jax.numpy as jnp
 from hetu_tpu.core.dtypes import autocast
 from hetu_tpu.nn.layers import RMSNorm
 from hetu_tpu.nn.module import Module, normal_init
-from hetu_tpu.nn.moe import (
-    ExpertShareMoE, count_group_held, count_local_share,
-)
+from hetu_tpu.nn.moe import ExpertShareMoE, count_group_held
 from hetu_tpu.nn.parallel import (
     KimiDeltaAttention, LatentAttention, LayerKV, ParallelMLP,
     SlotStateNotSupported, StackedBlocks, VocabParallelEmbedding,
@@ -196,7 +194,8 @@ class HybridBlock(Module):
             self.unsliced = (("moe", "wg"), ("moe", "wi"), ("moe", "wo"))
             held = self.moe.local_experts[1]
             self.layer_stats = {
-                "moe_local_sizes": ((held,), jnp.int32, count_local_share),
+                "moe_local_sizes": ((held,), jnp.int32,
+                                    self.moe.count_share),
                 "moe_group_held": ((2,), jnp.int32, count_group_held)}
         self._policy = {"float32": "fp32",
                         "bfloat16": "bf16"}[cfg.compute_dtype]

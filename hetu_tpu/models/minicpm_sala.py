@@ -209,7 +209,7 @@ class SalaBlock(Module):
         return y, new_cache
 
 
-def count_sparse_pages(values) -> None:
+def count_sparse_pages(values, tokens=None) -> None:
     """``layer_stats`` on the host: ``values (sparse layers, 4)`` — the
     pages the rows of a lane chose and could see, summed over its live
     rows and kv heads, as ``[chosen, visible]`` of the decode rows then

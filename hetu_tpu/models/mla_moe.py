@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from hetu_tpu.core.dtypes import autocast
 from hetu_tpu.nn.layers import RMSNorm
 from hetu_tpu.nn.module import Module, normal_init
-from hetu_tpu.nn.moe import ExpertShareMoE, count_local_share
+from hetu_tpu.nn.moe import ExpertShareMoE
 from hetu_tpu.nn.parallel import (
     LatentAttention, LatentKVNotSupported, LayerKV, ParallelMLP,
     StackedBlocks, VocabParallelEmbedding,
@@ -142,7 +142,7 @@ class MLABlock(Module):
             #: scan's slice (``StackedBlocks.decode``)
             self.unsliced = (("moe", "wg"), ("moe", "wi"), ("moe", "wo"))
             self.layer_stats = {"moe_local_sizes": (
-                (cfg.n_routed_experts,), jnp.int32, count_local_share)}
+                (cfg.n_routed_experts,), jnp.int32, self.moe.count_share)}
         self._policy = {"float32": "fp32",
                         "bfloat16": "bf16"}[cfg.compute_dtype]
 

@@ -796,15 +796,30 @@ def _ep_dispatch(x, idx, wgt, eparams, *, ep, num_experts, k,
 
 
 # -- one chip's share of an expert-parallel layer (serving) -------------------
-def count_local_share(sizes) -> None:
+def count_local_share(sizes, *, tile: Optional[int] = None,
+                      window: Optional[int] = None) -> None:
     """:class:`ExpertShareMoE`'s counters, on the host: ``sizes``
     ``(layer calls, held experts)`` int — the group sizes of every
     layer of one executed scan (``return_sizes=True``; the serving
-    engine calls this with what its step returned)."""
+    engine calls this with what its step returned). With ``tile`` (and
+    the ``window`` of a share) also what the grouped matmuls' aligned
+    layout cost: the rows routed here beside the rows of the tiles the
+    kernel visited (:meth:`ExpertShareMoE.count_share` knows both)."""
     from hetu_tpu import telemetry
     import numpy as np
     reg = telemetry.get_registry()
     sizes = np.asarray(sizes)
+    if tile:
+        from hetu_tpu.ops.grouped_matmul_pallas import grouped_rows_computed
+        rows = reg.counter(
+            "moe_grouped_rows_total",
+            "rows of the grouped expert matmuls: live = (token, choice) "
+            "pairs routed to a held expert, computed = rows of the row "
+            "tiles the kernel visited (every group rounded up to the "
+            "tile), summed over layer calls")
+        rows.inc(float(sizes.sum()), kind="live")
+        rows.inc(float(sum(grouped_rows_computed(s, tile, window)
+                           for s in sizes)), kind="computed")
     reg.counter(
         "moe_local_calls_total",
         "executed calls of an expert-share MoE layer").inc(
@@ -827,11 +842,12 @@ def count_local_share(sizes) -> None:
             per.inc(float(n), expert=str(e))
 
 
-def count_group_held(values) -> None:
+def count_group_held(values, tokens: Optional[int] = None) -> None:
     """A group-limited :class:`ExpertShareMoE`'s counters, on the host:
     ``values`` ``(layer calls, 2)`` int — per layer of one executed
     scan, the tokens whose kept groups reach an expert held here, and
-    the tokens routed (``return_stats=True``)."""
+    the tokens routed (``return_stats=True``; ``tokens``, the lane's
+    rows, is every ``layer_stats`` emit's and not needed here)."""
     from hetu_tpu import telemetry
     import numpy as np
     reg = telemetry.get_registry()
@@ -868,14 +884,26 @@ class ExpertShareMoE(Module):
     reference alike (``benchmark/reference/cohere2_moe.py``). No token
     is dropped: there is no capacity. The (token, choice) pairs are
     sorted by local expert and the three expert matmuls run as grouped
-    matmuls over the sorted rows (``jax.lax.ragged_dot``: a row costs
-    one expert's arithmetic, an expert's weights are read once) — never
-    a per-token gather of expert weights. Inside a layer scan the expert
-    weights come as :class:`~hetu_tpu.nn.module.StackedLeaf` (the
-    model's block lists them as ``unsliced``): the grouped matmul then
-    runs over the groups of ALL layers, every other layer's empty — the
-    kernel reads no page of an empty group, and no layer's 1.6 GB of
-    experts is sliced out (a copy) for it.
+    matmuls over the sorted rows — the Pallas kernel of
+    ``ops/grouped_matmul_pallas.py``, three calls a layer call: a row
+    costs one expert's arithmetic, a held expert that got a row has
+    each of its matrices read ONCE a call and one that got none never —
+    never a per-token gather of expert weights. The sorted rows are
+    laid out with each expert's on row tiles of their own
+    (:meth:`tile_rows`, from the call's rows and the held experts: 16
+    where a lane has a few rows an expert, 64 or 256 where it has
+    dozens or hundreds; the tables in the ``hetu.moe_route`` scope),
+    the second call writes ``silu(gate) * up`` as its epilogue, and the
+    results come back through the layout's inverse in the one gather
+    that brings the pairs to token order. Inside a layer scan the
+    expert weights come as :class:`~hetu_tpu.nn.module.StackedLeaf`
+    (the model's block lists them as ``unsliced``): the kernel's index
+    map takes ``layer x held + expert`` from a scalar operand — no
+    layer's 1.6 GB of experts is sliced out (a copy) for it. A SHARE
+    walks its sorted rows in windows (:meth:`_window_rows`), each laid
+    out and run by the same kernel. ``jax.lax.ragged_dot``, which these
+    calls were until PR 43, stays in the tests as the reference
+    (``tests/conftest.py::ragged_dot_experts``).
 
     Operands: the router in float32 at the highest matmul precision on
     the input as given (a top-k flips on rounding; the input is the
@@ -935,6 +963,24 @@ class ExpertShareMoE(Module):
             return pairs
         want = max(2 * pairs * count // self.num_experts, 128)
         return min(pairs, -(-want // 128) * 128)
+
+    def tile_rows(self, pairs: int) -> int:
+        """Rows of a tile of the grouped matmuls where a call routes
+        ``pairs`` (token, choice) pairs: from the window's rows and the
+        held experts (``ops.grouped_matmul_pallas.grouped_tile_rows``)."""
+        from hetu_tpu.ops.grouped_matmul_pallas import grouped_tile_rows
+        return grouped_tile_rows(self._window_rows(pairs),
+                                 self.local_experts[1])
+
+    def count_share(self, sizes, tokens: Optional[int] = None) -> None:
+        """The layer's host counters (a block's ``layer_stats`` emit):
+        :func:`count_local_share` of the scan's group sizes; the lane's
+        ``tokens`` a call say which tile and window the calls used."""
+        pairs = (tokens or 0) * self.k
+        if not pairs:
+            return count_local_share(sizes)
+        count_local_share(sizes, tile=self.tile_rows(pairs),
+                          window=self._window_rows(pairs))
 
     def _kept_groups(self, sel):
         """``sel (T, E)`` selection scores -> ``(T, n_group)`` bool: the
@@ -999,49 +1045,55 @@ class ExpertShareMoE(Module):
             sizes = jnp.sum(
                 key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
                 axis=0, dtype=jnp.int32)
-            w_rows = jnp.take(w.reshape(M), order)
             # where each pair's row went, to bring its result back
             back = jnp.zeros((M,), jnp.int32).at[order].set(
                 jnp.arange(M, dtype=jnp.int32))
             total = sizes.sum()
 
-        def grouped(a, name, sizes):
-            w, groups = params[name], sizes
+        from hetu_tpu.ops.grouped_matmul_pallas import (
+            grouped_layout, grouped_matmul,
+        )
+        R = self._window_rows(M)
+        tile = self.tile_rows(M)
+
+        def grouped(a, name, lay, **kw):
+            w, layer = params[name], None
             if isinstance(w, StackedLeaf):
                 w, layer = w
-                groups = jax.lax.dynamic_update_slice(
-                    jnp.zeros((w.shape[0] * count,), jnp.int32),
-                    sizes, (layer * count,))
-                w = w.reshape((-1,) + w.shape[2:])
-            return jax.lax.ragged_dot(
-                a, w.astype(dt), groups,
-                preferred_element_type=jnp.float32)
+            return grouped_matmul(a, w.astype(dt), lay, layer=layer, **kw)
 
-        def experts(at, w_at, sizes, live):
-            """The sorted pairs ``at`` (indices into the pairs) through
-            their experts, weighted; rows that are not ``live`` (behind
-            the last group: never computed) are zeros."""
+        def experts(at, sizes, wanted):
+            """The sorted pairs ``at`` (indices into the pairs; the
+            first ``sizes.sum()`` are live) through their experts, in
+            the ALIGNED layout, and back: row ``i`` of the result is
+            the sorted row ``wanted[i]``'s. Rows of no group are never
+            computed and never read."""
             with jax.named_scope("hetu.moe_route"):
-                rows = jnp.take(xf, at // k, axis=0).astype(dt)
-            h = (jax.nn.silu(grouped(rows, "wg", sizes))
-                 * grouped(rows, "wi", sizes)).astype(dt)
-            y = grouped(h, "wo", sizes)
-            return jnp.where(live[:, None], y * w_at[:, None], 0.0)
+                lay = grouped_layout(sizes, rows=at.shape[0], tile=tile)
+                rows = jnp.take(xd, jnp.take(at, lay.src) // k, axis=0)
+                wanted = jnp.take(lay.dst, wanted)
+            h = grouped(rows, "wi", lay, gate=grouped(rows, "wg", lay),
+                        out_dtype=dt)
+            return jnp.take(grouped(h, "wo", lay), wanted, axis=0)
 
-        R = self._window_rows(M)
+        w_pairs = w.reshape(M)
+        with jax.named_scope("hetu.moe_route"):
+            # the tokens in the operands' dtype once: the padded layout
+            # gathers more rows than there are pairs
+            xd = xf.astype(dt)
         with jax.named_scope("hetu.moe_experts"):
             if R >= M:
-                y = experts(order, w_rows, sizes, jnp.arange(M) < total)
-                out = jnp.take(y, back, axis=0).reshape(-1, k, d).sum(1)
+                out = jnp.where(
+                    (back < total)[:, None],
+                    experts(order, sizes, back) * w_pairs[:, None],
+                    0.0).reshape(-1, k, d).sum(1)
             else:
                 # a SHARE: the pairs held here are the first ``total``
                 # sorted rows, an eighth of them or so — the grouped
                 # matmuls walk them in windows of R rows (one window,
                 # unless the tokens crowd onto this chip), never the
                 # rows behind them
-                pad = -M % R
-                order_p = jnp.pad(order, (0, pad))
-                w_p = jnp.pad(w_rows, (0, pad))
+                order_p = jnp.pad(order, (0, -M % R))
                 hi = jnp.cumsum(sizes)
                 lo = hi - sizes
 
@@ -1049,13 +1101,11 @@ class ExpertShareMoE(Module):
                     start = i * R
                     inside = jnp.clip(jnp.minimum(hi, start + R)
                                       - jnp.maximum(lo, start), 0)
-                    y = experts(
-                        jax.lax.dynamic_slice(order_p, (start,), (R,)),
-                        jax.lax.dynamic_slice(w_p, (start,), (R,)),
-                        inside, start + jnp.arange(R) < total)
                     rel = back - start
                     mine_w = (rel >= 0) & (rel < R) & (back < total)
-                    part = jnp.take(y, jnp.clip(rel, 0, R - 1), axis=0)
+                    part = experts(
+                        jax.lax.dynamic_slice(order_p, (start,), (R,)),
+                        inside, jnp.clip(rel, 0, R - 1)) * w_pairs[:, None]
                     return out + jnp.where(mine_w[:, None], part, 0.0) \
                         .reshape(-1, k, d).sum(1)
 

@@ -2346,7 +2346,8 @@ class StackedBlocks(Module):
         decode call; ``with_stats=True`` returns them stacked over the
         layers as a third result here (``{}`` from any other block) —
         the serving step hands them out and the engine gives each
-        executed lane's to ``emit`` on the host."""
+        executed lane's to ``emit(values, tokens=the token rows a call
+        of that lane's layers takes)`` on the host."""
         xs = {"p": params,
               "layer": jnp.arange(self.num_layers, dtype=jnp.int32)}
         if self.first_layer:

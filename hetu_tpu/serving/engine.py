@@ -2773,12 +2773,14 @@ class ServingEngine:
                 m.prefill_kernel.inc(path=self._prefill_path)
             if telemetry.enabled():
                 # the layers' stats of the lanes that ran, to the host
-                # functions the model's block names for them
+                # functions the model's block names for them, with the
+                # token rows a call of that lane's layers takes
                 emit = getattr(self.model.blocks.block, "layer_stats", {})
-                for ran, stats in zip((active_prev.size, used),
-                                      res["stats"]):
+                for ran, rows, stats in zip(
+                        (active_prev.size, used),
+                        (em.size, pf["tokens"].size), res["stats"]):
                     for name, values in stats.items() if ran else ():
-                        emit[name][2](values)
+                        emit[name][2](values, tokens=rows)
             # decode results for the slots that were active going in:
             # each commits ncommit tokens (accepted drafts + bonus) —
             # EOS or budget can finish the request mid-commit, in which

@@ -339,3 +339,41 @@ def pytest_runtest_makereport(item, call):
 @pytest.fixture
 def rng():
     return jax.random.key(0)
+
+
+@pytest.fixture
+def ragged_dot_experts():
+    """``ExpertShareMoE`` as it computed its experts before the Pallas
+    grouped matmul (PR 43): the pairs held here sorted by expert, three
+    ``jax.lax.ragged_dot`` calls over the dense sorted rows, weighted
+    and brought back. The layer's own route; the reference its kernel
+    path is held to."""
+    import jax.numpy as jnp
+
+    def layer(moe, params, x):
+        d, k = x.shape[-1], moe.k
+        xf = x.reshape(-1, d)
+        M = xf.shape[0] * k
+        first, count = moe.local_experts
+        idx, w = moe.route(params, xf)
+        local = idx - first
+        key = jnp.where((local >= 0) & (local < count), local,
+                        count).reshape(M)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(key[:, None] == jnp.arange(count)[None], axis=0,
+                        dtype=jnp.int32)
+        dt = moe.compute_dtype()
+        rows = jnp.take(xf, order // k, axis=0).astype(dt)
+
+        def grouped(a, name):
+            return jax.lax.ragged_dot(a, params[name].astype(dt), sizes,
+                                      preferred_element_type=jnp.float32)
+
+        h = (jax.nn.silu(grouped(rows, "wg"))
+             * grouped(rows, "wi")).astype(dt)
+        y = grouped(h, "wo") * jnp.take(w.reshape(M), order)[:, None]
+        y = jnp.where((jnp.arange(M) < sizes.sum())[:, None], y, 0.0)
+        out = jnp.zeros((M, d), jnp.float32).at[order].set(y)
+        return out.reshape(-1, k, d).sum(1).astype(dt).reshape(x.shape)
+
+    return layer

@@ -146,6 +146,8 @@ def test_engine_counts_the_share_and_the_dead_window_blocks(tiny):
         before = {n: reg.counter(n).value() for n in names}
         per0 = [reg.counter("moe_local_expert_tokens").value(
             expert=str(e)) for e in range(8)]
+        rows0 = {kind: reg.counter("moe_grouped_rows_total").value(
+            kind=kind) for kind in ("live", "computed")}
         eng = ServingEngine(share, held, slots=2, max_len=32,
                             prefill_chunk=8, block_size=4,
                             prefix_cache=False)
@@ -159,12 +161,21 @@ def test_engine_counts_the_share_and_the_dead_window_blocks(tiny):
         got = {n: reg.counter(n).value() - before[n] for n in names}
         per = [reg.counter("moe_local_expert_tokens").value(
             expert=str(e)) - per0[e] for e in range(8)]
+        rows = {kind: reg.counter("moe_grouped_rows_total").value(
+            kind=kind) - rows0[kind] for kind in rows0}
     finally:
         telemetry.enable(False)
     assert len(req.tokens) == 12
     # 2 prefill chunks and 11 decode steps, 8 layers each
     assert got["moe_local_calls_total"] == 8 * (2 + 11)
     assert sum(per) == got["moe_local_assignments_total"] > 0
+    # what the aligned layout costs: the kernel visits whole tiles of
+    # 16 rows, one or more a touched expert and layer call — counted on
+    # the host from the same group sizes and the layer's tile rule
+    assert rows["live"] == got["moe_local_assignments_total"]
+    assert rows["computed"] % 16 == 0 and rows["live"] < rows["computed"] \
+        <= 16 * got["moe_local_experts_touched_total"] + rows["live"]
+    assert rows["computed"] >= 16 * got["moe_local_experts_touched_total"]
     assert 0 < got["moe_local_experts_touched_total"] \
         <= 8 * got["moe_local_calls_total"]
     # 8 of 16 experts held, top-4: about half of the (token, choice)
@@ -261,3 +272,26 @@ def test_interleaved_rope_is_a_pairwise_rotation():
     ref = np.asarray(reference.rope_interleaved(
         jnp.asarray(x[0], jnp.float32), jnp.asarray(pos[0]), theta))
     np.testing.assert_allclose(ref, want[0], atol=1e-5)
+
+
+def test_kernel_experts_equal_ragged_dot_through_two_windows(
+        ragged_dot_experts):
+    """A share of 2 of 16 experts that the router crowds (every token
+    picks both): 160 live pairs walk the window loop in TWO windows of
+    128 rows, each laid out on its own — against three ``ragged_dot``
+    calls over all the sorted rows at once."""
+    moe = ExpertShareMoE(16, 8, 16, k=4, local_experts=(4, 2))
+    params = moe.init(jax.random.key(0), dtype=jnp.float32)
+    bias = jnp.full((16,), -9.0).at[jnp.asarray([0, 1, 4, 5])].set(
+        jnp.asarray([3., 2., 6., 5.]))
+    x = jnp.concatenate([jax.random.normal(jax.random.key(1), (80, 15)),
+                         jnp.ones((80, 1))], axis=-1)
+    params = {**params, "router": params["router"].at[15].set(bias)}
+    assert moe._window_rows(80 * 4) == 128
+    out, sizes = jax.jit(lambda p, x: moe(p, x, return_sizes=True))(
+        params, x)
+    assert sizes.tolist() == [80, 80]            # two windows of 128
+    want = ragged_dot_experts(moe, params, x)
+    assert float(jnp.abs(want).max()) > 1e-4
+    np.testing.assert_allclose(out, want, atol=1e-6)
+

@@ -327,6 +327,23 @@ def test_group_limited_route_equals_the_references():
             != np.sort(np.asarray(ridx), 1)).any()
 
 
+def test_kernel_experts_equal_ragged_dot_under_the_group_limit(
+        ragged_dot_experts):
+    """``n_group`` 8 / ``topk_group`` 4, the held experts one group's
+    pair (Ling's deployment in small): the Pallas grouped matmuls
+    against ``ragged_dot`` on the layer's own group-limited route."""
+    moe = _moe(n_group=8, topk_group=4, local_experts=(6, 2))
+    params = moe.init(jax.random.key(0))
+    params["select_bias"] = 0.3 * jax.random.normal(jax.random.key(1), (16,))
+    u = jax.random.normal(jax.random.key(2), (200, 32))
+    out, stats = jax.jit(lambda p, x: moe(p, x, return_stats=True))(
+        params, u)
+    assert 0 < int(stats["sizes"].sum()) < 200 * 3
+    want = ragged_dot_experts(moe, params, u)
+    assert float(jnp.abs(want).max()) > 1e-4
+    np.testing.assert_allclose(out, want, atol=1e-6)
+
+
 @pytest.mark.parametrize("bias", [True, False])
 def test_one_group_is_the_ungrouped_route_to_the_bit(bias):
     moe = ExpertShareMoE(32, 16, 16, k=3, select_bias=bias, scale=2.5,

@@ -1552,3 +1552,53 @@ def test_tp2_paged_kernel_no_fallback_greedy_identical(gpt):
     assert trace_counts().get("serving_step", 0) - before == 1
     assert eng.step_executables() == 1      # one trace AND one compile
     assert want == [_ref(model, params, p, 6) for p in prompts]
+
+
+#: sha256(str(jaxpr))[:16] of the WHOLE fused serving step (CoW pass,
+#: both lanes, sampling; ``ServingEngine._build_step``) of GPT-2 small
+#: (148 slots) and GPT-2 large (32 slots) at the benchmark cells'
+#: shapes, at commit e9e9b15 (PR 42), traced under this suite's
+#: configuration: the PR that gave the expert layer its grouped-matmul
+#: kernel (and the ``layer_stats`` emitters the lane's token rows) must
+#: leave the program of the models WITHOUT an expert layer as it was.
+PARENT_STEP_JAXPR = {"gpt2-small": "6a47ef4078891afc", "gpt2-large": "20ae0a9a6d9445d6"}
+
+
+@pytest.mark.parametrize("name", list(PARENT_STEP_JAXPR))
+def test_fused_step_without_experts_is_the_parents(name):
+    import hashlib
+    from hetu_tpu.models import GPTConfig, GPTLMHeadModel
+    from hetu_tpu.serving import ServingEngine
+    cfg, slots = {
+        "gpt2-small": (GPTConfig.small(), 148),
+        "gpt2-large": (GPTConfig(
+            vocab_size=50257, max_positions=1024, hidden_size=1280,
+            num_layers=36, num_heads=20), 32)}[name]
+    model = GPTLMHeadModel(cfg)
+    params = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
+                       jax.random.key(0)))
+    eng = ServingEngine(model, params, max_len=1024, prefill_chunk=256,
+                        cache_dtype=jnp.bfloat16, block_size=16,
+                        slots=slots, kv_blocks=65, attn_kernel="paged",
+                        prefill_attn="flash_pallas")
+
+    class Recorded(Exception):
+        pass
+
+    def record(*args):
+        raise Recorded(args)
+
+    eng._fn = record
+    eng.submit([1, 2, 3])
+    with pytest.raises(Recorded) as seen:
+        eng.step()
+    args, = seen.value.args
+    sds = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), np.asarray(x).dtype
+                                       if not hasattr(x, "dtype")
+                                       else x.dtype), args)
+    jaxpr = jax.make_jaxpr(eng._build_step())(*sds)
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] \
+        == PARENT_STEP_JAXPR[name]

@@ -190,6 +190,35 @@ def test_no_token_is_dropped_when_all_pick_one_expert():
                                atol=1e-6)
 
 
+def test_kernel_experts_equal_ragged_dot_all_held(ragged_dot_experts):
+    """All 8 experts held (Kimi's layer): the Pallas grouped matmuls on
+    the aligned layout against three ``ragged_dot`` calls on the dense
+    sorted rows — plain, and as a ``StackedLeaf`` with a traced layer
+    inside a scan, as the serving step hands the weights over."""
+    from hetu_tpu.nn.module import StackedLeaf
+    moe = ExpertShareMoE(16, 8, 8, k=3, select_bias=True, scale=2.446)
+    params = moe.init(jax.random.key(4))
+    x = jax.random.normal(jax.random.key(5), (45, 16))
+    want = ragged_dot_experts(moe, params, x)
+    assert float(jnp.abs(want).max()) > 1e-3
+    out, sizes = jax.jit(lambda p, x: moe(p, x, return_sizes=True))(
+        params, x)
+    assert int(sizes.sum()) == 45 * 3 and moe.tile_rows(45 * 3) == 32
+    np.testing.assert_allclose(out, want, atol=1e-6)
+
+    # three layers stacked, layer 1 is this one's, the others' differ
+    stack = {n: jnp.stack([params[n] * 0 + 1, params[n], params[n] * 2])
+             for n in ("wg", "wi", "wo")}
+
+    def body(carry, layer):
+        p = {**params, **{n: StackedLeaf(stack[n], layer) for n in stack}}
+        return carry, moe(p, x)
+
+    _, outs = jax.jit(lambda: jax.lax.scan(body, 0, jnp.arange(3)))()
+    np.testing.assert_allclose(outs[1], want, atol=1e-6)
+    assert float(jnp.abs(outs[0] - want).max()) > 1e-3
+
+
 # -- (e) the arena: one latent leaf -----------------------------------------
 
 def test_arena_is_one_leaf_of_stored_rows(tiny):

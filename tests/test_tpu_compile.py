@@ -183,6 +183,37 @@ def test_fused_serving_step_keeps_the_arena_in_place_on_v5e(one_chip):
     assert r["temp_bytes"] < r["layer_leaf_bytes"], r
 
 
+def test_kimi_step_holds_three_expert_kernel_calls_a_lane_on_v5e(one_chip):
+    """Kimi-VL's fused step at the published widths (hidden 2048, experts
+    1408 wide; cut to one dense and two expert layers of 12 experts so
+    that the host holds its zeros): under ``hetu.moe_experts`` each lane
+    holds exactly the THREE Pallas grouped matmuls of a layer call
+    (``moe_experts_roofline_pct.*`` divides the scope by a third of its
+    custom calls), the compiler made no ``ragged-dot`` of its own, and
+    nothing copies or slices a layer's experts (``StackedLeaf``: the
+    kernel's index map takes the layer)."""
+    import json
+    import os
+
+    from benchmark.runners.serve_arch import load_arch
+    from workloads.aot_check import check_serving_step
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-vl-a3b-pp4.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=3, n_routed_experts=12, num_experts=12,
+               vocab_size=4096)
+    model = load_arch(cfg["arch"]).build(cfg)
+    r = check_serving_step(
+        list(one_chip.device_set), model=model, slots=8, n_blocks=400,
+        max_len=4096, chunk=512, block_size=64,
+        leaf_elements=12 * 2048 * 1408)
+    calls = r["kernel_calls"]
+    assert calls["hetu.decode_lane>hetu.moe_experts"] == 3, calls
+    assert calls["hetu.prefill_lane>hetu.moe_experts"] == 3, calls
+    assert r["arena_moves"] == {}, r
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "fp32"])
 def test_packed_prefill_lane_compiles_for_v5e(one_chip, dtype):

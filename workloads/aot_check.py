@@ -259,13 +259,36 @@ def arena_moves(hlo: str, leaf_elements: int) -> dict:
     return moves
 
 
-def _serving_report(compiled, caches, t0) -> dict:
+def kernel_calls(hlo: str) -> dict:
+    """Kernel calls (``tpu_custom_call``: the Pallas calls and what the
+    compiler made itself of a ``ragged_dot``; not its gather hints) of
+    an optimized HLO by where they stand: ``"<lane>><innermost hetu.*
+    scope>"`` (the benchmark's classifier, ``telemetry.device_scopes``)
+    -> how many."""
+    from hetu_tpu.telemetry import device_scopes
+    where = device_scopes.describe(hlo)
+    found: dict = {}
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m and 'custom_call_target="tpu_custom_call"' in line \
+                and m.group(1) in where:
+            sc = where[m.group(1)]
+            lane = next((p for p in sc.path if p.endswith("_lane")), "")
+            key = f"{lane}>{sc.label}" if lane else sc.label
+            found[key] = found.get(key, 0) + 1
+    return found
+
+
+def _serving_report(compiled, caches, t0, leaf_elements=None) -> dict:
     """Memory analysis and arena moves of a compiled serving program
-    whose donated operand ``caches`` is the stacked arena."""
+    whose donated operand ``caches`` is the stacked arena (or the moves
+    of any other leaf of ``leaf_elements`` elements a layer: a layer's
+    experts), and its kernel calls by scope."""
     leaf = max(caches, key=lambda c: math.prod(c.shape))
-    leaf_elements = math.prod(leaf.shape[1:])
+    leaf_elements = leaf_elements or math.prod(leaf.shape[1:])
     ma = compiled.memory_analysis()
     return {
+        "kernel_calls": kernel_calls(compiled.as_text()),
         "compile_s": round(time.perf_counter() - t0, 1),
         "temp_bytes": int(ma.temp_size_in_bytes),
         "peak_bytes_est": int(ma.temp_size_in_bytes
@@ -344,7 +367,7 @@ def check_serving_lane(devs, *, lane="decode", dtype=jnp.bfloat16,
 
 def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
                        slots=148, n_blocks=9473, max_len=1024, chunk=256,
-                       model=None, block_size=16):
+                       model=None, block_size=16, leaf_elements=None):
     """The REAL fused serving step (``ServingEngine._build_step``: CoW
     pass, decode lane, packed flash prefill lane, sampling) compiled
     for the target at a benchmark cell's sizes (defaults: GPT-2 small,
@@ -354,7 +377,8 @@ def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
     run, and the step is lowered from their abstract shapes with the
     arena at ``n_blocks`` on the described device. ``model=`` is any
     other model with the engine's interface (its weights are zeros
-    here: only their shapes reach the compiler)."""
+    here: only their shapes reach the compiler). ``leaf_elements``:
+    list the moves of leaves that large in place of the arena's."""
     from jax.sharding import SingleDeviceSharding
     from hetu_tpu.models import GPTConfig, GPTLMHeadModel
     from hetu_tpu.serving import ServingEngine
@@ -409,7 +433,7 @@ def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
     t0 = time.perf_counter()
     with _mosaic_aot_env():
         c = fn.lower(*sds).compile()
-    return _serving_report(c, sds[1], t0)
+    return _serving_report(c, sds[1], t0, leaf_elements)
 
 
 def check_fused_ce(devs, *, n=4096, e=768, v=50257):

@@ -3,6 +3,12 @@
 (``mla_moe``, ``minicpm_sala``, ``kda_mla_moe``: loaded on first use,
 so that the cells that never build one do not pay for its import).
 
+The four drawn decoders share one shell (``decoder.DecoderLM``); the
+three hybrids are a ``Config``, a ``make_block(cfg, kind, dense)`` and
+the list of their layers' kinds over ``nn.parallel.LayerStack`` and
+``nn.parallel.PreNormBlock`` — a new hybrid of mixers the repo has is
+a configuration and a list, not a container.
+
 Parity targets: ``python/hetu/models/gpt`` and
 ``python/hetu/models/llama/llama_model.py`` (LlamaModel :385,
 LlamaLMHeadModel :446).

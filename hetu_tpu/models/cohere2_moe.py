@@ -41,11 +41,12 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu.core.dtypes import autocast
+from hetu_tpu.models.decoder import DecoderLM
 from hetu_tpu.nn.layers import LayerNorm
 from hetu_tpu.nn.module import Module, normal_init
 from hetu_tpu.nn.moe import ExpertShareMoE
 from hetu_tpu.nn.parallel import (
-    ParallelAttention, ParallelMLP, StackedBlocks, VocabParallelEmbedding,
+    ParallelAttention, ParallelMLP, StackedBlocks,
 )
 from hetu_tpu.parallel.sharding import act_constrain
 
@@ -142,8 +143,8 @@ class Cohere2MoEBlock(Module):
         #: what a decode call reports beside its result
         #: (``StackedBlocks.decode(with_stats=True)``): the expert
         #: layer's group sizes, counted on the host
-        self.layer_stats = {"moe_local_sizes": (
-            (self.moe.local_experts[1],), jnp.int32, self.moe.count_share)}
+        self.layer_stats = {"moe_" + k: v
+                            for k, v in self.moe.layer_stats.items()}
         self._shared_mean = 1.0 / cfg.num_shared_experts
         self._policy = {"float32": "fp32",
                         "bfloat16": "bf16"}[cfg.compute_dtype]
@@ -176,56 +177,29 @@ class Cohere2MoEBlock(Module):
                     a, kv = a
             with jax.named_scope("hetu.moe_shared"):
                 shared = self.shared(params["shared"], h)
-            routed, sizes = self.moe(params["moe"], h, return_sizes=True)
+            routed, st = self.moe(params["moe"], h, return_stats=True)
         y = x + a.astype(x.dtype) \
             + shared.astype(x.dtype) * self._shared_mean \
             + routed.astype(x.dtype)
         if kv_cache is not None:
-            return y, new_cache, {"moe_local_sizes": sizes}
+            return y, new_cache, {"moe_" + k: v for k, v in st.items()}
         y = act_constrain(y, "tokens")
         return (y, kv) if return_kv else y
 
 
-class Cohere2MoEForCausalLM(Module):
+class Cohere2MoEForCausalLM(DecoderLM):
+    """Tied embeddings; ``logit_scale`` rides the final norm."""
+
     def __init__(self, cfg: Cohere2MoEConfig):
-        super().__init__()
-        self.cfg = cfg
-        self.wte = VocabParallelEmbedding(cfg.vocab_size, cfg.hidden_size,
-                                          init=normal_init(cfg.init_std))
         sliding = [t == SLIDING for t in cfg.layer_types]
-        self.blocks = StackedBlocks(
-            lambda: Cohere2MoEBlock(cfg), cfg.num_hidden_layers,
-            layer_data={
-                "window": jnp.asarray(
-                    [cfg.sliding_window if s else NO_WINDOW
-                     for s in sliding], jnp.int32),
-                "rope_on": jnp.asarray(sliding, bool)})
-        self.final_norm = LayerNorm(cfg.hidden_size,
-                                    eps=cfg.layer_norm_eps, use_bias=False)
-
-    def _head_weight(self, params):
-        return params["wte"]["weight"]          # tied, (V, E)
-
-    def embed(self, params, input_ids, *, positions=None):
-        del positions          # rotary positions are applied per layer
-        return act_constrain(self.wte(params["wte"], input_ids), "tokens")
-
-    def hidden_norm(self, params, h):
-        """The final norm, times ``logit_scale`` (the head is linear, so
-        the scale of the logits can ride their input)."""
-        return self.final_norm(params["final_norm"], h) \
-            * self.cfg.logit_scale
-
-    def hidden_states(self, params, input_ids, *, positions=None,
-                      segment_ids=None, attn_impl="auto"):
-        h = self.embed(params, input_ids)
-        h = self.blocks(params["blocks"], h, positions=positions,
-                        segment_ids=segment_ids, attn_impl=attn_impl)
-        return self.hidden_norm(params, h)
-
-    def __call__(self, params, input_ids, **kwargs):
-        h = self.hidden_states(params, input_ids, **kwargs)
-        logits = jnp.einsum(
-            "bse,ve->bsv", h.astype(jnp.float32),
-            self._head_weight(params).astype(jnp.float32))
-        return act_constrain(logits, "logits")
+        super().__init__(
+            cfg, StackedBlocks(
+                lambda: Cohere2MoEBlock(cfg), cfg.num_hidden_layers,
+                layer_data={
+                    "window": jnp.asarray(
+                        [cfg.sliding_window if s else NO_WINDOW
+                         for s in sliding], jnp.int32),
+                    "rope_on": jnp.asarray(sliding, bool)}),
+            LayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps,
+                      use_bias=False),
+            tied=True, norm_scale=cfg.logit_scale)

@@ -10,7 +10,6 @@ top-k / nucleus (top-p) sampling.
 
 from __future__ import annotations
 
-import math
 from typing import Optional
 
 import jax
@@ -57,63 +56,27 @@ def init_kv_caches(model, batch: int, max_len: int, dtype=jnp.float32):
     """(k, v) buffers stacked over layers: (L, b, max_len, hkv, d) — or
     whatever leaves the model's attention declares (its
     ``kv_leaf_shapes()``: a latent attention has ONE, ``(L, b, max_len,
-    1, row)``).
-
-    ``dtype=jnp.int8`` builds the QUANTIZED cache — (k int8, k scales,
-    v int8, v scales) with per-(position, head) fp32 scales — the
-    reference's inference-side weight/state compression applied to the
-    decode bottleneck (the per-step cache read is pure HBM bandwidth;
-    int8 halves it vs bf16 and quarters it vs fp32)."""
-    if hasattr(model.blocks, "init_paged_caches"):
-        from hetu_tpu.nn.parallel import SlotStateNotSupported
+    1, row)``); ``dtype=jnp.int8`` builds the quantized cache
+    (``nn.parallel.kv_leaves``)."""
+    from hetu_tpu.nn.parallel import SlotStateNotSupported, kv_leaves
+    if model.blocks.slot_state:
         raise SlotStateNotSupported(
             "the dense cache (generate, the draft model): this model "
             "caches pages for some layers and a slot's state for others")
-    attn = model.blocks.block.attn
-    lead = (model.blocks.num_layers, batch, max_len)
-    # the model's attention says what a token's cache leaves are
-    # (``kv_leaf_shapes``): K and V rows per kv head, or ONE latent row
-    shapes = [lead + tuple(t) for t in attn.kv_leaf_shapes()]
-    if dtype == jnp.int8:
-        if len(shapes) != 2:
-            from hetu_tpu.nn.parallel import LatentKVNotSupported
-            raise LatentKVNotSupported(
-                "the int8 cache keeps one scale per (position, kv head) "
-                "of a K and a V leaf; this model's attention caches "
-                f"{len(shapes)} leaf of {shapes[0][3:]} a token")
-        out = ()
-        for shape in shapes:
-            out += (jnp.zeros(shape, jnp.int8),
-                    jnp.zeros(shape[:-1] + (1,), jnp.float32))
-        return out
-    return tuple(jnp.zeros(shape, dtype) for shape in shapes)
+    return kv_leaves(model.blocks.block.attn,
+                     (model.blocks.num_layers, batch, max_len), dtype)
 
 
 def init_paged_caches(model, n_blocks: int, block_size: int,
                       dtype=jnp.float32, sharding=None, slots: int = 0):
-    """The block-paged arena: :func:`init_kv_caches` leaves with
-    (batch, max_len) := (n_blocks, block_size) and the trailing
-    ``(hkv, d)`` — ``(hkv, 1)`` for int8 scales — merged into ONE minor
-    dim, ``(L, n_blocks, block_size, hkv*d)``. The TPU tiles an array's
-    last two dims (8, 128): a (12, 64) pair would either pad 2.7x or
-    make XLA pick a blocks-minor layout the paged kernel cannot take a
-    page from. Allocated in the stored shape and, where ``sharding``
-    is given, in place on it — a reshape or a ``device_put`` of finished
-    zeros would hold the arena twice on the device.
-
-    A model whose layers cache different things says so itself
-    (``model.blocks.init_paged_caches``: paged leaves over the layers
+    """The block-paged arena, as the model's stack of blocks builds it
+    (``model.blocks.init_paged_caches``): ``(L, n_blocks, block_size,
+    hkv*d)`` leaves of what its attention caches a token; a model whose
+    layers cache different things has paged leaves over the layers
     that have keys, and a state per SLOT over those that have none —
-    ``slots`` is for that leaf)."""
-    own = getattr(model.blocks, "init_paged_caches", None)
-    if own is not None:
-        return own(n_blocks, block_size, dtype, slots, sharding)
-    leaves = jax.eval_shape(
-        lambda: init_kv_caches(model, n_blocks, block_size, dtype))
-    return tuple(
-        jnp.zeros(s.shape[:3] + (math.prod(s.shape[3:]),), s.dtype,
-                  device=sharding)
-        for s in leaves)
+    ``slots`` is for that leaf."""
+    return model.blocks.init_paged_caches(n_blocks, block_size, dtype,
+                                          slots, sharding)
 
 
 def decode(model, params, input_ids, positions, caches, *,

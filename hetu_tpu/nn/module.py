@@ -69,6 +69,11 @@ class Module:
     #: with a load-balance term — set this True so containers (Sequential,
     #: StackedBlocks, the pipeline executor) thread the aux accumulation.
     returns_aux: bool = False
+    #: ``{name: (shape, dtype, emit)}``: what a cached call of this
+    #: module reports beside its result, a layer (a third result
+    #: ``{name: value}``; ``StackedBlocks.decode`` stacks them over the
+    #: layers and the serving engine hands them to ``emit`` on the host)
+    layer_stats: dict = {}
 
     def __init__(self):
         self._param_specs: dict[str, ParamSpec] = {}

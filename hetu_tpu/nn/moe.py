@@ -800,7 +800,7 @@ def count_local_share(sizes, *, tile: Optional[int] = None,
                       window: Optional[int] = None) -> None:
     """:class:`ExpertShareMoE`'s counters, on the host: ``sizes``
     ``(layer calls, held experts)`` int — the group sizes of every
-    layer of one executed scan (``return_sizes=True``; the serving
+    layer of one executed scan (``return_stats=True``; the serving
     engine calls this with what its step returned). With ``tile`` (and
     the ``window`` of a share) also what the grouped matmuls' aligned
     layout cost: the rows routed here beside the rows of the tiles the
@@ -972,6 +972,16 @@ class ExpertShareMoE(Module):
         return grouped_tile_rows(self._window_rows(pairs),
                                  self.local_experts[1])
 
+    @property
+    def layer_stats(self) -> dict:
+        """What ``return_stats=True`` reports, as the ``layer_stats``
+        entries ``{name: (shape, dtype, emit)}`` of a block."""
+        out = {"sizes": ((self.local_experts[1],), jnp.int32,
+                         self.count_share)}
+        if self.n_group > 1:
+            out["group_held"] = ((2,), jnp.int32, count_group_held)
+        return out
+
     def count_share(self, sizes, tokens: Optional[int] = None) -> None:
         """The layer's host counters (a block's ``layer_stats`` emit):
         :func:`count_local_share` of the scan's group sizes; the lane's
@@ -1022,12 +1032,11 @@ class ExpertShareMoE(Module):
             return idx.astype(jnp.int32), w, kept
         return idx.astype(jnp.int32), w
 
-    def __call__(self, params, x, *, return_sizes: bool = False,
-                 return_stats: bool = False):
-        """``return_sizes``: also the ``(count,)`` int32 numbers of
-        (token, choice) pairs each held expert got — ``(out, sizes)``.
-        ``return_stats``: ``(out, {"sizes": ..., "group_held": (2,)
-        int32})`` — beside them the tokens whose kept groups hold an
+    def __call__(self, params, x, *, return_stats: bool = False):
+        """``return_stats``: ``(out, {"sizes": ...})`` — also the
+        ``(count,)`` int32 numbers of (token, choice) pairs each held
+        expert got — and, under a group limit, ``"group_held": (2,)
+        int32`` beside them: the tokens whose kept groups hold an
         expert held here, and the tokens routed."""
         dt = self.compute_dtype()
         d = x.shape[-1]
@@ -1113,7 +1122,10 @@ class ExpertShareMoE(Module):
                     0, (total + R - 1) // R, window,
                     jnp.zeros((xf.shape[0], d), jnp.float32))
         out = out.astype(dt).reshape(x.shape)
-        if return_stats:
+        if not return_stats:
+            return out
+        stats = {"sizes": sizes}
+        if self.n_group > 1:
             with jax.named_scope("hetu.moe_route"):
                 # the groups that overlap the experts held here
                 per = self.num_experts // kept.shape[1]
@@ -1121,6 +1133,6 @@ class ExpertShareMoE(Module):
                 mine_g = (g * per < first + count) & ((g + 1) * per > first)
                 held = jnp.sum(jnp.any(kept & mine_g[None], axis=1),
                                dtype=jnp.int32)
-            return out, {"sizes": sizes, "group_held": jnp.stack(
-                [held, jnp.asarray(xf.shape[0], jnp.int32)])}
-        return (out, sizes) if return_sizes else out
+            stats["group_held"] = jnp.stack(
+                [held, jnp.asarray(xf.shape[0], jnp.int32)])
+        return out, stats

@@ -22,6 +22,7 @@ exactly like the reference's per-block recompute config
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
@@ -29,6 +30,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
+from hetu_tpu.core.dtypes import autocast
+from hetu_tpu.nn.layers import RMSNorm
 from hetu_tpu.nn.module import (
     Module, ParamSpec, StackedLeaf, normal_init, zeros_init,
 )
@@ -436,6 +439,40 @@ class LayerKV(NamedTuple):
     layer: jax.Array
 
 
+def kv_leaves(attn, lead: tuple, dtype, *, paged: bool = False,
+              sharding=None) -> tuple:
+    """Zeros for the cache leaves ``attn`` declares
+    (``kv_leaf_shapes()``: K and V rows per kv head, or ONE latent row)
+    under the leading dims ``lead``: ``(layers, batch, max_len)`` of
+    the dense cache, ``(layers, n_blocks, block_size)`` of the
+    ``paged`` arena, whose trailing ``(hkv, d)`` are merged into ONE
+    minor dim. The TPU tiles an array's last two dims (8, 128): a
+    (12, 64) pair would either pad 2.7x or make XLA pick a
+    blocks-minor layout the paged kernel cannot take a page from.
+    Allocated in the stored shape and, where ``sharding`` is given, in
+    place on it — a reshape or a ``device_put`` of finished zeros would
+    hold the arena twice on the device.
+
+    ``dtype=jnp.int8`` builds the QUANTIZED cache — (k int8, k scales,
+    v int8, v scales) with per-(position, head) fp32 scales — the
+    reference's inference-side weight/state compression applied to the
+    decode bottleneck (the per-step cache read is pure HBM bandwidth;
+    int8 halves it vs bf16 and quarters it vs fp32)."""
+    shapes = [(tuple(t), dtype) for t in attn.kv_leaf_shapes()]
+    if dtype == jnp.int8:
+        if len(shapes) != 2:
+            raise LatentKVNotSupported(
+                "the int8 cache keeps one scale per (position, kv head) "
+                "of a K and a V leaf; this model's attention caches "
+                f"{len(shapes)} leaf of {shapes[0][0]} a token")
+        shapes = [x for t, _ in shapes
+                  for x in ((t, jnp.int8), (t[:-1] + (1,), jnp.float32))]
+    return tuple(
+        jnp.zeros(lead + ((math.prod(t),) if paged else t), dt,
+                  device=sharding)
+        for t, dt in shapes)
+
+
 class ParallelAttention(Module):
     """Multi-head attention with GQA, RoPE and flash-kernel dispatch, heads
     sharded over tp.
@@ -447,6 +484,14 @@ class ParallelAttention(Module):
     and only sees its local sequence chunk (positions/segment_ids make the
     causal mask correct for chunks).
     """
+
+    #: what the serving engine asks the attention that speaks for its
+    #: arena: a cache of ONE latent row a token (not K and V per kv
+    #: head)? is a pack's history read in tiles of a request's run (the
+    #: engine builds the tile map)?
+    latent, history_tiles = False, True
+    #: the leaves :meth:`init_leaves` builds (the int8 arena: twice)
+    cache_leaves = 2
 
     def __init__(self, embed_dim: int, num_heads: int, *,
                  num_kv_heads: Optional[int] = None,
@@ -494,10 +539,19 @@ class ParallelAttention(Module):
         arena adds one float32 scale a head to each)."""
         return ((self.num_kv_heads, self.head_dim),) * 2
 
-    def kv_needed_elements(self) -> int:
-        """Numbers a token's cache NEEDS in one layer (what is stored
-        may be padded to a lane tile)."""
-        return 2 * self.num_kv_heads * self.head_dim
+    def row_bytes(self, itemsize: int) -> dict:
+        """Bytes a token holds in one layer, as ``stored`` and as
+        ``needed`` (alike here: nothing is padded; the int8 arena,
+        ``itemsize`` 1, keeps a float32 scale a kv head beside K and V
+        each and needs all it stores)."""
+        row = 2 * self.num_kv_heads * (
+            self.head_dim * itemsize + (4 if itemsize == 1 else 0))
+        return {"stored": row, "needed": row}
+
+    def init_leaves(self, layers: int, n_blocks: int, block_size: int,
+                    dtype, sharding=None) -> tuple:
+        return kv_leaves(self, (layers, n_blocks, block_size), dtype,
+                         paged=True, sharding=sharding)
 
     def _rotate(self, q, k, positions, rope_on):
         """RoPE on q and k where the module has it; ``rope_on`` (a
@@ -1007,6 +1061,8 @@ class LatentAttention(Module):
     stored_row``; :meth:`kv_leaf_shapes` says what its arena holds.
     """
 
+    latent, history_tiles, cache_leaves = True, True, 1
+
     def __init__(self, embed_dim: int, num_heads: int, *, kv_rank: int,
                  nope_dim: int, rope_dim: int, v_dim: int,
                  stored_row: Optional[int] = None,
@@ -1057,8 +1113,16 @@ class LatentAttention(Module):
         """The trailing dims of each cache leaf, a token and layer."""
         return ((1, self.head_dim),)
 
-    def kv_needed_elements(self) -> int:
-        return self.row
+    def row_bytes(self, itemsize: int) -> dict:
+        """Bytes a token holds in one layer: the row as ``stored``
+        (padded to ``stored_row``) and the ``needed`` latent row."""
+        return {"stored": self.head_dim * itemsize,
+                "needed": self.row * itemsize}
+
+    def init_leaves(self, layers: int, n_blocks: int, block_size: int,
+                    dtype, sharding=None) -> tuple:
+        return kv_leaves(self, (layers, n_blocks, block_size), dtype,
+                         paged=True, sharding=sharding)
 
     def _rotate(self, x, positions):
         cos, sin = self._rope
@@ -1311,7 +1375,7 @@ def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack):
             raise SlotStateNotSupported(
                 "a prefill pack without its tokens' slots "
                 "(pack['slot'], pack['slot_tables']): the engine hands "
-                "them to a model whose blocks have refuse_serving")
+                "them to a model whose blocks keep a slot_state")
         return x[0], positions[0], pack["valid"], block_tables, \
             pack["slot"]
     if x.shape[1] != 1:
@@ -1326,6 +1390,24 @@ def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack):
             "dense cache")
     valid = slot_mask if row_mask is None else slot_mask & row_mask[:, 0]
     return x[:, 0], positions[:, 0], valid, block_tables, None
+
+
+def count_sparse_pages(values, tokens=None) -> None:
+    """:class:`BlockSparseAttention`'s ``layer_stats`` on the host:
+    ``values (sparse layers, 4)`` — the pages the rows of a lane chose
+    and could see, summed over its live rows and kv heads, as
+    ``[chosen, visible]`` of the decode rows then of the prefill pack —
+    into ``serving_sparse_pages_total{state, lane}``."""
+    import numpy as np
+    from hetu_tpu import telemetry
+    v = np.asarray(values, np.int64).sum(axis=0)
+    c = telemetry.get_registry().counter(
+        "serving_sparse_pages_total",
+        "pages the block-sparse attention's rows chose / could see")
+    for i, lane in enumerate(("decode", "prefill")):
+        if v[2 * i + 1]:
+            c.inc(int(v[2 * i]), state="chosen", lane=lane)
+            c.inc(int(v[2 * i + 1]), state="visible", lane=lane)
 
 
 class BlockSparseAttention(Module):
@@ -1362,7 +1444,9 @@ class BlockSparseAttention(Module):
     PAGES_PER_STEP, ROWS_PER_CALL, SELECT_ROWS = 8, 512, 256
     #: the pack's history is read through each token's OWN virtual
     #: table, not in tiles of a run (the engine builds no tile map)
-    history_tiles = False
+    latent, history_tiles, cache_leaves = False, False, 3
+    #: a cached call's third result (:func:`count_sparse_pages`)
+    layer_stats = {"sparse_pages": ((4,), jnp.int32, count_sparse_pages)}
 
     def __init__(self, embed_dim: int, num_heads: int, *,
                  num_kv_heads: int, head_dim: int, block_size: int = 64,
@@ -1421,9 +1505,6 @@ class BlockSparseAttention(Module):
         means are a leaf of their own, a row a stride:
         :meth:`init_leaves`)."""
         return ((self.num_kv_heads, self.head_dim),) * 2
-
-    def kv_needed_elements(self) -> int:
-        return 2 * self.num_kv_heads * self.head_dim
 
     def row_bytes(self, itemsize: int) -> dict:
         """Bytes a token holds in one layer, by leaf."""
@@ -1534,8 +1615,9 @@ class BlockSparseAttention(Module):
     def _cached(self, params, x, kv_cache, *, positions, slot_mask,
                 block_tables, row_mask, attn_kernel, pack):
         """Either cached lane (the class docstring). Returns ``(out,
-        (k, v, means), stats)``: ``stats`` is ``(chosen, visible)``
-        pages summed over the live rows and kv heads."""
+        (k, v, means), {"sparse_pages": (4,)})``: the ``[chosen,
+        visible]`` pages summed over the live rows and kv heads, of the
+        decode rows then of the pack — a lane fills its own pair."""
         from hetu_tpu.ops import sparse_select as ss
         (k_buf, v_buf, c_buf), layer = kv_cache
         layer = jnp.asarray(layer, jnp.int32)
@@ -1600,7 +1682,9 @@ class BlockSparseAttention(Module):
                 attn_kernel)
         out = self._output(params, o.reshape(N, -1), u)
         out = out[None] if pack is not None else out[:, None]
-        return out, (k_buf, v_buf, c_buf), stats
+        zeros = jnp.zeros_like(stats)
+        return out, (k_buf, v_buf, c_buf), {"sparse_pages": jnp.concatenate(
+            [zeros, stats] if pack is not None else [stats, zeros])}
 
     def _pack_scores(self, q, kbar, slot, pos, valid):
         """Window scores of a pack's tokens, each against ITS slot's
@@ -1715,6 +1799,7 @@ class LightningAttention(Module):
 
     #: pack tokens a block of the chunk scan
     SCAN_BLOCK = 256
+    cache_leaves = 1
 
     def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
                  rope_theta: float = 10000.0, max_positions: int = 4096,
@@ -1851,6 +1936,8 @@ class KimiDeltaAttention(Module):
     page is ever read or written. ``A_log``, ``dt_bias`` and the taps
     are drawn, not constants (a program that leaves one out must
     differ)."""
+
+    cache_leaves = 2
 
     def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
                  conv_size: int = 4, lower_bound: float = -5.0,
@@ -2389,13 +2476,36 @@ class StackedBlocks(Module):
         (x, caches), stats = jax.lax.scan(body, (x, tuple(caches)), xs)
         return (x, caches, stats or {}) if with_stats else (x, caches)
 
+    @property
+    def layer_stats(self) -> dict:
+        return self._block.layer_stats
+
     def layer_stats_zeros(self) -> dict:
         """Zeros with the shape of :meth:`decode`'s third result (``{}``
         for a block that reports nothing): what a lane that did not run
         returns in their place."""
         return {name: jnp.zeros((self.num_layers,) + tuple(shape), dtype)
-                for name, (shape, dtype, _) in
-                getattr(self._block, "layer_stats", {}).items()}
+                for name, (shape, dtype, _) in self.layer_stats.items()}
+
+    # -- what the serving engine asks a model's ``blocks`` about their
+    # caches (:class:`LayerStack` answers the same for layers of
+    # several kinds)
+    #: a recurrent state per SLOT beside the pages?
+    slot_state = False
+
+    def init_paged_caches(self, n_blocks: int, block_size: int, dtype,
+                          slots: int = 0, sharding=None) -> tuple:
+        """The block-paged arena, ``(layers, n_blocks, block_size,
+        hkv*d)`` leaves (:func:`kv_leaves`); no leaf over ``slots``."""
+        return self.block.attn.init_leaves(
+            self.num_layers, n_blocks, block_size, dtype, sharding)
+
+    def cache_bytes(self, itemsize: int) -> dict:
+        """``kv_row_bytes{kind}``: a token's bytes in ONE layer."""
+        return {"row": self.block.attn.row_bytes(itemsize), "state": {}}
+
+    def refuse_serving(self, **asked) -> None:
+        """A cache of token rows in pages alone refuses nothing."""
 
     def prefill(self, params, x, *, positions=None, segment_ids=None,
                 attn_impl: str = "auto"):
@@ -2422,3 +2532,285 @@ class StackedBlocks(Module):
 
         x, kvs = jax.lax.scan(body, x, (params, self.layer_data))
         return x, kvs
+
+
+class PreNormBlock(Module):
+    """One sequential pre-norm layer of a drawn decoder (``n`` =
+    RMSNorm, no biases): ``h = x + a Mixer(n1(x))``, ``y = h + a
+    FFN(n2(h))``. It is handed its ``mixer`` (an attention of this
+    file), its FFN — a dense ``mlp``, or the ``shared`` experts (ONE
+    gated MLP) summed with a routed ``moe``
+    (:class:`~hetu_tpu.nn.moe.ExpertShareMoE`) —, the residual scale
+    ``a`` and the operands' ``compute_dtype`` ("bfloat16": bf16
+    operands, float32 accumulation; the residual stream, the norms and
+    the router stay float32). A cached call returns ``(y, cache)``
+    and, where the mixer or the experts declare ``layer_stats``, what
+    they report as a third result (the experts' under ``moe_<name>``).
+    """
+    returns_aux = False
+
+    def __init__(self, features: int, mixer: Module, *, eps: float,
+                 mlp: Optional[Module] = None,
+                 shared: Optional[Module] = None,
+                 moe: Optional[Module] = None,
+                 residual_scale: float = 1.0,
+                 compute_dtype: str = "float32", model: str = "the model"):
+        super().__init__()
+        self.norm1 = RMSNorm(features, eps=eps)
+        self.norm2 = RMSNorm(features, eps=eps)
+        self.attn = mixer
+        self.layer_stats = dict(mixer.layer_stats)
+        self._dense = moe is None
+        if self._dense:
+            self.mlp = mlp
+        else:
+            self.shared, self.moe = shared, moe
+            #: the grouped expert matmul cannot read through the layer
+            #: scan's slice (``StackedBlocks.decode``)
+            self.unsliced = (("moe", "wg"), ("moe", "wi"), ("moe", "wo"))
+            self.layer_stats.update(
+                {"moe_" + k: v for k, v in moe.layer_stats.items()})
+        self._alpha = residual_scale
+        self._policy = {"float32": "fp32",
+                        "bfloat16": "bf16"}[compute_dtype]
+        self._model = model
+
+    def _add(self, x, branch):
+        branch = branch.astype(x.dtype)
+        return x + (branch if self._alpha == 1.0
+                    else self._alpha * branch)
+
+    def __call__(self, params, x, *, positions=None, segment_ids=None,
+                 attn_impl="auto", kv_cache=None, slot_mask=None,
+                 block_tables=None, row_mask=None,
+                 attn_kernel="reference", pack=None, w8a8=None,
+                 w8a8_wq=None, lora=None, dropout_key=None,
+                 return_kv=False):
+        if w8a8 is not None or lora or dropout_key is not None:
+            raise NotImplementedError(
+                f"{self._model} has no W8A8, LoRA or dropout lane")
+        new_cache, stats = None, {}
+        u = self.norm1(params["norm1"], x)              # float32
+        with autocast(self._policy):
+            if kv_cache is not None:
+                a, new_cache, *st = self.attn(
+                    params["attn"], u, positions=positions,
+                    kv_cache=kv_cache, slot_mask=slot_mask,
+                    block_tables=block_tables, row_mask=row_mask,
+                    attn_kernel=attn_kernel, pack=pack)
+                stats.update(*st)
+            else:
+                a = self.attn(params["attn"], u, positions=positions,
+                              segment_ids=segment_ids, attn_impl=attn_impl,
+                              return_kv=return_kv)
+        h = self._add(x, a)
+        u = self.norm2(params["norm2"], h)              # float32
+        with autocast(self._policy):
+            if self._dense:
+                f = self.mlp(params["mlp"], u)
+            else:
+                with jax.named_scope("hetu.moe_shared"):
+                    shared = self.shared(params["shared"], u)
+                routed, st = self.moe(params["moe"], u, return_stats=True)
+                f = shared.astype(jnp.float32) + routed.astype(jnp.float32)
+                stats.update({"moe_" + k: v for k, v in st.items()})
+        y = self._add(h, f)
+        if kv_cache is None:
+            return act_constrain(y, "tokens")
+        return (y, new_cache, stats) if stats else (y, new_cache)
+
+
+class LayerStack(Module):
+    """The layers of a decoder whose layers are NOT all one block, from
+    the list of their mixer ``kinds`` and ``make_block(kind, dense)``:
+    the first ``n_dense`` layers (``dense=True``: another FFN) run one
+    by one, every run of consecutive like layers behind them is ONE
+    :class:`StackedBlocks` scan. To the serving engine it is what
+    ``StackedBlocks`` is.
+
+    Each kind counts ITS OWN layers in its cache leaves (a run's
+    ``first_layer`` is that kind's layers before it) — no page for a
+    layer that has no keys. The caches are every kind's leaves, as its
+    mixer builds them (``init_leaves``): first the kinds that keep
+    token rows in pages (``kv_leaf_shapes()`` not empty), then those
+    that keep a state per SLOT, each group in the order the kinds first
+    appear. ``block`` is the first paged kind's first scanned block:
+    its attention speaks for the arena (heads, row width, page size).
+
+    The parameters are ``dense.<i>`` and ``runs.<i>``; ``lone_run``
+    names the ONE run of a stack that stores it under that name
+    instead."""
+
+    #: no layer differs by data (``StackedBlocks.layer_data``)
+    layer_data = None
+
+    def __init__(self, kinds: Sequence[str],
+                 make_block: Callable[[str, bool], Module], *,
+                 n_dense: int = 0, lone_run: Optional[str] = None,
+                 model: str = "the model"):
+        super().__init__()
+        kinds = tuple(kinds)
+        self.num_layers = len(kinds)
+        #: kind -> its layers (while building: those so far)
+        self.layers_of = count = dict.fromkeys(kinds, 0)
+        mixers = {}                                  # kind -> one of it
+        self.dense, self._dense_at = [], []
+        for kind in kinds[:n_dense]:
+            self.dense.append(make_block(kind, True))
+            mixers.setdefault(kind, self.dense[-1].attn)
+            self._dense_at.append((kind, count[kind]))
+            count[kind] += 1
+        self._runs, self.run_kinds = [], []
+        i = n_dense
+        while i < len(kinds):
+            kind, n = kinds[i], 1
+            while i + n < len(kinds) and kinds[i + n] == kind:
+                n += 1
+            self._runs.append(StackedBlocks(
+                lambda kind=kind: make_block(kind, False), n,
+                first_layer=count[kind]))
+            mixers.setdefault(kind, self._runs[-1].block.attn)
+            self.run_kinds.append(kind)
+            count[kind] += n
+            i += n
+        if lone_run is None:
+            self.runs = self._runs
+        else:
+            (run,) = self._runs
+            setattr(self, lone_run, run)
+        self._lone_run, self._model = lone_run, model
+        # the caches' order: the paged kinds, then the per-slot ones
+        paged = [k for k, m in mixers.items() if m.kv_leaf_shapes()]
+        self._mixers = {k: mixers[k] for k in
+                        paged + [k for k in mixers if k not in paged]}
+        self.slot_state = len(paged) < len(mixers)
+        self._block = next(
+            (r.block for r, k in zip(self._runs, self.run_kinds)
+             if paged and k == paged[0]), None)
+        if self._block is None:
+            raise ValueError(
+                f"{kinds} behind {n_dense} unscanned layers: at least "
+                f"one scanned layer of a kind that keeps token rows in "
+                f"pages (its attention speaks for the arena)")
+        #: every block's ``layer_stats`` (like names are like stats)
+        self.layer_stats = {}
+        for blk, _ in self._reporting():
+            self.layer_stats.update(blk.layer_stats)
+
+    @property
+    def block(self) -> Module:
+        return self._block
+
+    def _parts(self, params) -> list:
+        """``(block or run, its parameters, its kind, an unscanned
+        block's layer in its kind's leaves — ``None`` for a run)`` of
+        every unscanned layer and every run, in layer order."""
+        runs = [params[self._lone_run]] if self._lone_run else \
+            [params["runs"][str(i)] for i in range(len(self._runs))]
+        return [(b, params["dense"][str(i)], *self._dense_at[i])
+                for i, b in enumerate(self.dense)] \
+            + [(r, p, kind, None)
+               for r, p, kind in zip(self._runs, runs, self.run_kinds)]
+
+    def __call__(self, params, x, **kwargs):
+        for part, p, _, _ in self._parts(params):
+            x = part(p, x, **kwargs)
+        return x
+
+    # -- the caches ----------------------------------------------------------
+    def init_paged_caches(self, n_blocks: int, block_size: int, dtype,
+                          slots: int, sharding=None) -> tuple:
+        """Every kind's leaves over ITS layers: the paged kinds' over
+        the pages, then the per-slot kinds' over the ``slots``."""
+        leaves = ()
+        for kind, mixer in self._mixers.items():
+            n = self.layers_of[kind]
+            leaves += mixer.init_leaves(
+                n, n_blocks, block_size, dtype, sharding) \
+                if mixer.kv_leaf_shapes() \
+                else mixer.init_leaves(n, slots, sharding)
+        return leaves
+
+    def cache_bytes(self, itemsize: int) -> dict:
+        """``kv_row_bytes{kind}`` / ``kv_state_bytes{kind}``: a token's
+        bytes by leaf and a slot's state, each over ALL the layers that
+        keep it — where every layer keeps the same, a token's bytes in
+        ONE layer, as :meth:`StackedBlocks.cache_bytes` gives them."""
+        rows, state = {}, {}
+        for kind, mixer in self._mixers.items():
+            n = self.layers_of[kind] if len(self._mixers) > 1 else 1
+            if mixer.kv_leaf_shapes():
+                for leaf, b in mixer.row_bytes(itemsize).items():
+                    rows[leaf] = rows.get(leaf, 0) + b * n
+            else:
+                state["slot"] = state.get("slot", 0) \
+                    + mixer.state_bytes() * n
+        return {"row": rows, "state": state}
+
+    def refuse_serving(self, **asked) -> None:
+        """An honest refusal, by name, of what assumes a cache of token
+        rows in pages alone (``asked``: feature -> whether it is on),
+        where some layers keep a state per slot."""
+        for what, on in asked.items():
+            if on and self.slot_state:
+                raise SlotStateNotSupported(
+                    f"{what} is not available over a per-slot recurrent "
+                    f"state: it would need the state snapshotted (or "
+                    f"rolled back) with the pages")
+
+    def decode(self, params, x, caches, *, with_stats=False,
+               w8a8_mask=None, w8a8_wq=None, lora=None, **kwargs):
+        """:meth:`StackedBlocks.decode` over every layer: a layer or a
+        run gets its kind's leaves and hands them back; what the layers
+        report is concatenated over the layers that report it."""
+        if w8a8_mask is not None or w8a8_wq is not None or lora:
+            raise NotImplementedError(
+                f"{self._model} has no W8A8 or LoRA lane")
+        caches, leaves = tuple(caches), {}
+        for kind, mixer in self._mixers.items():
+            n = mixer.cache_leaves
+            leaves[kind], caches = caches[:n], caches[n:]
+        if caches:
+            raise NotImplementedError(
+                "leaves beyond the mixers' own (the int8 arena's "
+                "scales) under layers of several kinds")
+        stats = {}
+        for part, p, kind, layer in self._parts(params):
+            if layer is None:
+                x, leaves[kind], st = part.decode(
+                    p, x, leaves[kind], with_stats=True, **kwargs)
+            else:
+                x, leaves[kind], *st = part(
+                    p, x, kv_cache=LayerKV(
+                        leaves[kind], jnp.asarray(layer, jnp.int32)),
+                    **kwargs)
+                st = {k: v[None] for k, v in st[0].items()} if st else {}
+            for name, v in st.items():
+                stats.setdefault(name, []).append(v)
+        caches = sum((tuple(leaves[k]) for k in self._mixers), ())
+        if not with_stats:
+            return x, caches
+        return x, caches, {name: jnp.concatenate(v)
+                           for name, v in stats.items()}
+
+    def _reporting(self) -> list:
+        """``(block, its layers)`` of every unscanned layer and run."""
+        return [(b, 1) for b in self.dense] \
+            + [(r.block, r.num_layers) for r in self._runs]
+
+    def layer_stats_zeros(self) -> dict:
+        layers = {}
+        for blk, n in self._reporting():
+            for name in blk.layer_stats:
+                layers[name] = layers.get(name, 0) + n
+        return {name: jnp.zeros((layers[name],) + tuple(shape), dtype)
+                for name, (shape, dtype, _) in self.layer_stats.items()}
+
+    def prefill(self, *args, **kwargs):
+        raise (SlotStateNotSupported if self.slot_state
+               else LatentKVNotSupported if self._block.attn.latent
+               else NotImplementedError)(
+            "StackedBlocks.prefill (the CP-prefill lane) returns "
+            "per-head (k, v) of every layer: layers of several kinds "
+            "have them in no one shape, a latent layer caches one row a "
+            "token and a per-slot state has none")

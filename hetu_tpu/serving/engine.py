@@ -377,7 +377,7 @@ class ServingEngine:
         # The lane's prompt lengths snap to a small geometric bucket
         # ladder so its executable count is bounded (the
         # record_trace("serving_cp_prefill") audit: <= n lane buckets).
-        if len(model.blocks.block.attn.kv_leaf_shapes()) != 2:
+        if model.blocks.block.attn.latent:
             # a latent arena (one leaf a token): what needs per-head
             # (K, V) refuses here, by name — the int8 arena does in
             # generation.init_kv_caches
@@ -394,28 +394,27 @@ class ServingEngine:
                         f"{what} is not available over a latent KV "
                         f"arena")
         # a model that keeps a recurrent state per slot beside the
-        # arena (``model.blocks.refuse_serving``): what assumes that a
+        # arena (``model.blocks.slot_state``): what assumes that a
         # request's cache is token rows in pages alone refuses here, by
-        # name. ``prefix_cache`` / ``preempt`` left at None are on for
-        # every other model and off for such a one; asked for, they
-        # refuse like the rest
-        refuse = getattr(model.blocks, "refuse_serving", None)
-        self._slot_state = refuse is not None
-        if refuse is not None:
-            refuse(**{
-                "prefix_cache": bool(prefix_cache),
-                "preempt (preemption and spill)": bool(preempt),
-                "spill_host_budget_bytes (the spill arena)":
-                    spill_host_budget_bytes is not None,
-                "long_max_len (the CP-prefill lane)":
-                    long_max_len is not None,
-                "spec_depth (the verify lane)": bool(spec_depth),
-                "draft_model": draft_model is not None,
-                "cache_dtype=int8 (the int8 arena)":
-                    cache_dtype == jnp.int8,
-                "w8a8": w8a8 not in (None, False, "off"),
-                "tenancy (LoRA)": bool(tenancy),
-                "a tp plan": plan is not None and plan.strategy.tp > 1})
+        # name (every other model's blocks refuse nothing).
+        # ``prefix_cache`` / ``preempt`` left at None are on for every
+        # other model and off for such a one; asked for, they refuse
+        # like the rest
+        self._slot_state = model.blocks.slot_state
+        model.blocks.refuse_serving(**{
+            "prefix_cache": bool(prefix_cache),
+            "preempt (preemption and spill)": bool(preempt),
+            "spill_host_budget_bytes (the spill arena)":
+                spill_host_budget_bytes is not None,
+            "long_max_len (the CP-prefill lane)":
+                long_max_len is not None,
+            "spec_depth (the verify lane)": bool(spec_depth),
+            "draft_model": draft_model is not None,
+            "cache_dtype=int8 (the int8 arena)":
+                cache_dtype == jnp.int8,
+            "w8a8": w8a8 not in (None, False, "off"),
+            "tenancy (LoRA)": bool(tenancy),
+            "a tp plan": plan is not None and plan.strategy.tp > 1})
         if prefix_cache is None:
             prefix_cache = not self._slot_state
         if preempt is None:
@@ -746,14 +745,14 @@ class ServingEngine:
             self.prefill_chunk, self._hist_tile, self._fin_cap) \
             if prefill_attn != "reference" \
             and self.attn_kernel == "paged" \
-            and getattr(_attn_mod, "history_tiles", True) else 0
+            and _attn_mod.history_tiles else 0
         # a grid step of that read: a key tile of as many pages as fit
         # beside the cell — its span in positions and a table's steps,
         # for serving_prefill_hist_chunks_total
         pages, self._hist_steps = table_chunks(
             W, self.pool.block_size, history_tile_pages(
                 *shapes, tile_rows=self._hist_tile, kv_itemsize=itemsize,
-                latent=len(_attn_mod.kv_leaf_shapes()) == 1))
+                latent=_attn_mod.latent))
         self._hist_span = pages * self.pool.block_size
         # the decode rows' paged call walks the live (slot, chunk)
         # pairs (ops.paged_pallas.decode_work_list): a chunk's span in
@@ -794,26 +793,16 @@ class ServingEngine:
         self._w8a8_wq = self._prequantize_decode_weights()
 
         self._m = _bind_metrics(telemetry.get_registry())
-        own_bytes = getattr(model.blocks, "cache_bytes", None)
-        if own_bytes is not None:
-            # leaves of different kinds: a token's bytes by leaf (all
-            # of the layers that keep it), and a slot's state
-            got = own_bytes(self.pool.caches[0].dtype.itemsize)
-            for kind, n in got["row"].items():
-                self._m.kv_row.set(n, kind=kind)
-            for kind, n in got["state"].items():
-                self._m.kv_state.set(n, kind=kind)
-        else:
-            stored = sum(int(np.prod(c.shape[3:])) * c.dtype.itemsize
-                         for c in self.pool.caches)
-            self._m.kv_row.set(stored, kind="stored")
-            self._m.kv_row.set(
-                stored if self.pool.quantized
-                else _attn_mod.kv_needed_elements()
-                * self.pool.caches[0].dtype.itemsize, kind="needed")
+        # a token's bytes by leaf (as stored and as needed, or by kind
+        # where the layers keep different leaves), and a slot's state
+        got = model.blocks.cache_bytes(self.pool.caches[0].dtype.itemsize)
+        for kind, n in got["row"].items():
+            self._m.kv_row.set(n, kind=kind)
+        for kind, n in got["state"].items():
+            self._m.kv_state.set(n, kind=kind)
         # the smallest window of the model's layers (None: no layer has
         # one) — for the kv_window_dead_blocks gauge only
-        ld = getattr(model.blocks, "layer_data", None) or {}
+        ld = model.blocks.layer_data or {}
         self._min_window = int(np.min(ld["window"])) \
             if "window" in ld else None
         self._prefill_path = "flash" if prefill_attn != "reference" \
@@ -859,8 +848,7 @@ class ServingEngine:
         """What hands a request's cache to another engine, or takes one
         in, moves pages; a model that also keeps a state per slot
         refuses it by name (``model.blocks.refuse_serving``)."""
-        if self._slot_state:
-            self.model.blocks.refuse_serving(**{what: True})
+        self.model.blocks.refuse_serving(**{what: True})
 
     def step_executables(self) -> int:
         """How many executables the ONE fused step holds (the jit's
@@ -2775,7 +2763,7 @@ class ServingEngine:
                 # the layers' stats of the lanes that ran, to the host
                 # functions the model's block names for them, with the
                 # token rows a call of that lane's layers takes
-                emit = getattr(self.model.blocks.block, "layer_stats", {})
+                emit = self.model.blocks.layer_stats
                 for ran, rows, stats in zip(
                         (active_prev.size, used),
                         (em.size, pf["tokens"].size), res["stats"]):

@@ -288,9 +288,9 @@ def test_kernel_experts_equal_ragged_dot_through_two_windows(
                          jnp.ones((80, 1))], axis=-1)
     params = {**params, "router": params["router"].at[15].set(bias)}
     assert moe._window_rows(80 * 4) == 128
-    out, sizes = jax.jit(lambda p, x: moe(p, x, return_sizes=True))(
+    out, st = jax.jit(lambda p, x: moe(p, x, return_stats=True))(
         params, x)
-    assert sizes.tolist() == [80, 80]            # two windows of 128
+    assert st["sizes"].tolist() == [80, 80]      # two windows of 128
     want = ragged_dot_experts(moe, params, x)
     assert float(jnp.abs(want).max()) > 1e-4
     np.testing.assert_allclose(out, want, atol=1e-6)

@@ -183,7 +183,7 @@ def test_caches_are_a_latent_arena_beside_two_slot_leaves(tiny):
     assert model.cfg.mixer_types == ("kda", "kda", "mla", "kda", "kda",
                                      "mla", "kda")
     assert model.blocks.run_kinds == ["kda", "mla", "kda", "mla", "kda"]
-    assert (model.blocks.n_kda, model.blocks.n_mla) == (5, 2)
+    assert model.blocks.layers_of == {"kda": 5, "mla": 2}
     latent, state, tail = generation.init_paged_caches(
         model, 9, 4, jnp.bfloat16, slots=3)
     assert latent.shape == (2, 9, 4, 48) and latent.dtype == jnp.bfloat16
@@ -193,7 +193,7 @@ def test_caches_are_a_latent_arena_beside_two_slot_leaves(tiny):
     assert got["row"] == {"stored": 2 * 48 * 2, "needed": 2 * 40 * 2}
     assert got["state"] == {"slot": 5 * 4 * (64 * 16 + 3 * 192)}
     assert model.blocks.block.attn.kv_leaf_shapes() == ((1, 48),)
-    assert model.blocks._kda.attn.kv_leaf_shapes() == ()
+    assert model.blocks.dense[0].attn.kv_leaf_shapes() == ()
 
 
 CONTROLS = [{"no_erase": True}, {"no_conv": True}, {"head_decay": True},

@@ -54,9 +54,11 @@ def tiny():
 
 def test_runs_and_caches_count_their_own_layers(tiny):
     cfg, model, params = tiny
-    assert cfg.runs() == [("minicpm4", 1), ("lightning-attn", 2),
-                          ("minicpm4", 1), ("lightning-attn", 1)]
-    assert (model.blocks.n_sparse, model.blocks.n_linear) == (2, 3)
+    assert list(zip(model.blocks.run_kinds,
+                    [r.num_layers for r in model.blocks.runs])) == [
+        ("minicpm4", 1), ("lightning-attn", 2),
+        ("minicpm4", 1), ("lightning-attn", 1)]
+    assert model.blocks.layers_of == {"minicpm4": 2, "lightning-attn": 3}
     k, v, c, s = generation.init_paged_caches(model, 9, 4, jnp.float32,
                                               slots=3)
     # a page holds one kv head (head-minor in its block); 4 stride

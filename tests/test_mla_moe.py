@@ -183,8 +183,8 @@ def test_no_token_is_dropped_when_all_pick_one_expert():
     params = moe.init(jax.random.key(2))
     params["select_bias"] = jnp.zeros(8).at[5].set(10.0)   # all pick 5
     x = jax.random.normal(jax.random.key(3), (40, 16))
-    out, sizes = moe(params, x, return_sizes=True)
-    assert sizes.tolist() == [0, 0, 0, 0, 0, 40, 0, 0]
+    out, st = moe(params, x, return_stats=True)
+    assert st["sizes"].tolist() == [0, 0, 0, 0, 0, 40, 0, 0]
     h = jax.nn.silu(x @ params["wg"][5]) * (x @ params["wi"][5])
     np.testing.assert_allclose(out, 2.446 * (h @ params["wo"][5]),
                                atol=1e-6)
@@ -201,9 +201,10 @@ def test_kernel_experts_equal_ragged_dot_all_held(ragged_dot_experts):
     x = jax.random.normal(jax.random.key(5), (45, 16))
     want = ragged_dot_experts(moe, params, x)
     assert float(jnp.abs(want).max()) > 1e-3
-    out, sizes = jax.jit(lambda p, x: moe(p, x, return_sizes=True))(
+    out, st = jax.jit(lambda p, x: moe(p, x, return_stats=True))(
         params, x)
-    assert int(sizes.sum()) == 45 * 3 and moe.tile_rows(45 * 3) == 32
+    assert list(st) == ["sizes"]                 # no group limit here
+    assert int(st["sizes"].sum()) == 45 * 3 and moe.tile_rows(45 * 3) == 32
     np.testing.assert_allclose(out, want, atol=1e-6)
 
     # three layers stacked, layer 1 is this one's, the others' differ

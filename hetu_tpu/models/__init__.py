@@ -1,6 +1,7 @@
 """Model zoo: GPT-2, Llama, Command A+ (cohere2_moe) and latent-attention
-(MLA) expert decoders, MiniCPM-SALA and delta-rule / latent hybrids
-(``mla_moe``, ``minicpm_sala``, ``kda_mla_moe``: loaded on first use,
+(MLA) expert decoders, MiniCPM-SALA, delta-rule / latent hybrids and
+SDAR-MoE, which generates by diffusion over blocks (``mla_moe``,
+``minicpm_sala``, ``kda_mla_moe``, ``sdar_moe``: loaded on first use,
 so that the cells that never build one do not pay for its import).
 
 The four drawn decoders share one shell (``decoder.DecoderLM``); the
@@ -30,7 +31,9 @@ _LAZY = {"MLAMoEConfig": "hetu_tpu.models.mla_moe",
          "MiniCPMSALAConfig": "hetu_tpu.models.minicpm_sala",
          "MiniCPMSALAForCausalLM": "hetu_tpu.models.minicpm_sala",
          "KDAMLAMoEConfig": "hetu_tpu.models.kda_mla_moe",
-         "KDAMLAMoEForCausalLM": "hetu_tpu.models.kda_mla_moe"}
+         "KDAMLAMoEForCausalLM": "hetu_tpu.models.kda_mla_moe",
+         "SDARMoEConfig": "hetu_tpu.models.sdar_moe",
+         "SDARMoEForCausalLM": "hetu_tpu.models.sdar_moe"}
 
 
 def __getattr__(name):
@@ -45,4 +48,5 @@ __all__ = ["GPTConfig", "GPTLMHeadModel", "LlamaConfig", "BertConfig", "BertMode
            "MLAMoEConfig", "MLAMoEForCausalLM",
            "MiniCPMSALAConfig", "MiniCPMSALAForCausalLM",
            "KDAMLAMoEConfig", "KDAMLAMoEForCausalLM",
+           "SDARMoEConfig", "SDARMoEForCausalLM",
            "generate", "decode", "init_kv_caches"]

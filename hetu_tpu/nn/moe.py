@@ -867,7 +867,8 @@ class ExpertShareMoE(Module):
     count)`` of ``num_experts`` SwiGLU experts — one chip's share of an
     expert-parallel deployment (default: all of them).
 
-    The router keeps its full width: ``s = sigmoid(x W_r)``, the ``k``
+    The router keeps its full width: ``s = sigmoid(x W_r)``
+    (``score="softmax"``: ``softmax(x W_r)`` over the experts), the ``k``
     largest are chosen and weighted ``w_e = s_e / sum_chosen s``
     (normalised over ALL k chosen, held here or not). With
     ``select_bias`` the choice is by ``s + b`` (a per-expert bias, a
@@ -917,9 +918,17 @@ class ExpertShareMoE(Module):
                  k: int, local_experts: Optional[tuple] = None,
                  select_bias: bool = False,
                  scale: Optional[float] = None, n_group: int = 1,
-                 topk_group: int = 1, init=None):
+                 topk_group: int = 1, score: str = "sigmoid", init=None):
         super().__init__()
         first, count = local_experts or (0, num_experts)
+        if score not in ("sigmoid", "softmax") or (
+                score == "softmax" and (select_bias or n_group > 1)):
+            raise ValueError(
+                f"score {score!r}: sigmoid, or softmax without a "
+                f"selection bias or groups")
+        #: ``s``: ``sigmoid(z)`` a logit, or ``softmax(z)`` over the
+        #: experts (float32) — chosen and renormalised alike
+        self.score = score
         if num_experts % n_group or not 1 <= topk_group <= n_group \
                 or k > topk_group * (num_experts // n_group) \
                 or (n_group > 1 and num_experts // n_group < 2):
@@ -1023,6 +1032,8 @@ class ExpertShareMoE(Module):
             _, idx = jax.lax.top_k(
                 s + params["select_bias"].astype(jnp.float32), self.k)
             top = jnp.take_along_axis(s, idx, axis=-1)
+        elif self.score == "softmax":
+            top, idx = jax.lax.top_k(jax.nn.softmax(z, axis=-1), self.k)
         else:
             top, idx = jax.lax.top_k(jax.nn.sigmoid(z), self.k)
         w = top / top.sum(-1, keepdims=True)

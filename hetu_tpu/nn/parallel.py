@@ -500,8 +500,26 @@ class ParallelAttention(Module):
                  use_rope: bool = False, rope_theta: float = 10000.0,
                  rope_interleaved: bool = False,
                  min_window: Optional[int] = None,
-                 max_positions: int = 4096, init=None):
+                 max_positions: int = 4096, qk_norm: bool = False,
+                 qk_gain: float = 1.0, norm_eps: float = 1e-6,
+                 attn_block: int = 1, init=None):
         super().__init__()
+        # RMSNorm with a learned gain over each head's numbers, on q
+        # and on k, before RoPE (the gains are the heads' own, drawn at
+        # ``qk_gain``: 1 where a checkpoint's are learned)
+        self.qk_norm, self.norm_eps = bool(qk_norm), norm_eps
+        if qk_norm:
+            from hetu_tpu.nn.module import constant_init
+            hd = head_dim or embed_dim // num_heads
+            self.param("q_gain", (hd,), constant_init(qk_gain))
+            self.param("k_gain", (hd,), constant_init(qk_gain))
+        #: the BLOCK bound of a block-diffusion model (1: causal): a
+        #: query sees its own block of ``attn_block`` positions whole
+        #: and the blocks before it (``ops.attention.block_bound``)
+        self.attn_block = int(attn_block)
+        if self.attn_block != 1 and (min_window is not None or not causal):
+            raise ValueError("attn_block goes with causal attention "
+                             "and no window")
         self.rope_interleaved = rope_interleaved
         #: the smallest ``window=`` any layer is called with (static;
         #: the packed prefill lane checks its chunk against it)
@@ -532,6 +550,12 @@ class ParallelAttention(Module):
         else:
             self._rope = None
 
+    @property
+    def _bound(self) -> dict:
+        """``block=`` for the attention calls (nothing at 1: the calls
+        are the causal ones, operand for operand)."""
+        return {} if self.attn_block == 1 else {"block": self.attn_block}
+
     def kv_leaf_shapes(self) -> tuple:
         """The cache spec: the trailing dims of each cache leaf, a
         token and layer — what ``generation.init_kv_caches`` builds the
@@ -553,10 +577,15 @@ class ParallelAttention(Module):
         return kv_leaves(self, (layers, n_blocks, block_size), dtype,
                          paged=True, sharding=sharding)
 
-    def _rotate(self, q, k, positions, rope_on):
+    def _rotate(self, q, k, positions, rope_on, params=None):
         """RoPE on q and k where the module has it; ``rope_on`` (a
         traced bool from a layer scan whose layers differ by it, else
-        ``None``) says whether THIS layer rotates."""
+        ``None``) says whether THIS layer rotates. Where the module
+        norms q and k (``qk_norm``; ``params`` hold the gains), before
+        it does."""
+        if self.qk_norm:
+            q = _gain(params, "q_gain", q, self.norm_eps, q.dtype)
+            k = _gain(params, "k_gain", k, self.norm_eps, k.dtype)
         if self._rope is None:
             return q, k
         cos, sin = self._rope
@@ -607,7 +636,7 @@ class ParallelAttention(Module):
             b, s, self.num_kv_heads, self.head_dim)
         v = self.v_proj(params["v_proj"], x).reshape(
             b, s, self.num_kv_heads, self.head_dim)
-        q, k = self._rotate(q, k, positions, rope_on)
+        q, k = self._rotate(q, k, positions, rope_on, params)
         q = act_constrain(q, "heads")
         k = act_constrain(k, "heads")
         v = act_constrain(v, "heads")
@@ -617,14 +646,15 @@ class ParallelAttention(Module):
                      and "cp" in mctx.axes and mctx.mesh.shape["cp"] > 1)
         gspmd_cp = (ctx is not None and isinstance(ctx.seq, str)
                     and ctx.mesh.shape[ctx.seq] > 1)
-        if window is not None:
+        if window is not None or self.attn_block != 1:
             if manual_cp or gspmd_cp:
                 raise NotImplementedError(
-                    "a windowed layer under context parallelism")
+                    "a windowed or block-causal layer under context "
+                    "parallelism")
             out = attention_reference(
                 q, k, v, causal=self.causal, segment_ids=segment_ids,
                 window=window, dropout_rate=dropout_rate,
-                dropout_key=dropout_key)
+                dropout_key=dropout_key, block=self.attn_block)
         elif manual_cp:
             # inside a manual region (pipeline executor) with cp bound:
             # run the cp attention core directly on the bound axis —
@@ -776,10 +806,10 @@ class ParallelAttention(Module):
         v = lora_apply(lora, "v_proj", x,
                        self.v_proj(params["v_proj"], x)).reshape(
             b, s, self.num_kv_heads, self.head_dim)
-        if self._rope is not None:
+        if self._rope is not None or self.qk_norm:
             q, k = self._rotate(
                 q, k, positions if positions is not None
-                else jnp.arange(s)[None, :], rope_on)
+                else jnp.arange(s)[None, :], rope_on, params)
 
         if paged:
             n_blk, blk = leaves[0].shape[1], leaves[0].shape[2]
@@ -858,7 +888,8 @@ class ParallelAttention(Module):
             from hetu_tpu.ops.paged_pallas import paged_attention_auto
             out = paged_attention_auto(q, k_buf, v_buf, block_tables,
                                        index, layer=layer, window=window,
-                                       live=slot_mask, **arena)
+                                       live=slot_mask, **self._bound,
+                                       **arena)
         elif paged:
             if attn_kernel == "paged":
                 from hetu_tpu.ops.attention import record_kernel_fallback
@@ -874,7 +905,7 @@ class ParallelAttention(Module):
             out = paged_attention_reference(
                 q, _at_layer(k_buf, layer), _at_layer(v_buf, layer),
                 block_tables, index, causal=self.causal, window=window,
-                **arena)
+                **self._bound, **arena)
         else:
             k_buf, v_buf = _at_layer(k_buf, layer), _at_layer(v_buf, layer)
             if quant:
@@ -882,7 +913,8 @@ class ParallelAttention(Module):
                 v_buf = dequantize_int8(v_buf, vs_l, q.dtype)
             out = attention_reference(
                 q, k_buf, v_buf, causal=self.causal,
-                q_offset=index, kv_offset=0, window=window)
+                q_offset=index, kv_offset=0, window=window,
+                **self._bound)
         out = out.reshape(b, s, self.num_heads * self.head_dim)
         return lora_apply(lora, "out_proj", out,
                           self.out_proj(params["out_proj"], out)), \
@@ -943,7 +975,7 @@ class ParallelAttention(Module):
         v = lora_apply(lora, "v_proj", x,
                        self.v_proj(params["v_proj"], x)).reshape(
             b, C, self.num_kv_heads, self.head_dim)
-        q, k = self._rotate(q, k, positions, rope_on)
+        q, k = self._rotate(q, k, positions, rope_on, params)
         pos = positions[0]                               # (C,)
         blk_ids = jnp.take_along_axis(block_tables,
                                       (pos // blk)[:, None], axis=1)[:, 0]
@@ -985,9 +1017,14 @@ class ParallelAttention(Module):
             combine_attention_lse, paged_attention_reference,
             paged_history_attention,
         )
+        # under a block bound a request's run starts at a whole block
+        # and holds whole blocks (the engine cuts its chunks so): a
+        # token's block lies inside the pack, index for position, and
+        # every history key (< the run's start) below every block of it
         intra, lse_i = attention_with_lse(
             q, k, v, causal=self.causal,
-            segment_ids=pack["segment_ids"], impl=pack["impl"])
+            segment_ids=pack["segment_ids"], impl=pack["impl"],
+            **self._bound)
 
         if window is not None and self.min_window is not None \
                 and C > self.min_window:
@@ -2539,7 +2576,8 @@ class PreNormBlock(Module):
     RMSNorm, no biases): ``h = x + a Mixer(n1(x))``, ``y = h + a
     FFN(n2(h))``. It is handed its ``mixer`` (an attention of this
     file), its FFN — a dense ``mlp``, or the ``shared`` experts (ONE
-    gated MLP) summed with a routed ``moe``
+    gated MLP; ``None`` where the model has none) summed with a routed
+    ``moe``
     (:class:`~hetu_tpu.nn.moe.ExpertShareMoE`) —, the residual scale
     ``a`` and the operands' ``compute_dtype`` ("bfloat16": bf16
     operands, float32 accumulation; the residual stream, the norms and
@@ -2564,7 +2602,10 @@ class PreNormBlock(Module):
         if self._dense:
             self.mlp = mlp
         else:
-            self.shared, self.moe = shared, moe
+            if shared is not None:      # (a model may have none)
+                self.shared = shared
+            self._shared = shared is not None
+            self.moe = moe
             #: the grouped expert matmul cannot read through the layer
             #: scan's slice (``StackedBlocks.decode``)
             self.unsliced = (("moe", "wg"), ("moe", "wi"), ("moe", "wo"))
@@ -2609,10 +2650,13 @@ class PreNormBlock(Module):
             if self._dense:
                 f = self.mlp(params["mlp"], u)
             else:
-                with jax.named_scope("hetu.moe_shared"):
-                    shared = self.shared(params["shared"], u)
+                if self._shared:
+                    with jax.named_scope("hetu.moe_shared"):
+                        shared = self.shared(params["shared"], u)
                 routed, st = self.moe(params["moe"], u, return_stats=True)
-                f = shared.astype(jnp.float32) + routed.astype(jnp.float32)
+                f = routed.astype(jnp.float32)
+                if self._shared:
+                    f = shared.astype(jnp.float32) + f
                 stats.update({"moe_" + k: v for k, v in st.items()})
         y = self._add(h, f)
         if kv_cache is None:

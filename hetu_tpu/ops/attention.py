@@ -130,6 +130,17 @@ def gather_block_rows(buf, block_tables):
     return jnp.take(flat, rows, axis=0)
 
 
+def block_bound(pos, block: int):
+    """The last key a query at ``pos`` sees under the block bound:
+    ``pos | (block - 1)`` — the end of its block of ``block`` positions
+    (a power of two); ``pos`` itself, untouched, at 1."""
+    if block == 1:
+        return pos
+    if block < 1 or block & (block - 1):
+        raise ValueError(f"a block bound is a power of two, got {block}")
+    return pos | (block - 1)
+
+
 def attention_reference(q, k, v, *, causal: bool = False,
                         segment_ids: Optional[jnp.ndarray] = None,
                         kv_segment_ids: Optional[jnp.ndarray] = None,
@@ -139,8 +150,13 @@ def attention_reference(q, k, v, *, causal: bool = False,
                         kv_offset: int | jnp.ndarray = 0,
                         dropout_rate: float = 0.0,
                         dropout_key: Optional[jax.Array] = None,
-                        window=None):
+                        window=None, block: int = 1):
     """Pure-jnp attention oracle, fp32 softmax.
+
+    ``block`` (causal only; a power of two, 1 = plain causal): the
+    BLOCK bound of a block-diffusion model — a query at absolute
+    position ``p`` sees every key ``j <= p | (block - 1)``, its own
+    block whole and the blocks before it (:func:`block_bound`).
 
     ``window`` (causal only; an int, a traced int32 scalar or one per
     batch row, ``None`` = no window): a query at absolute position ``p``
@@ -182,19 +198,20 @@ def attention_reference(q, k, v, *, causal: bool = False,
             # to (b, sq, sk) then into the (b, 1, sq, sk) mask layout
             qpos = jnp.arange(sq)[None, :, None] + qoff.reshape(-1, 1, 1)
             kpos = jnp.arange(sk)[None, None, :] + koff.reshape(-1, 1, 1)
-            seen = qpos >= kpos
+            seen = block_bound(qpos, block) >= kpos
             if window is not None:
                 seen = seen & (kpos > qpos - window)
             mask = mask & seen[:, None]
         else:
             qpos = jnp.arange(sq)[:, None] + q_offset
             kpos = jnp.arange(sk)[None, :] + kv_offset
-            seen = qpos >= kpos
+            seen = block_bound(qpos, block) >= kpos
             if window is not None:
                 seen = seen & (kpos > qpos - window)
             mask = mask & seen[None, None]
-    elif window is not None:
-        raise ValueError("a window needs causal attention")
+    elif window is not None or block != 1:
+        raise ValueError("a window or a block bound needs causal "
+                         "attention")
     if segment_ids is not None:
         kv_seg = kv_segment_ids if kv_segment_ids is not None else segment_ids
         mask = mask & (segment_ids[:, None, :, None] == kv_seg[:, None, None, :])
@@ -260,7 +277,8 @@ def attention_with_lse(q, k, v, *, causal: bool = False,
                        segment_ids: Optional[jnp.ndarray] = None,
                        scale: Optional[float] = None,
                        impl: str = "reference",
-                       interpret: Optional[bool] = None):
+                       interpret: Optional[bool] = None,
+                       block: int = 1):
     """Attention that ALSO returns the log-sum-exp — ``(out, lse)`` with
     ``out`` (b, s, h, d) and ``lse`` (b, h, s) fp32.
 
@@ -269,7 +287,8 @@ def attention_with_lse(q, k, v, *, causal: bool = False,
     isolation via ``segment_ids``) and an arena-history part (the paged
     kernel) — ``ops.paged_pallas.combine_attention_lse``. Inference-only
     (no vjp); ``impl="pallas"`` runs the flash forward kernel,
-    ``"reference"`` the fp32 oracle."""
+    ``"reference"`` the fp32 oracle. ``block``: the block bound
+    (:func:`block_bound`) in place of the causal one."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     if impl == "pallas":
@@ -277,11 +296,11 @@ def attention_with_lse(q, k, v, *, causal: bool = False,
         out, lse = _flash_fwd(
             jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
             jnp.swapaxes(v, 1, 2), segment_ids, segment_ids,
-            causal=causal, scale=scale, interpret=interpret)
+            causal=causal, scale=scale, interpret=interpret, block=block)
         return jnp.swapaxes(out, 1, 2), lse
     return attention_reference(q, k, v, causal=causal,
                                segment_ids=segment_ids, scale=scale,
-                               return_lse=True)
+                               return_lse=True, block=block)
 
 
 def _pallas_sharded_call(q, k, v, *, causal, segment_ids, scale,

@@ -387,17 +387,22 @@ def _at(j, block, start, size):
 
 
 def _tile_mask(q0, k0, block_q, block_k, *, causal, q_ids, kv_ids,
-               transposed=False):
+               transposed=False, bound: int = 1):
     """bool (block_q, block_k) for the tile whose first query is absolute
     position ``q0`` and first key ``k0`` (``transposed``: (block_k,
-    block_q), keys down); None if nothing masks."""
+    block_q), keys down); None if nothing masks. ``bound`` > 1: the
+    block bound — a query sees the keys up to the end of its block of
+    ``bound`` positions (``ops.attention.block_bound``)."""
     mask = None
     if causal:
         shape = (block_k, block_q) if transposed else (block_q, block_k)
         q_at = jax.lax.broadcasted_iota(jnp.int32, shape, int(transposed))
         k_at = jax.lax.broadcasted_iota(jnp.int32, shape,
                                         int(not transposed))
-        mask = q_at - k_at >= k0 - q0
+        if bound == 1:
+            mask = q_at - k_at >= k0 - q0
+        else:
+            mask = ((q_at + q0) | (bound - 1)) >= k_at + k0
     if q_ids is not None:
         # (block_q,1) == (1,block_k), or (1,block_q) == (block_k,1)
         smask = q_ids == kv_ids
@@ -464,7 +469,8 @@ def _fwd_value_tile(s, v, m, lpart_scr, acc_scr, keep, dropout_rate):
 
 
 def _fwd_kernel(*refs, causal, has_seg, block_q, block_k, kv_major,
-                q_blocks, k_blocks, q_offset, kv_offset, dropout_rate):
+                q_blocks, k_blocks, q_offset, kv_offset, dropout_rate,
+                bound=1):
     """One query block against one resident major block of K and V: the
     exact softmax of the block in two passes over its live sub-tiles
     (scores and the row max; probs, sums and values), merged into the
@@ -495,7 +501,7 @@ def _fwd_kernel(*refs, causal, has_seg, block_q, block_k, kv_major,
             mask = _tile_mask(
                 iq * block_q + q_offset + q0,
                 (ikm * n_tiles + j) * block_k + kv_offset + k0, nq, nk,
-                causal=causal_mask,
+                causal=causal_mask, bound=bound,
                 q_ids=qseg_ref[0, q0:q0 + nq, :1] if seg_mask else None,
                 kv_ids=kseg_ref[0, :1, _at(j, block_k, k0, nk)]
                 if seg_mask else None)
@@ -592,16 +598,31 @@ def _last_live_major(iq, ikm, *, causal, block_q, kv_major, q_offset,
 def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, scale,
                q_offset=0, kv_offset=0, interpret=None,
                block_q=None, block_k=None,
-               dropout_rate=0.0, seed=None):
+               dropout_rate=0.0, seed=None, block: int = 1):
     """q (b,hq,sq,d); k/v (b,hkv,sk,d); seg ids (b,s) or None.
 
     Returns out (b,hq,sq,d) and lse (b,hq,sq) (natural-log-sum-exp of the
     scaled, masked logits — fp32).
+
+    ``block`` > 1 (causal; forward only): the block bound. Tiles, strips
+    and offsets are whole blocks, so a tile's class — dead, interior,
+    crossing the diagonal — is the causal one's and only the mask of a
+    crossing tile changes: the last row of a tile or strip is the last
+    of its block and sees what it saw.
     """
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     rep = hq // hkv
     block_q, block_k = _resolve_blocks(sq, sk, "fwd", block_q, block_k)
+    if block != 1:
+        strip = _STRIP if block_q % _STRIP == 0 else block_q
+        if not causal or block & (block - 1) or any(
+                n % block for n in (block_q, block_k, strip, q_offset,
+                                    kv_offset)):
+            raise ValueError(
+                f"a block bound of {block} needs causal attention and "
+                f"tiles ({block_q}, {block_k}), strips and offsets "
+                f"({q_offset}, {kv_offset}) of whole blocks")
     has_seg = q_seg is not None
     # resident a key: K and V rows, its ids, and (not double buffered) a
     # query block's float32 scores
@@ -642,7 +663,8 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, scale,
                 block_q=block_q, block_k=block_k, kv_major=kv_major,
                 q_blocks=sq // block_q, k_blocks=sk // block_k,
                 q_offset=q_offset, kv_offset=kv_offset,
-                dropout_rate=dropout_rate),
+                dropout_rate=dropout_rate,
+                **({"bound": block} if block != 1 else {})),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=len(ranges),
                 grid=grid,

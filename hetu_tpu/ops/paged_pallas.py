@@ -150,7 +150,7 @@ def table_chunks(table_width: int, block_size: int,
 
 
 def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
-                  quant, windowed, tiled=False, v_width=None):
+                  quant, windowed, tiled=False, v_width=None, block=1):
     """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
     ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
     page dims are squeezed out of the block — every kv head: a TPU
@@ -175,7 +175,10 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     causal compare.
     ``v_width`` (a latent arena): there are
     no value pages — a key row's first ``v_width`` columns are its
-    value, taken from the key page the step already holds."""
+    value, taken from the key page the step already holds.
+    ``block`` > 1 (the decode rows' call, no window): the block bound —
+    a row at position ``p`` sees the keys ``<= p | (block - 1)``, its
+    own block whole (``ops.attention.block_bound``)."""
     del lyr_ref                     # read by the page index maps only
     win_ref = None
     if windowed:
@@ -237,6 +240,8 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
         qpos = off + jax.lax.broadcasted_iota(
             jnp.int32, (rows, span), 0) // g
     last_q = off + (rows // g - 1)
+    if block != 1:
+        qpos, last_q = qpos | (block - 1), last_q | (block - 1)
     if tiled:
         # the tile's own rows are [lo, hi) of the cell and none sees a
         # key above the cap (they sit ABOVE the keys they read)
@@ -365,9 +370,11 @@ def _stacked(x):
 
 
 def decode_work_list(q_offset, live=None, *, rows: int, span: int,
-                     n_steps: int, window=None):
+                     n_steps: int, window=None, block: int = 1):
     """The decode and verify rows' work: the live (slot, table chunk)
     pairs, in slot order, chunks ascending — the paged call's grid.
+    (``block``: the rows' block bound; a slot's last chunk is the one
+    that holds the END of its last row's block.)
 
     A chunk is ``span`` positions of a slot's table (``pages_per_step``
     pages). Slot ``s`` with its ``rows`` q rows at ``q_offset[s] ..``
@@ -382,7 +389,10 @@ def decode_work_list(q_offset, live=None, *, rows: int, span: int,
     no gather, no sort."""
     off = jnp.asarray(q_offset, jnp.int32)
     S = off.shape[0]
-    last = jnp.clip((off + (rows - 1)) // span, 0, n_steps - 1)
+    last_q = off + (rows - 1)
+    if block != 1:
+        last_q = last_q | (block - 1)
+    last = jnp.clip(last_q // span, 0, n_steps - 1)
     if window is None:
         first = jnp.zeros_like(last)
     else:
@@ -415,7 +425,8 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                            interpret: Optional[bool] = None,
                            return_lse: bool = False, window=None,
                            live=None, tiles=None,
-                           v_width: Optional[int] = None):
+                           v_width: Optional[int] = None,
+                           block: int = 1):
     """Decode attention through per-slot block tables, in-kernel.
 
     - ``q``: ``(S, R, hq, d)`` — S slots × R rows (1 for classic decode,
@@ -476,6 +487,11 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
       A page is fetched once; the result is ``v_width`` wide. ``scale``
       must be given: the row's width says nothing about it. No int8
       form.
+    - ``block`` (1 = causal; a static power of two): the block bound of
+      a block-diffusion model's lane — row ``i`` of slot ``s`` sees the
+      keys ``<= (q_offset[s] + i) | (block - 1)``, every key of its own
+      block. Not with ``window`` or ``tiles`` (a tile's rows stand above
+      their keys: the history read needs no bound).
 
     Returns ``(S, R, hq, d)`` in q's dtype (plus the fp32
     ``(S, R*… )``-shaped LSE ``(S, hq, R)`` when ``return_lse`` — the
@@ -520,6 +536,11 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         scalars += (jnp.broadcast_to(jnp.asarray(window, jnp.int32),
                                      (T,)),)
     tiled = tiles is not None
+    if block != 1 and (block < 1 or block & (block - 1) or windowed
+                       or tiled):
+        raise ValueError(
+            f"block={block}: a block bound is a power of two, and goes "
+            f"with neither a window nor tiles")
     if tiled:
         if live is not None:
             raise ValueError("live= is per slot; a dead tile says so "
@@ -539,7 +560,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         with jax.named_scope("hetu.paged_attn"):
             slot, chunk, n = decode_work_list(
                 q_offset, live, rows=R, span=L * bs, n_steps=n_steps,
-                window=window)
+                window=window, block=block)
         scalars += (slot, chunk)
         grid = (jnp.maximum(n, 1),)
     interpret = _interpret_default() if interpret is None else interpret
@@ -618,7 +639,8 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         out, lse_l = pl.pallas_call(
             functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
                               hkv=hkv, quant=quant, windowed=windowed,
-                              tiled=tiled, v_width=v_width),
+                              tiled=tiled, v_width=v_width,
+                              **({"block": block} if block != 1 else {})),
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
@@ -654,7 +676,7 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
                          interpret: Optional[bool] = None,
                          return_lse: bool = False, window=None,
                          live=None, tiles=None,
-                         v_width: Optional[int] = None):
+                         v_width: Optional[int] = None, block: int = 1):
     """:func:`paged_attention_pallas`, tp-aware.
 
     Mosaic kernels cannot be GSPMD-auto-partitioned, so under a
@@ -678,7 +700,7 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
             q, k, v, tbl, off, layer=layer, k_scale=ks, v_scale=vs,
             scale=scale, pages_per_step=pages_per_step,
             interpret=interpret, return_lse=return_lse, window=window,
-            live=live, tiles=tiles, v_width=v_width)
+            live=live, tiles=tiles, v_width=v_width, block=block)
 
     ctx = current_act_sharding()
     head_ax = ctx.tp if ctx is not None and isinstance(ctx.tp, str) \
@@ -915,7 +937,8 @@ def paged_attention_reference(q, k, v, block_tables, q_offset, *,
                               scale: Optional[float] = None,
                               causal: bool = True,
                               return_lse: bool = False, window=None,
-                              v_width: Optional[int] = None):
+                              v_width: Optional[int] = None,
+                              block: int = 1):
     """The XLA-gather twin (and parity oracle): materialize each slot's
     table view with :func:`~hetu_tpu.ops.attention.gather_block_rows`
     and run the dense reference — exactly what ``ParallelAttention.
@@ -947,7 +970,7 @@ def paged_attention_reference(q, k, v, block_tables, q_offset, *,
     return attention_reference(q, k_buf, v_buf, causal=causal,
                                q_offset=q_offset, kv_offset=0,
                                scale=scale, return_lse=return_lse,
-                               window=window)
+                               window=window, block=block)
 
 
 def combine_attention_lse(o1, lse1, o2, lse2):

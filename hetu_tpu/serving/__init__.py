@@ -35,6 +35,7 @@ cache with radix-tree prefix sharing.
 the fleet state machines.
 """
 
+from hetu_tpu.serving.block_diffusion import BlockGenerationNotSupported
 from hetu_tpu.serving.engine import ServingEngine
 from hetu_tpu.serving.fleet import (
     RemoteEngineProxy, RemoteReplicaHandle, RemoteRequest,
@@ -57,7 +58,7 @@ from hetu_tpu.serving.speculative import (
 )
 
 __all__ = [
-    "ServingEngine",
+    "ServingEngine", "BlockGenerationNotSupported",
     "KVPool", "BlockManager", "NULL_BLOCK", "cache_dtype_name",
     "HostSpillArena", "SpillEntry",
     "PrefixCache",

@@ -97,6 +97,10 @@ import numpy as np
 from hetu_tpu import telemetry
 from hetu_tpu.engine.train_step import record_trace
 from hetu_tpu.models import generation
+from hetu_tpu.serving import block_diffusion
+from hetu_tpu.serving.block_diffusion import (
+    REMASKING, denoise_slots, first_block,
+)
 from hetu_tpu.serving.kv_pool import (
     BlockManager, HostSpillArena, KVPool, SpillEntry,
 )
@@ -296,6 +300,30 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
     )
 
 
+def _bind_diffusion_metrics(reg) -> dict:
+    """The block lane's handles (an engine whose model generates by
+    diffusion over blocks binds them beside the rest)."""
+    return dict(
+        diff_passes=reg.counter(
+            "serving_diffusion_passes_total",
+            "slot-passes of the block lane by kind (denoise = a block "
+            "with a masked position ran and some were unmasked, its "
+            "K/V to be overwritten; commit = a block with none ran "
+            "once more, its K/V kept and its tokens emitted)"),
+        diff_blocks=reg.counter(
+            "serving_diffusion_blocks_total",
+            "blocks committed by the block lane"),
+        diff_tokens=reg.counter(
+            "serving_diffusion_tokens_total",
+            "tokens the committed blocks handed their requests (a "
+            "first block's prompt tail and what lies beyond "
+            "max_tokens or a stop id are not counted)"),
+        diff_per_block=reg.histogram(
+            "serving_diffusion_passes_per_block",
+            "passes a committed block took, its commit pass among "
+            "them (at most denoising_steps + 1)"))
+
+
 #: iterations between two refreshes of the spill-tier, replica-store and
 #: adapter-page gauges when no submit or auxiliary job sets them sooner
 _TIER_GAUGES_EVERY = 32
@@ -401,6 +429,36 @@ class ServingEngine:
         # other model and off for such a one; asked for, they refuse
         # like the rest
         self._slot_state = model.blocks.slot_state
+        # a model that generates by diffusion over blocks states it
+        # (``model.generation``); the decode lane takes its shape from
+        # that: B rows a slot, denoised in place and committed together
+        # (serving/block_diffusion.py). What assumes a token a slot and
+        # step refuses, by name; ``prefix_cache`` / ``preempt`` left at
+        # None are off
+        self._gen = getattr(model, "generation", None)
+        if self._gen is not None:
+            block_diffusion.refuse(**{
+                "spec_depth (the verify lane)": bool(spec_depth),
+                "draft_model": draft_model is not None,
+                "prefix_cache (a hit may end inside a block)":
+                    bool(prefix_cache),
+                "preempt (a slot spilled mid-block)": bool(preempt),
+                "spill_host_budget_bytes (the spill arena)":
+                    spill_host_budget_bytes is not None,
+                "long_max_len (the CP-prefill lane)":
+                    long_max_len is not None,
+                "cache_dtype=int8 (the int8 arena)":
+                    cache_dtype == jnp.int8,
+                "w8a8": w8a8 not in (None, False, "off"),
+                "tenancy (LoRA)": bool(tenancy),
+                "a tp plan": plan is not None and plan.strategy.tp > 1})
+            B = self._gen.block_length
+            if max_len % B or prefill_chunk % B or block_size % B:
+                raise ValueError(
+                    f"max_len {max_len}, prefill_chunk {prefill_chunk} "
+                    f"and block_size {block_size} must hold whole "
+                    f"blocks of {B} positions")
+            prefix_cache, preempt = False, False
         model.blocks.refuse_serving(**{
             "prefix_cache": bool(prefix_cache),
             "preempt (preemption and spill)": bool(preempt),
@@ -542,7 +600,8 @@ class ServingEngine:
             self.pool.slots, self.pool.max_len, blocks=self.blocks,
             prefix_cache=self.prefix_cache,
             block_size=self.pool.block_size,
-            long_max_len=long_max_len, class_weights=class_weights)
+            long_max_len=long_max_len, class_weights=class_weights,
+            token_block=self._gen.block_length if self._gen else 1)
         self._plan = plan
         self._counter_sample_every = counter_sample_every
 
@@ -550,6 +609,10 @@ class ServingEngine:
         # (the verify lane's width), per-slot effective depth is data —
         # spec_depth=0 keeps the lane at the classic one-row decode
         self.spec_depth = check_draft_depth(spec_depth, max_len)
+        #: q rows a slot feeds the decode lane: its last token and the
+        #: drafts, or a block-diffusion model's block
+        self._lane_rows = self._gen.block_length if self._gen \
+            else self.spec_depth + 1
         self._draftsman = None
         if draft_model is not None:
             if self.spec_depth == 0:
@@ -611,6 +674,23 @@ class ServingEngine:
         self._topk = np.zeros(S, np.int32)
         self._topp = np.zeros(S, np.float32)
         self._bt = np.zeros((S, W), np.int32)    # per-slot block tables
+        if self._gen is not None:
+            # the block lane's state a slot (block_diffusion.py): the
+            # block's tokens, which are still masked, the passes it has
+            # had; the request's steps, rule and threshold. The step
+            # takes and returns the first three; the host's mirrors
+            # follow the packed fetch. ``_blk_at``: the pass at which
+            # each position was unmasked (the host's own account)
+            B = self._gen.block_length
+            self._blk = {
+                "blk_tok": np.full((S, B), self._gen.mask_token_id,
+                                   np.int32),
+                "blk_masked": np.ones((S, B), bool),
+                "blk_pass": np.zeros(S, np.int32),
+                "blk_steps": np.ones(S, np.int32),
+                "blk_dynamic": np.zeros(S, bool),
+                "blk_thresh": np.zeros(S, np.float32)}
+            self._blk_at = np.zeros((S, B), np.int8)
         # device-resident mirrors of the control vectors + block tables:
         # rebuilt from the np mirrors only when an admission / prefill
         # completion / finish dirtied them — steady decode iterations
@@ -793,6 +873,9 @@ class ServingEngine:
         self._w8a8_wq = self._prequantize_decode_weights()
 
         self._m = _bind_metrics(telemetry.get_registry())
+        if self._gen is not None:
+            vars(self._m).update(
+                _bind_diffusion_metrics(telemetry.get_registry()))
         # a token's bytes by leaf (as stored and as needed, or by kind
         # where the layers keep different leaves), and a slot's state
         got = model.blocks.cache_bytes(self.pool.caches[0].dtype.itemsize)
@@ -847,7 +930,11 @@ class ServingEngine:
     def _refuse_slot_state(self, what: str) -> None:
         """What hands a request's cache to another engine, or takes one
         in, moves pages; a model that also keeps a state per slot
-        refuses it by name (``model.blocks.refuse_serving``)."""
+        refuses it by name (``model.blocks.refuse_serving``); so does
+        one that generates by diffusion over blocks, whose slot holds a
+        block in the making beside its pages."""
+        if self._gen is not None:
+            block_diffusion.refuse(**{what: True})
         self.model.blocks.refuse_serving(**{what: True})
 
     def step_executables(self) -> int:
@@ -931,7 +1018,8 @@ class ServingEngine:
     def _build_step(self):
         model = self.model
         R = self._fin_cap
-        K = self.spec_depth
+        K = self._lane_rows - 1
+        gen = self._gen
         kern = self.attn_kernel
         w8a8_mask = self._w8a8_mask
         flash_lane = self.prefill_attn != "reference"
@@ -1039,12 +1127,42 @@ class ServingEngine:
                                    new_kd, ctl["key"])
                 return caches, committed, ncommit, last_tok, new_kd, stats
 
+            # the decode lane of a model that generates by diffusion
+            # over blocks is the BLOCK lane (block_diffusion.py): every
+            # slot feeds its block's B current tokens as B q rows at
+            # pos..pos+B-1 (the verify lane's shape; the attention's
+            # block bound lets each row see the whole block), their K/V
+            # written as ordinary paged writes that a later pass
+            # overwrites; ``denoise_slots`` unmasks or commits by the
+            # slot's own state — data, like the pass count — and the
+            # new state leaves the step beside pos
+            def do_block(caches):
+                positions = ctl["pos"][:, None] + jnp.arange(K + 1)[None, :]
+                logits, caches, stats = generation.decode(
+                    model, params, ctl["blk_tok"], positions, caches,
+                    slot_mask=ctl["active"], block_tables=bt,
+                    row_mask=jnp.broadcast_to(ctl["active"][:, None],
+                                              positions.shape),
+                    attn_kernel=kern, with_stats=True)
+                with jax.named_scope("hetu.diffusion_sample"):
+                    committed, ncommit, *blk = denoise_slots(
+                        logits, ctl["blk_tok"], ctl["blk_masked"],
+                        ctl["blk_pass"], ctl["blk_steps"],
+                        ctl["blk_dynamic"], ctl["blk_thresh"],
+                        ctl["active"], mask_id=gen.mask_token_id)
+                return (caches, committed, ncommit, ctl["last_tok"],
+                        ctl["key"], stats, tuple(blk))
+
             def no_decode(caches):
                 S = ctl["pos"].shape[0]
                 z = jnp.zeros((S,), jnp.int32)
-                return (caches, jnp.zeros((S, K + 1), jnp.int32),
-                        z, z, ctl["key"],
-                        model.blocks.layer_stats_zeros())
+                out = (caches, jnp.zeros((S, K + 1), jnp.int32),
+                       z, z, ctl["key"],
+                       model.blocks.layer_stats_zeros())
+                if gen is not None:
+                    out += ((ctl["blk_tok"], ctl["blk_masked"],
+                             ctl["blk_pass"]),)
+                return out
 
             # what the layers of each lane report beside their result
             # (``StackedBlocks.decode(with_stats=)``; nothing, from most
@@ -1052,9 +1170,11 @@ class ServingEngine:
             # inside it would hold the device and keep the executable
             # out of the compile cache
             with jax.named_scope("hetu.decode_lane"):
-                caches, committed, ncommit, last_tok, new_kd, dec_stats \
-                    = jax.lax.cond(ctl["active"].any(), do_decode,
-                                   no_decode, caches)
+                caches, committed, ncommit, last_tok, new_kd, dec_stats, \
+                    *blk = jax.lax.cond(
+                        ctl["active"].any(),
+                        do_decode if gen is None else do_block,
+                        no_decode, caches)
 
             # packed prefill: a C-token budget shared by every
             # admitting request — per-token (slot, position) operands
@@ -1116,6 +1236,10 @@ class ServingEngine:
                 # FIRST tokens for the <= R requests whose prefill
                 # completes this iteration: head only on their last
                 # real rows (never the full pack's vocab projection)
+                if gen is not None:
+                    # a prompt's whole blocks yield no token: its first
+                    # comes with its first block
+                    return no_prefill(caches)[:3] + (stats,)
                 hf = jnp.take(hrow, pf["fin_row"], axis=0)[:, None]
                 hf = model.hidden_norm(params, hf)
                 w = generation._head_weight(model, params)
@@ -1165,17 +1289,30 @@ class ServingEngine:
             new_last = jnp.where(ctl["active"], last_tok,
                                  ctl["last_tok"])
             # the iteration's ONE fetch: what the host reads of it
-            out = results.pack_device({
+            fields = {
                 "committed": committed, "ncommit": ncommit,
                 "first_toks": first_toks, "key": new_key,
-                "stats": (dec_stats, pf_stats)})
-            return caches, new_pos, new_last, new_key, out
+                "stats": (dec_stats, pf_stats)}
+            if gen is None:
+                return (caches, new_pos, new_last, new_key,
+                        results.pack_device(fields))
+            # the block state: to the next iteration on the device, and
+            # to the host's mirrors in the one fetch
+            blk_tok, blk_masked, blk_pass = blk[0]
+            fields["blk"] = (blk_tok, blk_masked.astype(jnp.int32),
+                             blk_pass)
+            return (caches, new_pos, new_last, new_key,
+                    results.pack_device(fields),
+                    {"blk_tok": blk_tok, "blk_masked": blk_masked,
+                     "blk_pass": blk_pass})
 
         # what the step returns AND takes again keeps its home
-        # (__init__): the arena, and the advanced pos/last_tok/key
+        # (__init__): the arena, and the advanced pos/last_tok/key (and
+        # the block lane's state)
         rep = self._rep
         return jax.jit(step, donate_argnums=(1,), out_shardings=(
-            self._arena_sh, rep, rep, rep, None))
+            self._arena_sh, rep, rep, rep, None)
+            + ((rep,) if gen is not None else ()))
 
     def _result_layout(self) -> PackedFields:
         """The layout of the ONE vector the fused step hands the host
@@ -1183,16 +1320,20 @@ class ServingEngine:
         table), from what the engine knows at construction — slots,
         draft depth, the finishing rows, the key's words, the block's
         ``layer_stats``."""
-        S, R, K = self.pool.slots, self._fin_cap, self.spec_depth
+        S, R, K = self.pool.slots, self._fin_cap, self._lane_rows - 1
         stats = jax.eval_shape(self.model.blocks.layer_stats_zeros)
 
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, np.int32)
 
-        return PackedFields({
+        fields = {
             "committed": i32(S, K + 1), "ncommit": i32(S),
             "first_toks": i32(R), "key": self._key_state,
-            "stats": (stats, stats)})
+            "stats": (stats, stats)}
+        if self._gen is not None:
+            # the block lane's state after the step: tokens, masks, pass
+            fields["blk"] = (i32(S, K + 1), i32(S, K + 1), i32(S))
+        return PackedFields(fields)
 
     # -- the CP-prefill lane ------------------------------------------------
     def _build_cp_prefill(self):
@@ -1934,6 +2075,7 @@ class ServingEngine:
         (``prefill_only`` / the fleet router) evicts the KV and resumes
         it on a decode-tier replica."""
         sampling = sampling or SamplingParams()
+        self._check_generation(sampling, resume=resume, handoff=handoff)
         if sampling.adapter is not None and self.tenancy is None:
             raise ValueError(
                 "SamplingParams.adapter without tenancy= — construct "
@@ -2013,6 +2155,37 @@ class ServingEngine:
                       outcome="queued" if admitted else "rejected")
         self._record_gauges(tiers=True)
         return req
+
+    def _check_generation(self, sp: SamplingParams, *, resume,
+                          handoff: bool) -> None:
+        """What a request may ask of this engine's way of generating:
+        the block-diffusion knobs of a model that states one and of no
+        other; of such a model greedy tokens alone, and neither a
+        resumed cache nor the prefill tier's hand-off."""
+        asked = [n for n in ("denoising_steps", "remasking",
+                             "confidence_threshold")
+                 if getattr(sp, n) is not None]
+        g = self._gen
+        if g is None:
+            if asked:
+                raise ValueError(
+                    f"SamplingParams.{asked[0]} is for a model that "
+                    f"generates by diffusion over blocks; this one "
+                    f"yields a token a step")
+            return
+        block_diffusion.refuse(**{
+            "temperature > 0 (the block sampler is greedy)":
+                sp.temperature > 0,
+            "resume= (a cache spilled mid-block)": resume is not None,
+            "handoff (the prefill tier parks after a first token; a "
+            "block lane has none before its first block)": handoff})
+        steps = sp.denoising_steps
+        if steps is not None and not 1 <= steps <= g.block_length:
+            raise ValueError(f"denoising_steps {steps} of a block of "
+                             f"{g.block_length}")
+        if sp.remasking is not None and sp.remasking not in REMASKING:
+            raise ValueError(f"remasking {sp.remasking!r}: one of "
+                             f"{sorted(REMASKING)}")
 
     def result(self, req: Request,
                timeout: Optional[float] = None) -> Optional[dict]:
@@ -2477,10 +2650,30 @@ class ServingEngine:
                 # beyond one slot's budget: one cp-sharded prefill pass
                 # instead of the packed chunk loop
                 self._cp_pending.append({"req": req, "slot": slot})
-            else:
+            elif self._gen is None:
                 self._prefilling.append(
                     {"req": req, "slot": slot,
-                     "off": plan["first_uncached"]})
+                     "off": plan["first_uncached"],
+                     "end": len(req.prompt)})
+            else:
+                # the prompt's whole blocks are prefilled; its tail
+                # begins the first generated block. The request's own
+                # steps, rule and threshold, or the model's
+                g, B = self._gen, self._gen.block_length
+                b = self._blk
+                b["blk_steps"][slot] = sp.denoising_steps \
+                    or g.denoising_steps
+                b["blk_dynamic"][slot] = REMASKING[
+                    sp.remasking or g.remasking]
+                b["blk_thresh"][slot] = g.confidence_threshold \
+                    if sp.confidence_threshold is None \
+                    else sp.confidence_threshold
+                end = len(req.prompt) // B * B
+                if end:
+                    self._prefilling.append(
+                        {"req": req, "slot": slot, "off": 0, "end": end})
+                else:
+                    self._begin_blocks(slot, req)
             if self._draftsman is not None:
                 # the slot's draft state belongs to its NEW occupant
                 # (resumes re-seed with the full history at map-back)
@@ -2535,7 +2728,7 @@ class ServingEngine:
         m = self._m
         C = self.prefill_chunk
         R = self._fin_cap
-        K = self.spec_depth
+        K = self._lane_rows - 1
         S = self.pool.slots
         it = self._iter + 1
         active_prev = None
@@ -2617,17 +2810,21 @@ class ServingEngine:
                       "active": self._active, "temp": self._temp,
                       "topk": self._topk, "topp": self._topp,
                       "key": self._key_state,
-                      "adapter": self._adapter_page}, self._bt),
+                      "adapter": self._adapter_page,
+                      **(self._blk if self._gen else {})}, self._bt),
                     self._rep)
                 self._ctl_dirty = False
-                m.transfers.inc(9, dir="up")
+                m.transfers.inc(9 + (6 if self._gen else 0), dir="up")
             ctl = self._ctl_dev
             if self._active.any():
                 # the decode lane's sampler: the step's own predicate
                 # (speculative.sample_needs), on the uploaded vectors
-                m.sample_path.inc(lane="decode", path=sample_path(
-                    *sample_needs(self._active, self._temp,
-                                  self._topk, self._topp)))
+                # (the block lane has its own: its passes are counted
+                # at the commit)
+                if self._gen is None:
+                    m.sample_path.inc(lane="decode", path=sample_path(
+                        *sample_needs(self._active, self._temp,
+                                      self._topk, self._topp)))
                 if self._chunk_steps:
                     # an active slot's K + 1 rows end at position pos +
                     # K, in chunk (pos + K) // span: its pairs are that
@@ -2664,7 +2861,7 @@ class ServingEngine:
                 if used >= C:                    # decode-only iteration
                     break
                 req, off = ent["req"], ent["off"]
-                n = int(min(C - used, len(req.prompt) - off))
+                n = int(min(C - used, ent["end"] - off))
                 tokens[used:used + n] = req.prompt[off:off + n]
                 tpos[used:used + n] = np.arange(off, off + n)
                 tslot[used:used + n] = ent["slot"]
@@ -2677,14 +2874,14 @@ class ServingEngine:
                 tseg[used:used + n] = ent["slot"]
                 thist[used:used + n] = off
                 runs.append((ent["slot"], used, n, off))
-                if off + n >= len(req.prompt):
+                if off + n >= ent["end"]:
                     fin_row[len(fin_ents)] = used + n - 1
                     fin_slot[len(fin_ents)] = ent["slot"]
                     fin_valid[len(fin_ents)] = True
                     fin_ents.append(ent)
                 fills.append((ent, n))
                 used += n
-            if used:
+            if used and self._gen is None:
                 # the prefill lane's sampler sees the finishing rows
                 m.sample_path.inc(lane="prefill", path=sample_path(
                     *sample_needs(fin_valid, self._temp[fin_slot],
@@ -2730,7 +2927,8 @@ class ServingEngine:
         # argument handling; the ONE fetch of what the host reads of
         # the step starts as soon as the call returns
         with span("serve/dispatch", iter=it), ctx:
-            caches, pos_dev, last_dev, key_dev, out = self._fn(*args)
+            caches, pos_dev, last_dev, key_dev, out, *blk_dev = \
+                self._fn(*args)
             del args                # the arena was donated
             self.pool.caches = caches
             out.copy_to_host_async()
@@ -2775,7 +2973,13 @@ class ServingEngine:
             # case the remaining committed tokens are discarded (the
             # _finish path marks control state dirty, so the device's
             # advanced pos is rebuilt from the host mirrors)
-            for r in active_prev:
+            token_rows = active_prev
+            if self._gen is not None:
+                # (the block lane commits whole blocks, by its own rule)
+                n_generated += self._commit_blocks(
+                    active_prev, em, nc, res["blk"], now)
+                token_rows = ()
+            for r in token_rows:
                 req = self._slot_req[int(r)]
                 n = int(nc[r])
                 if req is None or n == 0:
@@ -2815,18 +3019,18 @@ class ServingEngine:
                                 ts_s=t0, iter=self._iter)
             if used:
                 m.tokens.inc(used, kind="prompt")
+            if self._gen is not None:
+                for ent in fin_ents:
+                    self._begin_blocks(ent["slot"], ent["req"])
+                    self._prefilling.remove(ent)
+                fin_ents = []
             for i, ent in enumerate(fin_ents):
                 req, slot = ent["req"], ent["slot"]
                 self._pos[slot] = len(req.prompt)
                 self._active[slot] = True
                 self._ctl_dirty = True       # slot turned on mid-flight
                 req.status = "decode"
-                req.first_token_s = now
-                req.mark("first_token", ts_s=now)
-                ttft = now - req.submit_s
-                m.ttft.observe(ttft)
-                if self.slo is not None:
-                    self.slo.observe("serving_ttft_seconds", ttft)
+                self._first_token(req, now)
                 # the finished prompt's whole blocks enter the radix
                 # cache (the trie takes refs, so they outlive the slot)
                 if self.prefix_cache is not None:
@@ -2845,7 +3049,8 @@ class ServingEngine:
             # _ctl_dirty, which forces a rebuild from the np mirrors.
             if not self._ctl_dirty:
                 self._ctl_dev = dict(self._ctl_dev, pos=pos_dev,
-                                     last_tok=last_dev, key=key_dev)
+                                     last_tok=last_dev, key=key_dev,
+                                     **(blk_dev[0] if blk_dev else {}))
             self._record_gauges(
                 tiers=did_aux or self._iter % _TIER_GAUGES_EVERY == 0)
         with span("serve/pump"):
@@ -2855,7 +3060,7 @@ class ServingEngine:
             # function returns: their release hands the interpreter to
             # the wire threads the pump just woke (2-3 ms of a backlog
             # iteration on the chip), and that wait belongs to a child
-            del caches, pos_dev, last_dev, key_dev, out
+            del caches, pos_dev, last_dev, key_dev, out, blk_dev
             step_s = time.monotonic() - t0
             m.step_seconds.observe(step_s)
             if self.slo is not None:
@@ -2877,7 +3082,80 @@ class ServingEngine:
             lock_wait_s=lock.waited_s,
             admitted=self._n_admitted - admitted0, frames=frames,
             since_prev_s=pc0 - end if end is not None else 0.0)
+        if self._gen is not None:
+            # the block lane's live q rows of this iteration
+            step_span.set(lane_rows=int(active_prev.size) * (K + 1))
         return True
+
+    # -- the block lane's host half (serving/block_diffusion.py) -----------
+    def _begin_blocks(self, slot: int, req: Request) -> None:
+        """The prompt's whole blocks are in the arena (or it has none):
+        the slot joins the block lane with its first block — the
+        prompt's tail, then masks (caller holds the lock)."""
+        g, b = self._gen, self._blk
+        self._pos[slot], b["blk_tok"][slot], b["blk_masked"][slot] = \
+            first_block(req.prompt, g.block_length, g.mask_token_id)
+        b["blk_pass"][slot] = 0
+        self._blk_at[slot] = 0
+        self._active[slot] = True
+        self._ctl_dirty = True
+        req.status = "decode"
+
+    def _commit_blocks(self, active_prev, em, nc, blk, now) -> int:
+        """What the fetched block state says of the slots that ran:
+        which positions a denoise pass unmasked (and at which pass: the
+        request's own account), which blocks were committed — their
+        tokens go to their requests, all at once. Returns the tokens
+        handed on (caller holds the lock)."""
+        m, b, B = self._m, self._blk, self._gen.block_length
+        tok, masked, passes = blk
+        ran = np.asarray(active_prev)
+        done = nc[ran] > 0
+        # a denoise pass: what it unmasked, it did at this pass
+        den = ran[~done]
+        newly = b["blk_masked"][den] & (masked[den] == 0)
+        self._blk_at[den] = np.where(
+            newly, b["blk_pass"][den][:, None], self._blk_at[den])
+        n_tokens = 0
+        for r in map(int, ran[done]):
+            req = self._slot_req[r]
+            m.diff_per_block.observe(int(b["blk_pass"][r]) + 1)
+            # the prompt's tail in a first block is not output
+            skip = max(0, len(req.prompt) - int(self._pos[r]))
+            self._pos[r] += B
+            n_tokens += self._on_block(
+                r, em[r, skip:], self._blk_at[r, skip:], now)
+            self._blk_at[r] = 0
+        # the mirrors follow the device (a finished slot's are
+        # rewritten when it is taken again)
+        b["blk_tok"][ran], b["blk_masked"][ran] = tok[ran], masked[ran] != 0
+        b["blk_pass"][ran] = passes[ran]
+        commits = int(done.sum())
+        if commits:
+            m.diff_passes.inc(commits, kind="commit")
+            m.diff_blocks.inc(commits)
+            m.diff_tokens.inc(n_tokens)
+        if len(den):
+            m.diff_passes.inc(len(den), kind="denoise")
+        return n_tokens
+
+    def _on_block(self, slot: int, toks, at, now: float) -> int:
+        """A committed block's tokens for ``slot``'s request, cut at
+        ``max_tokens`` and after a stop id; the first block's arrival
+        is the request's first token."""
+        req = self._slot_req[slot]
+        sp = req.sampling
+        toks = [int(t) for t in toks[:sp.max_tokens - len(req.tokens)]]
+        hit_eos = sp.eos_id is not None and sp.eos_id in toks
+        if hit_eos:
+            toks = toks[:toks.index(sp.eos_id) + 1]
+        if not req.tokens:
+            self._first_token(req, now)
+        req.tokens.extend(toks)
+        req.unmask_pass.extend(int(p) for p in at[:len(toks)])
+        if hit_eos or len(req.tokens) >= sp.max_tokens:
+            self._finish(slot, now)
+        return len(toks)
 
     def _draft(self, active_prev, d_tok, d_len, d_q):
         """This iteration's draft proposals (speculation only): the
@@ -2934,6 +3212,15 @@ class ServingEngine:
             if v:
                 np.clip(d_tok, 0, v - 1, out=d_tok)
         return d_tok, d_len, d_q
+
+    def _first_token(self, req: Request, now: float) -> None:
+        """``req``'s first token (or first block) arrives ``now``."""
+        req.first_token_s = now
+        req.mark("first_token", ts_s=now)
+        ttft = now - req.submit_s
+        self._m.ttft.observe(ttft)
+        if self.slo is not None:
+            self.slo.observe("serving_ttft_seconds", ttft)
 
     def _on_token(self, slot: int, tok: int, now: float) -> None:
         """Record one sampled token for ``slot`` (caller holds lock):

@@ -63,6 +63,14 @@ class SamplingParams:
     #: ``generate(rng=jax.random.key(seed))``. None derives a stream
     #: from the engine seed + request id (reproducible per engine).
     seed: Optional[int] = None
+    #: generation by diffusion over blocks (a model that states one:
+    #: ``models.sdar_moe.BlockDiffusion``; refused of any other): the
+    #: passes a block is unmasked over, the rule that picks what a pass
+    #: unmasks (``low_confidence_static`` | ``low_confidence_dynamic``)
+    #: and the dynamic rule's threshold — None: the model's defaults
+    denoising_steps: Optional[int] = None
+    remasking: Optional[str] = None
+    confidence_threshold: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -148,6 +156,13 @@ class Request:
     #                                    originating id
     events: list = dataclasses.field(default_factory=list,
                                      repr=False, compare=False)
+    unmask_pass: list = dataclasses.field(
+        default_factory=list, repr=False, compare=False)  # block
+    #                                    diffusion: for every output
+    #                                    token, the pass of its block at
+    #                                    which it was unmasked (a byte a
+    #                                    token: the request's account of
+    #                                    quality against steps)
     chunk_iters: list = dataclasses.field(
         default_factory=list, repr=False, compare=False)  # engine
     #                                    iteration of each
@@ -208,10 +223,13 @@ class Request:
         return out
 
     def result(self) -> dict:
-        return {"id": self.id, "status": self.status,
-                "tokens": list(self.tokens), "error": self.error,
-                "weight_version": self.weight_version,
-                "timing": self.timing()}
+        out = {"id": self.id, "status": self.status,
+               "tokens": list(self.tokens), "error": self.error,
+               "weight_version": self.weight_version,
+               "timing": self.timing()}
+        if self.unmask_pass:
+            out["unmask_pass"] = list(self.unmask_pass)
+        return out
 
 
 class Scheduler:
@@ -251,8 +269,13 @@ class Scheduler:
     def __init__(self, slots: int, max_len: int, *, blocks=None,
                  prefix_cache=None, block_size: Optional[int] = None,
                  long_max_len: Optional[int] = None,
-                 class_weights: Optional[dict] = None):
+                 class_weights: Optional[dict] = None,
+                 token_block: int = 1):
         self.slots = int(slots)
+        #: positions a decode step fills at once (1: a token a step; a
+        #: block-diffusion model's block): a request's last block is
+        #: generated whole, so its worst case ends on a whole block
+        self.token_block = int(token_block)
         self.max_len = int(max_len)
         #: CP-prefill lane budget: requests whose worst case exceeds
         #: one slot's max_len but fits here are admitted with
@@ -293,7 +316,7 @@ class Scheduler:
         lane exists — its larger budget, so a caller knows which knob
         (max_len / long_max_len / max_tokens) would admit the request.
         """
-        worst = len(req.prompt) + req.sampling.max_tokens
+        worst = self._span(len(req.prompt) + req.sampling.max_tokens)
         limit = self.long_max_len or self.max_len
         if len(req.prompt) == 0:
             req.status, req.error = "rejected", "empty prompt"
@@ -320,6 +343,11 @@ class Scheduler:
         req.mark("queued")
         self.queue.append(req)
         return True
+
+    def _span(self, positions: int) -> int:
+        """``positions`` up to the end of their last ``token_block``."""
+        b = self.token_block
+        return -(-positions // b) * b
 
     def requeue_preempted(self, req: Request) -> None:
         """Put an evicted request back at the HEAD of the queue (it was
@@ -384,7 +412,7 @@ class Scheduler:
         reservation, so price P+1 instead of P+max_tokens."""
         bs = self.block_size or self.max_len
         tail = 1 if req.handoff else req.sampling.max_tokens
-        return -(-(len(req.prompt) + tail) // bs)
+        return -(-self._span(len(req.prompt) + tail) // bs)
 
     def preemption_victim(self, candidate: Request,
                           running) -> Optional[int]:
@@ -471,7 +499,7 @@ class Scheduler:
         # blocks — reserving max_tokens of decode room would only
         # throttle this tier's admission for space it never uses
         tail = 1 if req.handoff else req.sampling.max_tokens
-        total = -(-(P + tail) // bs)                      # worst case
+        total = -(-self._span(P + tail) // bs)            # worst case
         shared: list[int] = []
         partial = None
         # CP-lane requests skip the prefix cache: their prefill is one

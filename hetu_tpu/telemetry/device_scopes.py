@@ -26,6 +26,7 @@ The vocabulary (all start ``hetu.``; ``docs/OBSERVABILITY.md``):
 ``hetu.decode_lane``  the fused serving step's decode/verify lane
 ``hetu.kv_arena``     KV arena writes (paged scatters, CoW copies)
 ``hetu.sample``       sampling: logits adjustment, draws, verify
+``hetu.diffusion_sample`` the block lane's sampler: confidence, choice, transfer
 ``hetu.moe_route``    expert-share MoE: router, top-k, sort, row gather
 ``hetu.moe_experts``  expert-share MoE: grouped matmuls, weighting, unsort
 ``hetu.moe_shared``   the shared experts' gated MLP (averaged or summed)
@@ -70,6 +71,7 @@ VOCABULARY = (
     "hetu.decode_lane", "hetu.kv_arena", "hetu.sample",
     "hetu.moe_route", "hetu.moe_experts", "hetu.moe_shared",
     "hetu.mla_down", "hetu.mla_absorb", "hetu.mla_expand",
+    "hetu.diffusion_sample",
 )
 
 #: ``op_name``s the TPU compiler gives an op it made from a program's

@@ -1,0 +1,317 @@
+"""CPU rehearsal of ``arch: sdar_moe`` (``benchmark/archs/sdar_moe.py``)
+under the ``serve_arch_blocks`` runner: the model and its plain
+reference end to end at a tiny size through a manifest, a configuration
+and a mix of their own (new files HERE only), with and without
+``--trace``; each planted control refused THROUGH the harness; the
+block states rebuilt from a request's unmask passes; what
+``BENCHMARK.json`` says of the cell — by NAME, so that the next cell can
+be appended behind it — and of the pins' views in ``tests/conftest.py``;
+the configuration against the catalog's row; and the arithmetic of
+``benchmark/flops_sdar_moe.py``."""
+
+import json
+import os
+import sys
+import time
+import types
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+sys.path.insert(0, ROOT)
+
+from benchmark import flops, flops_sdar_moe as fs, harness  # noqa: E402
+from benchmark.peaks import peaks_for  # noqa: E402
+from benchmark.runners import serve_arch_blocks  # noqa: E402
+
+MANIFEST = os.path.join(HERE, "manifest_blocks.json")
+CELL = "sdar-30b-a3b-ep8.reason-1k-backlog"
+BEFORE = "ling-3.0-flash-vl-ep8.video-8k-backlog"
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+BLOCKGEN = [
+    "step_decode_ms", "step_prefill_ms", "step_sample_ms", "engine_iter_ms",
+    "step_moe_experts_ms", "step_moe_route_ms", "moe_experts_roofline_pct",
+    "moe_local_imbalance", "paged_block_roofline_pct",
+    "diffusion_tokens_per_pass", "diffusion_commit_pass_pct"]
+#: read without a device plane: counters and the window's iterations
+NO_DEVICE = {"engine_iter_ms", "moe_local_imbalance",
+             "diffusion_tokens_per_pass", "diffusion_commit_pass_pct"}
+COUNTED = {n + ".blockgen" for n in NO_DEVICE} | {"setup_compile_s",
+                                                  "kv_used_peak_pct"}
+ACCOUNT = ["engine_host_cpu_ms", "engine_host_offcpu_ms",
+           "host_dispatch_ms", "wire_cpu_ms", "step_launch_lag_ms",
+           "step_fetch_lag_ms"]
+
+
+def _config():
+    with open(os.path.join(
+            ROOT, "benchmark/configs/sdar-30b-a3b-ep8.json")) as f:
+        return json.load(f)
+
+
+def _run(trace=False):
+    import jax
+    return harness.run_cell(
+        harness.load_manifest(MANIFEST), ROOT, "tiny.blocks",
+        seed=2**31 + 45, seconds=1.5, trace=trace, devices=jax.devices(),
+        on_chip=False, t_process=time.perf_counter())
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_serve_arch_blocks_cell_end_to_end_at_tiny_size(trace):
+    out = _run(trace)
+    assert not out["why_incorrect"]
+    line = out["line"]
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    ref = out["info"]["reference"]
+    assert ref["compared_positions"] == ref["route_near_ties"] > 0
+    assert ref["max_logit_gap_at_near_ties"] <= 1e-3   # float32 both sides
+    assert ref["compared_passes"] > 0 and ref["confidence_swaps"] == 0
+    assert ref["limits"] and ref["control"] == {}
+    assert len(ref["compared_prompt_lens"]) == 8
+    # prompts with a tail of 1-3 among the compared
+    assert any(n % 4 for n in ref["compared_prompt_lens"])
+    d = out["info"]["diffusion"]
+    assert d["commit_passes"] == d["serving_diffusion_blocks_total"] > 0
+    # K and V of 3 layers x 65 blocks x 4 x 32, float32
+    assert out["info"]["arena_bytes"] == 2 * 3 * 65 * 4 * 32 * 4
+    if not trace:
+        assert set(line["metrics"]) == {"serve_tokens_per_s", "setup_s"}
+        assert line["metrics"]["serve_tokens_per_s"]["value"] > 0
+    else:
+        # no device plane on the CPU: the metrics that read device
+        # scopes are left out, the counted ones are there
+        assert set(line["metrics"]) == COUNTED
+        m = line["metrics"]
+        # 4 denoise passes and a commit a block; tails and cuts hand on
+        # fewer than 4 tokens of some blocks
+        assert 19.9 <= m["diffusion_commit_pass_pct.blockgen"]["value"] \
+            <= 25
+        assert 0.5 < m["diffusion_tokens_per_pass.blockgen"]["value"] < 0.8
+        assert line["device"]["busy_s"] == 0.0
+    json.dumps(line)
+
+
+CONTROLS = {
+    "causal_inside_a_block": {"intra": "causal"},
+    "no_commit_pass": {"keys_from": "last_denoise"},
+    "own_keys_left_out": {"intra": "none"},
+    "left_to_right": {"order": "left_to_right"},
+    "sigmoid_router": {"score": "sigmoid"},
+    "no_qk_norm": {"qk_norm": False},
+    "float8_e4m3fn_operands": {"operands": "float8_e4m3fn"},
+}
+
+
+@pytest.mark.parametrize("control", sorted(CONTROLS))
+def test_a_planted_control_is_refused_through_the_harness(control):
+    """The seven computations the limits must refuse (``reference.
+    CONTROL``), each planted in the reference's seat of a whole
+    ``harness.run_cell``: the program's tokens and passes are then NOT
+    that computation's, and the run comes out ``correct: false`` by the
+    limits of ``archs/sdar_moe.py``. (The tiny configuration draws its
+    weights at 0.3: at 0.02 and 32 columns the logits are flat.)"""
+    import jax.numpy as jnp
+    from benchmark.reference import sdar_moe as reference
+    reference.CONTROL.update({
+        k: getattr(jnp, v) if k == "operands" else v
+        for k, v in CONTROLS[control].items()})
+    try:
+        out = _run()
+    finally:
+        reference.CONTROL.clear()
+    assert out["line"]["correct"] is False and out["line"]["failed"] == 0
+    why = " ".join(out["why_incorrect"])
+    ref = out["info"]["reference"]
+    if control == "left_to_right":
+        assert "from the reference rule's choice" in why
+        assert ref["near_ties_over_share"] == 0      # the logits are right
+    else:
+        assert "below the reference's top logit" in why
+    assert ref["control"]
+
+
+def test_block_states_from_the_unmask_passes():
+    gen = {"block_length": 4, "mask_token_id": 99, "denoising_steps": 4}
+    prompt = np.arange(1, 7)                     # a tail of 2
+    toks = np.asarray([10, 11, 20, 21, 22, 23, 30])   # the last block cut
+    unmask = [1, 0, 2, 0, 3, 1, 0]
+    clean, noised, start, when, end = serve_arch_blocks.block_states(
+        prompt, toks, unmask, gen, 20)
+    assert (start, end) == (4, 12)               # 13 // 4 * 4
+    assert clean.tolist() == [1, 2, 3, 4, 5, 6, 10, 11, 20, 21, 22, 23] \
+        + [0] * 8
+    assert when[:12].tolist() == [-1] * 6 + [1, 0, 2, 0, 3, 1]
+    M = 99
+    assert noised[:, :8].tolist() == [
+        [5, 6, M, M, M, M, M, M],                # going into pass 0
+        [5, 6, M, 11, M, 21, M, M],
+        [5, 6, 10, 11, M, 21, M, 23],
+        [5, 6, 10, 11, 20, 21, M, 23]]
+    assert (noised[:, 8:] == 0).all()            # beyond the whole blocks
+
+
+def test_manifest_names_what_the_blocks_cell_needs():
+    """By name, not by place: a later PR appends behind these."""
+    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    cells = [w["name"] for w in m["workloads"]]
+    cell = m["workloads"][cells.index(CELL)]
+    assert cells.index(CELL) == cells.index(BEFORE) + 1
+    assert cell["chips"] == 1 and cell["config"] == "sdar-30b-a3b-ep8"
+    config = next(c for c in m["configs"] if c["name"] == cell["config"])
+    assert config["reduced"] == ["num_experts", "vocab_size"]
+    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
+    with open(os.path.join(ROOT, "benchmark/traffic",
+                           f"{cell['traffic']}.json")) as f:
+        mix = json.load(f)
+    assert mix["kind"] == "serve_arch_blocks" and mix["schedule_seed"] == 45
+    assert mix["arrivals"] == {"process": "backlog", "count": 400}
+    assert mix["drain_s"] == 0 and mix["ramp_s"] >= 50
+    assert mix["prompt_len"] == {"dist": "fixed", "value": 512,
+                                 "min": 512, "max": 512}
+    assert mix["output_len"] == {"dist": "uniform", "min": 512,
+                                 "max": 1024}
+    gen = _config()["serve"]["generation"]
+    assert all(gen[k] == v for k, v in mix["generation"].items())
+    names = [x["name"] for x in m["per_layer"]]
+    first = names.index(BLOCKGEN[0] + ".blockgen")
+    assert names[first:first + len(BLOCKGEN)] == \
+        [n + ".blockgen" for n in BLOCKGEN]
+    assert first > names.index("mla_decode_roofline_pct.video")
+    rehearsed = {x["name"] for x in
+                 harness.load_manifest(MANIFEST)["per_layer"]}
+    for x in m["per_layer"][first:first + len(BLOCKGEN)]:
+        mod = harness.find_reader(ROOT, m, x["name"])
+        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
+            (x["name"], x["unit"], x["layer"], x["moves"])
+        assert x["moves"] == "serve_tokens_per_s"
+        assert x["workloads"] == [CELL] and x["name"] in rehearsed
+        assert x["source"] in ("device_trace", "host_clock")
+        if "roofline" in x["name"]:
+            assert x["unit"] == "%" and x["better"] == "higher"
+    # the cell behind the Ling cell wherever both are listed
+    listed = [x for x in m["end_to_end"] + m["per_layer"]
+              if CELL in x.get("workloads", []) and x["workloads"] != [CELL]]
+    assert [x["name"] for x in listed] == [
+        "serve_tokens_per_s", "kv_used_peak_pct", "setup_compile_s"] + [
+        n + ".backlogs" for n in ACCOUNT]
+    for x in listed:
+        w = x["workloads"]
+        assert w.index(CELL) == w.index(BEFORE) + 1
+
+
+def test_the_pins_see_the_file_as_of_their_cell():
+    """``tests/conftest.py``: ``as_of`` the Ling cell leaves out exactly
+    what this cell appended; at this cell it is the file."""
+    sys.path.insert(0, os.path.dirname(HERE))
+    from conftest import AS_OF_LATER_PINS, AS_OF_PINS, as_of
+    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    assert set(AS_OF_LATER_PINS.values()) == {BEFORE}
+    assert not set(AS_OF_LATER_PINS) & set(AS_OF_PINS)
+    cells = [w["name"] for w in m["workloads"]]
+    assert as_of(m, cells[-1]) == m
+    old = as_of(m, BEFORE)
+    at = cells.index(CELL)
+    assert [w["name"] for w in old["workloads"]] == cells[:at]
+    assert [c["name"] for c in old["configs"]] == \
+        [c["name"] for c in m["configs"]][:6]
+    gone = {x["name"] for x in m["per_layer"]} \
+        - {x["name"] for x in old["per_layer"]}
+    assert gone >= {n + ".blockgen" for n in BLOCKGEN}
+    for kind in ("end_to_end", "per_layer"):
+        kept = {x["name"]: x for x in m[kind]}
+        for x in old[kind]:
+            assert CELL not in x.get("workloads", [])
+            assert dict(kept[x["name"]], workloads=None) == \
+                dict(x, workloads=None)
+
+
+def test_published_widths_are_in_the_sdar_configuration():
+    c = _config()
+    rows = []
+    if os.path.exists(CATALOG):
+        with open(CATALOG) as f:
+            rows = [json.loads(x) for x in f if '"SDAR-30B-A3B-Chat"' in x]
+    for row in rows:                # every key of the catalog's config
+        assert c["source"] == row["source_url"]
+        for k, v in row["config"].items():
+            assert c[k] == v or k in c["reduced"], k
+            assert c["published"].get(k, v) == v, k
+    assert c["reduced"] == ["num_experts", "vocab_size"]
+    assert (c["hidden_size"], c["moe_intermediate_size"],
+            c["num_attention_heads"], c["num_key_value_heads"],
+            c["head_dim"], c["num_experts_per_tok"], c["rope_theta"],
+            c["rms_norm_eps"], c["tie_word_embeddings"]) == (
+        2048, 768, 32, 4, 128, 8, 1000000, 1e-6, False)
+    # ALL the layers; an eighth of the experts and of the vocabulary
+    assert (c["num_hidden_layers"], c["num_experts"], c["vocab_size"]) \
+        == (48, 16, 18992)
+    pub = c["published"]
+    assert (pub["num_hidden_layers"], pub["num_experts"],
+            pub["vocab_size"]) == (48, 128, 151936)
+    assert c["num_experts"] * 8 == pub["num_experts"]
+    assert c["vocab_size"] * 8 == pub["vocab_size"]
+    assert c["deployment"]["chips"] == 8
+    s, g = c["serve"], c["serve"]["generation"]
+    assert (s["max_len"], s["slots"], s["kv_blocks"], s["block_size"],
+            s["prefill_chunk"]) == (1600, 32, 800, 64, 512)
+    assert (g["block_length"], g["denoising_steps"], g["remasking"],
+            g["mask_token_id"]) == (4, 4, "low_confidence_static", 18991)
+    assert c["assumed"]["qk_norm_gain"] == 2.0
+    assert all(n % g["block_length"] == 0 for n in (
+        s["max_len"], s["block_size"], s["prefill_chunk"]))
+    # the worst request (512 + 1,024) in whole pages, every slot at once
+    assert s["slots"] * -(-1536 // s["block_size"]) < s["kv_blocks"]
+    from benchmark.runners.serve_arch import load_arch
+    import jax
+    arch = load_arch(c["arch"])
+    model = arch.build(c)
+    assert model.cfg.local_experts == (0, 16)
+    assert model.cfg.num_experts == 128
+    assert model.generation.mask_token_id == 18991
+    assert model.blocks.block.attn.attn_block == 4
+    assert model.blocks.block.moe.score == "softmax"
+    n = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(
+        jax.eval_shape(model.init, jax.random.key(0))))
+    assert abs(n - 4.62e9) < 5e6
+    leaves = jax.eval_shape(lambda: model.blocks.init_paged_caches(
+        s["kv_blocks"], s["block_size"], jax.numpy.bfloat16, s["slots"]))
+    assert [x.shape for x in leaves] == [(48, 800, 64, 512)] * 2
+    cache = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves)
+    assert cache == 800 * 64 * 98304
+    assert arch.arena_row_elements(c) == c["n_embd"] == 512
+    assert 0.83 <= (2 * n + cache) / 16.91e9 <= 0.86
+    assert arch.reference_config(c)["num_experts"] == 128
+
+
+def test_flops_sdar_arithmetic_and_readers_without_a_device():
+    c = _config()
+    peaks = peaks_for("TPU v5 lite")
+    assert fs.expert_bytes(c) == 3 * 2048 * 768 * 2
+    call = fs.moe_experts_call(c, 128, 16)
+    assert call == {"bytes": 16 * 9437184.0,
+                    "flops": 6.0 * 2048 * 768 * 128}
+    # 32 slots of ~14 pages: K and V once a slot, 4 rows against them
+    blk = fs.paged_block_call(c, 448, 64)
+    assert blk["bytes"] == 2.0 * 448 * 64 * 512 * 2
+    assert blk["flops"] == 4.0 * 448 * 64 * 4 * 32 * 128
+    # bound by its bytes
+    assert flops.roofline_seconds(blk["flops"], blk["bytes"], peaks) \
+        == pytest.approx(blk["bytes"] / 819e9)
+    run = types.SimpleNamespace(config=c, peaks=peaks, trace=None,
+                                cell={"name": "none"}, records={})
+    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    for name in BLOCKGEN:
+        assert harness.find_reader(ROOT, m, name + ".blockgen") \
+            .read(run) is None, name
+    run.records = {"diffusion": {
+        "denoise_passes": 400.0, "commit_passes": 100.0,
+        "serving_diffusion_blocks_total": 100.0,
+        "serving_diffusion_tokens_total": 398.0}}
+    assert harness.find_reader(
+        ROOT, m, "diffusion_commit_pass_pct.blockgen").read(run) == 20.0
+    assert harness.find_reader(
+        ROOT, m, "diffusion_tokens_per_pass.blockgen").read(run) == 0.796

@@ -1,0 +1,514 @@
+"""SDAR-MoE (``models/sdar_moe.py``) and the block lane
+(``serving/block_diffusion.py``): the block bound in the attention
+paths against an explicit mask, the softmax route, the eight shares of
+an expert layer, and generation by diffusion over blocks through the
+engine against the plain reference's ``block_diffusion_generate``
+(``benchmark/reference/sdar_moe.py``) token for token and pass for
+pass — on the CPU, at the tiny preset, seeded."""
+
+import dataclasses
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.reference import sdar_moe as reference  # noqa: E402
+from hetu_tpu import telemetry  # noqa: E402
+from hetu_tpu.engine import trace_counts  # noqa: E402
+from hetu_tpu.models import generation  # noqa: E402
+from hetu_tpu.models.sdar_moe import (  # noqa: E402
+    BlockDiffusion, SDARMoEConfig, SDARMoEForCausalLM,
+)
+from hetu_tpu.nn.moe import ExpertShareMoE  # noqa: E402
+from hetu_tpu.ops.attention import (  # noqa: E402
+    attention_reference, attention_with_lse, block_bound,
+)
+from hetu_tpu.ops.paged_pallas import (  # noqa: E402
+    decode_work_list, paged_attention_pallas, paged_attention_reference,
+)
+from hetu_tpu.serving import ServingEngine  # noqa: E402
+from hetu_tpu.serving.block_diffusion import (  # noqa: E402
+    BlockGenerationNotSupported, denoise_slots,
+)
+from hetu_tpu.serving.scheduler import SamplingParams  # noqa: E402
+
+
+def _config(cfg: SDARMoEConfig, **over) -> dict:
+    """``cfg`` as the reference reads it (the published keys)."""
+    g = cfg.generation
+    return {"num_attention_heads": cfg.num_attention_heads,
+            "num_key_value_heads": cfg.num_key_value_heads,
+            "head_dim": cfg.head_dim, "rms_norm_eps": cfg.rms_norm_eps,
+            "rope_theta": cfg.rope_theta, "num_experts": cfg.num_experts,
+            "num_experts_per_tok": cfg.num_experts_per_tok,
+            "block_length": g.block_length,
+            "mask_token_id": g.mask_token_id,
+            "denoising_steps": g.denoising_steps,
+            "remasking": g.remasking,
+            "confidence_threshold": g.confidence_threshold, **over}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = SDARMoEConfig.tiny()
+    model = SDARMoEForCausalLM(cfg)
+    return cfg, model, model.init(jax.random.key(0))
+
+
+@pytest.fixture(scope="module")
+def sharp():
+    """A tiny model whose confidences pass a threshold: weights drawn
+    ten times as wide."""
+    cfg = SDARMoEConfig.tiny(init_std=0.2)
+    model = SDARMoEForCausalLM(cfg)
+    return cfg, model, model.init(jax.random.key(3))
+
+
+# -- the block bound ---------------------------------------------------------
+
+def _explicit(q, k, v, qpos, block):
+    """Attention under the mask written out: key ``j`` is seen from
+    position ``p`` iff ``j // block <= p // block``."""
+    hq, hkv = q.shape[2], k.shape[2]
+    k, v = (jnp.repeat(x, hq // hkv, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    seen = jnp.arange(k.shape[1])[None, None, :] // block \
+        <= qpos[:, :, None] // block
+    s = jnp.where(seen[:, None], s, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("block", [1, 4])
+def test_block_bound_in_attention_reference(block):
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(2, 8, 4, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(2, 24, 2, 16)), jnp.float32)
+            for _ in range(2))
+    off = jnp.asarray([4, 12], jnp.int32)
+    out = attention_reference(q, k, v, causal=True, q_offset=off,
+                              block=block)
+    qpos = off[:, None] + jnp.arange(8)[None]
+    np.testing.assert_allclose(out, _explicit(q, k, v, qpos, block),
+                               atol=1e-5)
+    plain = attention_reference(q, k, v, causal=True, q_offset=off)
+    if block == 1:
+        assert (np.asarray(out) == np.asarray(plain)).all()
+    else:
+        assert np.abs(np.asarray(out - plain)).max() > 1e-3
+    # the scalar-offset branch (the whole-sequence forward)
+    out0 = attention_reference(q, k[:, :8], v[:, :8], causal=True,
+                               block=block)
+    np.testing.assert_allclose(out0, _explicit(
+        q, k[:, :8], v[:, :8], jnp.arange(8)[None].repeat(2, 0), block),
+        atol=1e-5)
+    assert block_bound(5, 1) == 5 and block_bound(5, 4) == 7
+    with pytest.raises(ValueError):
+        block_bound(5, 3)
+    with pytest.raises(ValueError):
+        attention_reference(q, k, v, block=4)
+
+
+@pytest.mark.parametrize("block", [1, 4])
+def test_block_bound_in_the_paged_call(block):
+    """The kernel (interpret mode) over block tables == the mask
+    written out, rows of a slot at its own offset; at 1 the call is the
+    causal one to the bit, and so is the work list."""
+    rng = np.random.default_rng(1)
+    S, R, hq, hkv, d, bs, W, nb = 3, 4, 4, 2, 16, 4, 8, 40
+    q = jnp.asarray(rng.normal(size=(S, R, hq, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(nb, bs, hkv * d)), jnp.float32)
+            for _ in range(2))
+    tbl = jnp.asarray(np.stack([rng.permutation(np.arange(1, nb))[:W]
+                                for _ in range(S)]), jnp.int32)
+    off = jnp.asarray([0, 8, 20], jnp.int32)
+    live = jnp.asarray([True, True, True])
+    kw = {} if block == 1 else {"block": block}
+    gather = lambda x: jnp.take(x, tbl, axis=0).reshape(S, W * bs, hkv, d)
+    want = _explicit(q, gather(k), gather(v),
+                     off[:, None] + jnp.arange(R)[None], block)
+    for pages in (1, 2, 8):
+        out = paged_attention_pallas(q, k, v, tbl, off, live=live,
+                                     pages_per_step=pages, **kw)
+        np.testing.assert_allclose(out, want, atol=1e-5)
+    np.testing.assert_allclose(
+        paged_attention_reference(q, k, v, tbl, off, **kw), want,
+        atol=1e-5)
+    if block == 1:
+        plain = paged_attention_pallas(q, k, v, tbl, off, live=live)
+        assert (np.asarray(plain) == np.asarray(
+            paged_attention_pallas(q, k, v, tbl, off, live=live,
+                                   block=1))).all()
+        for a, b in zip(
+                decode_work_list(off, live, rows=R, span=8, n_steps=4),
+                decode_work_list(off, live, rows=R, span=8, n_steps=4,
+                                 block=1)):
+            assert (np.asarray(a) == np.asarray(b)).all()
+    with pytest.raises(ValueError):
+        paged_attention_pallas(q, k, v, tbl, off, block=4, window=8)
+
+
+@pytest.mark.parametrize("block", [1, 4])
+@pytest.mark.parametrize("impl", ["reference", "pallas"])
+def test_block_bound_in_the_flash_forward(block, impl):
+    """The packed lane's in-pack attention: two requests' runs of whole
+    blocks in one row, ids isolating them."""
+    rng = np.random.default_rng(2)
+    C, hq, hkv, d = 32, 4, 2, 16
+    q = jnp.asarray(rng.normal(size=(1, C, hq, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, C, hkv, d)), jnp.float32)
+            for _ in range(2))
+    seg = jnp.asarray([[0] * 12 + [1] * 16 + [-1] * 4], jnp.int32)
+    kw = {} if block == 1 else {"block": block}
+    out, lse = attention_with_lse(q, k, v, causal=True, segment_ids=seg,
+                                  impl=impl, interpret=True, **kw)
+    kk, vv = (jnp.repeat(x, hq // hkv, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / np.sqrt(d)
+    at = jnp.arange(C)
+    seen = (at[None, :] // block <= at[:, None] // block) \
+        & (seg[0][:, None] == seg[0][None, :])
+    s = jnp.where(seen[None, None], s, -1e30)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
+    live = np.asarray(seg[0] >= 0)
+    np.testing.assert_allclose(np.asarray(out)[0][live],
+                               np.asarray(want)[0][live], atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse)[0][:, live],
+        np.asarray(jax.nn.logsumexp(s, -1))[0][:, live], atol=1e-5)
+    if block == 1:
+        plain, _ = attention_with_lse(q, k, v, causal=True,
+                                      segment_ids=seg, impl=impl,
+                                      interpret=True)
+        assert (np.asarray(plain) == np.asarray(out)).all()
+
+
+def test_flash_forward_refuses_a_bound_its_tiles_do_not_hold():
+    from hetu_tpu.ops.flash_pallas import _flash_fwd
+    q = jnp.zeros((1, 2, 8, 16))
+    with pytest.raises(ValueError, match="whole blocks"):
+        _flash_fwd(q, q, q, None, None, causal=True, scale=1.0,
+                   interpret=True, block=16)
+    with pytest.raises(ValueError, match="whole blocks"):
+        _flash_fwd(q, q, q, None, None, causal=False, scale=1.0,
+                   interpret=True, block=4)
+
+
+# -- the route and the shares ------------------------------------------------
+
+def test_softmax_route_is_the_references_and_sigmoid_is_untouched():
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(24, 32)), jnp.float32)
+    moe = ExpertShareMoE(32, 16, 8, k=2, score="softmax")
+    p = moe.init(jax.random.key(1))
+    idx, w = moe.route(p, x)
+    ridx, rw, _ = reference.route(p["router"], x,
+                                  {"num_experts_per_tok": 2})
+    assert (np.asarray(idx) == np.asarray(ridx)).all()
+    np.testing.assert_allclose(w, rw, rtol=1e-6)
+    # the sigmoid routes, as they were: the formula written out
+    z = jnp.matmul(x, p["router"], precision=jax.lax.Precision.HIGHEST)
+    for kw in ({}, {"select_bias": True, "scale": 2.5},
+               {"select_bias": True, "n_group": 4, "topk_group": 2}):
+        m = ExpertShareMoE(32, 16, 8, k=2, **kw)
+        pp = dict(m.init(jax.random.key(1)), router=p["router"])
+        idx, w = m.route(pp, x)
+        s = jax.nn.sigmoid(z)
+        if not kw:
+            top, want = jax.lax.top_k(s, 2)
+            assert (np.asarray(idx) == np.asarray(want)).all()
+            assert (np.asarray(w) == np.asarray(
+                top / top.sum(-1, keepdims=True))).all()
+        else:
+            top = jnp.take_along_axis(s, idx, axis=-1)
+            ww = top / top.sum(-1, keepdims=True) * (kw.get("scale") or 1)
+            assert (np.asarray(w) == np.asarray(ww)).all()
+    for bad in ({"score": "softmax", "select_bias": True},
+                {"score": "softmax", "n_group": 2, "topk_group": 1},
+                {"score": "tanh"}):
+        with pytest.raises(ValueError):
+            ExpertShareMoE(32, 16, 8, k=2, **bad)
+
+
+def test_the_eight_shares_of_one_expert_layer_add_up():
+    """Each share holds one expert of eight (the router eight wide);
+    their outputs add up to the uncut reference's layer."""
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.normal(size=(20, 32)), jnp.float32)
+    whole = ExpertShareMoE(32, 16, 8, k=2, score="softmax")
+    p = whole.init(jax.random.key(2))
+    want, _ = reference.moe({"moe": p}, x, {
+        "num_experts": 8, "num_experts_per_tok": 2})
+    total = 0.0
+    for e in range(8):
+        share = ExpertShareMoE(32, 16, 8, k=2, score="softmax",
+                               local_experts=(e, 1))
+        ps = dict(p, **{n: p[n][e:e + 1] for n in ("wg", "wi", "wo")})
+        total = total + share(ps, x)
+        one, _ = reference.moe({"moe": ps}, x, {
+            "num_experts": 8, "num_experts_per_tok": 2}, (e, 1))
+        np.testing.assert_allclose(share(ps, x), one, atol=1e-5)
+    np.testing.assert_allclose(total, want, atol=1e-5)
+    np.testing.assert_allclose(whole(p, x), want, atol=1e-5)
+
+
+# -- the model against the reference ----------------------------------------
+
+def test_forward_is_the_references_block_causal_logits(tiny):
+    cfg, model, p = tiny
+    ids = np.asarray(jax.random.randint(jax.random.key(1), (2, 20), 0, 95))
+    np.testing.assert_allclose(
+        model(p, ids), reference.logits(p, ids, _config(cfg)), atol=2e-5)
+    # a later block is not seen; the own block is, whole
+    later = np.asarray(model(p, np.where(np.arange(20) == 8, 7, ids)))
+    assert np.abs(later - np.asarray(model(p, ids)))[:, :8].max() == 0
+    own = np.asarray(model(p, np.where(np.arange(20) == 7, 7, ids)))
+    assert np.abs(own - np.asarray(model(p, ids)))[:, 4].max() > 1e-4
+    assert model.generation == BlockDiffusion(4, 95, 4)
+    with pytest.raises(ValueError):
+        BlockDiffusion(3, 0, 2)
+    with pytest.raises(ValueError):
+        BlockDiffusion(4, 0, 5)
+
+
+def test_prefill_then_block_passes_through_the_arena(tiny):
+    """The prompt's whole blocks written through the paged arena (a
+    batch row a token, the reference lane), then the block lane's rows
+    — a noised block of 4 at its positions — read it back: the logits
+    are the reference's streams' (the clean one's at a commit pass)."""
+    cfg, model, p = tiny
+    rng = np.random.default_rng(6)
+    B, M = 4, cfg.vocab_size - 1
+    seq = rng.integers(1, M, 16).astype(np.int32)
+    bs, W = 4, 8
+    caches = model.blocks.init_paged_caches(W + 1, bs, jnp.float32)
+    bt = jnp.arange(1, W + 1, dtype=jnp.int32)[None]
+    for lo in (0, 8):                      # two chunks of two blocks
+        pos = np.arange(lo, lo + 8)
+        _, caches = generation.decode(
+            model, p, seq[pos][:, None], pos[:, None].astype(np.int32),
+            caches, slot_mask=jnp.ones(8, bool),
+            block_tables=jnp.repeat(bt, 8, axis=0))
+    noised = np.stack([np.where([1, 0, 1, 1], M, seq[12:16]),
+                       np.where([0, 0, 1, 0], M, seq[12:16]),
+                       seq[12:16]]).astype(np.int32)
+    clean = seq.copy()
+    hc, hn, _ = reference.streams(
+        p, clean, np.concatenate(
+            [np.repeat(seq[None, 8:12], 3, 0), noised], axis=1),
+        _config(cfg), start=8)
+    want = np.asarray(hn @ p["lm_head"]["weight"].T)[:, 4:]
+    for i, blk in enumerate(noised):
+        lg, caches = generation.decode(
+            model, p, blk[None], np.arange(12, 16, dtype=np.int32)[None],
+            caches, slot_mask=jnp.ones(1, bool), block_tables=bt)
+        np.testing.assert_allclose(lg[0], want[i], atol=3e-5)
+    # the last pass was the commit pass: the clean stream's logits
+    np.testing.assert_allclose(
+        lg[0], np.asarray(hc @ p["lm_head"]["weight"].T)[12:], atol=3e-5)
+
+
+# -- the sampler -------------------------------------------------------------
+
+def test_denoise_slots_by_the_state_of_each_slot():
+    """Four slots in one call: a static pass, a dynamic pass whose
+    threshold fires on two positions, a commit, a dead slot."""
+    V, M = 12, 11
+    lg = np.full((4, 4, V), -4.0, np.float32)
+    lg[..., M] = 9.0                         # never drawn
+    for s in range(4):
+        for i, (tok, height) in enumerate(
+                [(3, -2.0), (5, 6.0), (7, -1.0), (2, 5.0)]):
+            lg[s, i, tok] = height
+    tok = np.full((4, 4), M, np.int32)
+    masked = np.ones((4, 4), bool)
+    tok[2], masked[2] = [1, 2, 3, 4], False            # to be committed
+    tok[0, 1], masked[0, 1] = 9, False                 # already unmasked
+    out = denoise_slots(
+        jnp.asarray(lg), jnp.asarray(tok), jnp.asarray(masked),
+        jnp.asarray([1, 0, 4, 2]), jnp.asarray([4, 4, 4, 4]),
+        jnp.asarray([False, True, False, False]),
+        jnp.asarray([0.0, 0.9, 0.0, 0.0], jnp.float32),
+        jnp.asarray([True, True, True, False]), mask_id=M)
+    committed, ncommit, ntok, nmask, npass = map(np.asarray, out)
+    assert ncommit.tolist() == [0, 0, 4, 0]
+    assert committed[2].tolist() == [1, 2, 3, 4]
+    # static, one a pass: the most confident MASKED position (3)
+    assert ntok[0].tolist() == [M, 9, M, 2]
+    assert nmask[0].tolist() == [True, False, True, False]
+    # dynamic: positions 1 and 3 pass 0.9 (two, at least one)
+    assert ntok[1].tolist() == [M, 5, M, 2]
+    assert nmask[1].tolist() == [True, False, True, False]
+    # the committed slot begins its next block; the dead one keeps its own
+    assert ntok[2].tolist() == [M] * 4 and nmask[2].all()
+    assert ntok[3].tolist() == [M] * 4 and nmask[3].all()
+    assert npass.tolist() == [2, 1, 0, 2]
+
+
+# -- the engine against the reference's generation loop ----------------------
+
+def _engine(model, p, **kw):
+    return ServingEngine(model, p, **{**dict(
+        slots=2, max_len=64, prefill_chunk=8, block_size=8), **kw})
+
+
+def _generate(eng, prompts, params):
+    reqs = [eng.submit(list(map(int, pr)), sp)
+            for pr, sp in zip(prompts, params)]
+    eng.run_until_drained()
+    return [(r.tokens, r.unmask_pass) for r in reqs]
+
+
+CASES = {
+    # (prompt lengths, max_tokens, sampling knobs)
+    "static4_tails_and_cuts": ((8, 9, 10, 11, 3, 16), (8, 7, 5, 9, 12, 4),
+                               {}),
+    "static2": ((8, 5, 14), (8, 6, 11), {"denoising_steps": 2}),
+    "static3_remainder_first": ((8, 6), (8, 9), {"denoising_steps": 3}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_engine_generates_as_the_reference_token_and_pass(tiny, case):
+    """More requests than slots (a slot is taken by a second request),
+    of lengths that put the two slots at different passes in one
+    step."""
+    cfg, model, p = tiny
+    lens, outs, knobs = CASES[case]
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 95, n) for n in lens]
+    traces0 = trace_counts().get("serving_step", 0)
+    eng = _engine(model, p)
+    got = _generate(eng, prompts, [SamplingParams(max_tokens=t, **knobs)
+                                   for t in outs])
+    for pr, t, (toks, at) in zip(prompts, outs, got):
+        want = reference.block_diffusion_generate(
+            p, pr, _config(cfg), max_tokens=t, **knobs)
+        assert (toks, at) == want
+        assert len(toks) == t and 95 not in toks
+    assert eng.step_executables() == 1
+    assert trace_counts()["serving_step"] - traces0 == 1
+
+
+def test_engine_dynamic_remasking_with_a_threshold_that_fires(sharp):
+    cfg, model, p = sharp
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(1, 95, n) for n in (8, 7, 12, 9)]
+    knobs = dict(remasking="low_confidence_dynamic",
+                 confidence_threshold=0.5)
+    eng = _engine(model, p)
+    got = _generate(eng, prompts, [SamplingParams(max_tokens=12, **knobs),
+                                   SamplingParams(max_tokens=9, **knobs),
+                                   SamplingParams(max_tokens=8),
+                                   SamplingParams(max_tokens=10, **knobs)])
+    fired = False
+    for pr, (toks, at), kn in zip(prompts, got,
+                                  (knobs, knobs, {}, knobs)):
+        want = reference.block_diffusion_generate(
+            p, pr, _config(cfg), max_tokens=len(toks), **kn)
+        assert (toks, at) == want
+        # a pass that unmasked two positions: the threshold fired
+        fired |= bool(kn) and len(at) - len(set(
+            (i // 4, a) for i, a in enumerate(at))) > 0
+    assert fired
+    assert eng.step_executables() == 1
+
+
+def test_engine_stops_at_a_block_holding_the_stop_id(tiny):
+    cfg, model, p = tiny
+    prompt = np.random.default_rng(9).integers(1, 95, 8)
+    free, _ = reference.block_diffusion_generate(
+        p, prompt, _config(cfg), max_tokens=12)
+    eos = free[5]
+    want = reference.block_diffusion_generate(
+        p, prompt, _config(cfg), max_tokens=12, eos_id=eos)
+    eng = _engine(model, p)
+    (toks, at), = _generate(eng, [prompt], [SamplingParams(
+        max_tokens=12, eos_id=eos)])
+    assert (toks, at) == want and toks[-1] == eos and len(toks) < 12
+
+
+def test_block_lane_counters_span_field_and_result(tiny):
+    cfg, model, p = tiny
+    telemetry.enable(True)
+    reg = telemetry.get_registry()
+    passes = reg.counter("serving_diffusion_passes_total")
+    names = ("serving_diffusion_blocks_total",
+             "serving_diffusion_tokens_total")
+    before = [passes.value(kind="denoise"), passes.value(kind="commit")] \
+        + [reg.counter(n).value() for n in names]
+    eng = _engine(model, p)
+    req = eng.submit(list(range(1, 9)), SamplingParams(max_tokens=10))
+    eng.run_until_drained()
+    after = [passes.value(kind="denoise"), passes.value(kind="commit")] \
+        + [reg.counter(n).value() for n in names]
+    # 3 blocks of 4 passes + a commit; 10 of 12 tokens handed on
+    assert [b - a for a, b in zip(before, after)] == [12, 3, 3, 10]
+    res = req.result()
+    assert res["unmask_pass"] == req.unmask_pass
+    assert sorted(res["unmask_pass"][:4]) == [0, 1, 2, 3]
+    assert res["timing"]["ttft_ms"] > 0
+    # a model that yields a token a step has no such field
+    from hetu_tpu.models import GPTConfig, GPTLMHeadModel
+    gpt = GPTLMHeadModel(GPTConfig.tiny())
+    e2 = ServingEngine(gpt, gpt.init(jax.random.key(0)), slots=1,
+                       max_len=32, prefill_chunk=8)
+    r2 = e2.submit([1, 2, 3], SamplingParams(max_tokens=2))
+    e2.run_until_drained()
+    assert "unmask_pass" not in r2.result()
+    with pytest.raises(ValueError, match="diffusion over blocks"):
+        e2.submit([1, 2, 3], SamplingParams(denoising_steps=2))
+
+
+# -- what a block lane refuses -----------------------------------------------
+
+REFUSED_AT_CONSTRUCTION = {
+    "spec_depth": dict(spec_depth=2),
+    "prefix_cache": dict(prefix_cache=True),
+    "preempt": dict(preempt=True),
+    "spill": dict(spill_host_budget_bytes=1e6),
+    "long_max_len": dict(long_max_len=128),
+    "int8": dict(cache_dtype=jnp.int8),
+    "w8a8": dict(w8a8="on"),
+    "tenancy": dict(tenancy=True),
+}
+
+
+@pytest.mark.parametrize("what", sorted(REFUSED_AT_CONSTRUCTION))
+def test_block_generation_refuses_at_construction_by_name(tiny, what):
+    _, model, p = tiny
+    with pytest.raises(BlockGenerationNotSupported,
+                       match="diffusion over blocks"):
+        _engine(model, p, **REFUSED_AT_CONSTRUCTION[what])
+
+
+def test_block_generation_refuses_at_submit_and_hand_over(tiny):
+    _, model, p = tiny
+    eng = _engine(model, p)
+    assert eng.prefix_cache is None and not eng.preempt
+    for sp, kw in ((SamplingParams(temperature=0.7), {}),
+                   (SamplingParams(), {"handoff": True})):
+        with pytest.raises(BlockGenerationNotSupported):
+            eng.submit([1, 2, 3, 4], sp, **kw)
+    for bad in (dict(denoising_steps=5), dict(denoising_steps=0),
+                dict(remasking="sequential")):
+        with pytest.raises(ValueError):
+            eng.submit([1, 2, 3, 4], SamplingParams(**bad))
+    with pytest.raises(BlockGenerationNotSupported):
+        eng.export_prefix([1, 2, 3, 4])
+    with pytest.raises(BlockGenerationNotSupported):
+        eng.prefill_only([1, 2, 3, 4])
+    req = eng.submit([1, 2, 3, 4], SamplingParams(max_tokens=4))
+    with pytest.raises(BlockGenerationNotSupported):
+        eng.evict_request(req)
+    with pytest.raises(ValueError, match="whole blocks"):
+        _engine(model, p, max_len=62)
+    # a request's worst case ends on a whole block
+    assert eng.scheduler.blocks_needed(dataclasses.replace(
+        req, prompt=np.arange(6), sampling=SamplingParams(
+            max_tokens=3))) == 2            # 9 -> 12 positions, pages of 8

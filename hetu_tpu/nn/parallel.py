@@ -1949,6 +1949,27 @@ class LightningAttention(Module):
         return self._output(params, o, x), (buf,)
 
 
+def count_kda_steps(values, tokens=None) -> None:
+    """:class:`KimiDeltaAttention`'s ``layer_stats`` on the host:
+    ``values (KDA layers, 2)`` — of each layer's scan call, the grid
+    steps that held a valid row and the grid steps run
+    (``ops.kda_pallas.hetu_kda_scan(return_steps=True)``; zeros from
+    the decode rows, which run no scan) — into
+    ``kda_scan_steps_total{kind}``."""
+    import numpy as np
+    from hetu_tpu import telemetry
+    live, computed = np.asarray(values, np.int64).sum(axis=0).tolist()
+    if computed:
+        c = telemetry.get_registry().counter(
+            "kda_scan_steps_total",
+            "grid steps of the delta-rule scan kernel: live = (piece, "
+            "head block) steps that held a valid row, computed = steps "
+            "run (a chunk without a valid row costs one that writes "
+            "zeros), summed over layer calls")
+        c.inc(float(live), kind="live")
+        c.inc(float(computed), kind="computed")
+
+
 class KimiDeltaAttention(Module):
     """Kimi Delta Attention (``ops.kda``): a gated delta rule with a
     decay per channel over a per-slot recurrent state, behind a short
@@ -1969,12 +1990,17 @@ class KimiDeltaAttention(Module):
     slot held. The decode rows advance their slot by a token
     (``hetu.kda_update``), a prefill pack's tokens theirs in chunks
     (``hetu.kda_scan``), both behind ``hetu.kda_conv``; each addresses
-    the live slots of its own layer in the stacked leaves in place. No
-    page is ever read or written. ``A_log``, ``dt_bias`` and the taps
+    the live slots of its own layer in the stacked leaves in place
+    (the scan is the Pallas kernel ``ops.kda_pallas.hetu_kda_scan``: one
+    call a layer call over the pack's pieces, a run's state in VMEM
+    across its chunks, interpreted on the CPU; ``ops.kda.kda_scan`` is
+    its oracle). No page is ever read or written. ``A_log``, ``dt_bias`` and the taps
     are drawn, not constants (a program that leaves one out must
     differ)."""
 
     cache_leaves = 2
+    #: a cached call's third result (:func:`count_kda_steps`)
+    layer_stats = {"kda_steps": ((2,), jnp.int32, count_kda_steps)}
 
     def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
                  conv_size: int = 4, lower_bound: float = -5.0,
@@ -2105,12 +2131,15 @@ class KimiDeltaAttention(Module):
             with jax.named_scope("hetu.kda_update"):
                 o, state = kda.kda_update(q, k, v, g, beta, state, valid,
                                           layer=layer, fresh=pos == 0)
+            steps = jnp.zeros((2,), jnp.int32)
         else:
+            from hetu_tpu.ops.kda_pallas import hetu_kda_scan
             with jax.named_scope("hetu.kda_scan"):
-                o, state = kda.kda_scan(q, k, v, g, beta, state, slot,
-                                        pos, valid, layer=layer)
+                o, state, steps = hetu_kda_scan(
+                    q, k, v, g, beta, state, slot, pos, valid,
+                    layer=layer, return_steps=True)
         return self._output(params, o, gate).reshape(x.shape), \
-            (state, tail)
+            (state, tail), {"kda_steps": steps}
 
 
 def remat_policy(name: str):

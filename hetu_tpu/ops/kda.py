@@ -16,7 +16,11 @@ and adds).
 * :func:`kda_recurrence` — that, a token at a time (the oracle);
 * :func:`kda_update` — one token a slot: the decode rows;
 * :func:`kda_scan` — a PACK of tokens of several slots' runs, in chunks
-  of ``CHUNK`` pack rows (the prefill lane);
+  of ``CHUNK`` pack rows: the ``jax.numpy`` chunk form. The prefill
+  lane runs the SAME arithmetic as one Pallas call
+  (``ops.kda_pallas.hetu_kda_scan``, interpreted on the CPU); this
+  form is that kernel's oracle beside the recurrence, and only tests
+  (and ``workloads/kda_bench.py``) reach it;
 * :func:`conv_pack`, :func:`conv_rows` — the causal depthwise
   convolution over the tokens of one request, carried across packs by
   a slot's TAIL (the last ``taps - 1`` input rows).

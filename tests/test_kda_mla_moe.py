@@ -290,6 +290,35 @@ def test_engine_serves_tokens_the_reference_puts_on_top(tiny):
         model.blocks.cache_bytes(4)["state"]["slot"]
 
 
+def test_the_counter_counts_a_served_requests_steps(tiny):
+    """``kda_scan_steps_total{kind}`` on a served request: computed =
+    the grid steps run, live of them held a valid row."""
+    from hetu_tpu import telemetry
+    from hetu_tpu.serving import SamplingParams, ServingEngine
+    _, model, params = tiny
+    telemetry.enable(True)
+    try:
+        reg = telemetry.get_registry()
+
+        def read():
+            c = reg.counter("kda_scan_steps_total")
+            return [c.value(kind=k) for k in ("live", "computed")]
+        before = read()
+        eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                            block_size=4, slots=2, kv_blocks=40, seed=0)
+        eng.generate_many([list(range(1, 12))],
+                          SamplingParams(max_tokens=3))
+        live, computed = (a - b for a, b in zip(read(), before))
+    finally:
+        telemetry.enable(False)
+    # 11 tokens in two packs of 8 rows, each one chunk: one live piece a
+    # pack and KDA layer (all heads in one block), nothing else computed
+    # — the decode rows run no scan
+    kda_layers = model.blocks.layers_of["kda"]
+    assert 0 < live <= computed
+    assert live == computed == 2 * kda_layers
+
+
 def _moe(**kw):
     return ExpertShareMoE(32, 16, 16, k=3, select_bias=True, scale=2.5,
                           **kw)

@@ -214,6 +214,48 @@ def test_kimi_step_holds_three_expert_kernel_calls_a_lane_on_v5e(one_chip):
     assert r["arena_moves"] == {}, r
 
 
+def test_ling_step_holds_the_scan_kernel_a_kda_layer_run_on_v5e(one_chip):
+    """Ling-3.0-flash-VL's fused step at the published widths (32 KDA
+    heads of 128, hidden 2560; cut to six layers — a dense KDA layer, a
+    scanned run of four, one MLA layer —, 8 experts and 4,096 ids so
+    that the host holds its zeros) with the cell's 2,048-token pack:
+    under ``hetu.kda_scan`` the prefill lane holds ONE Pallas call a KDA
+    layer run (``hetu_kda_scan``: the unscanned layer's and the scan
+    body's) and the decode lane none, it compiles within its VMEM
+    limit, and nothing copies or slices a layer of the state leaf out
+    of or into it — the kernel reads ``[layer, slot]`` by DMA from the
+    leaf where it lies."""
+    import json
+    import os
+    import re
+
+    from benchmark.runners.serve_arch import load_arch
+    from workloads.aot_check import check_serving_step
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ling-3.0-flash-vl-ep8.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=6, first_k_dense_replace=1, num_experts=8,
+               vocab_size=4096)
+    cfg["published"] = dict(cfg["published"], num_experts=64)
+    model = load_arch(cfg["arch"]).build(cfg)
+    # (the cell's slots: a leaf of a few slots fits the chip's fast
+    # memory and the compiler prefetches it there whole)
+    slots = 72
+    r = check_serving_step(
+        list(one_chip.device_set), model=model, slots=slots, n_blocks=400,
+        max_len=4096, chunk=2048, block_size=64, with_text=True)
+    calls = r["kernel_calls"]
+    assert calls["hetu.prefill_lane>hetu.kda_scan"] == 2, calls
+    assert "hetu.decode_lane>hetu.kda_scan" not in calls, calls
+    assert len(re.findall(r"%hetu_kda_scan[.\d]* = ", r["text"])) == 2
+    # the state leaf (5 KDA layers) and a layer of it, as a result shape
+    leaf = rf"f32\[(5,|1,)?{slots},32,128,128\]"
+    moved = [m.group(1) for m in re.finditer(
+        rf"%((?:copy|slice|dynamic[-_]slice)[\w.\-]*) = {leaf}", r["text"])]
+    assert moved == [], moved
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "fp32"])
 def test_packed_prefill_lane_compiles_for_v5e(one_chip, dtype):

@@ -367,7 +367,8 @@ def check_serving_lane(devs, *, lane="decode", dtype=jnp.bfloat16,
 
 def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
                        slots=148, n_blocks=9473, max_len=1024, chunk=256,
-                       model=None, block_size=16, leaf_elements=None):
+                       model=None, block_size=16, leaf_elements=None,
+                       with_text=False):
     """The REAL fused serving step (``ServingEngine._build_step``: CoW
     pass, decode lane, packed flash prefill lane, sampling) compiled
     for the target at a benchmark cell's sizes (defaults: GPT-2 small,
@@ -378,7 +379,8 @@ def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
     arena at ``n_blocks`` on the described device. ``model=`` is any
     other model with the engine's interface (its weights are zeros
     here: only their shapes reach the compiler). ``leaf_elements``:
-    list the moves of leaves that large in place of the arena's."""
+    list the moves of leaves that large in place of the arena's;
+    ``with_text``: the optimized HLO too, under ``"text"``."""
     from jax.sharding import SingleDeviceSharding
     from hetu_tpu.models import GPTConfig, GPTLMHeadModel
     from hetu_tpu.serving import ServingEngine
@@ -433,7 +435,10 @@ def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
     t0 = time.perf_counter()
     with _mosaic_aot_env():
         c = fn.lower(*sds).compile()
-    return _serving_report(c, sds[1], t0, leaf_elements)
+    report = _serving_report(c, sds[1], t0, leaf_elements)
+    if with_text:
+        report["text"] = c.as_text()
+    return report
 
 
 def check_fused_ce(devs, *, n=4096, e=768, v=50257):

@@ -176,10 +176,12 @@ def run_cell(manifest: dict, root: str, workload: str, *, seed: int,
     t_runner = time.perf_counter()
     out = runner.run(ctx)
     ctx.finish_trace_slice()
-    # where set-up went: imports and device start-up come before the
-    # runner, the rest is the runner's own account
-    out.setdefault("info", {})["process_to_runner_s"] = \
-        t_runner - t_process
+    t_ran = time.perf_counter()
+    # where the run's wall goes: imports and device start-up come
+    # before the runner, the runner gives its own account, and reading
+    # the trace and the metrics comes after it (set below)
+    info = out.setdefault("info", {})
+    info["process_to_runner_s"] = t_runner - t_process
 
     kind = devices[0].device_kind
     peaks = None
@@ -216,5 +218,9 @@ def run_cell(manifest: dict, root: str, workload: str, *, seed: int,
         device["window_s"] = reduced["window_s"]
         line["breakdown"] = {"device_ops": reduced["device_ops"],
                              "idle_gaps": reduced["idle_gaps"]}
-    return {"line": line, "info": out.get("info", {}),
+    # the run's wall from the process's start to here, all but the
+    # printing of its lines: the driver stops a run at 360 s
+    info["metrics_read_s"] = time.perf_counter() - t_ran
+    info["run_wall_s"] = time.perf_counter() - t_process
+    return {"line": line, "info": info,
             "why_incorrect": out.get("why_incorrect", [])}

@@ -15,8 +15,16 @@ finished requests complete — plus the reference comparison of this
 kind:
 
 * 8 finished requests of the window are compared, the 2 LONGEST among
-  them, teacher-forced on prompt + output, one row at a time (an 8k row
-  beside the served weights; the arena is released first);
+  them and the seed's draw of the others (the mix's
+  ``reference_requests`` / ``reference_longest`` where it gives them: a
+  count of the traffic file's, never of the program's speed; a mix of
+  ONE length says ``reference_longest: 0``, where "the longest" would
+  be the first offered on every seed, and all it compares is the
+  seed's draw), teacher-forced on prompt + output, one row at a time (an 8k
+  row beside the served weights; the arena is released first). The
+  rows' one program is lowered in set-up and compiled on a host thread
+  while the ramp runs (:class:`ReferenceRows`); nothing of it runs on
+  the device before the window has closed;
 * every emitted token must sit within ``LOGIT_TOL`` of the reference's
   top logit at its position — except where the reference's own top-k
   ROUTING is a near-tie on an expert held here (in some layer a held
@@ -76,16 +84,80 @@ def load_arch(name: str):
         "archs", f"{name}.py"))
 
 
-def _reference_check(arch, config, params, recs, max_len: int,
-                     max_out: int) -> tuple[list[str], dict]:
-    """See the module docstring. One jitted call per request at fixed
-    shapes (the row padded to ``max_len``, outputs to ``max_out``): it
-    compiles once."""
-    import jax
+class ReferenceRows:
+    """``arch.reference_rows`` at the cell's fixed shapes (the row
+    padded to ``max_len``, outputs to ``max_out``): ONE program, traced
+    and lowered where this is built (Python: the caller's thread, in
+    set-up), compiled on a host thread of its own once :meth:`start` is
+    called (the compiler releases the interpreter; nothing touches the
+    device), and called like the jitted function it stands for — the
+    first call waits for the compile. The executable is what
+    ``jax.jit`` would have compiled at its first call after the window:
+    the same lowering, so the same logits to the bit and the same entry
+    of the compilation cache."""
 
+    def __init__(self, arch, config, params, max_len: int, max_out: int):
+        import jax
+        t0 = time.perf_counter()
+        self._lowered = jax.jit(lambda p, ids, start: arch.reference_rows(
+            config, p, ids, start, max_out)).lower(
+                params, jax.ShapeDtypeStruct((max_len,), np.int32),
+                jax.ShapeDtypeStruct((), np.int32))
+        self.lower_s = time.perf_counter() - t0
+        self.compile_s = self.compiled_at = None
+        self._compiled = self._error = None
+        self._thread = threading.Thread(
+            target=self._compile, daemon=True,
+            name="bench-reference-compile")
+
+    def _compile(self) -> None:
+        t0 = time.perf_counter()
+        try:
+            self._compiled = self._lowered.compile()
+        except Exception as e:              # raised again by the caller
+            self._error = e
+        finally:
+            self._lowered = None            # the module's text goes
+            self.compiled_at = time.perf_counter()
+            self.compile_s = self.compiled_at - t0
+
+    def start(self) -> None:
+        self._thread.start()
+
+    def wait(self) -> None:
+        """Until the compile has ended (started here if it was not);
+        what it raised is raised here."""
+        if self._thread.ident is None:
+            self.start()
+        self._thread.join()
+        if self._error is not None:
+            raise self._error
+
+    def __call__(self, params, ids, start):
+        self.wait()
+        return self._compiled(params, ids, start)
+
+
+def pick_reference(finished: list, seed: int, n: int, longest: int
+                   ) -> list[int]:
+    """Indices into ``finished`` of the requests to compare: the
+    ``longest`` LONGEST prompts first (equal lengths: as offered), then
+    a draw from ``seed`` of the others, ``n`` in all (fewer where fewer
+    finished)."""
+    from benchmark import traffic
+    by_len = sorted(range(len(finished)),
+                    key=lambda i: -len(finished[i]["prompt"]))
+    pick = by_len[:min(longest, n)]
+    rest = [i for i in traffic.rng_for(seed, "reference")
+            .permutation(len(finished)) if i not in pick]
+    return pick + rest[:n - len(pick)]
+
+
+def _reference_check(arch, config, rows, params, recs, max_len: int
+                     ) -> tuple[list[str], dict]:
+    """See the module docstring. One call of ``rows`` (a
+    :class:`ReferenceRows`) per request at its fixed shapes."""
     window = arch.window(config)
-    rows = jax.jit(lambda p, ids, start: arch.reference_rows(
-        config, p, ids, start, max_out))
     why, compared, near, beyond, worst = [], 0, 0, 0, 0.0
     worst_near, largest, below = 0.0, [], []
     for r in recs:
@@ -136,7 +208,15 @@ def _reference_check(arch, config, params, recs, max_len: int,
                  "compared_prompt_lens": [len(r["prompt"]) for r in recs]}
 
 
-def run(ctx) -> dict:
+def run(ctx, reference_check=_reference_check, *,
+        rows_ahead: bool = True) -> dict:
+    """``reference_check(arch, config, rows, params, recs, max_len)`` is
+    the comparison of the runner's kind (``serve_arch_ties`` and
+    ``serve_arch_blocks`` hand in their own); ``rows`` is the ONE
+    :class:`ReferenceRows` built here, or ``None`` with ``rows_ahead``
+    false (a comparison whose rows have another signature compiles its
+    own)."""
+    t_run = time.perf_counter()
     import jax
     from benchmark import harness, traffic
     from hetu_tpu import telemetry
@@ -194,6 +274,13 @@ def run(ctx) -> dict:
         t0 = time.perf_counter()
         for w in warm:
             cli.submit(w)
+        # the reference's rows: traced and lowered here, while the
+        # engine's own thread compiles and warms the step (this thread
+        # would only sleep), so no Python of it runs beside the serving
+        # loop once the clock has started
+        rows = ReferenceRows(
+            arch, config, params, serve["max_len"],
+            int(mix["output_len"]["max"])) if rows_ahead else None
         while not all(w["done_t"] or w["failed"] for w in warm):
             if time.perf_counter() - t0 > 900:
                 raise RuntimeError("warm-up did not finish")
@@ -201,7 +288,8 @@ def run(ctx) -> dict:
         if any(w["failed"] for w in warm):
             raise RuntimeError(f"warm-up failed: "
                                f"{[w['failed'] for w in warm]}")
-        warm_s = time.perf_counter() - t0
+        t_warm = time.perf_counter()
+        warm_s = t_warm - t0
         traces_warm = trace_counts().get("serving_step", 0)
 
         origin = time.perf_counter()
@@ -222,6 +310,8 @@ def run(ctx) -> dict:
         sender = threading.Thread(target=send, daemon=True,
                                   name="bench-sender")
         sender.start()
+        if rows is not None:
+            rows.start()        # the compile, on the host, in the ramp
 
         def iters() -> float:
             return reg.counter("serving_attn_kernel_total").value(
@@ -286,6 +376,14 @@ def run(ctx) -> dict:
         if cli is not None:
             cli.close()
         srv.stop()
+        # the server's drainers (daemon threads, one a stream) end once
+        # they see the connection closed: a process that exits while
+        # they still run can abort in the interpreter's shutdown. Five
+        # seconds for all of them together, not each
+        until = time.perf_counter() + 5.0
+        for th in threading.enumerate():
+            if th.name.startswith("stream-drain-"):
+                th.join(timeout=max(0.0, until - time.perf_counter()))
     ctx.finish_trace_slice()
 
     for r in judged:
@@ -317,22 +415,27 @@ def run(ctx) -> dict:
                    f"number of tokens")
 
     # the reference: the LONGEST finished requests, and a seeded draw
-    # of the others
-    by_len = sorted(range(len(finished)),
-                    key=lambda i: -len(finished[i]["prompt"]))
-    pick = by_len[:LONGEST]
-    rest = [i for i in traffic.rng_for(ctx.seed, "reference")
-            .permutation(len(finished)) if i not in pick]
-    pick += rest[:REFERENCE_REQUESTS - len(pick)]
+    # of the others; how many of each is the traffic file's to say
+    pick = pick_reference(
+        finished, ctx.seed,
+        int(mix.get("reference_requests", REFERENCE_REQUESTS)),
+        int(mix.get("reference_longest", LONGEST)))
     arena_bytes, n_blocks = eng.pool.nbytes(), eng.pool.n_blocks
     slots = eng.pool.slots
     eng.pool.caches = None          # the reference's rows need the room
     t0 = time.perf_counter()
     checked = {}
+    if rows is not None:
+        rows.wait()             # it is never cancelled, only reported
+        margin = w_lo - rows.compiled_at
+        if margin < min(10.0, mix["ramp_s"] / 2):
+            harness.say(note=f"the reference's compile ended {margin} s "
+                             f"before the window opened (under 10 s: it "
+                             f"shares the window's host)")
     if finished:
-        more, checked = _reference_check(
-            arch, config, params, [finished[i] for i in pick],
-            serve["max_len"], int(mix["output_len"]["max"]))
+        more, checked = reference_check(
+            arch, config, rows, params, [finished[i] for i in pick],
+            serve["max_len"])
         why += more
         if window is not None and not checked["compared_beyond_window"]:
             harness.say(note="no compared position lay beyond the "
@@ -375,9 +478,25 @@ def run(ctx) -> dict:
         "engine_iterations": records["engine_iterations"],
         "preemptions": sum(t.get("preemptions", 0) for t in timings),
         "queue_depth_at_window_end": depth_at_end,
-        "drain_s": t_drained - w_hi, "warmup_s": warm_s,
-        "engine_build_and_requests_s": build_s,
+        # where the run's wall goes (NEW_CELLS.md rule 7), in order:
+        # ``process_to_runner_s`` (the harness's), the weights (the
+        # model's description and the one jitted ``model.init`` call),
+        # the engine and the requests, the warm-up, the ramp (from the
+        # warm-up's end to the window's start), the window, the drain,
+        # stopping the server and the tracer, the comparison
+        "weights_init_s": t_build - t_run,
+        "engine_build_and_requests_s": build_s, "warmup_s": warm_s,
+        "ramp_s": w_lo - t_warm, "window_s": w_hi - w_lo,
+        "drain_s": t_drained - w_hi, "teardown_s": t0 - t_drained,
         "reference_check_s": check_s, "reference": checked,
+        # which of the window's finished requests (offered order)
+        "reference_pick": [int(i) for i in pick[:len(finished)]],
+        **({} if rows is None else {
+            "reference_lower_s": rows.lower_s,
+            "reference_compile_s": rows.compile_s,
+            # it ended before the window opened, by this many seconds
+            "reference_compile_overlapped": rows.compiled_at < w_lo,
+            "reference_compile_to_window_s": w_lo - rows.compiled_at}),
         "arena_blocks": n_blocks, "slots": slots,
         "arena_bytes": arena_bytes,
         "kv_blocks_in_use_peak": max(kv_used),

@@ -2,8 +2,11 @@
 window, judging and audits, line for line (``runners/serve_arch.py``
 runs; nothing of it is copied) — for a model that GENERATES BY
 DIFFUSION OVER BLOCKS, with the comparison that way of generating needs
-installed in place of ``serve_arch._reference_check`` for this process
-(a run is a process), as ``serve_arch_ties`` installs its own.
+handed to ``serve_arch.run`` in place of ``serve_arch._reference_check``,
+as ``serve_arch_ties`` hands in its own. Its rows take a clean and a
+noised stream and are one program a prompt length's whole blocks, not
+``serve_arch.ReferenceRows``' one program a cell: they keep their own
+``jax.jit`` (``rows_ahead=False``) and compile at their first call.
 
 Why a kind of its own. A teacher-forced row says nothing of such a
 program: a token was not predicted from the tokens before it but from
@@ -164,12 +167,12 @@ def readings(arch, config, params, recs, max_len: int):
         sorted(largest, reverse=True)[:6]
 
 
-def reference_check(limits: dict, arch, config, params, recs,
-                    max_len: int, max_out: int) -> tuple[list[str], dict]:
+def reference_check(limits: dict, arch, config, rows, params, recs,
+                    max_len: int) -> tuple[list[str], dict]:
     """See the module docstring: :func:`readings` under the arch
-    file's limits."""
+    file's limits (``rows`` is ``None``: see there)."""
     from benchmark.reference import sdar_moe as reference
-    del max_out
+    del rows
     why, gap, margin, below, swap, largest = readings(
         arch, config, params, recs, max_len)
     tie = margin < limits["ROUTE_TOL"]
@@ -236,9 +239,8 @@ def run(ctx) -> dict:
         raise ValueError(f"the mix's generation and the configuration's "
                          f"serve.generation differ: {differ}")
     limits = {name: float(getattr(arch, name)) for name in LIMITS}
-    serve_arch._reference_check = functools.partial(reference_check,
-                                                    limits)
-    out = serve_arch.run(ctx)
+    out = serve_arch.run(ctx, functools.partial(reference_check, limits),
+                         rows_ahead=False)
     reg = telemetry.get_registry()
     passes = reg.counter("serving_diffusion_passes_total")
     out["records"]["diffusion"] = {

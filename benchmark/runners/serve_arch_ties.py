@@ -35,9 +35,10 @@ their SHARE instead:
 
 The arch file gives the four limits, each with its two readings
 (``LOGIT_TOL``, ``ROUTE_TOL``, ``NEAR_TIE_OVER_MAX``,
-``ROUTE_SHARE_MAX``). The comparison is installed in place of
-``serve_arch._reference_check`` for this process (a run is a process):
-``serve_arch.run`` then does everything else, unchanged.
+``ROUTE_SHARE_MAX``). The comparison is handed to ``serve_arch.run``
+in place of ``serve_arch._reference_check``; ``serve_arch.run`` does
+everything else, unchanged, the compile of the reference's rows
+included.
 """
 
 from __future__ import annotations
@@ -51,14 +52,11 @@ from benchmark.runners import serve_arch
 LIMITS = ("LOGIT_TOL", "ROUTE_TOL", "NEAR_TIE_OVER_MAX", "ROUTE_SHARE_MAX")
 
 
-def reference_check(limits: dict, arch, config, params, recs,
-                    max_len: int, max_out: int) -> tuple[list[str], dict]:
+def reference_check(limits: dict, arch, config, rows, params, recs,
+                    max_len: int) -> tuple[list[str], dict]:
     """``serve_arch._reference_check`` under the rule of this kind (see
-    the module docstring): the same rows, one jitted call a request."""
-    import jax
+    the module docstring): the same ``rows``, one call a request."""
     logit_tol, route_tol = limits["LOGIT_TOL"], limits["ROUTE_TOL"]
-    rows = jax.jit(lambda p, ids, start: arch.reference_rows(
-        config, p, ids, start, max_out))
     why, gaps, margins, below, largest = [], [], [], [], []
     for r in recs:
         toks = np.asarray(r["tokens"], np.int64)
@@ -114,6 +112,4 @@ def reference_check(limits: dict, arch, config, params, recs,
 def run(ctx) -> dict:
     arch = serve_arch.load_arch(ctx.config["arch"])
     limits = {name: float(getattr(arch, name)) for name in LIMITS}
-    serve_arch._reference_check = functools.partial(reference_check,
-                                                    limits)
-    return serve_arch.run(ctx)
+    return serve_arch.run(ctx, functools.partial(reference_check, limits))

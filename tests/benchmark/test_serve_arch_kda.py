@@ -12,7 +12,6 @@ the arithmetic of ``benchmark/flops_kda_mla_moe.py`` and
 import json
 import os
 import sys
-import time
 import types
 
 import numpy as np
@@ -25,6 +24,9 @@ sys.path.insert(0, ROOT)
 from benchmark import flops, flops_kda_mla_moe as fk, harness  # noqa: E402
 from benchmark import kda as kda_readers  # noqa: E402
 from benchmark.peaks import peaks_for  # noqa: E402
+
+sys.path.insert(0, HERE)
+import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_kda.json")
 CELL = "ling-3.0-flash-vl-ep8.video-8k-backlog"
@@ -53,11 +55,8 @@ def _config():
 
 
 def _run(trace=False):
-    import jax
-    return harness.run_cell(
-        harness.load_manifest(MANIFEST), ROOT, "tiny.video",
-        seed=2**31 + 41, seconds=1.5, trace=trace, devices=jax.devices(),
-        on_chip=False, t_process=time.perf_counter())
+    return tiny_run.run_cell(MANIFEST, "tiny.video", seed=2**31 + 41,
+                             trace=trace)
 
 
 @pytest.mark.parametrize("trace", [False, True])

@@ -8,7 +8,6 @@ arithmetic of ``benchmark/flops_mla_moe.py``."""
 import json
 import os
 import sys
-import time
 import types
 
 import numpy as np
@@ -21,6 +20,9 @@ sys.path.insert(0, ROOT)
 from benchmark import flops, flops_mla_moe, harness  # noqa: E402
 from benchmark.peaks import peaks_for  # noqa: E402
 
+sys.path.insert(0, HERE)
+import tiny_run  # noqa: E402
+
 MANIFEST = os.path.join(HERE, "manifest_mla.json")
 CELL = "kimi-vl-a3b-pp4.longdoc-backlog"
 COUNTED = {"moe_local_imbalance.longdoc", "engine_host_ms.longdoc",
@@ -29,11 +31,8 @@ COUNTED = {"moe_local_imbalance.longdoc", "engine_host_ms.longdoc",
 
 @pytest.mark.parametrize("trace", [False, True])
 def test_serve_arch_mla_cell_end_to_end_at_tiny_size(trace):
-    import jax
-    out = harness.run_cell(
-        harness.load_manifest(MANIFEST), ROOT, "tiny.longdoc",
-        seed=2**31 + 30, seconds=1.5, trace=trace, devices=jax.devices(),
-        on_chip=False, t_process=time.perf_counter())
+    out = tiny_run.run_cell(MANIFEST, "tiny.longdoc", seed=2**31 + 30,
+                            trace=trace)
     assert not out["why_incorrect"]
     line = out["line"]
     assert line["correct"] is True and line["failed"] == 0

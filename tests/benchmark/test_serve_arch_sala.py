@@ -2,15 +2,16 @@
 minicpm_sala.py``) under the ``serve_arch_ties`` runner: the model and
 its plain reference end to end at a tiny size through a manifest, a
 configuration and a mix of their own (new files HERE only), with and
-without ``--trace``; what ``BENCHMARK.json`` says of the cell and of the
-cells before it; the configuration against the catalog's row; and the
+without ``--trace``; how many requests the comparison takes (the traffic
+file's count), its rows compiled ahead of time, and the account of a
+run's wall; what ``BENCHMARK.json`` says of the cell and of the cells
+before it; the configuration against the catalog's row; and the
 arithmetic of ``benchmark/flops_minicpm_sala.py`` and
 ``benchmark/longctx.py``."""
 
 import json
 import os
 import sys
-import time
 import types
 
 import numpy as np
@@ -26,6 +27,7 @@ from benchmark.peaks import peaks_for  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import test_iteration_account as acc  # noqa: E402
+import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_sala.json")
 CELL = "minicpm-sala-pp2.longdoc-32k-backlog"
@@ -50,13 +52,14 @@ def _config():
         return json.load(f)
 
 
+def _run(workload="tiny.longctx", *, trace=False):
+    return tiny_run.run_cell(MANIFEST, workload, seed=2**31 + 39,
+                             trace=trace)
+
+
 @pytest.mark.parametrize("trace", [False, True])
 def test_serve_arch_sala_cell_end_to_end_at_tiny_size(trace):
-    import jax
-    out = harness.run_cell(
-        harness.load_manifest(MANIFEST), ROOT, "tiny.longctx",
-        seed=2**31 + 39, seconds=1.5, trace=trace, devices=jax.devices(),
-        on_chip=False, t_process=time.perf_counter())
+    out = _run(trace=trace)
     assert not out["why_incorrect"]
     line = out["line"]
     assert line["correct"] is True and line["failed"] == 0
@@ -65,7 +68,11 @@ def test_serve_arch_sala_cell_end_to_end_at_tiny_size(trace):
     assert ref["compared_positions"] > 0
     assert ref["max_logit_gap"] <= 1e-3     # float32 on both sides
     assert ref["near_ties_over_logit_tol"] == 0 and ref["limits"]
-    assert len(ref["compared_prompt_lens"]) == 8
+    # a mix without ``reference_requests``: the runner's 8, or all that
+    # finished, the 2 longest first
+    lens = ref["compared_prompt_lens"]
+    assert len(lens) == 8 <= out["info"]["n_finished"]
+    assert lens[:2] == sorted(lens, reverse=True)[:2]
     # K and V pages of one kv head each, the stride means, the states:
     # 2 sparse layers x 65 blocks, 3 lightning layers x 4 slots, float32
     pages = 2 * 65 * (2 * 4) * 16 * 4
@@ -94,21 +101,26 @@ def test_a_planted_control_is_refused_through_the_harness(control):
     its weights at 0.16, the 0.02 of the published width scaled to 64
     columns: at 0.02 the mixers move a tiny model's logits by less
     than ``LOGIT_TOL``.)"""
-    import jax
     import jax.numpy as jnp
     from benchmark.reference import minicpm_sala as reference
     planted = {"operands": {"operands": jnp.float8_e4m3fn},
                "forced_only": {"forced_only": True},
                "no_decay": {"no_decay": True}}[control]
+    # the two FAR controls at the compared count and pick of the cell's
+    # own mix: 2 requests of one length, the seed's draw (64 positions;
+    # off near-ties they read 0.26-2.7 against LOGIT_TOL 0.02 on four
+    # seeds). The nearest one reads a SHARE, which 64 positions of this
+    # size do not hold (refused on 2 seeds of 4): it keeps the eight
+    # requests it had, and is refused at the cell's 512 positions on the
+    # chip (PERF.md section 6, PR 50)
+    workload, n = ("tiny.longctx", 8) if control == "operands" \
+        else ("tiny.longctx-two", 2)
     reference.CONTROL.update(planted)
     try:
-        out = harness.run_cell(
-            harness.load_manifest(MANIFEST), ROOT, "tiny.longctx",
-            seed=2**31 + 39, seconds=1.5, trace=False,
-            devices=jax.devices(), on_chip=False,
-            t_process=time.perf_counter())
+        out = _run(workload)
     finally:
         reference.CONTROL.clear()
+    assert len(out["info"]["reference"]["compared_prompt_lens"]) == n
     assert out["line"]["correct"] is False and out["line"]["failed"] == 0
     assert out["why_incorrect"]
     assert "below the float32 reference's top logit" in \
@@ -116,6 +128,135 @@ def test_a_planted_control_is_refused_through_the_harness(control):
     ref = out["info"]["reference"]
     assert max(ref["max_logit_gap"],
                ref["max_logit_gap_at_near_ties"]) > 0.1
+
+
+@pytest.fixture(scope="module")
+def two():
+    """One run of the mix that says ``reference_requests: 2`` and
+    ``reference_longest: 0`` as the cell's does (one length; and a ramp
+    of 3 s for the reference's compile to end in)."""
+    return _run("tiny.longctx-two")
+
+
+def test_the_traffic_file_says_how_many_requests_are_compared(two):
+    """... and WHICH: the mix's ``reference_longest: 0`` goes through
+    the run, so both are the seed's draw of the finished requests."""
+    from benchmark import traffic
+    assert two["line"]["correct"] is True and not two["why_incorrect"]
+    info = two["info"]
+    assert info["n_finished"] > 2
+    lens = info["reference"]["compared_prompt_lens"]
+    assert len(lens) == 2
+    assert info["reference"]["compared_positions"] > 0
+    drawn = traffic.rng_for(2**31 + 39, "reference").permutation(
+        info["n_finished"])[:2]
+    assert info["reference_pick"] == drawn.tolist()
+
+
+def _finished(lens):
+    return [{"prompt": np.zeros(n, np.int32)} for n in lens]
+
+
+def test_a_mix_without_the_keys_picks_what_it_picked_before():
+    """The pick of the parent's lines (the 2 longest, then the seed's
+    draw of the others, 8 in all), pinned for one seed."""
+    from benchmark.runners import serve_arch
+    lens = [24, 44, 17, 31, 44, 12, 29, 38, 21, 35, 26, 19]
+    assert serve_arch.pick_reference(
+        _finished(lens), 2**31 + 39, serve_arch.REFERENCE_REQUESTS,
+        serve_arch.LONGEST) == [1, 4, 10, 8, 5, 2, 3, 9]
+    assert (serve_arch.REFERENCE_REQUESTS, serve_arch.LONGEST) == (8, 2)
+    # fewer finished than asked for: all of them, the longest first
+    assert serve_arch.pick_reference(
+        _finished([5, 9, 7]), 2**31 + 39, 8, 2)[:2] == [1, 2]
+
+
+def _cell_mix():
+    with open(os.path.join(
+            ROOT, "benchmark/traffic/longdoc-fixed-32k-backlog.json")) as f:
+        return json.load(f)
+
+
+#: the cell's pick at equal lengths, pinned for one seed
+PICKED = {3: [1, 0], 7: [1, 5], 9: [1, 5]}
+
+
+@pytest.mark.parametrize("n_finished", [3, 7, 9])
+def test_two_compared_requests_whatever_finished(n_finished):
+    """The cell's own mix (``reference_requests: 2``,
+    ``reference_longest: 0``): a program that finishes more requests in
+    its window is compared on as many as a slower one, and which two is
+    the seed's draw — among equal lengths "the 2 longest" are the first
+    two offered on every seed (the runner's default, for mixes whose
+    lengths differ), and the cell must not compare those alone."""
+    from benchmark.runners import serve_arch
+    mix = _cell_mix()
+    n, longest = mix["reference_requests"], mix["reference_longest"]
+    finished = _finished([mix["prompt_len"]["value"]] * n_finished)
+    seed = 2**31 + 39
+    assert serve_arch.pick_reference(
+        finished, seed, n, serve_arch.LONGEST) == [0, 1]
+    drawn = serve_arch.pick_reference(finished, seed, n, longest)
+    assert drawn == PICKED[n_finished]
+    assert drawn == serve_arch.pick_reference(finished, seed, n, longest)
+    # over the seeds every finished request is compared
+    seen = {i for s in range(40) for i in serve_arch.pick_reference(
+        finished, seed + s, n, longest)}
+    assert seen == set(range(n_finished))
+
+
+def test_rows_compiled_ahead_are_the_jitted_rows_to_the_bit(two):
+    """``serve_arch.ReferenceRows`` (lowered, then compiled on a thread)
+    against ``jax.jit`` at its first call, on one tiny request; and the
+    run says where the compile went."""
+    import jax
+    from benchmark.runners import serve_arch
+    with open(os.path.join(HERE, "configs/sala-tiny.json")) as f:
+        config = json.load(f)
+    arch = serve_arch.load_arch(config["arch"])
+    params = arch.build(config).init(jax.random.key(39),
+                                     dtype=jax.numpy.float32)
+    max_len, max_out = config["serve"]["max_len"], 16
+    ids = np.zeros(max_len, np.int32)
+    ids[:40] = np.random.default_rng(39).integers(
+        1, config["vocab_size"], 40)
+    start = np.int32(29)
+    ahead = serve_arch.ReferenceRows(arch, config, params, max_len,
+                                     max_out)
+    assert ahead.compiled_at is None    # nothing compiled before start
+    lg, margin = ahead(params, ids, start)
+    lg0, margin0 = jax.jit(lambda p, i, s: arch.reference_rows(
+        config, p, i, s, max_out))(params, ids, start)
+    assert np.array_equal(np.asarray(lg), np.asarray(lg0))
+    assert np.array_equal(np.asarray(margin), np.asarray(margin0))
+    assert lg.shape == (max_out, config["vocab_size"])
+    # the run: the compile started with the ramp and is accounted for
+    info = two["info"]
+    assert info["reference_compile_s"] > 0 and info["reference_lower_s"] > 0
+    assert info["reference_compile_overlapped"] is \
+        (info["reference_compile_to_window_s"] > 0)
+    # it started with the ramp, so it ended ramp - compile before the
+    # window; where the compile was shorter than the ramp it overlapped
+    # (held to what the run measured, not to this machine's speed)
+    if info["reference_compile_s"] < info["ramp_s"] - 1.0:
+        assert info["reference_compile_overlapped"] is True
+
+
+WALL = ("process_to_runner_s", "weights_init_s",
+        "engine_build_and_requests_s", "warmup_s", "ramp_s", "window_s",
+        "drain_s", "teardown_s", "reference_check_s", "metrics_read_s")
+
+
+def test_a_run_says_where_its_wall_went(two):
+    info = two["info"]
+    for key in WALL + ("reference_compile_s", "run_wall_s",
+                       "reference_compile_overlapped"):
+        assert key in info, key
+    # the window the run was asked for (``tiny_run`` doubles it where a
+    # loaded machine finished too few), not this machine's 1.5 s
+    assert info["window_s"] == two["window_asked_s"]
+    assert 3.0 <= info["ramp_s"] < 4.0
+    assert abs(info["run_wall_s"] - sum(info[k] for k in WALL)) < 1.0
 
 
 def test_manifest_names_what_the_longctx_cell_needs():
@@ -132,6 +273,10 @@ def test_manifest_names_what_the_longctx_cell_needs():
     assert mix["kind"] == "serve_arch_ties" and mix["schedule_seed"] == 39
     assert mix["arrivals"] == {"process": "backlog", "count": 160}
     assert (mix["ramp_s"], mix["drain_s"]) == (120, 0)
+    # the comparison's count is the file's, and both are the seed's
+    # draw: every request has the same lengths, none is the longest
+    assert mix["reference_requests"] == 2
+    assert mix["reference_longest"] == 0
     assert (mix["prompt_len"]["dist"], mix["prompt_len"]["value"]) == \
         ("fixed", 32000)
     assert (mix["output_len"]["dist"], mix["output_len"]["value"],

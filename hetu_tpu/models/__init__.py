@@ -1,7 +1,8 @@
 """Model zoo: GPT-2, Llama, Command A+ (cohere2_moe) and latent-attention
-(MLA) expert decoders, MiniCPM-SALA, delta-rule / latent hybrids and
-SDAR-MoE, which generates by diffusion over blocks (``mla_moe``,
-``minicpm_sala``, ``kda_mla_moe``, ``sdar_moe``: loaded on first use,
+(MLA) expert decoders, MiniCPM-SALA, delta-rule / latent hybrids,
+SDAR-MoE, which generates by diffusion over blocks, and Brumby, whose
+every layer is power retention (``mla_moe``, ``minicpm_sala``,
+``kda_mla_moe``, ``sdar_moe``, ``brumby``: loaded on first use,
 so that the cells that never build one do not pay for its import).
 
 The four drawn decoders share one shell (``decoder.DecoderLM``); the
@@ -33,7 +34,9 @@ _LAZY = {"MLAMoEConfig": "hetu_tpu.models.mla_moe",
          "KDAMLAMoEConfig": "hetu_tpu.models.kda_mla_moe",
          "KDAMLAMoEForCausalLM": "hetu_tpu.models.kda_mla_moe",
          "SDARMoEConfig": "hetu_tpu.models.sdar_moe",
-         "SDARMoEForCausalLM": "hetu_tpu.models.sdar_moe"}
+         "SDARMoEForCausalLM": "hetu_tpu.models.sdar_moe",
+         "BrumbyConfig": "hetu_tpu.models.brumby",
+         "BrumbyForCausalLM": "hetu_tpu.models.brumby"}
 
 
 def __getattr__(name):
@@ -49,4 +52,5 @@ __all__ = ["GPTConfig", "GPTLMHeadModel", "LlamaConfig", "BertConfig", "BertMode
            "MiniCPMSALAConfig", "MiniCPMSALAForCausalLM",
            "KDAMLAMoEConfig", "KDAMLAMoEForCausalLM",
            "SDARMoEConfig", "SDARMoEForCausalLM",
+           "BrumbyConfig", "BrumbyForCausalLM",
            "generate", "decode", "init_kv_caches"]

@@ -1402,11 +1402,14 @@ def _gain(params, name, x, eps, dtype):
     return rms_norm(x.astype(jnp.float32), params[name], eps).astype(dtype)
 
 
-def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack):
+def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack,
+                 paged: bool = True):
     """The rows of either cached lane, flat: ``(u (N, E), pos (N,),
     valid (N,), tables (N, W), slot (N,) or None)`` — the decode rows
     (row ``r`` is slot ``r``: ``slot`` is ``None``) or a prefill pack
-    (``pack["slot"]`` names each token's slot)."""
+    (``pack["slot"]`` names each token's slot). ``paged=False``: a
+    mixer of a stack that keeps no token rows at all is handed no
+    tables."""
     if pack is not None:
         if "slot" not in pack:
             raise SlotStateNotSupported(
@@ -1420,7 +1423,7 @@ def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack):
             "the verify lane (spec_depth > 0): a slot's rows beyond its "
             "first would advance a state that a rejected draft cannot "
             "roll back")
-    if block_tables is None or slot_mask is None:
+    if (paged and block_tables is None) or slot_mask is None:
         raise SlotStateNotSupported(
             "this attention decodes from the paged arena and the slot "
             "states, per slot (block_tables= and slot_mask=); it has no "
@@ -2142,6 +2145,193 @@ class KimiDeltaAttention(Module):
             (state, tail), {"kda_steps": steps}
 
 
+def count_retention(values, tokens=None) -> None:
+    """:class:`PowerRetention`'s ``layer_stats`` on the host: ``values
+    (layers, 3)`` — of each layer's call ``[decode rows, prefill rows,
+    runs]`` (the live rows its update advanced; the valid tokens and the
+    runs its scan held: a state is read and written once a RUN) — into
+    ``retention_rows_total{lane}`` and ``retention_runs_total``, summed
+    over layer calls."""
+    import numpy as np
+    from hetu_tpu import telemetry
+    dec, pre, runs = np.asarray(values, np.int64).sum(axis=0).tolist()
+    reg = telemetry.get_registry()
+    rows = reg.counter(
+        "retention_rows_total",
+        "token rows the power-retention layers advanced a state by, "
+        "summed over layer calls")
+    if dec:
+        rows.inc(float(dec), lane="decode")
+    if pre:
+        rows.inc(float(pre), lane="prefill")
+        reg.counter(
+            "retention_runs_total",
+            "runs (a slot's tokens in one pack) the retention scan "
+            "held: a state tile set read and written once each, summed "
+            "over layer calls").inc(float(runs))
+
+
+class PowerRetention(Module):
+    """Power retention of degree 2 (``ops.retention``): with ``u`` the
+    block's normed input, ``q = RoPE(RMSNorm_head(u W_q))`` over ``H``
+    heads, ``k`` likewise and ``v = u W_v`` over ``Hkv`` (a kv head
+    serves ``H / Hkv`` query heads), a gate a kv head and token ``log g
+    = logsigmoid(u W_g + b_g)``; per kv head a float32 state ``S_t = g_t
+    S_{t-1} + phi(k_t / d^{1/4}) [v_t, 1]^T`` of the keys' symmetric
+    second tensor power against the values and a normaliser, ``y_t =
+    n_t / (z_t + eps)``, ``[n_t, z_t] = phi(q_t / d^{1/4})^T S_t``; then
+    ``W_o``. No softmax, no token row: what is cached is a SLOT's, ONE
+    leaf ``(layers, slots, Hkv, d / 2 + 1, R, d)`` float32
+    (:meth:`init_leaves`; the tiles of ``ops.retention.phi_tiles`` —
+    36.2 MB a layer and slot at 8 kv heads of 128, the 8,256 features
+    of the model in 8,320 places and 129 value rows in 136), whatever
+    the context.
+
+    The decode rows advance their slot's state by a token IN PLACE
+    (``hetu.retention_update``: ``ops.retention_pallas.
+    hetu_retention_update``), a prefill pack's tokens theirs in chunks
+    with a run's state in VMEM (``hetu.retention_scan``:
+    ``hetu_retention_scan``), a slot whose run starts at position 0
+    from zeros; both kernels are interpreted on the CPU and neither has
+    a ``jax.numpy`` form behind it. ``b_g`` is drawn so that the mean
+    gate runs from ``gate_means[0]`` on a layer's first kv head to
+    ``gate_means[1]`` on its last (evenly in the logit): horizons from
+    a hundred tokens to the whole context in one layer, so a program
+    that drops the gate or loses a state must differ."""
+
+    cache_leaves = 1
+    latent = False
+    #: a cached call's third result (:func:`count_retention`)
+    layer_stats = {"retention": ((3,), jnp.int32, count_retention)}
+
+    def __init__(self, embed_dim: int, num_heads: int, *,
+                 num_kv_heads: int, head_dim: int,
+                 rope_theta: float = 1e6, max_positions: int = 4096,
+                 norm_eps: float = 1e-6, qk_gain: float = 1.0,
+                 eps: float = 1e-6, gate_means=(0.99, 0.99999),
+                 init=None):
+        super().__init__()
+        from hetu_tpu.nn.module import constant_init
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} query heads over "
+                             f"{num_kv_heads} kv heads")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.norm_eps, self.eps = head_dim, norm_eps, eps
+        self.min_window = None
+        init = init or normal_init(0.02)
+        self.q_proj = ColumnParallelLinear(
+            embed_dim, num_heads * head_dim, bias=False, init=init,
+            axis="heads", out_kind="hidden")
+        for name in ("k_proj", "v_proj"):
+            setattr(self, name, ColumnParallelLinear(
+                embed_dim, num_kv_heads * head_dim, bias=False, init=init,
+                axis="heads", out_kind="hidden"))
+        self.gate_proj = ColumnParallelLinear(
+            embed_dim, num_kv_heads, bias=False, init=init, axis=None,
+            out_kind="hidden")
+        self.out_proj = RowParallelLinear(
+            num_heads * head_dim, embed_dim, bias=False, init=init,
+            axis="heads")
+        self.param("q_gain", (head_dim,), constant_init(qk_gain))
+        self.param("k_gain", (head_dim,), constant_init(qk_gain))
+        lo, hi = (math.log(g / (1.0 - g)) for g in gate_means)
+
+        def gate_bias(key, shape, dtype):
+            del key
+            return jnp.linspace(lo, hi, shape[0]).astype(dtype)
+        # (float32 whatever the weights are served in: it sets horizons)
+        self.param("gate_bias", (num_kv_heads,), gate_bias,
+                   dtype=jnp.float32)
+        self._rope = rope_frequencies(head_dim, max_positions,
+                                      theta=rope_theta)
+
+    def kv_leaf_shapes(self) -> tuple:
+        """No leaf a token: the state is a slot's."""
+        return ()
+
+    def _tiles(self) -> tuple:
+        from hetu_tpu.ops.retention import feature_rows, value_rows
+        return (self.num_kv_heads, feature_rows(self.head_dim),
+                value_rows(self.head_dim), self.head_dim)
+
+    def state_bytes(self) -> int:
+        """Bytes a slot's state holds in one layer."""
+        return 4 * math.prod(self._tiles())
+
+    def init_leaves(self, layers: int, slots: int, sharding=None) -> tuple:
+        return (jnp.zeros((layers, slots) + self._tiles(), jnp.float32,
+                          device=sharding),)
+
+    def _inputs(self, params, u, positions):
+        """``u (b, s, E)``, ``positions (b, s)`` -> ``q (b, s, H, d)``,
+        ``k``, ``v (b, s, Hkv, d)`` and ``log g (b, s, Hkv)`` float32."""
+        dt = self.compute_dtype()
+        cos, sin = self._rope
+
+        def heads(proj, n):
+            return getattr(self, proj)(params[proj], u).reshape(
+                u.shape[:-1] + (n, self.head_dim))
+        q = _gain(params, "q_gain", heads("q_proj", self.num_heads),
+                  self.norm_eps, dt)
+        k = _gain(params, "k_gain", heads("k_proj", self.num_kv_heads),
+                  self.norm_eps, dt)
+        gate = self.gate_proj(params["gate_proj"], u).astype(jnp.float32)
+        return (apply_rotary(q, cos, sin, positions=positions),
+                apply_rotary(k, cos, sin, positions=positions),
+                heads("v_proj", self.num_kv_heads),
+                jax.nn.log_sigmoid(gate + params["gate_bias"]))
+
+    def _output(self, params, y):
+        """``y (..., H, d)`` float32."""
+        return self.out_proj(
+            params["out_proj"], y.reshape(y.shape[:-2] + (-1,))
+            .astype(self.compute_dtype()))
+
+    def __call__(self, params, x, *, positions=None, segment_ids=None,
+                 attn_impl: str = "auto", kv_cache=None, slot_mask=None,
+                 block_tables=None, row_mask=None, attn_kernel="reference",
+                 pack=None, return_kv: bool = False):
+        del attn_impl, attn_kernel       # no attention kernel here
+        b, s, _ = x.shape
+        if kv_cache is None:
+            if return_kv or segment_ids is not None:
+                raise SlotStateNotSupported(
+                    "return_kv (the CP-prefill lane) and packed "
+                    "documents: power retention has no (k, v) to hand "
+                    "out, and its whole-sequence forward is one document "
+                    "a row")
+            from hetu_tpu.ops.retention import retention_recurrence
+            if positions is None:
+                positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+            y = jax.vmap(lambda *t: retention_recurrence(
+                *t, eps=self.eps)[0])(*self._inputs(params, x, positions))
+            return self._output(params, y)
+        from hetu_tpu.ops.retention_pallas import (
+            hetu_retention_scan, hetu_retention_update,
+        )
+        (buf,), layer = kv_cache
+        _, pos, valid, _, slot = _cached_rows(
+            x, positions, slot_mask, block_tables, row_mask, pack,
+            paged=False)
+        q, k, v, log_g = self._inputs(params, x, positions)
+        zero = jnp.zeros((), jnp.int32)
+        if slot is None:
+            with jax.named_scope("hetu.retention_update"):
+                y, buf = hetu_retention_update(
+                    q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], buf, valid,
+                    eps=self.eps, layer=layer)
+            y = y[:, None]
+            stats = jnp.stack([jnp.sum(valid, dtype=jnp.int32), zero, zero])
+        else:
+            with jax.named_scope("hetu.retention_scan"):
+                y, buf, runs = hetu_retention_scan(
+                    q[0], k[0], v[0], log_g[0], buf, slot, pos, valid,
+                    eps=self.eps, layer=layer, return_runs=True)
+            y = y[None]
+            stats = jnp.stack([zero, jnp.sum(valid, dtype=jnp.int32), runs])
+        return self._output(params, y), (buf,), {"retention": stats}
+
+
 def remat_policy(name: str):
     """Map a Strategy remat/offload name to a ``jax.checkpoint`` policy.
 
@@ -2558,6 +2748,8 @@ class StackedBlocks(Module):
     # several kinds)
     #: a recurrent state per SLOT beside the pages?
     slot_state = False
+    #: token rows in pages? (a :class:`LayerStack` may have none)
+    paged = True
 
     def init_paged_caches(self, n_blocks: int, block_size: int, dtype,
                           slots: int = 0, sharding=None) -> tuple:
@@ -2709,6 +2901,9 @@ class LayerStack(Module):
     that keep a state per SLOT, each group in the order the kinds first
     appear. ``block`` is the first paged kind's first scanned block:
     its attention speaks for the arena (heads, row width, page size).
+    Where NO kind is paged (``paged`` is False: every layer keeps a
+    state a slot) the caches are the slot leaves alone and ``block`` is
+    the first kind's first scanned block.
 
     The parameters are ``dense.<i>`` and ``runs.<i>``; ``lone_run``
     names the ONE run of a stack that stores it under that name
@@ -2757,14 +2952,19 @@ class LayerStack(Module):
         self._mixers = {k: mixers[k] for k in
                         paged + [k for k in mixers if k not in paged]}
         self.slot_state = len(paged) < len(mixers)
+        #: does any layer keep token rows in pages? A stack whose every
+        #: kind keeps a state a slot has no arena at all
+        self.paged = bool(paged)
+        head = paged[0] if paged else next(iter(self._mixers))
         self._block = next(
             (r.block for r, k in zip(self._runs, self.run_kinds)
-             if paged and k == paged[0]), None)
+             if k == head), None)
         if self._block is None:
             raise ValueError(
                 f"{kinds} behind {n_dense} unscanned layers: at least "
-                f"one scanned layer of a kind that keeps token rows in "
-                f"pages (its attention speaks for the arena)")
+                f"one scanned layer of the kind that speaks for the "
+                f"caches ({head!r}: the first that keeps token rows in "
+                f"pages, or the first kind where none does)")
         #: every block's ``layer_stats`` (like names are like stats)
         self.layer_stats = {}
         for blk, _ in self._reporting():
@@ -2816,8 +3016,10 @@ class LayerStack(Module):
                 for leaf, b in mixer.row_bytes(itemsize).items():
                     rows[leaf] = rows.get(leaf, 0) + b * n
             else:
+                # (a slot's state is what admission prices where no
+                # layer has rows: over all its layers, always)
                 state["slot"] = state.get("slot", 0) \
-                    + mixer.state_bytes() * n
+                    + mixer.state_bytes() * self.layers_of[kind]
         return {"row": rows, "state": state}
 
     def refuse_serving(self, **asked) -> None:

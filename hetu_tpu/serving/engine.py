@@ -102,7 +102,7 @@ from hetu_tpu.serving.block_diffusion import (
     REMASKING, denoise_slots, first_block,
 )
 from hetu_tpu.serving.kv_pool import (
-    BlockManager, HostSpillArena, KVPool, SpillEntry,
+    BlockManager, HostSpillArena, KVPool, NoBlocks, SpillEntry,
 )
 from hetu_tpu.serving.prefix_cache import PrefixCache
 from hetu_tpu.serving.scheduler import Request, SamplingParams, Scheduler
@@ -149,7 +149,8 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
         attn_kernel=reg.counter(
             "serving_attn_kernel_total",
             "fused decode/verify steps by attention path (paged = "
-            "Pallas block-table kernel, reference = XLA gather)"),
+            "Pallas block-table kernel, reference = XLA gather, none = "
+            "a step without attention: no layer keeps token rows)"),
         prefill_kernel=reg.counter(
             "prefill_attn_kernel_total",
             "prefill-lane executions by attention path (flash = "
@@ -269,6 +270,11 @@ def _bind_metrics(reg) -> types.SimpleNamespace:
         kv_in_use=reg.gauge(
             "serving_kv_blocks_in_use",
             "live KV blocks (slot tables + prefix cache)"),
+        slots=reg.gauge(
+            "serving_slots",
+            "slots by state (live = holding a request, free): what an "
+            "engine without an arena admits by — a slot's recurrent "
+            "state is the whole price of a request there"),
         spill_arena=reg.gauge(
             "serving_kv_spill_arena_blocks",
             "KV blocks parked in the host spill arena (preempted "
@@ -429,6 +435,11 @@ class ServingEngine:
         # other model and off for such a one; asked for, they refuse
         # like the rest
         self._slot_state = model.blocks.slot_state
+        # ... and one whose layers ALL do has no arena: no page, no
+        # table, no block ledger; a free slot is the whole price of a
+        # request and ``max_len`` bounds positions only. Read from what
+        # the stack's leaves say (``blocks.paged``), never from a name
+        self._paged = getattr(model.blocks, "paged", True)
         # a model that generates by diffusion over blocks states it
         # (``model.generation``); the decode lane takes its shape from
         # that: B rows a slot, denoised in place and committed together
@@ -524,6 +535,11 @@ class ServingEngine:
         if slots is None:
             if hbm_budget_bytes is None:
                 raise ValueError("pass slots= or hbm_budget_bytes=")
+            if not self._paged:
+                raise ValueError(
+                    "hbm_budget_bytes= sizes an arena of pages; a model "
+                    "whose layers keep no token rows is sized in slots: "
+                    "pass slots= (kv_state_bytes{kind=slot} a slot)")
             if kv_blocks is not None:
                 raise ValueError(
                     "kv_blocks= conflicts with hbm_budget_bytes= "
@@ -593,11 +609,14 @@ class ServingEngine:
         #: stale prefills can never survive a swap
         self.weight_version = 0
         self.prefill_chunk = int(prefill_chunk)  # PACK budget/iteration
-        self.blocks = BlockManager(self.pool.n_blocks)
+        self.blocks = BlockManager(self.pool.n_blocks) if self._paged \
+            else NoBlocks()
         self.prefix_cache: Optional[PrefixCache] = PrefixCache(
             self.pool.block_size, self.blocks) if prefix_cache else None
         self.scheduler = Scheduler(
-            self.pool.slots, self.pool.max_len, blocks=self.blocks,
+            self.pool.slots, self.pool.max_len,
+            # (no arena: no ledger, a free slot is the whole price)
+            blocks=self.blocks if self._paged else None,
             prefix_cache=self.prefix_cache,
             block_size=self.pool.block_size,
             long_max_len=long_max_len, class_weights=class_weights,
@@ -776,10 +795,11 @@ class ServingEngine:
         from hetu_tpu.ops.attention import resolve_decode_kernel
         tp = plan.strategy.tp if plan is not None else 1
         _attn_mod = model.blocks.block.attn
+        # (a step without attention resolves nothing: "none")
         self.attn_kernel = resolve_decode_kernel(
             attn_kernel, tp=tp, site="serving_decode",
             num_heads=_attn_mod.num_heads,
-            num_kv_heads=_attn_mod.num_kv_heads)
+            num_kv_heads=_attn_mod.num_kv_heads) if self._paged else "none"
         # prefill lanes: "flash" packs the chunk as ONE row — intra-pack
         # flash attention with segment isolation, LSE-combined with each
         # token's arena history through its block table; "reference" is
@@ -811,36 +831,10 @@ class ServingEngine:
         # the chunk (ops.paged_pallas.paged_history_attention). Tile
         # size from the head shapes; the tile count is static: a pack
         # holds at most _fin_cap runs, each may open one more tile
-        from hetu_tpu.ops.paged_pallas import (
-            history_tile_count, history_tile_pages, history_tile_rows,
-            pack_history_tiles, table_chunks,
-        )
-        self._pack_tiles = pack_history_tiles
-        shapes = (_attn_mod.num_heads // _attn_mod.num_kv_heads,
-                  _attn_mod.head_dim, _attn_mod.num_kv_heads,
-                  self.pool.block_size)
-        itemsize = jnp.dtype(self.pool.caches[0].dtype).itemsize
-        self._hist_tile = history_tile_rows(*shapes, kv_itemsize=itemsize)
-        self._hist_tiles = history_tile_count(
-            self.prefill_chunk, self._hist_tile, self._fin_cap) \
-            if prefill_attn != "reference" \
-            and self.attn_kernel == "paged" \
-            and _attn_mod.history_tiles else 0
-        # a grid step of that read: a key tile of as many pages as fit
-        # beside the cell — its span in positions and a table's steps,
-        # for serving_prefill_hist_chunks_total
-        pages, self._hist_steps = table_chunks(
-            W, self.pool.block_size, history_tile_pages(
-                *shapes, tile_rows=self._hist_tile, kv_itemsize=itemsize,
-                latent=_attn_mod.latent))
-        self._hist_span = pages * self.pool.block_size
-        # the decode rows' paged call walks the live (slot, chunk)
-        # pairs (ops.paged_pallas.decode_work_list): a chunk's span in
-        # positions and a table's chunks, for
-        # serving_decode_chunks_total (0: the gather path has no list)
-        pages, steps = table_chunks(W, self.pool.block_size)
-        self._chunk_span = pages * self.pool.block_size
-        self._chunk_steps = steps if self.attn_kernel == "paged" else 0
+        self._hist_tile = self._hist_tiles = self._hist_steps = 0
+        self._hist_span = self._chunk_span = self._chunk_steps = 0
+        if self._paged:
+            self._size_history_tiles(_attn_mod, W, prefill_attn)
         # W8A8 decode-FFN compute: per-layer A/B as a (layers,) bool
         # baked into the step. Gated on the int8 arena — an operator
         # who priced the KV at 8 bits has already accepted 8-bit error
@@ -896,6 +890,39 @@ class ServingEngine:
         self._cp_fn = self._build_cp_prefill() \
             if self._cp_buckets is not None else None
         self._spill_fn, self._resume_fn = self._build_spill_resume()
+
+    def _size_history_tiles(self, _attn_mod, W, prefill_attn) -> None:
+        """What the paged reads walk, from the arena's head shapes."""
+        from hetu_tpu.ops.paged_pallas import (
+            history_tile_count, history_tile_pages, history_tile_rows,
+            pack_history_tiles, table_chunks,
+        )
+        self._pack_tiles = pack_history_tiles
+        shapes = (_attn_mod.num_heads // _attn_mod.num_kv_heads,
+                  _attn_mod.head_dim, _attn_mod.num_kv_heads,
+                  self.pool.block_size)
+        itemsize = jnp.dtype(self.pool.caches[0].dtype).itemsize
+        self._hist_tile = history_tile_rows(*shapes, kv_itemsize=itemsize)
+        self._hist_tiles = history_tile_count(
+            self.prefill_chunk, self._hist_tile, self._fin_cap) \
+            if prefill_attn != "reference" \
+            and self.attn_kernel == "paged" \
+            and _attn_mod.history_tiles else 0
+        # a grid step of that read: a key tile of as many pages as fit
+        # beside the cell — its span in positions and a table's steps,
+        # for serving_prefill_hist_chunks_total
+        pages, self._hist_steps = table_chunks(
+            W, self.pool.block_size, history_tile_pages(
+                *shapes, tile_rows=self._hist_tile, kv_itemsize=itemsize,
+                latent=_attn_mod.latent))
+        self._hist_span = pages * self.pool.block_size
+        # the decode rows' paged call walks the live (slot, chunk)
+        # pairs (ops.paged_pallas.decode_work_list): a chunk's span in
+        # positions and a table's chunks, for
+        # serving_decode_chunks_total (0: the gather path has no list)
+        pages, steps = table_chunks(W, self.pool.block_size)
+        self._chunk_span = pages * self.pool.block_size
+        self._chunk_steps = steps if self.attn_kernel == "paged" else 0
 
     def _register_device_scopes(self, args) -> None:
         """At the first dispatch: keep the fused step's ABSTRACT
@@ -1024,6 +1051,7 @@ class ServingEngine:
         w8a8_mask = self._w8a8_mask
         flash_lane = self.prefill_attn != "reference"
         wants_slots = self._slot_state
+        paged = self._paged
         pack_impl = self._pack_impl
         tile_rows = self._hist_tile
         # the draftsman's q rows: host-only draftsmen (and no
@@ -1063,9 +1091,13 @@ class ServingEngine:
             # device scopes (telemetry/device_scopes.py) go around the
             # cond CALLS: a decorated branch function costs seconds of
             # tracing on the chip's host (PERF.md, PR 24)
-            with jax.named_scope("hetu.kv_arena"):
-                caches = jax.lax.cond(cow["run"], apply_cow,
-                                      lambda cs: cs, caches)
+            # (an engine without an arena has no block to copy and no
+            # table: ``cow`` is empty, ``bt`` unread)
+            if paged:
+                with jax.named_scope("hetu.kv_arena"):
+                    caches = jax.lax.cond(cow["run"], apply_cow,
+                                          lambda cs: cs, caches)
+            tables = bt if paged else None
 
             # the decode lane is a VERIFY lane (speculative decoding):
             # every slot feeds its last token plus up to K drafted
@@ -1099,7 +1131,7 @@ class ServingEngine:
                 # adapter load/evict/mixed-tenant churn never retraces
                 logits, caches, stats = generation.decode(
                     model, params, tok_in, positions, caches,
-                    slot_mask=ctl["active"], block_tables=bt,
+                    slot_mask=ctl["active"], block_tables=tables,
                     row_mask=row_valid, attn_kernel=kern,
                     w8a8_mask=w8a8_mask, w8a8_wq=wq,
                     lora={"ids": jnp.broadcast_to(
@@ -1203,7 +1235,9 @@ class ServingEngine:
                     if wants_slots:
                         # a model that keeps a state per slot: whose
                         # each token is, and the slots' own tables
-                        pack["slot"], pack["slot_tables"] = pf["slot"], bt
+                        pack["slot"] = pf["slot"]
+                        if paged:
+                            pack["slot_tables"] = bt
                     if "tiles" in pf:
                         # (fields, tiles): row 0 is each tile's slot
                         pack["tiles"] = {
@@ -1212,7 +1246,8 @@ class ServingEngine:
                                                axis=0)}
                     h, caches, stats = model.blocks.decode(
                         params["blocks"], h, caches, positions=pos,
-                        block_tables=jnp.take(bt, pf["slot"], axis=0),
+                        block_tables=jnp.take(bt, pf["slot"], axis=0)
+                        if paged else None,
                         attn_kernel=kern, pack=pack,
                         lora={"ids": jnp.take(ctl["adapter"],
                                               pf["slot"])[None, :],
@@ -2637,7 +2672,9 @@ class ServingEngine:
                 else jax.random.fold_in(self._key, req.id)
             self._key_state[slot] = np.asarray(jax.random.key_data(k0))
             self._slot_req[slot] = req
-            plan = req.admit
+            # (no arena: the slot is the whole plan)
+            plan = req.admit or {"table": (), "first_uncached": 0,
+                                 "cow": None}
             self._bt[slot, :] = 0
             self._bt[slot, :len(plan["table"])] = plan["table"]
             if plan["cow"] is not None:
@@ -2909,7 +2946,7 @@ class ServingEngine:
             for i, (src, dst) in enumerate(cows):
                 cow_src[i], cow_dst[i] = src, dst
             cow = {"run": np.bool_(bool(cows)), "src": cow_src,
-                   "dst": cow_dst}
+                   "dst": cow_dst} if self._paged else {}
             bt = self._bt_dev
             spec = {"tok": d_tok, "len": d_len}
             if d_q is not None:
@@ -3336,7 +3373,12 @@ class ServingEngine:
         m = self._m
         m.queue_depth.set(self.scheduler.depth)
         m.occupancy.set(self.scheduler.occupancy)
-        m.kv_in_use.set(self.blocks.blocks_in_use)
+        if self._paged:
+            m.kv_in_use.set(self.blocks.blocks_in_use)
+        else:
+            live = self.pool.slots - len(self.scheduler.free)
+            m.slots.set(live, state="live")
+            m.slots.set(self.pool.slots - live, state="free")
         if self._min_window is not None:
             # the next query of an active slot sits at pos: blocks whose
             # last row is at or below pos - window are dead to it

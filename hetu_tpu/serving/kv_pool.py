@@ -116,6 +116,15 @@ class BlockManager:
         return self.n_blocks - 1 - len(self.free)
 
 
+class NoBlocks:
+    """The ledger of an engine WITHOUT an arena (no layer of its model
+    keeps token rows): nothing to hand out and nothing in use. What
+    reads the ledger of any engine (gauges, the benchmark's samplers)
+    reads zeros here; the scheduler is handed no ledger at all and
+    admits by free slots."""
+    n_blocks = free_blocks = blocks_in_use = 0
+
+
 class KVPool:
     """The block-paged arena plus its shape metadata (block/refcount
     bookkeeping belongs to :class:`BlockManager` and the scheduler; the
@@ -140,6 +149,24 @@ class KVPool:
                 f"max_positions {max_positions}")
         self.slots = int(slots)
         self.max_len = int(max_len)
+        #: does any layer keep token rows in pages? Where none does
+        #: (``model.blocks.paged`` False: every layer keeps a state a
+        #: SLOT) there is NO arena: no page, no table, and the caches
+        #: are the slot leaves alone — ``max_len`` bounds positions only
+        self.paged = getattr(model.blocks, "paged", True)
+        if not self.paged:
+            if n_blocks or table_len:
+                raise ValueError(
+                    f"kv_blocks={n_blocks} / long_max_len={table_len}: "
+                    f"no layer of this model keeps token rows, so it "
+                    f"has no arena to size (kv_blocks 0 or None)")
+            self.block_size = self.table_len = self.max_len
+            self.blocks_per_slot = self.table_width = self.n_blocks = 0
+            self.cache_dtype, self.weight_version = cache_dtype, 0
+            self.caches = init_paged_caches(
+                model, 0, self.max_len, cache_dtype, sharding=sharding,
+                slots=self.slots)
+            return
         self.block_size = int(block_size) if block_size else self.max_len
         if self.max_len % self.block_size != 0:
             raise ValueError(

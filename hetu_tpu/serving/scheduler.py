@@ -410,6 +410,8 @@ class Scheduler:
         Handoff requests decode elsewhere: a prefill-tier replica only
         ever writes the prompt + the first token before releasing the
         reservation, so price P+1 instead of P+max_tokens."""
+        if self.blocks is None:
+            return 0          # no arena: a free slot is the whole price
         bs = self.block_size or self.max_len
         tail = 1 if req.handoff else req.sampling.max_tokens
         return -(-self._span(len(req.prompt) + tail) // bs)

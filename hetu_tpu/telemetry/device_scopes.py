@@ -33,6 +33,8 @@ The vocabulary (all start ``hetu.``; ``docs/OBSERVABILITY.md``):
 ``hetu.mla_down``     latent attention: down-projection, latent norm, RoPE key
 ``hetu.mla_absorb``   latent attention: q through W_uk, results through W_uv
 ``hetu.mla_expand``   latent attention: per-head K and V from the latent rows
+``hetu.retention_scan``   power retention: a prefill pack's chunk form (one kernel)
+``hetu.retention_update`` power retention: the decode rows' update in place
 ====================  ================================================
 
 The rule (:func:`classify`): an instruction belongs to the INNERMOST
@@ -71,7 +73,8 @@ VOCABULARY = (
     "hetu.decode_lane", "hetu.kv_arena", "hetu.sample",
     "hetu.moe_route", "hetu.moe_experts", "hetu.moe_shared",
     "hetu.mla_down", "hetu.mla_absorb", "hetu.mla_expand",
-    "hetu.diffusion_sample",
+    "hetu.diffusion_sample", "hetu.retention_scan",
+    "hetu.retention_update",
 )
 
 #: ``op_name``s the TPU compiler gives an op it made from a program's

@@ -400,7 +400,9 @@ def check_serving_step(devs, *, config="small", dtype=jnp.bfloat16,
     eng = ServingEngine(model, params, max_len=max_len,
                         prefill_chunk=chunk, cache_dtype=dtype,
                         block_size=block_size, slots=slots,
-                        kv_blocks=max_len // block_size + 1,
+                        # (a model without an arena takes none)
+                        kv_blocks=max_len // block_size + 1
+                        if getattr(model.blocks, "paged", True) else 0,
                         attn_kernel="paged", prefill_attn="flash_pallas")
 
     class Recorded(Exception):

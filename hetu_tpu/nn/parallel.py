@@ -488,7 +488,8 @@ class ParallelAttention(Module):
     #: what the serving engine asks the attention that speaks for its
     #: arena: a cache of ONE latent row a token (not K and V per kv
     #: head)? is a pack's history read in tiles of a request's run (the
-    #: engine builds the tile map)?
+    #: engine builds the tile map; ``"every_run"``: of the runs without
+    #: history too)?
     latent, history_tiles = False, True
     #: the leaves :meth:`init_leaves` builds (the int8 arena: twice)
     cache_leaves = 2
@@ -1434,10 +1435,13 @@ def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack,
 
 def count_sparse_pages(values, tokens=None) -> None:
     """:class:`BlockSparseAttention`'s ``layer_stats`` on the host:
-    ``values (sparse layers, 4)`` — the pages the rows of a lane chose
+    ``values (sparse layers, 5)`` — the pages the rows of a lane chose
     and could see, summed over its live rows and kv heads, as
-    ``[chosen, visible]`` of the decode rows then of the prefill pack —
-    into ``serving_sparse_pages_total{state, lane}``."""
+    ``[chosen, visible]`` of the decode rows then of the prefill pack,
+    then how many of the pack's chosen pages were FORCED and read a
+    tile of the pack at a time (``state="band"``; none where the pack
+    read a token at a time) — into ``serving_sparse_pages_total{state,
+    lane}``."""
     import numpy as np
     from hetu_tpu import telemetry
     v = np.asarray(values, np.int64).sum(axis=0)
@@ -1448,6 +1452,58 @@ def count_sparse_pages(values, tokens=None) -> None:
         if v[2 * i + 1]:
             c.inc(int(v[2 * i]), state="chosen", lane=lane)
             c.inc(int(v[2 * i + 1]), state="visible", lane=lane)
+    if v[4]:
+        c.inc(int(v[4]), state="band", lane="prefill")
+
+
+# The two halves of :meth:`BlockSparseAttention._read_split` that are new
+# to a pack are functions of their own under ``jax.jit``: the sparse
+# layers of a stack are traced one after another at the same shapes, and
+# a trace — the kernel's body, its nineteen index maps, the tables'
+# compares: some 500 small traces a layer, 7 s of the chip's host over
+# four layers — is made once and found again by the others.
+
+@functools.partial(jax.jit, static_argnames=(
+    "hkv", "tile_rows", "block_size", "init_blocks", "window_blocks"))
+def _split_tables(ids, n, tables, pos, valid, tile_map, slot_tables, *,
+                  hkv, tile_rows, **forced):
+    """A pack's chosen blocks as the split read's operands: the free
+    lanes' virtual tables, offsets and live rows, the tiles' band
+    tables and map (``ops.sparse_select``), and how many of the live
+    rows' chosen blocks the tiles read."""
+    from hetu_tpu.ops import sparse_select as ss
+    free = ss.free_tables(ids, n, tables, pos, valid, **forced)
+    band = ss.band_tables(tile_map, slot_tables, hkv=hkv,
+                          cells=-(-pos.shape[0] // tile_rows),
+                          tile_rows=tile_rows, **forced)
+    return free + band + (jnp.sum(jnp.where(
+        valid, ss.forced_blocks(pos, **forced), 0)),)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "hkv", "tile_rows", "band", "scale"))
+def _band_read(q, k, v, tables, valid, tiles, layer, *, hkv, tile_rows,
+               band, scale):
+    """The forced blocks' attention of a pack ``q (N, hq, d)`` over
+    pages of ONE kv head, a TILE of a run at a time: the pack laid out
+    a head at a time, each head's rows in whole cells, through the
+    banded tiled call. Returns ``(N, hq, d)`` and the LSE ``(N, hq)``."""
+    from hetu_tpu.ops.paged_pallas import paged_history_attention
+    N, hq, d = q.shape
+    G, pad = hq // hkv, -N % tile_rows
+    qh = jnp.pad(q.reshape(N, hkv, G, d).transpose(1, 0, 2, 3),
+                 ((0, 0), (0, pad), (0, 0), (0, 0)))
+    out = paged_history_attention(
+        qh.reshape(-1, G, d), k, v, tables,
+        jnp.broadcast_to(jnp.pad(valid, (0, pad))[None],
+                         (hkv, N + pad)).reshape(-1), tiles,
+        tile_rows=tile_rows, band=band, layer=layer, scale=scale)
+
+    def rows(x):              # (hkv * cells * rows, G, ..) -> (N, hq, ..)
+        x = x.reshape((hkv, N + pad) + x.shape[1:])[:, :N]
+        return jnp.moveaxis(x, 0, 1).reshape((N, hq) + x.shape[3:])
+
+    return tuple(rows(x) for x in out)
 
 
 class BlockSparseAttention(Module):
@@ -1463,13 +1519,21 @@ class BlockSparseAttention(Module):
 
     Every cached path — the decode rows and a prefill pack alike — is
     rows ``(slot table, position)``: a row's K, V land in the arena, a
-    row that completes a stride writes its mean, every row chooses its
-    pages (``hetu.sparse_select``) and reads them through the paged
-    attention call as a ``topk``-lane table of its own
-    (``hetu.sparse_attn``; a pack is no longer than the forced window,
-    so a token's in-pack keys are among its chosen pages, written
-    before they are read). The whole-sequence forward is the same rule
-    with a dense mask.
+    row that completes a stride writes its mean, and every row chooses
+    its pages (``hetu.sparse_select``) and reads them through the paged
+    attention call (``hetu.sparse_attn``; a pack is no longer than the
+    forced window, so a token's in-pack keys are among its chosen
+    pages, written before they are read). A DECODE row reads its
+    choice as a ``topk``-lane table of its own. A prefill PACK on the
+    kernel path reads it in two parts joined by their LSE
+    (:meth:`_read_split`): the FORCED blocks — the first, the window
+    before the token's own, its own: the same pages for the tokens of
+    a block and all but a few for a tile of the pack — once a TILE of
+    a run, through the tiled call under its banded causal mask; and
+    the token's free choices, at most ``topk`` less the forced blocks,
+    as a table of its own (without the engine's tile map, or on the
+    gather path, the whole choice as the decode rows do). The
+    whole-sequence forward is the same rule with a dense mask.
 
     The arena's leaves (:meth:`init_leaves`): K and V ``(layers,
     n_blocks, hkv * block_size, d)`` — a page holds ONE kv head,
@@ -1482,11 +1546,19 @@ class BlockSparseAttention(Module):
     #: joins, virtual slots a call (its tables ride SMEM), and pack
     #: tokens a block of the selection's scores
     PAGES_PER_STEP, ROWS_PER_CALL, SELECT_ROWS = 8, 512, 256
-    #: the pack's history is read through each token's OWN virtual
-    #: table, not in tiles of a run (the engine builds no tile map)
-    latent, history_tiles, cache_leaves = False, False, 3
+    #: rows of one kv head in a tile of the pack's band read (tokens x
+    #: the group: 16 tokens here). The call is 2.6 ms a layer at 1,024
+    #: rows and 3.9 at 256, of a read of ~20 — but the step's compile
+    #: is 7.6 s longer than without the band at 1,024 and 2.9 s at 256
+    #: (the chip's host, PERF.md section 6, PR 52), and the benchmark's
+    #: cold run has seconds to spare
+    BAND_ROWS = 256
+    #: a pack's forced blocks are read in tiles of a run, its own keys
+    #: among them: the engine's tile map cuts EVERY run of the pack,
+    #: with or without history
+    latent, history_tiles, cache_leaves = False, "every_run", 3
     #: a cached call's third result (:func:`count_sparse_pages`)
-    layer_stats = {"sparse_pages": ((4,), jnp.int32, count_sparse_pages)}
+    layer_stats = {"sparse_pages": ((5,), jnp.int32, count_sparse_pages)}
 
     def __init__(self, embed_dim: int, num_heads: int, *,
                  num_kv_heads: int, head_dim: int, block_size: int = 64,
@@ -1655,9 +1727,11 @@ class BlockSparseAttention(Module):
     def _cached(self, params, x, kv_cache, *, positions, slot_mask,
                 block_tables, row_mask, attn_kernel, pack):
         """Either cached lane (the class docstring). Returns ``(out,
-        (k, v, means), {"sparse_pages": (4,)})``: the ``[chosen,
+        (k, v, means), {"sparse_pages": (5,)})``: the ``[chosen,
         visible]`` pages summed over the live rows and kv heads, of the
-        decode rows then of the pack — a lane fills its own pair."""
+        decode rows then of the pack — a lane fills its own pair —
+        and how many of the pack's chosen pages its tiles read
+        (:func:`count_sparse_pages`)."""
         from hetu_tpu.ops import sparse_select as ss
         (k_buf, v_buf, c_buf), layer = kv_cache
         layer = jnp.asarray(layer, jnp.int32)
@@ -1709,22 +1783,41 @@ class BlockSparseAttention(Module):
                     q, ss.compressed_keys(means_of(pack["slot_tables"]),
                                           ratio), slot, pos, valid)
             ids, n = self._choose(s, pos)
-            vt, voff = ss.virtual_tables(ids, n, tables, pos,
-                                         block_size=bs)
+            # a pack on the kernel path reads a token's forced blocks a
+            # TILE at a time and only its free choices through a table
+            # of its own; every other row its whole choice through one
+            tiles = pack.get("tiles") if pack is not None \
+                and attn_kernel == "paged" else None
+            if tiles is None:
+                vt, voff = ss.virtual_tables(ids, n, tables, pos,
+                                             block_size=bs)
+                live = jnp.repeat(valid, hkv)
+                band = 0
+            else:
+                vt, voff, live, bt, bmap, band = _split_tables(
+                    ids, n, tables, pos, valid, tiles["map"],
+                    pack["slot_tables"], hkv=hkv, tile_rows=tiles["rows"],
+                    block_size=bs, init_blocks=self.init_blocks,
+                    window_blocks=self.window_blocks)
             own = pos // bs + 1
             stats = jnp.stack([
                 jnp.sum(jnp.where(valid, n, 0)),
                 jnp.sum(jnp.where(valid, own, 0))]).astype(jnp.int32) * hkv
 
         with jax.named_scope("hetu.sparse_attn"):
-            o, k_buf, v_buf = self._read(
-                q, k_buf, v_buf, layer, vt, voff, jnp.repeat(valid, hkv),
-                attn_kernel)
+            if tiles is None:
+                o, k_buf, v_buf = self._read(
+                    q, k_buf, v_buf, layer, vt, voff, live, attn_kernel)
+            else:
+                o, k_buf, v_buf = self._read_split(
+                    q, k_buf, v_buf, layer, vt, voff, live, bt, bmap,
+                    valid, tiles["rows"])
         out = self._output(params, o.reshape(N, -1), u)
         out = out[None] if pack is not None else out[:, None]
         zeros = jnp.zeros_like(stats)
+        lanes = [zeros, stats] if pack is not None else [stats, zeros]
         return out, (k_buf, v_buf, c_buf), {"sparse_pages": jnp.concatenate(
-            [zeros, stats] if pack is not None else [stats, zeros])}
+            lanes + [jnp.asarray(band * hkv, jnp.int32)[None]])}
 
     def _pack_scores(self, q, kbar, slot, pos, valid):
         """Window scores of a pack's tokens, each against ITS slot's
@@ -1763,13 +1856,51 @@ class BlockSparseAttention(Module):
             for a in (q, slot, pos, valid)))
         return s.reshape((-1,) + s.shape[2:])[:C]
 
-    def _read(self, q, k_buf, v_buf, layer, vt, voff, live, attn_kernel):
+    def _read_split(self, q, k_buf, v_buf, layer, vt, voff, live, bt,
+                    bmap, valid, tile_rows):
+        """A pack's read on the kernel path, in two parts joined by
+        their LSE: the forced blocks a TILE of the pack at a time —
+        one virtual tile a (kv head, tile) of ``tile_rows x G`` rows
+        over the head's own pages, under the banded causal mask
+        (:func:`_band_read` on ``sparse_select.band_tables``) — and a
+        token's free choices through its own table (:meth:`_read` on
+        ``sparse_select.free_tables``; a token without one gets the
+        empty part)."""
+        from hetu_tpu.ops.paged_pallas import combine_attention_lse
+        hkv, d, bs = self.num_kv_heads, self.head_dim, self.block_size
+        L, n_blk = k_buf.shape[:2]
+        N = q.shape[0]
+        pages = (L, n_blk * hkv, bs, d)
+        free = None                  # (topk may be the forced blocks)
+        if vt.shape[1]:
+            # first: the leaves come out of its loop as they went in,
+            # and the tiles read what the loop hands on (reading them
+            # beside the loop, XLA copies each leaf for it)
+            free, k_buf, v_buf = self._read(
+                q, k_buf, v_buf, layer, vt, voff, live, "paged",
+                return_lse=True)
+        ob, lb = _band_read(
+            q, k_buf.reshape(pages), v_buf.reshape(pages), bt, valid,
+            bmap, layer, hkv=hkv, tile_rows=tile_rows,
+            band=(self.window_blocks, self.init_blocks), scale=self.scale)
+        if free is None:
+            return ob, k_buf, v_buf
+        of, lf = free
+        o = combine_attention_lse(
+            ob[None], lb.T[None], of.reshape(N, -1, d)[None],
+            lf.reshape(N, -1).T[None])
+        return o[0], k_buf, v_buf
+
+    def _read(self, q, k_buf, v_buf, layer, vt, voff, live, attn_kernel,
+              return_lse: bool = False):
         """The chosen pages' attention: one virtual slot a (row, kv
         head), ``G`` query heads over the head's own pages — the paged
         call, ``ROWS_PER_CALL`` virtual slots at a time (their tables
         ride SMEM). Returns ``(o, k_buf, v_buf)``: the leaves are the
         CARRY of the loop over the calls and come back as they went in
-        — closed over, the loop would read a copy of each."""
+        — closed over, the loop would read a copy of each.
+        ``return_lse``: ``o`` is ``(o, its LSE (M, G, 1))``, one part
+        of a joined read."""
         from hetu_tpu.ops.paged_pallas import (
             paged_attention_auto, paged_attention_reference,
         )
@@ -1783,10 +1914,11 @@ class BlockSparseAttention(Module):
             if attn_kernel == "paged":
                 return paged_attention_auto(
                     qg, kp, vp, tg, og, layer=layer, live=lg,
-                    scale=self.scale, pages_per_step=step)
+                    scale=self.scale, pages_per_step=step,
+                    return_lse=return_lse)
             return paged_attention_reference(
                 qg, _at_layer(kp, layer), _at_layer(vp, layer), tg, og,
-                scale=self.scale)
+                scale=self.scale, return_lse=return_lse)
 
         # one more chunk of lanes than any row can fill: the paged call's
         # work list is then never FULL. The kernel's pipeline looks one
@@ -1794,7 +1926,7 @@ class BlockSparseAttention(Module):
         # page of nowhere, and the chip halts (PERF.md section 6, PR 39:
         # every row at 64 chosen pages fills it; no other cell's slots
         # all stand in their last table chunk at once)
-        step = min(self.PAGES_PER_STEP, self.topk)
+        step = min(self.PAGES_PER_STEP, vt.shape[1])
         vt = jnp.pad(vt, ((0, 0), (0, step)))
         R = self.ROWS_PER_CALL
         if M <= R:
@@ -1817,8 +1949,8 @@ class BlockSparseAttention(Module):
             body, (k_buf.reshape(pages), v_buf.reshape(pages)), tuple(
                 a.reshape((-1, R) + a.shape[1:])
                 for a in (qv, vt, voff, live)))
-        return o.reshape((-1,) + o.shape[2:])[:M], \
-            kp.reshape(k_buf.shape), vp.reshape(v_buf.shape)
+        o = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:])[:M], o)
+        return o, kp.reshape(k_buf.shape), vp.reshape(v_buf.shape)
 
 
 class LightningAttention(Module):

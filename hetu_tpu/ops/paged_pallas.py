@@ -150,7 +150,8 @@ def table_chunks(table_width: int, block_size: int,
 
 
 def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
-                  quant, windowed, tiled=False, v_width=None, block=1):
+                  quant, windowed, tiled=False, v_width=None, block=1,
+                  band=None):
     """One grid step: slot ``s``, table-lane chunk ``w`` (L whole pages
     ``(bs, hkv*d)`` of the layer the index maps picked — the layer and
     page dims are squeezed out of the block — every kv head: a TPU
@@ -178,7 +179,12 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     value, taken from the key page the step already holds.
     ``block`` > 1 (the decode rows' call, no window): the block bound —
     a row at position ``p`` sees the keys ``<= p | (block - 1)``, its
-    own block whole (``ops.attention.block_bound``)."""
+    own block whole (``ops.attention.block_bound``).
+    ``band`` ``(band_blocks, init_blocks)`` (a ``tiled`` call, no
+    window): the tile's table holds its rows' OWN keys — a row at
+    ``t`` sees the keys ``<= t`` of the ``band_blocks`` blocks before
+    its own, of its own, and of the table's first ``init_blocks``
+    lanes; the cap says only where the tile's last row stands."""
     del lyr_ref                     # read by the page index maps only
     win_ref = None
     if windowed:
@@ -239,6 +245,12 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
         # need theirs for a window's lower edge only)
         qpos = off + jax.lax.broadcasted_iota(
             jnp.int32, (rows, span), 0) // g
+    if band is not None:
+        # a row's position and the first key of its band: a column
+        band_blocks, init_blocks = band
+        qpos = off + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) // g
+        edge = (qpos // bs - band_blocks) * bs
     last_q = off + (rows // g - 1)
     if block != 1:
         qpos, last_q = qpos | (block - 1), last_q | (block - 1)
@@ -251,6 +263,9 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     if windowed:
         # the first key the slot's FIRST row sees (the lowest any sees)
         first_k = off - win_ref[s_i] + 1
+    if band is not None:
+        # ... and the tile's first row's, past the leading lanes
+        first_k = (off // bs - band_blocks) * bs
 
     # the online-softmax lines below speak lax, not jnp: this body is
     # traced hkv times and each jnp call pays the jit machinery again
@@ -287,14 +302,22 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
         # above the cap: the causal compare would be true everywhere
         kpos = chunk_start + jax.lax.broadcasted_iota(
             jnp.int32, (rows, span), 1)
-        mask = kpos <= cap if tiled else kpos <= qpos
+        if band is not None:
+            # the tile's own keys: causal, from the row's band's first
+            # block (FLOORED to a block, the selection's rule) or in
+            # the table's leading lanes
+            mask = (kpos <= qpos) & (
+                (kpos >= edge) | (kpos < init_blocks * bs))
+        else:
+            mask = kpos <= cap if tiled else kpos <= qpos
         if windowed:
             mask &= kpos > qpos - win_ref[s_i]
         # a row whose keys of this chunk are ALL masked would weigh
         # them 1 (exp(NEG_INF - NEG_INF)): p is masked again — but a
         # live chunk's first key is under the cap, so without a window
         # every row of a tile sees one and exp(NEG_INF - m) is 0
-        remask = not tiled or windowed
+        # (under a band a chunk may lie above a row of the tile)
+        remask = not tiled or windowed or band is not None
         # a head's index as an array, made once a chunk: an int index
         # is converted again at each of its eight uses (a literal
         # either way)
@@ -336,6 +359,9 @@ def _paged_kernel(tbl_ref, off_ref, lyr_ref, *refs, rows, g, bs, L, hkv,
     live = chunk_start <= last_q
     if windowed:
         live &= chunk_start + span > first_k
+    if band is not None:
+        live &= (chunk_start + span > first_k) \
+            | (chunk_start < init_blocks * bs)
     pl.when(live)(compute)
 
     @pl.when(last())
@@ -426,7 +452,7 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                            return_lse: bool = False, window=None,
                            live=None, tiles=None,
                            v_width: Optional[int] = None,
-                           block: int = 1):
+                           block: int = 1, band=None):
     """Decode attention through per-slot block tables, in-kernel.
 
     - ``q``: ``(S, R, hq, d)`` — S slots × R rows (1 for classic decode,
@@ -492,6 +518,17 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
       keys ``<= (q_offset[s] + i) | (block - 1)``, every key of its own
       block. Not with ``window`` or ``tiles`` (a tile's rows stand above
       their keys: the history read needs no bound).
+    - ``band`` (``None`` = none; static ``(band_blocks, init_blocks)``,
+      with ``tiles`` and no ``window``): the tiles' tables hold their
+      rows' OWN keys, and row ``i`` of tile ``t`` at ``p = q_offset[t]
+      + i`` sees key ``j`` of the table iff ``j <= p`` and (``j //
+      block_size >= p // block_size - band_blocks`` or ``j <
+      init_blocks * block_size``): causal, from a lower edge FLOORED
+      to a block (a block-sparse selection's forced band, not the
+      sliding ``window``), plus the table's leading lanes. Of
+      ``tiles["cap"]`` only the sign is read (< 0: a dead tile); pages
+      wholly above a tile's last row, or below its first row's edge
+      and past the leading lanes, are neither fetched nor computed.
 
     Returns ``(S, R, hq, d)`` in q's dtype (plus the fp32
     ``(S, R*… )``-shaped LSE ``(S, hq, R)`` when ``return_lse`` — the
@@ -541,11 +578,19 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
         raise ValueError(
             f"block={block}: a block bound is a power of two, and goes "
             f"with neither a window nor tiles")
+    if band is not None and (not tiled or windowed):
+        raise ValueError(
+            f"band={band}: the banded causal mask is the tiled call's "
+            f"(tiles=), and its lower edge takes a window's place")
     if tiled:
         if live is not None:
             raise ValueError("live= is per slot; a dead tile says so "
                              "in its cap")
         cap = jnp.asarray(tiles["cap"], jnp.int32)
+        if band is not None:
+            # a live tile's last key is its last row's own
+            cap = jnp.where(cap >= 0, q_offset + jnp.asarray(
+                tiles["hi"], jnp.int32) - 1, -1)
         scalars += (cap,) + tuple(jnp.asarray(tiles[n], jnp.int32)
                                   for n in ("cell", "lo", "hi"))
         # tiles with history come first (the host packs them so) and
@@ -603,6 +648,11 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
                 # table lanes below the slot's window name its first
                 lane = jnp.maximum(
                     lane, jnp.maximum(first - more[0][s] + 1, 0) // bs)
+            if band is not None:
+                # ... and those below the band of the tile's first
+                # row, past the table's leading lanes, the band's first
+                lane = jnp.where(lane < band[1], lane, jnp.maximum(
+                    lane, first // bs - band[0]))
             if tiled:
                 # ... and lanes above its cap its last: a block whose
                 # index did not change is not copied
@@ -640,7 +690,9 @@ def paged_attention_pallas(q, k, v, block_tables, q_offset, *,
             functools.partial(_paged_kernel, rows=rows, g=g, bs=bs, L=L,
                               hkv=hkv, quant=quant, windowed=windowed,
                               tiled=tiled, v_width=v_width,
-                              **({"block": block} if block != 1 else {})),
+                              **({"block": block} if block != 1 else {}),
+                              **({} if band is None
+                                 else {"band": tuple(band)})),
             grid_spec=grid_spec,
             out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
@@ -676,7 +728,8 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
                          interpret: Optional[bool] = None,
                          return_lse: bool = False, window=None,
                          live=None, tiles=None,
-                         v_width: Optional[int] = None, block: int = 1):
+                         v_width: Optional[int] = None, block: int = 1,
+                         band=None):
     """:func:`paged_attention_pallas`, tp-aware.
 
     Mosaic kernels cannot be GSPMD-auto-partitioned, so under a
@@ -700,7 +753,8 @@ def paged_attention_auto(q, k, v, block_tables, q_offset, *,
             q, k, v, tbl, off, layer=layer, k_scale=ks, v_scale=vs,
             scale=scale, pages_per_step=pages_per_step,
             interpret=interpret, return_lse=return_lse, window=window,
-            live=live, tiles=tiles, v_width=v_width, block=block)
+            live=live, tiles=tiles, v_width=v_width, block=block,
+            band=band)
 
     ctx = current_act_sharding()
     head_ax = ctx.tp if ctx is not None and isinstance(ctx.tp, str) \
@@ -768,7 +822,8 @@ _TILE_VMEM_BUDGET = 12 * 2 ** 20
 
 
 def history_tile_rows(g: int, d: int, hkv: int, block_size: int, *,
-                      kv_itemsize: int = 2) -> int:
+                      kv_itemsize: int = 2,
+                      head_rows: Optional[int] = None) -> int:
     """Tokens per tile of the prefill lane's history read
     (:func:`paged_history_attention`), from shapes alone: the largest
     power of two up to 128 (a tile of ``Tq`` tokens is ``Tq * g`` rows
@@ -777,11 +832,19 @@ def history_tile_rows(g: int, d: int, hkv: int, block_size: int, *,
     double buffered (priced at float32), the three online-softmax
     scratch buffers, two sets of one K and one V page. 128 for GPT-2
     (``g`` = 1, 12 or 20 heads of 64), 16 for 128 q heads over 8 kv
-    heads of 128."""
+    heads of 128. ``head_rows``: the most rows of ONE kv head a tile
+    may hold, where the caller bounds them — one head's update keeps
+    some seven float32 tiles of its rows against a lane tile of keys
+    beside the blocks, which this pricing leaves out (a group of 16
+    over pages of ONE kv head of 128 prices to 128 tokens = 2,048
+    rows: 16.66 MB of the 16 MiB, refused by the chip's compiler), and
+    the kernel's compile time grows with them
+    (``BlockSparseAttention.BAND_ROWS``)."""
     pages = 2 * 2 * block_size * hkv * d * kv_itemsize
     tq = 128
-    while tq > 8 and _tile_cell_bytes(tq * g, d, hkv) + pages \
-            > _TILE_VMEM_BUDGET:
+    while tq > 8 and (_tile_cell_bytes(tq * g, d, hkv) + pages
+                      > _TILE_VMEM_BUDGET
+                      or head_rows is not None and tq * g > head_rows):
         tq //= 2
     return tq
 
@@ -842,7 +905,8 @@ def history_tile_count(chunk: int, tile_rows: int, max_runs: int) -> int:
 TILE_FIELDS = ("slot", "cell", "lo", "hi", "off", "cap")
 
 
-def pack_history_tiles(runs, *, tile_rows: int, n_tiles: int):
+def pack_history_tiles(runs, *, tile_rows: int, n_tiles: int,
+                       every_run: bool = False):
     """The tile map of one prefill pack, on the host (numpy).
 
     The pack lies in CELLS of ``tile_rows`` rows; ``runs`` is ``(slot,
@@ -856,7 +920,10 @@ def pack_history_tiles(runs, *, tile_rows: int, n_tiles: int):
     ``(len(TILE_FIELDS), n_tiles)`` int32 array, a row per field (one
     upload a step), and the counts ``(live tiles, empty tiles, rows in
     live tiles)``: an empty tile is one a run WITHOUT history would
-    have been."""
+    have been. ``every_run``: the map of a read that takes in the
+    pack's own keys (:func:`paged_history_attention` under ``band=``)
+    — a run without history is cut into tiles like any other, and a
+    tile's last key is its last row's own."""
     import numpy as np
     t = np.zeros((len(TILE_FIELDS), n_tiles), np.int32)
     t[-1] = -1
@@ -864,14 +931,16 @@ def pack_history_tiles(runs, *, tile_rows: int, n_tiles: int):
     for slot, first, n, hist in runs:
         cells = range(first // tile_rows,
                       (first + n - 1) // tile_rows + 1)
-        if hist <= 0:
+        if hist <= 0 and not every_run:
             empty += len(cells)
             continue
         for c in cells:
             base = c * tile_rows
             lo = max(first, base) - base
             hi = min(first + n, base + tile_rows) - base
-            t[:, live] = (slot, c, lo, hi, hist + base - first, hist - 1)
+            off = hist + base - first
+            t[:, live] = (slot, c, lo, hi, off,
+                          off + hi - 1 if every_run else hist - 1)
             live += 1
         rows += n
     return t, (live, empty, rows)
@@ -883,7 +952,8 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
                             scale: Optional[float] = None,
                             interpret: Optional[bool] = None,
                             v_width: Optional[int] = None,
-                            pages_per_step: Optional[int] = None):
+                            pages_per_step: Optional[int] = None,
+                            band=None):
     """Each pack token's attention over its request's RESIDENT history
     (arena positions ``< hist[t]``: earlier chunks, prefix-cache hits),
     one pass over a request's pages per TILE of its chunk.
@@ -901,6 +971,14 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
     is ``v_width`` wide; :func:`paged_attention_pallas`).
     ``pages_per_step`` (``None``: :func:`history_tile_pages` of the
     operands' shapes): the pages of a grid step's key tile.
+    ``band`` ``(band_blocks, init_blocks)``: the tiles read their
+    runs' OWN keys (written before they are read) under the banded
+    causal mask of :func:`paged_attention_pallas` — the forced part of
+    a block-sparse selection, which the tokens of a tile share. The
+    map then has EVERY run cut into tiles (:func:`pack_history_tiles`
+    ``every_run=True``: ``off`` the position of a cell's row 0 in the
+    tile's table, ``cap`` >= 0 for a live tile) and ``hist`` says only
+    which rows are live (> 0).
 
     Returns ``(C, hq, d)`` and the fp32 LSE ``(C, hq)``; a token
     without history gets the empty part (0, ``NEG_INF``), which
@@ -923,7 +1001,7 @@ def paged_history_attention(q, k, v, tile_tables, hist, tiles, *,
         k_scale=k_scale, v_scale=v_scale, scale=scale,
         pages_per_step=pages_per_step, interpret=interpret,
         return_lse=True,
-        window=window, v_width=v_width,
+        window=window, v_width=v_width, band=band,
         tiles={n: tiles[n] for n in ("cap", "cell", "lo", "hi")})
     live = hist > 0
     out = out.reshape(-1, hq, out.shape[-1])[:C]

@@ -897,12 +897,21 @@ class ServingEngine:
             history_tile_count, history_tile_pages, history_tile_rows,
             pack_history_tiles, table_chunks,
         )
+        # a tile map of the runs WITH history, or of every run: the
+        # attention's read takes in the pack's own keys (the
+        # block-sparse band)
+        self._every_run = _attn_mod.history_tiles == "every_run"
         self._pack_tiles = pack_history_tiles
+        # the kv heads a PAGE holds are the call's: the arena's row
+        # over the head's width (a block-sparse page holds one)
         shapes = (_attn_mod.num_heads // _attn_mod.num_kv_heads,
-                  _attn_mod.head_dim, _attn_mod.num_kv_heads,
+                  _attn_mod.head_dim,
+                  self.pool.caches[0].shape[-1] // _attn_mod.head_dim,
                   self.pool.block_size)
         itemsize = jnp.dtype(self.pool.caches[0].dtype).itemsize
-        self._hist_tile = history_tile_rows(*shapes, kv_itemsize=itemsize)
+        self._hist_tile = history_tile_rows(
+            *shapes, kv_itemsize=itemsize,
+            head_rows=_attn_mod.BAND_ROWS if self._every_run else None)
         self._hist_tiles = history_tile_count(
             self.prefill_chunk, self._hist_tile, self._fin_cap) \
             if prefill_attn != "reference" \
@@ -2933,11 +2942,12 @@ class ServingEngine:
                 # every other tile dead — data, on the one upload
                 pf["tiles"], (live, empty, rows) = self._pack_tiles(
                     runs, tile_rows=self._hist_tile,
-                    n_tiles=self._hist_tiles)
+                    n_tiles=self._hist_tiles, every_run=self._every_run)
                 if live:
                     m.hist_tiles.inc(live, state="live")
                     m.hist_rows.inc(rows)
-                    self._count_hist_chunks(pf["tiles"][:, :live])
+                    if not self._every_run:   # (chunks under a cap)
+                        self._count_hist_chunks(pf["tiles"][:, :live])
                 if empty:
                     m.hist_tiles.inc(empty, state="empty")
             # CoW lanes: unused dst = n_blocks scatters out of bounds

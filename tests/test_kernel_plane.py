@@ -276,6 +276,18 @@ PARENT_DECODE_JAXPR = {
     (4, "bf16"): ("2ebd2959388f611e", "eeeb5af50fe1ca1b"),
     (4, "int8"): ("468f2491561dd42a", "b58727f74513f6ab")}
 
+#: the same of the TILED call (``tiles=`` of zeros, ``return_lse``, two
+#: pages a grid step; plain | under a window) at commit 428bd76 (PR 51),
+#: the parent of the PR that gave the tiled call its banded causal mask
+#: (``band=``, which only the block-sparse attention passes): without
+#: the band the history read of every other model is the parent's
+#: kernel — same grid, same scalar operands, same mask.
+PARENT_TILED_JAXPR = {
+    (1, "bf16"): ("d2df8ad7de5e205d", "5e6086b00a22c733"),
+    (1, "int8"): ("534094dda382077e", "2f88d5b5fc6b2023"),
+    (4, "bf16"): ("3681360f6791e2e0", "94c4f28c20824d70"),
+    (4, "int8"): ("55c26ec2843d4090", "bdd0b78db86caf63")}
+
 
 @pytest.mark.parametrize("rows,arena", list(PARENT_DECODE_JAXPR))
 def test_decode_call_walks_a_work_list_and_tiled_call_is_the_parents(
@@ -328,9 +340,27 @@ def test_decode_call_walks_a_work_list_and_tiled_call_is_the_parents(
                 *args).jaxpr)
         assert gm.num_index_operands == 7
         assert gm.num_dynamic_grid_bounds == 2
+    # ... and is the parent's to the character where no band is asked
+    # for; the band is the same grid and operands under another mask
+    for kw, parents in zip(({}, {"window": jnp.asarray(6, jnp.int32)}),
+                           PARENT_TILED_JAXPR[rows, arena]):
+        jaxpr = jax.make_jaxpr(lambda *a: f(
+            *a, pages_per_step=2, tiles=tiles, return_lse=True,
+            **kw))(*args)
+        assert parents == hashlib.sha256(
+            str(jaxpr).encode()).hexdigest()[:16]
+    if not quant:
+        gm = grid_mapping(jax.make_jaxpr(lambda *a: f(
+            *a, pages_per_step=2, tiles=tiles, band=(2, 1)))(*args).jaxpr)
+        assert gm.num_index_operands == 7
+        assert gm.num_dynamic_grid_bounds == 2
     with pytest.raises(ValueError, match="live="):
         f(*(jnp.zeros(a.shape, a.dtype) for a in args), tiles=tiles,
           live=live)
+    for kw in ({}, {"tiles": tiles, "window": jnp.asarray(6, jnp.int32)}):
+        with pytest.raises(ValueError, match="band="):
+            f(*(jnp.zeros(a.shape, a.dtype) for a in args), band=(2, 1),
+              **kw)
 
 
 # -- the decode rows' work list: live (slot, chunk) pairs -------------------
@@ -956,6 +986,142 @@ def test_history_read_scores_a_key_tile_per_head(form, pages):
         assert count("iota") == (1 if window is None else 2)
 
 
+# -- the banded causal tiled call: a block-sparse pack's forced blocks ------
+
+BAND, INIT = 2, 1   # blocks before a row's own, leading lanes (pages of 4)
+
+#: runs of a pack as (tokens, first position); ``tq``: the tile's rows
+BAND_CASES = {
+    # the lower edge (floored to a block) with a row at a block's first
+    # key, its last, and one past: the edge moves a whole block
+    "edge_at_a_block_boundary": dict(runs=[(3, 15)]),
+    "edge_one_key_below": dict(runs=[(3, 14)]),
+    "edge_one_key_above": dict(runs=[(3, 16)]),
+    # own <= BAND: the band reaches the first block (read once)
+    "first_block_inside_the_band": dict(runs=[(9, 0)]),
+    "band_meets_the_first_block": dict(runs=[(4, 10)]),
+    "first_block_outside_the_band": dict(runs=[(7, 20)]),
+    "tile_spans_two_blocks": dict(runs=[(4, 18)]),
+    "tile_of_8_spans_three_blocks": dict(runs=[(8, 19)], tq=8),
+    "run_starts_mid_cell": dict(runs=[(2, 5), (9, 17)]),
+    "two_runs_share_a_cell_twice": dict(runs=[(5, 9), (6, 22), (3, 0)]),
+    "a_dead_tile_and_pad_rows": dict(runs=[(6, 13)], max_runs=4),
+}
+
+
+def _band_pack(rng, runs, *, C=16, hq=4, hkv=2, d=16, bs=4, W=8):
+    """A pack of ``runs`` (tokens, first position) whose keys are in
+    the arena already; what no row of a run may see — positions above
+    its last row, blocks between the leading lanes and its first
+    row's band, the null block — is ±GARBAGE."""
+    n_blocks = 1 + len(runs) * W
+    k, v = (rng.normal(size=(n_blocks, bs, hkv, d)).astype(np.float32)
+            for _ in range(2))
+    tbl = 1 + np.arange(len(runs) * W, dtype=np.int32).reshape(-1, W)
+    seen = np.zeros((n_blocks * bs,), bool)
+    slot, pos = np.zeros(C, np.int32), np.zeros(C, np.int32)
+    valid = np.zeros(C, bool)
+    run_list, used = [], 0
+    for s, (n, first) in enumerate(runs):
+        base = (1 + s * W) * bs
+        seen[base:base + INIT * bs] = True
+        edge = max(first // bs - BAND, 0) * bs
+        seen[base + edge:base + first + n] = True
+        slot[used:used + n], valid[used:used + n] = s, True
+        pos[used:used + n] = first + np.arange(n)
+        run_list.append((s, used, n, first))
+        used += n
+    for x in (k, v):
+        flat = x.reshape(-1, hkv, d)
+        flat[~seen] = GARBAGE * rng.choice(
+            [-1.0, 1.0], size=((~seen).sum(), hkv, d))
+    q = jnp.asarray(rng.normal(size=(C, hq, d)), jnp.float32)
+    return (q, _pages(jnp.asarray(k)), _pages(jnp.asarray(v)),
+            jnp.asarray(tbl), slot, pos, valid, run_list)
+
+
+def _band_reference(q, k, v, tbl, slot, pos, bs=4):
+    """A token at a time: its leading and band blocks as a table of
+    its own, read causally (``paged_attention_reference``)."""
+    own = pos // bs
+    first = np.maximum(own - BAND, INIT)
+    lane = np.arange(INIT + BAND + 1)[None, :]
+    blocks = np.where(lane < INIT, lane, first[:, None] + lane - INIT)
+    n = np.minimum(own + 1, INIT + np.maximum(own - first + 1, 0))
+    tables = np.where(lane < n[:, None],
+                      np.asarray(tbl)[slot[:, None], np.minimum(blocks, 7)],
+                      0)
+    return paged_attention_reference(
+        q[:, None], k, v, jnp.asarray(tables, jnp.int32),
+        jnp.asarray((n - 1) * bs + pos % bs, jnp.int32), return_lse=True)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 8])
+@pytest.mark.parametrize("case", list(BAND_CASES))
+def test_banded_tiled_call_matches_per_token_reference(case, pages):
+    """The tiled call under ``band=``: row ``t`` sees key ``j`` of its
+    run's table iff ``j <= t`` and ``j`` lies in the BAND blocks before
+    ``t``'s own, in its own, or in the INIT leading lanes — outputs and
+    LSE of one reference slot a token, at one, two and every page a
+    grid step; pad rows get the empty part; nothing a run's rows may
+    not see (the keys above them, the blocks the band skips) is seen."""
+    spec = BAND_CASES[case]
+    tq = spec.get("tq", TQ)
+    rng = np.random.default_rng(52)
+    q, k, v, tbl, slot, pos, valid, runs = _band_pack(rng, spec["runs"])
+    G = history_tile_count(q.shape[0], tq, spec.get("max_runs",
+                                                    len(runs)))
+    tiles, (live, _, rows) = pack_history_tiles(
+        runs, tile_rows=tq, n_tiles=G, every_run=True)
+    assert rows == valid.sum() and (tiles[-1][live:] == -1).all()
+    # a live tile's last key is its last row's
+    assert (tiles[-1][:live]
+            == tiles[4][:live] + tiles[3][:live] - 1).all()
+    out, lse = paged_history_attention(
+        q, k, v, jnp.take(tbl, tiles[0], axis=0), jnp.asarray(valid),
+        jnp.asarray(tiles), tile_rows=tq, band=(BAND, INIT),
+        pages_per_step=pages)
+    ref, lse_r = _band_reference(q, k, v, tbl, slot, pos)
+    np.testing.assert_allclose(np.asarray(out)[valid],
+                               np.asarray(ref)[valid, 0], atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse)[valid],
+                               np.asarray(lse_r)[valid, :, 0], atol=1e-5)
+    assert np.abs(np.asarray(ref)[valid]).max() < 10      # no garbage
+    assert not np.asarray(out)[~valid].any()
+    assert (np.asarray(lse)[~valid] == NEG_INF).all()
+
+
+@pytest.mark.parametrize("g", [1, 4, 16])
+def test_banded_tiled_call_group_sizes_in_a_layer_scan(g):
+    """``g`` query heads a kv head (a tile is ``tq x g`` rows on the
+    MXU) over the stacked arena at a traced layer; a dead tile's table
+    and the lanes above a tile's last row may name any page."""
+    rng = np.random.default_rng(53)
+    q, k, v, tbl, slot, pos, valid, runs = _band_pack(
+        rng, [(5, 9), (6, 22), (3, 0)], hq=2 * g)
+    G = history_tile_count(q.shape[0], TQ, 6)
+    tiles, (live, _, _) = pack_history_tiles(runs, tile_rows=TQ, n_tiles=G,
+                                             every_run=True)
+    tables = np.asarray(jnp.take(tbl, tiles[0], axis=0)).copy()
+    tables[live:] = 0
+    for t in range(live):
+        tables[t, tiles[-1][t] // 4 + 1:] = 0
+    ks, vs = jnp.stack([k * 0, k]), jnp.stack([v * 0, v])
+
+    @jax.jit
+    def f(layer):
+        return paged_history_attention(
+            q, ks, vs, jnp.asarray(tables), jnp.asarray(valid),
+            jnp.asarray(tiles), tile_rows=TQ, band=(BAND, INIT),
+            layer=layer)
+    out, lse = f(jnp.asarray(1, jnp.int32))
+    ref, lse_r = _band_reference(q, k, v, tbl, slot, pos)
+    np.testing.assert_allclose(np.asarray(out)[valid],
+                               np.asarray(ref)[valid, 0], atol=1e-5)
+    np.testing.assert_allclose(np.asarray(lse)[valid],
+                               np.asarray(lse_r)[valid, :, 0], atol=1e-5)
+
+
 def test_history_tile_pages_from_shapes():
     """The pages of a grid step's key tile are a function of shapes,
     rows first: 8 pages beside the latent cells (16 or 32 heads over
@@ -981,6 +1147,14 @@ def test_history_tile_rows_from_shapes():
     assert history_tile_rows(1, 64, 12, 16) == 128
     assert history_tile_rows(1, 64, 20, 16) == 128
     assert history_tile_rows(16, 128, 8, 64) == 16
+    assert history_tile_rows(16, 640, 1, 64) == 32      # (the latent
+    assert history_tile_rows(32, 640, 1, 64) == 16      # cells)
+    # a caller that bounds the rows of one kv head: the block-sparse
+    # band's tile (a group of 16 over pages of ONE kv head) is 16
+    # tokens at 256 rows, where the pricing alone says 128
+    assert history_tile_rows(16, 128, 1, 64) == 128
+    assert history_tile_rows(16, 128, 1, 64, head_rows=256) == 16
+    assert history_tile_rows(16, 128, 8, 64, head_rows=1024) == 16
     assert history_tile_count(256, 128, 32) == 33
     assert history_tile_count(512, 16, 48) == 79
     assert history_tile_count(8, 128, 3) == 3

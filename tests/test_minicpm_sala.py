@@ -24,6 +24,9 @@ from hetu_tpu.models.minicpm_sala import (  # noqa: E402
 from hetu_tpu.nn.parallel import SlotStateNotSupported  # noqa: E402
 from hetu_tpu.ops import linear_attention as la  # noqa: E402
 from hetu_tpu.ops import sparse_select as ss  # noqa: E402
+from hetu_tpu.ops.paged_pallas import (  # noqa: E402
+    history_tile_count, pack_history_tiles,
+)
 
 
 def ref_config(cfg: MiniCPMSALAConfig) -> dict:
@@ -93,13 +96,15 @@ def test_model_matches_the_reference_where_selection_drops_blocks(tiny):
 
 
 def _serve_logits(model, params, requests, *, slots, chunk, block_size,
-                  n_blocks, max_len, attn_kernel="reference"):
+                  n_blocks, max_len, attn_kernel="reference",
+                  tile_rows=None):
     """Drive ``generation.decode`` the way the fused step does — a
     prefill pack of at most ``chunk`` tokens a call (FCFS, runs of
     several requests in one pack), then decode rows, a token a call —
     and collect every position's logits. ``requests``: ``(slot, ids,
     n_decode)`` in admission order; a slot named twice is REUSED once
-    its first request is done."""
+    its first request is done. ``tile_rows``: the pack carries the
+    engine's tile map of every run (the split read)."""
     caches = generation.init_paged_caches(model, n_blocks, block_size,
                                           jnp.float32, slots=slots)
     W = max_len // block_size
@@ -148,11 +153,12 @@ def _serve_logits(model, params, requests, *, slots, chunk, block_size,
             valid = np.zeros(chunk, bool)
             seg = np.full(chunk, -1, np.int32)
             hist = np.zeros(chunk, np.int32)
-            used, fills = 0, []
+            used, fills, runs = 0, [], []
             for r in prefilling:
                 if used >= chunk:
                     break
                 n = min(chunk - used, len(r["ids"]) - r["n"] - r["off"])
+                runs.append((r["slot"], used, n, r["off"]))
                 sl = slice(used, used + n)
                 tokens[sl] = r["ids"][r["off"]:r["off"] + n]
                 tpos[sl] = np.arange(r["off"], r["off"] + n)
@@ -164,6 +170,13 @@ def _serve_logits(model, params, requests, *, slots, chunk, block_size,
                     "hist": jnp.asarray(hist), "valid": jnp.asarray(valid),
                     "impl": "reference", "slot": jnp.asarray(tslot),
                     "slot_tables": btd}
+            if tile_rows:
+                tmap, _ = pack_history_tiles(
+                    runs, tile_rows=tile_rows, every_run=True,
+                    n_tiles=history_tile_count(chunk, tile_rows, slots))
+                pack["tiles"] = {"map": jnp.asarray(tmap),
+                                 "rows": tile_rows,
+                                 "tables": btd[tmap[0]]}
             lg, caches = _decode_pack(model, params, tokens, tpos, caches,
                                       btd, tslot, pack, attn_kernel)
             for r, at, n in fills:
@@ -189,16 +202,57 @@ def _decode_pack(model, params, tokens, tpos, caches, btd, tslot, pack,
         caches
 
 
-@pytest.mark.parametrize("attn_kernel,looped", [
-    ("reference", False), ("paged", False), ("reference", True)])
+def _compile_the_two_calls(mp, model, attn_kernel):
+    """``_serve_logits``' two calls compiled once each instead of run op
+    by op — the same arithmetic (an eager run on the kernel path leaves
+    some 17,000 memory maps behind, of the 65,530 a process may
+    hold)."""
+    decode, one_pack = generation.decode, _decode_pack
+    rows = jax.jit(lambda params, tok, pos, caches, act, bt: decode(
+        model, params, tok, pos, caches, slot_mask=act, block_tables=bt,
+        row_mask=act[:, None], attn_kernel=attn_kernel))
+
+    def packed(tile_rows):
+        def call(params, tokens, tpos, caches, bt, tslot, pack):
+            pack = {**pack, "impl": "reference"}
+            if tile_rows:
+                pack["tiles"] = {**pack["tiles"], "rows": tile_rows}
+            return one_pack(model, params, tokens, tpos, caches, bt,
+                            tslot, pack, attn_kernel)
+        return jax.jit(call)
+
+    packs = {}
+    mp.setattr(
+        generation, "decode",
+        lambda m, p, tok, pos, caches, *, slot_mask, block_tables,
+        row_mask, attn_kernel: rows(p, tok, pos, caches, slot_mask,
+                                    block_tables))
+
+    def one(m, p, tokens, tpos, caches, bt, tslot, pack, kern):
+        tiles = dict(pack.get("tiles", {}))
+        tr = tiles.pop("rows", None)
+        pack = {k: v for k, v in pack.items() if k != "impl"}
+        if tr:
+            pack["tiles"] = tiles
+        return packs.setdefault(tr, packed(tr))(
+            p, tokens, tpos, caches, bt, tslot, pack)
+    mp.setattr(sys.modules[__name__], "_decode_pack", one)
+
+
+@pytest.mark.parametrize("attn_kernel,looped,tile_rows", [
+    ("reference", False, None), ("paged", False, None),
+    ("reference", True, None), ("paged", False, 4), ("paged", True, 8)])
 def test_chunked_prefill_then_decode_equals_one_forward_pass(
-        tiny, attn_kernel, looped, monkeypatch):
+        tiny, attn_kernel, looped, tile_rows, monkeypatch):
     """Logits, not tokens: two slots of different lengths in one pack,
     chunks that cut strides and pages, and slot 0 REUSED by a third
     request (its state must start from zeros, its pages be its own).
     ``looped``: at sizes under a pack's, so that the read goes call by
     call over padded rows and the scores and the scan block by block,
-    as they do at the served size."""
+    as they do at the served size. ``tile_rows``: the pack carries the
+    engine's tile map and reads its forced blocks a tile at a time,
+    its free choices a token at a time (two runs a pack, a run that
+    starts mid-cell, cells of one block and of two)."""
     if looped:
         from hetu_tpu.nn.parallel import (
             BlockSparseAttention, LightningAttention,
@@ -207,26 +261,74 @@ def test_chunked_prefill_then_decode_equals_one_forward_pass(
         monkeypatch.setattr(BlockSparseAttention, "SELECT_ROWS", 4)
         monkeypatch.setattr(LightningAttention, "SCAN_BLOCK", 4)
     cfg, model, params = tiny
+    if attn_kernel == "paged":
+        _compile_the_two_calls(monkeypatch, model, attn_kernel)
     rng = np.random.default_rng(39)
     reqs = [(0, rng.integers(1, 128, 31), 5),
             (1, rng.integers(1, 128, 22), 7),
             (0, rng.integers(1, 128, 27), 6)]
     got = _serve_logits(model, params, reqs, slots=2, chunk=10,
                         block_size=4, n_blocks=24, max_len=32,
-                        attn_kernel=attn_kernel)
+                        attn_kernel=attn_kernel, tile_rows=tile_rows)
     config = ref_config(cfg)
     for i, (_, ids, _) in enumerate(reqs):
         want = reference.logits(params, jnp.asarray(ids), config)
         np.testing.assert_allclose(got[i], want, atol=2e-5)
 
 
-def test_engine_serves_tokens_the_reference_puts_on_top(tiny):
+@pytest.fixture(scope="module")
+def split_reads():
+    """One 41-token prompt under top-6 (3 forced blocks of 4, up to 3
+    free), prefilled in packs of 16: the logits of the split read (the
+    band a tile of 8 at a time + the free lanes, joined), of the
+    64-lane-style read a token at a time (no tile map: the parent's),
+    and of the whole-sequence forward."""
+    cfg = MiniCPMSALAConfig.tiny(topk=6)
+    model = MiniCPMSALAForCausalLM(cfg)
+    params = model.init(jax.random.key(52))
+    ids = np.random.default_rng(52).integers(1, 128, 41)
+    kw = dict(slots=1, chunk=16, block_size=4, n_blocks=16, max_len=48,
+              attn_kernel="paged")
+    with pytest.MonkeyPatch.context() as mp:
+        _compile_the_two_calls(mp, model, "paged")
+        return (_serve_logits(model, params, [(0, ids, 1)], tile_rows=8,
+                              **kw)[0],
+                _serve_logits(model, params, [(0, ids, 1)], **kw)[0],
+                np.asarray(model(params, jnp.asarray(ids)[None])[0]))
+
+
+@pytest.mark.parametrize("where,rows", [
+    ("no_free_lane", slice(0, 12)), ("fewer_free_than_lanes", slice(12, 20)),
+    ("every_free_lane", slice(20, 24)), ("past_topk_blocks", slice(24, 41))])
+def test_split_read_is_the_per_token_read_and_the_forward(
+        split_reads, where, rows):
+    """Below position 12 every visible block is forced (the free part
+    is the empty one), to 20 a token has 1 or 2 of its 3 free lanes, to
+    24 all three and sees every block, past it the selection drops
+    blocks: the band read a tile at a time joined with the free lanes
+    is the read of the whole choice a token at a time, and the dense
+    rule's."""
+    split, per_token, forward = (x[rows] for x in split_reads)
+    np.testing.assert_allclose(split, per_token, atol=2e-5)
+    np.testing.assert_allclose(split, forward, atol=2e-5)
+
+
+@pytest.mark.parametrize("attn_kernel", ["auto", "paged"])
+def test_engine_serves_tokens_the_reference_puts_on_top(tiny, attn_kernel):
+    """``paged``: the kernel path (interpret mode here) — the engine
+    cuts every run of a pack into tiles for the block-sparse band (a
+    pack holds the end of one prompt and the start of the next)."""
     from hetu_tpu.serving import SamplingParams, ServingEngine
     cfg, model, params = tiny
     eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                        block_size=4, slots=3, kv_blocks=40, seed=0)
+                        block_size=4, slots=3, kv_blocks=40, seed=0,
+                        attn_kernel=attn_kernel)
     assert eng.prefix_cache is None and eng.preempt is False
     assert eng.prefill_attn == "flash"        # the pack as one row
+    assert eng.attn_kernel == ("paged" if attn_kernel == "paged"
+                               else "reference")
+    # a tile map of every run, sized for pages of ONE kv head
+    assert eng._hist_tiles == (0 if attn_kernel == "auto" else 1 + 3 - 1)
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, 128, n).tolist() for n in (21, 13, 30, 23, 7)]
     outs = eng.generate_many(prompts, SamplingParams(max_tokens=6))
@@ -420,6 +522,96 @@ def test_other_models_keep_their_defaults_and_their_programs():
     assert eng.prefix_cache is not None and eng.preempt is True
     assert eng._slot_state is False and eng.pool.quantized is False
     assert len(eng.pool.caches) == 2
+    # its tile map is of the runs WITH history, as it was (the tiled
+    # call's program without the band is pinned beside the decode
+    # call's: tests/test_kernel_plane.py, PARENT_TILED_JAXPR)
+    assert eng._every_run is False
+    assert type(model.blocks.block.attn).history_tiles is True
+
+
+#: what ``ServingEngine._size_history_tiles`` gave the other serving
+#: cells at commit 428bd76 (PR 51), the parent of the PR that made the
+#: block-sparse class ask for a tile map: heads, kv heads, head dim,
+#: latent, a page's width and dtype, block size, chunk, runs a pack,
+#: table width -> tile rows, tiles, a table's steps, a step's keys
+PARENT_TILE_SIZES = {
+    "gpt2-small": ((12, 12, 64, False, 768, jnp.float32, 16, 256, 148,
+                    65), (128, 149, 9, 128)),
+    "gpt2-large": ((20, 20, 64, False, 1280, jnp.float32, 16, 256, 32,
+                    65), (128, 33, 9, 128)),
+    "command-a-plus-ep8": ((128, 8, 128, False, 1024, jnp.bfloat16, 64,
+                            512, 48, 129), (16, 79, 33, 256)),
+    "kimi-vl-a3b-pp4": ((16, 1, 640, True, 640, jnp.bfloat16, 64, 2048,
+                         48, 257), (32, 111, 33, 512)),
+    "ling-3.0-flash-vl-ep8": ((32, 1, 640, True, 640, jnp.bfloat16, 64,
+                               2048, 72, 133), (16, 199, 17, 512)),
+    # (this class: a page holds ONE of its 2 kv heads — 16 tokens x 16
+    # group members a tile, 128 cells + 19 more runs, 8 pages a step)
+    "minicpm-sala-pp2": ((32, 2, 128, False, 128, jnp.bfloat16, 64,
+                          2048, 20, 520), (16, 147, 65, 512)),
+}
+
+
+@pytest.mark.parametrize("name", list(PARENT_TILE_SIZES))
+def test_tile_map_is_sized_from_the_pages_shapes(name):
+    """The engine sizes the prefill lane's tile map from what the paged
+    call sees — the group, the head's width and the kv heads a PAGE
+    holds — with no test of the model: the other cells' tile rows,
+    tile counts and steps are the parent's to the number."""
+    import types
+    from hetu_tpu.serving import ServingEngine
+    (h, hkv, d, latent, minor, dtype, bs, chunk, runs, W), want = \
+        PARENT_TILE_SIZES[name]
+    eng = types.SimpleNamespace(
+        pool=types.SimpleNamespace(block_size=bs, caches=[
+            jax.ShapeDtypeStruct((2, 9, bs, minor), dtype)]),
+        prefill_chunk=chunk, _fin_cap=runs, attn_kernel="paged")
+    attn = types.SimpleNamespace(
+        num_heads=h, num_kv_heads=hkv, head_dim=d, latent=latent,
+        **({"history_tiles": "every_run", "BAND_ROWS": 256}
+           if name.startswith("minicpm") else {"history_tiles": True}))
+    ServingEngine._size_history_tiles(eng, attn, W, "flash")
+    assert (eng._hist_tile, eng._hist_tiles, eng._hist_steps,
+            eng._hist_span) == want
+    assert eng._every_run == name.startswith("minicpm")
+
+
+def test_band_counter_is_the_forced_pages_of_the_packs_rows(tiny):
+    """``serving_sparse_pages_total{state="band", lane="prefill"}``:
+    the chosen pages of the pack's live rows that a TILE read — by
+    hand, a token at ``t`` has ``min(t // 4 + 1, 3)`` forced blocks of
+    its ``min(t // 4 + 1, 4)`` chosen and ``t // 4 + 1`` visible, a kv
+    head and sparse layer (2 x 2). Without the tile map (the gather
+    path) nothing is a tile's and ``chosen`` / ``visible`` read the
+    same."""
+    from hetu_tpu import telemetry
+    from hetu_tpu.serving import SamplingParams, ServingEngine
+    cfg, model, params = tiny
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(1, 128, n).tolist() for n in (21, 13, 30)]
+    blocks = np.concatenate([np.arange(len(p)) // 4 + 1 for p in prompts])
+    was = telemetry.enabled()
+    try:
+        for kernel, band in (("paged", np.minimum(blocks, 3).sum() * 4),
+                             ("reference", 0)):
+            telemetry.reset()
+            telemetry.enable(True)
+            eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                                block_size=4, slots=3, kv_blocks=40,
+                                seed=0, attn_kernel=kernel)
+            eng.generate_many(prompts, SamplingParams(max_tokens=2))
+            c = telemetry.get_registry().counter(
+                "serving_sparse_pages_total")
+            assert c.value(state="band", lane="prefill") == band
+            assert c.value(state="chosen", lane="prefill") \
+                == np.minimum(blocks, 4).sum() * 4
+            assert c.value(state="visible", lane="prefill") \
+                == blocks.sum() * 4
+            assert c.value(state="band", lane="decode") == 0
+            assert c.value(state="chosen", lane="decode") > 0
+    finally:
+        telemetry.reset()
+        telemetry.enable(was)
 
 
 def test_importing_the_package_loads_none_of_the_new_modules():

@@ -656,6 +656,7 @@ class Trainer:
                         if tel:
                             # sample the mem_*/comm_* registry series into
                             # Perfetto counter tracks on the log cadence
+                            telemetry.process.sample()
                             self.tracer.record_counters(
                                 self.registry.snapshot())
                     # step dispatch + the log boundary's blocking fetch: the

@@ -3113,7 +3113,11 @@ class ServingEngine:
             if self.slo is not None:
                 self.slo.observe("serving_step_seconds", step_s)
             if self._counter_sample_every and \
-                    self._iter % self._counter_sample_every == 0:
+                    self._iter % self._counter_sample_every == 0 \
+                    and telemetry.enabled():
+                # the process beside this loop (CPU of every thread,
+                # threads, resident peak), on the tracks' cadence
+                telemetry.process.sample()
                 telemetry.get_tracer().record_counters(
                     telemetry.get_registry().scalars())
         # set once, at the end: host CPU = cpu_s - wait_cpu_s, host wall

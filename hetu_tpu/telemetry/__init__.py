@@ -14,7 +14,11 @@ with (``utils/logging.py`` JSONL sink, ``utils/profiler.py`` step stats):
 - :mod:`~hetu_tpu.telemetry.device_scopes` — the ``hetu.*`` named
   scopes of the compiled steps, read back from their optimized HLO;
 - :mod:`~hetu_tpu.telemetry.compile_events` — what JAX spent tracing,
-  lowering and compiling, by function (always on).
+  lowering and compiling, by function, thread and cache outcome (always
+  on);
+- :mod:`~hetu_tpu.telemetry.process` — the process beside the loop:
+  its CPU, threads and resident peak on the counter tracks' cadence,
+  and garbage collections as spans.
 
 Process-global default instances live here (the Prometheus
 default-registry idiom): instrumented hot paths write through
@@ -33,6 +37,7 @@ from hetu_tpu.telemetry.aggregate import (
     aggregate_snapshots, cluster_aggregate, collect_snapshots,
     publish_snapshot,
 )
+from hetu_tpu.telemetry import process
 from hetu_tpu.telemetry.compile_events import (
     CompileEvent, compile_events,
 )
@@ -79,9 +84,12 @@ def get_registry() -> MetricRegistry:
 def enable(on: bool = True) -> None:
     """Master switch for the global tracer + registry. Off by default;
     the disabled fast path is a single attribute check per call site
-    (<1% of any real step loop — asserted in ``tests/test_telemetry.py``)."""
+    (<1% of any real step loop — asserted in ``tests/test_telemetry.py``).
+    On, a garbage collection is a ``gc/collect`` span: the switch adds
+    and removes the one ``gc.callbacks`` hook (``telemetry/process.py``)."""
     _TRACER.enabled = on
     _REGISTRY.enabled = on
+    process.install_gc_hook(on)
 
 
 def enabled() -> bool:
@@ -94,6 +102,7 @@ def reset() -> None:
     always-on black box, not part of the opt-in switch)."""
     _TRACER.clear()
     _REGISTRY.clear()
+    process.reset()
     get_flight_recorder().clear()
     from hetu_tpu.telemetry.flight import _clear_trip_totals
     _clear_trip_totals()
@@ -153,7 +162,7 @@ __all__ = [
     "TRACEPARENT_VERBS", "make_traceparent", "parse_traceparent",
     "new_span_id", "current_traceparent", "use_trace",
     "parse_prometheus", "merge_prometheus", "health_rollup",
-    "CompileEvent", "compile_events",
+    "CompileEvent", "compile_events", "process",
     "get_tracer", "get_registry", "enable", "enabled", "reset", "span",
     "export_dir",
 ]

@@ -107,8 +107,11 @@ class Counter(_Metric):
         return self._values.get(_label_key(labels), 0.0)
 
     def _snapshot(self) -> dict[str, float]:
+        # a copy: the gc hook (telemetry/process.py) counts a collection
+        # that starts inside this loop, on this thread, and the first of
+        # a generation adds a series
         return {_series_name(self.name, k): v
-                for k, v in self._values.items()}
+                for k, v in self._values.copy().items()}
 
 
 class Gauge(_Metric):
@@ -315,6 +318,6 @@ class MetricRegistry:
                             f"{_prom_series(m.name + '_sum', key)} "
                             f"{s['sum']}")
                 else:
-                    for key, v in m._values.items():
+                    for key, v in m._values.copy().items():
                         lines.append(f"{_prom_series(m.name, key)} {v}")
         return "\n".join(lines) + ("\n" if lines else "")

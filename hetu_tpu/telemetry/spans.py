@@ -88,6 +88,8 @@ DEFAULT_COUNTER_TRACK_PREFIXES = (
     "mem_", "comm_", "dp_grad_syncs_total", "optimizer_updates_total",
     "step_cache_", "tp_ring_fallback_total", "data_stall_seconds",
     "serving_", "slo_", "watchdog_",
+    # the process beside the loop (telemetry/process.py)
+    "process_", "gc_",
 )
 
 
@@ -152,7 +154,10 @@ class Tracer:
         self._events: list[SpanEvent] = []
         self._counters: list[tuple] = []   # (name, ts_s, value) samples
         self._track_names: dict[int, str] = {}   # synthetic-track labels
-        self._lock = threading.Lock()
+        # re-entrant: the gc hook (telemetry/process.py) records an
+        # event on whichever thread collects, at any bytecode boundary
+        # — also one inside a block that holds this lock
+        self._lock = threading.RLock()
         self._local = threading.local()
 
     # -- recording ----------------------------------------------------------

@@ -2086,16 +2086,22 @@ class LightningAttention(Module):
 
 def count_kda_steps(values, tokens=None) -> None:
     """:class:`KimiDeltaAttention`'s ``layer_stats`` on the host:
-    ``values (KDA layers, 2)`` — of each layer's scan call, the grid
-    steps that held a valid row and the grid steps run
-    (``ops.kda_pallas.hetu_kda_scan(return_steps=True)``; zeros from
-    the decode rows, which run no scan) — into
-    ``kda_scan_steps_total{kind}``."""
+    ``values (KDA layers, 4)`` — of each layer's call ``[live,
+    computed, advanced, stepped]``: a prefill pack's scan reports the
+    grid steps that held a valid row and the grid steps run
+    (``ops.kda_pallas.hetu_kda_scan(return_steps=True)``) and zeros
+    behind them, the decode rows' update zeros and then the live slots
+    it advanced and the slot steps of its grid
+    (``hetu_kda_update(return_steps=True)``) — into
+    ``kda_scan_steps_total{kind}`` and ``kda_update_slots_total{kind}``:
+    neither lane adds to the other's counter."""
     import numpy as np
     from hetu_tpu import telemetry
-    live, computed = np.asarray(values, np.int64).sum(axis=0).tolist()
+    live, computed, advanced, stepped = np.asarray(
+        values, np.int64).sum(axis=0).tolist()
+    reg = telemetry.get_registry()
     if computed:
-        c = telemetry.get_registry().counter(
+        c = reg.counter(
             "kda_scan_steps_total",
             "grid steps of the delta-rule scan kernel: live = (piece, "
             "head block) steps that held a valid row, computed = steps "
@@ -2103,6 +2109,16 @@ def count_kda_steps(values, tokens=None) -> None:
             "zeros), summed over layer calls")
         c.inc(float(live), kind="live")
         c.inc(float(computed), kind="computed")
+    if stepped:
+        c = reg.counter(
+            "kda_update_slots_total",
+            "slots of the delta-rule decode rows' update kernel: live = "
+            "slots whose state it advanced by a token (read once, "
+            "written once), stepped = slot steps of its grid (a step "
+            "behind the live ones moves nothing), summed over layer "
+            "calls")
+        c.inc(float(advanced), kind="live")
+        c.inc(float(stepped), kind="stepped")
 
 
 class KimiDeltaAttention(Module):
@@ -2125,17 +2141,20 @@ class KimiDeltaAttention(Module):
     slot held. The decode rows advance their slot by a token
     (``hetu.kda_update``), a prefill pack's tokens theirs in chunks
     (``hetu.kda_scan``), both behind ``hetu.kda_conv``; each addresses
-    the live slots of its own layer in the stacked leaves in place
-    (the scan is the Pallas kernel ``ops.kda_pallas.hetu_kda_scan``: one
-    call a layer call over the pack's pieces, a run's state in VMEM
-    across its chunks, interpreted on the CPU; ``ops.kda.kda_scan`` is
-    its oracle). No page is ever read or written. ``A_log``, ``dt_bias`` and the taps
+    the live slots of its own layer in the stacked leaves in place.
+    Both are ONE Pallas call a layer call on the state leaf where it
+    lies (``ops.kda_pallas``, interpreted on the CPU):
+    ``hetu_kda_update`` walks the live slots, each state read once and
+    written once; ``hetu_kda_scan`` walks the pack's pieces, a run's
+    state in VMEM across its chunks. ``ops.kda.kda_update`` and
+    ``ops.kda.kda_scan`` are their oracles and never run here. No page
+    is ever read or written. ``A_log``, ``dt_bias`` and the taps
     are drawn, not constants (a program that leaves one out must
     differ)."""
 
     cache_leaves = 2
     #: a cached call's third result (:func:`count_kda_steps`)
-    layer_stats = {"kda_steps": ((2,), jnp.int32, count_kda_steps)}
+    layer_stats = {"kda_steps": ((4,), jnp.int32, count_kda_steps)}
 
     def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
                  conv_size: int = 4, lower_bound: float = -5.0,
@@ -2262,17 +2281,20 @@ class KimiDeltaAttention(Module):
                 y, tail = kda.conv_pack(a, taps, tail, slot, pos, valid,
                                         layer=layer)
         q, k, v = self._qkv(y)
+        from hetu_tpu.ops.kda_pallas import hetu_kda_scan, hetu_kda_update
+        none = jnp.zeros((2,), jnp.int32)
         if slot is None:
             with jax.named_scope("hetu.kda_update"):
-                o, state = kda.kda_update(q, k, v, g, beta, state, valid,
-                                          layer=layer, fresh=pos == 0)
-            steps = jnp.zeros((2,), jnp.int32)
+                o, state, steps = hetu_kda_update(
+                    q, k, v, g, beta, state, valid, layer=layer,
+                    fresh=pos == 0, return_steps=True)
+            steps = jnp.concatenate([none, steps])
         else:
-            from hetu_tpu.ops.kda_pallas import hetu_kda_scan
             with jax.named_scope("hetu.kda_scan"):
                 o, state, steps = hetu_kda_scan(
                     q, k, v, g, beta, state, slot, pos, valid,
                     layer=layer, return_steps=True)
+            steps = jnp.concatenate([steps, none])
         return self._output(params, o, gate).reshape(x.shape), \
             (state, tail), {"kda_steps": steps}
 

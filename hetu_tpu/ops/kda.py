@@ -14,13 +14,15 @@ before ``v_t`` is written there (``ops.linear_attention`` only decays
 and adds).
 
 * :func:`kda_recurrence` — that, a token at a time (the oracle);
-* :func:`kda_update` — one token a slot: the decode rows;
+* :func:`kda_update` — one token a slot (the decode rows): a gather of
+  the live slots' states, the formula, a scatter;
 * :func:`kda_scan` — a PACK of tokens of several slots' runs, in chunks
-  of ``CHUNK`` pack rows: the ``jax.numpy`` chunk form. The prefill
-  lane runs the SAME arithmetic as one Pallas call
-  (``ops.kda_pallas.hetu_kda_scan``, interpreted on the CPU); this
-  form is that kernel's oracle beside the recurrence, and only tests
-  (and ``workloads/kda_bench.py``) reach it;
+  of ``CHUNK`` pack rows: the ``jax.numpy`` chunk form.
+  The layer runs the SAME arithmetic of both as one Pallas call each
+  on the state leaf in place (``ops.kda_pallas.hetu_kda_update``,
+  ``hetu_kda_scan``; interpreted on the CPU); these two forms are
+  those kernels' oracles beside the recurrence, and only tests and
+  ``workloads/kda_bench.py`` reach them;
 * :func:`conv_pack`, :func:`conv_rows` — the causal depthwise
   convolution over the tokens of one request, carried across packs by
   a slot's TAIL (the last ``taps - 1`` input rows).
@@ -117,7 +119,12 @@ def kda_update(q, k, v, g, beta, state, live, *, layer=None, fresh=None):
     are read and written (a gather and a scatter of their rows of that
     layer); a slot that is not live keeps its state and its row of ``o``
     is zeros' result. ``fresh`` ``(S,)`` bool: slots that start from a
-    zero state. Returns ``(o (S, H, dv) float32, new state)``."""
+    zero state. Returns ``(o (S, H, dv) float32, new state)``.
+
+    The oracle of ``ops.kda_pallas.hetu_kda_update``, which the layer
+    runs (the TPU compiler lowers this gather and scatter to a loop over
+    the slots, 2 MB a step): tests and ``workloads/kda_bench.py
+    --update`` alone reach it."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
     buf, layer = _stacked(state, layer)
     old, at = _live_rows(buf, layer, live, fresh)

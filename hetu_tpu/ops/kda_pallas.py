@@ -1,6 +1,12 @@
-"""Pallas TPU kernel of the gated delta rule's chunk form over a prefill
-pack (:func:`hetu_kda_scan`): what :class:`~hetu_tpu.nn.parallel.
-KimiDeltaAttention` runs under ``hetu.kda_scan``.
+"""The two Pallas TPU kernels of the gated delta rule: what
+:class:`~hetu_tpu.nn.parallel.KimiDeltaAttention` runs under
+``hetu.kda_scan`` — the chunk form over a prefill pack
+(:func:`hetu_kda_scan`, below) — and under ``hetu.kda_update`` — the
+decode rows' one token a slot on the state leaf IN PLACE
+(:func:`hetu_kda_update`: the grid walks the live slots,
+:func:`live_list`, by blocks of heads; a live slot's state read once
+and written once, nothing else of the leaf touched; its docstring has
+the rest). ``ops/kda.py`` holds both kernels' ``jax.numpy`` oracles.
 
 ``ops.kda.kda_scan`` (plain ``jax.numpy``, the oracle the tests hold
 this to) computed the chunk-local products for all chunks at once —
@@ -436,4 +442,128 @@ def hetu_kda_scan(q, k, v, g, beta, state, slot, pos, valid, *, layer=None,
     out = (o.reshape(Cp, H, dv)[:C], buf if state.ndim == 5 else buf[0])
     if return_steps:
         out += (jnp.stack([work.live, work.n]) * (H // hb),)
+    return out
+
+
+# -- the decode rows -----------------------------------------------------------
+def live_list(live):
+    """``live (S,)`` bool -> ``(ids (S,) int32, n (1,) int32)``: the
+    live slots in order, then zeros — compares and sums, no ``nonzero``,
+    no sort."""
+    S = live.shape[0]
+    rank = jnp.cumsum(live.astype(jnp.int32)) - 1
+    at = live[None, :] & (rank[None, :] == jnp.arange(S)[:, None])
+    ids = jnp.sum(jnp.where(at, jnp.arange(S, dtype=jnp.int32)[None, :], 0),
+                  axis=1, dtype=jnp.int32)
+    return ids, jnp.sum(live, dtype=jnp.int32).reshape(1)
+
+
+def _update_kernel(layer_ref, ids_ref, n_ref, fresh_ref, beta_ref, g_ref,
+                   k_ref, q_ref, v_ref, state_in, o_ref, state_out, x_ref,
+                   *, H, hb, at):
+    del layer_ref
+    r, first = pl.program_id(0), pl.program_id(1) * hb
+    n = n_ref[0]
+
+    @pl.when(r < n)
+    def _():
+        slot = ids_ref[r]
+        # e^g, k and q run along the lanes; the formula needs them down
+        # the state's rows: one transpose a step turns them all
+        x_ref[0:hb, :] = jnp.exp(g_ref[...])
+        x_ref[at:at + hb, :] = k_ref[...]
+        x_ref[2 * at:2 * at + hb, :] = q_ref[...]
+        cols = x_ref[...].T                                 # (dk, rows)
+        fresh = fresh_ref[slot] != 0
+        head = slot * H + first
+        for j in range(hb):
+            def col(i):
+                return cols[:, i * at + j:i * at + j + 1]   # (dk, 1)
+            s = col(0) * jnp.where(fresh, 0.0, state_in[j])
+            rest = v_ref[j:j + 1, :] - jnp.sum(col(1) * s, axis=0,
+                                               keepdims=True)
+            s = s + col(1) * (beta_ref[head + j] * rest)
+            state_out[j] = s
+            o_ref[j:j + 1, :] = jnp.sum(col(2) * s, axis=0, keepdims=True)
+
+    @pl.when(n == 0)         # (no live row: the one block named, as it is)
+    def _():
+        state_out[...] = state_in[...]
+
+
+def hetu_kda_update(q, k, v, g, beta, state, live, *, layer=None,
+                    fresh=None, head_block: Optional[int] = None,
+                    interpret: Optional[bool] = None,
+                    return_steps: bool = False):
+    """``ops.kda.kda_update``'s contract, as one Pallas call on the
+    state IN PLACE: one token a slot, ``q``, ``k``, ``g`` ``(S, H,
+    dk)``, ``v`` ``(S, H, dv)``, ``beta`` ``(S, H)``; ``state`` ``(S, H,
+    dk, dv)`` float32, or the STACKED leaf ``(layers, S, H, dk, dv)``
+    with ``layer=`` — aliased to the result. The grid walks the ``live``
+    slots (:func:`live_list`) by blocks of heads
+    (:func:`kda_head_block`): a live slot's state is read once and
+    written once, ``S <- Diag(e^g) S; r = v - k^T S; S <- S + (beta k)
+    r^T; o = S^T q`` on its tile in VMEM, all of it float32 on the VPU
+    (no dot). A slot that is not live is never fetched — the steps
+    behind the live ones name the last live block again, which moves
+    nothing — and its row of ``o`` is zeros; a ``fresh`` slot starts
+    from a zero state whatever it held.
+
+    Returns ``(o (S, H, dv) float32, new state)`` and, with
+    ``return_steps``, ``[live, stepped]`` int32: the slots advanced and
+    the slot steps of the grid."""
+    q, k, v, g, beta = _f32(q, k, v, g, beta)
+    S, H, dk = q.shape
+    dv = v.shape[-1]
+    interpret = _interpret_default() if interpret is None else interpret
+    if not interpret and (dk % 128 or dv % 128):
+        raise ValueError(
+            f"hetu_kda_update compiled for a TPU takes head sizes of whole "
+            f"lane tiles (multiples of 128); got dk={dk}, dv={dv}")
+    hb = kda_head_block(H, dk, dv) if head_block is None else head_block
+    if H % hb:
+        raise ValueError(f"head_block {hb} does not divide {H} heads")
+    buf, layer = _stacked(state, layer)
+    nh = H // hb
+    ids, n = live_list(live)
+    fresh = jnp.zeros((S,), jnp.int32) if fresh is None \
+        else fresh.astype(jnp.int32)
+    scalars = (layer.reshape(1), ids, n, fresh, beta.reshape(S * H))
+
+    def block(r, i, layer, ids, n, *_):
+        """The (slot, head block) of a step: behind the live slots, the
+        last live one's last again."""
+        return (ids[jnp.minimum(r, jnp.maximum(n[0] - 1, 0))],
+                jnp.where(r < n[0], i, nh - 1))
+
+    def rows(d):
+        return pl.BlockSpec((None, None, hb, d),
+                            lambda *a: block(*a) + (0, 0))
+    leaf = pl.BlockSpec((None, None, hb, dk, dv),
+                        lambda r, i, layer, *a: (layer[0],) + block(
+                            r, i, layer, *a) + (0, 0))
+    # the rows of e^g, k, q as one matrix of whole tiles to transpose:
+    # each quantity's rows start a sublane tile
+    at = -(-hb // 8) * 8
+    o, buf = pl.pallas_call(
+        functools.partial(_update_kernel, H=H, hb=hb, at=at),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(S, nh),
+            in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), leaf],
+            out_specs=[rows(dv), leaf],
+            scratch_shapes=[pltpu.VMEM((-(-3 * at // 128) * 128, dk),
+                                       jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((S, nh, hb, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(buf.shape, jnp.float32)],
+        input_output_aliases={len(scalars) + 4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="hetu_kda_update",
+    )(*scalars, *(a.reshape(S, nh, hb, -1) for a in (g, k, q, v)), buf)
+    o = jnp.where(live[:, None, None], o.reshape(S, H, dv), 0.0)
+    out = (o, buf if state.ndim == 5 else buf[0])
+    if return_steps:
+        out += (jnp.stack([n[0], jnp.int32(S)]),)
     return out

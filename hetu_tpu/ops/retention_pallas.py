@@ -32,10 +32,10 @@ The kernel hands out numerators and normaliser ``(Hkv, G, R, C)``; the
 division is the caller's (XLA fuses it with the transpose back).
 
 :func:`hetu_retention_update` — the decode rows, ON THE LEAF IN PLACE
-(``input_output_aliases``): the grid walks the LIVE slots (a list made
-with compares and sums), kv heads and tiles of feature rows; a live
-slot's state is read once and written once, ``g M + [v, 1] phi(k)``,
-and read out against ``phi(q_i)`` on the VPU as it passes (the MXU
+(``input_output_aliases``): the grid walks the LIVE slots
+(``kda_pallas.live_list``: compares and sums), kv heads and tiles of
+feature rows; a live slot's state is read once and written once, ``g M
++ [v, 1] phi(k)``, and read out against ``phi(q_i)`` on the VPU as it passes (the MXU
 would push a 136-row weight tile for 8 rows of queries). A slot that is
 not live is never fetched: the steps behind the live ones name the last
 live block again, which moves nothing. ``phi`` of the S decode rows —
@@ -63,7 +63,7 @@ from jax.experimental.pallas import tpu as pltpu
 from hetu_tpu.ops.flash_pallas import _interpret_default
 from hetu_tpu.ops.kda import _stacked
 from hetu_tpu.ops.kda_pallas import (
-    _CLOSES, _OPENS, _ZERO, scan_work_list,
+    _CLOSES, _OPENS, _ZERO, live_list, scan_work_list,
 )
 from hetu_tpu.ops.retention import (
     feature_rows, phi_tiles, value_rows, values_one,
@@ -316,18 +316,6 @@ def _update_kernel(layer_ref, ids_ref, n_ref, g_ref, fk_ref, fq_ref, v_ref,
     @pl.when(n == 0)         # (no live row: the one block named, as it is)
     def _():
         state_out[...] = state_in[...]
-
-
-def live_list(live):
-    """``live (S,)`` bool -> ``(ids (S,) int32, n (1,) int32)``: the
-    live slots in order, then zeros — compares and sums, no ``nonzero``,
-    no sort."""
-    S = live.shape[0]
-    rank = jnp.cumsum(live.astype(jnp.int32)) - 1
-    at = live[None, :] & (rank[None, :] == jnp.arange(S)[:, None])
-    ids = jnp.sum(jnp.where(at, jnp.arange(S, dtype=jnp.int32)[None, :], 0),
-                  axis=1, dtype=jnp.int32)
-    return ids, jnp.sum(live, dtype=jnp.int32).reshape(1)
 
 
 def hetu_retention_update(q, k, v, log_g, state, live, *, eps: float,

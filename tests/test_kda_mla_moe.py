@@ -292,7 +292,10 @@ def test_engine_serves_tokens_the_reference_puts_on_top(tiny):
 
 def test_the_counter_counts_a_served_requests_steps(tiny):
     """``kda_scan_steps_total{kind}`` on a served request: computed =
-    the grid steps run, live of them held a valid row."""
+    the grid steps run, live of them held a valid row. And
+    ``kda_update_slots_total{kind}``: live = decode rows x KDA layers,
+    stepped = slots x KDA layers a decode call — neither lane adds to
+    the other's counter."""
     from hetu_tpu import telemetry
     from hetu_tpu.serving import SamplingParams, ServingEngine
     _, model, params = tiny
@@ -302,13 +305,16 @@ def test_the_counter_counts_a_served_requests_steps(tiny):
 
         def read():
             c = reg.counter("kda_scan_steps_total")
-            return [c.value(kind=k) for k in ("live", "computed")]
+            u = reg.counter("kda_update_slots_total")
+            return [c.value(kind=k) for k in ("live", "computed")] + [
+                u.value(kind=k) for k in ("live", "stepped")]
         before = read()
         eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
                             block_size=4, slots=2, kv_blocks=40, seed=0)
         eng.generate_many([list(range(1, 12))],
                           SamplingParams(max_tokens=3))
-        live, computed = (a - b for a, b in zip(read(), before))
+        live, computed, advanced, stepped = (
+            a - b for a, b in zip(read(), before))
     finally:
         telemetry.enable(False)
     # 11 tokens in two packs of 8 rows, each one chunk: one live piece a
@@ -317,6 +323,10 @@ def test_the_counter_counts_a_served_requests_steps(tiny):
     kda_layers = model.blocks.layers_of["kda"]
     assert 0 < live <= computed
     assert live == computed == 2 * kda_layers
+    # the second pack samples the first token; the other two are decode
+    # rows of ONE live slot of the engine's two
+    assert advanced == 2 * kda_layers
+    assert stepped == 2 * advanced
 
 
 def _moe(**kw):

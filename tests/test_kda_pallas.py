@@ -6,8 +6,13 @@ piece list — two runs in one chunk beside a run that restarts at
 position 0 in a slot whose state is not zero, a whole chunk at the decay
 bound, a pack with no valid row, a FULL piece list (the spare entry);
 dead slots' states and the other layers of the stacked leaf to the bit;
-the head-block rule (the counter on a served request is counted in
-``test_kda_mla_moe.py``, beside the engine it already builds)."""
+the head-block rule (the counters on a served request are counted in
+``test_kda_mla_moe.py``, beside the engine it already builds). And the
+decode rows' update kernel (ISSUE 54) against ``kda.kda_update`` AND one
+step of the recurrence: dead slots' states to the bit and their rows of
+``o`` zeros, fresh slots over a state that is not zero, no live slot at
+all, a full list, ``layer=`` on a stacked leaf, head blocks, the decay
+bound."""
 
 import os
 import sys
@@ -21,7 +26,9 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from hetu_tpu.ops import kda  # noqa: E402
 from hetu_tpu.ops import kda_pallas  # noqa: E402
-from hetu_tpu.ops.kda_pallas import hetu_kda_scan  # noqa: E402
+from hetu_tpu.ops.kda_pallas import (  # noqa: E402
+    hetu_kda_scan, hetu_kda_update,
+)
 from test_kda_mla_moe import H, D, _draw, _pack  # noqa: E402
 
 SLOTS = 4
@@ -185,9 +192,98 @@ def test_head_block_rule_and_the_refusal_by_name():
         hetu_kda_scan(*x, jnp.zeros((1, H, D, D)), *where, head_block=3)
 
 
+#: the decode rows: ``(live, fresh, layer of a stacked leaf or None,
+#: head block or None — the rule's —, g at the decay bound)`` over
+#: ``ROWS`` slots
+ROWS = 6
+_T, _F = True, False
+UPDATES = {
+    "all-live": ([_T] * ROWS, [_F] * ROWS, None, None, False),
+    "some-dead": ([_T, _F, _T, _T, _F, _T], [_F] * ROWS, None, None, False),
+    # slots 2 and 5 start again from zeros over a state that is not zero;
+    # slot 1 is fresh but NOT live: it keeps what it held
+    "fresh": ([_T, _F, _T, _F, _T, _T], [_F, _T, _T, _F, _F, _T], None,
+              None, False),
+    "none-live": ([_F] * ROWS, [_F] * ROWS, 1, None, False),
+    "none-live-one-layer": ([_F] * ROWS, [_T] * ROWS, None, None, False),
+    # every slot live: the list is FULL, no step names a block again
+    "full-list-stacked": ([_T] * ROWS, [_T] + [_F] * (ROWS - 1), 2, None,
+                          False),
+    "stacked-first-layer": ([_F, _T, _T, _F, _F, _T], [_F] * ROWS, 0, None,
+                            False),
+    "head-block-1": ([_T, _F, _T, _T, _F, _F], [_F, _F, _T, _F, _F, _F], 1,
+                     1, False),
+    "head-block-2": ([_F, _F, _T, _T, _T, _F], [_F] * ROWS, None, 2, False),
+    "decay-bound": ([_T, _T, _F, _T, _T, _T], [_F] * ROWS, None, None,
+                    True),
+}
+
+
+@pytest.mark.parametrize("name", list(UPDATES))
+def test_update_kernel_equals_the_update_and_a_step_of_the_recurrence(name):
+    live, fresh, layer, head_block, at_bound = UPDATES[name]
+    x = _draw(jax.random.key(6), ROWS, at_bound=at_bound)
+    state0 = jax.random.normal(jax.random.key(7), (ROWS, H, D, D))
+    live, fresh = jnp.asarray(live), jnp.asarray(fresh)
+    kw = dict(fresh=fresh, head_block=head_block, return_steps=True)
+    if layer is None:
+        o, st, steps = jax.jit(lambda *a: hetu_kda_update(*a, **kw))(
+            *x, state0, live)
+        o2, st2 = jax.jit(kda.kda_update)(*x, state0, live, fresh=fresh)
+    else:
+        buf0 = jnp.stack([state0 + float(i + 1) for i in range(3)]) \
+            .at[layer].set(state0)
+        at = jnp.int32(layer)
+        o, buf, steps = jax.jit(lambda *a: hetu_kda_update(
+            *a, layer=at, **kw))(*x, buf0, live)
+        o2, buf2 = jax.jit(lambda *a: kda.kda_update(
+            *a, layer=at, fresh=fresh))(*x, buf0, live)
+        # the other layers of the leaf to the bit
+        others = [i for i in range(3) if i != layer]
+        assert (np.asarray(buf)[others] == np.asarray(buf0)[others]).all()
+        st, st2 = buf[layer], buf2[layer]
+    assert steps.tolist() == [int(live.sum()), ROWS]
+    for s in range(ROWS):
+        if not live[s]:
+            # not live: the state to the bit (fresh or not), zeros of o
+            assert (np.asarray(st[s]) == np.asarray(state0[s])).all()
+            assert (np.asarray(o[s]) == 0).all()
+            continue
+        want_o, want_s = kda.kda_recurrence(
+            *(a[s:s + 1] for a in x),
+            state=None if fresh[s] else state0[s])
+        assert np.isfinite(np.asarray(o[s])).all()
+        np.testing.assert_allclose(o[s], want_o[0], atol=2e-6)
+        np.testing.assert_allclose(st[s], want_s, atol=2e-6)
+        np.testing.assert_allclose(o[s], o2[s], atol=2e-6)
+    np.testing.assert_allclose(st, st2, atol=2e-6)
+
+
+def test_update_kernel_refuses_by_name():
+    x = _draw(jax.random.key(5), ROWS)
+    state, live = jnp.zeros((ROWS, H, D, D)), jnp.ones(ROWS, bool)
+    with pytest.raises(ValueError, match="hetu_kda_update.*whole lane "
+                       "tiles.*dk=16"):
+        hetu_kda_update(*x, state, live, interpret=False)
+    with pytest.raises(ValueError, match="does not divide"):
+        hetu_kda_update(*x, state, live, head_block=3)
+
+
+def test_live_list_has_one_definition():
+    """Both decode-row kernels walk the same list."""
+    from hetu_tpu.ops import retention_pallas
+    assert retention_pallas.live_list is kda_pallas.live_list
+    ids, n = kda_pallas.live_list(jnp.asarray([_F, _T, _F, _T, _T]))
+    assert ids.tolist() == [1, 3, 4, 0, 0] and n.tolist() == [3]
+
+
 def test_every_dot_of_the_kernel_is_float32_at_the_highest_precision():
-    """The configuration's precision, read from the kernel's jaxpr."""
+    """The configuration's precision, read from the kernels' jaxprs (the
+    decode rows' update has no dot at all: it runs on the VPU)."""
     x = _draw(jax.random.key(5), kda.CHUNK)
+    update = jax.make_jaxpr(lambda *a: hetu_kda_update(*a))(
+        *_draw(jax.random.key(5), ROWS), jnp.zeros((ROWS, H, D, D)),
+        jnp.ones(ROWS, bool))
     jaxpr = jax.make_jaxpr(lambda *a: hetu_kda_scan(*a))(
         *x, jnp.zeros((1, H, D, D)), jnp.zeros(kda.CHUNK, jnp.int32),
         jnp.arange(kda.CHUNK, dtype=jnp.int32), jnp.ones(kda.CHUNK, bool))
@@ -202,6 +298,8 @@ def test_every_dot_of_the_kernel_is_float32_at_the_highest_precision():
                     sub = getattr(sub, "jaxpr", sub)
                     if hasattr(sub, "eqns"):
                         walk(sub)
+    walk(update.jaxpr)
+    assert dots == []
     walk(jaxpr.jaxpr)
     # 4 row blocks, 10 of the solve, M [K e^G], M V, [W; Q e^G] S_0,
     # P U, S_C

@@ -221,10 +221,13 @@ def test_ling_step_holds_the_scan_kernel_a_kda_layer_run_on_v5e(one_chip):
     that the host holds its zeros) with the cell's 2,048-token pack:
     under ``hetu.kda_scan`` the prefill lane holds ONE Pallas call a KDA
     layer run (``hetu_kda_scan``: the unscanned layer's and the scan
-    body's) and the decode lane none, it compiles within its VMEM
-    limit, and nothing copies or slices a layer of the state leaf out
-    of or into it — the kernel reads ``[layer, slot]`` by DMA from the
-    leaf where it lies."""
+    body's) and the decode lane none; under ``hetu.kda_update`` the
+    decode lane holds ONE Pallas call a KDA layer run too
+    (``hetu_kda_update``) and no ``while`` (the gather of 72 states was
+    a loop of 2 MB slices); it compiles within its VMEM limit, and
+    nothing copies or slices a layer of the state leaf out of or into
+    it — the kernels address ``[layer, slot]`` of the leaf where it
+    lies."""
     import json
     import os
     import re
@@ -249,6 +252,12 @@ def test_ling_step_holds_the_scan_kernel_a_kda_layer_run_on_v5e(one_chip):
     assert calls["hetu.prefill_lane>hetu.kda_scan"] == 2, calls
     assert "hetu.decode_lane>hetu.kda_scan" not in calls, calls
     assert len(re.findall(r"%hetu_kda_scan[.\d]* = ", r["text"])) == 2
+    assert calls["hetu.decode_lane>hetu.kda_update"] == 2, calls
+    assert "hetu.prefill_lane>hetu.kda_update" not in calls, calls
+    assert len(re.findall(r"%hetu_kda_update[.\d]* = ", r["text"])) == 2
+    loops = [line for line in r["text"].splitlines()
+             if "hetu.kda_update" in line and " while(" in line]
+    assert loops == [], loops
     # the state leaf (5 KDA layers) and a layer of it, as a result shape
     leaf = rf"f32\[(5,|1,)?{slots},32,128,128\]"
     moved = [m.group(1) for m in re.finditer(

@@ -10,6 +10,12 @@ section 6, PR 46, has the chip's table).
     chiprun -- python workloads/kda_bench.py [--heads 2 4 8] [pack ...]
     python workloads/kda_bench.py --aot          # compile for a v5e, no chip
 
+``--update``: the decode rows' update ALONE instead (the
+``hetu.kda_update`` scope: one token a slot, 72 slots of which 64 are
+live and one fresh) — ``ops.kda.kda_update``, a gather, the formula and
+a scatter, beside ``ops.kda_pallas.hetu_kda_update`` at each head block
+(``PERF.md`` section 6, PR 54); with ``--aot`` it compiles that.
+
 ``--dots``: the float32 highest-precision dot inside a kernel alone —
 ``(64, 128) @ (128, 128)`` a head in a loop, and batched over heads.
 ``--profile``: every form's device time by instruction (the kernel's
@@ -36,6 +42,8 @@ import numpy as np
 #: Ling-3.0-flash-VL's KDA layers in the video cell: tokens a pack,
 #: heads, head size, slots, KDA layers
 C, H, D, SLOTS, LAYERS = 2048, 32, 128, 72, 10
+#: slots that decode beside the pack
+LIVE = 64
 #: runs ``(slot, first position, tokens)`` in pack order
 PACKS = {
     "one-run": [(3, 2048, 2048)],
@@ -44,25 +52,41 @@ PACKS = {
 }
 
 
-def draw(key, runs, sharding=None):
-    """Operands as the mixer makes them (unit q, k; g in (-5, 0))."""
+def operands(key, rows):
+    """``rows`` tokens' operands as the mixer makes them (unit q, k; g
+    in (-5, 0))."""
     ks = jax.random.split(key, 6)
 
     def normal(k, shape):
         return jax.random.normal(k, shape, jnp.float32)
-    q, k, v = (normal(ks[i], (C, H, D)) for i in range(3))
+    q, k, v = (normal(ks[i], (rows, H, D)) for i in range(3))
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    g = -5.0 * jax.nn.sigmoid(2.0 * normal(ks[3], (C, H, D)))
-    beta = jax.nn.sigmoid(normal(ks[4], (C, H)))
+    g = -5.0 * jax.nn.sigmoid(2.0 * normal(ks[3], (rows, H, D)))
+    return q, k, v, g, jax.nn.sigmoid(normal(ks[4], (rows, H)))
+
+
+def draw(key, runs):
+    """A pack's operands and where its rows stand."""
     slot, pos = np.zeros(C, np.int32), np.zeros(C, np.int32)
     valid, used = np.zeros(C, bool), 0
     for s, p0, n in runs:
         slot[used:used + n], valid[used:used + n] = s, True
         pos[used:used + n] = np.arange(p0, p0 + n)
         used += n
-    return (q, k, v, g, beta), (jnp.asarray(slot), jnp.asarray(pos),
-                                jnp.asarray(valid))
+    return operands(key, C), (jnp.asarray(slot), jnp.asarray(pos),
+                              jnp.asarray(valid))
+
+
+def draw_rows(key):
+    """The decode rows' operands (one token a slot) and which slots are
+    live — ``LIVE`` of them, spread over the leaf — and fresh (one)."""
+    dead = np.linspace(0, SLOTS - 1, SLOTS - LIVE).round().astype(int)
+    live = np.ones(SLOTS, bool)
+    live[dead] = False
+    fresh = np.zeros(SLOTS, bool)
+    fresh[np.flatnonzero(live)[1]] = True
+    return operands(key, SLOTS), (jnp.asarray(live), jnp.asarray(fresh))
 
 
 def state_leaf(key):
@@ -77,6 +101,21 @@ def forms(heads):
     for hb in heads or [kda_head_block(H, D, D)]:
         out[f"kernel/{hb}"] = functools.partial(hetu_kda_scan,
                                                 head_block=hb)
+    return out
+
+
+def update_forms(heads):
+    """The decode rows' update: the ``jax.numpy`` form (a gather, the
+    formula, a scatter) beside the kernel at each head block."""
+    from hetu_tpu.ops import kda
+    from hetu_tpu.ops.kda_pallas import hetu_kda_update, kda_head_block
+
+    def on(fn, **kw):
+        return lambda *a, layer: fn(*a[:-1], layer=layer, fresh=a[-1],
+                                    **kw)
+    out = {"jnp": on(kda.kda_update)}
+    for hb in heads or [kda_head_block(H, D, D)]:
+        out[f"kernel/{hb}"] = on(hetu_kda_update, head_block=hb)
     return out
 
 
@@ -105,7 +144,12 @@ def aot_main(args):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
     ops = (sds((C, H, D)),) * 4 + (sds((C, H)),)
     where = (sds((C,), jnp.int32), sds((C,), jnp.int32), sds((C,), bool))
-    for name, fn in forms(args.heads).items():
+    which = forms
+    if args.update:
+        ops = (sds((SLOTS, H, D)),) * 4 + (sds((SLOTS, H)),)
+        where = (sds((SLOTS,), bool),) * 2
+        which = update_forms
+    for name, fn in which(args.heads).items():
         t0 = time.perf_counter()
         c = looped(fn, 2).lower(ops, sds((LAYERS, SLOTS, H, D, D)),
                                 where).compile()
@@ -207,11 +251,35 @@ def profile_main(args):
                       f"{[round(w * 1e-6, 3) for w in whiles[:LAYERS + 2]]}")
 
 
+def timed(label, which, ops, where, slots, rows, calls):
+    """Each form's ms a call over ``calls`` chained layer calls, and the
+    largest difference of its first call from the ``jax.numpy`` form's
+    on ``rows`` of ``o`` and on the states of ``slots``."""
+    first = {}
+    for name, fn in which.items():
+        one, many = looped(fn, 1), looped(fn, calls)
+        buf, o = one(ops, state_leaf(jax.random.key(1)), where)
+        first[name] = (o[rows], buf[0, slots])
+        buf = jax.block_until_ready(many(ops, buf, where))[0]
+        t0 = time.perf_counter()
+        buf = jax.block_until_ready(many(ops, buf, where))[0]
+        ms = (time.perf_counter() - t0) / calls * 1e3
+        del buf
+        diff = "" if name == "jnp" else (
+            f"  max |o - jnp's| "
+            f"{float(jnp.abs(first[name][0] - first['jnp'][0]).max()):.2e}"
+            f", state "
+            f"{float(jnp.abs(first[name][1] - first['jnp'][1]).max()):.2e}")
+        print(f"{label:10s} {name:10s} {ms:8.3f} ms a call{diff}",
+              flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--aot", action="store_true")
     ap.add_argument("--dots", action="store_true")
     ap.add_argument("--profile", action="store_true")
+    ap.add_argument("--update", action="store_true")
     ap.add_argument("--heads", type=int, nargs="*")
     ap.add_argument("--calls", type=int, default=40)
     ap.add_argument("packs", nargs="*")
@@ -224,24 +292,17 @@ def main():
         dots_main(args)
     if args.profile:
         profile_main(args)
+    if args.update:
+        # one layer call of the decode rows at the cell's shape; the
+        # rows of slots that are not live differ by contract
+        ops, where = draw_rows(jax.random.key(0))
+        live = np.flatnonzero(np.asarray(where[0]))
+        return timed(f"{LIVE}/{SLOTS}-live", update_forms(args.heads), ops,
+                     where, live, live, args.calls)
     for pack in args.packs or PACKS:
         ops, where = draw(jax.random.key(0), PACKS[pack])
-        first = {}
-        for name, fn in forms(args.heads).items():
-            one, many = looped(fn, 1), looped(fn, args.calls)
-            buf, o = one(ops, state_leaf(jax.random.key(1)), where)
-            first[name] = (o, buf[0, [r[0] for r in PACKS[pack]]])
-            buf = jax.block_until_ready(many(ops, buf, where))[0]
-            t0 = time.perf_counter()
-            buf = jax.block_until_ready(many(ops, buf, where))[0]
-            ms = (time.perf_counter() - t0) / args.calls * 1e3
-            del buf
-            diff = "" if name == "jnp" else (
-                f"  max |o - jnp's| "
-                f"{float(jnp.abs(o - first['jnp'][0]).max()):.2e}, state "
-                f"{float(jnp.abs(first[name][1] - first['jnp'][1]).max()):.2e}")
-            print(f"{pack:10s} {name:10s} {ms:8.3f} ms a call{diff}",
-                  flush=True)
+        timed(pack, forms(args.heads), ops, where,
+              [r[0] for r in PACKS[pack]], slice(None), args.calls)
 
 
 if __name__ == "__main__":

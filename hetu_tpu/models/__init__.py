@@ -1,8 +1,10 @@
 """Model zoo: GPT-2, Llama, Command A+ (cohere2_moe) and latent-attention
 (MLA) expert decoders, MiniCPM-SALA, delta-rule / latent hybrids,
-SDAR-MoE, which generates by diffusion over blocks, and Brumby, whose
-every layer is power retention (``mla_moe``, ``minicpm_sala``,
-``kda_mla_moe``, ``sdar_moe``, ``brumby``: loaded on first use,
+SDAR-MoE, which generates by diffusion over blocks, Brumby, whose
+every layer is power retention, and Jamba, whose layers are Mamba
+selective scans beside a few attention layers (``mla_moe``,
+``minicpm_sala``, ``kda_mla_moe``, ``sdar_moe``, ``brumby``,
+``jamba``: loaded on first use,
 so that the cells that never build one do not pay for its import).
 
 The four drawn decoders share one shell (``decoder.DecoderLM``); the
@@ -36,7 +38,9 @@ _LAZY = {"MLAMoEConfig": "hetu_tpu.models.mla_moe",
          "SDARMoEConfig": "hetu_tpu.models.sdar_moe",
          "SDARMoEForCausalLM": "hetu_tpu.models.sdar_moe",
          "BrumbyConfig": "hetu_tpu.models.brumby",
-         "BrumbyForCausalLM": "hetu_tpu.models.brumby"}
+         "BrumbyForCausalLM": "hetu_tpu.models.brumby",
+         "JambaConfig": "hetu_tpu.models.jamba",
+         "JambaForCausalLM": "hetu_tpu.models.jamba"}
 
 
 def __getattr__(name):
@@ -53,4 +57,5 @@ __all__ = ["GPTConfig", "GPTLMHeadModel", "LlamaConfig", "BertConfig", "BertMode
            "KDAMLAMoEConfig", "KDAMLAMoEForCausalLM",
            "SDARMoEConfig", "SDARMoEForCausalLM",
            "BrumbyConfig", "BrumbyForCausalLM",
+           "JambaConfig", "JambaForCausalLM",
            "generate", "decode", "init_kv_caches"]

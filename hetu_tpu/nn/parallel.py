@@ -2486,6 +2486,254 @@ class PowerRetention(Module):
         return self._output(params, y), (buf,), {"retention": stats}
 
 
+def count_ssm_steps(values, tokens=None) -> None:
+    """:class:`MambaMixer`'s ``layer_stats`` on the host: ``values
+    (Mamba layers, 4)`` — of each layer's call ``[live, computed,
+    advanced, stepped]``, as :func:`count_kda_steps` reads the delta
+    rule's: a prefill pack's scan reports the grid steps that held a
+    valid row and the grid steps run
+    (``ops.selective_scan_pallas.hetu_selective_scan(return_steps=
+    True)``) and zeros behind them, the decode rows' update zeros and
+    then the live slots it advanced and the slot steps of its grid —
+    into ``ssm_scan_steps_total{kind}`` and
+    ``ssm_update_slots_total{kind}``."""
+    import numpy as np
+    from hetu_tpu import telemetry
+    live, computed, advanced, stepped = np.asarray(
+        values, np.int64).sum(axis=0).tolist()
+    reg = telemetry.get_registry()
+    if computed:
+        c = reg.counter(
+            "ssm_scan_steps_total",
+            "grid steps of the selective-scan kernel: live = (piece, "
+            "channel block) steps that held a valid row, computed = "
+            "steps run (a chunk without a valid row costs one that "
+            "writes zeros), summed over layer calls")
+        c.inc(float(live), kind="live")
+        c.inc(float(computed), kind="computed")
+    if stepped:
+        c = reg.counter(
+            "ssm_update_slots_total",
+            "slots of the selective scan's decode-row update kernel: "
+            "live = slots whose state it advanced by a token (read "
+            "once, written once), stepped = slot steps of its grid (a "
+            "step behind the live ones moves nothing), summed over "
+            "layer calls")
+        c.inc(float(advanced), kind="live")
+        c.inc(float(stepped), kind="stepped")
+
+
+class MambaMixer(Module):
+    """The Mamba-1 mixer of the Jamba family (``ops.selective_scan``;
+    Gu & Dao, arXiv:2312.00752; Jamba, arXiv:2403.19887, whose one
+    addition is the three inner norms): a selective scan over a
+    DIAGONAL per-slot state behind a short causal convolution. With
+    ``u`` the block's normed input, ``D`` inner channels, ``N``
+    states::
+
+        [x | z] = u W_in
+        x <- SiLU(conv(x) + b_conv)                 a channel at a time
+        [r | B | C] = x W_x;  r, B, C <- RMSNorm    each its own gain
+        dt = softplus(r W_dt + b_dt),  A = -exp(A_log)
+        h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+        y_t[c] = sum_n h_t[n, c] C_t[n] + D[c] x_t[c]
+        out = (y * SiLU(z)) W_out
+
+    What is cached is a SLOT's, in TWO leaves (:meth:`init_leaves`):
+    the float32 state ``(layers, slots, N, R, 128)`` — a state ``n``'s
+    channels in ``R = D / 128`` rows of 128 lanes
+    (``ops.selective_scan_pallas.state_tiles``: 16 x 40 x 128 at 5120
+    channels, 327,680 B, nothing padded) — and the convolution's TAIL
+    ``(layers, slots, tail_rows, D)``, the last inputs of the
+    convolution: ``taps - 1`` of them in whole groups of four (3 -> 4;
+    the window runs over ``tail_rows + 1`` taps whose first are zero —
+    a leaf of 3 rows the TPU compiler re-lays whole at the step's entry
+    and exit, two copies of 28.7 MB a step at the served size, a leaf
+    of 4 rows it leaves where it lies). A run that starts at position 0 starts from a zero
+    state AND a zero tail, whatever its slot held. The decode rows
+    advance their slot by a token (``hetu.ssm_update``), a prefill
+    pack's tokens theirs one after another (``hetu.ssm_scan``), both
+    behind ``hetu.ssm_conv`` (``ops.kda``'s three convolution
+    functions, the bias added behind them); each is ONE Pallas call a
+    layer call on the state leaf where it lies
+    (``ops.selective_scan_pallas``, interpreted on the CPU), and
+    ``ops.selective_scan``'s forms are their oracles and never run
+    here. No page is ever read or written.
+
+    ``A_log``, ``b_dt``, the taps and ``D`` are DRAWN as the family
+    initialises them: ``A_log[n, c] = log(n + 1)``, ``b_dt`` the inverse
+    softplus of a step drawn log-uniform in ``dt_range`` — horizons from
+    under a token to a thousand tokens across channels and states, so a
+    program that loses the state between chunks, drops the tail or
+    leaves the inner norms out must differ. The state, ``dt``, ``A``,
+    the decays and the sum over ``n`` are float32; the projections take
+    the compute dtype's operands and accumulate in float32 (``x_proj``
+    and ``dt_proj`` keep their float32 results)."""
+
+    cache_leaves = 2
+    #: a cached call's third result (:func:`count_ssm_steps`)
+    layer_stats = {"ssm_steps": ((4,), jnp.int32, count_ssm_steps)}
+
+    def __init__(self, embed_dim: int, *, d_state: int = 16,
+                 d_conv: int = 4, expand: int = 2,
+                 dt_rank: Optional[int] = None, conv_bias: bool = True,
+                 norm_eps: float = 1e-6, dt_range=(1e-3, 1e-1),
+                 init=None):
+        super().__init__()
+        from hetu_tpu.nn.module import ones_init
+        from hetu_tpu.ops.selective_scan_pallas import state_tiles
+        self.d_inner, self.d_state = expand * embed_dim, d_state
+        self.d_conv, self.norm_eps = d_conv, norm_eps
+        self.dt_rank = dt_rank or -(-embed_dim // 16)
+        self.conv_bias = conv_bias
+        self._tiles = state_tiles(self.d_inner)
+        #: rows of a slot's tail: the window's history in fours
+        self.tail_rows = -(-(d_conv - 1) // 4) * 4
+        init = init or normal_init(0.02)
+        D, N, r = self.d_inner, d_state, self.dt_rank
+        self.in_proj = ColumnParallelLinear(
+            embed_dim, 2 * D, bias=False, init=init, axis="heads",
+            out_kind="hidden")
+        self.x_proj = ColumnParallelLinear(
+            D, r + 2 * N, bias=False, init=init, axis=None,
+            out_kind="hidden")
+        self.dt_proj = ColumnParallelLinear(
+            r, D, bias=False, init=init, axis=None, out_kind="hidden")
+        self.out_proj = RowParallelLinear(D, embed_dim, bias=False,
+                                          init=init, axis="heads")
+        # (float32 whatever the weights are served in: they shape the
+        # window, the step and the decays)
+        self.param("conv", (d_conv, D), normal_init(d_conv ** -0.5),
+                   dtype=jnp.float32)
+        if conv_bias:
+            self.param("conv_bias", (D,), normal_init(0.1),
+                       dtype=jnp.float32)
+
+        def a_log(key, shape, dtype):
+            del key
+            return jnp.broadcast_to(jnp.log(jnp.arange(
+                1, shape[0] + 1, dtype=jnp.float32))[:, None],
+                shape).astype(dtype)
+        self.param("A_log", (N, D), a_log, dtype=jnp.float32)
+        lo, hi = (math.log(v) for v in dt_range)
+
+        def dt_bias(key, shape, dtype):
+            dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
+            return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+        self.param("dt_bias", (D,), dt_bias, dtype=jnp.float32)
+        self.param("D", (D,), ones_init(), dtype=jnp.float32)
+        for name, n in (("dt_gain", r), ("b_gain", N), ("c_gain", N)):
+            self.param(name, (n,), ones_init())
+
+    def kv_leaf_shapes(self) -> tuple:
+        """No leaf a token: the state and the tail are a slot's."""
+        return ()
+
+    def state_bytes(self) -> int:
+        """Bytes a slot's state and tail hold in one layer."""
+        return 4 * self.d_inner * (self.d_state + self.tail_rows)
+
+    def init_leaves(self, layers: int, slots: int, sharding=None) -> tuple:
+        return (jnp.zeros((layers, slots, self.d_state) + self._tiles,
+                          jnp.float32, device=sharding),
+                jnp.zeros((layers, slots, self.tail_rows, self.d_inner),
+                          jnp.float32, device=sharding))
+
+    def _accumulated(self, name, params, x):
+        """``x W`` of a small projection, its float32 accumulation kept
+        (the step and ``B``, ``C`` are not rounded to the operands')."""
+        dt = self.compute_dtype()
+        return jnp.matmul(x.astype(dt), params[name]["weight"].astype(dt),
+                          preferred_element_type=jnp.float32)
+
+    def _activate(self, params, y):
+        """The convolution's result ``(N, D)`` -> ``(x, dt, B, C)``
+        float32: the activated input and what it selects."""
+        r, N = self.dt_rank, self.d_state
+        if self.conv_bias:
+            y = y + params["conv_bias"]
+        x = jax.nn.silu(y)
+        sel = self._accumulated("x_proj", params, x)
+        parts = (sel[:, :r], sel[:, r:r + N], sel[:, r + N:])
+        rr, B, C = (_gain(params, g, p, self.norm_eps, jnp.float32)
+                    for g, p in zip(("dt_gain", "b_gain", "c_gain"), parts))
+        dt = jax.nn.softplus(self._accumulated("dt_proj", params, rr)
+                             + params["dt_bias"])
+        return x, dt, B, C
+
+    def _output(self, params, y, x, z):
+        y = (y + params["D"] * x) * jax.nn.silu(z.astype(jnp.float32))
+        return self.out_proj(params["out_proj"],
+                             y.astype(self.compute_dtype()))
+
+    def __call__(self, params, x, *, positions=None, segment_ids=None,
+                 attn_impl: str = "auto", kv_cache=None, slot_mask=None,
+                 block_tables=None, row_mask=None, attn_kernel="reference",
+                 pack=None, return_kv: bool = False):
+        del attn_impl, attn_kernel       # no attention kernel here
+        from hetu_tpu.ops import kda
+        b, s, E = x.shape
+        D, taps = self.d_inner, params["conv"]
+        A = -jnp.exp(params["A_log"])
+        if kv_cache is None:
+            if return_kv or segment_ids is not None:
+                raise SlotStateNotSupported(
+                    "return_kv (the CP-prefill lane) and packed "
+                    "documents: a selective scan has no (k, v) to hand "
+                    "out, and its whole-sequence forward is one document "
+                    "a row")
+            from hetu_tpu.ops.selective_scan import selective_recurrence
+            xz = self.in_proj(params["in_proj"], x.reshape(-1, E))
+            y = jax.vmap(lambda a: kda.conv_sequence(a, taps))(
+                xz[:, :D].reshape(b, s, D))
+            xs, dt, B, C = self._activate(params, y.reshape(b * s, D))
+
+            def rows(t):
+                return t.reshape((b, s) + t.shape[1:])
+            y = jax.vmap(lambda *t: selective_recurrence(
+                t[0], t[1], A, t[2], t[3])[0])(
+                    rows(xs), rows(dt), rows(B), rows(C))
+            return self._output(params, y.reshape(b * s, D), xs,
+                                xz[:, D:]).reshape(b, s, E)
+        from hetu_tpu.ops.selective_scan_pallas import (
+            hetu_selective_scan, hetu_selective_update,
+        )
+        (state, tail), layer = kv_cache
+        u, pos, valid, _, slot = _cached_rows(
+            x, positions, slot_mask, block_tables, row_mask, pack,
+            paged=False)
+        xz = self.in_proj(params["in_proj"], u)
+        # (the reads of a slot's state and tail out of their leaves and
+        # the writes back are the scopes': most of what a row moves)
+        with jax.named_scope("hetu.ssm_conv"):
+            # the window over the tail's rows: zero taps before the
+            # model's own
+            taps = jnp.pad(taps, ((self.tail_rows + 1 - self.d_conv, 0),
+                                  (0, 0)))
+            if slot is None:
+                y, tail = kda.conv_rows(xz[:, :D], taps, tail, valid,
+                                        layer=layer, fresh=pos == 0)
+            else:
+                y, tail = kda.conv_pack(xz[:, :D], taps, tail, slot, pos,
+                                        valid, layer=layer)
+        xs, dt, B, C = self._activate(params, y)
+        none = jnp.zeros((2,), jnp.int32)
+        if slot is None:
+            with jax.named_scope("hetu.ssm_update"):
+                y, state, steps = hetu_selective_update(
+                    xs, dt, A, B, C, state, valid, layer=layer,
+                    fresh=pos == 0, return_steps=True)
+            steps = jnp.concatenate([none, steps])
+        else:
+            with jax.named_scope("hetu.ssm_scan"):
+                y, state, steps = hetu_selective_scan(
+                    xs, dt, A, B, C, state, slot, pos, valid,
+                    layer=layer, return_steps=True)
+            steps = jnp.concatenate([steps, none])
+        return self._output(params, y, xs, xz[:, D:]).reshape(x.shape), \
+            (state, tail), {"ssm_steps": steps}
+
+
 def remat_policy(name: str):
     """Map a Strategy remat/offload name to a ``jax.checkpoint`` policy.
 

@@ -846,7 +846,26 @@ def history_tile_rows(g: int, d: int, hkv: int, block_size: int, *,
                       > _TILE_VMEM_BUDGET
                       or head_rows is not None and tq * g > head_rows):
         tq //= 2
+    # ... and not so many rows that the key tile beside them is under
+    # one lane tile of keys where the pages would give more: a tile of
+    # 2,560 rows (20 query heads over ONE kv head of 128) leaves room
+    # for one 64-key page a step — half a lane tile of scores, the
+    # accumulator rescaled as often as they are computed (PERF.md
+    # section 6, PR 55: 21.2 ms a layer and iteration where 1,280 rows
+    # x 512 keys read 4.6); every other shape the engines have met keeps
+    # its tile
+    while tq > 8 and _room_keys(tq * g, d, hkv, block_size, kv_itemsize) \
+            < min(_TILE_MIN_KEYS, _TILE_PAGES * block_size):
+        tq //= 2
     return tq
+
+
+def _room_keys(rows: int, d: int, hkv: int, block_size: int,
+               kv_itemsize: int) -> int:
+    """Keys of the key tile that fits beside a cell of ``rows`` rows a
+    kv head (:func:`history_tile_pages`' pricing, K and V leaves)."""
+    return block_size * history_tile_pages(
+        1, d, hkv, block_size, tile_rows=rows, kv_itemsize=kv_itemsize)
 
 
 def _tile_cell_bytes(rows: int, d: int, hkv: int) -> int:
@@ -861,6 +880,10 @@ def _tile_cell_bytes(rows: int, d: int, hkv: int) -> int:
 #: the most keys a tile of the history read scores a grid step (one
 #: score tile a kv head), from at most this many pages
 _TILE_KEYS, _TILE_PAGES = 512, 8
+#: ... and the fewest keys :func:`history_tile_rows` leaves room for
+#: (one whole lane tile of scores a step), where the pages reach that
+#: many
+_TILE_MIN_KEYS = NUM_LANES
 
 
 def history_tile_pages(g: int, d: int, hkv: int, block_size: int, *,

@@ -35,6 +35,9 @@ The vocabulary (all start ``hetu.``; ``docs/OBSERVABILITY.md``):
 ``hetu.mla_expand``   latent attention: per-head K and V from the latent rows
 ``hetu.retention_scan``   power retention: a prefill pack's chunk form (one kernel)
 ``hetu.retention_update`` power retention: the decode rows' update in place
+``hetu.ssm_conv``     selective scan (Mamba): the short convolution and its tails
+``hetu.ssm_scan``     selective scan: a prefill pack's tokens (one kernel)
+``hetu.ssm_update``   selective scan: the decode rows' update in place
 ====================  ================================================
 
 The rule (:func:`classify`): an instruction belongs to the INNERMOST
@@ -74,7 +77,8 @@ VOCABULARY = (
     "hetu.moe_route", "hetu.moe_experts", "hetu.moe_shared",
     "hetu.mla_down", "hetu.mla_absorb", "hetu.mla_expand",
     "hetu.diffusion_sample", "hetu.retention_scan",
-    "hetu.retention_update",
+    "hetu.retention_update", "hetu.ssm_conv", "hetu.ssm_scan",
+    "hetu.ssm_update",
 )
 
 #: ``op_name``s the TPU compiler gives an op it made from a program's

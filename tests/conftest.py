@@ -252,6 +252,20 @@ AS_OF_LATER_PINS = {
 }
 
 
+#: ... and PR 51's two, written when the Brumby cell was the last: they
+#: name every entry that lists their cell LAST of its cells and take the
+#: file for its own view as of that cell (ISSUE 55; the first is an
+#: enumerating pin as well, so it sees the file without PR 53's eleven
+#: too). What PR 55 appended behind the Brumby cell is asserted by name
+#: in ``tests/benchmark/test_serve_arch_ssm.py``
+AS_OF_BRUMBY_PINS = dict.fromkeys((
+    "test_serve_arch_retention.py::"
+    "test_manifest_names_what_the_retention_cell_needs",
+    "test_serve_arch_retention.py::"
+    "test_the_pins_still_see_the_file_as_of_their_cells",
+), "brumby-14b-pp4.repo-16k-backlog")
+
+
 #: ... and PR 53's eleven entries, which list the cells that WERE there
 #: (the process's account of every serving cell): five tests name EVERY
 #: entry that lists their cell beside another (``listed == [...]``) and
@@ -328,7 +342,8 @@ def as_of(manifest: dict, last_cell: str) -> dict:
 @pytest.fixture(autouse=True)
 def manifest_order_for_the_position_pins(request, monkeypatch):
     node = request.node.nodeid
-    last = next((c for t, c in {**AS_OF_PINS, **AS_OF_LATER_PINS}.items()
+    last = next((c for t, c in {**AS_OF_PINS, **AS_OF_LATER_PINS,
+                                **AS_OF_BRUMBY_PINS}.items()
                  if node.endswith(t)), None)
     enumerating = node.endswith(ENUMERATING_PINS)
     if last is None and not enumerating \
@@ -341,10 +356,9 @@ def manifest_order_for_the_position_pins(request, monkeypatch):
         m = load(path)
         if os.path.basename(path) != "BENCHMARK.json":
             return m
-        if enumerating:
-            return before_pr53(m)
-        return later_entries_first(m) if last is None \
-            else as_of(before_pr53(m), last)
+        if last is not None:
+            return as_of(before_pr53(m), last)
+        return before_pr53(m) if enumerating else later_entries_first(m)
     monkeypatch.setattr(harness, "load_manifest", shown)
 
 

@@ -1133,7 +1133,7 @@ def test_history_tile_pages_from_shapes():
             (1, 64, 12, 16, False, 8), (1, 64, 20, 16, False, 8),
             (16, 128, 8, 64, False, 4), (16, 640, 1, 64, True, 8),
             (32, 640, 1, 64, True, 8), (1, 64, 12, 128, False, 4),
-            (1, 64, 12, 1024, False, 1)):
+            (1, 64, 12, 1024, False, 1), (20, 128, 1, 64, False, 8)):
         tq = history_tile_rows(g, d, hkv, bs)
         assert history_tile_pages(g, d, hkv, bs, tile_rows=tq,
                                   latent=latent) == pages
@@ -1155,9 +1155,76 @@ def test_history_tile_rows_from_shapes():
     assert history_tile_rows(16, 128, 1, 64) == 128
     assert history_tile_rows(16, 128, 1, 64, head_rows=256) == 16
     assert history_tile_rows(16, 128, 8, 64, head_rows=1024) == 16
+    # 20 query heads over ONE kv head of 128: the pricing alone says 128
+    # tokens (2,560 rows), beside which ONE 64-key page fits — half a
+    # lane tile of scores a step; the rows give way until a lane tile of
+    # keys fits (PR 55)
+    assert history_tile_rows(20, 128, 1, 64) == 64
     assert history_tile_count(256, 128, 32) == 33
     assert history_tile_count(512, 16, 48) == 79
     assert history_tile_count(8, 128, 3) == 3
+
+
+#: (q heads a kv head, head width, kv heads a page row, page) of the
+#: paged models the benchmark and the tests serve: GPT-2 small, large
+#: and tiny; Command A+ and its tiny; the latent rows of Kimi and Ling
+#: (576 padded to 640) and their tinies; MiniCPM-SALA's band over pages
+#: of ONE kv head and over both, and its tiny; SDAR's group and its
+#: block of 4 x 8 rows, and its tiny; Jamba's tiny; Llama-shaped
+#: GQA; long pages
+TILE_SHAPES_THE_RULE_LEAVES = (
+    (1, 64, 12, 16), (1, 64, 20, 16), (1, 16, 4, 16),
+    (16, 128, 8, 64), (4, 16, 2, 4),
+    (16, 640, 1, 64), (32, 640, 1, 64), (4, 40, 1, 4), (4, 128, 1, 4),
+    (16, 128, 1, 64), (16, 128, 2, 64), (2, 16, 1, 4), (2, 16, 2, 4),
+    (8, 128, 4, 64), (32, 128, 4, 64),
+    (4, 16, 1, 4),
+    (4, 128, 8, 16), (8, 128, 8, 16), (2, 16, 2, 16),
+    (1, 64, 12, 128), (1, 64, 12, 1024))
+
+
+def _tile_rows_priced_alone(g, d, hkv, bs, kv_itemsize, head_rows):
+    """:func:`history_tile_rows` as it stood before PR 55's rule: the
+    blocks' VMEM price and the caller's bound, nothing else."""
+    from hetu_tpu.ops import paged_pallas as pp
+    pages = 2 * 2 * bs * hkv * d * kv_itemsize
+    tq = 128
+    while tq > 8 and (pp._tile_cell_bytes(tq * g, d, hkv) + pages
+                      > pp._TILE_VMEM_BUDGET
+                      or head_rows is not None and tq * g > head_rows):
+        tq //= 2
+    return tq
+
+
+@pytest.mark.parametrize("shape", TILE_SHAPES_THE_RULE_LEAVES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_the_lane_tile_rule_leaves_every_other_models_tile(shape):
+    """PR 55's rule in :func:`history_tile_rows` (the rows give way
+    until a lane tile of keys fits beside them) was written for 20
+    query heads over ONE kv head of 128; every shape another paged
+    model lowers keeps the tile the pricing alone gave it, at every
+    arena itemsize and under every bound a caller sets."""
+    for kv_itemsize in (1, 2, 4):
+        for head_rows in (None, 256, 1024):
+            assert history_tile_rows(
+                *shape, kv_itemsize=kv_itemsize, head_rows=head_rows) \
+                == _tile_rows_priced_alone(*shape, kv_itemsize, head_rows)
+
+
+def test_the_lane_tile_rule_moves_the_one_kv_head_of_twenty():
+    """... and what it moves: Jamba's attention layers (128 tokens =
+    2,560 rows beside ONE 64-key page, to 64 tokens beside 512 keys);
+    a group of 5 over 8 kv heads of 128, which no model has; and 32 or
+    40 kv heads of 128 with one query head each (the ``llama_7b`` /
+    ``llama_13b`` presets: code that no cell and no test serves at that
+    size — 64 tokens to 32, NOT measured)."""
+    assert _tile_rows_priced_alone(20, 128, 1, 64, 2, None) == 128
+    assert history_tile_rows(20, 128, 1, 64) == 64
+    assert history_tile_rows(5, 128, 8, 64) \
+        < _tile_rows_priced_alone(5, 128, 8, 64, 2, None)
+    for hkv in (32, 40):
+        assert _tile_rows_priced_alone(1, 128, hkv, 16, 2, None) == 64
+        assert history_tile_rows(1, 128, hkv, 16) == 32
 
 
 def test_combine_attention_lse_matches_joint_softmax():

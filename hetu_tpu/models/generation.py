@@ -114,11 +114,17 @@ def decode(model, params, input_ids, positions, caches, *,
         row_mask=row_mask, attn_kernel=attn_kernel,
         w8a8_mask=w8a8_mask, w8a8_wq=w8a8_wq, lora=lora,
         with_stats=True)
+    logits = head_logits(model, params, h)
+    return (logits, caches, stats) if with_stats else (logits, caches)
+
+
+def head_logits(model, params, h):
+    """The final norm and the head over hidden rows ``h (b, s, E)``:
+    float32 logits ``(b, s, V)``."""
     h = model.hidden_norm(params, h)
     w = _head_weight(model, params)
-    logits = jnp.einsum("bse,ve->bsv", h.astype(jnp.float32),
-                        w.astype(jnp.float32))
-    return (logits, caches, stats) if with_stats else (logits, caches)
+    return jnp.einsum("bse,ve->bsv", h.astype(jnp.float32),
+                      w.astype(jnp.float32))
 
 
 def _sample(logits, *, temperature: float, top_k: int, top_p: float, rng):

@@ -4,25 +4,36 @@ A model that states a ``generation`` (``models.sdar_moe.
 BlockDiffusion``) does not yield a token a slot and step. A slot holds
 a BLOCK of ``B`` positions ``pos .. pos + B - 1``; each iteration the
 fused step runs the block's ``B`` current tokens (``mask_token_id``
-where still masked) as ``B`` q rows against the committed cache and the
+where still masked) as q rows against the committed cache and the
 block's own keys — the verify lane's shape, ``docs/SERVING.md`` — and
-:func:`denoise_slots` decides, on the device, what the pass was:
+:func:`denoise_slots` unmasks, on the device. Every pass of a live slot
+is a **denoise pass**: at every masked position the top token and its
+probability (a float32 softmax over the slice, the mask id held at
+``-inf``: never drawn), then unmask — ``low_confidence_static`` the
+``n`` most confident masked positions, ``n = B // steps`` and the
+remainder on the first passes; ``low_confidence_dynamic`` every masked
+position whose confidence passes the slot's threshold, or those ``n`` if
+they are fewer. The pass's K/V were written from a block that still held
+a mask and will be overwritten.
 
-- a **denoise pass** (some position still masked): at every masked
-  position the top token and its probability (a float32 softmax over
-  the slice, the mask id held at ``-inf``: never drawn), then unmask —
-  ``low_confidence_static`` the ``n`` most confident masked positions,
-  ``n = B // steps`` and the remainder on the first passes;
-  ``low_confidence_dynamic`` every masked position whose confidence
-  passes the slot's threshold, or those ``n`` if they are fewer. The
-  pass's K/V were written and will be overwritten;
-- a **commit pass** (nothing masked going in): the block's final tokens
-  ran once more and their K/V stay; the ``B`` tokens are committed,
-  ``pos`` moves by ``B`` and the next block begins as ``B`` masks.
+The pass that removes a block's last mask FINISHES it: its ``B`` tokens
+are handed on with that iteration's fetch, ``pos`` moves by ``B`` and
+the next block begins as ``B`` masks. Its clean K/V are not in the arena
+yet. They are written INSIDE the next block's first pass: the slot's
+pass carries ``2B`` q rows at ``pos - B .. pos + B - 1`` — the finished
+block's tokens (the slot's **carry**: ``prev (S, B)``, live while
+``carry (S,)`` is set) below the current block's. The attention's block
+bound is a function of a row's position, so the carry rows see the cache
+and their own block, the current rows the cache, the carry block —
+written by this same pass: a layer writes all its rows' K/V before it
+attends — and themselves: the arithmetic of a commit pass and of the
+first pass after it, in one call. A carry row that is not live does not
+write; a request's last block needs no commit at all. A block of ``B``
+costs its denoise passes and no other.
 
 Everything is data per slot (tokens, masks, the pass, the steps, the
-rule, the threshold): slots at different passes, and a slot that
-commits beside one that denoises, share the one step.
+rule, the threshold, the carry): slots at different passes, with and
+without a carry, share the one step.
 """
 
 from __future__ import annotations
@@ -55,18 +66,23 @@ def refuse(**asked) -> None:
 
 
 def denoise_slots(logits, tok, masked, passes, steps, dynamic, thresh,
-                  live, *, mask_id: int):
+                  live, prev, carry, *, mask_id: int):
     """One pass of every live slot's block (see the module docstring).
 
     ``logits (S, B, V)`` of the rows ``tok (S, B)`` (position ``i``'s
     logits predict position ``i``), ``masked (S, B)`` bool, ``passes
     (S,)`` the passes the block has had, ``steps (S,)``, ``dynamic
-    (S,)`` bool, ``thresh (S,)`` float32, ``live (S,)`` bool. Returns
-    ``(committed (S, B), ncommit (S,): B or 0, tok, masked, passes)`` —
-    the last three the state the next iteration takes; a slot that is
-    not live keeps its own. No sort and no gather: the rank of a
-    position among its block's confidences is a ``(B, B)`` compare (ties
-    to the lower position)."""
+    (S,)`` bool, ``thresh (S,)`` float32, ``live (S,)`` bool, ``prev
+    (S, B)`` the block finished before this one and ``carry (S,)`` bool
+    whether its rows were still to run. Returns ``(committed (S, B),
+    ncommit (S,): B or 0, tok, masked, passes, prev, carry)`` — the last
+    five the state the next iteration takes; a slot that is not live
+    keeps its own. A slot whose pass leaves nothing masked hands its
+    block on (``committed``), keeps it as ``prev`` with ``carry`` set
+    and begins the next as ``B`` masks; any other live slot's carry rows
+    ran in this pass, and ``carry`` is cleared. No sort and no gather:
+    the rank of a position among its block's confidences is a ``(B, B)``
+    compare (ties to the lower position)."""
     import jax.numpy as jnp
     S, B, V = logits.shape
     lg = jnp.where(jnp.arange(V) == mask_id, -jnp.inf,
@@ -85,18 +101,46 @@ def denoise_slots(logits, tok, masked, passes, steps, dynamic, thresh,
     pick = masked & (rank < n[:, None])
     high = masked & (conf > thresh[:, None])
     pick = jnp.where((dynamic & (high.sum(-1) >= n))[:, None], high, pick)
-    commit = live & ~masked.any(-1)
-    denoise = live & ~commit
-    new_tok = jnp.where(pick & denoise[:, None], x0, tok)
-    new_masked = masked & ~(pick & denoise[:, None])
-    # a committed block leaves; the next begins as B masks
-    new_tok = jnp.where(commit[:, None], mask_id, new_tok)
-    new_masked = new_masked | commit[:, None]
-    new_passes = jnp.where(commit, 0, passes + denoise)
-    return (jnp.where(commit[:, None], tok, 0),
-            jnp.where(commit, B, 0).astype(jnp.int32),
-            new_tok.astype(jnp.int32), new_masked,
-            new_passes.astype(jnp.int32))
+    pick &= live[:, None]
+    final = jnp.where(pick, x0, tok)
+    left = masked & ~pick
+    done = live & ~left.any(-1)
+    whole = done[:, None]
+    # a finished block leaves; the next begins as B masks
+    return (jnp.where(whole, final, 0),
+            jnp.where(done, B, 0).astype(jnp.int32),
+            jnp.where(whole, mask_id, final).astype(jnp.int32),
+            left | whole,
+            jnp.where(done, 0, passes + live).astype(jnp.int32),
+            jnp.where(whole, final, prev).astype(jnp.int32),
+            jnp.where(live, done, carry))
+
+
+def lane_rows(pos, tok, prev, carry, live, *, mask_id: int):
+    """The ``2B`` q rows of every slot's pass: ``(tokens, positions,
+    writes)``, each ``(S, 2B)``, and ``early (S,)``.
+
+    The carry rows (``prev``, at ``pos - B ..``) stand below the current
+    block's (``tok``, at ``pos ..``); ``writes`` is ``live`` on the
+    current block's rows and ``live & carry`` on the carry rows — the
+    rest scatter nowhere. A slot whose first block starts below ``B``
+    (``early``: a prompt shorter than a block) has no position for carry
+    rows and no carry: its rows stand at ``0 .. 2B - 1``, the current
+    block's FIRST, the rest masks that write nothing — the caller takes
+    such a slot's logits from rows ``0 .. B - 1``, every other's from
+    ``B .. 2B - 1``."""
+    import jax.numpy as jnp
+    B = tok.shape[1]
+    early = pos < B
+    upper = jnp.arange(2 * B)[None] >= B
+    tokens = jnp.where(
+        early[:, None],
+        jnp.concatenate([tok, jnp.full_like(tok, mask_id)], axis=1),
+        jnp.concatenate([prev, tok], axis=1))
+    writes = live[:, None] & jnp.where(early[:, None], ~upper,
+                                       upper | carry[:, None])
+    positions = jnp.maximum(pos - B, 0)[:, None] + jnp.arange(2 * B)[None]
+    return tokens, positions, writes, early
 
 
 def first_block(prompt: np.ndarray, B: int, mask_id: int):

@@ -99,7 +99,7 @@ from hetu_tpu.engine.train_step import record_trace
 from hetu_tpu.models import generation
 from hetu_tpu.serving import block_diffusion
 from hetu_tpu.serving.block_diffusion import (
-    REMASKING, denoise_slots, first_block,
+    REMASKING, denoise_slots, first_block, lane_rows,
 )
 from hetu_tpu.serving.kv_pool import (
     BlockManager, HostSpillArena, KVPool, NoBlocks, SpillEntry,
@@ -313,12 +313,19 @@ def _bind_diffusion_metrics(reg) -> dict:
         diff_passes=reg.counter(
             "serving_diffusion_passes_total",
             "slot-passes of the block lane by kind (denoise = a block "
-            "with a masked position ran and some were unmasked, its "
-            "K/V to be overwritten; commit = a block with none ran "
-            "once more, its K/V kept and its tokens emitted)"),
+            "with a masked position ran and some were unmasked — "
+            "every pass is one, also the pass that carries the block "
+            "finished before it; commit = a finished block ran once "
+            "more for its K/V alone: retired, never incremented, kept "
+            "for the readers that divide by the sum of the two)"),
         diff_blocks=reg.counter(
             "serving_diffusion_blocks_total",
-            "blocks committed by the block lane"),
+            "blocks the block lane finished and handed on"),
+        diff_carried=reg.counter(
+            "serving_diffusion_carried_blocks_total",
+            "finished blocks whose clean K/V were written inside the "
+            "next block's first pass (every block but a request's "
+            "last, whose K/V nobody reads)"),
         diff_tokens=reg.counter(
             "serving_diffusion_tokens_total",
             "tokens the committed blocks handed their requests (a "
@@ -326,9 +333,13 @@ def _bind_diffusion_metrics(reg) -> dict:
             "max_tokens or a stop id are not counted)"),
         diff_per_block=reg.histogram(
             "serving_diffusion_passes_per_block",
-            "passes a committed block took, its commit pass among "
-            "them (at most denoising_steps + 1)"))
+            "passes a finished block took (at most denoising_steps: "
+            "its K/V are committed inside the next block's first)"))
 
+
+#: the block lane's state a slot that the fused step takes and returns,
+#: in the order ``block_diffusion.denoise_slots`` returns it
+_BLK_STATE = ("blk_tok", "blk_masked", "blk_pass", "blk_prev", "blk_carry")
 
 #: iterations between two refreshes of the spill-tier, replica-store and
 #: adapter-page gauges when no submit or auxiliary job sets them sooner
@@ -628,8 +639,10 @@ class ServingEngine:
         # (the verify lane's width), per-slot effective depth is data —
         # spec_depth=0 keeps the lane at the classic one-row decode
         self.spec_depth = check_draft_depth(spec_depth, max_len)
-        #: q rows a slot feeds the decode lane: its last token and the
-        #: drafts, or a block-diffusion model's block
+        #: token columns a slot's decode lane hands on: its last token
+        #: and the drafts, or a block-diffusion model's block (whose
+        #: lane RUNS twice the rows: the block finished before it rides
+        #: below — ``block_diffusion.lane_rows``)
         self._lane_rows = self._gen.block_length if self._gen \
             else self.spec_depth + 1
         self._draftsman = None
@@ -696,16 +709,20 @@ class ServingEngine:
         if self._gen is not None:
             # the block lane's state a slot (block_diffusion.py): the
             # block's tokens, which are still masked, the passes it has
-            # had; the request's steps, rule and threshold. The step
-            # takes and returns the first three; the host's mirrors
-            # follow the packed fetch. ``_blk_at``: the pass at which
-            # each position was unmasked (the host's own account)
+            # had; the block finished before it and whether its rows
+            # are still to run (the carry); the request's steps, rule
+            # and threshold. The step takes and returns the first five;
+            # the host's mirrors follow the packed fetch. ``_blk_at``:
+            # the pass at which each position was unmasked (the host's
+            # own account)
             B = self._gen.block_length
             self._blk = {
                 "blk_tok": np.full((S, B), self._gen.mask_token_id,
                                    np.int32),
                 "blk_masked": np.ones((S, B), bool),
                 "blk_pass": np.zeros(S, np.int32),
+                "blk_prev": np.zeros((S, B), np.int32),
+                "blk_carry": np.zeros(S, bool),
                 "blk_steps": np.ones(S, np.int32),
                 "blk_dynamic": np.zeros(S, bool),
                 "blk_thresh": np.zeros(S, np.float32)}
@@ -1170,27 +1187,38 @@ class ServingEngine:
 
             # the decode lane of a model that generates by diffusion
             # over blocks is the BLOCK lane (block_diffusion.py): every
-            # slot feeds its block's B current tokens as B q rows at
-            # pos..pos+B-1 (the verify lane's shape; the attention's
-            # block bound lets each row see the whole block), their K/V
-            # written as ordinary paged writes that a later pass
-            # overwrites; ``denoise_slots`` unmasks or commits by the
-            # slot's own state — data, like the pass count — and the
-            # new state leaves the step beside pos
+            # slot feeds 2B q rows at pos-B..pos+B-1 — its block's B
+            # current tokens and, below them, the B of the block it
+            # finished last, live on the pass after the one that
+            # finished it (the verify lane's shape; the attention's
+            # block bound is a row's position's: each row sees its own
+            # block whole and what lies before it). The current rows'
+            # K/V are ordinary paged writes that a later pass
+            # overwrites, the carry rows' the block's clean ones,
+            # written once; ``denoise_slots`` unmasks by the slot's own
+            # state — data, like the pass count — and the new state
+            # leaves the step beside pos. The head runs on a block's
+            # rows, not on both
             def do_block(caches):
-                positions = ctl["pos"][:, None] + jnp.arange(K + 1)[None, :]
-                logits, caches, stats = generation.decode(
-                    model, params, ctl["blk_tok"], positions, caches,
+                tok_in, positions, writes, early = lane_rows(
+                    ctl["pos"], ctl["blk_tok"], ctl["blk_prev"],
+                    ctl["blk_carry"], ctl["active"],
+                    mask_id=gen.mask_token_id)
+                h = model.embed(params, tok_in, positions=positions)
+                h, caches, stats = model.blocks.decode(
+                    params["blocks"], h, caches, positions=positions,
                     slot_mask=ctl["active"], block_tables=bt,
-                    row_mask=jnp.broadcast_to(ctl["active"][:, None],
-                                              positions.shape),
-                    attn_kernel=kern, with_stats=True)
+                    row_mask=writes, attn_kernel=kern, with_stats=True)
+                lower, upper = jnp.split(h, 2, axis=1)
+                logits = generation.head_logits(model, params, jnp.where(
+                    early[:, None, None], lower, upper))
                 with jax.named_scope("hetu.diffusion_sample"):
                     committed, ncommit, *blk = denoise_slots(
                         logits, ctl["blk_tok"], ctl["blk_masked"],
                         ctl["blk_pass"], ctl["blk_steps"],
                         ctl["blk_dynamic"], ctl["blk_thresh"],
-                        ctl["active"], mask_id=gen.mask_token_id)
+                        ctl["active"], ctl["blk_prev"], ctl["blk_carry"],
+                        mask_id=gen.mask_token_id)
                 return (caches, committed, ncommit, ctl["last_tok"],
                         ctl["key"], stats, tuple(blk))
 
@@ -1201,8 +1229,7 @@ class ServingEngine:
                        z, z, ctl["key"],
                        model.blocks.layer_stats_zeros())
                 if gen is not None:
-                    out += ((ctl["blk_tok"], ctl["blk_masked"],
-                             ctl["blk_pass"]),)
+                    out += (tuple(ctl[f] for f in _BLK_STATE),)
                 return out
 
             # what the layers of each lane report beside their result
@@ -1341,14 +1368,15 @@ class ServingEngine:
                 return (caches, new_pos, new_last, new_key,
                         results.pack_device(fields))
             # the block state: to the next iteration on the device, and
-            # to the host's mirrors in the one fetch
-            blk_tok, blk_masked, blk_pass = blk[0]
+            # to the host's mirrors in the one fetch (the carry rides it
+            # as ``committed`` and ``ncommit``: a finished block's
+            # tokens ARE the next pass's carry rows)
+            blk_tok, blk_masked, blk_pass = blk[0][:3]
             fields["blk"] = (blk_tok, blk_masked.astype(jnp.int32),
                              blk_pass)
             return (caches, new_pos, new_last, new_key,
                     results.pack_device(fields),
-                    {"blk_tok": blk_tok, "blk_masked": blk_masked,
-                     "blk_pass": blk_pass})
+                    dict(zip(_BLK_STATE, blk[0])))
 
         # what the step returns AND takes again keeps its home
         # (__init__): the arena, and the advanced pos/last_tok/key (and
@@ -2860,7 +2888,8 @@ class ServingEngine:
                       **(self._blk if self._gen else {})}, self._bt),
                     self._rep)
                 self._ctl_dirty = False
-                m.transfers.inc(9 + (6 if self._gen else 0), dir="up")
+                m.transfers.inc(9 + (len(self._blk) if self._gen else 0),
+                                dir="up")
             ctl = self._ctl_dev
             if self._active.any():
                 # the decode lane's sampler: the step's own predicate
@@ -2878,6 +2907,10 @@ class ServingEngine:
                     # of which the rows see those up to that position
                     tile, steps = self._chunk_span, self._chunk_steps
                     last = self._pos[self._active] + K
+                    if self._gen is not None:
+                        # (a block's rows stand at 0..2B-1 where it
+                        # starts below B: block_diffusion.lane_rows)
+                        last = np.maximum(last, 2 * K + 1)
                     live = int(np.minimum(last // tile + 1, steps).sum())
                     m.decode_chunks.inc(live, state="live")
                     m.decode_chunks.inc(S * steps - live, state="skipped")
@@ -3009,9 +3042,11 @@ class ServingEngine:
                 # functions the model's block names for them, with the
                 # token rows a call of that lane's layers takes
                 emit = self.model.blocks.layer_stats
+                # (the block lane runs two blocks of rows a slot)
                 for ran, rows, stats in zip(
                         (active_prev.size, used),
-                        (em.size, pf["tokens"].size), res["stats"]):
+                        (em.size * (2 if self._gen else 1),
+                         pf["tokens"].size), res["stats"]):
                     for name, values in stats.items() if ran else ():
                         emit[name][2](values, tokens=rows)
             # decode results for the slots that were active going in:
@@ -3022,9 +3057,10 @@ class ServingEngine:
             # advanced pos is rebuilt from the host mirrors)
             token_rows = active_prev
             if self._gen is not None:
-                # (the block lane commits whole blocks, by its own rule)
-                n_generated += self._commit_blocks(
+                # (the block lane hands on whole blocks, by its own rule)
+                handed, live_rows = self._commit_blocks(
                     active_prev, em, nc, res["blk"], now)
+                n_generated += handed
                 token_rows = ()
             for r in token_rows:
                 req = self._slot_req[int(r)]
@@ -3134,39 +3170,49 @@ class ServingEngine:
             admitted=self._n_admitted - admitted0, frames=frames,
             since_prev_s=pc0 - end if end is not None else 0.0)
         if self._gen is not None:
-            # the block lane's live q rows of this iteration
-            step_span.set(lane_rows=int(active_prev.size) * (K + 1))
+            # the block lane's LIVE q rows of this iteration: a block a
+            # live slot, and one more a slot that carried
+            step_span.set(lane_rows=live_rows)
         return True
 
     # -- the block lane's host half (serving/block_diffusion.py) -----------
     def _begin_blocks(self, slot: int, req: Request) -> None:
         """The prompt's whole blocks are in the arena (or it has none):
         the slot joins the block lane with its first block — the
-        prompt's tail, then masks (caller holds the lock)."""
+        prompt's tail, then masks — and no carry: what the slot's last
+        request left pending nobody reads (caller holds the lock)."""
         g, b = self._gen, self._blk
         self._pos[slot], b["blk_tok"][slot], b["blk_masked"][slot] = \
             first_block(req.prompt, g.block_length, g.mask_token_id)
         b["blk_pass"][slot] = 0
+        b["blk_carry"][slot] = False
         self._blk_at[slot] = 0
         self._active[slot] = True
         self._ctl_dirty = True
         req.status = "decode"
 
-    def _commit_blocks(self, active_prev, em, nc, blk, now) -> int:
-        """What the fetched block state says of the slots that ran:
-        which positions a denoise pass unmasked (and at which pass: the
-        request's own account), which blocks were committed — their
-        tokens go to their requests, all at once. Returns the tokens
-        handed on (caller holds the lock)."""
+    def _commit_blocks(self, active_prev, em, nc, blk, now) -> tuple:
+        """What the fetch says of the slots that ran, each a denoise
+        pass: which positions it unmasked (and at which pass: the
+        request's own account) and which blocks it FINISHED — their
+        tokens go to their requests, all at once, and stay the slot's
+        carry: their K/V are written by its next pass. Returns the
+        tokens handed on and the lane's live q rows (caller holds the
+        lock)."""
         m, b, B = self._m, self._blk, self._gen.block_length
         tok, masked, passes = blk
         ran = np.asarray(active_prev)
         done = nc[ran] > 0
-        # a denoise pass: what it unmasked, it did at this pass
-        den = ran[~done]
-        newly = b["blk_masked"][den] & (masked[den] == 0)
-        self._blk_at[den] = np.where(
-            newly, b["blk_pass"][den][:, None], self._blk_at[den])
+        carried = int(b["blk_carry"][ran].sum())
+        # what the pass unmasked, it did at this pass (a finished block
+        # comes back as the next one's masks: all it still had)
+        newly = b["blk_masked"][ran] & (done[:, None] | (masked[ran] == 0))
+        self._blk_at[ran] = np.where(
+            newly, b["blk_pass"][ran][:, None], self._blk_at[ran])
+        # the mirrors follow the device (a finished slot's are
+        # rewritten when it is taken again)
+        b["blk_prev"][ran[done]] = em[ran[done]]
+        b["blk_carry"][ran] = done
         n_tokens = 0
         for r in map(int, ran[done]):
             req = self._slot_req[r]
@@ -3177,18 +3223,16 @@ class ServingEngine:
             n_tokens += self._on_block(
                 r, em[r, skip:], self._blk_at[r, skip:], now)
             self._blk_at[r] = 0
-        # the mirrors follow the device (a finished slot's are
-        # rewritten when it is taken again)
         b["blk_tok"][ran], b["blk_masked"][ran] = tok[ran], masked[ran] != 0
         b["blk_pass"][ran] = passes[ran]
-        commits = int(done.sum())
-        if commits:
-            m.diff_passes.inc(commits, kind="commit")
-            m.diff_blocks.inc(commits)
+        if len(ran):
+            m.diff_passes.inc(len(ran), kind="denoise")
+        if carried:
+            m.diff_carried.inc(carried)
+        if done.any():
+            m.diff_blocks.inc(int(done.sum()))
             m.diff_tokens.inc(n_tokens)
-        if len(den):
-            m.diff_passes.inc(len(den), kind="denoise")
-        return n_tokens
+        return n_tokens, B * (len(ran) + carried)
 
     def _on_block(self, slot: int, toks, at, now: float) -> int:
         """A committed block's tokens for ``slot``'s request, cut at

@@ -35,7 +35,7 @@ from hetu_tpu.ops.paged_pallas import (  # noqa: E402
 )
 from hetu_tpu.serving import ServingEngine  # noqa: E402
 from hetu_tpu.serving.block_diffusion import (  # noqa: E402
-    BlockGenerationNotSupported, denoise_slots,
+    BlockGenerationNotSupported, denoise_slots, lane_rows,
 )
 from hetu_tpu.serving.scheduler import SamplingParams  # noqa: E402
 
@@ -115,13 +115,15 @@ def test_block_bound_in_attention_reference(block):
         attention_reference(q, k, v, block=4)
 
 
-@pytest.mark.parametrize("block", [1, 4])
-def test_block_bound_in_the_paged_call(block):
+@pytest.mark.parametrize("block,R", [(1, 4), (4, 4), (4, 8)])
+def test_block_bound_in_the_paged_call(block, R):
     """The kernel (interpret mode) over block tables == the mask
-    written out, rows of a slot at its own offset; at 1 the call is the
-    causal one to the bit, and so is the work list."""
+    written out, rows of a slot at its own offset — ``B`` rows, or the
+    block lane's ``2B`` that straddle two blocks: the lower four see
+    nothing of the upper four's block; at 1 the call is the causal one
+    to the bit, and so is the work list."""
     rng = np.random.default_rng(1)
-    S, R, hq, hkv, d, bs, W, nb = 3, 4, 4, 2, 16, 4, 8, 40
+    S, hq, hkv, d, bs, W, nb = 3, 4, 2, 16, 4, 8, 40
     q = jnp.asarray(rng.normal(size=(S, R, hq, d)), jnp.float32)
     k, v = (jnp.asarray(rng.normal(size=(nb, bs, hkv * d)), jnp.float32)
             for _ in range(2))
@@ -140,6 +142,17 @@ def test_block_bound_in_the_paged_call(block):
     np.testing.assert_allclose(
         paged_attention_reference(q, k, v, tbl, off, **kw), want,
         atol=1e-5)
+    if R == 2 * block:
+        # the carry rows' result is the call's of those rows alone, and
+        # the work list ends at the chunk of the upper block's end
+        alone = paged_attention_pallas(q[:, :block], k, v, tbl, off,
+                                       live=live, pages_per_step=1, **kw)
+        np.testing.assert_allclose(out[:, :block], alone, atol=1e-6)
+        _, chunk, n = decode_work_list(off, live, rows=R, span=4,
+                                       n_steps=8, **kw)
+        assert int(n) == 2 + 4 + 7
+        assert np.asarray(chunk)[:int(n)].tolist() == [
+            *range(2), *range(4), *range(7)]
     if block == 1:
         plain = paged_attention_pallas(q, k, v, tbl, off, live=live)
         assert (np.asarray(plain) == np.asarray(
@@ -280,7 +293,8 @@ def test_prefill_then_block_passes_through_the_arena(tiny):
     """The prompt's whole blocks written through the paged arena (a
     batch row a token, the reference lane), then the block lane's rows
     — a noised block of 4 at its positions — read it back: the logits
-    are the reference's streams' (the clean one's at a commit pass)."""
+    are the reference's streams' (the clean one's where the block holds
+    no mask)."""
     cfg, model, p = tiny
     rng = np.random.default_rng(6)
     B, M = 4, cfg.vocab_size - 1
@@ -308,46 +322,108 @@ def test_prefill_then_block_passes_through_the_arena(tiny):
             model, p, blk[None], np.arange(12, 16, dtype=np.int32)[None],
             caches, slot_mask=jnp.ones(1, bool), block_tables=bt)
         np.testing.assert_allclose(lg[0], want[i], atol=3e-5)
-    # the last pass was the commit pass: the clean stream's logits
+    # the last rows held no mask: the clean stream's logits
     np.testing.assert_allclose(
         lg[0], np.asarray(hc @ p["lm_head"]["weight"].T)[12:], atol=3e-5)
 
 
 # -- the sampler -------------------------------------------------------------
 
-def test_denoise_slots_by_the_state_of_each_slot():
-    """Four slots in one call: a static pass, a dynamic pass whose
-    threshold fires on two positions, a commit, a dead slot."""
-    V, M = 12, 11
-    lg = np.full((4, 4, V), -4.0, np.float32)
+V, M = 12, 11
+
+
+def _slot_logits(slots):
+    """Logits whose top tokens are 3, 5, 7, 2 at heights -2, 6, -1, 5
+    (confidence order: positions 1, 3, 2, 0), the mask id above all."""
+    lg = np.full((slots, 4, V), -4.0, np.float32)
     lg[..., M] = 9.0                         # never drawn
-    for s in range(4):
-        for i, (tok, height) in enumerate(
-                [(3, -2.0), (5, 6.0), (7, -1.0), (2, 5.0)]):
-            lg[s, i, tok] = height
-    tok = np.full((4, 4), M, np.int32)
-    masked = np.ones((4, 4), bool)
-    tok[2], masked[2] = [1, 2, 3, 4], False            # to be committed
-    tok[0, 1], masked[0, 1] = 9, False                 # already unmasked
+    for i, (tok, height) in enumerate(
+            [(3, -2.0), (5, 6.0), (7, -1.0), (2, 5.0)]):
+        lg[:, i, tok] = height
+    return jnp.asarray(lg)
+
+
+def _denoise(tok, masked, passes, steps, dynamic, thresh, live, prev,
+             carry):
     out = denoise_slots(
-        jnp.asarray(lg), jnp.asarray(tok), jnp.asarray(masked),
-        jnp.asarray([1, 0, 4, 2]), jnp.asarray([4, 4, 4, 4]),
-        jnp.asarray([False, True, False, False]),
-        jnp.asarray([0.0, 0.9, 0.0, 0.0], jnp.float32),
-        jnp.asarray([True, True, True, False]), mask_id=M)
-    committed, ncommit, ntok, nmask, npass = map(np.asarray, out)
-    assert ncommit.tolist() == [0, 0, 4, 0]
-    assert committed[2].tolist() == [1, 2, 3, 4]
-    # static, one a pass: the most confident MASKED position (3)
-    assert ntok[0].tolist() == [M, 9, M, 2]
-    assert nmask[0].tolist() == [True, False, True, False]
+        _slot_logits(len(tok)), jnp.asarray(tok, jnp.int32),
+        jnp.asarray(masked), jnp.asarray(passes), jnp.asarray(steps),
+        jnp.asarray(dynamic), jnp.asarray(thresh, jnp.float32),
+        jnp.asarray(live), jnp.asarray(prev, jnp.int32),
+        jnp.asarray(carry), mask_id=M)
+    return [np.asarray(x).tolist() for x in out]
+
+
+#: one slot a case: (tok, masked, pass, steps, dynamic, thresh, live,
+#: prev, carry) -> (committed, ncommit, tok, masked, pass, prev, carry)
+T, F = True, False
+SLOT_CASES = {
+    # static, one a pass: the most confident MASKED position (3); the
+    # carry rows ran in this pass
+    "a_carried_slot_clears_it": (
+        ([M, 9, M, M], [T, F, T, T], 1, 4, F, 0.0, T, [1, 2, 3, 4], T),
+        ([0] * 4, 0, [M, 9, M, 2], [T, F, T, F], 2, [1, 2, 3, 4], F)),
+    # the pass that removes the last mask hands the block on: B tokens,
+    # the carry set, B masks at pass 0
+    "the_last_mask_hands_the_block_on": (
+        ([M, 9, 8, 6], [T, F, F, F], 3, 4, F, 0.0, T, [1, 2, 3, 4], F),
+        ([3, 9, 8, 6], 4, [M] * 4, [T] * 4, 0, [3, 9, 8, 6], T)),
+    # steps of 1: every pass finishes a block, carried or not
+    "steps_of_1": (
+        ([M] * 4, [T] * 4, 0, 1, F, 0.0, T, [1, 2, 3, 4], T),
+        ([3, 5, 7, 2], 4, [M] * 4, [T] * 4, 0, [3, 5, 7, 2], T)),
+    # two a pass, the second pass of two finishes
+    "steps_of_2_finish": (
+        ([M, 5, M, 2], [T, F, T, F], 1, 2, F, 0.0, T, [0] * 4, F),
+        ([3, 5, 7, 2], 4, [M] * 4, [T] * 4, 0, [3, 5, 7, 2], T)),
     # dynamic: positions 1 and 3 pass 0.9 (two, at least one)
-    assert ntok[1].tolist() == [M, 5, M, 2]
-    assert nmask[1].tolist() == [True, False, True, False]
-    # the committed slot begins its next block; the dead one keeps its own
-    assert ntok[2].tolist() == [M] * 4 and nmask[2].all()
-    assert ntok[3].tolist() == [M] * 4 and nmask[3].all()
-    assert npass.tolist() == [2, 1, 0, 2]
+    "the_dynamic_rule": (
+        ([M] * 4, [T] * 4, 0, 4, T, 0.9, T, [1, 2, 3, 4], T),
+        ([0] * 4, 0, [M, 5, M, 2], [T, F, T, F], 1, [1, 2, 3, 4], F)),
+    # dynamic, a threshold every position passes: one pass finishes
+    "the_dynamic_rule_finishes": (
+        ([M] * 4, [T] * 4, 0, 4, T, 0.0, T, [1, 2, 3, 4], F),
+        ([3, 5, 7, 2], 4, [M] * 4, [T] * 4, 0, [3, 5, 7, 2], T)),
+    # a dead slot keeps its own, its pending carry too
+    "a_dead_slot_keeps_its_own": (
+        ([M, 9, M, M], [T, F, T, T], 2, 4, F, 0.0, F, [1, 2, 3, 4], T),
+        ([0] * 4, 0, [M, 9, M, M], [T, F, T, T], 2, [1, 2, 3, 4], T)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SLOT_CASES))
+def test_denoise_slots_by_the_state_of_a_slot(case):
+    state, want = SLOT_CASES[case]
+    got = _denoise(*([x] for x in state))
+    assert [g[0] for g in got] == list(want)
+
+
+def test_denoise_slots_of_different_states_share_one_call():
+    """Every case's slot in ONE call: what a slot gets is its own
+    state's, whatever its neighbours are at."""
+    cases = sorted(SLOT_CASES)
+    got = _denoise(*map(list, zip(*(SLOT_CASES[c][0] for c in cases))))
+    for i, c in enumerate(cases):
+        assert [g[i] for g in got] == list(SLOT_CASES[c][1]), c
+
+
+def test_lane_rows_stand_below_and_at_the_block():
+    """The ``2B`` rows a slot: carrying, not carrying, a first block
+    below ``B`` (no position below zero, its logits' rows first), dead."""
+    tok = np.arange(10, 26).reshape(4, 4)
+    prev = np.arange(50, 66).reshape(4, 4)
+    tokens, pos, writes, early = map(np.asarray, lane_rows(
+        jnp.asarray([8, 12, 0, 4]), jnp.asarray(tok), jnp.asarray(prev),
+        jnp.asarray([True, False, True, True]),
+        jnp.asarray([True, True, True, False]), mask_id=M))
+    assert early.tolist() == [False, False, True, False]
+    assert pos[:, 0].tolist() == [4, 8, 0, 0] and (pos >= 0).all()
+    assert (np.diff(pos, axis=1) == 1).all()
+    for s in (0, 1, 3):
+        assert tokens[s].tolist() == [*prev[s], *tok[s]]
+    assert tokens[2].tolist() == [*tok[2], M, M, M, M]
+    assert writes.tolist() == [[True] * 8, [False] * 4 + [True] * 4,
+                               [True] * 4 + [False] * 4, [False] * 8]
 
 
 # -- the engine against the reference's generation loop ----------------------
@@ -370,6 +446,14 @@ CASES = {
                                {}),
     "static2": ((8, 5, 14), (8, 6, 11), {"denoising_steps": 2}),
     "static3_remainder_first": ((8, 6), (8, 9), {"denoising_steps": 3}),
+    # every pass finishes a block and carries the one before it
+    "static1_a_block_a_pass": ((8, 7, 2), (12, 9, 10),
+                               {"denoising_steps": 1}),
+    # prompts shorter than a block: the first block starts at 0, where
+    # carry rows would stand below it
+    "prompts_shorter_than_a_block": ((1, 2, 3, 3), (11, 6, 9, 4), {}),
+    # tails of 1-3 tokens begin the first block
+    "tails_of_1_to_3": ((5, 6, 7, 13, 14, 15), (8, 8, 8, 5, 6, 7), {}),
 }
 
 
@@ -433,22 +517,106 @@ def test_engine_stops_at_a_block_holding_the_stop_id(tiny):
     assert (toks, at) == want and toks[-1] == eos and len(toks) < 12
 
 
+def _drain_one_slot(eng, reqs):
+    """Step ``eng`` (one slot) until drained: each request's block
+    table as it stood while the request held the slot, and whether the
+    slot's carry was pending after each step."""
+    tables, pending = {}, []
+    while eng.has_work():
+        eng.step()
+        for r in reqs:
+            if eng._slot_req[0] is r:
+                tables[r.id] = eng._bt[0].copy()
+        pending.append(bool(eng._blk["blk_carry"][0]))
+    return tables, pending
+
+
+def _arena_gap(model, p, eng, req, table):
+    """The largest distance between the arena's K/V at the positions of
+    ``req``'s prompt and of every block it was handed but the last, and
+    the CLEAN stream's: the same tokens written through a fresh arena
+    of the same table, a batch row a token (the prefill lane's own
+    arithmetic, ``test_prefill_then_block_passes_through_the_arena``)."""
+    B = model.generation.block_length
+    seq = np.asarray(list(req.prompt) + req.tokens, np.int32)
+    n = (len(seq) - 1) // B * B          # the last block is never carried
+    bt = jnp.asarray(table)[None]
+    clean = jax.tree.map(jnp.zeros_like, eng.pool.caches)
+    pos = np.arange(n, dtype=np.int32)
+    _, clean = generation.decode(
+        model, p, seq[:n, None], pos[:, None], clean,
+        slot_mask=jnp.ones(n, bool), block_tables=jnp.repeat(bt, n, 0))
+    bs = eng.pool.block_size
+    rows = np.asarray(bt)[0, pos // bs] * bs + pos % bs
+    flat = lambda c: np.asarray(c).reshape(  # noqa: E731
+        c.shape[0], -1, c.shape[-1])[:, rows]
+    return max(np.abs(flat(a) - flat(b)).max()
+               for a, b in zip(eng.pool.caches, clean))
+
+
+@pytest.mark.parametrize("carry_writes", [True, False])
+def test_the_arena_holds_the_clean_keys_of_every_handed_block(
+        tiny, monkeypatch, carry_writes):
+    """After a request of a tail and three blocks: the K/V of its
+    prompt and of every block but the last are the clean stream's — and
+    NOT where the carry rows are kept from writing (the benchmark's
+    ``no_commit_pass`` control in small: the keys then are the last
+    denoise pass's, written from a block that still held a mask)."""
+    from hetu_tpu.serving import engine as engine_mod
+    cfg, model, p = tiny
+    if not carry_writes:
+        def no_carry_writes(*a, **kw):
+            tokens, pos, writes, early = lane_rows(*a, **kw)
+            B = tokens.shape[1] // 2
+            return tokens, pos, writes & (
+                early[:, None] | (jnp.arange(2 * B) >= B)), early
+        monkeypatch.setattr(engine_mod, "lane_rows", no_carry_writes)
+    prompt = np.random.default_rng(11).integers(1, 95, 10)
+    eng = _engine(model, p, slots=1)
+    req = eng.submit(list(map(int, prompt)), SamplingParams(max_tokens=10))
+    tables, _ = _drain_one_slot(eng, [req])
+    assert len(req.tokens) == 10
+    gap = _arena_gap(model, p, eng, req, tables[req.id])
+    if carry_writes:
+        assert gap < 2e-5
+        assert (req.tokens, req.unmask_pass) == \
+            reference.block_diffusion_generate(
+                p, prompt, _config(cfg), max_tokens=10)
+    else:
+        assert gap > 1e-2
+
+
 def test_block_lane_counters_span_field_and_result(tiny):
     cfg, model, p = tiny
     telemetry.enable(True)
+    telemetry.get_tracer().clear()
     reg = telemetry.get_registry()
     passes = reg.counter("serving_diffusion_passes_total")
+    per_block = reg.histogram("serving_diffusion_passes_per_block")
     names = ("serving_diffusion_blocks_total",
+             "serving_diffusion_carried_blocks_total",
              "serving_diffusion_tokens_total")
-    before = [passes.value(kind="denoise"), passes.value(kind="commit")] \
-        + [reg.counter(n).value() for n in names]
+
+    def read():
+        h = per_block.summary()
+        return [passes.value(kind="denoise"), passes.value(kind="commit")] \
+            + [reg.counter(n).value() for n in names] \
+            + [h["count"], h["sum"]]
+
+    before = read()
     eng = _engine(model, p)
     req = eng.submit(list(range(1, 9)), SamplingParams(max_tokens=10))
     eng.run_until_drained()
-    after = [passes.value(kind="denoise"), passes.value(kind="commit")] \
-        + [reg.counter(n).value() for n in names]
-    # 3 blocks of 4 passes + a commit; 10 of 12 tokens handed on
-    assert [b - a for a, b in zip(before, after)] == [12, 3, 3, 10]
+    # 3 blocks of 4 passes and no pass beside them: the first two were
+    # carried into the next one's first pass, the last never; 10 of 12
+    # tokens handed on; 4 passes a block
+    assert [b - a for a, b in zip(before, read())] == \
+        [12, 0, 3, 2, 10, 3, 12]
+    # the lane's LIVE rows: a block a pass, and the carried block's on
+    # passes 4 and 8 (the first iteration is the prompt's prefill)
+    rows = [e.attrs["lane_rows"] for e in telemetry.get_tracer().events()
+            if e.name == "serve/step" and e.attrs["active"]]
+    assert rows == [4, 4, 4, 4, 8, 4, 4, 4, 8, 4, 4, 4]
     res = req.result()
     assert res["unmask_pass"] == req.unmask_pass
     assert sorted(res["unmask_pass"][:4]) == [0, 1, 2, 3]
@@ -463,6 +631,29 @@ def test_block_lane_counters_span_field_and_result(tiny):
     assert "unmask_pass" not in r2.result()
     with pytest.raises(ValueError, match="diffusion over blocks"):
         e2.submit([1, 2, 3], SamplingParams(denoising_steps=2))
+
+
+def test_a_slot_taken_again_begins_without_its_last_requests_carry(tiny):
+    """A request's last block leaves its carry pending; the slot's next
+    request (a prompt of whole blocks: its first pass stands above
+    positions the prefill wrote) must not write it."""
+    cfg, model, p = tiny
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(1, 95, n) for n in (8, 12)]
+    eng = _engine(model, p, slots=1)
+    reqs = [eng.submit(list(map(int, pr)), SamplingParams(max_tokens=8))
+            for pr in prompts]
+    tables, pending = _drain_one_slot(eng, reqs)
+    # a prefill and 2 blocks of 4 passes; the second request's 2
+    # prefills (pending until the second one ends and its first block
+    # begins) and its 2 blocks
+    a_request = ([False] * 3 + [True]) * 2
+    assert pending == [False] + a_request + [True, False] + a_request
+    assert _arena_gap(model, p, eng, reqs[1], tables[reqs[1].id]) < 2e-5
+    for pr, r in zip(prompts, reqs):
+        assert (r.tokens, r.unmask_pass) == \
+            reference.block_diffusion_generate(
+                p, pr, _config(cfg), max_tokens=8)
 
 
 # -- what a block lane refuses -----------------------------------------------

@@ -1,8 +1,10 @@
 """Host time of one engine iteration that is not the wait for the
 device: mean per iteration of the span ``serve/step`` minus its child
 ``serve/device_wait``, over the iterations wholly inside the traced
-slice (``program_trace``)."""
-NAME, UNIT = "engine_host_ms.mixed", "ms"
+slice (``program_trace``) — under the profiler's Python tracer, so
+about twice what ``engine_host_cpu_ms.backlogs`` +
+``engine_host_offcpu_ms.backlogs`` read of the untraced window."""
+NAME, UNIT = "engine_host_ms.backlogs", "ms"
 LAYER = "fused serving step (serving/engine.py)"
 MOVES = "serve_tokens_per_s"
 

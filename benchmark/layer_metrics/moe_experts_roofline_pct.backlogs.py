@@ -1,13 +1,15 @@
-"""The routed-expert layer's share of its roofline: the least time the
-chip could take for one call — the experts that got a token read once,
-6 x hidden x width operations per (token, choice) pair
-(``flops_mla_moe.moe_experts_call`` on the window's means of the
-program's counters ``moe_local_experts_touched_total`` and
-``moe_local_assignments_total`` per ``moe_local_calls_total``) — over
-the device seconds one call took under ``hetu.moe_experts`` (the
+"""The routed-expert layer's share of its roofline where the experts
+are ``flops_mla_moe``'s (DeepSeek-V3-shaped: Kimi, Ling): the least
+time the chip could take for one call — the held experts that got a
+token read once, 6 x hidden x width operations per (token, choice)
+pair routed here (``flops_mla_moe.moe_experts_call`` on the window's
+means of the program's counters ``moe_local_experts_touched_total``
+and ``moe_local_assignments_total`` per ``moe_local_calls_total``) —
+over the device seconds one call took under ``hetu.moe_experts`` (the
 scope's seconds over a third of its grouped-matmul calls: a layer call
-makes three)."""
-NAME, UNIT = "moe_experts_roofline_pct.longdoc", "%"
+makes three). ``.mixed`` and ``.blockgen`` count with other
+configurations' functions and keep readers of their own."""
+NAME, UNIT = "moe_experts_roofline_pct.backlogs", "%"
 LAYER = "expert layer (nn/moe.py)"
 MOVES = "serve_tokens_per_s"
 

@@ -1,9 +1,10 @@
 """Load imbalance of the experts held here over the window: the busiest
 local expert's (token, choice) pairs over the mean
-(``moe_local_expert_tokens{expert}``, the program's counter; 1.0 is
-even). ``source`` in the manifest says ``host_clock``, as the
-``.mixed`` entry's docstring explains."""
-NAME, UNIT = "moe_local_imbalance.blockgen", "x"
+(``moe_local_expert_tokens{expert}``, the program's counter, sampled
+on the benchmark's clock: ``source`` says ``host_clock``; 1.0 is
+even). Where a selection bias is drawn, not learned, it balances
+nothing here."""
+NAME, UNIT = "moe_local_imbalance.backlogs", "x"
 LAYER = "expert layer (nn/moe.py)"
 MOVES = "serve_tokens_per_s"
 

@@ -57,7 +57,7 @@ def _check_line(line, names):
      {"gen_late_p95_ms", "first_token_p95_ms", "first_token_mean_ms",
       "queue_wait_p95_ms", "engine_iter_ms.chat"}),
     ("tiny.backlog", {"serve_tokens_per_s", "setup_s"},
-     {"kv_used_peak_pct", "engine_iter_ms.backlog"}),
+     {"kv_used_peak_pct", "engine_iter_ms.backlogs"}),
 ])
 def test_cell_end_to_end_at_tiny_size(workload, e2e, layers):
     out = _run(workload)

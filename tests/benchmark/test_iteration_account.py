@@ -24,6 +24,9 @@ sys.path.insert(0, ROOT)
 
 from benchmark import harness, iteration_account as ia  # noqa: E402
 
+sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
+
 MANIFEST = os.path.join(HERE, "manifest_account.json")
 WINDOW_METRICS = ("engine_host_cpu_ms", "engine_host_offcpu_ms",
                   "host_dispatch_ms", "wire_cpu_ms")
@@ -256,41 +259,35 @@ def test_lags_from_a_synthetic_xplane(monkeypatch):
     assert ia._lags(None) is None                     # no --trace
 
 
-def test_new_manifest_entries_match_their_readers():
-    """The 14 entries ISSUE 35 appends: two an account metric (one
-    lists the chat cell, one the three backlog cells), readers whose
-    constants agree, cells that report the metric each moves, all
-    mirrored in the rehearsal's manifest."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    mine = harness.load_manifest(MANIFEST)
-    by_name = {x["name"]: x for x in m["per_layer"]}
-    assert [x["name"] for x in m["per_layer"][-14:]] == ALL
+@mc.cell_needs
+def the_fourteen_account_entries(m):
+    """The 14 entries ISSUE 35 brought: two an account metric (one
+    lists the cells that report ``gap_p95_ms``, one every cell that
+    reports ``serve_tokens_per_s``), readers whose constants agree,
+    all mirrored in the rehearsal's manifest, standing together."""
     e2e = {x["name"]: x for x in m["end_to_end"]}
-    backlogs = e2e["serve_tokens_per_s"]["workloads"]
-    rehearsed = {x["name"] for x in mine["per_layer"]}
+    mc.stand_together(m, ALL)
     for name in ALL:
-        x = by_name[name]
-        mod = harness.find_reader(ROOT, m, name)
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (name, x["unit"], x["layer"], x["moves"])
-        assert set(x) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
+        x = mc.entry(m, name)
+        base = name.rsplit(".", 1)[0]
+        unit, layer = mc.ACCOUNT.get(base, (
+            "%" if base == "iter_tail_host_pct" else "ms", mc.STEP))
+        moves = "gap_p95_ms" if name.endswith(".chat") else mc.TOKENS
+        assert x["workloads"] == e2e[moves]["workloads"]
+        mc.needs(m, x["workloads"][0], {name: (unit, layer, moves)},
+                 mirrored_in=MANIFEST)
         assert x["better"] == "lower"
-        if name.endswith(".chat"):
-            assert x["workloads"] == e2e["gap_p95_ms"]["workloads"]
-            assert x["moves"] == "gap_p95_ms"
-        else:
-            assert x["workloads"] == backlogs
-            assert x["moves"] == "serve_tokens_per_s"
-        # the label the frozen count allows; the docstring says the
-        # true source
-        lag = name.rsplit(".", 1)[0] in LAG_METRICS
-        assert x["source"] == ("device_trace" if lag else "host_clock")
-        assert "Source, truly" in mod.__doc__
-        assert x["unit"] == ("%" if name.startswith("iter_tail") else "ms")
-        assert name in rehearsed
-    layers = {x["layer"] for x in m["per_layer"][:-14]}
-    assert {by_name[n]["layer"] for n in ALL} <= layers
+        # the manifest's label; the docstring says the true source
+        assert x["source"] == ("device_trace" if base in LAG_METRICS
+                               else "host_clock")
+        assert "Source, truly" in harness.find_reader(
+            ROOT, m, name).__doc__
+    others = {x["layer"] for x in m["per_layer"] if x["name"] not in ALL}
+    assert {mc.entry(m, n)["layer"] for n in ALL} <= others
+
+
+def test_the_fourteen_account_entries_match_their_readers():
+    the_fourteen_account_entries(mc.real())
 
 
 def _rehearse(workload, seconds, capsys):
@@ -318,7 +315,9 @@ def test_short_rehearsal_window_leaves_the_metrics_out(workload, capsys):
     every new reader returns ``None`` and nothing raises."""
     got, info = _rehearse(workload, 1.5, capsys)
     assert not set(ALL) & set(got)
-    assert got["engine_host_ms." + workload.split(".")[1]]["value"] > 0
+    name = "engine_host_ms." + ("chat" if workload.endswith(".chat")
+                                else "backlogs")
+    assert got[name]["value"] > 0
     assert info["window"] is None and info["lags"] is None
     assert info["dropped"] == 0 < info["events"]
 

@@ -24,6 +24,9 @@ sys.path.insert(0, ROOT)
 from benchmark import harness, iteration_account as ia  # noqa: E402
 from benchmark import process_account as pa  # noqa: E402
 
+sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
+
 MANIFEST = os.path.join(HERE, "manifest_process.json")
 SPLIT = ("window_compile_s", "host_other_cpu_ms", "gc_pause_ms",
          "process_threads_peak", "idle_host_phases_ms")
@@ -169,59 +172,66 @@ def test_idle_account_takes_the_loops_host_phases():
                             "idle_by_span": None}) is None
 
 
-def test_new_manifest_entries_match_their_readers():
-    """The eleven entries ISSUE 53 appends: readers whose constants
-    agree, cells that report the metric each moves, all mirrored in the
-    rehearsal's manifest; nothing else of the manifest differs."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    mine = harness.load_manifest(MANIFEST)
-    by_name = {x["name"]: x for x in m["per_layer"]}
-    assert [x["name"] for x in m["per_layer"][-11:]] == ALL
+@mc.cell_needs
+def the_eleven_process_entries(m):
+    """The eleven entries ISSUE 53 brought: readers whose constants
+    agree, each listing the cells that report the metric it moves (a
+    cell appended to that metric is appended here), all mirrored in the
+    rehearsal's manifest, standing together."""
     e2e = {x["name"]: x for x in m["end_to_end"]}
-    rehearsed = {x["name"] for x in mine["per_layer"]}
-    layers = {x["layer"] for x in m["per_layer"][:-11]}
+    mc.stand_together(m, ALL)
     for name in ALL:
-        x = by_name[name]
-        mod = harness.find_reader(ROOT, m, name)
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (name, x["unit"], x["layer"], x["moves"])
-        assert set(x) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert x["better"] == "lower"
+        x = mc.entry(m, name)
+        base = name.rsplit(".", 1)[0] if "." in name else name
+        unit, layer = mc.PROCESS_ACCOUNT.get(base, ("s", mc.COMPILE))
+        assert unit == UNITS.get(base, "ms")
         if name.endswith(".chat"):
-            assert x["workloads"] == e2e["gap_p95_ms"]["workloads"]
-            assert x["moves"] == "gap_p95_ms"
+            moves, cells = "gap_p95_ms", e2e["gap_p95_ms"]["workloads"]
         elif name.endswith(".backlogs"):
-            assert x["workloads"] == \
-                by_name["engine_host_cpu_ms.backlogs"]["workloads"] == \
-                e2e["serve_tokens_per_s"]["workloads"]
-            assert x["moves"] == "serve_tokens_per_s"
+            moves, cells = mc.TOKENS, e2e[mc.TOKENS]["workloads"]
         else:
-            assert x["workloads"] == by_name["setup_compile_s"]["workloads"]
-            assert x["moves"] == "setup_s"
-        # the label the frozen count allows; the docstring says the
-        # true source
+            moves = mc.SETUP
+            cells = mc.entry(m, "setup_compile_s")["workloads"]
+        assert x["workloads"] == cells
+        mc.needs(m, cells[0], {name: (unit, layer, moves)},
+                 mirrored_in=MANIFEST)
+        assert x["better"] == "lower"
+        # the manifest's label; the docstring says the true source
         assert x["source"] == "host_clock"
-        assert "Source, truly" in mod.__doc__
-        assert x["unit"] == UNITS.get(name.rsplit(".", 1)[0]
-                                      if "." in name else name, "ms")
-        assert name in rehearsed
-    assert by_name["setup_cold_compile_s"]["layer"] in layers
-    assert by_name["idle_host_phases_ms.chat"]["layer"] in layers
+        assert "Source, truly" in harness.find_reader(
+            ROOT, m, name).__doc__
+    others = {x["layer"] for x in m["per_layer"] if x["name"] not in ALL}
+    assert mc.entry(m, "setup_cold_compile_s")["layer"] in others
+    assert mc.entry(m, "idle_host_phases_ms.chat")["layer"] in others
 
 
-def test_the_older_pins_see_the_file_without_the_eleven():
-    """``tests/conftest.py::before_pr53`` drops exactly this PR's
-    entries, which stand LAST in the real file; everything else keeps
-    its place and its content."""
-    sys.path.insert(0, os.path.dirname(HERE))
-    from conftest import APPENDED_BY_PR53, before_pr53
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    assert list(APPENDED_BY_PR53) == ALL
-    old = before_pr53(m)
-    assert old["per_layer"] == m["per_layer"][:-11]
-    assert {k: v for k, v in old.items() if k != "per_layer"} == \
-        {k: v for k, v in m.items() if k != "per_layer"}
+def test_the_eleven_process_entries_match_their_readers():
+    the_eleven_process_entries(mc.real())
+
+
+@mc.cell_needs
+def a_split_quantity_is_one_reader_under_two_names(m):
+    """An entry moves ONE end-to-end metric, so a quantity the chat
+    cell and the backlog cells both report is two entries: ``.chat``
+    and ``.backlogs`` — the same body to the byte, the same unit,
+    ``better`` and layer, each cell under exactly one of them."""
+    names = {x["name"] for x in m["per_layer"]}
+    split = sorted(n[:-len(".chat")] for n in names
+                   if n.endswith(".chat") and
+                   n[:-len(".chat")] + ".backlogs" in names)
+    assert set(SPLIT) <= set(split) and len(split) >= 17
+    for base in split:
+        a, b = mc.entry(m, base + ".chat"), mc.entry(m, base + ".backlogs")
+        assert (a["unit"], a["better"], a["layer"]) == \
+            (b["unit"], b["better"], b["layer"]), base
+        assert (a["moves"], b["moves"]) == ("gap_p95_ms", mc.TOKENS)
+        assert not set(a["workloads"]) & set(b["workloads"])
+        assert mc.reader_body(m, a["name"]) == \
+            mc.reader_body(m, b["name"]), base
+
+
+def test_a_split_quantity_is_one_reader_under_two_names():
+    a_split_quantity_is_one_reader_under_two_names(mc.real())
 
 
 def _rehearse(workload, seconds, capsys):

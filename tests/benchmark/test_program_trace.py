@@ -22,11 +22,14 @@ sys.path.insert(0, ROOT)
 
 from benchmark import harness, program_trace  # noqa: E402
 
+sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
+
 MANIFEST = os.path.join(HERE, "manifest_program.json")
 HOST_METRICS = {
     "tiny.pretrain": {"data_stall_ms"},
     "tiny.chat": {"engine_host_ms.chat", "setup_compile_s"},
-    "tiny.backlog": {"engine_host_ms.backlog", "setup_compile_s"},
+    "tiny.backlog": {"engine_host_ms.backlogs", "setup_compile_s"},
 }
 DEVICE_METRICS = {
     "tiny.pretrain": {"flash_fwd_roofline_pct", "flash_bwd_roofline_pct",
@@ -35,8 +38,8 @@ DEVICE_METRICS = {
                   "step_prefill_ms.chat", "step_kv_arena_ms.chat",
                   "step_sample_ms.chat"},
     "tiny.backlog": {"paged_decode_roofline_pct.backlog",
-                     "step_decode_ms.backlog", "step_prefill_ms.backlog",
-                     "step_kv_arena_ms.backlog"},
+                     "step_decode_ms.backlogs", "step_prefill_ms.backlogs",
+                     "step_kv_arena_ms.backlogs"},
 }
 
 
@@ -75,32 +78,54 @@ def test_host_span_metrics_on_the_cpu_rehearsal(workload, capsys):
         assert stages["trace"] > 0 and stages["compile"] > 0
 
 
-def test_new_manifest_entries_match_their_readers():
-    """Every per-layer entry this PR appended to BENCHMARK.json has a
-    reader whose constants agree, names a cell that reports the metric
-    it moves, and is mirrored in the rehearsal's manifest."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    mine = harness.load_manifest(MANIFEST)
-    new = [x for x in m["per_layer"]
-           if x["source"] in ("program_span", "program_counter")
-           and x["name"] not in ("queue_wait_p95_ms", "kv_used_peak_pct",
-                                 "train_step_ms", "engine_iter_ms.chat",
-                                 "engine_iter_ms.backlog")]
-    assert len(new) == 18
-    e2e = {x["name"]: x for x in m["end_to_end"]}
-    rehearsed = {x["name"] for x in mine["per_layer"]}
-    for x in new:
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
-        assert set(x) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        for cell in x["workloads"]:
-            assert cell in e2e[x["moves"]].get(
-                "workloads", [w["name"] for w in m["workloads"]])
-        assert x["name"] in rehearsed
-        if "roofline" in x["name"]:
-            assert x["unit"] == "%" and x["better"] == "higher"
+#: the per-layer entries PR 24 brought (the program's scopes, spans and
+#: compile events), under the names they have now: metric -> (unit,
+#: layer, the end-to-end metric it moves); each lists every cell of the
+#: rehearsal's kind that reports that metric
+TRAIN_STEP = "train step (engine/train_step.py)"
+PR24 = {
+    "flash_fwd_roofline_pct": ("%", mc.KERNELS, "train_tokens_per_s"),
+    "flash_bwd_roofline_pct": ("%", mc.KERNELS, "train_tokens_per_s"),
+    "train_fwd_ms": ("ms", TRAIN_STEP, "train_tokens_per_s"),
+    "train_bwd_ms": ("ms", TRAIN_STEP, "train_tokens_per_s"),
+    "train_opt_ms": ("ms", TRAIN_STEP, "train_tokens_per_s"),
+    "data_stall_ms": ("ms", "input pipeline (hetu_tpu/data)",
+                      "train_tokens_per_s"),
+    "paged_decode_roofline_pct.chat": ("%", mc.KERNELS, "gap_p95_ms"),
+    "paged_decode_roofline_pct.backlog": ("%", mc.KERNELS, mc.TOKENS),
+    **{f"{n}.chat": ("ms", layer, "gap_p95_ms")
+       for n, layer in (("step_decode_ms", mc.STEP),
+                        ("step_prefill_ms", mc.STEP),
+                        ("step_sample_ms", mc.STEP),
+                        ("step_kv_arena_ms", mc.KV),
+                        ("engine_host_ms", mc.STEP))},
+    **{f"{n}.backlogs": ("ms", layer, mc.TOKENS)
+       for n, layer in (("step_decode_ms", mc.STEP),
+                        ("step_prefill_ms", mc.STEP),
+                        ("step_kv_arena_ms", mc.KV),
+                        ("engine_host_ms", mc.STEP))},
+    "setup_compile_s": ("s", mc.COMPILE, mc.SETUP)}
+CELLS = {"train_tokens_per_s": "gpt2-small.pretrain",
+         "gap_p95_ms": "gpt2-small.chat", mc.TOKENS: "gpt2-large.backlog",
+         mc.SETUP: "gpt2-small.chat"}
+
+
+@mc.cell_needs
+def the_program_trace_entries(m):
+    """Every per-layer entry PR 24 brought has a reader whose constants
+    agree, lists the GPT-2 cell that reports the metric it moves, and is
+    mirrored in the rehearsal's manifest. (It held them to a COUNT of
+    ``program_span`` / ``program_counter`` labels until PR 58: later
+    readers of the program then had to say ``host_clock`` or
+    ``device_trace``; a label is now each entry's own to state.)"""
+    for name, spec in PR24.items():
+        mc.needs(m, CELLS[spec[2]], {name: spec}, mirrored_in=MANIFEST)
+        assert mc.entry(m, name)["source"] in (
+            "program_span", "program_counter", "device_trace")
+
+
+def test_the_program_trace_entries_match_their_readers():
+    the_program_trace_entries(mc.real())
 
 
 def _run(records, trace, config=None):

@@ -21,10 +21,14 @@ sys.path.insert(0, ROOT)
 from benchmark import flops_cohere2_moe, harness  # noqa: E402
 from benchmark.peaks import peaks_for  # noqa: E402
 
+sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
+
+CELL = "command-a-plus-ep8.mixed-backlog"
 MANIFEST = os.path.join(HERE, "manifest_arch.json")
-COUNTED = {"moe_local_imbalance.mixed", "kv_window_dead_pct.mixed",
-           "engine_host_ms.mixed", "setup_compile_s",
-           "engine_iter_ms.mixed", "kv_used_peak_pct"}
+COUNTED = {"moe_local_imbalance.backlogs", "kv_window_dead_pct.mixed",
+           "engine_host_ms.backlogs", "setup_compile_s",
+           "engine_iter_ms.backlogs", "kv_used_peak_pct"}
 
 
 def _run(*, trace):
@@ -57,23 +61,34 @@ def test_serve_arch_cell_end_to_end_at_tiny_size(trace):
         # no device plane on the CPU: the metrics that read device
         # scopes or kernels are left out, the counted ones are there
         assert set(line["metrics"]) == COUNTED
-        assert line["metrics"]["moe_local_imbalance.mixed"]["value"] >= 1
+        assert line["metrics"]["moe_local_imbalance.backlogs"]["value"] >= 1
         assert 0 < line["metrics"]["kv_used_peak_pct"]["value"] <= 100
-        assert line["metrics"]["engine_iter_ms.mixed"]["value"] > 0
+        assert line["metrics"]["engine_iter_ms.backlogs"]["value"] > 0
         assert line["device"]["busy_s"] == 0.0
     json.dumps(line)
 
 
-def test_manifest_names_what_the_cell_needs():
-    """BENCHMARK.json's new cell finds its files by name and every new
-    reader's constants agree with its entry."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = next(w for w in m["workloads"]
-                if w["name"] == "command-a-plus-ep8.mixed-backlog")
-    assert cell["chips"] == 1
-    with open(os.path.join(ROOT, "benchmark/traffic",
-                           f"{cell['traffic']}.json")) as f:
-        mix = json.load(f)
+#: beside what every backlog cell needs: the folded entries this
+#: cell's program feeds, and the entries of its own (bodies no other
+#: cell shares)
+MIXED = {
+    **mc.KV_PEAK, **mc.ENGINE_ITER,
+    **mc.of(["engine_host_ms"], ".backlogs", "ms", mc.STEP),
+    **mc.of(["step_moe_experts_ms", "step_moe_shared_ms",
+             "step_moe_route_ms"], ".backlogs", "ms", mc.MOE),
+    "moe_local_imbalance.backlogs": ("x", mc.MOE, mc.TOKENS),
+    "moe_experts_roofline_pct.mixed": ("%", mc.MOE, mc.TOKENS),
+    "paged_decode_roofline_pct.mixed": ("%", mc.KERNELS, mc.TOKENS),
+    "kv_window_dead_pct.mixed": ("%", mc.KV, mc.TOKENS)}
+
+
+@mc.cell_needs
+def the_mixed_cell(m):
+    cell, _ = mc.cell_of(
+        m, CELL, config="command-a-plus-ep8",
+        traffic="mixed-len-backlog-8k",
+        reduced=["num_hidden_layers", "num_experts", "vocab_size"])
+    mix = mc.traffic_of(cell)
     assert mix["kind"] == "serve_arch"
     assert mix["arrivals"] == {"process": "backlog", "count": 600}
     assert (mix["prompt_len"]["median"], mix["prompt_len"]["sigma"],
@@ -82,13 +97,16 @@ def test_manifest_names_what_the_cell_needs():
     assert (mix["output_len"]["median"], mix["output_len"]["sigma"],
             mix["output_len"]["min"], mix["output_len"]["max"]) == \
         (192, 0.6, 32, 768)
-    mine = [x for x in m["per_layer"]
-            if x.get("workloads") == [cell["name"]]]
-    assert len(mine) == 11
-    for x in mine:
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
+    mc.needs(m, CELL, mc.BACKLOG_CELL)
+    mc.needs(m, CELL, MIXED, mirrored_in=MANIFEST)
+    mc.stand_together(m, ["moe_experts_roofline_pct.mixed",
+                          "paged_decode_roofline_pct.mixed"])
+
+
+def test_benchmark_json_names_what_the_mixed_cell_needs():
+    """BENCHMARK.json's cell finds its files by name and every metric
+    it needs lists it, with a reader whose constants agree."""
+    the_mixed_cell(mc.real())
 
 
 def test_published_widths_are_in_the_configuration():
@@ -151,7 +169,7 @@ def test_new_metrics_arithmetic():
     assert p["bytes"] == 2 * tokens * 1024 * 2
     assert p["flops"] == 4 * tokens * 128 * 128
     # imbalance: busiest over mean
-    imb = harness.find_reader(ROOT, m, "moe_local_imbalance.mixed")
+    imb = harness.find_reader(ROOT, m, "moe_local_imbalance.backlogs")
     assert imb.read(_run_of(cfg, {"moe": {"per_expert": [10, 30, 20,
                                                         20]}})) == 1.5
     assert imb.read(_run_of(cfg, {})) is None
@@ -162,14 +180,15 @@ def test_new_metrics_arithmetic():
     assert got == pytest.approx(100 * 0.75 * 400 / 2000)
     assert dead.read(_run_of(cfg, {})) is None
     # the iteration: the window over the counter's increase
-    it = harness.find_reader(ROOT, m, "engine_iter_ms.mixed")
+    it = harness.find_reader(ROOT, m, "engine_iter_ms.backlogs")
     assert it.read(_run_of(cfg, {"engine_iterations": 190,
                                  "engine_iterations_s": 45.0})) == \
         pytest.approx(45e3 / 190)
     assert it.read(_run_of(cfg, {})) is None
     # readers of device scopes return nothing without a device plane
-    for name in ("step_moe_experts_ms.mixed", "step_moe_route_ms.mixed",
-                 "step_moe_shared_ms.mixed",
+    for name in ("step_moe_experts_ms.backlogs",
+                 "step_moe_route_ms.backlogs",
+                 "step_moe_shared_ms.backlogs",
                  "moe_experts_roofline_pct.mixed",
                  "paged_decode_roofline_pct.mixed"):
         run = _run_of(cfg, {"live_pages": [1], "live_pages_window": [1],

@@ -5,7 +5,7 @@ and a mix of their own (new files HERE only), with and without
 ``--trace``; each planted control refused THROUGH the harness; the
 block states rebuilt from a request's unmask passes; what
 ``BENCHMARK.json`` says of the cell — by NAME, so that the next cell can
-be appended behind it — and of the pins' views in ``tests/conftest.py``;
+be appended behind it;
 the configuration against the catalog's row; and the arithmetic of
 ``benchmark/flops_sdar_moe.py``."""
 
@@ -26,25 +26,34 @@ from benchmark.peaks import peaks_for  # noqa: E402
 from benchmark.runners import serve_arch_blocks  # noqa: E402
 
 sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
 import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_blocks.json")
 CELL = "sdar-30b-a3b-ep8.reason-1k-backlog"
-BEFORE = "ling-3.0-flash-vl-ep8.video-8k-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-BLOCKGEN = [
-    "step_decode_ms", "step_prefill_ms", "step_sample_ms", "engine_iter_ms",
-    "step_moe_experts_ms", "step_moe_route_ms", "moe_experts_roofline_pct",
-    "moe_local_imbalance", "paged_block_roofline_pct",
-    "diffusion_tokens_per_pass", "diffusion_commit_pass_pct"]
+#: the cell's own entries — bodies no other cell shares: the sampler's
+#: scope is ``hetu.diffusion_sample``, the experts count with
+#: ``flops_sdar_moe`` — of which the last three stand together ...
+OWN = {
+    "step_sample_ms.blockgen": ("ms", mc.STEP, mc.TOKENS),
+    "moe_experts_roofline_pct.blockgen": ("%", mc.MOE, mc.TOKENS),
+    "paged_block_roofline_pct.blockgen": ("%", mc.KERNELS, mc.TOKENS),
+    "diffusion_tokens_per_pass.blockgen": ("tokens/pass", mc.STEP,
+                                           mc.TOKENS),
+    "diffusion_commit_pass_pct.blockgen": ("%", mc.STEP, mc.TOKENS)}
+#: ... and, beside what every backlog cell needs, the folded entries
+#: its program feeds
+FOLDED = {
+    **mc.KV_PEAK, **mc.ENGINE_ITER,
+    **mc.of(["step_moe_experts_ms", "step_moe_route_ms"], ".backlogs",
+            "ms", mc.MOE),
+    "moe_local_imbalance.backlogs": ("x", mc.MOE, mc.TOKENS)}
 #: read without a device plane: counters and the window's iterations
-NO_DEVICE = {"engine_iter_ms", "moe_local_imbalance",
-             "diffusion_tokens_per_pass", "diffusion_commit_pass_pct"}
-COUNTED = {n + ".blockgen" for n in NO_DEVICE} | {"setup_compile_s",
-                                                  "kv_used_peak_pct"}
-ACCOUNT = ["engine_host_cpu_ms", "engine_host_offcpu_ms",
-           "host_dispatch_ms", "wire_cpu_ms", "step_launch_lag_ms",
-           "step_fetch_lag_ms"]
+COUNTED = {"engine_iter_ms.backlogs", "moe_local_imbalance.backlogs",
+           "diffusion_tokens_per_pass.blockgen",
+           "diffusion_commit_pass_pct.blockgen", "setup_compile_s",
+           "kv_used_peak_pct"}
 
 
 def _config():
@@ -174,19 +183,12 @@ def test_block_states_from_the_unmask_passes():
     assert (noised[:, 8:] == 0).all()            # beyond the whole blocks
 
 
-def test_manifest_names_what_the_blocks_cell_needs():
-    """By name, not by place: a later PR appends behind these."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = [w["name"] for w in m["workloads"]]
-    cell = m["workloads"][cells.index(CELL)]
-    assert cells.index(CELL) == cells.index(BEFORE) + 1
-    assert cell["chips"] == 1 and cell["config"] == "sdar-30b-a3b-ep8"
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    assert config["reduced"] == ["num_experts", "vocab_size"]
-    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
-    with open(os.path.join(ROOT, "benchmark/traffic",
-                           f"{cell['traffic']}.json")) as f:
-        mix = json.load(f)
+@mc.cell_needs
+def the_blocks_cell(m):
+    cell, _ = mc.cell_of(m, CELL, config="sdar-30b-a3b-ep8",
+                         traffic="reason-block4-backlog",
+                         reduced=["num_experts", "vocab_size"])
+    mix = mc.traffic_of(cell)
     assert mix["kind"] == "serve_arch_blocks" and mix["schedule_seed"] == 45
     assert mix["arrivals"] == {"process": "backlog", "count": 400}
     assert mix["drain_s"] == 0 and mix["ramp_s"] >= 50
@@ -196,57 +198,33 @@ def test_manifest_names_what_the_blocks_cell_needs():
                                  "max": 1024}
     gen = _config()["serve"]["generation"]
     assert all(gen[k] == v for k, v in mix["generation"].items())
-    names = [x["name"] for x in m["per_layer"]]
-    first = names.index(BLOCKGEN[0] + ".blockgen")
-    assert names[first:first + len(BLOCKGEN)] == \
-        [n + ".blockgen" for n in BLOCKGEN]
-    assert first > names.index("mla_decode_roofline_pct.video")
-    rehearsed = {x["name"] for x in
-                 harness.load_manifest(MANIFEST)["per_layer"]}
-    for x in m["per_layer"][first:first + len(BLOCKGEN)]:
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
-        assert x["moves"] == "serve_tokens_per_s"
-        assert x["workloads"] == [CELL] and x["name"] in rehearsed
-        assert x["source"] in ("device_trace", "host_clock")
-        if "roofline" in x["name"]:
-            assert x["unit"] == "%" and x["better"] == "higher"
-    # the cell behind the Ling cell wherever both are listed
-    listed = [x for x in m["end_to_end"] + m["per_layer"]
-              if CELL in x.get("workloads", []) and x["workloads"] != [CELL]]
-    assert [x["name"] for x in listed] == [
-        "serve_tokens_per_s", "kv_used_peak_pct", "setup_compile_s"] + [
-        n + ".backlogs" for n in ACCOUNT]
-    for x in listed:
-        w = x["workloads"]
-        assert w.index(CELL) == w.index(BEFORE) + 1
+    mc.needs(m, CELL, mc.BACKLOG_CELL)
+    mc.needs(m, CELL, FOLDED, mirrored_in=MANIFEST)
+    mc.needs(m, CELL, OWN, mirrored_in=MANIFEST,
+             sources=("device_trace", "host_clock"))
+    mc.stand_together(m, list(OWN)[-3:])
 
 
-def test_the_pins_see_the_file_as_of_their_cell():
-    """``tests/conftest.py``: ``as_of`` the Ling cell leaves out exactly
-    what this cell appended; at this cell it is the file."""
-    sys.path.insert(0, os.path.dirname(HERE))
-    from conftest import AS_OF_LATER_PINS, AS_OF_PINS, as_of
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    assert set(AS_OF_LATER_PINS.values()) == {BEFORE}
-    assert not set(AS_OF_LATER_PINS) & set(AS_OF_PINS)
-    cells = [w["name"] for w in m["workloads"]]
-    assert as_of(m, cells[-1]) == m
-    old = as_of(m, BEFORE)
-    at = cells.index(CELL)
-    assert [w["name"] for w in old["workloads"]] == cells[:at]
-    assert [c["name"] for c in old["configs"]] == \
-        [c["name"] for c in m["configs"]][:6]
-    gone = {x["name"] for x in m["per_layer"]} \
-        - {x["name"] for x in old["per_layer"]}
-    assert gone >= {n + ".blockgen" for n in BLOCKGEN}
-    for kind in ("end_to_end", "per_layer"):
-        kept = {x["name"]: x for x in m[kind]}
-        for x in old[kind]:
-            assert CELL not in x.get("workloads", [])
-            assert dict(kept[x["name"]], workloads=None) == \
-                dict(x, workloads=None)
+def test_benchmark_json_names_what_the_blocks_cell_needs():
+    """By name, not by place: a later PR appends behind these."""
+    the_blocks_cell(mc.real())
+
+
+@mc.cell_needs
+def the_block_lane_is_read_once(m):
+    """Two quantities of this cell have a body of their own AND a folded
+    namesake other cells list: the cell is read by its own and not by
+    the namesake — a run prints each quantity once."""
+    for own in ("step_sample_ms.blockgen",
+                "moe_experts_roofline_pct.blockgen"):
+        assert mc.lists(m, own, CELL)
+        twin = own.rsplit(".", 1)[0] + ".backlogs"
+        assert not mc.lists(m, twin, CELL), twin
+        assert mc.reader_body(m, own) != mc.reader_body(m, twin)
+
+
+def test_the_block_lane_has_its_own_sampler_and_expert_count():
+    the_block_lane_is_read_once(mc.real())
 
 
 def test_published_widths_are_in_the_sdar_configuration():
@@ -324,9 +302,9 @@ def test_flops_sdar_arithmetic_and_readers_without_a_device():
     run = types.SimpleNamespace(config=c, peaks=peaks, trace=None,
                                 cell={"name": "none"}, records={})
     m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    for name in BLOCKGEN:
-        assert harness.find_reader(ROOT, m, name + ".blockgen") \
-            .read(run) is None, name
+    mc.silent_without_a_device(
+        m, [n for n in {**FOLDED, **OWN} if n not in mc.KV_PEAK]
+        + ["step_decode_ms.backlogs", "step_prefill_ms.backlogs"], c)
     run.records = {"diffusion": {
         "denoise_passes": 400.0, "commit_passes": 100.0,
         "serving_diffusion_blocks_total": 100.0,

@@ -4,8 +4,8 @@ its plain reference end to end at a tiny size through a manifest, a
 configuration and a mix of their own (new files HERE only), with and
 without ``--trace``; each planted control refused THROUGH the harness;
 what ``BENCHMARK.json`` says of the cell — by NAME, so that the next
-cell can be appended behind it — and of the pins' views in
-``tests/conftest.py``; the configuration against the catalog's row; and
+cell can be appended behind it; the configuration against the
+catalog's row; and
 the arithmetic of ``benchmark/flops_kda_mla_moe.py`` and
 ``benchmark/kda.py``."""
 
@@ -26,26 +26,35 @@ from benchmark import kda as kda_readers  # noqa: E402
 from benchmark.peaks import peaks_for  # noqa: E402
 
 sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
 import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_kda.json")
 CELL = "ling-3.0-flash-vl-ep8.video-8k-backlog"
-BEFORE = "minicpm-sala-pp2.longdoc-32k-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-VIDEO = [
-    "step_prefill_ms", "step_decode_ms", "step_sample_ms", "engine_iter_ms",
-    "step_kda_conv_ms", "step_kda_scan_ms", "step_kda_update_ms",
-    "step_state_copies_ms", "kda_scan_roofline_pct",
-    "kda_update_roofline_pct", "step_moe_experts_ms", "step_moe_shared_ms",
-    "step_moe_route_ms", "moe_experts_roofline_pct", "moe_local_imbalance",
-    "moe_group_held_pct", "mla_decode_roofline_pct"]
+KDA = "Kimi Delta Attention (nn/parallel.py, ops/kda.py)"
+#: the cell's own entries, which stand together in this order (the
+#: expert layer's folded entries stood between the last two) ...
+OWN = {
+    **mc.of(["step_kda_conv_ms", "step_kda_scan_ms", "step_kda_update_ms",
+             "step_state_copies_ms"], ".video", "ms", KDA),
+    **mc.of(["kda_scan_roofline_pct", "kda_update_roofline_pct"], ".video",
+            "%", mc.KERNELS)}
+HELD = {"moe_group_held_pct.video": ("%", mc.MOE, mc.TOKENS)}
+#: ... and, beside what every backlog cell needs, the folded entries
+#: its program feeds
+FOLDED = {
+    **mc.KV_PEAK, **mc.ENGINE_ITER,
+    **mc.of(["step_sample_ms"], ".backlogs", "ms", mc.STEP),
+    **mc.of(["step_moe_experts_ms", "step_moe_shared_ms",
+             "step_moe_route_ms"], ".backlogs", "ms", mc.MOE),
+    "moe_local_imbalance.backlogs": ("x", mc.MOE, mc.TOKENS),
+    "moe_experts_roofline_pct.backlogs": ("%", mc.MOE, mc.TOKENS),
+    "mla_decode_roofline_pct.backlogs": ("%", mc.KERNELS, mc.TOKENS)}
 #: read without a device plane: counters and the window's iterations
-NO_DEVICE = {"engine_iter_ms", "moe_local_imbalance", "moe_group_held_pct"}
-COUNTED = {n + ".video" for n in NO_DEVICE} | {"setup_compile_s",
-                                               "kv_used_peak_pct"}
-ACCOUNT = ["engine_host_cpu_ms", "engine_host_offcpu_ms",
-           "host_dispatch_ms", "wire_cpu_ms", "step_launch_lag_ms",
-           "step_fetch_lag_ms"]
+NO_DEVICE = {"engine_iter_ms.backlogs", "moe_local_imbalance.backlogs",
+             "moe_group_held_pct.video"}
+COUNTED = NO_DEVICE | {"setup_compile_s", "kv_used_peak_pct"}
 
 
 def _config():
@@ -117,20 +126,13 @@ def test_a_planted_control_is_refused_through_the_harness(control):
     assert out["info"]["reference"]["near_ties_over_share"] > 0.05
 
 
-def test_manifest_names_what_the_video_cell_needs():
-    """By name, not by place: a later PR appends behind these."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = [w["name"] for w in m["workloads"]]
-    cell = m["workloads"][cells.index(CELL)]
-    assert cells.index(CELL) == cells.index(BEFORE) + 1
-    assert cell["chips"] == 1 and cell["config"] == "ling-3.0-flash-vl-ep8"
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    assert config["reduced"] == ["num_hidden_layers", "num_experts",
-                                 "vocab_size"]
-    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
-    with open(os.path.join(ROOT, "benchmark/traffic",
-                           f"{cell['traffic']}.json")) as f:
-        mix = json.load(f)
+@mc.cell_needs
+def the_video_cell(m):
+    cell, _ = mc.cell_of(
+        m, CELL, config="ling-3.0-flash-vl-ep8",
+        traffic="video-fixed-8k-backlog",
+        reduced=["num_hidden_layers", "num_experts", "vocab_size"])
+    mix = mc.traffic_of(cell)
     assert mix["kind"] == "serve_arch_ties" and mix["schedule_seed"] == 41
     assert mix["arrivals"] == {"process": "backlog", "count": 800}
     assert mix["drain_s"] == 0 and mix["ramp_s"] >= 60
@@ -138,74 +140,48 @@ def test_manifest_names_what_the_video_cell_needs():
                                  "min": 8192, "max": 8192}
     assert mix["output_len"] == {"dist": "fixed", "value": 256,
                                  "min": 256, "max": 256}
-    names = [x["name"] for x in m["per_layer"]]
-    first = names.index(VIDEO[0] + ".video")
-    assert names[first:first + len(VIDEO)] == [n + ".video" for n in VIDEO]
-    assert first > names.index("engine_iter_ms.longctx")
-    rehearsed = {x["name"] for x in
-                 harness.load_manifest(MANIFEST)["per_layer"]}
-    for x in m["per_layer"][first:first + len(VIDEO)]:
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
-        assert x["moves"] == "serve_tokens_per_s"
-        assert x["workloads"] == [CELL] and x["name"] in rehearsed
-        assert x["source"] in ("device_trace", "host_clock")
-        if "roofline" in x["name"]:
-            assert x["unit"] == "%" and x["better"] == "higher"
-    # the cell behind the MiniCPM cell wherever both are listed
-    listed = [x for x in m["end_to_end"] + m["per_layer"]
-              if CELL in x.get("workloads", []) and x["workloads"] != [CELL]]
-    assert [x["name"] for x in listed] == [
-        "serve_tokens_per_s", "kv_used_peak_pct", "setup_compile_s"] + [
-        n + ".backlogs" for n in ACCOUNT]
-    for x in listed:
-        w = x["workloads"]
-        assert w.index(CELL) == w.index(BEFORE) + 1
+    mc.needs(m, CELL, mc.BACKLOG_CELL)
+    mc.needs(m, CELL, FOLDED, mirrored_in=MANIFEST)
+    mc.needs(m, CELL, {**OWN, **HELD}, mirrored_in=MANIFEST,
+             sources=("device_trace", "host_clock"))
+    mc.stand_together(m, list(OWN))
 
 
-def test_the_pins_are_shown_the_files_own_entries():
-    """``tests/conftest.py``: ``later_entries_first`` only reorders;
-    ``as_of`` leaves out exactly what was appended after a cell, and at
-    the last cell it is the file."""
-    sys.path.insert(0, os.path.dirname(HERE))
-    from conftest import (
-        AS_OF_PINS, PINNED_LAST_CELL, PINNED_LAST_ENTRIES, as_of,
-        later_entries_first,
-    )
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    last = m["workloads"][-1]["name"]
-    assert as_of(m, last) == m
+def test_benchmark_json_names_what_the_video_cell_needs():
+    """By name, not by place: a later PR appends behind these."""
+    the_video_cell(mc.real())
 
-    def canon(x):
-        return json.dumps(dict(x, workloads=sorted(x.get("workloads", []))),
-                          sort_keys=True)
-    shown = later_entries_first(m)
-    for kind in ("end_to_end", "per_layer"):
-        assert sorted(map(canon, shown[kind])) == \
-            sorted(map(canon, m[kind]))
-        for x in shown[kind]:
-            if PINNED_LAST_CELL in x.get("workloads", []):
-                assert x["workloads"][-1] == PINNED_LAST_CELL
-    assert shown["per_layer"][-1]["name"] == PINNED_LAST_ENTRIES[1]
-    assert set(AS_OF_PINS.values()) == {BEFORE}
-    old = as_of(m, BEFORE)
-    assert [w["name"] for w in old["workloads"]] == \
-        [w["name"] for w in m["workloads"]][:6]
-    assert [c["name"] for c in old["configs"]] == \
-        [c["name"] for c in m["configs"]][:5]
-    gone = {x["name"] for x in m["per_layer"]} \
-        - {x["name"] for x in old["per_layer"]}
-    assert gone == {n + ".video" for n in VIDEO}
-    for kind in ("end_to_end", "per_layer"):
-        kept = {x["name"]: x for x in m[kind]}
-        for x in old[kind]:
-            assert CELL not in x.get("workloads", [])
-            assert dict(kept[x["name"]], workloads=None) == \
-                dict(x, workloads=None)
-            if "workloads" in x:
-                assert x["workloads"] == [
-                    c for c in kept[x["name"]]["workloads"] if c != CELL]
+
+def test_one_body_reads_both_latent_configurations(monkeypatch):
+    """The two folded rooflines count with ``flops_mla_moe`` from the
+    run's OWN configuration: Ling's 32 heads and 768-wide experts here,
+    Kimi's 16 and 1408 in ``test_serve_arch_mla.py`` — one reader, no
+    constant of either cell in it."""
+    from benchmark import program_trace, scopes
+    c, m = _config(), mc.real()
+    run = mc.run_without_a_device(c, {
+        "live_pages": [4000, 4200], "block_size": 64, "moe": {
+            "moe_local_calls_total": 10,
+            "moe_local_assignments_total": 20480,
+            "moe_local_experts_touched_total": 640}})
+    monkeypatch.setattr(program_trace, "kernel_seconds_per_call",
+                        lambda run, k, kernels_per_call=1: 2.6e-3)
+    monkeypatch.setattr(scopes, "seconds", lambda run, s: 3.0e-3)
+    monkeypatch.setattr(scopes, "calls", lambda run, s, op: 9)
+    got = harness.find_reader(
+        ROOT, m, "mla_decode_roofline_pct.backlogs").read(run)
+    need = 4100 * 64 * 576 * 2 / 819e9          # bound by its bytes
+    assert got == pytest.approx(100 * need / 2.6e-3) and 0 < got < 100
+    got = harness.find_reader(
+        ROOT, m, "moe_experts_roofline_pct.backlogs").read(run)
+    # 64 experts of 3 x 2560 x 768 bf16 read once a call; three kernel
+    # calls a layer call: 9 calls = 3 layer calls in 3 ms
+    need = 64 * 3 * c["hidden_size"] * c["moe_intermediate_size"] * 2 \
+        / 819e9
+    assert got == pytest.approx(100 * need / 1.0e-3) and 0 < got < 100
+    run.config = {"n_embd": 8}                  # no latent rows there
+    assert harness.find_reader(
+        ROOT, m, "mla_decode_roofline_pct.backlogs").read(run) is None
 
 
 def test_published_widths_are_in_the_ling_configuration():
@@ -294,10 +270,10 @@ def test_flops_kda_arithmetic_and_readers_without_a_device():
     run = types.SimpleNamespace(config=c, peaks=peaks, trace=None,
                                 cell={"name": "none"}, records={})
     m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    for name in VIDEO:
-        if name not in NO_DEVICE:
-            assert harness.find_reader(ROOT, m, name + ".video") \
-                .read(run) is None, name
+    mc.silent_without_a_device(
+        m, [n for n in {**FOLDED, **OWN}
+            if n not in NO_DEVICE | set(mc.KV_PEAK)]
+        + ["step_decode_ms.backlogs", "step_prefill_ms.backlogs"], c)
 
 
 def test_copies_of_the_slot_leaves_and_the_group_share(monkeypatch):

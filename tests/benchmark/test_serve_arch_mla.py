@@ -21,11 +21,12 @@ from benchmark import flops, flops_mla_moe, harness  # noqa: E402
 from benchmark.peaks import peaks_for  # noqa: E402
 
 sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
 import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_mla.json")
 CELL = "kimi-vl-a3b-pp4.longdoc-backlog"
-COUNTED = {"moe_local_imbalance.longdoc", "engine_host_ms.longdoc",
+COUNTED = {"moe_local_imbalance.backlogs", "engine_host_ms.backlogs",
            "setup_compile_s", "kv_used_peak_pct"}
 
 
@@ -53,20 +54,33 @@ def test_serve_arch_mla_cell_end_to_end_at_tiny_size(trace):
         # no device plane on the CPU: the metrics that read device
         # scopes or kernels are left out, the counted ones are there
         assert set(line["metrics"]) == COUNTED
-        assert line["metrics"]["moe_local_imbalance.longdoc"]["value"] >= 1
+        assert line["metrics"]["moe_local_imbalance.backlogs"]["value"] >= 1
         assert 0 < line["metrics"]["kv_used_peak_pct"]["value"] <= 100
         assert line["device"]["busy_s"] == 0.0
     json.dumps(line)
 
 
-def test_manifest_names_what_the_longdoc_cell_needs():
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = next(w for w in m["workloads"] if w["name"] == CELL)
-    assert cell["chips"] == 1 and cell["config"] == "kimi-vl-a3b-pp4"
-    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
-    with open(os.path.join(ROOT, "benchmark/traffic",
-                           f"{cell['traffic']}.json")) as f:
-        mix = json.load(f)
+#: beside what every backlog cell needs: the folded entries this
+#: cell's program feeds, and the one of its own
+LONGDOC = {
+    **mc.KV_PEAK,
+    **mc.of(["step_sample_ms", "engine_host_ms"], ".backlogs", "ms",
+            mc.STEP),
+    **mc.of(["step_moe_experts_ms", "step_moe_shared_ms",
+             "step_moe_route_ms"], ".backlogs", "ms", mc.MOE),
+    "moe_local_imbalance.backlogs": ("x", mc.MOE, mc.TOKENS),
+    "moe_experts_roofline_pct.backlogs": ("%", mc.MOE, mc.TOKENS),
+    "mla_decode_roofline_pct.backlogs": ("%", mc.KERNELS, mc.TOKENS),
+    "step_mla_absorb_ms.longdoc": (
+        "ms", "latent attention (nn/parallel.py)", mc.TOKENS)}
+
+
+@mc.cell_needs
+def the_longdoc_cell(m):
+    cell, _ = mc.cell_of(m, CELL, config="kimi-vl-a3b-pp4",
+                         traffic="longdoc-backlog-16k",
+                         reduced=["num_hidden_layers"])
+    mix = mc.traffic_of(cell)
     assert mix["kind"] == "serve_arch_ties" and mix["schedule_seed"] == 30
     assert mix["arrivals"] == {"process": "backlog", "count": 600}
     assert (mix["ramp_s"], mix["drain_s"]) == (30, 0)
@@ -76,23 +90,12 @@ def test_manifest_names_what_the_longdoc_cell_needs():
     assert [mix["output_len"][k] for k in
             ("dist", "median", "sigma", "min", "max")] == \
         ["lognormal", 256, 0.6, 32, 1024]
-    mine = [x for x in m["per_layer"] if x.get("workloads") == [CELL]]
-    assert len(mine) == 11
-    rehearsed = {x["name"] for x in
-                 harness.load_manifest(MANIFEST)["per_layer"]}
-    for x in mine:
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
-        assert x["moves"] == "serve_tokens_per_s"
-        assert x["name"] in rehearsed
-        if "roofline" in x["name"]:
-            assert x["unit"] == "%" and x["better"] == "higher"
-    for name in ("serve_tokens_per_s", "setup_compile_s",
-                 "kv_used_peak_pct"):
-        entry = next(x for x in m["end_to_end"] + m["per_layer"]
-                     if x["name"] == name)
-        assert entry["workloads"][-1] == CELL
+    mc.needs(m, CELL, mc.BACKLOG_CELL)
+    mc.needs(m, CELL, LONGDOC, mirrored_in=MANIFEST)
+
+
+def test_benchmark_json_names_what_the_longdoc_cell_needs():
+    the_longdoc_cell(mc.real())
 
 
 def test_published_widths_are_in_the_kimi_configuration():
@@ -167,14 +170,14 @@ def test_flops_mla_moe_arithmetic():
             "per_expert": [10, 30, 20, 20],
             "moe_local_calls_total": 1, "moe_local_assignments_total": 1,
             "moe_local_experts_touched_total": 1}})
-    imb = harness.find_reader(ROOT, m, "moe_local_imbalance.longdoc")
+    imb = harness.find_reader(ROOT, m, "moe_local_imbalance.backlogs")
     assert imb.read(run) == 1.5
     # readers of device scopes return nothing without a device plane,
     # and the latent roofline nothing on a configuration without MLA
-    for name in ("step_mla_absorb_ms.longdoc", "step_moe_experts_ms.longdoc",
-                 "step_moe_route_ms.longdoc", "step_moe_shared_ms.longdoc",
-                 "step_decode_ms.longdoc", "step_prefill_ms.longdoc",
-                 "step_sample_ms.longdoc",
-                 "moe_experts_roofline_pct.longdoc",
-                 "mla_decode_roofline_pct.longdoc"):
+    for name in ("step_mla_absorb_ms.longdoc", "step_moe_experts_ms.backlogs",
+                 "step_moe_route_ms.backlogs", "step_moe_shared_ms.backlogs",
+                 "step_decode_ms.backlogs", "step_prefill_ms.backlogs",
+                 "step_sample_ms.backlogs",
+                 "moe_experts_roofline_pct.backlogs",
+                 "mla_decode_roofline_pct.backlogs"):
         assert harness.find_reader(ROOT, m, name).read(run) is None

@@ -25,22 +25,25 @@ from benchmark import retention as readers  # noqa: E402
 from benchmark.peaks import peaks_for  # noqa: E402
 
 sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
 import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_retention.json")
 CELL = "brumby-14b-pp4.repo-16k-backlog"
-BEFORE = "sdar-30b-a3b-ep8.reason-1k-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-RETENTION = [
-    "step_prefill_ms", "step_decode_ms", "step_sample_ms", "engine_iter_ms",
-    "step_retention_scan_ms", "step_retention_update_ms",
-    "step_state_copies_ms", "retention_scan_roofline_pct",
-    "retention_update_roofline_pct", "state_slots_live_pct"]
-#: read without a device plane: the window's iterations and samples
-NO_DEVICE = {"engine_iter_ms", "state_slots_live_pct"}
-ACCOUNT = ["engine_host_cpu_ms", "engine_host_offcpu_ms",
-           "host_dispatch_ms", "wire_cpu_ms", "step_launch_lag_ms",
-           "step_fetch_lag_ms"]
+POWER = "power retention (nn/parallel.py, ops/retention_pallas.py)"
+#: the cell's own entries, which stand together in this order ...
+OWN = {
+    **mc.of(["step_retention_scan_ms", "step_retention_update_ms",
+             "step_state_copies_ms"], ".retention", "ms", POWER),
+    **mc.of(["retention_scan_roofline_pct",
+             "retention_update_roofline_pct"], ".retention", "%",
+            mc.KERNELS),
+    "state_slots_live_pct.retention": ("%", mc.KV, mc.TOKENS)}
+#: ... and, beside what every backlog cell needs, the folded entries
+#: its program feeds (no arena: no share of blocks)
+FOLDED = {**mc.ENGINE_ITER,
+          **mc.of(["step_sample_ms"], ".backlogs", "ms", mc.STEP)}
 
 
 def _config():
@@ -80,11 +83,10 @@ def test_serve_arch_state_cell_end_to_end_at_tiny_size(trace):
         # no device plane on the CPU: the metrics that read device
         # scopes are left out, the counted ones are there
         assert set(line["metrics"]) >= {
-            "setup_compile_s", "engine_iter_ms.retention",
+            "setup_compile_s", "engine_iter_ms.backlogs",
             "state_slots_live_pct.retention"}
         assert not any("roofline" in k or k.startswith("step_")
-                       and k.endswith(".retention")
-                       for k in line["metrics"])
+                       and "lag" not in k for k in line["metrics"])
         live = line["metrics"]["state_slots_live_pct.retention"]["value"]
         assert 0 < live <= 100
         assert line["device"]["busy_s"] == 0.0
@@ -159,23 +161,14 @@ def test_the_runner_asks_of_the_step_what_can_be_said_of_it(monkeypatch):
     assert not out["correct"] and "keeps token" in out["why_incorrect"][0]
 
 
-def test_manifest_names_what_the_retention_cell_needs():
-    """By name, not by place: a later PR appends behind these."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = [w["name"] for w in m["workloads"]]
-    cell = m["workloads"][cells.index(CELL)]
-    assert cells.index(CELL) == cells.index(BEFORE) + 1
-    assert cell["chips"] == 1 and cell["config"] == "brumby-14b-pp4"
-    assert cell["traffic"] == "repo-fixed-16k-backlog"
-    assert len(cell["why"]) <= 200
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    assert config["reduced"] == ["num_hidden_layers"]
+@mc.cell_needs
+def the_retention_cell(m):
+    cell, config = mc.cell_of(m, CELL, config="brumby-14b-pp4",
+                              traffic="repo-fixed-16k-backlog",
+                              reduced=["num_hidden_layers"])
     assert config["file"] == "benchmark/configs/brumby-14b-pp4.json"
     assert config["source"] == _config()["source"]
-    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
-    with open(os.path.join(ROOT, "benchmark/traffic",
-                           f"{cell['traffic']}.json")) as f:
-        mix = json.load(f)
+    mix = mc.traffic_of(cell)
     assert mix["kind"] == "serve_arch_state" and mix["schedule_seed"] == 51
     assert mix["arrivals"] == {"process": "backlog", "count": 320}
     assert mix["drain_s"] == 0 and mix["ramp_s"] >= 40
@@ -188,55 +181,35 @@ def test_manifest_names_what_the_retention_cell_needs():
     # the lane stays full: slots x chunks >= chunks + outputs
     chunks = 16384 // _config()["serve"]["prefill_chunk"]
     assert slots * chunks >= chunks + mix["output_len"]["value"]
-    names = [x["name"] for x in m["per_layer"]]
-    first = names.index(RETENTION[0] + ".retention")
-    assert names[first:first + len(RETENTION)] == \
-        [n + ".retention" for n in RETENTION]
-    assert first > names.index("diffusion_commit_pass_pct.blockgen")
-    rehearsed = {x["name"] for x in
-                 harness.load_manifest(MANIFEST)["per_layer"]}
-    for x in m["per_layer"][first:first + len(RETENTION)]:
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
-        assert x["moves"] == "serve_tokens_per_s"
-        assert x["workloads"] == [CELL] and x["name"] in rehearsed
-        assert x["source"] in ("device_trace", "host_clock")
-        assert set(x) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        if "roofline" in x["name"]:
-            assert x["unit"] == "%" and x["better"] == "higher"
-    # the cell behind the SDAR cell wherever both are listed; NOT among
-    # the cells that report a share of blocks (it has none)
-    listed = [x for x in m["end_to_end"] + m["per_layer"]
-              if CELL in x.get("workloads", []) and x["workloads"] != [CELL]]
-    assert [x["name"] for x in listed] == [
-        "serve_tokens_per_s", "setup_compile_s"] + [
-        n + ".backlogs" for n in ACCOUNT]
-    for x in listed:
-        assert x["workloads"][-1] == CELL
-    kv = next(x for x in m["per_layer"] if x["name"] == "kv_used_peak_pct")
-    assert CELL not in kv["workloads"]
+    mc.needs(m, CELL, mc.BACKLOG_CELL)
+    mc.needs(m, CELL, FOLDED, mirrored_in=MANIFEST)
+    mc.needs(m, CELL, OWN, mirrored_in=MANIFEST,
+             sources=("device_trace", "host_clock"))
+    mc.stand_together(m, list(OWN))
 
 
-def test_the_pins_still_see_the_file_as_of_their_cells():
-    """``tests/conftest.py``: what this PR appended is left out of the
-    views the older pins are shown, and nothing else is."""
-    sys.path.insert(0, os.path.dirname(HERE))
-    from conftest import as_of
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    assert as_of(m, CELL) == m
-    old = as_of(m, BEFORE)
-    assert [w["name"] for w in old["workloads"]] == \
-        [w["name"] for w in m["workloads"]][:-1]
-    assert [c["name"] for c in old["configs"]] == \
-        [c["name"] for c in m["configs"]][:-1]
-    gone = {x["name"] for x in m["per_layer"]} \
-        - {x["name"] for x in old["per_layer"]}
-    assert gone == {n + ".retention" for n in RETENTION}
-    for kind in ("end_to_end", "per_layer"):
-        for x in old[kind]:
-            assert CELL not in x.get("workloads", [])
+def test_benchmark_json_names_what_the_retention_cell_needs():
+    """By name, not by place: a later PR appends behind these."""
+    the_retention_cell(mc.real())
+
+
+@mc.cell_needs
+def the_retention_cell_lists_nothing_its_program_lacks(m):
+    """An engine without an arena and a model without experts or latent
+    rows: no share of blocks, no arena time, no expert or latent
+    reader lists the cell — a folded entry is lent to the cells whose
+    program has the scope or counter, not to all."""
+    for name in ("kv_used_peak_pct", "step_kv_arena_ms.backlogs",
+                 "step_moe_experts_ms.backlogs",
+                 "step_moe_shared_ms.backlogs", "step_moe_route_ms.backlogs",
+                 "moe_local_imbalance.backlogs",
+                 "moe_experts_roofline_pct.backlogs",
+                 "mla_decode_roofline_pct.backlogs"):
+        assert not mc.lists(m, name, CELL), name
+
+
+def test_the_retention_cell_is_listed_by_nothing_its_program_lacks():
+    the_retention_cell_lists_nothing_its_program_lacks(mc.real())
 
 
 def test_published_widths_are_in_the_brumby_configuration():
@@ -311,9 +284,9 @@ def test_flops_brumby_against_a_hand_count_at_the_published_widths():
     run = types.SimpleNamespace(config=c, peaks=peaks, trace=None,
                                 cell={"name": "none"}, records={})
     m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    for name in RETENTION:
-        assert harness.find_reader(ROOT, m, name + ".retention") \
-            .read(run) is None, name
+    mc.silent_without_a_device(
+        m, [*FOLDED, *OWN, "step_decode_ms.backlogs",
+            "step_prefill_ms.backlogs"], c)
 
 
 def test_copies_of_the_state_leaf_and_the_live_slots(monkeypatch):

@@ -26,24 +26,39 @@ from benchmark import longctx  # noqa: E402
 from benchmark.peaks import peaks_for  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import manifest_checks as mc  # noqa: E402
 import test_iteration_account as acc  # noqa: E402
 import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_sala.json")
 CELL = "minicpm-sala-pp2.longdoc-32k-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-COUNTED = {"sparse_chosen_share_pct.longctx", "engine_iter_ms.longctx",
-           "engine_host_ms.longctx", "setup_compile_s", "kv_used_peak_pct"}
-LONGCTX = [
-    "step_decode_ms", "step_prefill_ms", "step_sparse_select_ms",
-    "step_sparse_attn_ms", "step_linear_scan_ms", "step_linear_update_ms",
-    "sparse_attn_roofline_pct", "linear_scan_roofline_pct",
-    "linear_update_roofline_pct", "sparse_chosen_share_pct",
-    "step_state_copies_ms", "step_kv_arena_ms", "step_sample_ms",
-    "engine_host_ms", "engine_iter_ms"]
+COUNTED = {"sparse_chosen_share_pct.longctx", "engine_iter_ms.backlogs",
+           "engine_host_ms.backlogs", "setup_compile_s", "kv_used_peak_pct"}
+SPARSE = "block-sparse attention (nn/parallel.py, ops/sparse_select.py)"
+LIGHTNING = "lightning attention (nn/parallel.py, ops/linear_attention.py)"
+#: the cell's own entries, which stand together in this order ...
+OWN = {
+    "step_sparse_select_ms.longctx": ("ms", SPARSE, mc.TOKENS),
+    "step_sparse_attn_ms.longctx": ("ms", SPARSE, mc.TOKENS),
+    "step_linear_scan_ms.longctx": ("ms", LIGHTNING, mc.TOKENS),
+    "step_linear_update_ms.longctx": ("ms", LIGHTNING, mc.TOKENS),
+    "sparse_attn_roofline_pct.longctx": ("%", mc.KERNELS, mc.TOKENS),
+    "linear_scan_roofline_pct.longctx": ("%", mc.KERNELS, mc.TOKENS),
+    "linear_update_roofline_pct.longctx": ("%", mc.KERNELS, mc.TOKENS),
+    "sparse_chosen_share_pct.longctx": ("%", SPARSE, mc.TOKENS),
+    "step_state_copies_ms.longctx": ("ms", LIGHTNING, mc.TOKENS)}
+#: ... and, beside what every backlog cell needs, the folded entries
+#: its program feeds
+FOLDED = {
+    **mc.KV_PEAK, **mc.ENGINE_ITER,
+    **mc.of(["step_sample_ms", "engine_host_ms"], ".backlogs", "ms",
+            mc.STEP),
+    "step_kv_arena_ms.backlogs": ("ms", mc.KV, mc.TOKENS)}
 #: read without a device plane: a counter, the window's iterations, the
 #: host's spans in the slice
-NO_DEVICE = {"sparse_chosen_share_pct", "engine_iter_ms", "engine_host_ms"}
+NO_DEVICE = {"sparse_chosen_share_pct.longctx", "engine_iter_ms.backlogs",
+             "engine_host_ms.backlogs"}
 
 
 def _config():
@@ -183,24 +198,29 @@ PICKED = {3: [1, 0], 7: [1, 5], 9: [1, 5]}
 
 @pytest.mark.parametrize("n_finished", [3, 7, 9])
 def test_two_compared_requests_whatever_finished(n_finished):
-    """The cell's own mix (``reference_requests: 2``,
-    ``reference_longest: 0``): a program that finishes more requests in
-    its window is compared on as many as a slower one, and which two is
-    the seed's draw — among equal lengths "the 2 longest" are the first
-    two offered on every seed (the runner's default, for mixes whose
-    lengths differ), and the cell must not compare those alone."""
+    """The cell's own mix (``reference_requests`` 1 since PR 58, 2
+    before; ``reference_longest: 0``): a program that finishes more
+    requests in its window is compared on as many as a slower one, and
+    which is the seed's draw — among equal lengths "the longest" are
+    the first offered on every seed (the runner's default, for mixes
+    whose lengths differ), and the cell must not compare those alone.
+    The draw is a prefix: the one compared now is the first of the two
+    compared before."""
     from benchmark.runners import serve_arch
     mix = _cell_mix()
     n, longest = mix["reference_requests"], mix["reference_longest"]
+    assert n == 1
     finished = _finished([mix["prompt_len"]["value"]] * n_finished)
     seed = 2**31 + 39
     assert serve_arch.pick_reference(
-        finished, seed, n, serve_arch.LONGEST) == [0, 1]
+        finished, seed, 2, serve_arch.LONGEST) == [0, 1]
+    assert serve_arch.pick_reference(finished, seed, 2, longest) \
+        == PICKED[n_finished]
     drawn = serve_arch.pick_reference(finished, seed, n, longest)
-    assert drawn == PICKED[n_finished]
+    assert drawn == PICKED[n_finished][:n]
     assert drawn == serve_arch.pick_reference(finished, seed, n, longest)
     # over the seeds every finished request is compared
-    seen = {i for s in range(40) for i in serve_arch.pick_reference(
+    seen = {i for s in range(80) for i in serve_arch.pick_reference(
         finished, seed + s, n, longest)}
     assert seen == set(range(n_finished))
 
@@ -259,91 +279,58 @@ def test_a_run_says_where_its_wall_went(two):
     assert abs(info["run_wall_s"] - sum(info[k] for k in WALL)) < 1.0
 
 
-def test_manifest_names_what_the_longctx_cell_needs():
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cell = m["workloads"][-1]
-    assert cell["name"] == CELL and cell["chips"] == 1
-    assert cell["config"] == m["configs"][-1]["name"] == "minicpm-sala-pp2"
-    assert m["configs"][-1]["reduced"] == ["num_hidden_layers",
-                                           "mixer_types"]
-    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
-    with open(os.path.join(ROOT, "benchmark/traffic",
-                           f"{cell['traffic']}.json")) as f:
-        mix = json.load(f)
+@mc.cell_needs
+def the_longctx_cell(m):
+    cell, _ = mc.cell_of(m, CELL, config="minicpm-sala-pp2",
+                         traffic="longdoc-fixed-32k-backlog",
+                         reduced=["num_hidden_layers", "mixer_types"])
+    mix = mc.traffic_of(cell)
     assert mix["kind"] == "serve_arch_ties" and mix["schedule_seed"] == 39
     assert mix["arrivals"] == {"process": "backlog", "count": 160}
     assert (mix["ramp_s"], mix["drain_s"]) == (120, 0)
-    # the comparison's count is the file's, and both are the seed's
-    # draw: every request has the same lengths, none is the longest
-    assert mix["reference_requests"] == 2
+    # the comparison's count is the file's, and the one compared is the
+    # seed's draw: every request has the same lengths, none is the
+    # longest (ONE since PR 58: 256 positions hold the rule on a share
+    # as 512 did, and the cold run had 8 s to the driver's limit)
+    assert mix["reference_requests"] == 1
     assert mix["reference_longest"] == 0
     assert (mix["prompt_len"]["dist"], mix["prompt_len"]["value"]) == \
         ("fixed", 32000)
     assert (mix["output_len"]["dist"], mix["output_len"]["value"],
             mix["output_len"]["max"]) == ("fixed", 256, 256)
-    mine = m["per_layer"][-len(LONGCTX):]
-    assert [x["name"] for x in mine] == [n + ".longctx" for n in LONGCTX]
-    rehearsed = {x["name"] for x in
-                 harness.load_manifest(MANIFEST)["per_layer"]}
-    for x in mine:
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
-        assert x["moves"] == "serve_tokens_per_s"
-        assert x["workloads"] == [CELL] and x["name"] in rehearsed
-        assert x["source"] in ("device_trace", "host_clock")
-        if "roofline" in x["name"]:
-            assert x["unit"] == "%" and x["better"] == "higher"
-    for name in ("serve_tokens_per_s", "setup_compile_s",
-                 "kv_used_peak_pct"):
-        entry = next(x for x in m["end_to_end"] + m["per_layer"]
-                     if x["name"] == name)
-        assert entry["workloads"][-1] == CELL
+    mc.needs(m, CELL, mc.BACKLOG_CELL)
+    mc.needs(m, CELL, FOLDED, mirrored_in=MANIFEST)
+    mc.needs(m, CELL, OWN, mirrored_in=MANIFEST,
+             sources=("device_trace", "host_clock"))
+    mc.stand_together(m, list(OWN))
 
 
-def test_the_cells_before_keep_their_places_in_the_manifest():
-    """The file's REAL order (``tests/conftest.py`` shows two older
-    tests another): new entries stand at the END of their lists — the
-    Kimi cell right before this cell wherever both are listed, PR 35's
-    fourteen entries together right before this PR's — and every
-    ``.backlogs`` account metric lists ALL ``serve_tokens_per_s`` cells,
-    this one included. ``later_entries_first`` only reorders."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    kimi = "kimi-vl-a3b-pp4.longdoc-backlog"
+def test_benchmark_json_names_what_the_longctx_cell_needs():
+    the_longctx_cell(mc.real())
+
+
+@mc.cell_needs
+def the_two_accounts_of_every_serving_cell(m):
+    """PR 35's fourteen entries stand together, and so do PR 53's
+    eleven; each ``.chat`` entry lists the cells that report
+    ``gap_p95_ms``, each ``.backlogs`` entry EVERY cell that reports
+    ``serve_tokens_per_s``, in that metric's order — a cell appended
+    there is appended here."""
+    import test_process_account as proc
     e2e = {x["name"]: x for x in m["end_to_end"]}
-    backlogs = e2e["serve_tokens_per_s"]["workloads"]
-    assert backlogs[-2:] == [kimi, CELL]
-    listed = [x for x in m["end_to_end"] + m["per_layer"]
-              if CELL in x.get("workloads", []) and x["workloads"] != [CELL]]
-    assert [x["name"] for x in listed] == [
-        "serve_tokens_per_s", "kv_used_peak_pct", "setup_compile_s"] + [
-        n for n in acc.ALL if n.endswith(".backlogs")]
-    for x in listed:
-        assert x["workloads"][-2:] == [kimi, CELL]
-    before = m["per_layer"][-len(LONGCTX) - len(acc.ALL):-len(LONGCTX)]
-    assert [x["name"] for x in before] == acc.ALL
-    for x in before:
-        assert x["workloads"] == (
-            e2e["gap_p95_ms"]["workloads"] if x["name"].endswith(".chat")
-            else backlogs)
-    assert [w["name"] for w in m["workloads"][:-1]] == [
-        "gpt2-small.pretrain", "gpt2-small.chat", "gpt2-large.backlog",
-        "command-a-plus-ep8.mixed-backlog", kimi]
-    sys.path.insert(0, os.path.dirname(HERE))
-    from conftest import later_entries_first
-    shown = later_entries_first(m)
+    for names in (acc.ALL, proc.ALL):
+        mc.stand_together(m, names)
+        for name in names:
+            x = mc.entry(m, name)
+            if name.endswith(".chat"):
+                assert x["workloads"] == e2e["gap_p95_ms"]["workloads"]
+            elif name.endswith(".backlogs"):
+                assert x["workloads"] == e2e[mc.TOKENS]["workloads"]
+    assert CELL in e2e[mc.TOKENS]["workloads"]
 
-    def canon(x):
-        return json.dumps(dict(x, workloads=sorted(x.get("workloads", []))),
-                          sort_keys=True)
-    for kind in ("end_to_end", "per_layer"):
-        assert sorted(map(canon, shown[kind])) == \
-            sorted(map(canon, m[kind]))
-    assert [x["name"] for x in shown["per_layer"][-len(acc.ALL):]] \
-        == acc.ALL
-    assert {k: v for k, v in shown.items() if k not in (
-        "end_to_end", "per_layer")} == {k: v for k, v in m.items() if k
-                                        not in ("end_to_end", "per_layer")}
+
+def test_the_accounts_list_every_cell_that_reports_their_metric():
+    the_two_accounts_of_every_serving_cell(mc.real())
 
 
 def test_published_widths_are_in_the_sala_configuration():
@@ -418,14 +405,12 @@ def test_flops_minicpm_sala_arithmetic():
     assert scan["flops"] == 2048 * 32 * 4 * 128 * 128
     upd = fs.linear_update_call(c, 16)
     assert upd["bytes"] == 2 * 16 * 2097152
-    run = types.SimpleNamespace(config=c, peaks=peaks, trace=None,
-                                cell={"name": "none"}, records={})
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
     # readers of device scopes return nothing without a device plane
-    for name in LONGCTX:
-        if name not in NO_DEVICE:
-            assert harness.find_reader(ROOT, m, name + ".longctx") \
-                .read(run) is None, name
+    mc.silent_without_a_device(
+        mc.real(),
+        [n for n in {**FOLDED, **OWN}
+         if n not in NO_DEVICE | set(mc.KV_PEAK)]
+        + ["step_decode_ms.backlogs", "step_prefill_ms.backlogs"], c)
 
 
 def test_longctx_counts_and_path_seconds(monkeypatch):

@@ -29,26 +29,26 @@ from benchmark.peaks import peaks_for  # noqa: E402
 from benchmark.runners import serve_arch  # noqa: E402
 
 sys.path.insert(0, HERE)
+import manifest_checks as mc  # noqa: E402
 import tiny_run  # noqa: E402
 
 MANIFEST = os.path.join(HERE, "manifest_ssm.json")
 CELL = "jamba2-3b.doc-32k-backlog"
-BEFORE = "brumby-14b-pp4.repo-16k-backlog"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
-SSM = [
-    "step_ssm_conv_ms", "step_ssm_scan_ms", "step_ssm_update_ms",
-    "step_state_copies_ms", "step_mqa_attn_ms", "ssm_scan_roofline_pct",
-    "ssm_update_roofline_pct"]
-#: the iteration and its lanes: the accepted readers of the Brumby cell
-#: (``program_trace.device_ms_per_step`` / ``readers.engine_iter_ms``:
-#: nothing of them is that cell's), which list this cell too
-GENERIC = ["step_prefill_ms", "step_decode_ms", "step_sample_ms",
-           "engine_iter_ms"]
-ACCOUNT = ["engine_host_cpu_ms", "engine_host_offcpu_ms",
-           "host_dispatch_ms", "wire_cpu_ms", "step_launch_lag_ms",
-           "step_fetch_lag_ms"]
-PROCESS = ["window_compile_s", "host_other_cpu_ms", "gc_pause_ms",
-           "process_threads_peak", "idle_host_phases_ms"]
+SCAN = "selective scan (nn/parallel.py, ops/selective_scan_pallas.py)"
+#: the cell's own entries, which stand together in this order (since
+#: PR 58 in ``BENCHMARK.json`` itself: the list has room again) ...
+OWN = {
+    **mc.of(["step_ssm_conv_ms", "step_ssm_scan_ms", "step_ssm_update_ms",
+             "step_state_copies_ms"], ".ssm", "ms", SCAN),
+    "step_mqa_attn_ms.ssm": ("ms", mc.KERNELS, mc.TOKENS),
+    **mc.of(["ssm_scan_roofline_pct", "ssm_update_roofline_pct"], ".ssm",
+            "%", mc.KERNELS)}
+#: ... and, beside what every backlog cell needs, the folded entries
+#: its program feeds: the iteration and the sampler (generic readers:
+#: ``program_trace.device_ms_per_step`` / ``readers.engine_iter_ms``)
+FOLDED = {**mc.KV_PEAK, **mc.ENGINE_ITER,
+          **mc.of(["step_sample_ms"], ".backlogs", "ms", mc.STEP)}
 
 
 def _config():
@@ -96,10 +96,10 @@ def test_ssm_cell_end_to_end_at_tiny_size(trace):
         # no device plane on the CPU: the metrics that read device
         # scopes are left out, the counted ones are there
         assert set(line["metrics"]) >= {
-            "setup_compile_s", "engine_iter_ms.retention",
+            "setup_compile_s", "engine_iter_ms.backlogs",
             "kv_used_peak_pct"}
         assert not any("roofline" in k or k.startswith("step_")
-                       and k.endswith(".ssm") for k in line["metrics"])
+                       and "lag" not in k for k in line["metrics"])
         assert 0 < line["metrics"]["kv_used_peak_pct"]["value"] <= 100
         assert line["device"]["busy_s"] == 0.0
     json.dumps(line)
@@ -144,48 +144,24 @@ def test_a_planted_control_is_refused_through_the_harness(control):
 
 
 def test_every_ssm_reader_is_the_manifests_and_reads_nothing_off_chip():
-    m = harness.load_manifest(MANIFEST)
-    entries = {x["name"]: x for x in m["per_layer"]}
-    run = types.SimpleNamespace(config=_config(),
-                                peaks=peaks_for("TPU v5 lite"), trace=None,
-                                cell={"name": "none"}, records={})
-    for name in SSM + GENERIC:
-        x = entries[name + (".ssm" if name in SSM else ".retention")]
-        mod = harness.find_reader(ROOT, m, x["name"])
-        assert (mod.NAME, mod.UNIT, mod.LAYER, mod.MOVES) == \
-            (x["name"], x["unit"], x["layer"], x["moves"])
-        assert x["moves"] == "serve_tokens_per_s"
-        assert set(x) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
+    m = mc.real()
+    for name in OWN:
+        mod = harness.find_reader(ROOT, m, name)
         if "roofline" in name:
-            assert x["unit"] == "%" and x["better"] == "higher"
             assert "no VECTOR peak" in mod.__doc__ or "update" in name
-        if name != "engine_iter_ms":
-            assert mod.read(run) is None, name
+    mc.silent_without_a_device(
+        m, [*OWN, "step_sample_ms.backlogs", "step_decode_ms.backlogs",
+            "step_prefill_ms.backlogs"], _config())
 
 
-def test_manifest_names_what_the_ssm_cell_needs():
-    """By name, not by place: a later PR appends behind these. The
-    manifest holds 128 per-layer entries of the 128 it may: the seven
-    ``.ssm`` readers are files the rehearsal's manifest lists and
-    ``BENCHMARK.json`` cannot yet (PERF.md section 7, PR 55); the
-    iteration and its lanes are read by the accepted ``.retention``
-    entries, which list the cell."""
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = [w["name"] for w in m["workloads"]]
-    cell = m["workloads"][cells.index(CELL)]
-    assert cells.index(CELL) == cells.index(BEFORE) + 1
-    assert cell["chips"] == 1 and cell["config"] == "jamba2-3b"
-    assert cell["traffic"] == "doc-fixed-32k-backlog"
-    assert len(cell["why"]) <= 200
-    config = next(c for c in m["configs"] if c["name"] == cell["config"])
-    assert config["reduced"] == [] and len(config["why"]) <= 200
+@mc.cell_needs
+def the_ssm_cell(m):
+    cell, config = mc.cell_of(m, CELL, config="jamba2-3b",
+                              traffic="doc-fixed-32k-backlog", reduced=[])
+    assert len(config["why"]) <= 200
     assert config["file"] == "benchmark/configs/jamba2-3b.json"
     assert config["source"] == _config()["source"]
-    assert sum(w["chips"] == 4 for w in m["workloads"]) == 0
-    with open(os.path.join(ROOT, "benchmark/traffic",
-                           f"{cell['traffic']}.json")) as f:
-        mix = json.load(f)
+    mix = mc.traffic_of(cell)
     assert mix["kind"] == "serve_arch_ssm" and mix["schedule_seed"] == 55
     assert mix["arrivals"] == {"process": "backlog", "count": 240}
     assert mix["drain_s"] == 0 and mix["ramp_s"] in (40, 50)
@@ -201,46 +177,31 @@ def test_manifest_names_what_the_ssm_cell_needs():
     assert serve["max_len"] >= 32768 + 256
     assert serve["kv_blocks"] >= serve["slots"] * (
         serve["max_len"] // serve["block_size"])
-    assert len(m["per_layer"]) == 128        # no place left for `.ssm`
-    assert not any(x["name"].endswith(".ssm") for x in m["per_layer"])
-    # the cell LAST wherever it is listed, behind the Brumby cell where
-    # that is listed too
-    listed = [x for x in m["end_to_end"] + m["per_layer"]
-              if CELL in x.get("workloads", [])]
-    assert [x["name"] for x in listed] == [
-        "serve_tokens_per_s", "kv_used_peak_pct", "setup_compile_s"] + [
-        n + ".backlogs" for n in ACCOUNT] + [
-        n + ".retention" for n in GENERIC] + ["setup_cold_compile_s"] + [
-        n + ".backlogs" for n in PROCESS]
-    for x in listed:
-        assert x["workloads"][-1] == CELL
-        if x["name"] != "kv_used_peak_pct":
-            assert x["workloads"][-2] == BEFORE
-    rehearsed = {x["name"] for x in
-                 harness.load_manifest(MANIFEST)["per_layer"]}
-    assert {n + ".ssm" for n in SSM} \
-        | {n + ".retention" for n in GENERIC} <= rehearsed
+    mc.needs(m, CELL, mc.BACKLOG_CELL)
+    mc.needs(m, CELL, FOLDED, mirrored_in=MANIFEST)
+    mc.needs(m, CELL, OWN, mirrored_in=MANIFEST, sources=("device_trace",))
+    mc.stand_together(m, list(OWN))
 
 
-def test_the_pins_still_see_the_file_as_of_their_cells():
-    """``tests/conftest.py``: what this PR appended is left out of the
-    views the older pins are shown, and nothing else is."""
-    sys.path.insert(0, os.path.dirname(HERE))
-    from conftest import AS_OF_BRUMBY_PINS, as_of
-    m = harness.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
-    assert as_of(m, CELL) == m
-    old = as_of(m, BEFORE)
-    assert set(AS_OF_BRUMBY_PINS.values()) == {BEFORE}
-    assert [w["name"] for w in old["workloads"]] == \
-        [w["name"] for w in m["workloads"]][:-1]
-    assert [c["name"] for c in old["configs"]] == \
-        [c["name"] for c in m["configs"]][:-1]
-    # this PR added no metric: the same entries, without the cell
-    assert [x["name"] for x in old["per_layer"]] == \
-        [x["name"] for x in m["per_layer"]]
-    for kind in ("end_to_end", "per_layer"):
-        for x in old[kind]:
-            assert CELL not in x.get("workloads", [])
+def test_benchmark_json_names_what_the_ssm_cell_needs():
+    """By name, not by place: a later PR appends behind these. The
+    seven ``.ssm`` readers are entries of ``BENCHMARK.json`` since PR
+    58 (PR 55 had to leave them as files: the list was full)."""
+    the_ssm_cell(mc.real())
+
+
+def test_the_rehearsals_manifest_is_the_real_files_subset():
+    """What ``manifest_ssm.json`` rehearses on the CPU is what
+    ``BENCHMARK.json`` reads on the chip: every per-layer entry there
+    is an entry here, to the letter but for the cell it lists."""
+    m = mc.real()
+    mine = harness.load_manifest(MANIFEST)
+    assert {x["name"] for x in mine["per_layer"]} >= set(OWN) | set(FOLDED)
+    for x in mine["per_layer"]:
+        assert x["workloads"] == ["tiny.doc"]
+        assert dict(mc.entry(m, x["name"]), workloads=None) == \
+            dict(x, workloads=None)
+        assert mc.lists(m, x["name"], CELL)
 
 
 def test_published_widths_are_in_the_jamba_configuration():

@@ -1,10 +1,11 @@
 """Model zoo: GPT-2, Llama, Command A+ (cohere2_moe) and latent-attention
 (MLA) expert decoders, MiniCPM-SALA, delta-rule / latent hybrids,
 SDAR-MoE, which generates by diffusion over blocks, Brumby, whose
-every layer is power retention, and Jamba, whose layers are Mamba
-selective scans beside a few attention layers (``mla_moe``,
-``minicpm_sala``, ``kda_mla_moe``, ``sdar_moe``, ``brumby``,
-``jamba``: loaded on first use,
+every layer is power retention, Jamba, whose layers are Mamba
+selective scans beside a few attention layers, and Qwen3-Next, Gated
+DeltaNet layers beside gated attention over a wide softmax router
+(``mla_moe``, ``minicpm_sala``, ``kda_mla_moe``, ``sdar_moe``,
+``brumby``, ``jamba``, ``qwen3_next``: loaded on first use,
 so that the cells that never build one do not pay for its import).
 
 The four drawn decoders share one shell (``decoder.DecoderLM``); the
@@ -40,7 +41,9 @@ _LAZY = {"MLAMoEConfig": "hetu_tpu.models.mla_moe",
          "BrumbyConfig": "hetu_tpu.models.brumby",
          "BrumbyForCausalLM": "hetu_tpu.models.brumby",
          "JambaConfig": "hetu_tpu.models.jamba",
-         "JambaForCausalLM": "hetu_tpu.models.jamba"}
+         "JambaForCausalLM": "hetu_tpu.models.jamba",
+         "Qwen3NextConfig": "hetu_tpu.models.qwen3_next",
+         "Qwen3NextForCausalLM": "hetu_tpu.models.qwen3_next"}
 
 
 def __getattr__(name):
@@ -58,4 +61,5 @@ __all__ = ["GPTConfig", "GPTLMHeadModel", "LlamaConfig", "BertConfig", "BertMode
            "SDARMoEConfig", "SDARMoEForCausalLM",
            "BrumbyConfig", "BrumbyForCausalLM",
            "JambaConfig", "JambaForCausalLM",
+           "Qwen3NextConfig", "Qwen3NextForCausalLM",
            "generate", "decode", "init_kv_caches"]

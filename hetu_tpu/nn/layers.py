@@ -84,15 +84,27 @@ class LayerNorm(Module):
 
 
 class RMSNorm(Module):
+    """``x / sqrt(mean x^2 + eps) * scale``; ``zero_centered``: the gain
+    is ``1 + scale`` (the parameter is the gain's distance from one,
+    drawn ``normal(0, zero_centered)`` so that a program that reads it
+    as the gain itself must differ)."""
+
     def __init__(self, features: int, eps: float = 1e-6,
-                 axes: Sequence[Optional[str]] = (None,)):
+                 axes: Sequence[Optional[str]] = (None,),
+                 zero_centered: float = 0.0):
         super().__init__()
         self.features = features
         self.eps = eps
-        self.param("scale", (features,), ones_init(), axes=axes)
+        self.zero_centered = bool(zero_centered)
+        self.param("scale", (features,),
+                   normal_init(zero_centered) if zero_centered
+                   else ones_init(), axes=axes)
 
     def __call__(self, params, x):
-        return norm_ops.rms_norm(x, params["scale"], eps=self.eps).astype(
+        scale = params["scale"]
+        if self.zero_centered:
+            scale = 1.0 + scale.astype(jnp.float32)
+        return norm_ops.rms_norm(x, scale, eps=self.eps).astype(
             self.compute_dtype())
 
 

@@ -21,6 +21,7 @@ exactly like the reference's per-block recompute config
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Callable, NamedTuple, Optional, Sequence
@@ -503,17 +504,29 @@ class ParallelAttention(Module):
                  min_window: Optional[int] = None,
                  max_positions: int = 4096, qk_norm: bool = False,
                  qk_gain: float = 1.0, norm_eps: float = 1e-6,
-                 attn_block: int = 1, init=None):
+                 attn_block: int = 1, rotary_dim: Optional[int] = None,
+                 out_gate: bool = False, zero_centered: bool = False,
+                 init=None):
         super().__init__()
         # RMSNorm with a learned gain over each head's numbers, on q
         # and on k, before RoPE (the gains are the heads' own, drawn at
-        # ``qk_gain``: 1 where a checkpoint's are learned)
+        # ``qk_gain``: 1 where a checkpoint's are learned).
+        # ``zero_centered``: the parameter is the gain's distance from
+        # one, the gain ``1 + w``
         self.qk_norm, self.norm_eps = bool(qk_norm), norm_eps
+        self._gain_at = 1.0 if zero_centered else 0.0
         if qk_norm:
             from hetu_tpu.nn.module import constant_init
             hd = head_dim or embed_dim // num_heads
-            self.param("q_gain", (hd,), constant_init(qk_gain))
-            self.param("k_gain", (hd,), constant_init(qk_gain))
+            self.param("q_gain", (hd,),
+                       constant_init(qk_gain - self._gain_at))
+            self.param("k_gain", (hd,),
+                       constant_init(qk_gain - self._gain_at))
+        #: a sigmoid gate on the attention's output, a number a head
+        #: and channel, from the SAME projection as the queries:
+        #: ``q_proj`` is twice as wide, a head's ``head_dim`` of q then
+        #: its ``head_dim`` of gate
+        self.out_gate = bool(out_gate)
         #: the BLOCK bound of a block-diffusion model (1: causal): a
         #: query sees its own block of ``attn_block`` positions whole
         #: and the blocks before it (``ops.attention.block_bound``)
@@ -534,8 +547,8 @@ class ParallelAttention(Module):
         self.use_rope = use_rope
         init = init or normal_init(0.02)
         self.q_proj = ColumnParallelLinear(
-            embed_dim, num_heads * self.head_dim, bias=bias, init=init,
-            axis="heads", out_kind="hidden")
+            embed_dim, num_heads * self.head_dim * (2 if out_gate else 1),
+            bias=bias, init=init, axis="heads", out_kind="hidden")
         self.k_proj = ColumnParallelLinear(
             embed_dim, self.num_kv_heads * self.head_dim, bias=bias,
             init=init, axis="kv_heads", out_kind="hidden")
@@ -545,11 +558,35 @@ class ParallelAttention(Module):
         self.out_proj = RowParallelLinear(
             num_heads * self.head_dim, embed_dim, bias=bias, init=init,
             axis="heads")
+        #: numbers of a head that rotate (``None``: all): the FIRST
+        #: ``rotary_dim``, as one head of that size; the rest pass
+        self.rotary_dim = rotary_dim
+        if rotary_dim is not None and not (
+                use_rope and 0 < rotary_dim <= self.head_dim
+                and rotary_dim % 2 == 0):
+            raise ValueError(f"rotary_dim {rotary_dim} of a head of "
+                             f"{self.head_dim} (use_rope={use_rope})")
         if use_rope:
-            self._rope = rope_frequencies(self.head_dim, max_positions,
-                                          theta=rope_theta)
+            self._rope = rope_frequencies(rotary_dim or self.head_dim,
+                                          max_positions, theta=rope_theta)
         else:
             self._rope = None
+
+    def _heads_q(self, y, lead: tuple):
+        """``q_proj``'s result -> ``(q lead + (heads, head_dim), gate
+        lead + (heads * head_dim,) or None)``."""
+        if not self.out_gate:
+            return y.reshape(lead + (self.num_heads, self.head_dim)), None
+        y = y.reshape(lead + (self.num_heads, 2, self.head_dim))
+        return y[..., 0, :], y[..., 1, :].reshape(lead + (-1,))
+
+    def _gated(self, out, gate):
+        """The attention's output ``lead + (heads * head_dim,)`` under
+        its gate (float32 inside), where the module has one."""
+        if gate is None:
+            return out
+        return (out.astype(jnp.float32) * jax.nn.sigmoid(
+            gate.astype(jnp.float32))).astype(out.dtype)
 
     @property
     def _bound(self) -> dict:
@@ -585,15 +622,24 @@ class ParallelAttention(Module):
         norms q and k (``qk_norm``; ``params`` hold the gains), before
         it does."""
         if self.qk_norm:
-            q = _gain(params, "q_gain", q, self.norm_eps, q.dtype)
-            k = _gain(params, "k_gain", k, self.norm_eps, k.dtype)
+            q = _gain(params, "q_gain", q, self.norm_eps, q.dtype,
+                      self._gain_at)
+            k = _gain(params, "k_gain", k, self.norm_eps, k.dtype,
+                      self._gain_at)
         if self._rope is None:
             return q, k
         cos, sin = self._rope
-        qr = apply_rotary(q, cos, sin, positions=positions,
-                          interleaved=self.rope_interleaved)
-        kr = apply_rotary(k, cos, sin, positions=positions,
-                          interleaved=self.rope_interleaved)
+        rd = self.rotary_dim
+
+        def rotate(x):
+            if rd is None or rd == self.head_dim:
+                return apply_rotary(x, cos, sin, positions=positions,
+                                    interleaved=self.rope_interleaved)
+            return jnp.concatenate([
+                apply_rotary(x[..., :rd], cos, sin, positions=positions,
+                             interleaved=self.rope_interleaved),
+                x[..., rd:]], axis=-1)
+        qr, kr = rotate(q), rotate(k)
         if rope_on is None:
             return qr, kr
         return jnp.where(rope_on, qr, q), jnp.where(rope_on, kr, k)
@@ -631,8 +677,7 @@ class ParallelAttention(Module):
                                 lora=lora, window=window,
                                 rope_on=rope_on)
         b, s, _ = x.shape
-        q = self.q_proj(params["q_proj"], x).reshape(
-            b, s, self.num_heads, self.head_dim)
+        q, gate = self._heads_q(self.q_proj(params["q_proj"], x), (b, s))
         k = self.k_proj(params["k_proj"], x).reshape(
             b, s, self.num_kv_heads, self.head_dim)
         v = self.v_proj(params["v_proj"], x).reshape(
@@ -701,7 +746,8 @@ class ParallelAttention(Module):
                                   dropout_rate=dropout_rate,
                                   dropout_key=dropout_key)
         out = act_constrain(out, "heads")
-        out = out.reshape(b, s, self.num_heads * self.head_dim)
+        out = self._gated(
+            out.reshape(b, s, self.num_heads * self.head_dim), gate)
         out = self.out_proj(params["out_proj"], out)
         if return_kv:
             return out, (k, v)
@@ -798,9 +844,9 @@ class ParallelAttention(Module):
             index = positions[:, 0]                     # (b,) per-slot
         else:
             index = positions[0, 0] if positions is not None else 0
-        q = lora_apply(lora, "q_proj", x,
-                       self.q_proj(params["q_proj"], x)).reshape(
-            b, s, self.num_heads, self.head_dim)
+        q, gate = self._heads_q(
+            lora_apply(lora, "q_proj", x,
+                       self.q_proj(params["q_proj"], x)), (b, s))
         k = lora_apply(lora, "k_proj", x,
                        self.k_proj(params["k_proj"], x)).reshape(
             b, s, self.num_kv_heads, self.head_dim)
@@ -916,7 +962,8 @@ class ParallelAttention(Module):
                 q, k_buf, v_buf, causal=self.causal,
                 q_offset=index, kv_offset=0, window=window,
                 **self._bound)
-        out = out.reshape(b, s, self.num_heads * self.head_dim)
+        out = self._gated(
+            out.reshape(b, s, self.num_heads * self.head_dim), gate)
         return lora_apply(lora, "out_proj", out,
                           self.out_proj(params["out_proj"], out)), \
             new_cache
@@ -967,9 +1014,9 @@ class ParallelAttention(Module):
         quant = len(leaves) == 4
         b, C, _ = x.shape
         n_blk, blk = leaves[0].shape[1], leaves[0].shape[2]
-        q = lora_apply(lora, "q_proj", x,
-                       self.q_proj(params["q_proj"], x)).reshape(
-            b, C, self.num_heads, self.head_dim)
+        q, gate = self._heads_q(
+            lora_apply(lora, "q_proj", x,
+                       self.q_proj(params["q_proj"], x)), (b, C))
         k = lora_apply(lora, "k_proj", x,
                        self.k_proj(params["k_proj"], x)).reshape(
             b, C, self.num_kv_heads, self.head_dim)
@@ -1059,7 +1106,8 @@ class ParallelAttention(Module):
             hist = hist[:, 0][None]                  # (1, C, hq, d)
             lse_h = lse_h[:, :, 0].T[None]           # (C, hq, 1) → (1, hq, C)
         out = combine_attention_lse(intra, lse_i, hist, lse_h)
-        out = out.reshape(b, C, self.num_heads * self.head_dim)
+        out = self._gated(
+            out.reshape(b, C, self.num_heads * self.head_dim), gate)
         return lora_apply(lora, "out_proj", out,
                           self.out_proj(params["out_proj"], out)), \
             new_cache
@@ -1397,10 +1445,14 @@ class SlotStateNotSupported(NotImplementedError):
     cache."""
 
 
-def _gain(params, name, x, eps, dtype):
-    """RMSNorm over the last dim with the learned gain ``name``."""
+def _gain(params, name, x, eps, dtype, at: float = 0.0):
+    """RMSNorm over the last dim with the learned gain ``name`` (``at``
+    1: the parameter is the gain's distance from one)."""
     from hetu_tpu.ops.normalization import rms_norm
-    return rms_norm(x.astype(jnp.float32), params[name], eps).astype(dtype)
+    gain = params[name]
+    if at:
+        gain = at + gain.astype(jnp.float32)
+    return rms_norm(x.astype(jnp.float32), gain, eps).astype(dtype)
 
 
 def _cached_rows(x, positions, slot_mask, block_tables, row_mask, pack,
@@ -2084,17 +2136,19 @@ class LightningAttention(Module):
         return self._output(params, o, x), (buf,)
 
 
-def count_kda_steps(values, tokens=None) -> None:
-    """:class:`KimiDeltaAttention`'s ``layer_stats`` on the host:
-    ``values (KDA layers, 4)`` — of each layer's call ``[live,
-    computed, advanced, stepped]``: a prefill pack's scan reports the
-    grid steps that held a valid row and the grid steps run
-    (``ops.kda_pallas.hetu_kda_scan(return_steps=True)``) and zeros
-    behind them, the decode rows' update zeros and then the live slots
-    it advanced and the slot steps of its grid
+def count_kda_steps(values, tokens=None, *, mixer: str = "kda") -> None:
+    """A delta-rule mixer's ``layer_stats`` on the host
+    (:class:`KimiDeltaAttention`; ``mixer="gdn"``:
+    :class:`GatedDeltaNet`): ``values (its layers, 4)`` — of each
+    layer's call ``[live, computed, advanced, stepped]``: a prefill
+    pack's scan reports the grid steps that held a valid row and the
+    grid steps run (``ops.kda_pallas.hetu_kda_scan(return_steps=True)``)
+    and zeros behind them, the decode rows' update zeros and then the
+    live slots it advanced and the slot steps of its grid
     (``hetu_kda_update(return_steps=True)``) — into
-    ``kda_scan_steps_total{kind}`` and ``kda_update_slots_total{kind}``:
-    neither lane adds to the other's counter."""
+    ``<mixer>_scan_steps_total{kind}`` and
+    ``<mixer>_update_slots_total{kind}``: neither lane adds to the
+    other's counter."""
     import numpy as np
     from hetu_tpu import telemetry
     live, computed, advanced, stepped = np.asarray(
@@ -2102,7 +2156,7 @@ def count_kda_steps(values, tokens=None) -> None:
     reg = telemetry.get_registry()
     if computed:
         c = reg.counter(
-            "kda_scan_steps_total",
+            f"{mixer}_scan_steps_total",
             "grid steps of the delta-rule scan kernel: live = (piece, "
             "head block) steps that held a valid row, computed = steps "
             "run (a chunk without a valid row costs one that writes "
@@ -2111,7 +2165,7 @@ def count_kda_steps(values, tokens=None) -> None:
         c.inc(float(computed), kind="computed")
     if stepped:
         c = reg.counter(
-            "kda_update_slots_total",
+            f"{mixer}_update_slots_total",
             "slots of the delta-rule decode rows' update kernel: live = "
             "slots whose state it advanced by a token (read once, "
             "written once), stepped = slot steps of its grid (a step "
@@ -2121,76 +2175,52 @@ def count_kda_steps(values, tokens=None) -> None:
         c.inc(float(stepped), kind="stepped")
 
 
-class KimiDeltaAttention(Module):
-    """Kimi Delta Attention (``ops.kda``): a gated delta rule with a
-    decay per channel over a per-slot recurrent state, behind a short
-    causal convolution. With ``u`` the block's normed input::
+class DeltaRuleMixer(Module):
+    """What the gated-delta-rule mixers share (``ops.kda``): a short
+    causal convolution over the mixer's q, k and v channels, then the
+    rule over a per-slot recurrent state, a VALUE head at a time::
 
-        [q | k | v] = SiLU(conv(u [W_q | W_k | W_v]))    a channel at a time
-        q <- q / |q| * dk^-1/2,  k <- k / |k|            a head
-        g = lower_bound * sigmoid(exp(A_log_h) (u W_f + dt_bias))
-        beta = sigmoid(u W_b)                             one a head
         S_t = (I - beta k k^T) Diag(e^g) S_{t-1} + beta k v^T,  o = S_t^T q
-        out = W_o (RMSNorm_head(o) * sigmoid(u W_g))      one gate a head
+
+    A subclass gives the projections (:meth:`_inputs`: the
+    convolution's input, the log-decay ``g``, ``beta`` and what its
+    output gate takes), the activation and norms behind the convolution
+    (:meth:`_qkv`) and the output (:meth:`_output`), and names its
+    sizes — ``num_heads`` value heads of ``head_dim`` (``dk = dv``),
+    ``conv_channels`` — and its ``mixer`` (the device scopes
+    ``hetu.<mixer>_conv`` / ``_scan`` / ``_update``, the stat
+    ``<mixer>_steps``).
 
     What is cached is a SLOT's, in TWO leaves (:meth:`init_leaves`):
     the float32 state ``(layers, slots, H, dk, dv)`` and the
-    convolution's TAIL ``(layers, slots, taps - 1, 3 H dk)`` — the last
-    input rows of q, k and v before the activation. A run that starts
-    at position 0 starts from a zero state AND a zero tail, whatever its
-    slot held. The decode rows advance their slot by a token
-    (``hetu.kda_update``), a prefill pack's tokens theirs in chunks
-    (``hetu.kda_scan``), both behind ``hetu.kda_conv``; each addresses
-    the live slots of its own layer in the stacked leaves in place.
-    Both are ONE Pallas call a layer call on the state leaf where it
-    lies (``ops.kda_pallas``, interpreted on the CPU):
-    ``hetu_kda_update`` walks the live slots, each state read once and
-    written once; ``hetu_kda_scan`` walks the pack's pieces, a run's
-    state in VMEM across its chunks. ``ops.kda.kda_update`` and
-    ``ops.kda.kda_scan`` are their oracles and never run here. No page
-    is ever read or written. ``A_log``, ``dt_bias`` and the taps
-    are drawn, not constants (a program that leaves one out must
-    differ)."""
+    convolution's TAIL ``(layers, slots, taps - 1, conv_channels)`` —
+    the last input rows of q, k and v before the activation. A run that
+    starts at position 0 starts from a zero state AND a zero tail,
+    whatever its slot held. The decode rows advance their slot by a
+    token (``_update``), a prefill pack's tokens theirs in chunks
+    (``_scan``), both behind ``_conv``; each addresses the live slots of
+    its own layer in the stacked leaves in place. Both are ONE Pallas
+    call a layer call on the state leaf where it lies
+    (``ops.kda_pallas``, interpreted on the CPU): ``hetu_kda_update``
+    walks the live slots, each state read once and written once;
+    ``hetu_kda_scan`` walks the pack's pieces, a run's state in VMEM
+    across its chunks. ``ops.kda.kda_update`` and ``ops.kda.kda_scan``
+    are their oracles and never run here. No page is ever read or
+    written."""
 
     cache_leaves = 2
-    #: a cached call's third result (:func:`count_kda_steps`)
-    layer_stats = {"kda_steps": ((4,), jnp.int32, count_kda_steps)}
+    mixer = "kda"
+    #: (the subclass's ``__init__`` sets them)
+    num_heads: int
+    head_dim: int
+    conv_size: int
+    conv_channels: int
 
-    def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
-                 conv_size: int = 4, lower_bound: float = -5.0,
-                 norm_eps: float = 1e-6, init=None):
-        super().__init__()
-        from hetu_tpu.nn.module import ones_init
-        self.num_heads = self.num_kv_heads = num_heads
-        self.head_dim, self.conv_size = head_dim, conv_size
-        self.lower_bound, self.norm_eps = float(lower_bound), norm_eps
-        self.min_window = None
-        init = init or normal_init(0.02)
-        inner = num_heads * head_dim
-        self.qkv_proj = ColumnParallelLinear(
-            embed_dim, 3 * inner, bias=False, init=init, axis="heads",
-            out_kind="hidden")
-        self.decay_proj = ColumnParallelLinear(
-            embed_dim, inner, bias=False, init=init, axis="heads",
-            out_kind="hidden")
-        # [beta | gate]: one of each a head
-        self.head_proj = ColumnParallelLinear(
-            embed_dim, 2 * num_heads, bias=False, init=init, axis=None,
-            out_kind="hidden")
-        self.out_proj = RowParallelLinear(inner, embed_dim, bias=False,
-                                          init=init, axis="heads")
-        # (float32 whatever the weights are served in: they shape the
-        # decay and the window, a few thousand numbers)
-        self.param("conv", (conv_size, 3 * inner),
-                   normal_init(conv_size ** -0.5), dtype=jnp.float32)
-        self.param("A_log", (num_heads,), normal_init(0.5),
-                   dtype=jnp.float32)
-
-        def dt_bias(key, shape, dtype):
-            return (jax.random.normal(key, shape, jnp.float32)
-                    - 2.0).astype(dtype)
-        self.param("dt_bias", (inner,), dt_bias, dtype=jnp.float32)
-        self.param("o_gain", (head_dim,), ones_init())
+    @property
+    def layer_stats(self) -> dict:
+        """A cached call's third result (:func:`count_kda_steps`)."""
+        return {f"{self.mixer}_steps": ((4,), jnp.int32, functools.partial(
+            count_kda_steps, mixer=self.mixer))}
 
     def kv_leaf_shapes(self) -> tuple:
         """No leaf a token: the state and the tail are a slot's."""
@@ -2198,48 +2228,15 @@ class KimiDeltaAttention(Module):
 
     def state_bytes(self) -> int:
         """Bytes a slot's state and tail hold in one layer."""
-        inner = self.num_heads * self.head_dim
-        return 4 * (inner * self.head_dim
-                    + (self.conv_size - 1) * 3 * inner)
+        return 4 * (self.num_heads * self.head_dim * self.head_dim
+                    + (self.conv_size - 1) * self.conv_channels)
 
     def init_leaves(self, layers: int, slots: int, sharding=None) -> tuple:
-        inner = self.num_heads * self.head_dim
         return (jnp.zeros((layers, slots, self.num_heads, self.head_dim,
                            self.head_dim), jnp.float32, device=sharding),
-                jnp.zeros((layers, slots, self.conv_size - 1, 3 * inner),
-                          jnp.float32, device=sharding))
-
-    def _inputs(self, params, u):
-        """``u (N, E)`` -> ``(a (N, 3 H d) float32 — q, k, v before the
-        convolution —, g (N, H, d), beta (N, H), gate (N, H))``."""
-        H, d = self.num_heads, self.head_dim
-        a = self.qkv_proj(params["qkv_proj"], u).astype(jnp.float32)
-        f = self.decay_proj(params["decay_proj"], u).astype(jnp.float32)
-        f = (f + params["dt_bias"].astype(jnp.float32)).reshape(-1, H, d)
-        g = self.lower_bound * jax.nn.sigmoid(
-            jnp.exp(params["A_log"].astype(jnp.float32))[None, :, None] * f)
-        bg = jax.nn.sigmoid(self.head_proj(params["head_proj"], u)
-                            .astype(jnp.float32))
-        return a, g, bg[:, :H], bg[:, H:]
-
-    def _qkv(self, y):
-        """The convolution's result ``(N, 3 H d)`` -> ``q``, ``k``, ``v``
-        ``(N, H, d)`` float32, activated and normalised."""
-        H, d = self.num_heads, self.head_dim
-        q, k, v = (jax.nn.silu(y).reshape(-1, 3, H, d)[:, i]
-                   for i in range(3))
-
-        def unit(x):
-            return x * jax.lax.rsqrt(
-                jnp.sum(x * x, axis=-1, keepdims=True) + 1e-12)
-        return unit(q) * d ** -0.5, unit(k), v
-
-    def _output(self, params, o, gate):
-        """``o (N, H, dv)`` float32, ``gate (N, H)``."""
-        o = _gain(params, "o_gain", o, self.norm_eps, jnp.float32)
-        o = (o * gate[..., None]).reshape(o.shape[0], -1)
-        return self.out_proj(params["out_proj"],
-                             o.astype(self.compute_dtype()))
+                jnp.zeros((layers, slots, self.conv_size - 1,
+                           self.conv_channels), jnp.float32,
+                          device=sharding))
 
     def __call__(self, params, x, *, positions=None, segment_ids=None,
                  attn_impl: str = "auto", kv_cache=None, slot_mask=None,
@@ -2273,7 +2270,7 @@ class KimiDeltaAttention(Module):
         a, g, beta, gate = self._inputs(params, u)
         # (the reads of a slot's state and tail out of their leaves and
         # the writes back are the scopes': most of what a row moves)
-        with jax.named_scope("hetu.kda_conv"):
+        with jax.named_scope(f"hetu.{self.mixer}_conv"):
             if slot is None:
                 y, tail = kda.conv_rows(a, taps, tail, valid, layer=layer,
                                         fresh=pos == 0)
@@ -2284,19 +2281,206 @@ class KimiDeltaAttention(Module):
         from hetu_tpu.ops.kda_pallas import hetu_kda_scan, hetu_kda_update
         none = jnp.zeros((2,), jnp.int32)
         if slot is None:
-            with jax.named_scope("hetu.kda_update"):
+            with jax.named_scope(f"hetu.{self.mixer}_update"):
                 o, state, steps = hetu_kda_update(
                     q, k, v, g, beta, state, valid, layer=layer,
                     fresh=pos == 0, return_steps=True)
             steps = jnp.concatenate([none, steps])
         else:
-            with jax.named_scope("hetu.kda_scan"):
+            with jax.named_scope(f"hetu.{self.mixer}_scan"):
                 o, state, steps = hetu_kda_scan(
                     q, k, v, g, beta, state, slot, pos, valid,
                     layer=layer, return_steps=True)
             steps = jnp.concatenate([steps, none])
         return self._output(params, o, gate).reshape(x.shape), \
-            (state, tail), {"kda_steps": steps}
+            (state, tail), {f"{self.mixer}_steps": steps}
+
+
+def _unit(x):
+    """``x / |x|`` over the last dim (float32)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-12)
+
+
+class KimiDeltaAttention(DeltaRuleMixer):
+    """Kimi Delta Attention (``ops.kda``): a gated delta rule with a
+    decay per channel over a per-slot recurrent state, behind a short
+    causal convolution (:class:`DeltaRuleMixer` has the rule, the
+    caches and the lanes). With ``u`` the block's normed input::
+
+        [q | k | v] = SiLU(conv(u [W_q | W_k | W_v]))    a channel at a time
+        q <- q / |q| * dk^-1/2,  k <- k / |k|            a head
+        g = lower_bound * sigmoid(exp(A_log_h) (u W_f + dt_bias))
+        beta = sigmoid(u W_b)                             one a head
+        out = W_o (RMSNorm_head(o) * sigmoid(u W_g))      one gate a head
+
+    ``A_log``, ``dt_bias`` and the taps are drawn, not constants (a
+    program that leaves one out must differ)."""
+
+    def __init__(self, embed_dim: int, num_heads: int, *, head_dim: int,
+                 conv_size: int = 4, lower_bound: float = -5.0,
+                 norm_eps: float = 1e-6, init=None):
+        super().__init__()
+        from hetu_tpu.nn.module import ones_init
+        self.num_heads = self.num_kv_heads = num_heads
+        self.head_dim, self.conv_size = head_dim, conv_size
+        self.lower_bound, self.norm_eps = float(lower_bound), norm_eps
+        self.min_window = None
+        init = init or normal_init(0.02)
+        inner = num_heads * head_dim
+        self.conv_channels = 3 * inner
+        self.qkv_proj = ColumnParallelLinear(
+            embed_dim, 3 * inner, bias=False, init=init, axis="heads",
+            out_kind="hidden")
+        self.decay_proj = ColumnParallelLinear(
+            embed_dim, inner, bias=False, init=init, axis="heads",
+            out_kind="hidden")
+        # [beta | gate]: one of each a head
+        self.head_proj = ColumnParallelLinear(
+            embed_dim, 2 * num_heads, bias=False, init=init, axis=None,
+            out_kind="hidden")
+        self.out_proj = RowParallelLinear(inner, embed_dim, bias=False,
+                                          init=init, axis="heads")
+        # (float32 whatever the weights are served in: they shape the
+        # decay and the window, a few thousand numbers)
+        self.param("conv", (conv_size, 3 * inner),
+                   normal_init(conv_size ** -0.5), dtype=jnp.float32)
+        self.param("A_log", (num_heads,), normal_init(0.5),
+                   dtype=jnp.float32)
+
+        def dt_bias(key, shape, dtype):
+            return (jax.random.normal(key, shape, jnp.float32)
+                    - 2.0).astype(dtype)
+        self.param("dt_bias", (inner,), dt_bias, dtype=jnp.float32)
+        self.param("o_gain", (head_dim,), ones_init())
+
+    def _inputs(self, params, u):
+        """``u (N, E)`` -> ``(a (N, 3 H d) float32 — q, k, v before the
+        convolution —, g (N, H, d), beta (N, H), gate (N, H))``."""
+        H, d = self.num_heads, self.head_dim
+        a = self.qkv_proj(params["qkv_proj"], u).astype(jnp.float32)
+        f = self.decay_proj(params["decay_proj"], u).astype(jnp.float32)
+        f = (f + params["dt_bias"].astype(jnp.float32)).reshape(-1, H, d)
+        g = self.lower_bound * jax.nn.sigmoid(
+            jnp.exp(params["A_log"].astype(jnp.float32))[None, :, None] * f)
+        bg = jax.nn.sigmoid(self.head_proj(params["head_proj"], u)
+                            .astype(jnp.float32))
+        return a, g, bg[:, :H], bg[:, H:]
+
+    def _qkv(self, y):
+        """The convolution's result ``(N, 3 H d)`` -> ``q``, ``k``, ``v``
+        ``(N, H, d)`` float32, activated and normalised."""
+        H, d = self.num_heads, self.head_dim
+        q, k, v = (jax.nn.silu(y).reshape(-1, 3, H, d)[:, i]
+                   for i in range(3))
+        return _unit(q) * d ** -0.5, _unit(k), v
+
+    def _output(self, params, o, gate):
+        """``o (N, H, dv)`` float32, ``gate (N, H)``."""
+        o = _gain(params, "o_gain", o, self.norm_eps, jnp.float32)
+        o = (o * gate[..., None]).reshape(o.shape[0], -1)
+        return self.out_proj(params["out_proj"],
+                             o.astype(self.compute_dtype()))
+
+
+class GatedDeltaNet(DeltaRuleMixer):
+    """Gated DeltaNet (arXiv:2412.06464, as ``qwen3_next`` builds it):
+    the delta rule with ONE decay a head and token and ``num_key_heads``
+    key heads under ``num_heads`` value heads (value head ``j`` reads
+    key head ``j // (num_heads / num_key_heads)``; the state is a value
+    head's). With ``u`` the block's normed input, no bias anywhere::
+
+        [q | k | v | z] = u W_qkvz                      Hk dk, Hk dk, H dv, H dv
+        [b | a] = u W_ba                                H and H
+        [q | k | v] <- SiLU(conv([q | k | v]))          a channel at a time
+        q <- q / |q| * dk^-1/2,  k <- k / |k|           a key head
+        beta = sigmoid(b),  g = -exp(A_log_h) softplus(a + dt_bias_h)
+        out = W_o (RMSNorm_dv(o) * w_o * silu(z))       w_o (dv,): the heads'
+
+    ``W_qkvz`` stands in the order written (a checkpoint groups it by
+    key head; ``models/converter.py`` loads none). ``A_log``,
+    ``dt_bias`` and the taps are drawn: ``A`` uniform in ``a_range`` and
+    the step ``softplus(dt_bias)`` log-uniform in ``dt_range``, so a
+    layer's heads keep their past for a few tokens to thousands and a
+    program that drops the decay, or a state between chunks, must
+    differ. The kernels take this form by broadcasting it onto KDA's
+    (``ops.kda.widen``)."""
+
+    mixer = "gdn"
+
+    def __init__(self, embed_dim: int, num_heads: int, *,
+                 num_key_heads: int, head_dim: int, conv_size: int = 4,
+                 norm_eps: float = 1e-6, a_range: tuple = (0.0, 16.0),
+                 dt_range: tuple = (1e-3, 1e-1), init=None):
+        super().__init__()
+        from hetu_tpu.nn.module import ones_init
+        if num_heads % num_key_heads:
+            raise ValueError(f"{num_key_heads} key heads under "
+                             f"{num_heads} value heads")
+        self.num_heads, self.num_kv_heads = num_heads, num_heads
+        self.num_key_heads = num_key_heads
+        self.head_dim, self.conv_size = head_dim, conv_size
+        self.norm_eps, self.min_window = norm_eps, None
+        init = init or normal_init(0.02)
+        self._key, self._value = num_key_heads * head_dim, \
+            num_heads * head_dim
+        self.conv_channels = 2 * self._key + self._value
+        # [q | k | v | z]
+        self.qkvz_proj = ColumnParallelLinear(
+            embed_dim, self.conv_channels + self._value, bias=False,
+            init=init, axis="heads", out_kind="hidden")
+        # [b | a]: one of each a value head
+        self.ba_proj = ColumnParallelLinear(
+            embed_dim, 2 * num_heads, bias=False, init=init, axis=None,
+            out_kind="hidden")
+        self.out_proj = RowParallelLinear(self._value, embed_dim,
+                                          bias=False, init=init,
+                                          axis="heads")
+        self.param("conv", (conv_size, self.conv_channels),
+                   normal_init(conv_size ** -0.5), dtype=jnp.float32)
+        lo, hi = a_range
+
+        def a_log(key, shape, dtype):
+            return jnp.log(jax.random.uniform(
+                key, shape, jnp.float32, max(lo, 1e-3), hi)).astype(dtype)
+
+        def dt_bias(key, shape, dtype):
+            dt = jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, math.log(dt_range[0]),
+                math.log(dt_range[1])))
+            return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+        self.param("A_log", (num_heads,), a_log, dtype=jnp.float32)
+        self.param("dt_bias", (num_heads,), dt_bias, dtype=jnp.float32)
+        self.param("o_gain", (head_dim,), ones_init())
+
+    def _inputs(self, params, u):
+        """``u (N, E)`` -> ``(a (N, 2 Hk d + H d) float32 — q, k, v
+        before the convolution —, g (N, H), beta (N, H), z (N, H,
+        d))``."""
+        H, d = self.num_heads, self.head_dim
+        y = self.qkvz_proj(params["qkvz_proj"], u).astype(jnp.float32)
+        ba = self.ba_proj(params["ba_proj"], u).astype(jnp.float32)
+        g = -jnp.exp(params["A_log"].astype(jnp.float32)) \
+            * jax.nn.softplus(ba[:, H:]
+                              + params["dt_bias"].astype(jnp.float32))
+        return y[:, :self.conv_channels], g, jax.nn.sigmoid(ba[:, :H]), \
+            y[:, self.conv_channels:].reshape(-1, H, d)
+
+    def _qkv(self, y):
+        """The convolution's result -> ``q``, ``k`` ``(N, Hk, d)``,
+        ``v`` ``(N, H, d)`` float32, activated and normalised."""
+        d, key = self.head_dim, self._key
+        y = jax.nn.silu(y)
+        q = y[:, :key].reshape(-1, self.num_key_heads, d)
+        k = y[:, key:2 * key].reshape(-1, self.num_key_heads, d)
+        return _unit(q) * d ** -0.5, _unit(k), \
+            y[:, 2 * key:].reshape(-1, self.num_heads, d)
+
+    def _output(self, params, o, z):
+        """``o``, ``z`` ``(N, H, dv)`` float32."""
+        o = _gain(params, "o_gain", o, self.norm_eps, jnp.float32)
+        o = (o * jax.nn.silu(z)).reshape(o.shape[0], -1)
+        return self.out_proj(params["out_proj"],
+                             o.astype(self.compute_dtype()))
 
 
 def count_retention(values, tokens=None) -> None:
@@ -3204,7 +3388,12 @@ class PreNormBlock(Module):
     (:class:`~hetu_tpu.nn.moe.ExpertShareMoE`) —, the residual scale
     ``a`` and the operands' ``compute_dtype`` ("bfloat16": bf16
     operands, float32 accumulation; the residual stream, the norms and
-    the router stay float32). A cached call returns ``(y, cache)``
+    the router stay float32). ``zero_centered`` (a standard deviation):
+    both norms' gains are ``1 + w``, ``w`` drawn at it. ``shared_gate``:
+    the shared expert is weighted ``sigmoid(u w_sg)``, one number a
+    token (``shared_gate (features, 1)``, float32 inside).
+    ``mixer_scope`` names a device scope around the mixer's call. A
+    cached call returns ``(y, cache)``
     and, where the mixer or the experts declare ``layer_stats``, what
     they report as a third result (the experts' under ``moe_<name>``).
     """
@@ -3215,10 +3404,16 @@ class PreNormBlock(Module):
                  shared: Optional[Module] = None,
                  moe: Optional[Module] = None,
                  residual_scale: float = 1.0,
-                 compute_dtype: str = "float32", model: str = "the model"):
+                 compute_dtype: str = "float32", model: str = "the model",
+                 zero_centered: float = 0.0, shared_gate: bool = False,
+                 mixer_scope: Optional[str] = None):
         super().__init__()
-        self.norm1 = RMSNorm(features, eps=eps)
-        self.norm2 = RMSNorm(features, eps=eps)
+        self.norm1 = RMSNorm(features, eps=eps, zero_centered=zero_centered)
+        self.norm2 = RMSNorm(features, eps=eps, zero_centered=zero_centered)
+        self._mixer_scope = mixer_scope
+        self._shared_gate = bool(shared_gate) and shared is not None
+        if self._shared_gate:
+            self.param("shared_gate", (features, 1), normal_init(0.02))
         self.attn = mixer
         self.layer_stats = dict(mixer.layer_stats)
         self._dense = moe is None
@@ -3255,7 +3450,9 @@ class PreNormBlock(Module):
                 f"{self._model} has no W8A8, LoRA or dropout lane")
         new_cache, stats = None, {}
         u = self.norm1(params["norm1"], x)              # float32
-        with autocast(self._policy):
+        with autocast(self._policy), (
+                jax.named_scope(self._mixer_scope) if self._mixer_scope
+                else contextlib.nullcontext()):
             if kv_cache is not None:
                 a, new_cache, *st = self.attn(
                     params["attn"], u, positions=positions,
@@ -3276,6 +3473,13 @@ class PreNormBlock(Module):
                 if self._shared:
                     with jax.named_scope("hetu.moe_shared"):
                         shared = self.shared(params["shared"], u)
+                        if self._shared_gate:
+                            shared = shared.astype(jnp.float32) \
+                                * jax.nn.sigmoid(jnp.matmul(
+                                    u.astype(jnp.float32),
+                                    params["shared_gate"].astype(
+                                        jnp.float32),
+                                    precision=jax.lax.Precision.HIGHEST))
                 routed, st = self.moe(params["moe"], u, return_stats=True)
                 f = routed.astype(jnp.float32)
                 if self._shared:

@@ -47,6 +47,20 @@ PIECES — a chunk's rows of one run — that reads and writes ONE slot's
 state of ONE layer in the (stacked) state leaf in place; everything
 else is computed for all chunks at once.
 
+**Gated DeltaNet's form** (arXiv:2412.06464) is the same rule with ONE
+decay a head and token and FEWER key heads than value heads: every
+entry point here and in ``ops.kda_pallas`` takes ``g`` ``(T, H)``
+beside ``(T, H, dk)`` and ``q``, ``k`` of ``Hk`` heads where ``Hk``
+divides the ``H`` of ``v`` — value head ``j`` reads key head ``j // (H
+/ Hk)``; the state is a VALUE head's. :func:`widen` brings that form
+onto the per-channel one's SHAPES (a broadcast; no operation on operands
+that have them already). One thing of it is NOT the per-channel
+arithmetic: with one decay a head a pair's factor ``e^{G_i - G_j}`` is a
+number a pair and head, formed directly with no exponent above 0, so
+the chunk forms (here and in the kernel) take ``g`` of any size — Gated
+DeltaNet's ``g = -A softplus(.)`` has no lower bound, and the reference
+row's bound (``(SUB - 1) x |g| < 88``) would not hold.
+
 The states of ALL slots ride the calls, as in ``linear_scan``; with
 ``layer=`` the state is the STACKED leaf ``(layers, slots, H, dk,
 dv)`` and only ``[layer, slot]`` of a live slot or run is touched — no
@@ -71,6 +85,28 @@ def _f32(*xs):
     return tuple(jnp.asarray(x, jnp.float32) for x in xs)
 
 
+def widen(q, k, v, g):
+    """Gated DeltaNet's operands in Kimi Delta Attention's shapes: ``g``
+    ``(.., H)`` — one decay a head — over the ``dk`` channels, and
+    ``q``, ``k`` ``(.., Hk, dk)`` of ``Hk`` key heads under the ``H``
+    value heads of ``v`` ``(.., H, dv)``, value head ``j`` reading key
+    head ``j // (H / Hk)``. Broadcasts and reshapes (no ``repeat``, no
+    gather); operands already in those shapes come back as they are."""
+    H, Hk, dk = v.shape[-2], q.shape[-2], q.shape[-1]
+    if g.ndim == v.ndim - 1:
+        g = jnp.broadcast_to(g[..., None], g.shape + (dk,))
+    if Hk != H:
+        if H % Hk or k.shape[-2] != Hk:
+            raise ValueError(f"{Hk} key heads under {H} value heads")
+
+        def heads(x):
+            return jnp.broadcast_to(
+                x[..., :, None, :], x.shape[:-2] + (Hk, H // Hk, dk)
+            ).reshape(x.shape[:-2] + (H, dk))
+        q, k = heads(q), heads(k)
+    return q, k, g
+
+
 def _step(S, q, k, v, g, beta):
     """One token on states ``S (..., dk, dv)``: ``(S_t, o_t)``."""
     S = jnp.exp(g)[..., :, None] * S
@@ -82,8 +118,10 @@ def _step(S, q, k, v, g, beta):
 def kda_recurrence(q, k, v, g, beta, state=None):
     """The token recurrence over ONE sequence: ``q``, ``k``, ``g`` ``(T,
     H, dk)``, ``v`` ``(T, H, dv)``, ``beta`` ``(T, H)`` -> ``(o (T, H,
-    dv) float32, state (H, dk, dv))``."""
+    dv) float32, state (H, dk, dv))``; ``g (T, H)`` and ``q``, ``k`` of
+    fewer heads: :func:`widen`."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
+    q, k, g = widen(q, k, v, g)
     if state is None:
         state = jnp.zeros(q.shape[1:] + (v.shape[-1],), jnp.float32)
 
@@ -126,6 +164,7 @@ def kda_update(q, k, v, g, beta, state, live, *, layer=None, fresh=None):
     the slots, 2 MB a step): tests and ``workloads/kda_bench.py
     --update`` alone reach it."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
+    q, k, g = widen(q, k, v, g)
     buf, layer = _stacked(state, layer)
     old, at = _live_rows(buf, layer, live, fresh)
     new, o = _step(old, q, k, v, g, beta)
@@ -162,28 +201,38 @@ def _neumann_inverse(n):
     return inv
 
 
-def _chunk_parts(q, k, v, g, beta, first, same):
+def _chunk_parts(q, k, v, g, beta, first, same, scalar=False):
     """What of a chunk does not depend on its entering state, all chunks
     at once: operands ``(N, H, c, d)``, ``beta (N, H, c)``, ``first (N,
     c)``, ``same (N, c, c)`` (row and column in one piece). Returns
-    ``(Gs, W, U', Q e^G, tril((Q e^G)(K e^-G)^T))``."""
+    ``(Gs, W, U', Q e^G, tril((Q e^G)(K e^-G)^T))``. ``scalar``: ``g``
+    is ONE decay a head (its channels alike), and a pair's factor is
+    ``e^{G_i - G_j}`` itself, a number a pair and head with no
+    exponent above 0 — no reference row and no bound on ``g``."""
     c = q.shape[2]
     G = jnp.cumsum(g, axis=2)
     at = first[:, None, :, None]
     Gs = G - jnp.take_along_axis(G - g, at, axis=2)
     i = jnp.arange(c)
-    rows_q, rows_k = [], []
-    for b in range(c // SUB):
-        # the reference row of each column's piece for this row block
-        ref = jnp.take_along_axis(
-            Gs, jnp.maximum(b * SUB, first)[:, None, :, None], axis=2)
-        kc = k * jnp.exp(jnp.minimum(ref - Gs, _EXP_MAX))
-        sl = slice(b * SUB, (b + 1) * SUB)
-        rf = jnp.exp(jnp.minimum(Gs[:, :, sl] - ref[:, :, sl], _EXP_MAX))
-        rows_q.append(jnp.einsum("nhid,nhjd->nhij", q[:, :, sl] * rf, kc,
-                                 precision=_HI))
-        rows_k.append(jnp.einsum("nhid,nhjd->nhij", k[:, :, sl] * rf, kc,
-                                 precision=_HI))
+    if scalar:
+        G1 = Gs[..., 0]
+        dec = jnp.exp(jnp.minimum(G1[..., :, None] - G1[..., None, :], 0.0))
+        rows_q = [jnp.einsum("nhid,nhjd->nhij", q, k, precision=_HI) * dec]
+        rows_k = [jnp.einsum("nhid,nhjd->nhij", k, k, precision=_HI) * dec]
+    else:
+        rows_q, rows_k = [], []
+        for b in range(c // SUB):
+            # the reference row of each column's piece for this row block
+            ref = jnp.take_along_axis(
+                Gs, jnp.maximum(b * SUB, first)[:, None, :, None], axis=2)
+            kc = k * jnp.exp(jnp.minimum(ref - Gs, _EXP_MAX))
+            sl = slice(b * SUB, (b + 1) * SUB)
+            rf = jnp.exp(jnp.minimum(Gs[:, :, sl] - ref[:, :, sl],
+                                     _EXP_MAX))
+            rows_q.append(jnp.einsum("nhid,nhjd->nhij", q[:, :, sl] * rf,
+                                     kc, precision=_HI))
+            rows_k.append(jnp.einsum("nhid,nhjd->nhij", k[:, :, sl] * rf,
+                                     kc, precision=_HI))
     lower = same & (i[None, :] <= i[:, None])[None]
     strict = same & (i[None, :] < i[:, None])[None]
     Pq = jnp.where(lower[:, None], jnp.concatenate(rows_q, axis=2), 0.0)
@@ -219,6 +268,8 @@ def kda_scan(q, k, v, g, beta, state, slot, pos, valid, *, layer=None):
 
     Returns ``(o (C, H, dv) float32, new state)``."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
+    scalar = g.ndim == 2
+    q, k, g = widen(q, k, v, g)
     C, H, dk = q.shape
     dv = v.shape[-1]
     buf, layer = _stacked(state, layer)
@@ -244,7 +295,8 @@ def kda_scan(q, k, v, g, beta, state, slot, pos, valid, *, layer=None):
         & vc[:, :, None] & vc[:, None, :]
     kc = chunks(k)
     Gs, W, U0, Qe, Pq = _chunk_parts(
-        chunks(q), kc, chunks(v), chunks(g), chunks(beta), fc, same)
+        chunks(q), kc, chunks(v), chunks(g), chunks(beta), fc, same,
+        scalar)
 
     # the pieces: a chunk's rows of one run, in pack order
     n_max = N + min(buf.shape[1], Cp)

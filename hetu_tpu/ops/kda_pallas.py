@@ -82,7 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from hetu_tpu.ops.flash_pallas import _interpret_default
 from hetu_tpu.ops.kda import (
-    CHUNK, SUB, _EXP_MAX, _f32, _segments, _stacked,
+    CHUNK, SUB, _EXP_MAX, _f32, _segments, _stacked, widen,
 )
 
 _HI = jax.lax.Precision.HIGHEST
@@ -233,7 +233,7 @@ def _blockdiag(x, gs):
 
 
 def _piece(lo, hi, q_ref, k_ref, v_ref, beta_ref, gs_ref, row_ref, s_ref,
-           o_ref, *, c, hb, dk, dv):
+           o_ref, *, c, hb, dk, dv, scalar=False):
     """The step's heads on the piece ``[lo, hi)`` of a chunk of ``c``
     rows, a stage at a time
     over all of them (independent products back to back, not a head's
@@ -246,7 +246,14 @@ def _piece(lo, hi, q_ref, k_ref, v_ref, beta_ref, gs_ref, row_ref, s_ref,
     heads lie side by side in the lanes, ``[X_1 | X_2]`` ``(c, 2 c)``:
     a product with ``diag(Y_1, Y_2)`` gives ``[X_1 Y_1 | X_2 Y_2]`` at
     the rows of ONE head's product (the MXU's cost is the rows pushed
-    through a weight tile, and a 64-wide tile is half empty)."""
+    through a weight tile, and a 64-wide tile is half empty).
+
+    ``scalar``: ONE decay a head (Gated DeltaNet; the channels of
+    ``gs_ref`` are alike): the lower products are ``Q K^T`` and ``K
+    K^T`` as they are, ONE dot of all the chunk's rows, times
+    ``e^{G_i - G_j}`` a pair — no exponent above 0 whatever ``g``, no
+    reference row, ``c x c`` exponentials a head in place of ``2 c dk``
+    a row block."""
     nb = c // SUB
     gs = 2 if hb % 2 == 0 else 1
     w = gs * c
@@ -258,28 +265,47 @@ def _piece(lo, hi, q_ref, k_ref, v_ref, beta_ref, gs_ref, row_ref, s_ref,
     v = jnp.where(keep, _heads(v_ref, every, dv, hb), 0.0)
     beta = jnp.where(keep, _heads(beta_ref, every, 1, hb), 0.0)
     Gs = _heads(gs_ref, every, dk, hb)
-    # row block b against every column, scaled about the reference row
-    # between them: the first row of the block, or of the piece
     prods = []
-    for b in range(nb):
-        sl = slice(b * SUB, (b + 1) * SUB)
-        ref = _heads(row_ref, slice(b, b + 1), dk, hb)        # (hb, 1, dk)
-        kc = k * jnp.exp(jnp.minimum(ref - Gs, _EXP_MAX))
-        rf = jnp.exp(jnp.minimum(Gs[:, sl] - ref, _EXP_MAX))
-        prods.append(_dot(
-            _pair(jnp.concatenate([q[:, sl] * rf, k[:, sl] * rf], axis=1),
-                  gs), _blockdiag(kc, gs), ((2,), (2,))))  # (., 2 SUB, w)
-    ri = jax.lax.broadcasted_iota(jnp.int32, (1, c, w), 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, c, w), 2)
-    ci = lane % c
+
+    def lanes():             # a paired matrix's rows, lanes and columns
+        ri = jax.lax.broadcasted_iota(jnp.int32, (1, c, w), 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, c, w), 2)
+        return ri, lane, lane % c
 
     def half(a, b):          # a in the first head's lanes, b in the other's
         return jnp.where(lane < c, a, b)
+    if scalar:
+        ri, lane, ci = lanes()
+        # G down the rows is a lane of Gs; G along the columns is its
+        # transpose: the mean over a head's (alike) channels as a dot
+        mean = jnp.full((hb, 8, dk), 1.0 / dk, jnp.float32)
+        g_cols = _pair(_dot(mean, Gs, ((2,), (2,)))[:, :1], gs)  # (., 1, w)
+        g_rows = Gs[:, :, :1].reshape((-1, gs, c, 1))
+        dec = jnp.exp(jnp.minimum(
+            half(g_rows[:, 0], g_rows[:, gs - 1]) - g_cols, 0.0))
+        both = _dot(_pair(jnp.concatenate([q, k], axis=1), gs),
+                    _blockdiag(k, gs), ((2,), (2,)))       # (., 2 c, w)
+        prods = [jnp.concatenate([both[:, :c] * dec, both[:, c:] * dec],
+                                 axis=1)]
+    else:
+        # row block b against every column, scaled about the reference
+        # row between them: the first row of the block, or of the piece
+        for b in range(nb):
+            sl = slice(b * SUB, (b + 1) * SUB)
+            ref = _heads(row_ref, slice(b, b + 1), dk, hb)    # (hb, 1, dk)
+            kc = k * jnp.exp(jnp.minimum(ref - Gs, _EXP_MAX))
+            rf = jnp.exp(jnp.minimum(Gs[:, sl] - ref, _EXP_MAX))
+            prods.append(_dot(
+                _pair(jnp.concatenate([q[:, sl] * rf, k[:, sl] * rf],
+                                      axis=1), gs),
+                _blockdiag(kc, gs), ((2,), (2,))))     # (., 2 SUB, w)
+        ri, lane, ci = lanes()
+    part = c if scalar else SUB          # rows of q, then of k, a product
     Pq = jnp.where(ci <= ri, jnp.concatenate(
-        [x[:, :SUB] for x in prods], axis=1), 0.0)
+        [x[:, :part] for x in prods], axis=1), 0.0)
     by_group = beta.reshape((-1, gs) + beta.shape[1:])
     A = jnp.where(ci < ri, jnp.concatenate(
-        [x[:, SUB:] for x in prods], axis=1), 0.0) \
+        [x[:, part:] for x in prods], axis=1), 0.0) \
         * half(by_group[:, 0], by_group[:, gs - 1])
 
     def mm(x, y):            # [X_1 Y_1 | X_2 Y_2] of two paired matrices
@@ -321,7 +347,8 @@ def _piece(lo, hi, q_ref, k_ref, v_ref, beta_ref, gs_ref, row_ref, s_ref,
 
 def _kernel(layer_ref, chunk_ref, lo_ref, hi_ref, slot_ref, flag_ref,
             q_ref, k_ref, v_ref, g_ref, beta_ref, state_in, o_ref,
-            state_out, s_ref, gs_ref, row_ref, sem, *, c, hb, dk, dv):
+            state_out, s_ref, gs_ref, row_ref, sem, *, c, hb, dk, dv,
+            scalar=False):
     del chunk_ref, state_in          # (the index maps'; aliased to out)
     p = pl.program_id(1)
     lo, hi, flags = lo_ref[p], hi_ref[p], flag_ref[p]
@@ -355,7 +382,7 @@ def _kernel(layer_ref, chunk_ref, lo_ref, hi_ref, slot_ref, flag_ref,
         row_ref[c // SUB:c // SUB + 1, :] = gs_ref[
             pl.ds(hi - 1, 1), :]
         _piece(lo, hi, q_ref, k_ref, v_ref, beta_ref, gs_ref, row_ref,
-               s_ref, o_ref, c=c, hb=hb, dk=dk, dv=dv)
+               s_ref, o_ref, c=c, hb=hb, dk=dk, dv=dv, scalar=scalar)
 
     @pl.when(flags & _CLOSES != 0)
     def _():
@@ -377,12 +404,19 @@ def hetu_kda_scan(q, k, v, g, beta, state, slot, pos, valid, *, layer=None,
     scalar, traced inside the layer scan) — read and written in place
     at ``[layer, slot]`` of the slots with a run here, nothing else of
     it touched. A run whose first token stands at position 0 starts
-    from zeros.
+    from zeros. Gated DeltaNet's form — ``g (C, H)``, ``q`` and ``k``
+    of fewer heads than ``v`` — is widened onto these shapes
+    (``ops.kda.widen``) in front of the same call, whose steps then
+    form a pair's decay ``e^{G_i - G_j}`` directly (``_piece``'s
+    ``scalar``): exact for any ``g``, where the per-channel form needs
+    ``g >= -5``.
 
     Returns ``(o (C, H, dv) float32 — zeros on rows that are not valid
     —, new state)`` and, with ``return_steps``, ``[live, computed]``
     int32: the grid steps that held a valid row and the steps run."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
+    scalar = g.ndim == 2         # one decay a head: exact pair factors
+    q, k, g = widen(q, k, v, g)
     C, H, dk = q.shape
     dv = v.shape[-1]
     interpret = _interpret_default() if interpret is None else interpret
@@ -414,7 +448,8 @@ def hetu_kda_scan(q, k, v, g, beta, state, slot, pos, valid, *, layer=None,
     def wide(d):
         return pl.BlockSpec((chunk, hb * d), rows_at)
     o, buf = pl.pallas_call(
-        functools.partial(_kernel, c=chunk, hb=hb, dk=dk, dv=dv),
+        functools.partial(_kernel, c=chunk, hb=hb, dk=dk, dv=dv,
+                          scalar=scalar),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(H // hb, work.n),
@@ -507,12 +542,14 @@ def hetu_kda_update(q, k, v, g, beta, state, live, *, layer=None,
     (no dot). A slot that is not live is never fetched — the steps
     behind the live ones name the last live block again, which moves
     nothing — and its row of ``o`` is zeros; a ``fresh`` slot starts
-    from a zero state whatever it held.
+    from a zero state whatever it held. Gated DeltaNet's form (``g (S,
+    H)``, fewer key heads): ``ops.kda.widen``, as the scan.
 
     Returns ``(o (S, H, dv) float32, new state)`` and, with
     ``return_steps``, ``[live, stepped]`` int32: the slots advanced and
     the slot steps of the grid."""
     q, k, v, g, beta = _f32(q, k, v, g, beta)
+    q, k, g = widen(q, k, v, g)
     S, H, dk = q.shape
     dv = v.shape[-1]
     interpret = _interpret_default() if interpret is None else interpret

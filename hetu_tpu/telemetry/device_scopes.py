@@ -38,6 +38,10 @@ The vocabulary (all start ``hetu.``; ``docs/OBSERVABILITY.md``):
 ``hetu.ssm_conv``     selective scan (Mamba): the short convolution and its tails
 ``hetu.ssm_scan``     selective scan: a prefill pack's tokens (one kernel)
 ``hetu.ssm_update``   selective scan: the decode rows' update in place
+``hetu.gdn_conv``     Gated DeltaNet: the short convolution and its tails
+``hetu.gdn_scan``     Gated DeltaNet: a prefill pack's chunk form (the KDA kernel)
+``hetu.gdn_update``   Gated DeltaNet: the decode rows' update in place
+``hetu.gated_attn``   gated softmax attention: the whole mixer of such a layer
 ====================  ================================================
 
 The rule (:func:`classify`): an instruction belongs to the INNERMOST
@@ -78,7 +82,8 @@ VOCABULARY = (
     "hetu.mla_down", "hetu.mla_absorb", "hetu.mla_expand",
     "hetu.diffusion_sample", "hetu.retention_scan",
     "hetu.retention_update", "hetu.ssm_conv", "hetu.ssm_scan",
-    "hetu.ssm_update",
+    "hetu.ssm_update", "hetu.gdn_conv", "hetu.gdn_scan",
+    "hetu.gdn_update", "hetu.gated_attn",
 )
 
 #: ``op_name``s the TPU compiler gives an op it made from a program's

@@ -1,0 +1,421 @@
+"""Qwen3-Next (ISSUE 59) at ``Qwen3NextConfig.tiny``-like sizes on the
+CPU: the delta-rule oracles and the two Pallas kernels (interpreted) at
+ONE decay a head and grouped key heads against a token recurrence
+written here, with decays far beyond Kimi Delta Attention's bound; the
+system's prefill-then-decode through the engine against the float32
+reference's full forward (logits and the slot states); partial rotary
+and the output gate against the reference one layer at a time; the
+share test of the ``model-configs`` guide's section 4; the planted
+controls; the counters and the refusals."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.reference import qwen3_next as reference  # noqa: E402
+from benchmark.runners.serve_arch import load_arch  # noqa: E402
+from hetu_tpu.ops import kda  # noqa: E402
+from hetu_tpu.ops.kda_pallas import (  # noqa: E402
+    hetu_kda_scan, hetu_kda_update,
+)
+
+HK, H, D = 2, 4, 16            # key heads under value heads of D
+
+
+# -- the delta rule at one decay a head, grouped key heads ---------------------
+def _token_rule(q, k, v, g, beta, state):
+    """Gated DeltaNet's recurrence in numpy, float64: ``q``, ``k`` ``(T,
+    HK, D)``, ``v`` ``(T, H, D)``, ``g``, ``beta`` ``(T, H)``; value
+    head ``j`` reads key head ``j // (H / HK)``."""
+    q, k, v, g, beta = (np.asarray(x, np.float64)
+                        for x in (q, k, v, g, beta))
+    S = np.array(state, np.float64)
+    out = np.zeros(v.shape)
+    for t in range(len(q)):
+        for j in range(H):
+            kj, qj = k[t, j // (H // HK)], q[t, j // (H // HK)]
+            S[j] = np.exp(g[t, j]) * S[j]
+            r = v[t, j] - kj @ S[j]
+            S[j] = S[j] + beta[t, j] * np.outer(kj, r)
+            out[t, j] = qj @ S[j]
+    return out, S
+
+
+def _draw(key, T, steep=False):
+    """Operands as the mixer makes them: unit q, k a KEY head; ``g (T,
+    H)`` in (-4, 0), or ``steep``: a token in five decays by e^-20 to
+    e^-60 (no lower bound: ``g = -A softplus(.)``)."""
+    ks = jax.random.split(key, 6)
+    q, k = (jax.random.normal(ks[i], (T, HK, D)) for i in range(2))
+    v = jax.random.normal(ks[2], (T, H, D))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = -4.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (T, H)))
+    if steep:
+        g = jnp.where(jax.random.uniform(ks[5], (T, H)) < 0.2,
+                      -20.0 - 40.0 * jax.random.uniform(ks[5], (T, H)), g)
+    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
+
+
+def _pack(runs, C, slots, key, steep):
+    """``runs``: ``(slot, first position, tokens)`` in pack order ->
+    operands with garbage on the pad rows, the states before, where,
+    and what :func:`_token_rule` wants."""
+    state0 = np.asarray(jax.random.normal(jax.random.fold_in(key, 99),
+                                          (slots, H, D, D)))
+    slot, pos = np.zeros(C, np.int32), np.zeros(C, np.int32)
+    valid = np.zeros(C, bool)
+    parts, want_o, want_s, used = [], [], state0.copy(), 0
+    for i, (s, p0, n) in enumerate(runs):
+        x = _draw(jax.random.fold_in(key, i), n, steep)
+        parts.append(x)
+        slot[used:used + n], valid[used:used + n] = s, True
+        pos[used:used + n] = np.arange(p0, p0 + n)
+        o, st = _token_rule(*x, state0[s] if p0 else np.zeros((H, D, D)))
+        want_o.append(o)
+        want_s[s] = st
+        used += n
+    ops = [jnp.concatenate([p[j] for p in parts] + [jnp.full(
+        (C - used,) + parts[0][j].shape[1:], 7.0)]) for j in range(5)]
+    return ops, jnp.asarray(state0), (
+        jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(valid)), \
+        np.concatenate(want_o), want_s, used
+
+
+THREE = [(2, 0, 70), (0, 37, 100), (1, 0, 5)]
+PACKS = {
+    "one-run": ([(0, 0, 50)], 50, None, False),
+    "three-slots": (THREE, 200, None, False),
+    "three-slots-stacked-steep": (THREE, 200, 1, True),
+    "shared-chunk-restart-steep": ([(1, 9, 20), (3, 0, 30), (0, 5, 90)],
+                                   192, 2, True),
+    "short-16": ([(0, 0, 6), (2, 5, 4)], 10, None, True),
+}
+
+
+@pytest.mark.parametrize("form", ["kernel", "chunk_form"])
+@pytest.mark.parametrize("name", list(PACKS))
+def test_scan_at_one_decay_a_head_equals_the_token_rule(name, form):
+    """``hetu_kda_scan`` (interpreted) and its ``jax.numpy`` oracle on
+    Gated DeltaNet's operands — ``g (C, H)``, two key heads under four
+    value heads — against the token rule; ``steep`` packs decay by up
+    to e^-60 a token, where the per-channel form's reference row would
+    overflow (the pair factors are formed directly here)."""
+    runs, C, layer, steep = PACKS[name]
+    ops, state0, where, want_o, want_s, used = _pack(
+        runs, C, 4, jax.random.key(11), steep)
+    fn = hetu_kda_scan if form == "kernel" else kda.kda_scan
+    with jax.default_matmul_precision("highest"):
+        if layer is None:
+            o, st = jax.jit(fn)(*ops, state0, *where)
+        else:
+            buf0 = jnp.stack([state0 + float(i + 1) for i in range(3)]) \
+                .at[layer].set(state0)
+            o, buf = jax.jit(lambda *a: fn(*a, layer=jnp.int32(layer)))(
+                *ops, buf0, *where)
+            others = [i for i in range(3) if i != layer]
+            assert (np.asarray(buf)[others]
+                    == np.asarray(buf0)[others]).all()
+            st = buf[layer]
+    assert np.isfinite(np.asarray(o)).all()
+    # (a steep pack's running sum of g reaches -700 inside a chunk: a
+    # float32 step there is 6e-5, and a pair's factor is its exponential)
+    np.testing.assert_allclose(o[:used], want_o,
+                               atol=5e-5 if steep else 1e-5)
+    np.testing.assert_allclose(st, want_s, atol=5e-5 if steep else 2e-5)
+    idle = sorted(set(range(4)) - {s for s, _, _ in runs})
+    assert (np.asarray(st)[idle] == np.asarray(state0)[idle]).all()
+
+
+@pytest.mark.parametrize("form", ["kernel", "gather_form"])
+@pytest.mark.parametrize("steep", [False, True], ids=["mild", "steep"])
+def test_update_at_one_decay_a_head_equals_the_token_rule(steep, form):
+    rows = 6
+    x = _draw(jax.random.key(6), rows, steep)
+    state0 = jax.random.normal(jax.random.key(7), (rows, H, D, D))
+    live = jnp.asarray([True, False, True, True, False, True])
+    fresh = jnp.asarray([False, True, True, False, False, False])
+    fn = hetu_kda_update if form == "kernel" else kda.kda_update
+    o, st = jax.jit(lambda *a: fn(*a, fresh=fresh))(*x, state0, live)
+    for s in range(rows):
+        if not live[s]:
+            assert (np.asarray(st[s]) == np.asarray(state0[s])).all()
+            continue
+        want_o, want_s = _token_rule(
+            *(a[s:s + 1] for a in x),
+            np.zeros((H, D, D)) if fresh[s] else state0[s])
+        np.testing.assert_allclose(o[s], want_o[0], atol=2e-6)
+        np.testing.assert_allclose(st[s], want_s, atol=2e-6)
+
+
+def test_recurrence_takes_both_forms_and_widen_leaves_kdas_alone():
+    """``kda_recurrence(..., g (T, H))`` with grouped key heads is the
+    token rule; operands already in Kimi Delta Attention's shapes pass
+    ``widen`` as the SAME arrays (Ling's lowered kernels see no new
+    operation), and head counts that do not divide are refused."""
+    x = _draw(jax.random.key(2), 40, steep=True)
+    o, st = kda.kda_recurrence(*x)
+    want_o, want_s = _token_rule(*x, np.zeros((H, D, D)))
+    np.testing.assert_allclose(o, want_o, atol=2e-6)
+    np.testing.assert_allclose(st, want_s, atol=2e-6)
+    q, k, v, g, _ = x
+    qw, kw, gw = kda.widen(q, k, v, g)
+    assert qw.shape == kw.shape == gw.shape == v.shape
+    np.testing.assert_array_equal(qw[:, 1], q[:, 0])    # j // 2, not j % 2
+    np.testing.assert_array_equal(qw[:, 2], q[:, 1])
+    again = kda.widen(qw, kw, v, gw)
+    assert again[0] is qw and again[1] is kw and again[2] is gw
+    with pytest.raises(ValueError, match="key heads under"):
+        kda.widen(q[:, :1].repeat(3, 1), k[:, :1].repeat(3, 1), v, g)
+
+
+# -- the model -----------------------------------------------------------------
+@pytest.fixture(scope="module")
+def tiny():
+    """``tests/benchmark/configs/qwen3-next-tiny.json`` with EVERY
+    expert held (the uncut model), its model and float32 weights."""
+    with open(os.path.join(
+            ROOT, "tests/benchmark/configs/qwen3-next-tiny.json")) as f:
+        config = json.load(f)
+    config["num_experts"] = config["published"]["num_experts"]
+    config["deployment"] = {"expert_share": 0}
+    model = load_arch("qwen3_next").build(config)
+    return config, model, model.init(jax.random.key(3))
+
+
+def test_tiny_is_the_issues(tiny):
+    from hetu_tpu.models import Qwen3NextConfig
+    from hetu_tpu.models.qwen3_next import ATTENTION, GDN
+    cfg = Qwen3NextConfig.tiny()
+    assert cfg.mixer_types == (GDN, GDN, GDN, ATTENTION) * 2
+    assert (cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+            cfg.linear_key_head_dim) == (4, 8, 16)
+    assert (cfg.head_dim, cfg.rotary_dim) == (32, 8)
+    assert (cfg.num_experts, cfg.num_experts_per_tok) == (16, 3)
+    _, model, _ = tiny
+    assert model.blocks.layers_of == {GDN: 6, ATTENTION: 2}
+    assert model.blocks.slot_state and model.blocks.paged
+
+
+def test_forward_equals_the_reference(tiny):
+    config, model, params = tiny
+    ids = jax.random.randint(jax.random.key(1), (2, 37), 1, 128)
+    got = model(params, ids)
+    for b in range(2):
+        want = reference.logits(params, ids[b], config)
+        assert float(jnp.abs(want).max()) > 1.0
+        np.testing.assert_allclose(got[b], want, atol=1e-3)
+
+
+CONTROLS = [{"no_erase": True}, {"no_conv": True}, {"tile_key_heads": True},
+            {"full_rotary": True}, {"no_out_gate": True},
+            {"no_shared_gate": True}, {"plain_gain": True},
+            {"sigmoid_router": True}, {"operands": jnp.float8_e4m3fn},
+            {"state_dtype": jnp.float8_e4m3fn}]
+
+
+@pytest.mark.parametrize("control", CONTROLS,
+                         ids=[next(iter(c)) for c in CONTROLS])
+def test_each_planted_control_moves_the_reference(tiny, control):
+    config, _, params = tiny
+    ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
+    base = reference.logits(params, ids, config)
+    moved = reference.logits(params, ids, config, **control)
+    assert float(jnp.abs(moved - base)[16:].max()) > 0.1, control
+
+
+def _attention_layer(tiny, **over):
+    """The first gated attention layer's mixer alone, the program's and
+    the reference's, on one random normed input."""
+    from hetu_tpu.nn.parallel import ParallelAttention
+    config, model, params = tiny
+    a = jax.tree.map(lambda x: x[0], params["blocks"]["runs"]["1"]["attn"])
+    u = jax.random.normal(jax.random.key(4), (1, 29, config["hidden_size"]))
+    d = config["head_dim"]
+    kw = dict(num_kv_heads=config["num_key_value_heads"], head_dim=d,
+              bias=False, use_rope=True, rope_theta=config["rope_theta"],
+              max_positions=64, rotary_dim=d // 4, qk_norm=True,
+              qk_gain=2.0, zero_centered=True, out_gate=True)
+    kw.update(over)
+    attn = ParallelAttention(config["hidden_size"],
+                             config["num_attention_heads"], **kw)
+    got = attn(a, u, positions=jnp.arange(29)[None])[0]
+    return got, lambda **c: reference.gated_attention(a, u[0], config, **c)
+
+
+def test_partial_rotary_and_the_output_gate_one_layer_at_a_time(tiny):
+    """The quarter rotary (8 of 32 numbers, as ONE head of 8) and the
+    gate from the doubled ``q_proj`` against the reference's layer; a
+    full rotary, a missing gate and a plain gain are each ANOTHER
+    function, in the program and the reference alike."""
+    got, ref = _attention_layer(tiny)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(got, ref(), atol=2e-5)
+        for over, control in ((dict(rotary_dim=None), "full_rotary"),
+                              (dict(zero_centered=False, qk_gain=1.0),
+                               "plain_gain")):
+            moved, ref = _attention_layer(tiny, **over)
+            assert float(jnp.abs(moved - got).max()) > 1e-2, control
+            np.testing.assert_allclose(moved, ref(**{control: True}),
+                                       atol=2e-5)
+    with pytest.raises(ValueError, match="rotary_dim"):
+        _attention_layer(tiny, rotary_dim=7)
+
+
+def test_eight_shares_add_up_to_the_uncut_layer(tiny):
+    """The guide's share test: the routed sums of the eight chips of a
+    deployment (two of the sixteen experts each, the weights normalised
+    over ALL three chosen) and the gated shared expert counted ONCE add
+    up to the reference's layer with every expert held."""
+    from hetu_tpu.nn.moe import ExpertShareMoE
+    config, _, params = tiny
+    blk = jax.tree.map(lambda x: x[1], params["blocks"]["runs"]["0"])
+    E = config["hidden_size"]
+    u = jax.random.normal(jax.random.key(2), (60, E))
+    total, sizes = jnp.zeros_like(u), []
+    for g in range(8):
+        share = ExpertShareMoE(E, config["moe_intermediate_size"], 16, k=3,
+                               local_experts=(2 * g, 2), score="softmax")
+        part = {"router": blk["moe"]["router"],
+                **{n: blk["moe"][n][2 * g:2 * g + 2]
+                   for n in ("wg", "wi", "wo")}}
+        out, st = share(part, u, return_stats=True)
+        total = total + out
+        sizes.append(int(st["sizes"].sum()))
+    assert sum(sizes) == 60 * 3               # every chosen pair, once
+    with jax.default_matmul_precision("highest"):
+        want, _ = reference.expert_ffn(blk, u, config)
+        shared = reference.swiglu(blk["shared"], u) * jax.nn.sigmoid(
+            u @ blk["shared_gate"])
+        np.testing.assert_allclose(total + shared, want, atol=2e-6)
+        # ... and one share's layer is the reference's at that share
+        one = {**config, "num_experts": 2, "deployment": {"expert_share": 5}}
+        share = ExpertShareMoE(E, config["moe_intermediate_size"], 16, k=3,
+                               local_experts=(10, 2), score="softmax")
+        got = share({"router": blk["moe"]["router"],
+                     **{n: blk["moe"][n][10:12]
+                        for n in ("wg", "wi", "wo")}}, u)
+        part = {**blk, "moe": {"router": blk["moe"]["router"],
+                               **{n: blk["moe"][n][10:12]
+                                  for n in ("wg", "wi", "wo")}}}
+        np.testing.assert_allclose(
+            got + shared, reference.expert_ffn(part, u, one)[0], atol=2e-6)
+
+
+@pytest.mark.parametrize("lanes", [
+    dict(), dict(attn_kernel="paged", prefill_attn="flash_pallas")],
+    ids=["reference_lanes", "paged_kernels"])
+def test_engine_serves_the_references_tokens_and_states(tiny, lanes):
+    """The real engine — scheduler, fused step, one executable — over
+    five requests through three slots, chunks that cut the convolution's
+    window and the scan's pieces: every emitted token is the reference's
+    top token within rounding (float32 both sides), the slot's STATE
+    where the last chunk and where the last decoded token leave it is
+    the token recurrence's, and the counters count what the kernels
+    walked. ``paged_kernels``: the flash prefill and BOTH paged calls
+    interpreted, at a head of 32."""
+    from benchmark.runners import serve_arch_ssm
+    from hetu_tpu import telemetry
+    from hetu_tpu.serving import ServingEngine
+    config, model, params = tiny
+    arch = load_arch("qwen3_next")
+    telemetry.enable(True)
+    try:
+        reg = telemetry.get_registry()
+
+        def read(mixer="gdn"):
+            c = reg.counter(f"{mixer}_scan_steps_total")
+            u = reg.counter(f"{mixer}_update_slots_total")
+            return [c.value(kind=k) for k in ("live", "computed")] + [
+                u.value(kind=k) for k in ("live", "stepped")]
+        before, kda_before = read(), read("kda")
+        eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                            block_size=4, slots=3, kv_blocks=40, seed=0,
+                            **lanes)
+        assert eng.prefix_cache is None and eng.preempt is False
+        assert len(eng.pool.caches) == 4
+        rng = np.random.default_rng(7)
+        prompts = [rng.integers(1, 128, n) for n in (21, 13, 30)]
+        recs = serve_arch_ssm.probe(arch, eng, prompts, 6)
+        live, computed, advanced, stepped = (
+            a - b for a, b in zip(read(), before))
+        # (Ling's counters, which another engine of this process may
+        # have fed, take nothing from this one)
+        assert read("kda") == kda_before
+    finally:
+        telemetry.enable(False)
+    assert eng.step_executables() == 1
+    for r in recs:
+        p, toks = len(r["prompt"]), r["tokens"]
+        ids = np.zeros(64, np.int32)
+        ids[:p + 6] = np.concatenate([r["prompt"], toks])
+        lg, margin, states = arch.reference_rows(
+            config, params, jnp.asarray(ids), jnp.int32(p - 1), 6)
+        lg = np.asarray(lg)
+        gap = lg.max(-1) - lg[np.arange(6), toks]
+        assert gap.max() <= 1e-3, (p, gap)
+        assert np.isfinite(np.asarray(margin)).all()
+        read_gap = arch.state_gap(config, params, r["states"], states)
+        assert read_gap["gap"] < arch.state_tol(config), read_gap
+        assert max(read_gap["whole_by_layer"]) < 1e-3, read_gap
+    layers = model.blocks.layers_of["linear_attention"]
+    assert 0 < live <= computed and live % layers == 0
+    # three requests x five decode rows each (the first token is the
+    # prefill's), every one a live slot of the three
+    assert advanced == 3 * 5 * layers
+    assert stepped % (3 * layers) == 0 and stepped >= advanced
+    assert reg.gauge("kv_state_bytes").value(kind="slot") == \
+        model.blocks.cache_bytes(4)["state"]["slot"]
+
+
+def test_a_steep_decay_survives_the_chunked_lanes():
+    """Weights drawn so that heads decay by e^-16 a token and more (the
+    tiny preset's ``dt_range`` up to 1): chunked prefill then decode
+    still serve the full forward's tokens — the per-channel chunk form
+    returns garbage there (its reference row's exponent passes 88)."""
+    from hetu_tpu.models.qwen3_next import (
+        Qwen3NextConfig, Qwen3NextForCausalLM,
+    )
+    from hetu_tpu.serving import SamplingParams, ServingEngine
+    model = Qwen3NextForCausalLM(Qwen3NextConfig.tiny(init_std=0.16))
+    params = model.init(jax.random.key(7))
+    ids = np.random.default_rng(0).integers(1, 127, 40)
+    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                        block_size=4, slots=2, kv_blocks=40)
+    (toks,) = eng.generate_many([ids.tolist()], SamplingParams(max_tokens=8))
+    lg = np.asarray(model(params, jnp.asarray(
+        np.concatenate([ids, toks]))[None])[0])[39:47]
+    assert (lg.max(-1) - lg[np.arange(8), toks]).max() <= 1e-3
+
+
+REFUSED = [("prefix_cache", dict(prefix_cache=True)),
+           ("preempt", dict(preempt=True)),
+           ("spec_depth", dict(spec_depth=2))]
+
+
+@pytest.mark.parametrize("name,kw", REFUSED, ids=[n for n, _ in REFUSED])
+def test_what_assumes_block_kv_refuses_by_name(tiny, name, kw):
+    from hetu_tpu.nn.parallel import SlotStateNotSupported
+    from hetu_tpu.serving import ServingEngine
+    _, model, params = tiny
+    with pytest.raises(SlotStateNotSupported, match=name):
+        ServingEngine(model, params, max_len=64, prefill_chunk=8,
+                      block_size=4, slots=2, kv_blocks=40, **kw)
+
+
+def test_the_config_refuses_what_is_not_built():
+    from hetu_tpu.models import Qwen3NextConfig
+    with pytest.raises(NotImplementedError, match="tied head"):
+        Qwen3NextConfig.tiny(tie_word_embeddings=True)
+    with pytest.raises(NotImplementedError, match="differ in size"):
+        Qwen3NextConfig.tiny(linear_value_head_dim=32)
+    with pytest.raises(ValueError, match="at least one attention"):
+        Qwen3NextConfig.tiny(num_hidden_layers=3)

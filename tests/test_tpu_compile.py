@@ -265,6 +265,55 @@ def test_ling_step_holds_the_scan_kernel_a_kda_layer_run_on_v5e(one_chip):
     assert moved == [], moved
 
 
+def test_qwen3_next_step_holds_both_mixers_kernels_at_heads_of_256_on_v5e(
+        one_chip):
+    """Qwen3-Next's fused step at the published widths (32 value heads
+    over 16 key heads of 128, ONE decay a head; 16 query heads over 2 kv
+    heads of 256; hidden 2048; cut to one period of four layers, 8 held
+    experts of 64 and 4,096 ids so that the host holds its zeros) with
+    the cell's 2,048-token pack and 18 slots: the delta-rule kernels'
+    ``scalar`` path and the paged calls at ``d = 256``, 8 query heads a
+    kv head, compile for the chip within their VMEM limits — ONE
+    ``hetu_kda_scan`` under ``hetu.gdn_scan`` in the prefill lane, ONE
+    ``hetu_kda_update`` under ``hetu.gdn_update`` in the decode lane (a
+    run of three layers is one scan body), one paged call a lane and one
+    flash part under ``hetu.gated_attn`` — and nothing copies or slices
+    a layer of the state leaf out of or into it."""
+    import json
+    import os
+    import re
+
+    from benchmark.runners.serve_arch import load_arch
+    from workloads.aot_check import check_serving_step
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "qwen3-next-80b-a3b-ep8.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=4, num_experts=8, vocab_size=4096)
+    cfg["published"] = dict(cfg["published"], num_experts=64)
+    model = load_arch(cfg["arch"]).build(cfg)
+    slots = 18
+    r = check_serving_step(
+        list(one_chip.device_set), model=model, slots=slots, n_blocks=1200,
+        max_len=4096, chunk=2048, block_size=64, with_text=True)
+    calls = r["kernel_calls"]
+    assert calls["hetu.prefill_lane>hetu.gdn_scan"] == 1, calls
+    assert calls["hetu.decode_lane>hetu.gdn_update"] == 1, calls
+    assert not any("kda_" in k for k in calls), calls
+    assert len(re.findall(r"%hetu_kda_scan[.\d]* = ", r["text"])) == 1
+    assert len(re.findall(r"%hetu_kda_update[.\d]* = ", r["text"])) == 1
+    assert calls["hetu.decode_lane>hetu.paged_attn"] == 1, calls
+    assert calls["hetu.prefill_lane>hetu.paged_attn"] == 1, calls
+    assert calls["hetu.prefill_lane>hetu.flash_fwd"] == 1, calls
+    assert "hetu.gated_attn" in r["text"]
+    assert r["arena_moves"] == {}
+    # the state leaf (3 Gated DeltaNet layers) and a layer of it
+    leaf = rf"f32\[(3,|1,)?{slots},32,128,128\]"
+    moved = [m.group(1) for m in re.finditer(
+        rf"%((?:copy|slice|dynamic[-_]slice)[\w.\-]*) = {leaf}", r["text"])]
+    assert moved == [], moved
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "fp32"])
 def test_packed_prefill_lane_compiles_for_v5e(one_chip, dtype):

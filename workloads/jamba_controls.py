@@ -41,24 +41,32 @@ CONTROLS = {
 }
 
 
-def main():
+def main(controls=None, *, reference="jamba", seed=2155550251,
+         config="benchmark/configs/jamba2-3b.json", extra=None):
+    """``controls`` (default: this file's), the ``reference`` module's
+    name under ``benchmark/reference/`` and the defaults of ``--seed``
+    and ``--config`` are the cell's; ``extra(seen, recs, logits, limits)
+    -> dict`` adds to a reading's line (``logits``: the reference's rows
+    a compared request). ``workloads/qwen3_next_controls.py`` calls this
+    with its own."""
+    controls = CONTROLS if controls is None else controls
     ap = argparse.ArgumentParser()
-    ap.add_argument("--seed", type=int, default=2155550251)
-    ap.add_argument("--config", default=os.path.join(
-        ROOT, "benchmark/configs/jamba2-3b.json"))
+    ap.add_argument("--seed", type=int, default=seed)
+    ap.add_argument("--config", default=os.path.join(ROOT, config))
     ap.add_argument("--requests", type=int, default=1)
     ap.add_argument("--prompt", type=int, default=32768)
     ap.add_argument("--outputs", type=int, default=256)
     ap.add_argument("--only", nargs="*", default=None)
     args = ap.parse_args()
 
+    import importlib
     import jax
     import jax.numpy as jnp
     from benchmark import traffic
     from benchmark.model import dtype
-    from benchmark.reference import jamba as reference
     from benchmark.runners import serve_arch, serve_arch_ssm, \
         serve_arch_ties
+    reference = importlib.import_module(f"benchmark.reference.{reference}")
     from hetu_tpu.serving import ServingEngine
 
     with open(args.config) as f:
@@ -86,7 +94,7 @@ def main():
                       "device": jax.devices()[0].device_kind}), flush=True)
     eng.pool.caches = None
     limits = {n: float(getattr(arch, n)) for n in serve_arch_ties.LIMITS}
-    for name, control in CONTROLS.items():
+    for name, control in controls.items():
         if args.only and name not in args.only:
             continue
         reference.CONTROL.clear()
@@ -97,8 +105,14 @@ def main():
         t0 = time.perf_counter()
         rows = serve_arch.ReferenceRows(arch, config, params,
                                         serve["max_len"], args.outputs)
+        logits = []
+
+        def tokens_rows(*a):
+            out = rows(*a)[:2]
+            logits.append(np.asarray(out[0]))
+            return out
         why, seen = serve_arch_ties.reference_check(
-            limits, arch, config, lambda *a: rows(*a)[:2], params, recs,
+            limits, arch, config, tokens_rows, params, recs,
             serve["max_len"])
         more, state = serve_arch_ssm.state_check(
             arch, config, rows, params, recs, serve["max_len"])
@@ -112,6 +126,7 @@ def main():
             "state_limit": state["state_tolerance"],
             "state_gap": state["state_gap"],
             "state_readings": state["state_readings"],
+            **(extra(seen, recs, logits, limits) if extra else {}),
             "s": time.perf_counter() - t0}), flush=True)
     reference.CONTROL.clear()
 

@@ -92,38 +92,16 @@ def _pick_block(n: int, target: int = 512) -> int:
     return n
 
 
-def _tuned_entries() -> tuple:
-    """Block winners measured by ``workloads/flash_tune.py`` on this
-    machine's chip; () when absent or when not running on TPU."""
-    if jax.default_backend() != "tpu":
-        return ()
-    from hetu_tpu.core.measured import read_measured
-    data = read_measured("flash_blocks.json")
-    try:
-        return tuple(tuple(sorted(e.items())) for e in data["entries"])
-    except (KeyError, TypeError):
-        return ()
-
-
 def _default_blocks(sq: int, sk: int, kind: str) -> tuple:
     """The compute tile (block_q, block_k) of one kernel — ``kind``:
-    "fwd" | "dq" | "dkv" ("bwd": either backward kernel): tuned for this
-    q/kv length if measured (exact q-seq match whose blocks divide both
-    lengths; the file's "bwd" entry serves dq and dk/dv), else the static
-    rule: the largest of 1024 / 512 / 256 / 128 that divides each
-    length, for all three kernels. Swept on a v5e over {128, 256, 512,
-    1024}² at (32, 1024, 12, 64) and (4, 2048, 16, 64) on rows packed as
-    ``pretrain-packed-1k`` packs them (``workloads/flash_tune.py``;
-    PERF.md, PR 40): a visited tile costs ~0.4 µs whatever its size, so at
-    1k rows 128² tiles — 55 % of the square dead — take 2.5–3.7 x the
-    time of one 1024² tile a head worked in strips."""
-    tuned = "fwd" if kind == "fwd" else "bwd"
-    for items in _tuned_entries():
-        e = dict(items)
-        if e.get("seq") == sq and tuned in e:
-            bq, bk = e[tuned]
-            if sq % bq == 0 and sk % bk == 0:
-                return bq, bk
+    "fwd" | "dq" | "dkv" —: the largest of 1024 / 512 / 256 / 128 that
+    divides each length, for all three kernels. Swept on a v5e over
+    {128, 256, 512, 1024}² at (32, 1024, 12, 64) and (4, 2048, 16, 64)
+    on rows packed as ``pretrain-packed-1k`` packs them
+    (``workloads/flash_tune.py``; PERF.md, PR 40): a visited tile costs
+    ~0.4 µs whatever its size, so at 1k rows 128² tiles — 55 % of the
+    square dead — take 2.5–3.7 x the time of one 1024² tile a head
+    worked in strips."""
     return _pick_block(sq, _TILE), _pick_block(sk, _TILE)
 
 

@@ -31,9 +31,9 @@ jax.config.update("jax_default_matmul_precision", "highest")
 import pytest  # noqa: E402
 
 # Tests measured >~7s on the 8-CPU mesh (mostly multi-strategy parity runs
-# that compile many XLA programs). `pytest -m quick` is the builder's inner
-# loop (<2 min); `pytest` runs everything. Central list so the split stays
-# visible and maintainable.
+# that compile many XLA programs) when the list was made: the full tier's.
+# Plain `pytest` runs the quick tier (`pytest.ini`), `pytest -m ""`
+# everything. Central list so the split stays visible and maintainable.
 SLOW_TESTS = {
     # fused CE kernel (interpret-mode pallas is slow on CPU)
     "test_fused_ce_token_padding",
@@ -198,168 +198,7 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: heavy multi-strategy tests (full runs only)")
     config.addinivalue_line(
-        "markers", "quick: fast tests — `pytest -m quick` < 2 min")
-
-
-#: Tests under ``tests/benchmark`` (files of the benchmark: no later PR
-#: may edit them) pin the POSITION of entries in BENCHMARK.json: the
-#: Kimi cell LAST in three ``workloads`` lists, PR 35's fourteen entries
-#: LAST in ``per_layer`` — and, since PR 39, the MiniCPM cell, its
-#: configuration and its fifteen entries LAST in their lists with the
-#: five older cells counted before it. The driver takes a new entry at
-#: the END of its list only (one put in the middle reads as an edit of
-#: what was there), so no PR that adds a serving cell can satisfy them
-#: on the file as it stands. Order means nothing to the harness
-#: (everything is found by name), so each pin is shown the manifest it
-#: was written against, made from the file's own entries: the first two
-#: with the later entries moved in front of the pinned ones
-#: (``later_entries_first``), PR 39's two — which also count the cells
-#: — as the file stood when their cell was the last (``as_of``: what
-#: was appended after it left out, nothing else touched). Every
-#: assertion of theirs runs. The file's real order, and the entries a
-#: later PR appended, are asserted by name in
-#: ``tests/benchmark/test_serve_arch_kda.py`` (ISSUE 41; the next
-#: ``benchmark`` PR should free the pins of the position).
-POSITION_PINS = (
-    "test_serve_arch_mla.py::"
-    "test_manifest_names_what_the_longdoc_cell_needs",
-    "test_iteration_account.py::"
-    "test_new_manifest_entries_match_their_readers",
-)
-PINNED_LAST_CELL = "kimi-vl-a3b-pp4.longdoc-backlog"
-PINNED_LAST_ENTRIES = ("engine_host_cpu_ms.chat", "iter_tail_host_pct.chat")
-#: the pins that count the cells: test -> the cell that was last
-AS_OF_PINS = {
-    "test_serve_arch_sala.py::"
-    "test_manifest_names_what_the_longctx_cell_needs":
-        "minicpm-sala-pp2.longdoc-32k-backlog",
-    "test_serve_arch_sala.py::"
-    "test_the_cells_before_keep_their_places_in_the_manifest":
-        "minicpm-sala-pp2.longdoc-32k-backlog",
-}
-
-
-#: ... and PR 41's own check of these views, which takes the file's
-#: last cell for the Ling cell and names what was appended behind the
-#: MiniCPM cell: it sees the file as of ITS cell (ISSUE 45; kept apart
-#: from ``AS_OF_PINS``, whose values that test asserts). What a later PR
-#: appends is asserted by name in its own test
-#: (``tests/benchmark/test_serve_arch_blocks.py``)
-AS_OF_LATER_PINS = {
-    "test_serve_arch_kda.py::"
-    "test_the_pins_are_shown_the_files_own_entries":
-        "ling-3.0-flash-vl-ep8.video-8k-backlog",
-}
-
-
-#: ... and PR 51's two, written when the Brumby cell was the last: they
-#: name every entry that lists their cell LAST of its cells and take the
-#: file for its own view as of that cell (ISSUE 55; the first is an
-#: enumerating pin as well, so it sees the file without PR 53's eleven
-#: too). What PR 55 appended behind the Brumby cell is asserted by name
-#: in ``tests/benchmark/test_serve_arch_ssm.py``
-AS_OF_BRUMBY_PINS = dict.fromkeys((
-    "test_serve_arch_retention.py::"
-    "test_manifest_names_what_the_retention_cell_needs",
-    "test_serve_arch_retention.py::"
-    "test_the_pins_still_see_the_file_as_of_their_cells",
-), "brumby-14b-pp4.repo-16k-backlog")
-
-
-#: ... and PR 53's eleven entries, which list the cells that WERE there
-#: (the process's account of every serving cell): five tests name EVERY
-#: entry that lists their cell beside another (``listed == [...]``) and
-#: ``as_of`` keeps an entry whose cells it keeps. Those tests, and the
-#: ``as_of`` pins above, see the file without the eleven — as it stood
-#: when each was written; nothing else touched. The eleven are asserted
-#: by name, LAST in the real file, in
-#: ``tests/benchmark/test_process_account.py`` (ISSUE 53).
-APPENDED_BY_PR53 = ("setup_cold_compile_s",) + tuple(
-    f"{n}{s}" for n in ("window_compile_s", "host_other_cpu_ms",
-                        "gc_pause_ms", "process_threads_peak",
-                        "idle_host_phases_ms")
-    for s in (".chat", ".backlogs"))
-ENUMERATING_PINS = (
-    "test_serve_arch_kda.py::"
-    "test_manifest_names_what_the_video_cell_needs",
-    "test_serve_arch_blocks.py::"
-    "test_manifest_names_what_the_blocks_cell_needs",
-    "test_serve_arch_retention.py::"
-    "test_manifest_names_what_the_retention_cell_needs",
-)
-
-
-def before_pr53(manifest: dict) -> dict:
-    """``manifest`` without the entries PR 53 appended to ``per_layer``;
-    what remains keeps its place and its content."""
-    return dict(manifest, per_layer=[
-        x for x in manifest["per_layer"]
-        if x["name"] not in APPENDED_BY_PR53])
-
-
-def later_entries_first(manifest: dict) -> dict:
-    """``manifest`` with what was appended after the pinned entries put
-    right before them; nothing added, dropped or changed."""
-    m = dict(manifest)
-    for kind in ("end_to_end", "per_layer"):
-        m[kind] = [dict(x) for x in m[kind]]
-        for x in m[kind]:
-            w = x.get("workloads", [])
-            if PINNED_LAST_CELL in w:
-                x["workloads"] = [c for c in w if c != PINNED_LAST_CELL] \
-                    + [PINNED_LAST_CELL]
-    names = [x["name"] for x in m["per_layer"]]
-    lo, hi = (names.index(n) for n in PINNED_LAST_ENTRIES)
-    pl = m["per_layer"]
-    m["per_layer"] = pl[:lo] + pl[hi + 1:] + pl[lo:hi + 1]
-    return m
-
-
-def as_of(manifest: dict, last_cell: str) -> dict:
-    """``manifest`` as it stood when ``last_cell`` was its last cell:
-    the cells appended after it, their configurations, their names in
-    the metrics' ``workloads`` and the metrics that list only them are
-    left out; what remains keeps its place and its content."""
-    m = dict(manifest)
-    cells = [w["name"] for w in m["workloads"]]
-    keep = set(cells[:cells.index(last_cell) + 1])
-    m["workloads"] = [w for w in m["workloads"] if w["name"] in keep]
-    used = {w["config"] for w in m["workloads"]}
-    m["configs"] = [c for c in m["configs"] if c["name"] in used]
-    for kind in ("end_to_end", "per_layer"):
-        out = []
-        for x in m[kind]:
-            if "workloads" in x:
-                w = [c for c in x["workloads"] if c in keep]
-                if not w:
-                    continue
-                x = dict(x, workloads=w)
-            out.append(x)
-        m[kind] = out
-    return m
-
-
-@pytest.fixture(autouse=True)
-def manifest_order_for_the_position_pins(request, monkeypatch):
-    node = request.node.nodeid
-    last = next((c for t, c in {**AS_OF_PINS, **AS_OF_LATER_PINS,
-                                **AS_OF_BRUMBY_PINS}.items()
-                 if node.endswith(t)), None)
-    enumerating = node.endswith(ENUMERATING_PINS)
-    if last is None and not enumerating \
-            and not node.endswith(POSITION_PINS):
-        return
-    from benchmark import harness
-    load = harness.load_manifest
-
-    def shown(path):
-        m = load(path)
-        if os.path.basename(path) != "BENCHMARK.json":
-            return m
-        if last is not None:
-            return as_of(before_pr53(m), last)
-        return before_pr53(m) if enumerating else later_entries_first(m)
-    monkeypatch.setattr(harness, "load_manifest", shown)
+        "markers", "quick: tier 1, everything not marked slow")
 
 
 def pytest_collection_modifyitems(config, items):
@@ -374,10 +213,14 @@ def pytest_collection_modifyitems(config, items):
 # -- quick-tier time-budget audit -------------------------------------------
 # The quick tier is the builder's inner loop AND the driver's tier-1
 # gate: a new test landing without a `slow` marker that takes minutes
-# silently rots the loop for everyone. Budget chosen WELL above the
-# slowest legitimate quick test (53s solo / 92s under full-suite load
-# on the 8-CPU mesh) so only genuine misplacements trip; override with
-# HETU_QUICK_TIER_BUDGET_S (0 = off).
+# silently rots the loop for everyone. The budget is about TWICE the
+# slowest legitimate quick test under the whole suite's load
+# (`tests/test_tpu_compile.py`'s Ling step, one TPU compile of a whole
+# serving step: 47 s solo, 86-102 s under the driver's command with six
+# workers on an 8-core sandbox, 68 s in the driver's own run; PR 61 —
+# the parent's slowest, ring attention's dropout case, read 113 s there
+# and 195 s here, over this budget), so only genuine misplacements
+# trip; override with HETU_QUICK_TIER_BUDGET_S (0 = off).
 QUICK_TIER_BUDGET_S = float(
     os.environ.get("HETU_QUICK_TIER_BUDGET_S", "180"))
 

@@ -13,6 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from served import ServedArchContract
 from benchmark.reference import cohere2_moe as reference
 from hetu_tpu.models import Cohere2MoEConfig, Cohere2MoEForCausalLM
 from hetu_tpu.models.generation import decode, init_paged_caches
@@ -25,13 +26,13 @@ from hetu_tpu.ops.rotary import apply_rotary, rope_frequencies
 ATOL = 1e-4
 
 
-def _config(cfg) -> dict:
-    """The dataclass as the published keys the reference reads."""
-    return dataclasses.asdict(cfg)
+VOCAB = Cohere2MoEConfig.tiny().vocab_size
 
 
 @pytest.fixture(scope="module")
 def tiny():
+    """The dataclass as the published keys the reference reads, the
+    model, its weights."""
     cfg = Cohere2MoEConfig.tiny()
     model = Cohere2MoEForCausalLM(cfg)
     # init_std 0.02 at hidden 64 would leave every logit ~1e-3 and the
@@ -39,20 +40,41 @@ def tiny():
     params = jax.tree.map(
         lambda x: x * 8.0 if x.ndim > 2 or x.shape[0] > 64 else x,
         model.init(jax.random.key(0), dtype=jnp.float32))
-    return cfg, model, params
+    return dataclasses.asdict(cfg), model, params
 
 
-def test_whole_sequence_logits_match_the_reference(tiny):
+class TestCohere2MoE(ServedArchContract):
     """(a) ``model(params, ids)`` — the window through the reference
     attention path, RoPE or none by layer, the expert layer, the shared
-    mean, the parallel block — against the plain forward."""
-    cfg, model, params = tiny
-    ids = np.random.default_rng(0).integers(1, cfg.vocab_size, (2, 24))
-    got = np.asarray(model(params, jnp.asarray(ids)))
-    want = np.asarray(reference.logits(params, ids, _config(cfg),
-                                       attn_block=8))
-    assert np.abs(want).max() > 0.1          # the comparison has teeth
-    np.testing.assert_allclose(got, want, atol=ATOL)
+    mean, the parallel block — against the plain forward. (b) through
+    ``ServingEngine``: packed prefill in chunks of 8 beside decode, one
+    trace; every emitted token within 1e-3 (float32 both sides; a tie
+    closer than that may break either way) of the reference's top logit
+    at its position, teacher-forced."""
+    reference = reference
+    forward_ids = jnp.asarray(
+        np.random.default_rng(0).integers(1, VOCAB, (2, 24)))
+    tol = ATOL
+    lanes = [dict(attn_kernel="paged", prefill_attn="flash_pallas"),
+             dict(attn_kernel="reference", prefill_attn="reference")]
+    engine = dict(slots=3, max_len=32, prefill_chunk=8, block_size=4)
+    prompts = (3, 1, VOCAB, (5, 13, 19))
+    max_tokens = 10
+    token_tol = 1e-3
+
+    def one_sequence(self, tiny, ids, **control):
+        """One sequence, zeros behind it up to the engine's ``max_len``
+        where it is no whole number of the reference's attention blocks
+        of 8."""
+        config, _, params = tiny
+        pad = 0 if len(ids) % 8 == 0 else 32 - len(ids)
+        seq = jnp.pad(jnp.asarray(ids), (0, pad))[None]
+        return reference.logits(params, seq, config,
+                                attn_block=8)[0, :len(ids)]
+
+    def close(self, got, want, atol):
+        assert np.abs(want).max() > 0.1      # the comparison has teeth
+        super().close(got, want, atol)
 
 
 @pytest.mark.parametrize("kernel", ["paged", "reference"])
@@ -65,12 +87,10 @@ def test_chunked_prefill_then_decode_through_the_paged_cache(
     forward. Contexts run to 24 with window 8: the window's lower edge
     ``p - 8`` falls inside a page and on a page boundary for both block
     sizes, and pages wholly below it are skipped by the kernel."""
-    cfg, model, params = tiny
+    config, model, params = tiny
     total, chunk, n_req = 24, 6, 2
-    ids = np.random.default_rng(1).integers(1, cfg.vocab_size,
-                                            (n_req, total))
-    want = np.asarray(reference.logits(params, ids, _config(cfg),
-                                       attn_block=8))
+    ids = np.random.default_rng(1).integers(1, VOCAB, (n_req, total))
+    want = np.asarray(reference.logits(params, ids, config, attn_block=8))
     per = total // block_size
     caches = init_paged_caches(model, 1 + n_req * per, block_size)
     # scattered pages, so that a table lookup is really needed
@@ -91,35 +111,6 @@ def test_chunked_prefill_then_decode_through_the_paged_cache(
                                atol=ATOL)
 
 
-@pytest.mark.parametrize("lanes", [
-    dict(attn_kernel="paged", prefill_attn="flash_pallas"),
-    dict(attn_kernel="reference", prefill_attn="reference")])
-def test_engine_serves_it_through_the_one_fused_step(tiny, lanes):
-    """(b) through ``ServingEngine``: packed prefill in chunks of 8
-    beside decode, one trace; every emitted token within 1e-3 (float32
-    both sides; a tie closer than that may break either way) of the
-    reference's top logit at its position, teacher-forced."""
-    from hetu_tpu.engine import trace_counts
-    from hetu_tpu.serving import SamplingParams, ServingEngine
-    cfg, model, params = tiny
-    rng = np.random.default_rng(3)
-    prompts = [rng.integers(1, cfg.vocab_size, n).tolist()
-               for n in (5, 13, 19)]
-    before = trace_counts().get("serving_step", 0)
-    eng = ServingEngine(model, params, slots=3, max_len=32,
-                        prefill_chunk=8, block_size=4, **lanes)
-    outs = eng.generate_many(prompts, SamplingParams(max_tokens=10))
-    assert trace_counts().get("serving_step", 0) - before == 1
-    for prompt, out in zip(prompts, outs):
-        seq = np.asarray([prompt + out + [0] * (32 - len(prompt)
-                                                - len(out))])
-        lg = np.asarray(reference.logits(params, seq, _config(cfg),
-                                         attn_block=8))[0]
-        rows = lg[len(prompt) - 1:len(prompt) - 1 + len(out)]
-        gap = rows.max(-1) - rows[np.arange(len(out)), out]
-        assert gap.max() <= 1e-3, (len(prompt), gap)
-
-
 def test_engine_counts_the_share_and_the_dead_window_blocks(tiny):
     """With telemetry on, the engine counts the expert layer's (token,
     choice) pairs from what the fused step RETURNS (each lane's group
@@ -130,7 +121,8 @@ def test_engine_counts_the_share_and_the_dead_window_blocks(tiny):
     window."""
     from hetu_tpu import telemetry
     from hetu_tpu.serving import SamplingParams, ServingEngine
-    cfg, model, params = tiny
+    _, model, params = tiny
+    cfg = model.cfg
     share = Cohere2MoEForCausalLM(
         dataclasses.replace(cfg, local_experts=(4, 8)))
     held = jax.tree.map(lambda x: x, params)
@@ -192,10 +184,11 @@ def test_shares_add_up_to_the_uncut_layer(tiny):
     layer, plus the shared mean counted once, equal the uncut
     reference's layer output — to float32 rounding (1e-5 on outputs of
     magnitude ~1: nothing here is approximate)."""
-    cfg, model, params = tiny
+    config, model, params = tiny
+    cfg = model.cfg
     blk = jax.tree.map(lambda x: x[0], params["blocks"])
     u = jax.random.normal(jax.random.key(4), (37, cfg.hidden_size))
-    whole, _ = reference.ffn(blk, u, _config(cfg))
+    whole, _ = reference.ffn(blk, u, config)
     total = jnp.zeros_like(u)
     for first in range(0, cfg.num_experts, 2):
         share = ExpertShareMoE(
@@ -206,17 +199,17 @@ def test_shares_add_up_to_the_uncut_layer(tiny):
                    for n in ("wg", "wi", "wo")}}
         total = total + share(held, u)
         # and the reference, given the same share, says the same
-        part, _ = reference.ffn({**blk, "moe": held}, u, _config(cfg),
+        part, _ = reference.ffn({**blk, "moe": held}, u, config,
                                 local_experts=(first, 2))
         only_shared, _ = reference.ffn(
             {**blk, "moe": {**held, "wo": held["wo"] * 0}}, u,
-            _config(cfg), local_experts=(first, 2))
+            config, local_experts=(first, 2))
         np.testing.assert_allclose(
             np.asarray(share(held, u)),
             np.asarray(part - only_shared), atol=1e-5)
     shared_mean, _ = reference.ffn(
         {**blk, "moe": {**blk["moe"], "wo": blk["moe"]["wo"] * 0}}, u,
-        _config(cfg))
+        config)
     assert float(jnp.abs(whole).max()) > 0.1
     np.testing.assert_allclose(np.asarray(total + shared_mean),
                                np.asarray(whole), atol=1e-5)

@@ -85,3 +85,58 @@ def test_named_paths_exist(doc):
             missing.add(shown)
     assert not missing, f"{doc} names files that are not in the tree: " \
                         f"{sorted(missing)}"
+
+
+# -- the test tree: what two test modules share lives in tests/served.py ------
+
+def _test_tree():
+    """Python files under ``tests/`` outside ``tests/benchmark/`` (the
+    benchmark's own) -> ``(path from tests/, its syntax tree)``."""
+    import ast
+    tests = os.path.join(ROOT, "tests")
+    for top, dirs, files in os.walk(tests):
+        dirs[:] = [d for d in dirs if d != "benchmark" and d[0] not in "._"]
+        for name in sorted(files):
+            if name.endswith(".py"):
+                path = os.path.join(top, name)
+                with open(path) as f:
+                    yield os.path.relpath(path, tests), ast.parse(f.read())
+
+
+def test_no_test_module_imports_a_test_module():
+    """A helper two files need is in ``tests/served.py``: a test module
+    imported for its helpers is collected twice over (its module
+    fixtures and its parametrised tables built again) and ties one
+    architecture's file to another's."""
+    import ast
+    sideways = []
+    for path, tree in _test_tree():
+        for node in ast.walk(tree):
+            names = [a.name for a in node.names] \
+                if isinstance(node, ast.Import) else \
+                [node.module or ""] if isinstance(node, ast.ImportFrom) \
+                else []
+            sideways += [(path, n) for n in names
+                         if n.split(".")[-1].startswith("test_")]
+    assert not sideways, sideways
+
+
+def test_the_contract_runs_only_through_its_subclasses():
+    """``tests/served.py`` defines no ``test_*`` function and no class
+    pytest would take for a test class (``python_classes`` is the
+    default, ``Test*``): the contract's methods run where an
+    architecture's file subclasses them, and nowhere else."""
+    import ast
+    (tree,) = [t for p, t in _test_tree() if p == "served.py"]
+    tests = [n.name for n in tree.body
+             if isinstance(n, (ast.FunctionDef, ast.ClassDef))
+             and n.name.lower().startswith("test")]
+    assert tests == []
+    with open(os.path.join(ROOT, "pytest.ini")) as f:
+        ini = f.read()
+    assert "python_classes" not in ini and "python_files" not in ini
+    contract = [n for n in tree.body if isinstance(n, ast.ClassDef)
+                and n.name == "ServedArchContract"]
+    assert contract and any(
+        isinstance(n, ast.FunctionDef) and n.name.startswith("test_")
+        for n in contract[0].body)

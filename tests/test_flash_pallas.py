@@ -93,37 +93,20 @@ def test_dispatch_pallas_importable(rng):
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-def test_tuned_block_defaults_lookup():
-    """_default_blocks consults flash_tune winners (exact q-seq match
-    whose blocks divide both lengths) and falls back to _pick_block."""
+@pytest.mark.parametrize("sq,sk,kind", [
+    (1024, 1024, "fwd"), (2048, 2048, "fwd"), (4096, 4096, "fwd"),
+    (1024, 384, "fwd"),                # a ring hop with ragged kv
+    (1024, 1024, "dq"), (1024, 1024, "dkv")])
+def test_default_blocks_are_the_static_rule(sq, sk, kind):
+    """No measured table overrides it: the largest of 1024 / 512 / 256 /
+    128 that divides each length (the whole length where none does), for
+    all three kernels — GPT-2's packed-1k rows are ONE tile a head."""
     from hetu_tpu.ops import flash_pallas as fp
-
-    entries = (
-        tuple(sorted({"seq": 1024, "fwd": [256, 512],
-                      "bwd": [512, 256]}.items())),
-        tuple(sorted({"seq": 4096, "fwd": [512, 1024],
-                      "bwd": [1024, 512]}.items())),
-    )
-    orig = fp._tuned_entries
-    fp._tuned_entries = lambda: entries
-    try:
-        assert fp._default_blocks(1024, 1024, "fwd") == (256, 512)
-        assert fp._default_blocks(1024, 1024, "bwd") == (512, 256)
-        assert fp._default_blocks(4096, 4096, "fwd") == (512, 1024)
-        # unmeasured seq -> static heuristic
-        assert fp._default_blocks(2048, 2048, "fwd") == \
-            (fp._pick_block(2048, fp._TILE), fp._pick_block(2048, fp._TILE))
-        # measured q-seq but kv length the tuned block doesn't divide
-        # (ring hop with ragged kv) -> fallback
-        assert fp._default_blocks(1024, 384, "fwd") == \
-            (fp._pick_block(1024, fp._TILE), fp._pick_block(384, fp._TILE))
-    finally:
-        fp._tuned_entries = orig
-
-
-def test_tuned_entries_absent_on_cpu():
-    from hetu_tpu.ops import flash_pallas as fp
-    assert fp._tuned_entries() == ()
+    bq, bk = fp._default_blocks(sq, sk, kind)
+    assert (bq, bk) == (fp._pick_block(sq, fp._TILE),
+                        fp._pick_block(sk, fp._TILE))
+    assert sq % bq == 0 and sk % bk == 0
+    assert bq == min(sq, 1024) and bk == {384: 128}.get(sk, min(sk, 1024))
 
 
 def _drop_oracle_mask(key, b, h, sq, sk, rate):

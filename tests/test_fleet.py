@@ -28,7 +28,8 @@ from hetu_tpu.serving.fleet import (
 )
 from hetu_tpu.serving.kv_pool import SpillEntry
 from hetu_tpu.serving.router import Router
-from hetu_tpu.serving.scheduler import Request, SamplingParams
+from hetu_tpu.serving.scheduler import SamplingParams
+from served import StubEngine
 
 @pytest.fixture()
 def tele():
@@ -49,84 +50,6 @@ def _free_port():
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
-
-
-# -- stub engine: the full duck type, host-side, zero compiles ---------------
-
-
-class _StubEngine:
-    """Echo engine behind a real coordinator: a submitted request
-    completes with ``prompt[:max_tokens]`` after ``delay_s`` (a worker
-    thread plays the decode loop). Speaks everything the serving verbs
-    and the RemoteEngineProxy touch."""
-
-    def __init__(self, delay_s: float = 0.0):
-        self.delay_s = delay_s
-        self.weight_version = 0
-        self.submits = 0
-        self._next = 0
-        self._requests_by_id: dict[int, Request] = {}
-        self._lock = threading.Lock()
-
-        class _Sched:
-            depth = 0
-            occupancy = 0.0
-        self.scheduler = _Sched()
-
-    @property
-    def load(self):
-        return sum(1 for r in self._requests_by_id.values()
-                   if not r.done.is_set())
-
-    def has_work(self):
-        return self.load > 0
-
-    def submit(self, prompt, sampling=None, *, resume=None,
-               handoff=False, traceparent=None):
-        sampling = sampling or SamplingParams()
-        with self._lock:
-            req = Request(id=self._next,
-                          prompt=np.asarray(prompt, np.int32).ravel(),
-                          sampling=sampling, submit_s=time.monotonic())
-            self._next += 1
-            self.submits += 1
-        if traceparent:
-            tid, _span = telemetry.parse_traceparent(traceparent)
-            if tid:
-                req.trace_id = tid
-                req.traceparent = traceparent
-        if resume is not None:
-            req.spill = resume
-            req.tokens = list(resume.tokens)
-
-        def finish():
-            if self.delay_s:
-                time.sleep(self.delay_s)
-            req.tokens = [int(t) for t in
-                          req.prompt[:sampling.max_tokens]]
-            req.status = "done"
-            req.first_token_s = time.monotonic()
-            req.done.set()
-
-        threading.Thread(target=finish, daemon=True).start()
-        return req
-
-    def result(self, req, timeout=None):
-        if not req.done.wait(timeout):
-            return None
-        return req.result()
-
-    def cancel_queued(self, ids=None):
-        return []
-
-    def evict_request(self, req, *, lock_timeout_s=None):
-        return None
-
-    def start(self):
-        pass
-
-    def stop(self):
-        pass
 
 
 def _serve_stub(stub):
@@ -185,7 +108,7 @@ def test_submit_idempotency_dedups_duplicate_delivery():
     """SATELLITE: two SUBMIT deliveries with one key = ONE queued
     request, same id returned — the retry-after-response-timeout
     scenario, replayed deliberately."""
-    stub = _StubEngine()
+    stub = StubEngine()
     srv, port = _serve_stub(stub)
     try:
         cli = CoordinatorClient(port, timeout=5.0)
@@ -208,7 +131,7 @@ def test_submit_idempotency_dedups_duplicate_delivery():
 
 
 def test_generate_idempotency_joins_original():
-    stub = _StubEngine(delay_s=0.05)
+    stub = StubEngine(delay_s=0.05)
     srv, port = _serve_stub(stub)
     try:
         cli1 = CoordinatorClient(port, timeout=10.0)
@@ -274,8 +197,8 @@ def test_remote_handle_lifecycle_stale_dead_requeue(tele):
     """SATELLITE: register → serve → heartbeat-stale → dead → the
     in-flight request requeues onto a live peer and completes exactly
     once. Stub engines, real sockets, no compiles."""
-    slow = _StubEngine(delay_s=30.0)         # never finishes in time
-    fast = _StubEngine()
+    slow = StubEngine(delay_s=30.0)         # never finishes in time
+    fast = StubEngine()
     srv_slow, port_slow = _serve_stub(slow)
     srv_fast, port_fast = _serve_stub(fast)
     router = Router(poll_s=0.005, beat_timeout_s=0.3)
@@ -324,7 +247,7 @@ def test_publisher_transport_guards():
         WeightPublisher(router, transport="carrier_pigeon")
     with pytest.raises(ValueError, match="ckpt_dir"):
         WeightPublisher(router, transport="dist_ckpt")
-    stub = _StubEngine()
+    stub = StubEngine()
     srv, port = _serve_stub(stub)
     try:
         router.register("s0", RemoteEngineProxy(port, poll_s=0.02))

@@ -11,26 +11,24 @@ counters; what is refused over a slot state is refused by name."""
 
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-from benchmark.reference import jamba as reference  # noqa: E402
-from benchmark.runners.serve_arch import load_arch  # noqa: E402
-from hetu_tpu.models import generation  # noqa: E402
-from hetu_tpu.nn.parallel import (  # noqa: E402
+import served
+from served import REFUSED, ROOT, ServedArchContract
+from served import pack_slices as _pack
+from benchmark.reference import jamba as reference
+from benchmark.runners.serve_arch import load_arch
+from hetu_tpu import telemetry
+from hetu_tpu.models import generation
+from hetu_tpu.nn.parallel import (
     MambaMixer, ParallelAttention, SlotStateNotSupported,
 )
-from hetu_tpu.ops import selective_scan as S  # noqa: E402
-from hetu_tpu.ops import selective_scan_pallas as P  # noqa: E402
-from test_minicpm_sala import REFUSED, _serve_logits  # noqa: E402
+from hetu_tpu.ops import selective_scan as S
+from hetu_tpu.ops import selective_scan_pallas as P
 
 D, N = 256, 4
 
@@ -45,21 +43,6 @@ def _draw(T, seed=0):
 
 
 A = -jnp.exp(0.5 * jax.random.normal(jax.random.key(9), (N, D)))
-
-
-def _pack(parts, C):
-    """``parts``: ``(slot, (first, behind), sequence)`` runs in pack
-    order -> ``((x, dt, B, C), (slot, pos, valid))`` of ``C`` rows."""
-    ops = [jnp.concatenate([s[i][a:b] for _, (a, b), s in parts])
-           for i in range(4)]
-    n = ops[0].shape[0]
-    ops = [jnp.pad(x, ((0, C - n), (0, 0))) for x in ops]
-    slot = sum(([s] * (b - a) for s, (a, b), _ in parts), [])
-    pos = sum((list(range(a, b)) for _, (a, b), _ in parts), [])
-    return tuple(ops), (
-        jnp.asarray(slot + [0] * (C - n), jnp.int32),
-        jnp.asarray(pos + [0] * (C - n), jnp.int32),
-        jnp.asarray([True] * n + [False] * (C - n)))
 
 
 def _forms(form):
@@ -220,14 +203,15 @@ def test_mixer_cached_equals_its_whole_sequence_forward():
     want = mixer(params, x)
     caches, out = (state, tail), []
     layer = jnp.int32(1)
-    for lo, hi in ((0, 8), (8, 20)):
+    packed = jax.jit(lambda rows, pos, caches, pack: mixer(
+        params, rows, positions=pos, kv_cache=(caches, layer), pack=pack))
+    for lo, hi in ((0, 8), (8, 20)):       # ONE program, two packs
         n = hi - lo
         rows = jnp.pad(x[:, lo:hi], ((0, 0), (0, 12 - n), (0, 0)))
         pos = jnp.pad(jnp.arange(lo, hi), (0, 12 - n))[None]
-        o, caches, st = mixer(
-            params, rows, positions=pos, kv_cache=(caches, layer),
-            pack={"valid": jnp.arange(12) < n,
-                  "slot": jnp.full((12,), 2, jnp.int32)})
+        o, caches, st = packed(
+            rows, pos, caches, {"valid": jnp.arange(12) < n,
+                                "slot": jnp.full((12,), 2, jnp.int32)})
         out.append(o[0, :n])
         assert st["ssm_steps"].shape == (4,)
         assert st["ssm_steps"][2:].tolist() == [0, 0]
@@ -289,11 +273,80 @@ def test_attention_without_positions_paged_equals_the_reference():
 # -- the model -----------------------------------------------------------------
 @pytest.fixture(scope="module")
 def tiny():
-    with open(os.path.join(ROOT, "tests", "benchmark", "configs",
-                           "jamba-tiny.json")) as f:
-        config = json.load(f)
-    model = load_arch("jamba").build(config)
-    return config, model, model.init(jax.random.key(55))
+    return served.tiny("jamba", 55)
+
+
+def _ssm_counters():
+    reg = telemetry.get_registry()
+    c = reg.counter("ssm_scan_steps_total")
+    u = reg.counter("ssm_update_slots_total")
+    return [c.value(kind=k) for k in ("live", "computed")] + [
+        u.value(kind=k) for k in ("live", "stepped")]
+
+
+class TestJamba(ServedArchContract):
+    """Float32 both sides: the program (the oracle recurrence, the
+    kernels interpreted) against the reference's token loop differs by
+    rounding alone — 1e-3 on logits that span +-12 at the tiny
+    configuration's init_std of 0.3 (1e-4 of their size, over 14
+    layers). The chunks cut the convolution's window and the scan's
+    pieces: a reused slot's state AND its tail start from zeros.
+    ``paged_kernels``: the flash prefill and BOTH paged calls (the
+    decode rows' and the pack's history tiles) interpreted, at ONE kv
+    head."""
+    reference = reference
+    forward_ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
+    tol = token_tol = 1e-3
+    controls = [{"reset_every": 8}, {"drop_tail_every": 8},
+                {"no_inner_norms": True}, {"no_skip": True},
+                {"operands": jnp.float8_e4m3fn},
+                {"state_dtype": jnp.float8_e4m3fn}]
+    control_ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
+    control_from, control_moves = 16, 0.3
+    chunks = (10, 7, 8)
+    requests = (55, 1, 128, ((0, 23, 3), (1, 14, 4), (0, 17, 3)))
+    lanes = [dict(), dict(attn_kernel="paged", prefill_attn="flash_pallas")]
+    counters = staticmethod(_ssm_counters)
+    refused = REFUSED
+    page_movers = tuple(served.PAGE_MOVERS)
+    refuses_the_dense_cache = True
+    new_modules = ("hetu_tpu.models.jamba", "hetu_tpu.ops.selective_scan",
+                   "hetu_tpu.ops.selective_scan_pallas")
+
+    def engine_served(self, eng, model, lanes, counted):
+        """The counters count what the kernels walked."""
+        assert eng.prefix_cache is None and eng.preempt is False
+        assert len(eng.pool.caches) == 4
+        assert eng.pool.nbytes() == sum(c.nbytes for c in eng.pool.caches)
+        live, computed, advanced, stepped = counted
+        layers = model.blocks.layers_of["mamba"]
+        assert 0 < live <= computed and live % layers == 0
+        # five requests x five decode rows each (the first token is the
+        # prefill's), every one a live slot of the three
+        assert advanced == 5 * 5 * layers
+        assert stepped % (3 * layers) == 0 and stepped >= advanced
+        assert telemetry.get_registry().gauge("kv_state_bytes").value(
+            kind="slot") == model.blocks.cache_bytes(4)["state"]["slot"]
+
+    def test_dense_cache_and_cp_prefill_refuse_by_name(self, tiny):
+        """... and the mixer's own refusals, and the configuration's."""
+        from hetu_tpu.models.jamba import JambaConfig
+        super().test_dense_cache_and_cp_prefill_refuse_by_name(tiny)
+        _, model, params = tiny
+        mixer = model.blocks._runs[0].block.attn
+        with pytest.raises(SlotStateNotSupported, match="return_kv"):
+            mixer(jax.tree.map(lambda x: x[0],
+                               params["blocks"]["runs"]["0"]["attn"]),
+                  jnp.zeros((1, 4, 64)), return_kv=True)
+        with pytest.raises(SlotStateNotSupported, match="verify lane"):
+            mixer(jax.tree.map(lambda x: x[0],
+                               params["blocks"]["runs"]["0"]["attn"]),
+                  jnp.zeros((2, 3, 64)), positions=jnp.zeros((2, 3)),
+                  kv_cache=((None, None), 0), slot_mask=jnp.ones(2, bool))
+        with pytest.raises(NotImplementedError, match="num_experts=16"):
+            JambaConfig(num_experts=16)
+        with pytest.raises(ValueError, match="at least one attention"):
+            JambaConfig(num_hidden_layers=6)
 
 
 def test_caches_are_an_arena_of_two_leaves_beside_two_slot_leaves(tiny):
@@ -314,159 +367,6 @@ def test_caches_are_an_arena_of_two_leaves_beside_two_slot_leaves(tiny):
     # the attention speaks for the arena: one kv head
     assert model.blocks.block.attn.num_kv_heads == 1
     assert "lm_head" not in model.init(jax.random.key(0))
-
-
-def test_model_matches_the_reference(tiny):
-    """Float32 both sides: the program's whole-sequence forward (the
-    oracle recurrence) against the reference's token loop differ by
-    rounding alone — 1e-3 on logits that span +-12 at the tiny
-    configuration's init_std of 0.3 (1e-4 of their size, over 14
-    layers)."""
-    config, model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
-    got = model(params, ids)
-    for b in range(2):
-        want = reference.logits(params, ids[b], config)
-        np.testing.assert_allclose(got[b], want, atol=1e-3)
-
-
-CONTROLS = [{"reset_every": 8}, {"drop_tail_every": 8},
-            {"no_inner_norms": True}, {"no_skip": True},
-            {"operands": jnp.float8_e4m3fn},
-            {"state_dtype": jnp.float8_e4m3fn}]
-
-
-@pytest.mark.parametrize("control", CONTROLS,
-                         ids=[next(iter(c)) for c in CONTROLS])
-def test_each_planted_control_moves_the_reference(tiny, control):
-    config, _, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
-    base = reference.logits(params, ids, config)
-    moved = reference.logits(params, ids, config, **control)
-    assert float(jnp.abs(moved - base)[16:].max()) > 0.3, control
-
-
-@pytest.mark.parametrize("chunk", [10, 7, 8])
-def test_chunked_prefill_then_decode_equals_one_forward_pass(tiny, chunk):
-    """Logits, not tokens: two slots of different lengths in one pack,
-    chunks that cut the convolution's window and the scan's pieces, and
-    slot 0 REUSED by a third request — its state AND its tail must
-    start from zeros, its pages be its own. Float32 both sides: rounding
-    alone (1e-3 on logits of +-12, as above)."""
-    config, model, params = tiny
-    rng = np.random.default_rng(55)
-    reqs = [(0, rng.integers(1, 128, 23), 3),
-            (1, rng.integers(1, 128, 14), 4),
-            (0, rng.integers(1, 128, 17), 3)]
-    got = _serve_logits(
-        model, {**params, "lm_head": params["wte"]}, reqs, slots=2,
-        chunk=chunk, block_size=4, n_blocks=24, max_len=32)
-    for i, (_, ids, _) in enumerate(reqs):
-        want = reference.logits(params, jnp.asarray(ids), config)
-        np.testing.assert_allclose(got[i], want, atol=1e-3)
-
-
-@pytest.mark.parametrize("lanes", [
-    dict(), dict(attn_kernel="paged", prefill_attn="flash_pallas")],
-    ids=["reference_lanes", "paged_kernels"])
-def test_engine_serves_tokens_the_reference_puts_on_top(tiny, lanes):
-    """The real engine — scheduler, fused step, one executable — over
-    five requests through three slots: every emitted token is the
-    reference's top token (float32: within rounding of it, 1e-3 on
-    logits of +-12); the counters count what the kernels walked.
-    ``paged_kernels``: the flash prefill and BOTH paged calls (the
-    decode rows' and the pack's history tiles) interpreted, at ONE kv
-    head."""
-    from hetu_tpu import telemetry
-    from hetu_tpu.serving import SamplingParams, ServingEngine
-    config, model, params = tiny
-    telemetry.enable(True)
-    try:
-        reg = telemetry.get_registry()
-
-        def read():
-            c = reg.counter("ssm_scan_steps_total")
-            u = reg.counter("ssm_update_slots_total")
-            return [c.value(kind=k) for k in ("live", "computed")] + [
-                u.value(kind=k) for k in ("live", "stepped")]
-        before = read()
-        eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                            block_size=4, slots=3, kv_blocks=40, seed=0,
-                            **lanes)
-        assert eng.prefix_cache is None and eng.preempt is False
-        assert len(eng.pool.caches) == 4
-        assert eng.pool.nbytes() == sum(c.nbytes for c in eng.pool.caches)
-        rng = np.random.default_rng(7)
-        prompts = [rng.integers(1, 128, n).tolist()
-                   for n in (21, 13, 30, 23, 7)]
-        outs = eng.generate_many(prompts, SamplingParams(max_tokens=6))
-        live, computed, advanced, stepped = (
-            a - b for a, b in zip(read(), before))
-    finally:
-        telemetry.enable(False)
-    assert eng.step_executables() == 1
-    for p, toks in zip(prompts, outs):
-        lg = np.asarray(reference.logits(
-            params, jnp.asarray(p + toks), config))[len(p) - 1:-1]
-        gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
-        assert gap.max() <= 1e-3, (len(p), gap)
-    layers = model.blocks.layers_of["mamba"]
-    assert 0 < live <= computed and live % layers == 0
-    # five requests x five decode rows each (the first token is the
-    # prefill's), every one a live slot of the three
-    assert advanced == 5 * 5 * layers
-    assert stepped % (3 * layers) == 0 and stepped >= advanced
-    assert reg.gauge("kv_state_bytes").value(kind="slot") == \
-        model.blocks.cache_bytes(4)["state"]["slot"]
-
-
-# -- what is refused, by name --------------------------------------------------
-@pytest.mark.parametrize("name,kw", REFUSED, ids=[n for n, _ in REFUSED])
-def test_what_assumes_block_kv_refuses_at_construction_by_name(
-        tiny, name, kw):
-    from hetu_tpu.serving import ServingEngine
-    _, model, params = tiny
-    with pytest.raises(SlotStateNotSupported, match=name):
-        ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                      block_size=4, slots=2, kv_blocks=40, **kw)
-
-
-@pytest.mark.parametrize("call", [
-    "export_prefix", "import_prefix", "configure_replication",
-    "evict_request", "prefill_only"])
-def test_what_moves_a_requests_pages_refuses_by_name(tiny, call):
-    from hetu_tpu.serving import ServingEngine
-    _, model, params = tiny
-    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                        block_size=4, slots=2, kv_blocks=40)
-    args = {"export_prefix": ([1, 2, 3],), "import_prefix": (None,),
-            "configure_replication": (lambda d: None,),
-            "evict_request": (0,), "prefill_only": ([1, 2, 3],)}[call]
-    with pytest.raises(SlotStateNotSupported):
-        getattr(eng, call)(*args)
-
-
-def test_dense_cache_cp_prefill_and_experts_refuse_by_name(tiny):
-    from hetu_tpu.models.jamba import JambaConfig
-    _, model, params = tiny
-    with pytest.raises(SlotStateNotSupported, match="dense cache"):
-        generation.init_kv_caches(model, 1, 16)
-    with pytest.raises(SlotStateNotSupported, match="CP-prefill"):
-        model.blocks.prefill(params["blocks"], None)
-    mixer = model.blocks._runs[0].block.attn
-    with pytest.raises(SlotStateNotSupported, match="return_kv"):
-        mixer(jax.tree.map(lambda x: x[0],
-                           params["blocks"]["runs"]["0"]["attn"]),
-              jnp.zeros((1, 4, 64)), return_kv=True)
-    with pytest.raises(SlotStateNotSupported, match="verify lane"):
-        mixer(jax.tree.map(lambda x: x[0],
-                           params["blocks"]["runs"]["0"]["attn"]),
-              jnp.zeros((2, 3, 64)), positions=jnp.zeros((2, 3)),
-              kv_cache=((None, None), 0), slot_mask=jnp.ones(2, bool))
-    with pytest.raises(NotImplementedError, match="num_experts=16"):
-        JambaConfig(num_experts=16)
-    with pytest.raises(ValueError, match="at least one attention"):
-        JambaConfig(num_hidden_layers=6)
 
 
 def test_published_widths_and_what_a_slot_holds():
@@ -494,16 +394,3 @@ def test_published_widths_and_what_a_slot_holds():
     assert c["sizes"]["model_slot_bytes"] == 10117120
     assert sum(got["row"].values()) >= c["sizes"]["cache_bytes_a_token"]
     assert c["sizes"]["cache_bytes_a_token"] == 1024
-
-
-def test_importing_the_package_loads_none_of_the_new_modules():
-    import subprocess
-    code = ("import sys, hetu_tpu, hetu_tpu.serving, hetu_tpu.models; "
-            "bad = [m for m in ('hetu_tpu.models.jamba', "
-            "'hetu_tpu.ops.selective_scan', "
-            "'hetu_tpu.ops.selective_scan_pallas') "
-            "if m in sys.modules]; print(bad); sys.exit(bool(bad))")
-    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
-                       capture_output=True, text=True)
-    assert r.returncode == 0, r.stdout + r.stderr

@@ -8,74 +8,25 @@ against the reference's and against the ungrouped one to the bit; the
 eight shares of an expert layer against the uncut layer; the refusals,
 by name."""
 
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-from benchmark.reference import kda_mla_moe as reference  # noqa: E402
-from benchmark.runners.serve_arch import load_arch  # noqa: E402
-from hetu_tpu.models import generation  # noqa: E402
-from hetu_tpu.nn.moe import ExpertShareMoE  # noqa: E402
-from hetu_tpu.nn.parallel import SlotStateNotSupported  # noqa: E402
-from hetu_tpu.ops import kda  # noqa: E402
-import test_minicpm_sala as sala_tests  # noqa: E402
-from test_minicpm_sala import REFUSED, _serve_logits  # noqa: E402
-
-H, D = 2, 16
+import served
+from served import KDA_D as D, KDA_H as H, REFUSED, ServedArchContract
+from served import kda_draw as _draw, kda_pack as _pack
+from benchmark.reference import kda_mla_moe as reference
+from hetu_tpu import telemetry
+from hetu_tpu.models import generation
+from hetu_tpu.nn.moe import ExpertShareMoE
+from hetu_tpu.nn.parallel import LatentKVNotSupported
+from hetu_tpu.ops import kda
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    with open(os.path.join(ROOT, "tests", "benchmark", "configs",
-                           "ling-tiny.json")) as f:
-        config = json.load(f)
-    model = load_arch("kda_mla_moe").build(config)
-    return config, model, model.init(jax.random.key(41))
-
-
-def _draw(key, T, at_bound=False):
-    """Operands as the mixer makes them: unit q, k; g in (-5, 0)."""
-    ks = jax.random.split(key, 5)
-    q, k, v = (jax.random.normal(ks[i], (T, H, D)) for i in range(3))
-    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) * D ** -0.5
-    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
-    g = jnp.full((T, H, D), -5.0) if at_bound else \
-        -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (T, H, D)))
-    return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
-
-
-def _pack(runs, C, slots, key):
-    """``runs``: ``(slot, first position, tokens)`` in pack order."""
-    state0 = jax.random.normal(jax.random.fold_in(key, 99),
-                               (slots, H, D, D))
-    slot, pos = np.zeros(C, np.int32), np.zeros(C, np.int32)
-    valid = np.zeros(C, bool)
-    parts, want_o, want_s, used = [], [], np.array(state0), 0
-    for i, (s, p0, n) in enumerate(runs):
-        x = _draw(jax.random.fold_in(key, i), n)
-        parts.append(x)
-        slot[used:used + n], valid[used:used + n] = s, True
-        pos[used:used + n] = np.arange(p0, p0 + n)
-        o, st = kda.kda_recurrence(*x,
-                                   state=state0[s] if p0 else None)
-        want_o.append(o)
-        want_s[s] = st
-        used += n
-    # the pad lanes hold garbage, not zeros
-    ops = [jnp.concatenate([p[j] for p in parts] + [jnp.full(
-        (C - used,) + parts[0][j].shape[1:], 7.0)]) for j in range(5)]
-    return ops, state0, (jnp.asarray(slot), jnp.asarray(pos),
-                         jnp.asarray(valid)), \
-        jnp.concatenate(want_o), want_s, used
+    return served.tiny("ling", 41)
 
 
 @pytest.mark.parametrize("runs,C,layer", [
@@ -196,98 +147,59 @@ def test_caches_are_a_latent_arena_beside_two_slot_leaves(tiny):
     assert model.blocks.dense[0].attn.kv_leaf_shapes() == ()
 
 
-CONTROLS = [{"no_erase": True}, {"no_conv": True}, {"head_decay": True},
-            {"no_group_limit": True}, {"ignore_bias": True},
-            {"operands": jnp.float8_e4m3fn}]
-
-
-def test_model_matches_the_reference(tiny):
-    config, model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
-    got = model(params, ids)
-    for b in range(2):
-        want = reference.logits(params, ids[b], config)
-        np.testing.assert_allclose(got[b], want, atol=1e-4)
-
-
-@pytest.mark.parametrize("control", CONTROLS,
-                         ids=[next(iter(c)) for c in CONTROLS])
-def test_each_planted_control_moves_the_reference(tiny, control):
-    config, _, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
-    base = reference.logits(params, ids, config)
-    moved = reference.logits(params, ids, config, **control)
-    assert float(jnp.abs(moved - base).max()) > 0.01, control
-
-
-@pytest.mark.parametrize("chunk", [10, 7])
-def test_chunked_prefill_then_decode_equals_one_forward_pass(
-        tiny, chunk, monkeypatch):
-    """Logits, not tokens: two slots of different lengths in one pack,
-    chunks that cut the convolution's window and the scan's chunks, and
-    slot 0 REUSED by a third request — its state AND its tail must
-    start from zeros, its pages be its own."""
-    config, model, params = tiny
-    # (the helper's two calls, compiled once each instead of run op by
-    # op: the same arithmetic)
-    decode, one_pack = generation.decode, sala_tests._decode_pack
-    rows = jax.jit(lambda params, tok, pos, caches, act, bt: decode(
-        model, params, tok, pos, caches, slot_mask=act, block_tables=bt,
-        row_mask=act[:, None]))
-    packed = jax.jit(lambda params, tokens, tpos, caches, bt, tslot, pack:
-                     one_pack(model, params, tokens, tpos, caches, bt,
-                              tslot, {**pack, "impl": "reference"},
-                              "reference"))
-    monkeypatch.setattr(
-        generation, "decode",
-        lambda m, p, tok, pos, caches, *, slot_mask, block_tables,
-        row_mask, attn_kernel: rows(p, tok, pos, caches, slot_mask,
-                                    block_tables))
-    monkeypatch.setattr(
-        sala_tests, "_decode_pack",
-        lambda m, p, tokens, tpos, caches, bt, tslot, pack, kern:
-        packed(p, tokens, tpos, caches, bt, tslot,
-               {k: v for k, v in pack.items() if k != "impl"}))
-    rng = np.random.default_rng(41)
-    reqs = [(0, rng.integers(1, 128, 23), 3),
-            (1, rng.integers(1, 128, 14), 4),
-            (0, rng.integers(1, 128, 17), 3)]
-    got = _serve_logits(model, params, reqs, slots=2, chunk=chunk,
-                        block_size=4, n_blocks=24, max_len=32)
-    for i, (_, ids, _) in enumerate(reqs):
-        want = reference.logits(params, jnp.asarray(ids), config)
-        np.testing.assert_allclose(got[i], want, atol=2e-4)
-
-
-def test_engine_serves_tokens_the_reference_puts_on_top(tiny):
-    from hetu_tpu import telemetry
-    from hetu_tpu.serving import SamplingParams, ServingEngine
-    config, model, params = tiny
-    telemetry.enable(True)
+def _group_counters():
     reg = telemetry.get_registry()
-    held0 = reg.counter("moe_group_held_total").value()
-    tokens0 = reg.counter("moe_group_tokens_total").value()
-    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                        block_size=4, slots=3, kv_blocks=40, seed=0)
-    assert eng.prefix_cache is None and eng.preempt is False
-    assert eng.prefill_attn == "flash"        # the pack as one row
-    assert eng.pool.nbytes() == sum(c.nbytes for c in eng.pool.caches)
-    assert len(eng.pool.caches) == 3
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(1, 128, n).tolist() for n in (21, 13, 30, 23, 7)]
-    outs = eng.generate_many(prompts, SamplingParams(max_tokens=6))
-    assert eng.step_executables() == 1
-    for p, toks in zip(prompts, outs):
-        lg = np.asarray(reference.logits(
-            params, jnp.asarray(p + toks), config))[len(p) - 1:-1]
-        gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
-        assert gap.max() <= 1e-4, (len(p), gap)
-    held = reg.counter("moe_group_held_total").value() - held0
-    tokens = reg.counter("moe_group_tokens_total").value() - tokens0
-    # two of four groups stay: about half the tokens reach the held one
-    assert 0 < held < tokens and 0.25 < held / tokens < 0.75
-    assert reg.gauge("kv_state_bytes").value(kind="slot") == \
-        model.blocks.cache_bytes(4)["state"]["slot"]
+    return [reg.counter("moe_group_held_total").value(),
+            reg.counter("moe_group_tokens_total").value()]
+
+
+class TestKDAMLAMoE(ServedArchContract):
+    """The chunks cut the convolution's window and the scan's chunks; a
+    reused slot's state AND its tail start from zeros, its pages are its
+    own."""
+    reference = reference
+    forward_ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
+    tol = token_tol = 1e-4
+    controls = [{"no_erase": True}, {"no_conv": True}, {"head_decay": True},
+                {"no_group_limit": True}, {"ignore_bias": True},
+                {"operands": jnp.float8_e4m3fn}]
+    control_ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
+    control_moves = 0.01
+    chunks = (10, 7)
+    requests = (41, 1, 128, ((0, 23, 3), (1, 14, 4), (0, 17, 3)))
+    serve_tol = 2e-4
+    lanes = [dict()]
+    counters = staticmethod(_group_counters)
+    refused = REFUSED
+    refuses_the_dense_cache = True
+    new_modules = ("hetu_tpu.models.kda_mla_moe", "hetu_tpu.ops.kda")
+
+    def engine_served(self, eng, model, lanes, counted):
+        assert eng.prefix_cache is None and eng.preempt is False
+        assert eng.prefill_attn == "flash"        # the pack as one row
+        assert eng.pool.nbytes() == sum(c.nbytes for c in eng.pool.caches)
+        assert len(eng.pool.caches) == 3
+        held, tokens = counted
+        # two of four groups stay: about half the tokens reach the held one
+        assert 0 < held < tokens and 0.25 < held / tokens < 0.75
+        assert telemetry.get_registry().gauge("kv_state_bytes").value(
+            kind="slot") == model.blocks.cache_bytes(4)["state"]["slot"]
+
+    def refusal(self, name):
+        """The latent arena refuses some before the slot state is
+        asked."""
+        if name in ("long_max_len", "w8a8", "tenancy"):
+            return LatentKVNotSupported, name
+        return super().refusal(name)
+
+    def test_dense_cache_and_cp_prefill_refuse_by_name(self, tiny):
+        """... and the configuration's own refusals."""
+        from hetu_tpu.models.kda_mla_moe import KDAMLAMoEConfig
+        super().test_dense_cache_and_cp_prefill_refuse_by_name(tiny)
+        with pytest.raises(NotImplementedError, match="q_lora_rank"):
+            KDAMLAMoEConfig.tiny(q_lora_rank=64)
+        with pytest.raises(ValueError, match="latent layer"):
+            KDAMLAMoEConfig.tiny(num_hidden_layers=2)
 
 
 def test_the_counter_counts_a_served_requests_steps(tiny):
@@ -447,44 +359,3 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         got = total + reference.gated(shared, u)
         np.testing.assert_allclose(got, want, atol=2e-6)
         np.testing.assert_allclose(whole(params, u), total, atol=2e-6)
-
-
-#: what the latent arena refuses before the slot state is asked
-LATENT_FIRST = ("long_max_len", "w8a8", "tenancy")
-
-
-@pytest.mark.parametrize("name,kw", REFUSED, ids=[n for n, _ in REFUSED])
-def test_what_assumes_block_kv_refuses_at_construction_by_name(
-        tiny, name, kw):
-    from hetu_tpu.nn.parallel import LatentKVNotSupported
-    from hetu_tpu.serving import ServingEngine
-    _, model, params = tiny
-    with pytest.raises(LatentKVNotSupported if name in LATENT_FIRST
-                       else SlotStateNotSupported, match=name):
-        ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                      block_size=4, slots=2, kv_blocks=40, **kw)
-
-
-def test_dense_cache_cp_prefill_and_query_compression_refuse_by_name(tiny):
-    from hetu_tpu.models.kda_mla_moe import KDAMLAMoEConfig
-    _, model, params = tiny
-    with pytest.raises(SlotStateNotSupported, match="dense cache"):
-        generation.init_kv_caches(model, 1, 16)
-    with pytest.raises(SlotStateNotSupported, match="CP-prefill"):
-        model.blocks.prefill(params["blocks"], None)
-    with pytest.raises(NotImplementedError, match="q_lora_rank"):
-        KDAMLAMoEConfig.tiny(q_lora_rank=64)
-    with pytest.raises(ValueError, match="latent layer"):
-        KDAMLAMoEConfig.tiny(num_hidden_layers=2)
-
-
-def test_importing_the_package_loads_none_of_the_new_modules():
-    import subprocess
-    code = ("import sys, hetu_tpu, hetu_tpu.serving, hetu_tpu.models; "
-            "bad = [m for m in ('hetu_tpu.models.kda_mla_moe', "
-            "'hetu_tpu.ops.kda') if m in sys.modules]; print(bad); "
-            "sys.exit(bool(bad))")
-    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
-                       capture_output=True, text=True)
-    assert r.returncode == 0, r.stdout + r.stderr

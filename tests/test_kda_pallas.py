@@ -14,22 +14,16 @@ step of the recurrence: dead slots' states to the bit and their rows of
 all, a full list, ``layer=`` on a stacked leaf, head blocks, the decay
 bound."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-from hetu_tpu.ops import kda  # noqa: E402
-from hetu_tpu.ops import kda_pallas  # noqa: E402
-from hetu_tpu.ops.kda_pallas import (  # noqa: E402
-    hetu_kda_scan, hetu_kda_update,
-)
-from test_kda_mla_moe import H, D, _draw, _pack  # noqa: E402
+from served import KDA_D as D, KDA_H as H
+from served import kda_draw as _draw, kda_pack as _pack
+from hetu_tpu.ops import kda
+from hetu_tpu.ops import kda_pallas
+from hetu_tpu.ops.kda_pallas import hetu_kda_scan, hetu_kda_update
 
 SLOTS = 4
 THREE = [(2, 0, 70), (0, 37, 100), (1, 0, 5)]

@@ -150,11 +150,18 @@ def test_a_new_hybrid_costs_a_list_and_decodes_as_its_layers_one_by_one():
     kw = dict(slot_mask=mask, block_tables=tables,
               attn_kernel="reference")
     want_caches = list(caches)
+    # (the stack's call and a layer's call of each block compiled once,
+    # not dispatched op by op at every step: the same arithmetic)
+    decode = jax.jit(lambda p, x, c, pos: stack.decode(
+        p, x, c, positions=pos, with_stats=True, **kw))
+    one = {blk: jax.jit(lambda p, h, leaf, at, pos, blk=blk: blk(
+        p, h, positions=pos, kv_cache=LayerKV((leaf,), at), **kw))
+        for blk in (stack.dense[0], stack.runs[0].block,
+                    stack.runs[1].block)}
     for step in range(3):                  # the state and the rows grow
         x = jax.random.normal(jax.random.key(10 + step), (slots, 1, 32))
         pos = jnp.full((slots, 1), step, jnp.int32)
-        y, caches, stats = stack.decode(params, x, caches, positions=pos,
-                                        with_stats=True, **kw)
+        y, caches, stats = decode(params, x, caches, pos)
         # the layers one by one, each on its kind's leaves at ITS layer
         layers = [(stack.dense[0], params["dense"]["0"], 0, 0)] + [
             (run.block, jax.tree.map(lambda p: p[i], params["runs"][r]),
@@ -164,10 +171,8 @@ def test_a_new_hybrid_costs_a_list_and_decodes_as_its_layers_one_by_one():
             for i in range(run.num_layers)]
         h, sizes = x, []
         for blk, p, leaf, at in layers:
-            h, (want_caches[leaf],), *st = blk(
-                p, h, positions=pos, kv_cache=LayerKV(
-                    (want_caches[leaf],), jnp.asarray(at, jnp.int32)),
-                **kw)
+            h, (want_caches[leaf],), *st = one[blk](
+                p, h, want_caches[leaf], jnp.asarray(at, jnp.int32), pos)
             sizes += [s["moe_sizes"] for s in st]
         np.testing.assert_allclose(y, h, atol=1e-6)
         for a, b in zip(caches, want_caches):

@@ -5,28 +5,21 @@ chunked lightning form against the recurrence; the selection against a
 direct argsort; the refusals, by name; the paged call on a table that
 selects ALL pages (the other cells' path)."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from benchmark.reference import minicpm_sala as reference  # noqa: E402
-from hetu_tpu.models import generation  # noqa: E402
-from hetu_tpu.models.minicpm_sala import (  # noqa: E402
+from served import (
+    PAGE_MOVERS, REFUSED, ServedArchContract, serve_logits,
+)
+from benchmark.reference import minicpm_sala as reference
+from hetu_tpu.models import generation
+from hetu_tpu.models.minicpm_sala import (
     MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
 )
-from hetu_tpu.nn.parallel import SlotStateNotSupported  # noqa: E402
-from hetu_tpu.ops import linear_attention as la  # noqa: E402
-from hetu_tpu.ops import sparse_select as ss  # noqa: E402
-from hetu_tpu.ops.paged_pallas import (  # noqa: E402
-    history_tile_count, pack_history_tiles,
-)
+from hetu_tpu.ops import linear_attention as la
+from hetu_tpu.ops import sparse_select as ss
 
 
 def ref_config(cfg: MiniCPMSALAConfig) -> dict:
@@ -50,13 +43,12 @@ def ref_config(cfg: MiniCPMSALAConfig) -> dict:
 
 @pytest.fixture(scope="module")
 def tiny():
-    cfg = MiniCPMSALAConfig.tiny()
-    model = MiniCPMSALAForCausalLM(cfg)
-    return cfg, model, model.init(jax.random.key(39))
+    model = MiniCPMSALAForCausalLM(MiniCPMSALAConfig.tiny())
+    return ref_config(model.cfg), model, model.init(jax.random.key(39))
 
 
 def test_runs_and_caches_count_their_own_layers(tiny):
-    cfg, model, params = tiny
+    _, model, params = tiny
     assert list(zip(model.blocks.run_kinds,
                     [r.num_layers for r in model.blocks.runs])) == [
         ("minicpm4", 1), ("lightning-attn", 2),
@@ -74,206 +66,89 @@ def test_runs_and_caches_count_their_own_layers(tiny):
     assert got["state"] == {"slot": 3 * 4 * 16 * 16 * 4}
 
 
-def test_model_matches_the_reference_where_selection_drops_blocks(tiny):
-    cfg, model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
-    got = model(params, ids)
-    config = ref_config(cfg)
-    for b in range(2):
-        want = reference.logits(params, ids[b], config, q_block=16)
-        np.testing.assert_allclose(got[b], want, atol=5e-6)
-    # 12 blocks of 4 at the last position, 4 chosen: blocks ARE dropped,
-    # and the controls the benchmark's limits must refuse move the logits
-    _, margin = reference.hidden_states(params, ids[0], config,
-                                        with_margins=True)
-    assert np.isinf(np.asarray(margin[:16])).all()      # <= 4 visible
-    assert np.isfinite(np.asarray(margin[16:])).all()
-    base = reference.logits(params, ids[0], config)
-    for control in ({"forced_only": True}, {"no_decay": True},
-                    {"operands": jnp.float8_e4m3fn}):
-        moved = reference.logits(params, ids[0], config, **control)
-        assert float(jnp.abs(moved - base).max()) > 0.02, control
+class TestMiniCPMSALA(ServedArchContract):
+    reference = reference
+    tol = 2e-5
+    token_tol = 1e-4
+    requests = (39, 1, 128, ((0, 31, 5), (1, 22, 7), (0, 27, 6)))
+    lanes = [dict(attn_kernel="auto"), dict(attn_kernel="paged")]
+    refused = REFUSED
+    page_movers = tuple(PAGE_MOVERS)
+    refuses_the_dense_cache = True
+    new_modules = ("hetu_tpu.models.minicpm_sala",
+                   "hetu_tpu.ops.sparse_select",
+                   "hetu_tpu.ops.linear_attention")
 
+    def test_model_matches_the_reference(self, tiny):
+        """... where selection DROPS blocks."""
+        config, model, params = tiny
+        ids = jax.random.randint(jax.random.key(1), (2, 45), 1, 128)
+        got = model(params, ids)
+        for b in range(2):
+            want = reference.logits(params, ids[b], config, q_block=16)
+            np.testing.assert_allclose(got[b], want, atol=5e-6)
+        # 12 blocks of 4 at the last position, 4 chosen: blocks ARE
+        # dropped, and the controls the benchmark's limits must refuse
+        # move the logits
+        _, margin = reference.hidden_states(params, ids[0], config,
+                                            with_margins=True)
+        assert np.isinf(np.asarray(margin[:16])).all()      # <= 4 visible
+        assert np.isfinite(np.asarray(margin[16:])).all()
+        base = reference.logits(params, ids[0], config)
+        for control in ({"forced_only": True}, {"no_decay": True},
+                        {"operands": jnp.float8_e4m3fn}):
+            moved = reference.logits(params, ids[0], config, **control)
+            assert float(jnp.abs(moved - base).max()) > 0.02, control
 
-def _serve_logits(model, params, requests, *, slots, chunk, block_size,
-                  n_blocks, max_len, attn_kernel="reference",
-                  tile_rows=None):
-    """Drive ``generation.decode`` the way the fused step does — a
-    prefill pack of at most ``chunk`` tokens a call (FCFS, runs of
-    several requests in one pack), then decode rows, a token a call —
-    and collect every position's logits. ``requests``: ``(slot, ids,
-    n_decode)`` in admission order; a slot named twice is REUSED once
-    its first request is done. ``tile_rows``: the pack carries the
-    engine's tile map of every run (the split read)."""
-    caches = generation.init_paged_caches(model, n_blocks, block_size,
-                                          jnp.float32, slots=slots)
-    W = max_len // block_size
-    bt = np.zeros((slots, W), np.int32)
-    free = list(range(1, n_blocks))
-    out = {}
-    pending = [dict(i=i, slot=s, ids=np.asarray(ids), off=0, n=n)
-               for i, (s, ids, n) in enumerate(requests)]
-    busy, prefilling, decoding = set(), [], []
-    while pending or prefilling or decoding:
-        for r in list(pending):              # admit where the slot is free
-            if r["slot"] not in busy:
-                busy.add(r["slot"])
-                need = -(-len(r["ids"]) // block_size)
-                bt[r["slot"]] = 0
-                bt[r["slot"], :need] = [free.pop(0) for _ in range(need)]
-                prefilling.append(r)
-                pending.remove(r)
-                out[r["i"]] = np.zeros((len(r["ids"]), model.cfg.vocab_size),
-                                       np.float32)
-        btd = jnp.asarray(bt)
-        if decoding:                         # the decode rows first
-            pos = np.zeros(slots, np.int32)
-            tok = np.zeros(slots, np.int32)
-            act = np.zeros(slots, bool)
-            for r in decoding:
-                pos[r["slot"]], act[r["slot"]] = r["off"], True
-                tok[r["slot"]] = r["ids"][r["off"]]
-            lg, caches = generation.decode(
-                model, params, jnp.asarray(tok)[:, None],
-                jnp.asarray(pos)[:, None], caches,
-                slot_mask=jnp.asarray(act), block_tables=btd,
-                row_mask=jnp.asarray(act)[:, None],
-                attn_kernel=attn_kernel)
-            for r in list(decoding):
-                out[r["i"]][r["off"]] = np.asarray(lg[r["slot"], 0])
-                r["off"] += 1
-                if r["off"] == len(r["ids"]):
-                    decoding.remove(r)
-                    busy.discard(r["slot"])
-                    free += [b for b in bt[r["slot"]] if b]
-        if prefilling:                       # then one pack
-            tokens = np.zeros(chunk, np.int32)
-            tpos = np.zeros(chunk, np.int32)
-            tslot = np.zeros(chunk, np.int32)
-            valid = np.zeros(chunk, bool)
-            seg = np.full(chunk, -1, np.int32)
-            hist = np.zeros(chunk, np.int32)
-            used, fills, runs = 0, [], []
-            for r in prefilling:
-                if used >= chunk:
-                    break
-                n = min(chunk - used, len(r["ids"]) - r["n"] - r["off"])
-                runs.append((r["slot"], used, n, r["off"]))
-                sl = slice(used, used + n)
-                tokens[sl] = r["ids"][r["off"]:r["off"] + n]
-                tpos[sl] = np.arange(r["off"], r["off"] + n)
-                tslot[sl], valid[sl], seg[sl] = r["slot"], True, r["slot"]
-                hist[sl] = r["off"]
-                fills.append((r, used, n))
-                used += n
-            pack = {"segment_ids": jnp.asarray(seg)[None],
-                    "hist": jnp.asarray(hist), "valid": jnp.asarray(valid),
-                    "impl": "reference", "slot": jnp.asarray(tslot),
-                    "slot_tables": btd}
-            if tile_rows:
-                tmap, _ = pack_history_tiles(
-                    runs, tile_rows=tile_rows, every_run=True,
-                    n_tiles=history_tile_count(chunk, tile_rows, slots))
-                pack["tiles"] = {"map": jnp.asarray(tmap),
-                                 "rows": tile_rows,
-                                 "tables": btd[tmap[0]]}
-            lg, caches = _decode_pack(model, params, tokens, tpos, caches,
-                                      btd, tslot, pack, attn_kernel)
-            for r, at, n in fills:
-                out[r["i"]][r["off"]:r["off"] + n] = \
-                    np.asarray(lg[0, at:at + n])
-                r["off"] += n
-                if r["off"] == len(r["ids"]) - r["n"]:
-                    prefilling.remove(r)
-                    decoding.append(r)
-    return out
+    @pytest.mark.parametrize("attn_kernel,looped,tile_rows", [
+        ("reference", False, None), ("paged", False, None),
+        ("reference", True, None), ("paged", False, 4), ("paged", True, 8)])
+    def test_chunked_prefill_then_decode_equals_one_forward_pass(
+            self, tiny, attn_kernel, looped, tile_rows, monkeypatch):
+        """Chunks that cut strides and pages. ``looped``: at sizes
+        under a pack's, so that the read goes call by call over padded
+        rows and the scores and the scan block by block, as they do at
+        the served size. ``tile_rows``: the pack carries the engine's
+        tile map and reads its forced blocks a tile at a time, its free
+        choices a token at a time (two runs a pack, a run that starts
+        mid-cell, cells of one block and of two)."""
+        if looped:
+            from hetu_tpu.nn.parallel import (
+                BlockSparseAttention, LightningAttention,
+            )
+            monkeypatch.setattr(BlockSparseAttention, "ROWS_PER_CALL", 8)
+            monkeypatch.setattr(BlockSparseAttention, "SELECT_ROWS", 4)
+            monkeypatch.setattr(LightningAttention, "SCAN_BLOCK", 4)
+        _, model, params = tiny
+        reqs = self.draw_requests()
+        got = serve_logits(
+            model, params, reqs, chunk=10, attn_kernel=attn_kernel,
+            tile_rows=tile_rows, traced=("looped",) * looped, **self.serve)
+        for i, (_, ids, _) in enumerate(reqs):
+            self.close(got[i], self.ref_logits(tiny, jnp.asarray(ids)),
+                       self.tol)
 
+    def engine_served(self, eng, model, lanes, counted):
+        """``paged``: the kernel path (interpret mode here) — the engine
+        cuts every run of a pack into tiles for the block-sparse band (a
+        pack holds the end of one prompt and the start of the next)."""
+        from hetu_tpu import telemetry
+        paged = lanes["attn_kernel"] == "paged"
+        assert eng.prefix_cache is None and eng.preempt is False
+        assert eng.prefill_attn == "flash"        # the pack as one row
+        assert eng.attn_kernel == ("paged" if paged else "reference")
+        # a tile map of every run, sized for pages of ONE kv head
+        assert eng._hist_tiles == (1 + 3 - 1 if paged else 0)
+        if telemetry.enabled():
+            c = telemetry.get_registry().counter(
+                "serving_sparse_pages_total")
+            assert c.value(state="visible", lane="decode") >= \
+                c.value(state="chosen", lane="decode") > 0
 
-def _decode_pack(model, params, tokens, tpos, caches, btd, tslot, pack,
-                 attn_kernel):
-    pos = jnp.asarray(tpos)[None]
-    h = model.embed(params, jnp.asarray(tokens)[None], positions=pos)
-    h, caches = model.blocks.decode(
-        params["blocks"], h, caches, positions=pos,
-        block_tables=jnp.take(btd, jnp.asarray(tslot), axis=0),
-        attn_kernel=attn_kernel, pack=pack)
-    h = model.hidden_norm(params, h)
-    return jnp.einsum("bse,ve->bsv", h, params["lm_head"]["weight"]), \
-        caches
-
-
-def _compile_the_two_calls(mp, model, attn_kernel):
-    """``_serve_logits``' two calls compiled once each instead of run op
-    by op — the same arithmetic (an eager run on the kernel path leaves
-    some 17,000 memory maps behind, of the 65,530 a process may
-    hold)."""
-    decode, one_pack = generation.decode, _decode_pack
-    rows = jax.jit(lambda params, tok, pos, caches, act, bt: decode(
-        model, params, tok, pos, caches, slot_mask=act, block_tables=bt,
-        row_mask=act[:, None], attn_kernel=attn_kernel))
-
-    def packed(tile_rows):
-        def call(params, tokens, tpos, caches, bt, tslot, pack):
-            pack = {**pack, "impl": "reference"}
-            if tile_rows:
-                pack["tiles"] = {**pack["tiles"], "rows": tile_rows}
-            return one_pack(model, params, tokens, tpos, caches, bt,
-                            tslot, pack, attn_kernel)
-        return jax.jit(call)
-
-    packs = {}
-    mp.setattr(
-        generation, "decode",
-        lambda m, p, tok, pos, caches, *, slot_mask, block_tables,
-        row_mask, attn_kernel: rows(p, tok, pos, caches, slot_mask,
-                                    block_tables))
-
-    def one(m, p, tokens, tpos, caches, bt, tslot, pack, kern):
-        tiles = dict(pack.get("tiles", {}))
-        tr = tiles.pop("rows", None)
-        pack = {k: v for k, v in pack.items() if k != "impl"}
-        if tr:
-            pack["tiles"] = tiles
-        return packs.setdefault(tr, packed(tr))(
-            p, tokens, tpos, caches, bt, tslot, pack)
-    mp.setattr(sys.modules[__name__], "_decode_pack", one)
-
-
-@pytest.mark.parametrize("attn_kernel,looped,tile_rows", [
-    ("reference", False, None), ("paged", False, None),
-    ("reference", True, None), ("paged", False, 4), ("paged", True, 8)])
-def test_chunked_prefill_then_decode_equals_one_forward_pass(
-        tiny, attn_kernel, looped, tile_rows, monkeypatch):
-    """Logits, not tokens: two slots of different lengths in one pack,
-    chunks that cut strides and pages, and slot 0 REUSED by a third
-    request (its state must start from zeros, its pages be its own).
-    ``looped``: at sizes under a pack's, so that the read goes call by
-    call over padded rows and the scores and the scan block by block,
-    as they do at the served size. ``tile_rows``: the pack carries the
-    engine's tile map and reads its forced blocks a tile at a time,
-    its free choices a token at a time (two runs a pack, a run that
-    starts mid-cell, cells of one block and of two)."""
-    if looped:
-        from hetu_tpu.nn.parallel import (
-            BlockSparseAttention, LightningAttention,
-        )
-        monkeypatch.setattr(BlockSparseAttention, "ROWS_PER_CALL", 8)
-        monkeypatch.setattr(BlockSparseAttention, "SELECT_ROWS", 4)
-        monkeypatch.setattr(LightningAttention, "SCAN_BLOCK", 4)
-    cfg, model, params = tiny
-    if attn_kernel == "paged":
-        _compile_the_two_calls(monkeypatch, model, attn_kernel)
-    rng = np.random.default_rng(39)
-    reqs = [(0, rng.integers(1, 128, 31), 5),
-            (1, rng.integers(1, 128, 22), 7),
-            (0, rng.integers(1, 128, 27), 6)]
-    got = _serve_logits(model, params, reqs, slots=2, chunk=10,
-                        block_size=4, n_blocks=24, max_len=32,
-                        attn_kernel=attn_kernel, tile_rows=tile_rows)
-    config = ref_config(cfg)
-    for i, (_, ids, _) in enumerate(reqs):
-        want = reference.logits(params, jnp.asarray(ids), config)
-        np.testing.assert_allclose(got[i], want, atol=2e-5)
+    def test_dense_cache_and_cp_prefill_refuse_by_name(self, tiny):
+        super().test_dense_cache_and_cp_prefill_refuse_by_name(tiny)
+        with pytest.raises(ValueError, match="block_size"):
+            tiny[1].blocks.init_paged_caches(9, 8, jnp.float32, 2)
 
 
 @pytest.fixture(scope="module")
@@ -289,12 +164,10 @@ def split_reads():
     ids = np.random.default_rng(52).integers(1, 128, 41)
     kw = dict(slots=1, chunk=16, block_size=4, n_blocks=16, max_len=48,
               attn_kernel="paged")
-    with pytest.MonkeyPatch.context() as mp:
-        _compile_the_two_calls(mp, model, "paged")
-        return (_serve_logits(model, params, [(0, ids, 1)], tile_rows=8,
-                              **kw)[0],
-                _serve_logits(model, params, [(0, ids, 1)], **kw)[0],
-                np.asarray(model(params, jnp.asarray(ids)[None])[0]))
+    return (serve_logits(model, params, [(0, ids, 1)], tile_rows=8,
+                         **kw)[0],
+            serve_logits(model, params, [(0, ids, 1)], **kw)[0],
+            np.asarray(model(params, jnp.asarray(ids)[None])[0]))
 
 
 @pytest.mark.parametrize("where,rows", [
@@ -311,39 +184,6 @@ def test_split_read_is_the_per_token_read_and_the_forward(
     split, per_token, forward = (x[rows] for x in split_reads)
     np.testing.assert_allclose(split, per_token, atol=2e-5)
     np.testing.assert_allclose(split, forward, atol=2e-5)
-
-
-@pytest.mark.parametrize("attn_kernel", ["auto", "paged"])
-def test_engine_serves_tokens_the_reference_puts_on_top(tiny, attn_kernel):
-    """``paged``: the kernel path (interpret mode here) — the engine
-    cuts every run of a pack into tiles for the block-sparse band (a
-    pack holds the end of one prompt and the start of the next)."""
-    from hetu_tpu.serving import SamplingParams, ServingEngine
-    cfg, model, params = tiny
-    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                        block_size=4, slots=3, kv_blocks=40, seed=0,
-                        attn_kernel=attn_kernel)
-    assert eng.prefix_cache is None and eng.preempt is False
-    assert eng.prefill_attn == "flash"        # the pack as one row
-    assert eng.attn_kernel == ("paged" if attn_kernel == "paged"
-                               else "reference")
-    # a tile map of every run, sized for pages of ONE kv head
-    assert eng._hist_tiles == (0 if attn_kernel == "auto" else 1 + 3 - 1)
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(1, 128, n).tolist() for n in (21, 13, 30, 23, 7)]
-    outs = eng.generate_many(prompts, SamplingParams(max_tokens=6))
-    assert eng.step_executables() == 1
-    config = ref_config(cfg)
-    for p, toks in zip(prompts, outs):
-        lg = np.asarray(reference.logits(
-            params, jnp.asarray(p + toks), config))[len(p) - 1:-1]
-        gap = lg.max(-1) - lg[np.arange(len(toks)), toks]
-        assert gap.max() <= 1e-4, (len(p), gap)
-    from hetu_tpu import telemetry
-    if telemetry.enabled():
-        c = telemetry.get_registry().counter("serving_sparse_pages_total")
-        assert c.value(state="visible", lane="decode") >= \
-            c.value(state="chosen", lane="decode") > 0
 
 
 def test_chunk_scan_equals_the_recurrence_for_ragged_runs():
@@ -463,54 +303,6 @@ def test_paged_call_on_a_table_that_selects_all_pages(d, bs, g):
     np.testing.assert_allclose(got, want, atol=1e-5)
 
 
-REFUSED = [
-    ("prefix_cache", dict(prefix_cache=True)),
-    ("preempt", dict(preempt=True)),
-    ("spill_host_budget_bytes", dict(spill_host_budget_bytes=1e6)),
-    ("long_max_len", dict(long_max_len=128)),
-    ("spec_depth", dict(spec_depth=2)),
-    ("int8", dict(cache_dtype=jnp.int8)),
-    ("w8a8", dict(w8a8="on")),
-    ("tenancy", dict(tenancy=True)),
-    ("prefill_attn='reference'", dict(prefill_attn="reference")),
-]
-
-
-@pytest.mark.parametrize("name,kw", REFUSED, ids=[n for n, _ in REFUSED])
-def test_what_assumes_block_kv_refuses_at_construction_by_name(
-        tiny, name, kw):
-    from hetu_tpu.serving import ServingEngine
-    _, model, params = tiny
-    with pytest.raises(SlotStateNotSupported, match=name):
-        ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                      block_size=4, slots=2, kv_blocks=40, **kw)
-
-
-@pytest.mark.parametrize("call", [
-    "export_prefix", "import_prefix", "configure_replication",
-    "evict_request", "prefill_only"])
-def test_what_moves_a_requests_pages_refuses_when_called(tiny, call):
-    from hetu_tpu.serving import ServingEngine
-    _, model, params = tiny
-    eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                        block_size=4, slots=2, kv_blocks=40)
-    args = {"export_prefix": ([1, 2, 3],), "import_prefix": (None,),
-            "configure_replication": (lambda doc: None,),
-            "evict_request": (None,), "prefill_only": ([1, 2, 3],)}[call]
-    with pytest.raises(SlotStateNotSupported, match=call):
-        getattr(eng, call)(*args)
-
-
-def test_dense_cache_and_cp_prefill_refuse_by_name(tiny):
-    _, model, params = tiny
-    with pytest.raises(SlotStateNotSupported, match="dense cache"):
-        generation.init_kv_caches(model, 1, 16)
-    with pytest.raises(SlotStateNotSupported, match="CP-prefill"):
-        model.blocks.prefill(params["blocks"], None)
-    with pytest.raises(ValueError, match="block_size"):
-        model.blocks.init_paged_caches(9, 8, jnp.float32, 2)
-
-
 def test_other_models_keep_their_defaults_and_their_programs():
     """``prefix_cache`` / ``preempt`` left at None are ON for a model
     without slot state, and its pack carries no slot operand."""
@@ -589,7 +381,7 @@ def test_band_counter_is_the_forced_pages_of_the_packs_rows(tiny):
     same."""
     from hetu_tpu import telemetry
     from hetu_tpu.serving import SamplingParams, ServingEngine
-    cfg, model, params = tiny
+    _, model, params = tiny
     rng = np.random.default_rng(8)
     prompts = [rng.integers(1, 128, n).tolist() for n in (21, 13, 30)]
     blocks = np.concatenate([np.arange(len(p)) // 4 + 1 for p in prompts])
@@ -615,15 +407,3 @@ def test_band_counter_is_the_forced_pages_of_the_packs_rows(tiny):
     finally:
         telemetry.reset()
         telemetry.enable(was)
-
-
-def test_importing_the_package_loads_none_of_the_new_modules():
-    import subprocess
-    code = ("import sys, hetu_tpu, hetu_tpu.serving, hetu_tpu.models; "
-            "bad = [m for m in ('hetu_tpu.models.minicpm_sala', "
-            "'hetu_tpu.ops.sparse_select', 'hetu_tpu.ops.linear_attention')"
-            " if m in sys.modules]; print(bad); sys.exit(bool(bad))")
-    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
-                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
-                       capture_output=True, text=True)
-    assert r.returncode == 0, r.stdout + r.stderr

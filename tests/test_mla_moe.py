@@ -8,24 +8,17 @@ scale 2.446. The oracle is the plain reference
 (``benchmark/reference/mla_moe.py``), which shares no code with the
 model. Every tolerance says why it is what it is."""
 
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from benchmark.reference import mla_moe as reference  # noqa: E402
-from hetu_tpu.models.mla_moe import (  # noqa: E402
-    MLAMoEConfig, MLAMoEForCausalLM,
-)
-from hetu_tpu.nn.moe import ExpertShareMoE  # noqa: E402
-from hetu_tpu.nn.parallel import LatentKVNotSupported  # noqa: E402
-from hetu_tpu.ops.paged_pallas import (  # noqa: E402
+from served import ServedArchContract
+from benchmark.reference import mla_moe as reference
+from hetu_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
+from hetu_tpu.nn.moe import ExpertShareMoE
+from hetu_tpu.nn.parallel import LatentKVNotSupported
+from hetu_tpu.ops.paged_pallas import (
     pack_history_tiles, paged_attention_pallas,
     paged_attention_reference, paged_history_attention,
 )
@@ -50,10 +43,10 @@ def _published(cfg: MLAMoEConfig) -> dict:
 @pytest.fixture(scope="module", params=[None, 48],
                 ids=["row40", "row48padded"])
 def tiny(request):
-    """The tiny model, with the arena row as it is (40) and padded."""
-    cfg = MLAMoEConfig.tiny(stored_row=request.param)
-    model = MLAMoEForCausalLM(cfg)
-    return cfg, model, model.init(jax.random.key(30))
+    """The tiny model, with the arena row as it is (40) and padded;
+    its configuration under the published keys the reference reads."""
+    model = MLAMoEForCausalLM(MLAMoEConfig.tiny(stored_row=request.param))
+    return _published(model.cfg), model, model.init(jax.random.key(30))
 
 
 def _ids(n, seed=0, rows=1):
@@ -61,51 +54,44 @@ def _ids(n, seed=0, rows=1):
         1, 128, (rows, n)), jnp.int32)
 
 
-# -- (a) whole-sequence logits ----------------------------------------------
+# -- (a) whole-sequence logits; (b) chunked prefill, then decoding through
+# -- the latent arena --------------------------------------------------------
 
-def test_whole_sequence_logits_equal_the_reference(tiny):
-    cfg, model, params = tiny
-    ids = _ids(40, rows=2)
-    want = reference.logits(params, ids, _published(cfg))
-    np.testing.assert_allclose(model(params, ids), want, atol=F32)
-    # the bias is live: a reference that routes by s alone differs by
-    # far more than rounding, so a program that left it out would too
-    off = reference.logits(params, ids, _published(cfg), ignore_bias=True)
-    assert float(jnp.abs(off - want).max()) > 100 * F32
-
-
-# -- (b) chunked prefill, then decoding through the latent arena ------------
-
-@pytest.mark.parametrize("kernel,prefill", [
-    ("reference", "reference"), ("paged", "flash_pallas")])
-def test_engine_tokens_are_the_references_greedy_tokens(tiny, kernel,
-                                                        prefill):
-    """Prompts of 37, 9 and 20 tokens over pages of 8 and chunks of 16
-    (contexts cross page and chunk boundaries; three requests on two
-    slots, so a freed slot stands beside a live one and is taken
+class TestMLAMoE(ServedArchContract):
+    """(b): prompts of 37, 9 and 20 tokens over pages of 8 and chunks
+    of 16 (contexts cross page and chunk boundaries; three requests on
+    two slots, so a freed slot stands beside a live one and is taken
     again), through the gather lane and through the interpreted kernel
     with the history read in tiles. Every emitted token must be the
     reference's argmax at its position, up to float32 ties."""
-    from hetu_tpu.engine import trace_counts
-    from hetu_tpu.serving import SamplingParams, ServingEngine
-    cfg, model, params = tiny
-    ids = _ids(37, seed=1)[0]
-    prompts = [[int(t) for t in ids[:n]] for n in (37, 9, 20)]
-    before = trace_counts().get("serving_step", 0)
-    eng = ServingEngine(model, params, max_len=64, prefill_chunk=16,
-                        block_size=8, slots=2, kv_blocks=20,
-                        attn_kernel=kernel, prefill_attn=prefill,
-                        prefix_cache=False)
-    assert eng.attn_kernel == kernel and len(eng.pool.caches) == 1
-    outs = eng.generate_many(prompts, SamplingParams(max_tokens=6))
-    assert trace_counts().get("serving_step", 0) - before == 1
-    for prompt, out in zip(prompts, outs):
-        seq = jnp.asarray([prompt + list(out)], jnp.int32)
-        lg = np.asarray(reference.logits(params, seq,
-                                         _published(cfg)))[0]
-        at = lg[len(prompt) - 1:len(prompt) - 1 + len(out)]
-        gap = at.max(-1) - at[np.arange(len(out)), list(out)]
-        assert len(out) == 6 and gap.max() <= F32, (len(prompt), gap)
+    reference = reference
+    forward_ids = _ids(40, rows=2)
+    tol = token_tol = F32
+    lanes = [dict(attn_kernel="reference", prefill_attn="reference"),
+             dict(attn_kernel="paged", prefill_attn="flash_pallas")]
+    engine = dict(max_len=64, prefill_chunk=16, block_size=8, slots=2,
+                  kv_blocks=20, prefix_cache=False)
+
+    def one_sequence(self, tiny, ids, **control):
+        config, _, params = tiny
+        return reference.logits(params, ids[None], config, **control)[0]
+
+    def test_model_matches_the_reference(self, tiny):
+        super().test_model_matches_the_reference(tiny)
+        # the bias is live: a reference that routes by s alone differs by
+        # far more than rounding, so a program that left it out would too
+        ids = self.forward_ids[0]
+        off = self.ref_logits(tiny, ids, ignore_bias=True)
+        assert float(jnp.abs(off - self.ref_logits(tiny, ids)).max()) \
+            > 100 * F32
+
+    def draw_prompts(self):
+        ids = _ids(37, seed=1)[0]
+        return [[int(t) for t in ids[:n]] for n in (37, 9, 20)]
+
+    def engine_served(self, eng, model, lanes, counted):
+        assert eng.attn_kernel == lanes["attn_kernel"]
+        assert len(eng.pool.caches) == 1
 
 
 # -- (c) absorbed equals expanded, per head ---------------------------------
@@ -115,8 +101,8 @@ def test_absorbed_form_equals_expanded_form_per_head(tiny):
     ``(sum p c) W_uv,h = sum p v_h`` for every head: the same products
     reassociated, so they agree to float32 rounding of sums of ~40
     terms of magnitude ~0.1."""
-    cfg, model, params = tiny
-    attn = model.blocks.block.attn
+    _, model, params = tiny
+    cfg, attn = model.cfg, model.blocks.block.attn
     p = jax.tree.map(lambda x: x[0], params["blocks"]["experts"]["attn"])
     u = jax.random.normal(jax.random.key(3), (1, 24, cfg.hidden_size))
     pos = jnp.arange(24)[None]
@@ -225,7 +211,8 @@ def test_kernel_experts_equal_ragged_dot_all_held(ragged_dot_experts):
 def test_arena_is_one_leaf_of_stored_rows(tiny):
     from hetu_tpu import telemetry
     from hetu_tpu.serving import ServingEngine
-    cfg, model, params = tiny
+    _, model, params = tiny
+    cfg = model.cfg
     telemetry.reset()
     telemetry.enable(True)
     try:
@@ -247,7 +234,7 @@ def test_arena_is_one_leaf_of_stored_rows(tiny):
 
 def test_what_needs_per_head_kv_refuses_by_name(tiny):
     from hetu_tpu.serving import ServingEngine
-    cfg, model, params = tiny
+    _, model, params = tiny
     kw = dict(max_len=64, prefill_chunk=16, block_size=8, slots=2,
               kv_blocks=20)
     for bad in (dict(cache_dtype=jnp.int8), dict(long_max_len=128),
@@ -268,7 +255,7 @@ def test_prefix_sharing_spill_and_speculation_carry_the_leaf(tiny):
     resume, and n-gram speculation are written over the arena's leaves,
     whatever they are: each gives the tokens of an undisturbed run."""
     from hetu_tpu.serving import SamplingParams, ServingEngine
-    cfg, model, params = tiny
+    _, model, params = tiny
     kw = dict(max_len=64, prefill_chunk=16, block_size=8, kv_blocks=24)
     ids = [int(t) for t in _ids(30, seed=4)[0]]
     a, b = ids[:21], ids[:19] + ids[25:30]      # 19 shared: 2 pages + 3

@@ -8,24 +8,18 @@ and the output gate against the reference one layer at a time; the
 share test of the ``model-configs`` guide's section 4; the planted
 controls; the counters and the refusals."""
 
-import json
-import os
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from benchmark.reference import qwen3_next as reference  # noqa: E402
-from benchmark.runners.serve_arch import load_arch  # noqa: E402
-from hetu_tpu.ops import kda  # noqa: E402
-from hetu_tpu.ops.kda_pallas import (  # noqa: E402
-    hetu_kda_scan, hetu_kda_update,
-)
+import served
+from served import ServedArchContract, counted, top_token_gaps
+from benchmark.reference import qwen3_next as reference
+from benchmark.runners.serve_arch import load_arch
+from hetu_tpu import telemetry
+from hetu_tpu.ops import kda
+from hetu_tpu.ops.kda_pallas import hetu_kda_scan, hetu_kda_update
 
 HK, H, D = 2, 4, 16            # key heads under value heads of D
 
@@ -37,7 +31,7 @@ def _token_rule(q, k, v, g, beta, state):
     head ``j`` reads key head ``j // (H / HK)``."""
     q, k, v, g, beta = (np.asarray(x, np.float64)
                         for x in (q, k, v, g, beta))
-    S = np.array(state, np.float64)
+    S = np.zeros((H, D, D)) if state is None else np.array(state, np.float64)
     out = np.zeros(v.shape)
     for t in range(len(q)):
         for j in range(H):
@@ -65,29 +59,18 @@ def _draw(key, T, steep=False):
     return q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
 
 
+def _rule(x, state):
+    return _token_rule(*x, state)
+
+
+_rule.state_shape = (H, D, D)
+
+
 def _pack(runs, C, slots, key, steep):
-    """``runs``: ``(slot, first position, tokens)`` in pack order ->
-    operands with garbage on the pad rows, the states before, where,
-    and what :func:`_token_rule` wants."""
-    state0 = np.asarray(jax.random.normal(jax.random.fold_in(key, 99),
-                                          (slots, H, D, D)))
-    slot, pos = np.zeros(C, np.int32), np.zeros(C, np.int32)
-    valid = np.zeros(C, bool)
-    parts, want_o, want_s, used = [], [], state0.copy(), 0
-    for i, (s, p0, n) in enumerate(runs):
-        x = _draw(jax.random.fold_in(key, i), n, steep)
-        parts.append(x)
-        slot[used:used + n], valid[used:used + n] = s, True
-        pos[used:used + n] = np.arange(p0, p0 + n)
-        o, st = _token_rule(*x, state0[s] if p0 else np.zeros((H, D, D)))
-        want_o.append(o)
-        want_s[s] = st
-        used += n
-    ops = [jnp.concatenate([p[j] for p in parts] + [jnp.full(
-        (C - used,) + parts[0][j].shape[1:], 7.0)]) for j in range(5)]
-    return ops, jnp.asarray(state0), (
-        jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(valid)), \
-        np.concatenate(want_o), want_s, used
+    """:func:`served.delta_rule_pack` of :func:`_draw` against
+    :func:`_token_rule`."""
+    return served.delta_rule_pack(
+        runs, C, slots, key, lambda k, n: _draw(k, n, steep), _rule)
 
 
 THREE = [(2, 0, 70), (0, 37, 100), (1, 0, 5)]
@@ -150,8 +133,7 @@ def test_update_at_one_decay_a_head_equals_the_token_rule(steep, form):
             assert (np.asarray(st[s]) == np.asarray(state0[s])).all()
             continue
         want_o, want_s = _token_rule(
-            *(a[s:s + 1] for a in x),
-            np.zeros((H, D, D)) if fresh[s] else state0[s])
+            *(a[s:s + 1] for a in x), None if fresh[s] else state0[s])
         np.testing.assert_allclose(o[s], want_o[0], atol=2e-6)
         np.testing.assert_allclose(st[s], want_s, atol=2e-6)
 
@@ -163,7 +145,7 @@ def test_recurrence_takes_both_forms_and_widen_leaves_kdas_alone():
     operation), and head counts that do not divide are refused."""
     x = _draw(jax.random.key(2), 40, steep=True)
     o, st = kda.kda_recurrence(*x)
-    want_o, want_s = _token_rule(*x, np.zeros((H, D, D)))
+    want_o, want_s = _token_rule(*x, None)
     np.testing.assert_allclose(o, want_o, atol=2e-6)
     np.testing.assert_allclose(st, want_s, atol=2e-6)
     q, k, v, g, _ = x
@@ -182,13 +164,8 @@ def test_recurrence_takes_both_forms_and_widen_leaves_kdas_alone():
 def tiny():
     """``tests/benchmark/configs/qwen3-next-tiny.json`` with EVERY
     expert held (the uncut model), its model and float32 weights."""
-    with open(os.path.join(
-            ROOT, "tests/benchmark/configs/qwen3-next-tiny.json")) as f:
-        config = json.load(f)
-    config["num_experts"] = config["published"]["num_experts"]
-    config["deployment"] = {"expert_share": 0}
-    model = load_arch("qwen3_next").build(config)
-    return config, model, model.init(jax.random.key(3))
+    return served.tiny("qwen3-next", 3, num_experts=16,
+                       deployment={"expert_share": 0})
 
 
 def test_tiny_is_the_issues(tiny):
@@ -200,36 +177,87 @@ def test_tiny_is_the_issues(tiny):
             cfg.linear_key_head_dim) == (4, 8, 16)
     assert (cfg.head_dim, cfg.rotary_dim) == (32, 8)
     assert (cfg.num_experts, cfg.num_experts_per_tok) == (16, 3)
-    _, model, _ = tiny
+    config, model, _ = tiny
+    assert config["num_experts"] == config["published"]["num_experts"]
     assert model.blocks.layers_of == {GDN: 6, ATTENTION: 2}
     assert model.blocks.slot_state and model.blocks.paged
 
 
-def test_forward_equals_the_reference(tiny):
-    config, model, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (2, 37), 1, 128)
-    got = model(params, ids)
-    for b in range(2):
-        want = reference.logits(params, ids[b], config)
+def _mixer_counters(mixer="gdn"):
+    reg = telemetry.get_registry()
+    c = reg.counter(f"{mixer}_scan_steps_total")
+    u = reg.counter(f"{mixer}_update_slots_total")
+    return [c.value(kind=k) for k in ("live", "computed")] + [
+        u.value(kind=k) for k in ("live", "stepped")]
+
+
+class TestQwen3Next(ServedArchContract):
+    reference = reference
+    forward_ids = jax.random.randint(jax.random.key(1), (2, 37), 1, 128)
+    tol = 1e-3
+    controls = [{"no_erase": True}, {"no_conv": True},
+                {"tile_key_heads": True}, {"full_rotary": True},
+                {"no_out_gate": True}, {"no_shared_gate": True},
+                {"plain_gain": True}, {"sigmoid_router": True},
+                {"operands": jnp.float8_e4m3fn},
+                {"state_dtype": jnp.float8_e4m3fn}]
+    control_ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
+    control_from, control_moves = 16, 0.1
+    lanes = [dict(), dict(attn_kernel="paged", prefill_attn="flash_pallas")]
+    refused = [("prefix_cache", dict(prefix_cache=True)),
+               ("preempt", dict(preempt=True)),
+               ("spec_depth", dict(spec_depth=2))]
+
+    def close(self, got, want, atol):
         assert float(jnp.abs(want).max()) > 1.0
-        np.testing.assert_allclose(got[b], want, atol=1e-3)
+        super().close(got, want, atol)
 
-
-CONTROLS = [{"no_erase": True}, {"no_conv": True}, {"tile_key_heads": True},
-            {"full_rotary": True}, {"no_out_gate": True},
-            {"no_shared_gate": True}, {"plain_gain": True},
-            {"sigmoid_router": True}, {"operands": jnp.float8_e4m3fn},
-            {"state_dtype": jnp.float8_e4m3fn}]
-
-
-@pytest.mark.parametrize("control", CONTROLS,
-                         ids=[next(iter(c)) for c in CONTROLS])
-def test_each_planted_control_moves_the_reference(tiny, control):
-    config, _, params = tiny
-    ids = jax.random.randint(jax.random.key(1), (45,), 1, 128)
-    base = reference.logits(params, ids, config)
-    moved = reference.logits(params, ids, config, **control)
-    assert float(jnp.abs(moved - base)[16:].max()) > 0.1, control
+    def test_engine_serves_tokens_the_reference_puts_on_top(self, tiny,
+                                                            lanes):
+        """... and the STATES beside the tokens: over three requests
+        through three slots, chunks that cut the convolution's window
+        and the scan's pieces, the slot's state where the last chunk
+        and where the last decoded token leave it is the token
+        recurrence's, and the counters count what the kernels walked.
+        ``paged_kernels``: the flash prefill and BOTH paged calls
+        interpreted, at a head of 32."""
+        from benchmark.runners import serve_arch_ssm
+        from hetu_tpu.serving import ServingEngine
+        config, model, params = tiny
+        arch = load_arch("qwen3_next")
+        kda_before = _mixer_counters("kda")
+        with counted(_mixer_counters) as delta:
+            eng = ServingEngine(model, params, **{**self.engine, **lanes})
+            assert eng.prefix_cache is None and eng.preempt is False
+            assert len(eng.pool.caches) == 4
+            rng = np.random.default_rng(7)
+            prompts = [rng.integers(1, 128, n) for n in (21, 13, 30)]
+            recs = serve_arch_ssm.probe(arch, eng, prompts, 6)
+            # (Ling's counters, which another engine of this process may
+            # have fed, take nothing from this one)
+            assert _mixer_counters("kda") == kda_before
+        live, computed, advanced, stepped = delta
+        assert eng.step_executables() == 1
+        for r in recs:
+            p, toks = len(r["prompt"]), r["tokens"]
+            ids = np.zeros(64, np.int32)
+            ids[:p + 6] = np.concatenate([r["prompt"], toks])
+            lg, margin, states = arch.reference_rows(
+                config, params, jnp.asarray(ids), jnp.int32(p - 1), 6)
+            gap = top_token_gaps(lg, 1, toks)
+            assert gap.max() <= 1e-3, (p, gap)
+            assert np.isfinite(np.asarray(margin)).all()
+            read_gap = arch.state_gap(config, params, r["states"], states)
+            assert read_gap["gap"] < arch.state_tol(config), read_gap
+            assert max(read_gap["whole_by_layer"]) < 1e-3, read_gap
+        layers = model.blocks.layers_of["linear_attention"]
+        assert 0 < live <= computed and live % layers == 0
+        # three requests x five decode rows each (the first token is the
+        # prefill's), every one a live slot of the three
+        assert advanced == 3 * 5 * layers
+        assert stepped % (3 * layers) == 0 and stepped >= advanced
+        assert telemetry.get_registry().gauge("kv_state_bytes").value(
+            kind="slot") == model.blocks.cache_bytes(4)["state"]["slot"]
 
 
 def _attention_layer(tiny, **over):
@@ -310,72 +338,6 @@ def test_eight_shares_add_up_to_the_uncut_layer(tiny):
             got + shared, reference.expert_ffn(part, u, one)[0], atol=2e-6)
 
 
-@pytest.mark.parametrize("lanes", [
-    dict(), dict(attn_kernel="paged", prefill_attn="flash_pallas")],
-    ids=["reference_lanes", "paged_kernels"])
-def test_engine_serves_the_references_tokens_and_states(tiny, lanes):
-    """The real engine — scheduler, fused step, one executable — over
-    five requests through three slots, chunks that cut the convolution's
-    window and the scan's pieces: every emitted token is the reference's
-    top token within rounding (float32 both sides), the slot's STATE
-    where the last chunk and where the last decoded token leave it is
-    the token recurrence's, and the counters count what the kernels
-    walked. ``paged_kernels``: the flash prefill and BOTH paged calls
-    interpreted, at a head of 32."""
-    from benchmark.runners import serve_arch_ssm
-    from hetu_tpu import telemetry
-    from hetu_tpu.serving import ServingEngine
-    config, model, params = tiny
-    arch = load_arch("qwen3_next")
-    telemetry.enable(True)
-    try:
-        reg = telemetry.get_registry()
-
-        def read(mixer="gdn"):
-            c = reg.counter(f"{mixer}_scan_steps_total")
-            u = reg.counter(f"{mixer}_update_slots_total")
-            return [c.value(kind=k) for k in ("live", "computed")] + [
-                u.value(kind=k) for k in ("live", "stepped")]
-        before, kda_before = read(), read("kda")
-        eng = ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                            block_size=4, slots=3, kv_blocks=40, seed=0,
-                            **lanes)
-        assert eng.prefix_cache is None and eng.preempt is False
-        assert len(eng.pool.caches) == 4
-        rng = np.random.default_rng(7)
-        prompts = [rng.integers(1, 128, n) for n in (21, 13, 30)]
-        recs = serve_arch_ssm.probe(arch, eng, prompts, 6)
-        live, computed, advanced, stepped = (
-            a - b for a, b in zip(read(), before))
-        # (Ling's counters, which another engine of this process may
-        # have fed, take nothing from this one)
-        assert read("kda") == kda_before
-    finally:
-        telemetry.enable(False)
-    assert eng.step_executables() == 1
-    for r in recs:
-        p, toks = len(r["prompt"]), r["tokens"]
-        ids = np.zeros(64, np.int32)
-        ids[:p + 6] = np.concatenate([r["prompt"], toks])
-        lg, margin, states = arch.reference_rows(
-            config, params, jnp.asarray(ids), jnp.int32(p - 1), 6)
-        lg = np.asarray(lg)
-        gap = lg.max(-1) - lg[np.arange(6), toks]
-        assert gap.max() <= 1e-3, (p, gap)
-        assert np.isfinite(np.asarray(margin)).all()
-        read_gap = arch.state_gap(config, params, r["states"], states)
-        assert read_gap["gap"] < arch.state_tol(config), read_gap
-        assert max(read_gap["whole_by_layer"]) < 1e-3, read_gap
-    layers = model.blocks.layers_of["linear_attention"]
-    assert 0 < live <= computed and live % layers == 0
-    # three requests x five decode rows each (the first token is the
-    # prefill's), every one a live slot of the three
-    assert advanced == 3 * 5 * layers
-    assert stepped % (3 * layers) == 0 and stepped >= advanced
-    assert reg.gauge("kv_state_bytes").value(kind="slot") == \
-        model.blocks.cache_bytes(4)["state"]["slot"]
-
-
 def test_a_steep_decay_survives_the_chunked_lanes():
     """Weights drawn so that heads decay by e^-16 a token and more (the
     tiny preset's ``dt_range`` up to 1): chunked prefill then decode
@@ -394,21 +356,6 @@ def test_a_steep_decay_survives_the_chunked_lanes():
     lg = np.asarray(model(params, jnp.asarray(
         np.concatenate([ids, toks]))[None])[0])[39:47]
     assert (lg.max(-1) - lg[np.arange(8), toks]).max() <= 1e-3
-
-
-REFUSED = [("prefix_cache", dict(prefix_cache=True)),
-           ("preempt", dict(preempt=True)),
-           ("spec_depth", dict(spec_depth=2))]
-
-
-@pytest.mark.parametrize("name,kw", REFUSED, ids=[n for n, _ in REFUSED])
-def test_what_assumes_block_kv_refuses_by_name(tiny, name, kw):
-    from hetu_tpu.nn.parallel import SlotStateNotSupported
-    from hetu_tpu.serving import ServingEngine
-    _, model, params = tiny
-    with pytest.raises(SlotStateNotSupported, match=name):
-        ServingEngine(model, params, max_len=64, prefill_chunk=8,
-                      block_size=4, slots=2, kv_blocks=40, **kw)
 
 
 def test_the_config_refuses_what_is_not_built():

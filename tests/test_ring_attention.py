@@ -335,10 +335,12 @@ def test_ring_dropout_matches_masked_oracle(rng):
         o = jnp.einsum("bhqk,bkhd->bqhd", a, v.astype(jnp.float32))
         return (o ** 2).sum(), o
 
-    (lr, outr), gr = jax.value_and_grad(ring_loss, argnums=(0, 1, 2),
-                                        has_aux=True)(q, k, v)
-    (lo, outo), go = jax.value_and_grad(oracle_loss, argnums=(0, 1, 2),
-                                        has_aux=True)(q, k, v)
+    # (each side compiled once: op by op, a ring hop under shard_map is
+    # dispatched eight devices at a time)
+    (lr, outr), gr = jax.jit(jax.value_and_grad(
+        ring_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    (lo, outo), go = jax.jit(jax.value_and_grad(
+        oracle_loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
     np.testing.assert_allclose(np.asarray(outr), np.asarray(outo),
                                rtol=2e-5, atol=2e-5)
     for a, b_ in zip(gr, go):
@@ -359,15 +361,19 @@ def test_ring_dropout_zigzag_and_model(rng):
                              cp_layout="zigzag")
     q, k, v = _qkv(rng, b=2, s=32, hq=2, hkv=2, d=8)
     key = jax.random.key(4)
+
+    @jax.jit
+    def dropped(q, k, v, key):          # ONE program, run twice
+        with ctx:
+            return ring_attention(q, k, v, ctx=ctx, causal=True,
+                                  impl="reference", layout="zigzag",
+                                  dropout_rate=0.3, dropout_key=key)
+
     with ctx:
-        base = ring_attention(q, k, v, ctx=ctx, causal=True,
-                              impl="reference", layout="zigzag")
-        d1 = ring_attention(q, k, v, ctx=ctx, causal=True,
-                            impl="reference", layout="zigzag",
-                            dropout_rate=0.3, dropout_key=key)
-        d2 = ring_attention(q, k, v, ctx=ctx, causal=True,
-                            impl="reference", layout="zigzag",
-                            dropout_rate=0.3, dropout_key=key)
+        base = jax.jit(lambda q, k, v: ring_attention(
+            q, k, v, ctx=ctx, causal=True, impl="reference",
+            layout="zigzag"))(q, k, v)
+    d1, d2 = dropped(q, k, v, key), dropped(q, k, v, key)
     assert not np.allclose(np.asarray(base), np.asarray(d1))
     np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
 
@@ -396,12 +402,10 @@ def test_ring_dropout_pallas_matches_ref_hops(rng):
     os.environ["HETU_PALLAS_INTERPRET"] = "1"
     try:
         with ctx:
-            ref = ring_attention(q, k, v, ctx=ctx, causal=True,
-                                 impl="reference", dropout_rate=0.3,
-                                 dropout_key=key)
-            pal = ring_attention(q, k, v, ctx=ctx, causal=True,
-                                 impl="pallas", dropout_rate=0.3,
-                                 dropout_key=key)
+            ref, pal = (jax.jit(lambda q, k, v, impl=impl: ring_attention(
+                q, k, v, ctx=ctx, causal=True, impl=impl, dropout_rate=0.3,
+                dropout_key=key))(q, k, v) for impl in ("reference",
+                                                         "pallas"))
     finally:
         del os.environ["HETU_PALLAS_INTERPRET"]
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),

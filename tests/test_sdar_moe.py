@@ -7,8 +7,6 @@ engine against the plain reference's ``block_diffusion_generate``
 pass — on the CPU, at the tiny preset, seeded."""
 
 import dataclasses
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -16,28 +14,26 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from benchmark.reference import sdar_moe as reference  # noqa: E402
-from hetu_tpu import telemetry  # noqa: E402
-from hetu_tpu.engine import trace_counts  # noqa: E402
-from hetu_tpu.models import generation  # noqa: E402
-from hetu_tpu.models.sdar_moe import (  # noqa: E402
+from served import ServedArchContract
+from benchmark.reference import sdar_moe as reference
+from hetu_tpu import telemetry
+from hetu_tpu.engine import trace_counts
+from hetu_tpu.models import generation
+from hetu_tpu.models.sdar_moe import (
     BlockDiffusion, SDARMoEConfig, SDARMoEForCausalLM,
 )
-from hetu_tpu.nn.moe import ExpertShareMoE  # noqa: E402
-from hetu_tpu.ops.attention import (  # noqa: E402
+from hetu_tpu.nn.moe import ExpertShareMoE
+from hetu_tpu.ops.attention import (
     attention_reference, attention_with_lse, block_bound,
 )
-from hetu_tpu.ops.paged_pallas import (  # noqa: E402
+from hetu_tpu.ops.paged_pallas import (
     decode_work_list, paged_attention_pallas, paged_attention_reference,
 )
-from hetu_tpu.serving import ServingEngine  # noqa: E402
-from hetu_tpu.serving.block_diffusion import (  # noqa: E402
+from hetu_tpu.serving import ServingEngine
+from hetu_tpu.serving.block_diffusion import (
     BlockGenerationNotSupported, denoise_slots, lane_rows,
 )
-from hetu_tpu.serving.scheduler import SamplingParams  # noqa: E402
+from hetu_tpu.serving.scheduler import SamplingParams
 
 
 def _config(cfg: SDARMoEConfig, **over) -> dict:
@@ -272,23 +268,6 @@ def test_the_eight_shares_of_one_expert_layer_add_up():
 
 # -- the model against the reference ----------------------------------------
 
-def test_forward_is_the_references_block_causal_logits(tiny):
-    cfg, model, p = tiny
-    ids = np.asarray(jax.random.randint(jax.random.key(1), (2, 20), 0, 95))
-    np.testing.assert_allclose(
-        model(p, ids), reference.logits(p, ids, _config(cfg)), atol=2e-5)
-    # a later block is not seen; the own block is, whole
-    later = np.asarray(model(p, np.where(np.arange(20) == 8, 7, ids)))
-    assert np.abs(later - np.asarray(model(p, ids)))[:, :8].max() == 0
-    own = np.asarray(model(p, np.where(np.arange(20) == 7, 7, ids)))
-    assert np.abs(own - np.asarray(model(p, ids)))[:, 4].max() > 1e-4
-    assert model.generation == BlockDiffusion(4, 95, 4)
-    with pytest.raises(ValueError):
-        BlockDiffusion(3, 0, 2)
-    with pytest.raises(ValueError):
-        BlockDiffusion(4, 0, 5)
-
-
 def test_prefill_then_block_passes_through_the_arena(tiny):
     """The prompt's whole blocks written through the paged arena (a
     batch row a token, the reference lane), then the block lane's rows
@@ -457,26 +436,72 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_engine_generates_as_the_reference_token_and_pass(tiny, case):
-    """More requests than slots (a slot is taken by a second request),
-    of lengths that put the two slots at different passes in one
-    step."""
-    cfg, model, p = tiny
-    lens, outs, knobs = CASES[case]
-    rng = np.random.default_rng(7)
-    prompts = [rng.integers(1, 95, n) for n in lens]
-    traces0 = trace_counts().get("serving_step", 0)
-    eng = _engine(model, p)
-    got = _generate(eng, prompts, [SamplingParams(max_tokens=t, **knobs)
-                                   for t in outs])
-    for pr, t, (toks, at) in zip(prompts, outs, got):
-        want = reference.block_diffusion_generate(
-            p, pr, _config(cfg), max_tokens=t, **knobs)
-        assert (toks, at) == want
-        assert len(toks) == t and 95 not in toks
-    assert eng.step_executables() == 1
-    assert trace_counts()["serving_step"] - traces0 == 1
+
+class TestSDARMoE(ServedArchContract):
+    """Generation by diffusion over blocks: the forward is the
+    reference's block-causal one, the engine's cases hold it to the
+    reference's generation loop token for token and PASS for pass, and
+    what a block lane cannot do is refused as such."""
+    tol = 2e-5
+    forward_ids = jax.random.randint(jax.random.key(1), (2, 20), 0, 95)
+    small_engine = dict(slots=2, max_len=64, prefill_chunk=8, block_size=8)
+    refused = sorted(dict(
+        spec_depth=dict(spec_depth=2), prefix_cache=dict(prefix_cache=True),
+        preempt=dict(preempt=True),
+        spill=dict(spill_host_budget_bytes=1e6),
+        long_max_len=dict(long_max_len=128), int8=dict(cache_dtype=jnp.int8),
+        w8a8=dict(w8a8="on"), tenancy=dict(tenancy=True)).items())
+
+    def one_sequence(self, tiny, ids, **control):
+        cfg, _, p = tiny
+        return reference.logits(p, ids[None], _config(cfg), **control)[0]
+
+    def refusal(self, name):
+        return BlockGenerationNotSupported, "diffusion over blocks"
+
+    def test_model_matches_the_reference(self, tiny):
+        super().test_model_matches_the_reference(tiny)
+        cfg, model, p = tiny
+        ids = np.asarray(self.forward_ids)
+        # a later block is not seen; the own block is, whole
+        later = np.asarray(model(p, np.where(np.arange(20) == 8, 7, ids)))
+        assert np.abs(later - np.asarray(model(p, ids)))[:, :8].max() == 0
+        own = np.asarray(model(p, np.where(np.arange(20) == 7, 7, ids)))
+        assert np.abs(own - np.asarray(model(p, ids)))[:, 4].max() > 1e-4
+        assert model.generation == BlockDiffusion(4, 95, 4)
+        with pytest.raises(ValueError):
+            BlockDiffusion(3, 0, 2)
+        with pytest.raises(ValueError):
+            BlockDiffusion(4, 0, 5)
+
+    @pytest.fixture(scope="class")
+    def served(self, tiny):
+        """ONE engine for the cases below — they differ only in what
+        they ask of it — and the traces counted before it was built."""
+        _, model, p = tiny
+        return trace_counts().get("serving_step", 0), _engine(model, p)
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_engine_serves_tokens_the_reference_puts_on_top(
+            self, tiny, served, case):
+        """Token for token and pass for pass. More requests than slots
+        (a slot is taken by a second request, and by the next case's),
+        of lengths that put the two slots at different passes in one
+        step; every case through the ONE executable of one trace."""
+        cfg, model, p = tiny
+        traces0, eng = served
+        lens, outs, knobs = CASES[case]
+        rng = np.random.default_rng(7)
+        prompts = [rng.integers(1, 95, n) for n in lens]
+        got = _generate(eng, prompts, [SamplingParams(max_tokens=t, **knobs)
+                                       for t in outs])
+        for pr, t, (toks, at) in zip(prompts, outs, got):
+            want = reference.block_diffusion_generate(
+                p, pr, _config(cfg), max_tokens=t, **knobs)
+            assert (toks, at) == want
+            assert len(toks) == t and 95 not in toks
+        assert eng.step_executables() == 1
+        assert trace_counts()["serving_step"] - traces0 == 1
 
 
 def test_engine_dynamic_remasking_with_a_threshold_that_fires(sharp):
@@ -657,26 +682,6 @@ def test_a_slot_taken_again_begins_without_its_last_requests_carry(tiny):
 
 
 # -- what a block lane refuses -----------------------------------------------
-
-REFUSED_AT_CONSTRUCTION = {
-    "spec_depth": dict(spec_depth=2),
-    "prefix_cache": dict(prefix_cache=True),
-    "preempt": dict(preempt=True),
-    "spill": dict(spill_host_budget_bytes=1e6),
-    "long_max_len": dict(long_max_len=128),
-    "int8": dict(cache_dtype=jnp.int8),
-    "w8a8": dict(w8a8="on"),
-    "tenancy": dict(tenancy=True),
-}
-
-
-@pytest.mark.parametrize("what", sorted(REFUSED_AT_CONSTRUCTION))
-def test_block_generation_refuses_at_construction_by_name(tiny, what):
-    _, model, p = tiny
-    with pytest.raises(BlockGenerationNotSupported,
-                       match="diffusion over blocks"):
-        _engine(model, p, **REFUSED_AT_CONSTRUCTION[what])
-
 
 def test_block_generation_refuses_at_submit_and_hand_over(tiny):
     _, model, p = tiny

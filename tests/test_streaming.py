@@ -472,8 +472,8 @@ def test_client_generate_stream_falls_back_to_poll(tele):
     """When the server cannot stream (no ``stream_subscribe`` on the
     serving object → drop "unsupported"), the generator still delivers
     everything via the loud RESULT-poll fallback."""
-    from test_fleet import _StubEngine
-    stub = _StubEngine(delay_s=0.05)
+    from served import StubEngine
+    stub = StubEngine(delay_s=0.05)
     srv, port = _serve(stub)
     try:
         cli = CoordinatorClient(port, timeout=5.0)

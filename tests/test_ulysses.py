@@ -155,18 +155,21 @@ def test_ulysses_attention_dropout():
             return ulysses_attention(q, k, v, ctx=ctx, causal=True,
                                      dropout_rate=rate, dropout_key=key)
 
-    base = run()
-    dropped = run(key, 0.3)
+    # (compiled: op by op, the a2a under shard_map is dispatched eight
+    # devices at a time; the dropped program is ONE, run twice)
+    base = jax.jit(run)()
+    with_rate = jax.jit(lambda key: run(key, 0.3))
+    dropped = with_rate(key)
     assert not np.allclose(np.asarray(base), np.asarray(dropped))
     np.testing.assert_array_equal(np.asarray(dropped),
-                                  np.asarray(run(key, 0.3)))
+                                  np.asarray(with_rate(key)))
     # differentiable end to end (grads finite, nonzero)
     def loss(q):
         with ctx:
             o = ulysses_attention(q, k, v, ctx=ctx, causal=True,
                                   dropout_rate=0.3, dropout_key=key)
         return (o.astype(jnp.float32) ** 2).sum()
-    g = jax.grad(loss)(q)
+    g = jax.jit(jax.grad(loss))(q)
     assert np.isfinite(np.asarray(g)).all() and float(jnp.abs(g).sum()) > 0
 
     # model path: cp2 ulysses trains with attn_pdrop (ring does too —

@@ -9,9 +9,7 @@ whole sharded train steps using them — for v5e topologies via
 ``jax.experimental.topologies`` with ``HETU_PALLAS_INTERPRET=0``:
 
 - flash attention fwd+bwd: causal bench shape, GQA, packed segment
-  ids, head_dim 128, and every tuned block entry recorded by
-  ``flash_tune.py`` (a tuned config that stops compiling is caught
-  HERE, not mid-window);
+  ids, head_dim 128;
 - fused streaming LM-head+CE fwd+bwd at the bench vocab;
 - the dp2×tp2×cp2 ring-attention train step on a v5e:2x4 target
   (collectives + Pallas inside shard_map);
@@ -525,29 +523,6 @@ def check_decode(devs, *, batch=4, prompt=32, new=16):
     return {"compile_s": round(time.perf_counter() - t0, 1)}
 
 
-def tuned_block_checks():
-    """One flash check per tuned entry in flash_blocks.json (both fwd
-    and bwd blocks) at that entry's seq — a tuned config that stops
-    Mosaic-compiling must fail here, not mid-window."""
-    from hetu_tpu.core.measured import read_measured
-    data = read_measured("flash_blocks.json")
-    out = []
-    for e in (data or {}).get("entries", []):
-        # a malformed entry must cost only itself, not the whole gate
-        try:
-            seq = int(e["seq"])
-            for kind in ("fwd", "bwd"):
-                if kind in e:
-                    bq, bk = (int(x) for x in e[kind])
-                    out.append((f"flash_tuned_{kind}_s{seq}_q{bq}k{bk}",
-                                dict(shape=(1, seq, 8, 64), block_q=bq,
-                                     block_k=bk)))
-        except (KeyError, TypeError, ValueError) as err:
-            print(f"skipping malformed flash_blocks entry {e!r}: {err}",
-                  flush=True)
-    return out
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
@@ -609,8 +584,6 @@ def main():
         ("serving_lane_prefill_int8",
          lambda: check_serving_lane(d1, lane="prefill", dtype=jnp.int8)),
     ]
-    checks += [(name, lambda kw=kw: check_flash(d1, **kw))
-               for name, kw in tuned_block_checks()]
     if not args.quick:
         checks += [
             ("step_dp2tp2cp2_ring_v5e8",

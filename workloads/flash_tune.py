@@ -6,10 +6,12 @@ APART — forward, dq, dk/dv — at representative shapes, the bench shape
 as ``pretrain-packed-1k`` draws them (documents of half a row to a whole
 row, first-fit packed, a trailing pad id), so the rows have the dead,
 interior and edge tiles the kernels walk by class
-(``ops.flash_pallas.tile_classes``, printed beside each time). The
-winners go to ``workloads/out/flash_blocks.json``, which
-``ops.flash_pallas`` consults for its default tiling on TPU ("bwd": the
-tile at which dq + dk/dv is least).
+(``ops.flash_pallas.tile_classes``, printed beside each time). It
+prints every time and, a shape, the fastest tile of each kernel ("bwd":
+the tile at which dq + dk/dv is least) and chooses nothing: the tile
+the kernels take is the static rule of
+``ops.flash_pallas._default_blocks``, which these sweeps set (PERF.md,
+PR 40). ``--out FILE`` keeps the winners as JSON.
 
 Timing runs the kernel inside ONE jit via ``lax.scan`` (iterations
 chained through a negligible 1e-30 feedback term so XLA cannot hoist or
@@ -18,7 +20,7 @@ otherwise swamp sub-ms kernels and make every block choice look
 identical. The backward's two calls are timed apart by taking only dq,
 or only dk + dv, of ``_flash_bwd``: XLA drops the call nobody reads.
 
-Usage: python workloads/flash_tune.py [--shapes N] [--seed S]
+Usage: python workloads/flash_tune.py [--shapes N] [--seed S] [--out FILE]
 """
 
 from __future__ import annotations
@@ -37,9 +39,6 @@ import numpy as np
 from hetu_tpu.data.packing import pack_sequences
 from hetu_tpu.ops.flash_pallas import _flash_bwd, _flash_fwd, tile_classes
 from workloads._timing import scan_loop, time_loop_ms
-
-OUT_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "out", "flash_blocks.json")
 
 # (batch, seq, heads, head_dim, iters): bench shape first, then
 # long-context — iters shrink as the quadratic cost grows (32k causal is
@@ -75,6 +74,7 @@ def main():
     ap.add_argument("--shapes", type=int, default=len(SHAPES),
                     help="sweep the first N shapes")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", help="write the winners here as JSON")
     args = ap.parse_args()
 
     if jax.devices()[0].platform != "tpu":
@@ -136,12 +136,11 @@ def main():
                               "best_dkv": best("dkv"),
                               "best_bwd": best_b}), flush=True)
 
-    if entries:
-        os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
-        with open(OUT_PATH, "w") as f:
+    if entries and args.out:
+        with open(args.out, "w") as f:
             json.dump({"device": kind_name, "entries": entries}, f,
                       indent=1)
-        print(f"wrote {OUT_PATH}")
+        print(f"wrote {args.out}")
 
 
 if __name__ == "__main__":

@@ -797,14 +797,17 @@ def _ep_dispatch(x, idx, wgt, eparams, *, ep, num_experts, k,
 
 # -- one chip's share of an expert-parallel layer (serving) -------------------
 def count_local_share(sizes, *, tile: Optional[int] = None,
-                      window: Optional[int] = None) -> None:
+                      window: Optional[int] = None,
+                      form: Optional[str] = None) -> None:
     """:class:`ExpertShareMoE`'s counters, on the host: ``sizes``
     ``(layer calls, held experts)`` int — the group sizes of every
     layer of one executed scan (``return_stats=True``; the serving
     engine calls this with what its step returned). With ``tile`` (and
     the ``window`` of a share) also what the grouped matmuls' aligned
     layout cost: the rows routed here beside the rows of the tiles the
-    kernel visited (:meth:`ExpertShareMoE.count_share` knows both)."""
+    kernel visited, and with ``form`` which form of the grouped call the
+    lane's layer calls made (:meth:`ExpertShareMoE.count_share` knows
+    all three)."""
     from hetu_tpu import telemetry
     import numpy as np
     reg = telemetry.get_registry()
@@ -820,6 +823,13 @@ def count_local_share(sizes, *, tile: Optional[int] = None,
         rows.inc(float(sizes.sum()), kind="live")
         rows.inc(float(sum(grouped_rows_computed(s, tile, window)
                            for s in sizes)), kind="computed")
+    if form:
+        reg.counter(
+            "moe_grouped_form_calls_total",
+            "layer calls of an expert-share MoE layer by the form of "
+            "their grouped expert call: fused = gate, up, SwiGLU and "
+            "down of a (row tile, expert) in one grid step, split = "
+            "three grouped matmuls").inc(float(sizes.shape[0]), form=form)
     reg.counter(
         "moe_local_calls_total",
         "executed calls of an expert-share MoE layer").inc(
@@ -884,19 +894,27 @@ class ExpertShareMoE(Module):
     absent experts would have added is left out, here and in the
     reference alike (``benchmark/reference/cohere2_moe.py``). No token
     is dropped: there is no capacity. The (token, choice) pairs are
-    sorted by local expert and the three expert matmuls run as grouped
-    matmuls over the sorted rows — the Pallas kernel of
-    ``ops/grouped_matmul_pallas.py``, three calls a layer call: a row
-    costs one expert's arithmetic, a held expert that got a row has
-    each of its matrices read ONCE a call and one that got none never —
-    never a per-token gather of expert weights. The sorted rows are
-    laid out with each expert's on row tiles of their own
-    (:meth:`tile_rows`, from the call's rows and the held experts: 16
-    where a lane has a few rows an expert, 64 or 256 where it has
-    dozens or hundreds; the tables in the ``hetu.moe_route`` scope),
-    the second call writes ``silu(gate) * up`` as its epilogue, and the
-    results come back through the layout's inverse in the one gather
-    that brings the pairs to token order. Inside a layer scan the
+    sorted by local expert and the experts run as a grouped call over
+    the sorted rows — the Pallas kernels of
+    ``ops/grouped_matmul_pallas.py``: a row costs one expert's
+    arithmetic, a held expert that got a row has each of its matrices
+    read ONCE a call and one that got none never — never a per-token
+    gather of expert weights. The sorted rows are laid out with each
+    expert's on row tiles of their own (:meth:`tile_rows`, from the
+    call's rows and the held experts: 16 where a lane has a few rows an
+    expert, 64 or 256 where it has dozens or hundreds; the tables in the
+    ``hetu.moe_route`` scope). The call takes one of two forms, from the
+    shapes alone (:meth:`grouped_form`). **Fused**, where an expert's
+    three matrices fit a grid step beside the lane's float32 result
+    (every expert of the benchmark but Command A+'s): ONE call a layer
+    call and lane whose step is gate, up, SwiGLU and down of a (row
+    tile, expert) and then the weighted sum — each live row times its
+    routing weight added into its token's row of the result, in the
+    rows' order; no result in the aligned layout, no gather back.
+    **Split**: three calls a layer call — the second writes ``silu(gate)
+    * up`` as its epilogue — and the results come back through the
+    layout's inverse in the one gather that brings the pairs to token
+    order, to be weighted and summed over the choices. Inside a layer scan the
     expert weights come as :class:`~hetu_tpu.nn.module.StackedLeaf`
     (the model's block lists them as ``unsliced``): the kernel's index
     map takes ``layer x held + expert`` from a scalar operand — no
@@ -944,6 +962,12 @@ class ExpertShareMoE(Module):
             raise ValueError(f"top-{k} of {num_experts} experts")
         self.num_experts, self.k = num_experts, k
         self.local_experts = (int(first), int(count))
+        #: an expert's gate and up are ``features x hidden``
+        self.widths = (int(features), int(hidden))
+        #: ``{pairs a call: (form, tile)}`` as each traced lane chose
+        #: them — under ITS compute dtype, which the host thread that
+        #: counts does not run under: the counters read the choice
+        self._lanes: dict = {}
         init = init or normal_init(0.02)
         self.param("router", (features, num_experts), init,
                    axes=("embed", None))
@@ -973,13 +997,35 @@ class ExpertShareMoE(Module):
         want = max(2 * pairs * count // self.num_experts, 128)
         return min(pairs, -(-want // 128) * 128)
 
+    def grouped_form(self, pairs: int) -> str:
+        """``"fused"`` where an expert's three matrices and the lane's
+        float32 result fit one call of
+        ``ops.grouped_matmul_pallas.grouped_swiglu`` (from the widths,
+        the compute dtype and the tokens of a call that routes
+        ``pairs`` pairs), else ``"split"``: three ``grouped_matmul``
+        calls and the gather back."""
+        from hetu_tpu.ops.grouped_matmul_pallas import grouped_swiglu_fits
+        fits = grouped_swiglu_fits(
+            *self.widths, pairs // self.k,
+            jnp.dtype(self.compute_dtype()).itemsize)
+        return "fused" if fits else "split"
+
     def tile_rows(self, pairs: int) -> int:
-        """Rows of a tile of the grouped matmuls where a call routes
-        ``pairs`` (token, choice) pairs: from the window's rows and the
-        held experts (``ops.grouped_matmul_pallas.grouped_tile_rows``)."""
+        """Rows of a tile of the grouped call where a call routes
+        ``pairs`` (token, choice) pairs
+        (``ops.grouped_matmul_pallas.grouped_tile_rows`` of the held
+        experts and the call's rows): the split form's from the
+        window's rows; a share's fused form's from one and a half times
+        the rows its experts EXPECT (three quarters of the window, which
+        is twice them) — an expert's busiest-over-mean is 1.1-1.4, the
+        fused step costs its rows and a second tile of an expert
+        fetches nothing again (the chip's sweep: PERF.md, PR 62)."""
         from hetu_tpu.ops.grouped_matmul_pallas import grouped_tile_rows
-        return grouped_tile_rows(self._window_rows(pairs),
-                                 self.local_experts[1])
+        rows, count = self._window_rows(pairs), self.local_experts[1]
+        if count < self.num_experts \
+                and self.grouped_form(pairs) == "fused":
+            rows = 3 * rows // 4
+        return grouped_tile_rows(rows, count)
 
     @property
     def layer_stats(self) -> dict:
@@ -994,11 +1040,14 @@ class ExpertShareMoE(Module):
     def count_share(self, sizes, tokens: Optional[int] = None) -> None:
         """The layer's host counters (a block's ``layer_stats`` emit):
         :func:`count_local_share` of the scan's group sizes; the lane's
-        ``tokens`` a call say which tile and window the calls used."""
+        ``tokens`` a call say which window the calls used and which
+        form and tile the lane's trace chose."""
         pairs = (tokens or 0) * self.k
         if not pairs:
             return count_local_share(sizes)
-        count_local_share(sizes, tile=self.tile_rows(pairs),
+        form, tile = self._lanes.get(pairs) or (
+            self.grouped_form(pairs), self.tile_rows(pairs))
+        count_local_share(sizes, tile=tile, form=form,
                           window=self._window_rows(pairs))
 
     def _kept_groups(self, sel):
@@ -1055,6 +1104,11 @@ class ExpertShareMoE(Module):
         k = self.k
         M = xf.shape[0] * k
         first, count = self.local_experts
+        R = self._window_rows(M)
+        tile = self.tile_rows(M)
+        form = self.grouped_form(M)
+        fused = form == "fused"
+        self._lanes[M] = (form, tile)
         with jax.named_scope("hetu.moe_route"):
             idx, w, kept = self.route(params, xf, return_kept=True)
             local = idx - first
@@ -1065,29 +1119,33 @@ class ExpertShareMoE(Module):
             sizes = jnp.sum(
                 key[:, None] == jnp.arange(count, dtype=key.dtype)[None],
                 axis=0, dtype=jnp.int32)
-            # where each pair's row went, to bring its result back
-            back = jnp.zeros((M,), jnp.int32).at[order].set(
-                jnp.arange(M, dtype=jnp.int32))
+            # where each pair's row went, to bring its result back (the
+            # fused form adds its rows into their tokens' and has no way
+            # back to walk)
+            back = None if fused else jnp.zeros((M,), jnp.int32) \
+                .at[order].set(jnp.arange(M, dtype=jnp.int32))
             total = sizes.sum()
 
         from hetu_tpu.ops.grouped_matmul_pallas import (
-            grouped_layout, grouped_matmul,
+            grouped_combine, grouped_layout, grouped_matmul, grouped_swiglu,
         )
-        R = self._window_rows(M)
-        tile = self.tile_rows(M)
 
-        def grouped(a, name, lay, **kw):
+        def leaf(name):
             w, layer = params[name], None
             if isinstance(w, StackedLeaf):
                 w, layer = w
-            return grouped_matmul(a, w.astype(dt), lay, layer=layer, **kw)
+            return w.astype(dt), layer
+
+        def grouped(a, name, lay, **kw):
+            w, layer = leaf(name)
+            return grouped_matmul(a, w, lay, layer=layer, **kw)
 
         def experts(at, sizes, wanted):
-            """The sorted pairs ``at`` (indices into the pairs; the
-            first ``sizes.sum()`` are live) through their experts, in
-            the ALIGNED layout, and back: row ``i`` of the result is
-            the sorted row ``wanted[i]``'s. Rows of no group are never
-            computed and never read."""
+            """The split form: the sorted pairs ``at`` (indices into the
+            pairs; the first ``sizes.sum()`` are live) through their
+            experts, in the ALIGNED layout, and back: row ``i`` of the
+            result is the sorted row ``wanted[i]``'s. Rows of no group
+            are never computed and never read."""
             with jax.named_scope("hetu.moe_route"):
                 lay = grouped_layout(sizes, rows=at.shape[0], tile=tile)
                 rows = jnp.take(xd, jnp.take(at, lay.src) // k, axis=0)
@@ -1096,13 +1154,29 @@ class ExpertShareMoE(Module):
                         out_dtype=dt)
             return jnp.take(grouped(h, "wo", lay), wanted, axis=0)
 
+        def fused_experts(at, sizes):
+            """The fused form: the sorted pairs ``at`` through their
+            experts in the aligned layout, every live row weighted and
+            added into its token's row — ``(tokens, d)`` float32."""
+            with jax.named_scope("hetu.moe_route"):
+                lay = grouped_layout(sizes, rows=at.shape[0], tile=tile)
+                pair = jnp.take(at, lay.src)
+                rows = jnp.take(xd, pair // k, axis=0)
+                combine = grouped_combine(lay, sizes, pair // k,
+                                          jnp.take(w_pairs, pair))
+            (wg, layer), (wi, _), (wo, _) = map(leaf, ("wg", "wi", "wo"))
+            return grouped_swiglu(rows, wg, wi, wo, lay, combine,
+                                  tokens=xf.shape[0], layer=layer)
+
         w_pairs = w.reshape(M)
         with jax.named_scope("hetu.moe_route"):
             # the tokens in the operands' dtype once: the padded layout
             # gathers more rows than there are pairs
             xd = xf.astype(dt)
         with jax.named_scope("hetu.moe_experts"):
-            if R >= M:
+            if R >= M and fused:
+                out = fused_experts(order, sizes)
+            elif R >= M:
                 out = jnp.where(
                     (back < total)[:, None],
                     experts(order, sizes, back) * w_pairs[:, None],
@@ -1121,6 +1195,9 @@ class ExpertShareMoE(Module):
                     start = i * R
                     inside = jnp.clip(jnp.minimum(hi, start + R)
                                       - jnp.maximum(lo, start), 0)
+                    if fused:
+                        return out + fused_experts(jax.lax.dynamic_slice(
+                            order_p, (start,), (R,)), inside)
                     rel = back - start
                     mine_w = (rel >= 0) & (rel < R) & (back < total)
                     part = experts(

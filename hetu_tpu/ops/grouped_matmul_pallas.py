@@ -2,8 +2,11 @@
 
 The routed-expert layer of the serving step
 (:class:`hetu_tpu.nn.moe.ExpertShareMoE`) sorts its (token, choice)
-pairs by expert and runs three matmuls a layer call over the sorted
-rows, each group of rows against its own expert's matrix. Until this
+pairs by expert and runs its experts over the sorted rows, each group
+of rows against its own expert's matrices: ONE call a layer call and
+lane where an expert's three matrices fit a grid step
+(:func:`grouped_swiglu`, below), else three matmuls
+(:func:`grouped_matmul`). Until this
 kernel they were ``jax.lax.ragged_dot``, which takes no tile sizes: on
 the chip it read the experts' weights at 19-46 % of the bandwidth
 wherever the rows were many or the width was not a multiple of 512
@@ -39,7 +42,24 @@ same product in the house style of ``ops/paged_pallas.py``:
   on the float32 accumulator, as the layer computed it in XLA, without
   a pass over the padded rows.
 
-The chip's sweep of this kernel alone (PERF.md, PR 43; ms a call,
+**A small expert is one grid step** (:func:`grouped_swiglu`, PR 62):
+where an expert's gate, up and down matrices fit VMEM double buffered
+(:func:`grouped_swiglu_fits`: 13-44 MB for the benchmark's experts of
+2048 x 512 to 2048 x 1408; Command A+'s 4096 x 4096 stay three calls in
+column blocks) the step of (one row tile of one expert) computes ``g =
+x @ wg``, ``u = x @ wi``, ``h = silu(g) * u`` rounded to the operands'
+dtype and ``y = h @ wo`` — the three calls' roundings in the same
+places, the gate and ``h`` never in HBM — and, with the combine tables
+(:func:`grouped_combine`), weights its rows and ADDS each live one into
+its token's row of a float32 result that stays in VMEM for the whole
+call: the layer's weighted sum over choices, with no result in the
+aligned layout and no gather back. The row loop runs while the next
+expert's matrices stream in, so it costs nothing a call: at Qwen3-Next's
+pack (2,560 live rows of 20,480 pairs over 64 experts of 6.3 MB) the
+three calls took 0.68 ms and the gather back with its sum 1.30; the one
+call takes 0.57 (PERF.md, PR 62).
+
+The chip's sweep of the matmul alone (PERF.md, PR 43; ms a call,
 ``ragged_dot`` -> the rule's tile): 1.27 -> 0.52 at 288 rows over 64
 experts of 2048 x 1408 (715 GB/s of weights), 3.76 -> 0.76 at 12,288
 rows; 0.86 -> 0.67 and 1.84 -> 0.76 at 16 experts of 4096 x 4096 in a
@@ -305,6 +325,181 @@ def grouped_matmul(x, w, layout: GroupedLayout, *, layer=None, gate=None,
         interpret=interpret,
         name="hetu_grouped_matmul",
     )(*scalars, *args)
+
+
+class GroupedCombine(NamedTuple):
+    """Where the laid-out rows of one call go (:func:`grouped_combine`):
+    :func:`grouped_swiglu` adds each live row, weighted, into its
+    token's row of the result."""
+    token: jax.Array    # (layout.rows,) int32 each laid-out row's token
+    weight: jax.Array   # (layout.rows, 1) float32 its weight
+    live: jax.Array     # (tiles + 1,) int32 each tile's live rows
+
+
+def grouped_combine(layout: GroupedLayout, sizes, token, weight):
+    """The combine tables of one call: ``token`` and ``weight``
+    ``(layout.rows,)`` of the LAID-OUT rows (each row's token and
+    routing weight, gathered through ``layout.src`` as the rows were),
+    and each tile's live rows from the groups' ``sizes`` (a group's rows
+    fill its tiles from the first; the rest of its last tile is dead and
+    is never added). Plain ``jnp`` on a few hundred integers — call it
+    in the scope that routes."""
+    tile = layout.tile
+    j = jnp.arange(layout.tile_group.shape[0], dtype=jnp.int32)
+    g = layout.tile_group
+    live = _pick(sizes.astype(jnp.int32), g) \
+        - (j - _pick(layout.group_first, g)) * tile
+    return GroupedCombine(
+        token=token.astype(jnp.int32),
+        weight=weight.astype(jnp.float32)[:, None],
+        live=jnp.where(j < layout.n_tiles, jnp.clip(live, 0, tile), 0))
+
+
+def grouped_swiglu_vmem(k: int, n: int, tile: int, tokens: int,
+                        itemsize: int = 2) -> int:
+    """Bytes of VMEM a step of :func:`grouped_swiglu` holds: an expert's
+    three whole matrices, the row tile and its weights double buffered,
+    the body's float32 gate, up and product, the rounded ``h``, the
+    float32 result of the tile and its weighted copy, and the ``(tokens,
+    k)`` float32 result that stays resident (two buffers of it)."""
+    return 2 * (3 * k * n + tile * k) * itemsize + 2 * tile * 128 * 4 \
+        + tile * n * (12 + itemsize) + 2 * tile * k * 4 \
+        + 2 * (-(-tokens // 8) * 8) * k * 4
+
+
+def grouped_swiglu_fits(k: int, n: int, tokens: int,
+                        itemsize: int = 2) -> bool:
+    """Whether an expert of ``(k, n)`` gate and up and ``(n, k)`` down
+    matrices can be ONE grid step (:func:`grouped_swiglu`) of a call
+    over ``tokens`` tokens, from shapes alone: its three matrices,
+    double buffered, beside the largest row tile and the resident result
+    within the kernel's VMEM limit (100 MiB less the compiler's 16: bf16
+    experts of 2048 x 512, 2048 x 768, 2560 x 768 and 2048 x 1408 take
+    13-44 MB and a 2,048-token float32 result 34-42 more; Command A+'s
+    4096 x 4096 would take 201 and stays three calls in column
+    blocks)."""
+    return grouped_swiglu_vmem(k, n, _MAX_TILE_ROWS, tokens, itemsize) \
+        + _VMEM_SPARE <= _VMEM_MOST
+
+
+def _swiglu_kernel(base_ref, grp_ref, st_ref, tok_ref, live_ref, x_ref,
+                   wg_ref, wi_ref, wo_ref, w_ref, o_ref, y_ref, *,
+                   tile: int):
+    x = x_ref[...]
+    g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wi_ref[...], preferred_element_type=jnp.float32)
+    # h is rounded where the split path's second call rounds it
+    h = (jax.nn.silu(g) * u).astype(x.dtype)
+    y_ref[...] = jnp.dot(h, wo_ref[...],
+                         preferred_element_type=jnp.float32) * w_ref[...]
+    s = pl.program_id(0)
+
+    @pl.when(s == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    first = st_ref[s] * tile
+
+    def add(r, carry):
+        at = pl.ds(tok_ref[first + r], 1)
+        o_ref[at, :] = o_ref[at, :] + y_ref[pl.ds(r, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, live_ref[st_ref[s]], add, 0)
+
+
+def grouped_swiglu(x, wg, wi, wo, layout: GroupedLayout,
+                   combine: GroupedCombine, *, tokens: int, layer=None,
+                   interpret: Optional[bool] = None):
+    """``out[t] = sum over the live rows r of token t of weight[r] *
+    y[r]``, ``y[r] = (silu(x[r] @ wg[e]) * (x[r] @ wi[e])) @ wo[e]``
+    with ``e`` the group of ``r``, over the laid-out rows, in ONE call
+    whose grid step is (one row tile of one group): what three
+    :func:`grouped_matmul` calls (gate; up with the ``gate=`` epilogue
+    and ``out_dtype=x.dtype``; down), the gather back and the layer's
+    weighted sum over choices compute, with the same roundings in the
+    same places — float32 accumulators, the SwiGLU product rounded to
+    ``x.dtype``, float32 products and sums — and neither the gate, ``h``
+    nor a result in the aligned layout ever in HBM. The step weights its
+    tile's rows and ADDS each live one into its token's row of the
+    ``(tokens, K)`` float32 result, which stays in VMEM for the whole
+    call; the adds run in the rows' order while the next group's
+    matrices stream in. For experts whose three matrices fit beside that
+    result (:func:`grouped_swiglu_fits`; refused otherwise).
+
+    - ``x``: ``(layout.rows, K)`` rows in the aligned layout;
+    - ``wg``, ``wi``: ``(groups, K, N)`` and ``wo``: ``(groups, N, K)``,
+      or the STACKED leaves ``(layers, groups, ...)`` with ``layer`` (an
+      int32 scalar, traced inside the layer scan) — all three indexed by
+      ``layer x groups + group``, never sliced. A group's blocks are not
+      copied again for its consecutive tiles; a group without a row is
+      never fetched;
+    - ``combine`` (:func:`grouped_combine`): each laid-out row's token
+      and weight, each tile's live rows.
+
+    Returns ``(tokens, K)`` float32; a token without a live row reads
+    zeros. Matches the three-call path up to the order of float32
+    partial sums."""
+    if (layer is None) != (wg.ndim == 3):
+        raise ValueError(
+            f"layer= goes with stacked (layers, groups, K, N) weights "
+            f"and only with them; got wg {wg.shape}, layer={layer!r}")
+    groups, K, N = wg.shape[-3:]
+    rows, tile = layout.rows, layout.tile
+    if wi.shape != wg.shape or wo.shape != wg.shape[:-2] + (N, K) \
+            or not wg.dtype == wi.dtype == wo.dtype:
+        raise ValueError(f"wg {wg.shape} {wg.dtype}, wi {wi.shape} "
+                         f"{wi.dtype}, wo {wo.shape} {wo.dtype}: not one "
+                         "expert's gate, up and down")
+    if x.shape != (rows, K) or x.dtype != wg.dtype:
+        raise ValueError(f"x {x.shape} {x.dtype} against a layout of "
+                         f"{rows} rows and wg {wg.shape} {wg.dtype}")
+    if combine.token.shape != (rows,) or combine.weight.shape != (rows, 1):
+        raise ValueError(f"combine tables {combine.token.shape}, "
+                         f"{combine.weight.shape} against a layout of "
+                         f"{rows} rows")
+    vmem = grouped_swiglu_vmem(K, N, tile, tokens, wg.dtype.itemsize)
+    if vmem + _VMEM_SPARE > _VMEM_MOST:
+        raise ValueError(
+            f"three {K} x {N} {wg.dtype} matrices, a {tile}-row tile and "
+            f"a result of {tokens} tokens take {vmem} bytes, over the "
+            f"{_VMEM_MOST} of the kernel's limit: use three "
+            f"grouped_matmul calls")
+    step_tile, _ = _step_list(layout, 1)
+    base = jnp.asarray(0 if layer is None else layer, jnp.int32) \
+        .reshape(1) * groups
+    # (the step WRITES by these: a token outside the result is clipped
+    # into it, never an address outside the kernel's memory)
+    scalars = (base, layout.tile_group, step_tile,
+               jnp.clip(combine.token, 0, tokens - 1), combine.live)
+
+    def rows_at(s, base, grp, st, tok, live):
+        return (st[s], 0)
+
+    def weight_at(s, base, grp, st, tok, live):
+        return (base[0] + grp[st[s]], 0, 0)
+
+    interpret = _interpret_default() if interpret is None else interpret
+    return pl.pallas_call(
+        functools.partial(_swiglu_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(jnp.maximum(layout.n_tiles, 1),),
+            in_specs=[pl.BlockSpec((tile, K), rows_at),
+                      pl.BlockSpec((None, K, N), weight_at),
+                      pl.BlockSpec((None, K, N), weight_at),
+                      pl.BlockSpec((None, N, K), weight_at),
+                      pl.BlockSpec((tile, 1), rows_at)],
+            out_specs=pl.BlockSpec((tokens, K), lambda s, *_: (0, 0)),
+            scratch_shapes=[pltpu.VMEM((tile, K), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((tokens, K), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem + _VMEM_SPARE),
+        interpret=interpret,
+        name="hetu_grouped_swiglu",
+    )(*scalars, x, wg.reshape((-1, K, N)), wi.reshape((-1, K, N)),
+      wo.reshape((-1, N, K)), combine.weight)
 
 
 def grouped_matmul_reference(x, w, sizes):

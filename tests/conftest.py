@@ -247,6 +247,20 @@ def rng():
     return jax.random.key(0)
 
 
+@pytest.fixture(params=["fused", "split"])
+def grouped_form(request, monkeypatch):
+    """Both forms of ``ExpertShareMoE``'s grouped expert call at a
+    test's tiny widths: ``"fused"`` is what the shape rule picks there
+    (an expert's three matrices fit one grid step), ``"split"`` refuses
+    it as the rule does for experts that do not fit (Command A+'s) —
+    three ``grouped_matmul`` calls and the gather back."""
+    if request.param == "split":
+        from hetu_tpu.ops import grouped_matmul_pallas
+        monkeypatch.setattr(grouped_matmul_pallas, "grouped_swiglu_fits",
+                            lambda *a, **k: False)
+    return request.param
+
+
 @pytest.fixture
 def ragged_dot_experts():
     """``ExpertShareMoE`` as it computed its experts before the Pallas

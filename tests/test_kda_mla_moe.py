@@ -279,11 +279,13 @@ def test_group_limited_route_equals_the_references():
 
 
 def test_kernel_experts_equal_ragged_dot_under_the_group_limit(
-        ragged_dot_experts):
+        ragged_dot_experts, grouped_form):
     """``n_group`` 8 / ``topk_group`` 4, the held experts one group's
-    pair (Ling's deployment in small): the Pallas grouped matmuls
-    against ``ragged_dot`` on the layer's own group-limited route."""
+    pair (Ling's deployment in small): the Pallas grouped call, fused
+    and split, against ``ragged_dot`` on the layer's own group-limited
+    route."""
     moe = _moe(n_group=8, topk_group=4, local_experts=(6, 2))
+    assert moe.grouped_form(200 * 3) == grouped_form
     params = moe.init(jax.random.key(0))
     params["select_bias"] = 0.3 * jax.random.normal(jax.random.key(1), (16,))
     u = jax.random.normal(jax.random.key(2), (200, 32))

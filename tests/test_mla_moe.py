@@ -176,12 +176,18 @@ def test_no_token_is_dropped_when_all_pick_one_expert():
                                atol=1e-6)
 
 
-def test_kernel_experts_equal_ragged_dot_all_held(ragged_dot_experts):
-    """All 8 experts held (Kimi's layer): the Pallas grouped matmuls on
-    the aligned layout against three ``ragged_dot`` calls on the dense
-    sorted rows — plain, and as a ``StackedLeaf`` with a traced layer
-    inside a scan, as the serving step hands the weights over."""
+def test_kernel_experts_equal_ragged_dot_all_held(ragged_dot_experts,
+                                                  grouped_form):
+    """All 8 experts held (Kimi's layer): the Pallas grouped call on
+    the aligned layout — fused (one call that adds its weighted rows
+    into the tokens') and split (three calls and the gather back) —
+    against three ``ragged_dot`` calls on the dense sorted rows — plain,
+    and as a ``StackedLeaf`` with a traced layer inside a scan, as the
+    serving step hands the weights over. The host's counters say which
+    form the lane ran and the rows of the tiles it visited."""
+    from hetu_tpu import telemetry
     from hetu_tpu.nn.module import StackedLeaf
+    from hetu_tpu.ops.grouped_matmul_pallas import grouped_rows_computed
     moe = ExpertShareMoE(16, 8, 8, k=3, select_bias=True, scale=2.446)
     params = moe.init(jax.random.key(4))
     x = jax.random.normal(jax.random.key(5), (45, 16))
@@ -191,7 +197,29 @@ def test_kernel_experts_equal_ragged_dot_all_held(ragged_dot_experts):
         params, x)
     assert list(st) == ["sizes"]                 # no group limit here
     assert int(st["sizes"].sum()) == 45 * 3 and moe.tile_rows(45 * 3) == 32
+    assert moe.grouped_form(45 * 3) == grouped_form
     np.testing.assert_allclose(out, want, atol=1e-6)
+
+    telemetry.enable(True)
+    try:
+        reg = telemetry.get_registry()
+
+        def read():
+            return ({f: reg.counter("moe_grouped_form_calls_total").value(
+                form=f) for f in ("fused", "split")},
+                reg.counter("moe_grouped_rows_total").value(
+                    kind="computed"))
+        forms0, rows0 = read()
+        # one scan of two layer calls at these sizes, a lane of 45 rows
+        moe.count_share(np.stack([st["sizes"]] * 2), tokens=45)
+        forms, rows = read()
+    finally:
+        telemetry.enable(False)
+    other = {"fused": "split", "split": "fused"}[grouped_form]
+    assert forms[grouped_form] - forms0[grouped_form] == 2
+    assert forms[other] == forms0[other]
+    assert rows - rows0 == 2 * grouped_rows_computed(
+        np.asarray(st["sizes"]), 32)
 
     # three layers stacked, layer 1 is this one's, the others' differ
     stack = {n: jnp.stack([params[n] * 0 + 1, params[n], params[n] * 2])
@@ -204,6 +232,30 @@ def test_kernel_experts_equal_ragged_dot_all_held(ragged_dot_experts):
     _, outs = jax.jit(lambda: jax.lax.scan(body, 0, jnp.arange(3)))()
     np.testing.assert_allclose(outs[1], want, atol=1e-6)
     assert float(jnp.abs(outs[0] - want).max()) > 1e-3
+
+
+def test_a_crowded_share_walks_two_windows_in_both_forms(
+        ragged_dot_experts, grouped_form):
+    """A share of 2 of 16 experts that every token picks both of: 160
+    live pairs walk the window loop in TWO windows of 128 rows — the
+    fused form adds each window's rows into the tokens', the split form
+    gathers each window's back — against ``ragged_dot`` over all the
+    sorted rows at once."""
+    moe = ExpertShareMoE(16, 8, 16, k=4, local_experts=(4, 2))
+    params = moe.init(jax.random.key(0), dtype=jnp.float32)
+    bias = jnp.full((16,), -9.0).at[jnp.asarray([0, 1, 4, 5])].set(
+        jnp.asarray([3., 2., 6., 5.]))
+    x = jnp.concatenate([jax.random.normal(jax.random.key(1), (80, 15)),
+                         jnp.ones((80, 1))], axis=-1)
+    params = {**params, "router": params["router"].at[15].set(bias)}
+    assert moe._window_rows(80 * 4) == 128
+    assert moe.grouped_form(80 * 4) == grouped_form
+    out, st = jax.jit(lambda p, x: moe(p, x, return_stats=True))(
+        params, x)
+    assert st["sizes"].tolist() == [80, 80]      # two windows of 128
+    want = ragged_dot_experts(moe, params, x)
+    assert float(jnp.abs(want).max()) > 1e-4
+    np.testing.assert_allclose(out, want, atol=1e-6)
 
 
 # -- (e) the arena: one latent leaf -----------------------------------------

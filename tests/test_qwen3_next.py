@@ -338,6 +338,29 @@ def test_eight_shares_add_up_to_the_uncut_layer(tiny):
             got + shared, reference.expert_ffn(part, u, one)[0], atol=2e-6)
 
 
+def test_share_in_both_forms_equals_ragged_dot_on_its_own_tile(
+        ragged_dot_experts, grouped_form):
+    """The pack lane's expert share in small — 8 of 64 experts under a
+    softmax top-4, 640 tokens, so a window of 640 rows for the ~320
+    expected: the fused call's tile holds one and a half times the rows
+    an expert expects (64 for 40, as the cell's 64 for 40), the split
+    calls' the window's share (128) — against three ``ragged_dot``
+    calls over the dense sorted rows."""
+    from hetu_tpu.nn.moe import ExpertShareMoE
+    moe = ExpertShareMoE(32, 16, 64, k=4, local_experts=(8, 8),
+                         score="softmax")
+    params = moe.init(jax.random.key(3))
+    u = jax.random.normal(jax.random.key(4), (640, 32))
+    assert moe._window_rows(640 * 4) == 640
+    assert (moe.grouped_form(640 * 4), moe.tile_rows(640 * 4)) \
+        == (grouped_form, {"fused": 64, "split": 128}[grouped_form])
+    out, st = jax.jit(lambda p, x: moe(p, x, return_stats=True))(params, u)
+    assert 0 < int(st["sizes"].sum()) <= 640
+    want = ragged_dot_experts(moe, params, u)
+    assert float(jnp.abs(want).max()) > 1e-4
+    np.testing.assert_allclose(out, want, atol=2e-6)
+
+
 def test_a_steep_decay_survives_the_chunked_lanes():
     """Weights drawn so that heads decay by e^-16 a token and more (the
     tiny preset's ``dt_range`` up to 1): chunked prefill then decode

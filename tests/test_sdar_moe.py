@@ -266,6 +266,23 @@ def test_the_eight_shares_of_one_expert_layer_add_up():
     np.testing.assert_allclose(whole(p, x), want, atol=1e-5)
 
 
+def test_a_share_in_both_forms_equals_ragged_dot(ragged_dot_experts,
+                                                 grouped_form):
+    """Two of eight experts under the softmax top-2 (the block lane's
+    share in small): the fused call and the three split ones against
+    ``ragged_dot`` over the dense sorted rows."""
+    moe = ExpertShareMoE(32, 16, 8, k=2, score="softmax",
+                         local_experts=(2, 2))
+    p = moe.init(jax.random.key(2))
+    x = jax.random.normal(jax.random.key(3), (40, 32))
+    assert moe.grouped_form(40 * 2) == grouped_form
+    out, st = jax.jit(lambda p, x: moe(p, x, return_stats=True))(p, x)
+    assert 0 < int(st["sizes"].sum()) < 40 * 2
+    want = ragged_dot_experts(moe, p, x)
+    assert float(jnp.abs(want).max()) > 1e-4
+    np.testing.assert_allclose(out, want, atol=1e-5)
+
+
 # -- the model against the reference ----------------------------------------
 
 def test_prefill_then_block_passes_through_the_arena(tiny):

@@ -183,15 +183,18 @@ def test_fused_serving_step_keeps_the_arena_in_place_on_v5e(one_chip):
     assert r["temp_bytes"] < r["layer_leaf_bytes"], r
 
 
-def test_kimi_step_holds_three_expert_kernel_calls_a_lane_on_v5e(one_chip):
+def test_kimi_step_holds_one_expert_kernel_call_a_lane_on_v5e(one_chip):
     """Kimi-VL's fused step at the published widths (hidden 2048, experts
     1408 wide; cut to one dense and two expert layers of 12 experts so
     that the host holds its zeros): under ``hetu.moe_experts`` each lane
-    holds exactly the THREE Pallas grouped matmuls of a layer call
-    (``moe_experts_roofline_pct.*`` divides the scope by a third of its
-    custom calls), the compiler made no ``ragged-dot`` of its own, and
-    nothing copies or slices a layer's experts (``StackedLeaf``: the
-    kernel's index map takes the layer)."""
+    holds exactly ONE Pallas call a layer call since PR 62 — the fused
+    ``hetu_grouped_swiglu``, three 5.8 MB matrices a step beside a
+    2,048-token float32 result in VMEM (three grouped matmuls until
+    then: ``moe_experts_roofline_pct.*`` still divides the scope by a
+    third of its custom calls and reads three times low, ROADMAP R0) —
+    the compiler made no ``ragged-dot`` of its own, and nothing copies
+    or slices a layer's experts (``StackedLeaf``: the kernel's index map
+    takes the layer)."""
     import json
     import os
 
@@ -209,9 +212,47 @@ def test_kimi_step_holds_three_expert_kernel_calls_a_lane_on_v5e(one_chip):
         max_len=4096, chunk=512, block_size=64,
         leaf_elements=12 * 2048 * 1408)
     calls = r["kernel_calls"]
-    assert calls["hetu.decode_lane>hetu.moe_experts"] == 3, calls
-    assert calls["hetu.prefill_lane>hetu.moe_experts"] == 3, calls
+    assert calls["hetu.decode_lane>hetu.moe_experts"] == 1, calls
+    assert calls["hetu.prefill_lane>hetu.moe_experts"] == 1, calls
     assert r["arena_moves"] == {}, r
+
+
+@pytest.mark.parametrize("lane,shape", [
+    ("qwen3next_pack", (5120, 64, 2048, 512, 64, 2048)),
+    ("qwen3next_decode", (128, 64, 2048, 512, 16, 18)),
+    ("ling_pack", (4096, 64, 2560, 768, 64, 2048)),
+    ("kimi_pack", (12288, 64, 2048, 1408, 256, 2048)),
+])
+def test_fused_expert_call_compiles_for_v5e(one_chip, lane, shape):
+    """``grouped_swiglu`` at the cells' widths, tiles
+    and tokens (rows a call, held experts, hidden, expert width, tile,
+    tokens): an expert's three matrices double buffered beside the
+    resident float32 result, the stacked leaves at a traced layer — the
+    chip's compiler takes the dynamic row adds and the VMEM the rule
+    allowed."""
+    from hetu_tpu.ops.grouped_matmul_pallas import (
+        grouped_combine, grouped_layout, grouped_swiglu,
+    )
+    rows, groups, K, N, tile, tokens = shape
+
+    def sds(s, t):
+        return jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+
+    def call(x, wg, wi, wo, sizes, token, weight, layer):
+        lay = grouped_layout(sizes, rows=rows, tile=tile)
+        return grouped_swiglu(
+            jnp.take(x, lay.src, axis=0), wg, wi, wo, lay,
+            grouped_combine(lay, sizes, jnp.take(token, lay.src),
+                            jnp.take(weight, lay.src)),
+            tokens=tokens, layer=layer)
+
+    from workloads.aot_check import _compile_kernel
+    w = sds((2, groups, K, N), jnp.bfloat16)
+    _compile_kernel(call, (
+        sds((rows, K), jnp.bfloat16), w, w,
+        sds((2, groups, N, K), jnp.bfloat16), sds((groups,), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.float32),
+        sds((), jnp.int32)))
 
 
 def test_ling_step_holds_the_scan_kernel_a_kda_layer_run_on_v5e(one_chip):
